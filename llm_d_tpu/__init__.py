@@ -15,7 +15,7 @@ TPU-first equivalents of every executable layer:
   - ``kv``        : KV-connector abstraction, P->D transfer, tiered offload,
                     KV events (NIXL / LMCache / OffloadingConnector equivalents).
   - ``server``    : OpenAI-compatible HTTP server with the vllm:* metric
-                    taxonomy and the three-probe contract
+                    names and the three-probe contract
                     (reference: docs/readiness-probes.md).
   - ``epp``       : endpoint-picker scheduler: plugin pipeline of profile
                     handlers / filters / scorers / pickers

@@ -73,7 +73,7 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
 def attention_block(
     lp: Params, config: ModelConfig, x: jax.Array, batch: Dict[str, jax.Array],
     caches: Tuple[jax.Array, ...], block_size: int, attn_backend: str,
-    layer: jax.Array = None,
+    layer: jax.Array = None, mesh=None,
 ) -> Tuple[jax.Array, Tuple[jax.Array, ...]]:
     """Shared by dense and MoE models. Returns (attn_out, caches').
 
@@ -100,7 +100,7 @@ def attention_block(
     attn, *new_caches = attention_with_kv_update(
         q, kx, vx, caches[0], caches[1], batch,
         block_size=block_size, backend=attn_backend, layer=layer,
-        k_scale=k_scale, v_scale=v_scale)
+        k_scale=k_scale, v_scale=v_scale, mesh=mesh)
     out = L.linear(attn.reshape(T, c.num_heads * dh), lp["o_proj"])
     return out, tuple(new_caches)
 
@@ -112,7 +112,7 @@ def forward(
     config: ModelConfig,
     block_size: int,
     attn_backend: str = "auto",
-    mesh=None,                        # unused (MoE models need it for EP)
+    mesh=None,                        # Pallas attention runs per tp shard
     moe_opts=None,                    # unused (MoE dispatch knobs)
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One engine step over a ragged batch.
@@ -142,7 +142,8 @@ def forward(
     # step (~10 ms at 1B scale) — the dominant decode cost before this.
     def attend(lp, hn, caches, ab, li):
         return attention_block(
-            lp, c, hn, ab, caches, block_size, attn_backend, layer=li)
+            lp, c, hn, ab, caches, block_size, attn_backend, layer=li,
+            mesh=mesh)
 
     def layer_body(carry, lp):
         h, caches, li = carry

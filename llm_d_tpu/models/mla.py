@@ -23,6 +23,7 @@ config-level extension, tracked separately).
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -84,6 +85,7 @@ def mla_attention_block(
     attn_backend: str,
     layer: jax.Array,
     kv_scale: jax.Array = None,   # int8 latent: [L, slots, SW] f32 scales
+    mesh=None,                    # multi-device: Pallas runs per tp shard
 ) -> Tuple[jax.Array, ...]:
     """Weight-absorbed MLA over the paged latent cache.
 
@@ -141,6 +143,38 @@ def mla_attention_block(
         row = jnp.pad(row, ((0, 0), (0, pad)))
         q_eff = jnp.pad(q_eff, ((0, 0), (0, 0), (0, pad)))
 
+    backend = A.resolve_backend(attn_backend)
+    attend = functools.partial(_mla_attend, block_size=block_size,
+                               backend=backend, scale=scale, R=R)
+    ab = {k: batch[k] for k in A.ATTN_BATCH_KEYS if k in batch}
+    scales = () if kv_scale is None else (kv_scale,)
+    if backend == "pallas":
+        # Per tp shard on a multi-device mesh: heads split, the latent
+        # cache replicated (every shard splices the same row into its own
+        # replica).
+        from jax.sharding import PartitionSpec as P
+        heads = P(None, "tp", None)
+        attend = A.manual_over_mesh(
+            attend, mesh,
+            in_specs=(heads, P(), P(), {k: P() for k in ab}, P())
+            + (P(),) * len(scales),
+            out_specs=(heads, P()) + (P(),) * len(scales))
+    out_lat, kv_cache, *new_scales = attend(
+        q_eff, row, kv_cache, ab, layer, *scales)
+
+    # --- absorb W_uv: latent -> per-head value space, then output proj ---
+    attn = jnp.einsum("thr,rhv->thv", out_lat,
+                      w_uv.astype(jnp.float32)).astype(x.dtype)
+    return (L.linear(attn.reshape(T, H * vdim), lp["o_proj"]),
+            kv_cache, *new_scales)
+
+
+def _mla_attend(q_eff, row, kv_cache, batch, layer, kv_scale=None, *,
+                block_size: int, backend: str, scale: float, R: int):
+    """Latent-cache update + attention over one tp shard's heads (or all):
+    (out_lat [T, H_local, R] f32, kv_cache'[, kv_scale'])."""
+    T = q_eff.shape[0]
+    F_cache = kv_cache.shape[-1]
     quantized = kv_scale is not None
     if quantized:
         # One symmetric f32 scale per latent row (SW = 1 — the row is
@@ -150,18 +184,12 @@ def mla_attention_block(
         from llm_d_tpu.ops.quant import quantize_kv_block
         row_q, row_s = quantize_kv_block(row, kv_scale.shape[-1])
 
-    def _ret(out_proj, kv_cache, kv_scale):
-        if quantized:
-            return out_proj, kv_cache, kv_scale
-        return out_proj, kv_cache
-
-    backend = A.resolve_backend(attn_backend)
     qtok_idx = batch["qtok_idx"]
-    # Int8 pages tile (32, 128): the quantized kernels additionally need
-    # block_size % 32 (same gate as the dense paged kernels).
-    kernel_ok = not quantized or block_size % 32 == 0
-    if backend == "pallas" and kernel_ok and A.pallas_decode_eligible(
-            batch, block_size, F_cache):
+    # An ineligible geometry takes the chunked XLA path; the engine
+    # announced that at construction (A.pallas_ineligible_reason).
+    kernel_ok = backend == "pallas" and A.pallas_ineligible_reason(
+        block_size, F_cache, quantized) is None
+    if kernel_ok and qtok_idx.shape[1] == 1:
         # Decode hot path: single-buffer MQA kernel — each latent page is
         # DMA'd once and used for both the score and value dots, with the
         # new row spliced in place (ops/pallas/mla_attention.py).
@@ -191,12 +219,11 @@ def mla_attention_block(
                 block_size=block_size, scale=scale, layer=layer,
                 seq_group=sg)
         out_lat = out[batch["token_seq_ids"]][..., :R].astype(jnp.float32)
-    elif backend == "pallas" and kernel_ok and qtok_idx.shape[1] > 1 \
-            and block_size % 16 == 0 and F_cache % 128 == 0:
+    elif kernel_ok:
         # Prefill / mixed batches: MLA flash kernel — the latent page is
         # DMA'd once per tile and serves both the score and value dots
         # (ops/pallas/mla_prefill.py; the chunked XLA path below cost
-        # ~90% of the MoE prefill step, BENCH_r04 Weak #4).
+        # ~90% of the MoE prefill step, round-4 verdict Weak #4).
         from llm_d_tpu.ops.pallas.mla_prefill import mla_flash_prefill
         wr = (row_q if quantized else row).reshape(T, 1, F_cache)
         kv_cache, _ = A.write_kv(
@@ -229,11 +256,9 @@ def mla_attention_block(
             v_scale=kv_scale)                               # [T, H, F_cache]
         out_lat = out_lat[..., :R].astype(jnp.float32)      # attended c_kv
 
-    # --- absorb W_uv: latent -> per-head value space, then output proj ---
-    attn = jnp.einsum("thr,rhv->thv", out_lat,
-                      w_uv.astype(jnp.float32)).astype(x.dtype)
-    return _ret(L.linear(attn.reshape(T, H * vdim), lp["o_proj"]),
-                kv_cache, kv_scale)
+    if quantized:
+        return out_lat, kv_cache, kv_scale
+    return out_lat, kv_cache
 
 
 def mla_sharding_rules():

@@ -159,10 +159,12 @@ def forward(
             from llm_d_tpu.models.mla import mla_attention_block
             a, *new_caches = mla_attention_block(
                 lp, c, hn, ab, caches[0], block_size, attn_backend,
-                layer=li, kv_scale=caches[1] if len(caches) > 1 else None)
+                layer=li, kv_scale=caches[1] if len(caches) > 1 else None,
+                mesh=mesh)
             return a, tuple(new_caches)
         return attention_block(
-            lp, c, hn, ab, caches, block_size, attn_backend, layer=li)
+            lp, c, hn, ab, caches, block_size, attn_backend, layer=li,
+            mesh=mesh)
 
     def attend(lp, hn, caches, li):
         """Stacked mode: per-dp-shard attention (manual dp, auto tp) —

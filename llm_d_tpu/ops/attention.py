@@ -32,6 +32,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from llm_d_tpu.ops.quant import dequantize_kv_block, quantize_kv_block
 
@@ -350,13 +351,47 @@ def resolve_backend(backend: str) -> str:
     return backend
 
 
-def pallas_decode_eligible(batch, block_size: int, row_width: int) -> bool:
-    """Shared gate for the Pallas decode kernels (dense and MLA):
-    pure-decode batch (Q == 1), bf16-sublane-aligned pages
-    (block_size % 16), 128-lane-aligned rows (row_width % 128)."""
-    qtok_idx = batch.get("qtok_idx")
-    return (qtok_idx is not None and qtok_idx.shape[1] == 1
-            and block_size % 16 == 0 and row_width % 128 == 0)
+def pallas_ineligible_reason(block_size: int, row_width: int,
+                             quantized: bool = False) -> Optional[str]:
+    """Why the Pallas attention kernels (dense and MLA, decode and prefill)
+    cannot serve a cache geometry; None when they can.  TPU DMA slices need
+    sublane-aligned pages and 128-lane-aligned rows — an ineligible shape
+    would fail Mosaic compilation, so the chunked XLA path serves it.  The
+    answer is static per engine: ``EngineCore`` asks ONCE at construction
+    and says so (log + ``engine_feature_disabled_total``) instead of
+    leaving the drop to be discovered from a profile."""
+    if block_size % 16:
+        return (f"block_size {block_size} is not a multiple of 16 "
+                f"(bf16 sublane tile)")
+    if row_width % 128:
+        return (f"cache row width {row_width} is not a multiple of 128 "
+                f"lanes")
+    if quantized and block_size % 32:
+        return (f"block_size {block_size} is not a multiple of 32 "
+                f"(int8 sublane tile)")
+    return None
+
+
+def manual_over_mesh(fn, mesh, in_specs, out_specs):
+    """Mosaic kernels cannot be partitioned automatically ("wrap the call
+    in a shard_map"): run ``fn`` MANUAL over every mesh axis that is not
+    manual already.  Under ``dp_attend`` the dp axis already is, so this
+    nests and takes the rest (tp, sp); on a plain TP mesh it takes all
+    three.  Head-sharded operands name ``"tp"`` in their specs; each shard
+    then sees exactly the local shapes the kernels handle on one chip."""
+    if mesh is None or mesh.devices.size == 1:
+        return fn
+    ctx = jax.sharding.get_abstract_mesh()
+    return jax.shard_map(
+        fn, mesh=None if ctx.manual_axes else mesh,
+        in_specs=in_specs, out_specs=out_specs,
+        axis_names=set(mesh.axis_names) - set(ctx.manual_axes),
+        check_vma=False)
+
+
+# Batch arrays attention consumes (replicated over tp under a TP mesh).
+ATTN_BATCH_KEYS = ("positions", "token_seq_ids", "token_qpos",
+                   "slot_mapping", "block_tables", "seq_lens", "qtok_idx")
 
 
 def attention_with_kv_update(
@@ -373,6 +408,7 @@ def attention_with_kv_update(
     layer: Optional[jax.Array] = None,   # i32 plane of a stacked cache
     k_scale: Optional[jax.Array] = None,  # int8 caches: f32 scale planes
     v_scale: Optional[jax.Array] = None,  # ([num_slots, SW] / [L, slots, SW])
+    mesh=None,               # multi-device mesh: Pallas runs per tp shard
 ):
     """Write this step's KV into the paged cache and attend over it.
 
@@ -394,8 +430,35 @@ def attention_with_kv_update(
     itself stays bf16/f32.  Returns a 5-tuple
     (attn_out, k_cache', v_cache', k_scale', v_scale') in that mode;
     the classic 3-tuple otherwise.
+
+    On a multi-device ``mesh`` the Pallas backend runs per tp shard under
+    ``manual_over_mesh``: heads (and the folded cache rows) split over
+    ``tp``, every shard attends its own heads with no cross-shard traffic.
     """
     backend = resolve_backend(backend)
+    if backend == "pallas" and mesh is not None and mesh.devices.size > 1:
+        ab = {k: batch[k] for k in ATTN_BATCH_KEYS if k in batch}
+        heads = P(None, "tp", None)
+        rows = P(*(None,) * (k_cache.ndim - 1), "tp")
+        scales = () if k_scale is None else (k_scale, v_scale)
+        s_spec = rows if scales and k_scale.shape[-1] > 1 else P()
+        largs = () if layer is None else (layer,)
+
+        def local(q, k_new, v_new, k_cache, v_cache, ab, *rest):
+            lyr = rest[0] if largs else None
+            ks, vs = rest[len(largs):] or (None, None)
+            return attention_with_kv_update(
+                q, k_new, v_new, k_cache, v_cache, ab, block_size,
+                scale=scale, soft_cap=soft_cap, backend=backend,
+                layer=lyr, k_scale=ks, v_scale=vs)
+
+        return manual_over_mesh(
+            local, mesh,
+            in_specs=(heads, heads, heads, rows, rows,
+                      {k: P() for k in ab}) + (P(),) * len(largs)
+            + (s_spec,) * len(scales),
+            out_specs=(heads, rows, rows) + (s_spec,) * len(scales),
+        )(q, k_new, v_new, k_cache, v_cache, ab, *largs, *scales)
     quantized = k_scale is not None
     T, H, D = q.shape
     F = k_cache.shape[-1]
@@ -411,13 +474,12 @@ def attention_with_kv_update(
         return out, k_cache, v_cache
 
     qtok_idx = batch.get("qtok_idx")
-    # TPU DMA slices need sublane- and lane-aligned pages (see
-    # pallas_decode_eligible); anything smaller falls back to the chunked
-    # XLA path instead of failing Mosaic compilation.  Int8 pages tile
-    # (32, 128), so the quantized kernel additionally needs block_size % 32.
-    if backend == "pallas" and soft_cap is None \
-            and pallas_decode_eligible(batch, block_size, F) \
-            and (not quantized or block_size % 32 == 0):
+    # An ineligible cache geometry takes the chunked XLA path; the engine
+    # announced that at construction.
+    kernel_ok = (backend == "pallas" and qtok_idx is not None
+                 and pallas_ineligible_reason(
+                     block_size, F, quantized) is None)
+    if kernel_ok and soft_cap is None and qtok_idx.shape[1] == 1:
         from llm_d_tpu.ops.pallas.paged_attention import (
             paged_attention_decode_update)
         rows = qtok_idx[:, 0].clip(0, T - 1)
@@ -451,12 +513,10 @@ def attention_with_kv_update(
         k_cache, v_cache = write_kv(
             k_cache, v_cache, k_new, v_new, batch["slot_mapping"],
             layer=layer)
-    if backend == "pallas" and qtok_idx is not None \
-            and qtok_idx.shape[1] > 1 and block_size % 16 == 0 \
-            and F % 128 == 0 and (not quantized or block_size % 32 == 0):
+    if kernel_ok and qtok_idx.shape[1] > 1:
         # Prefill / mixed batches: flash kernel streaming KV pages through
-        # VMEM (scatter-then-read; no aliasing needed).  Same lane/sublane
-        # gates as the decode kernel.
+        # VMEM (scatter-then-read; no aliasing needed).  Same geometry
+        # gate as the decode kernel.
         from llm_d_tpu.ops.pallas.flash_prefill import flash_prefill_paged
         qs, q_pos = gather_per_seq_queries(
             q, batch["positions"], qtok_idx)

@@ -33,7 +33,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from llm_d_tpu.ops.pallas.quant_util import make_page_dequant
-from llm_d_tpu.utils.jax_compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -170,14 +169,23 @@ def _prefill_kernel(
     o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _pick_q_tile(Q: int, H: int, F: int, budget: int = 6 << 20) -> int:
-    """Largest DIVISOR of Q whose f32 accumulator + query pair fits the
-    VMEM budget (divisor search, not halving: Q buckets can be
-    non-powers-of-two when ``--max-num-batched-tokens`` clamps them, and
-    an odd-but-oversized tile would fail Mosaic compilation)."""
+def _pick_q_tile(Q: int, H: int, F: int, budget: int = 8 << 20) -> int:
+    """Largest DIVISOR of Q whose per-program VMEM fits the budget
+    (divisor search, not halving: Q buckets can be non-powers-of-two when
+    ``--max-num-batched-tokens`` clamps them, and an odd-but-oversized
+    tile would fail Mosaic compilation).
+
+    Per fused row (Qt*H rows): the f32 accumulator + zero-expanded query
+    pair (8*F bytes) PLUS the blocks whose minor dim lane-pads to 128 —
+    the [rows, 1] i32 position column, the [rows, D] q/out blocks (double
+    buffered) and the [rows, block_size] f32 score/probability pair.  The
+    padded terms dominate when F is small (a tp shard's F = KVH*D/tp):
+    leaving them out let a 4096-row tile through at F=128, which the v5e
+    compiler refused (16.17 MB of scoped VMEM)."""
+    per_row = 8 * F + 3072
     best = 1
     for qt in range(1, Q + 1):
-        if Q % qt == 0 and qt * H * F * 8 <= budget:
+        if Q % qt == 0 and qt * H * per_row <= budget:
             best = qt
     return best
 
@@ -264,7 +272,7 @@ def flash_prefill_paged(
         kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((S, Q * H, D), qs.dtype)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(*operands)

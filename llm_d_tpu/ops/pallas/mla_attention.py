@@ -39,7 +39,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from llm_d_tpu.utils.jax_compat import CompilerParams
 
 from llm_d_tpu.ops.pallas.paged_attention import pick_seq_group
 from llm_d_tpu.ops.pallas.quant_util import make_page_dequant
@@ -314,7 +313,7 @@ def mla_paged_decode_update(
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), has_side_effects=True),
         interpret=interpret,
     )(*operands)

@@ -44,7 +44,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from llm_d_tpu.utils.jax_compat import CompilerParams
 
 
 def _grouped_kernel(
@@ -137,7 +136,7 @@ def grouped_moe_int8(
         _grouped_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S_pad, H), jnp.bfloat16),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(layer_arr, tile_expert, x_pad, wslot_pad,
@@ -217,7 +216,7 @@ def dense_moe_int8(
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, H), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),   # sequential accumulation
         interpret=interpret,
     )(layer_arr, x, comb.T.astype(jnp.float32),

@@ -67,7 +67,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from llm_d_tpu.utils.jax_compat import CompilerParams
 
 
 def _routed_kernel(
@@ -159,8 +158,11 @@ def _routed_kernel(
         # Gather matmul: one-hot row selector (exact for bf16 payloads).
         sel = (tok_c == jax.lax.broadcasted_iota(
             jnp.int32, (RT, Tp), 1)).astype(jnp.bfloat16)  # [RT, Tp]
+        # f32 accumulator (Mosaic's matmul takes no 16-bit one); the
+        # cast back is exact — each output is one selected bf16 value.
         xg = jax.lax.dot(sel, x_ref[...],
-                         preferred_element_type=jnp.bfloat16)   # [RT, H]
+                         preferred_element_type=jnp.float32
+                         ).astype(jnp.bfloat16)                 # [RT, H]
         wg = wg_buf[s].astype(jnp.bfloat16)                # exact |q|<=127
         wu = wu_buf[s].astype(jnp.bfloat16)
         h = jax.lax.dot(xg, wg,
@@ -231,7 +233,10 @@ def routed_moe_int8(
         in_specs=[
             pl.BlockSpec((Tp, H), lambda t, *_: (0, 0)),        # x resident
             pl.BlockSpec((row_tile, 1), lambda t, *_: (t, 0)),  # tok col
-            pl.BlockSpec((1, row_tile), lambda t, *_: (t, 0)),  # tok row
+            # One [1, RT] row per tile.  Blocked over a leading tile dim
+            # so the block's last two dims EQUAL the array's: a (1, RT)
+            # block of [NT, RT] breaks Mosaic's (8, 128) divisibility rule.
+            pl.BlockSpec((None, 1, row_tile), lambda t, *_: (t, 0, 0)),
             pl.BlockSpec((row_tile, 1), lambda t, *_: (t, 0)),  # wslot
             any_spec, any_spec, any_spec,                       # w_{g,u,d}_q
             any_spec, any_spec, any_spec,                       # scales
@@ -251,8 +256,8 @@ def routed_moe_int8(
         _routed_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Tp, H), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),   # sequential accumulation
         interpret=interpret,
-    )(meta, te, slot, load, x, tok_pad, tok_row, wslot_pad,
+    )(meta, te, slot, load, x, tok_pad, tok_row[:, None, :], wslot_pad,
       w_gate_q, w_up_q, w_down_q, w_gate_s, w_up_s, w_down_s)

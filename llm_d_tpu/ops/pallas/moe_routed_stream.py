@@ -62,7 +62,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from llm_d_tpu.utils.jax_compat import CompilerParams
 
 
 def _streamed_kernel(
@@ -99,8 +98,11 @@ def _streamed_kernel(
         # bf16 payloads) — the rows never take a detour through HBM.
         sel = (tok_c == jax.lax.broadcasted_iota(
             jnp.int32, (RT, Tc), 1)).astype(jnp.bfloat16)  # [RT, Tc]
+        # f32 accumulator (Mosaic's matmul takes no 16-bit one); the
+        # cast back is exact — each output is one selected bf16 value.
         xg = jax.lax.dot(sel, x_ref[...],
-                         preferred_element_type=jnp.bfloat16)   # [RT, H]
+                         preferred_element_type=jnp.float32
+                         ).astype(jnp.bfloat16)                 # [RT, H]
         wg = wg_ref[0, 0].astype(jnp.bfloat16)             # exact |q|<=127
         wu = wu_ref[0, 0].astype(jnp.bfloat16)
         h = jax.lax.dot(xg, wg,
@@ -170,7 +172,11 @@ def streamed_moe_int8(
         in_specs=[
             pl.BlockSpec((chunk_t, H), lambda c, t, *_: (c, 0)),  # x chunk
             pl.BlockSpec((row_tile, 1), tmap),                    # tok col
-            pl.BlockSpec((1, row_tile), tmap),                    # tok row
+            # tok row: blocked over a leading tile dim so the block's
+            # last two dims equal the array's (Mosaic's (8, 128) rule
+            # refuses a (1, RT) block of [NT, RT]).
+            pl.BlockSpec((None, 1, row_tile),
+                         lambda c, t, *_: (c * NT_c + t, 0, 0)),
             pl.BlockSpec((row_tile, 1), tmap),                    # wslot
             pl.BlockSpec((1, 1, H, I), wmap),
             pl.BlockSpec((1, 1, H, I), wmap),
@@ -185,10 +191,17 @@ def streamed_moe_int8(
         _streamed_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Tp, H), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # Sequential accumulation within a chunk; chunks advance the
             # resident x/output blocks (streamed, double-buffered).
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # The double-buffered blocks alone are 18 MB at the default
+            # chunk (x 2x2, f32 out 2x4, three int8 weight slabs 2x1 each
+            # at H=2048, I=512): over the compiler's 16 MB default scope,
+            # which it enforces from T = 32768 rows (the a2a path's
+            # arrival buffer at EP=4) and not below.  v5e has 128 MiB.
+            vmem_limit_bytes=48 << 20),
         interpret=interpret,
-    )(meta, num_tiles, tile_expert, x, tok_pad, tok_row, wslot_pad,
+    )(meta, num_tiles, tile_expert, x, tok_pad, tok_row[:, None, :],
+      wslot_pad,
       w_gate_q, w_up_q, w_down_q, w_gate_s, w_up_s, w_down_s)

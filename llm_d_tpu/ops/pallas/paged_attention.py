@@ -34,7 +34,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from llm_d_tpu.ops.pallas.quant_util import make_page_dequant
-from llm_d_tpu.utils.jax_compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -386,7 +385,7 @@ def paged_attention_decode_update(
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), has_side_effects=True),
         interpret=interpret,
     )(*operands)

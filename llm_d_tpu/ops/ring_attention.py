@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from llm_d_tpu.parallel.mesh import AXIS_SP, AXIS_TP
-from llm_d_tpu.utils.jax_compat import shard_map
 
 NEG_INF = -1e30
 
@@ -110,7 +109,7 @@ def ring_attention(
         out = acc / jnp.maximum(l, 1e-30)[..., None]
         return out.reshape(Tl, q_loc.shape[1], D).astype(q_loc.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(AXIS_SP, AXIS_TP, None), P(AXIS_SP, AXIS_TP, None),
                   P(AXIS_SP, AXIS_TP, None)),

@@ -32,11 +32,8 @@ from typing import Callable, Dict, Tuple
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from llm_d_tpu.utils.jax_compat import shard_map
 
-# Batch arrays attention consumes; all are per-shard in stacked mode.
-ATTN_BATCH_KEYS = ("positions", "token_seq_ids", "token_qpos",
-                   "slot_mapping", "block_tables", "seq_lens", "qtok_idx")
+from llm_d_tpu.ops.attention import ATTN_BATCH_KEYS  # per-shard when stacked
 
 AttendLocal = Callable[..., Tuple[jax.Array, Tuple[jax.Array, ...]]]
 
@@ -54,9 +51,12 @@ def dp_attend(
     per dp shard; returns (attn_out [dp, T_l, D], new caches).
 
     tp remains an auto axis inside the manual region (``axis_names={"dp"}``)
-    — the projections' tp sharding and collectives are unchanged, and the
-    Pallas kernels see exactly the per-shard local shapes they already
-    handle on a single chip.
+    — the projections' tp sharding and collectives are unchanged.  The
+    Pallas kernels cannot live in a partially-manual region (Mosaic is
+    never partitioned automatically): the attention ops nest a second
+    shard_map over the remaining axes around the kernel call
+    (``ops.attention.manual_over_mesh``), where they see exactly the
+    per-shard local shapes they already handle on a single chip.
     """
     ab = {k: batch[k] for k in ATTN_BATCH_KEYS if k in batch}
     n_cache = len(caches)
@@ -69,7 +69,7 @@ def dp_attend(
         return a[None], tuple(c[None] for c in new_caches)
 
     dp = P("dp")
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), dp, (dp,) * n_cache, {k: dp for k in ab}, P()),
         out_specs=(dp, (dp,) * n_cache),

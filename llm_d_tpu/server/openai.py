@@ -7,7 +7,7 @@ gateway/EPP/monitoring stack sees an identical surface
   GET  /health          -> 200 as soon as the process is up (liveness)
   GET  /v1/models       -> 200 only once the model is loaded (startup,
                            readiness: "model-aware readiness" doctrine)
-  GET  /metrics         -> Prometheus text, ``vllm:*`` taxonomy
+  GET  /metrics         -> Prometheus text, ``vllm:*`` names
   POST /v1/completions  -> OpenAI completions (+SSE streaming)
   POST /v1/chat/completions -> OpenAI chat (+SSE streaming)
 
@@ -1176,7 +1176,9 @@ def build_arg_parser() -> argparse.ArgumentParser:
     p.add_argument("--compilation-cache-dir", default=None,
                    help="persistent XLA compile cache surviving restarts "
                         "(reference: VLLM_CACHE_ROOT mounts, "
-                        "decode.yaml:152-164)")
+                        "decode.yaml:152-164); default <checkout>/"
+                        ".jax_cache; JAX_COMPILATION_CACHE_DIR wins over "
+                        "both")
     p.add_argument("--model", default="tiny")
     p.add_argument("--tokenizer", default=None)
     p.add_argument("--host", default="0.0.0.0")
@@ -1349,11 +1351,9 @@ def main(argv: Optional[List[str]] = None) -> None:
         p.error("--kv-shared-tier-port/--kv-shared-tier-peers require "
                 "--kv-offload-blocks > 0 (the shared tier serves the host "
                 "tier's blocks)")
-    if args.compilation_cache_dir:
-        import jax
-        jax.config.update("jax_compilation_cache_dir",
-                          args.compilation_cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    from llm_d_tpu.utils.compile_cache import configure_compile_cache
+    logger.info("compile cache: %s",
+                configure_compile_cache(args.compilation_cache_dir))
 
     import os as _os
 

@@ -1,7 +1,7 @@
 """Accelerator-free inference simulator (llm-d-inference-sim equivalent).
 
 A fake model server with the REAL API surface — OpenAI endpoints, the
-three-probe readiness contract, and the ``vllm:*`` metric taxonomy — but no
+three-probe readiness contract, and the ``vllm:*`` metric naming scheme — but no
 engine: responses are synthesized at configurable TTFT/TPOT.  The reference
 uses exactly such a component to scale-test the scheduler and autoscaler "in
 wide or dense configurations on CPU-only machines" (reference:

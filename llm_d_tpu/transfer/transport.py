@@ -1,10 +1,20 @@
 """Host-buffer transport under the KV connector (the NIXL/UCX role).
 
-The data plane is native C++ (``native/kv_transfer.cpp``), compiled once on
-first use and driven via ctypes: a registered-slab server whose accept loop
-runs off the GIL, plus blocking fetch/release clients.  A pure-Python
-fallback with the identical wire protocol keeps the feature alive on hosts
-without a toolchain (and doubles as a cross-check in tests).
+The data plane is native C++ (``native/kv_transfer.cpp``), compiled on
+first use from the committed source and driven via ctypes: a
+registered-slab server whose accept loop runs off the GIL, plus blocking
+fetch/release clients.  The binary is never committed: it is built into
+``native/libkvtransfer-<sha256 of the source>.so`` — a path fixed by the
+source's CONTENT, so a copy of the tree with arbitrary mtimes neither
+rebuilds needlessly nor loads a stale library — and an image build may
+prebuild it (docker/Dockerfile.tpu) so the runtime needs no toolchain.
+
+The facade (``make_server`` / ``fetch`` / ``release``) IS the native
+plane: a producer or consumer that cannot have it fails loudly with the
+compiler's message instead of degrading to Python.  The pure-Python
+transport below speaks the identical wire protocol; it serves the
+offload tier's shared-cache server (engine/offload.py asks for it by
+name) and cross-checks the protocol in tests.
 
 Reference roles mirrored here: NIXL point-to-point KV transfer without a
 metadata side channel (docs/proposals/llm-d.md:60-68); the vLLM TPUConnector
@@ -14,6 +24,7 @@ contract's remote_host/remote_port/uuid addressing (README.tpu.md:182-189).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import socket
@@ -26,63 +37,8 @@ logger = logging.getLogger(__name__)
 
 _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "native")
 _SRC = os.path.join(_NATIVE_DIR, "kv_transfer.cpp")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "libkvtransfer.so")
 _build_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_lib_failed = False
-
-
-def _load_native() -> Optional[ctypes.CDLL]:
-    """Compile (if stale) and load the native transport; None on failure."""
-    global _lib, _lib_failed
-    if _lib is not None or _lib_failed:
-        return _lib
-    with _build_lock:
-        if _lib is not None or _lib_failed:
-            return _lib
-        try:
-            # A wheel may ship only the prebuilt .so (no toolchain in the
-            # runtime image); rebuild solely when the source is present
-            # and newer.
-            if (not os.path.exists(_LIB_PATH)
-                    or (os.path.exists(_SRC)
-                        and os.path.getmtime(_LIB_PATH)
-                        < os.path.getmtime(_SRC))):
-                if not os.path.exists(_SRC):
-                    raise OSError(f"{_SRC} missing and no prebuilt library")
-                subprocess.run(
-                    ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                     "-pthread", "-o", _LIB_PATH + ".tmp", _SRC],
-                    check=True, capture_output=True)
-                os.replace(_LIB_PATH + ".tmp", _LIB_PATH)
-            lib = ctypes.CDLL(_LIB_PATH)
-            lib.kvts_create.restype = ctypes.c_void_p
-            lib.kvts_create.argtypes = [ctypes.c_char_p, ctypes.c_int]
-            lib.kvts_port.restype = ctypes.c_int
-            lib.kvts_port.argtypes = [ctypes.c_void_p]
-            lib.kvts_register.argtypes = [
-                ctypes.c_void_p, ctypes.c_char_p, ctypes.c_char_p,
-                ctypes.c_uint64]
-            lib.kvts_unregister.restype = ctypes.c_int
-            lib.kvts_unregister.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
-            lib.kvts_next_released.restype = ctypes.c_int
-            lib.kvts_next_released.argtypes = [
-                ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int]
-            lib.kvts_destroy.argtypes = [ctypes.c_void_p]
-            lib.kvts_fetch.restype = ctypes.c_int64
-            lib.kvts_fetch.argtypes = [
-                ctypes.c_char_p, ctypes.c_int, ctypes.c_char_p, ctypes.c_int,
-                ctypes.POINTER(ctypes.POINTER(ctypes.c_char))]
-            lib.kvts_free.argtypes = [ctypes.POINTER(ctypes.c_char)]
-            lib.kvts_release.restype = ctypes.c_int
-            lib.kvts_release.argtypes = [
-                ctypes.c_char_p, ctypes.c_int, ctypes.c_char_p, ctypes.c_int]
-            _lib = lib
-        except (OSError, subprocess.CalledProcessError) as e:
-            logger.warning(
-                "native kv-transfer build failed (%s); using Python transport", e)
-            _lib_failed = True
-    return _lib
 
 
 class TransferError(Exception):
@@ -91,6 +47,63 @@ class TransferError(Exception):
 
 class TransferNotFound(TransferError):
     pass
+
+
+def _lib_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_NATIVE_DIR, f"libkvtransfer-{digest}.so")
+
+
+def _load_native() -> ctypes.CDLL:
+    """Build (if this source has no library yet) and load the native
+    transport.  Raises ``TransferError`` carrying the toolchain's message
+    when it cannot: there is no silent Python fallback."""
+    global _lib
+    if _lib is not None:
+        return _lib
+    with _build_lock:
+        if _lib is not None:
+            return _lib
+        try:
+            path = _lib_path()
+            if not os.path.exists(path):
+                tmp = f"{path}.{os.getpid()}.tmp"   # atomic publish only
+                subprocess.run(
+                    ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
+                     "-pthread", "-o", tmp, _SRC],
+                    check=True, capture_output=True, text=True)
+                os.replace(tmp, path)
+            lib = ctypes.CDLL(path)
+        except subprocess.CalledProcessError as e:
+            raise TransferError(
+                f"native kv-transfer build failed: {e.stderr[-2000:]}") from e
+        except OSError as e:
+            raise TransferError(
+                f"native kv-transfer unavailable: {e}") from e
+        lib.kvts_create.restype = ctypes.c_void_p
+        lib.kvts_create.argtypes = [ctypes.c_char_p, ctypes.c_int]
+        lib.kvts_port.restype = ctypes.c_int
+        lib.kvts_port.argtypes = [ctypes.c_void_p]
+        lib.kvts_register.argtypes = [
+            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_char_p,
+            ctypes.c_uint64]
+        lib.kvts_unregister.restype = ctypes.c_int
+        lib.kvts_unregister.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
+        lib.kvts_next_released.restype = ctypes.c_int
+        lib.kvts_next_released.argtypes = [
+            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int]
+        lib.kvts_destroy.argtypes = [ctypes.c_void_p]
+        lib.kvts_fetch.restype = ctypes.c_int64
+        lib.kvts_fetch.argtypes = [
+            ctypes.c_char_p, ctypes.c_int, ctypes.c_char_p, ctypes.c_int,
+            ctypes.POINTER(ctypes.POINTER(ctypes.c_char))]
+        lib.kvts_free.argtypes = [ctypes.POINTER(ctypes.c_char)]
+        lib.kvts_release.restype = ctypes.c_int
+        lib.kvts_release.argtypes = [
+            ctypes.c_char_p, ctypes.c_int, ctypes.c_char_p, ctypes.c_int]
+        _lib = lib
+    return _lib
 
 
 # ---------------------------------------------------------------------------
@@ -148,10 +161,7 @@ class NativeTransferServer:
     """Slab registry + TCP server backed by the C++ accept loop."""
 
     def __init__(self, host: str = "0.0.0.0", port: int = 0) -> None:
-        lib = _load_native()
-        if lib is None:
-            raise TransferError("native transport unavailable")
-        self._lib = lib
+        lib = self._lib = _load_native()
         self._handle = lib.kvts_create(_resolve(host).encode()
                                        if host != "0.0.0.0" else b"0.0.0.0",
                                        port)
@@ -184,8 +194,6 @@ class NativeTransferServer:
 def native_fetch(host: str, port: int, uuid: str,
                  timeout_ms: int = 30000) -> bytes:
     lib = _load_native()
-    if lib is None:
-        raise TransferError("native transport unavailable")
     out = ctypes.POINTER(ctypes.c_char)()
     n = lib.kvts_fetch(_resolve(host).encode(), port, uuid.encode(),
                        timeout_ms, ctypes.byref(out))
@@ -203,15 +211,13 @@ def native_fetch(host: str, port: int, uuid: str,
 def native_release(host: str, port: int, uuid: str,
                    timeout_ms: int = 10000) -> bool:
     lib = _load_native()
-    if lib is None:
-        raise TransferError("native transport unavailable")
     return bool(lib.kvts_release(_resolve(host).encode(), port,
                                  uuid.encode(), timeout_ms))
 
 
 # ---------------------------------------------------------------------------
-# Pure-Python transport: identical wire protocol, used when the native build
-# is unavailable and to cross-check the protocol in tests.
+# Pure-Python transport: identical wire protocol; asked for by name (the
+# offload tier's shared-cache server, the protocol cross-check in tests).
 # ---------------------------------------------------------------------------
 
 _NOT_FOUND = 0xFFFFFFFFFFFFFFFF
@@ -229,7 +235,7 @@ def _recv_full(sock: socket.socket, n: int) -> bytes:
 
 
 class PyTransferServer:
-    """threading-based fallback with the same interface as the native server."""
+    """threading-based server with the same interface as the native one."""
 
     def __init__(self, host: str = "0.0.0.0", port: int = 0) -> None:
         self._blobs: Dict[str, bytes] = {}
@@ -325,25 +331,9 @@ def py_release(host: str, port: int, uuid: str, timeout_ms: int = 10000) -> bool
 
 
 # ---------------------------------------------------------------------------
-# Facade: native when available, Python otherwise.
+# Facade: the native plane (see module docstring — no Python fallback).
 # ---------------------------------------------------------------------------
 
-def make_server(host: str = "0.0.0.0", port: int = 0):
-    if _load_native() is not None:
-        try:
-            return NativeTransferServer(host, port)
-        except TransferError:
-            pass
-    return PyTransferServer(host, port)
-
-
-def fetch(host: str, port: int, uuid: str, timeout_ms: int = 30000) -> bytes:
-    if _load_native() is not None:
-        return native_fetch(host, port, uuid, timeout_ms)
-    return py_fetch(host, port, uuid, timeout_ms)
-
-
-def release(host: str, port: int, uuid: str, timeout_ms: int = 10000) -> bool:
-    if _load_native() is not None:
-        return native_release(host, port, uuid, timeout_ms)
-    return py_release(host, port, uuid, timeout_ms)
+make_server = NativeTransferServer
+fetch = native_fetch
+release = native_release

@@ -1,4 +1,4 @@
-"""Prometheus metrics with the llm-d metric taxonomy.
+"""Prometheus metrics with the llm-d metric naming scheme.
 
 The reference stack's observability contract is metrics-first: every model
 server exposes ``vllm:*`` metrics that the scheduler scrapes for load

@@ -189,7 +189,10 @@ def phase_attribution(spans: Iterable[Dict[str, Any]],
     traces = group_traces(spans)
     root_class: Dict[str, Optional[str]] = {}
     for tid, tspans in traces.items():
-        root = min(tspans, key=lambda s: s.get("ts") or float("inf"))
+        # Earliest span; a child recorded at its parent's own timestamp
+        # must not win the tie (file order is not stable).
+        root = min(tspans, key=lambda s: (s.get("ts") or float("inf"),
+                                          bool(s.get("parent"))))
         root_class[tid] = (root.get("attrs") or {}).get("criticality")
     buckets: Dict[str, Dict[str, List[float]]] = {}
     for tid, tspans in traces.items():

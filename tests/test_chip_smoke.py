@@ -1,0 +1,105 @@
+"""chip_smoke.py rehearsed on the CPU (on-chip-measurement guide §2.1) and
+the compile-cache helper every entry point shares.
+
+The phase functions are driven with ``tiny`` / ``tiny-mla`` at toy sizes:
+the XLA attention / dequantize paths serve (no Pallas, no interpret mode),
+so this finds wrong paths, arguments and control flow — nothing about the
+Mosaic-compiled kernels, which only the chip run can show.
+"""
+
+import importlib.util
+import json
+import pathlib
+
+import jax
+import pytest
+
+REPO = pathlib.Path(__file__).resolve().parent.parent
+
+
+@pytest.fixture(scope="module")
+def smoke():
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", REPO / "chip_smoke.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+TINY = dict(min_token_bucket=16, min_seq_bucket=4)
+
+
+def test_server_phase_tiny(smoke):
+    info = smoke.server_phase(
+        "tiny", ["--block-size", "8", "--num-blocks", "256",
+                 "--max-num-seqs", "8", "--max-num-batched-tokens", "64"],
+        prompt_lens=[150, 5, 12, 30, 7, 21], max_tokens=8, seed=0,
+        expect_kernels=False, cfg_overrides=TINY,
+        parity_lens=(9, 20), parity_ks=(0, 2, 5))
+    assert info["programs"] >= 2
+    assert info["custom_calls"] == {"decode": 0, "prefill": 0}
+    assert info["attn_parity"] <= smoke.ATTN_LOGPROB_TOL
+
+
+@pytest.mark.parametrize("latent", ["bf16", "int8"])
+def test_moe_phase_tiny_mla(smoke, latent):
+    info = smoke.moe_phase(
+        "tiny-mla", n_seqs=12, prompt_len=6, max_tokens=10, seed=0,
+        expect_kernels=False, latent_dtype=latent,
+        cfg_overrides=dict(TINY, block_size=8, num_blocks=128,
+                           max_num_seqs=16, max_num_batched_tokens=64,
+                           num_scheduler_steps=4),
+        small_wave=2, n_parity=2, parity_ks=(0, 2, 5), op_sizes=(4, 24))
+    assert info["moe_parity"] <= smoke.MOE_LOGPROB_TOL_MAX
+    assert info["moe_op_parity"] <= smoke.MOE_OP_REL_RMS_TOL
+
+
+def test_sharded_phase_tiny_on_virtual_devices(smoke, devices):
+    """The --chips 4 phase on four of the suite's virtual CPU devices."""
+    info = smoke.sharded_phase(
+        "tiny", ["--tensor-parallel-size", "2", "--block-size", "8",
+                 "--num-blocks", "128", "--max-num-seqs", "8",
+                 "--max-num-batched-tokens", "64", "--allow-device-subset"],
+        ["all-reduce"], seed=0, prompt_lens=(9, 20, 40),
+        parity_ks=(0, 2, 5), cfg_overrides=TINY)
+    assert "all-reduce" in info["collectives"]
+    info = smoke.sharded_phase(
+        "tiny-mla", ["--data-parallel-size", "4", "--block-size", "8",
+                     "--num-blocks", "128", "--max-num-seqs", "8",
+                     "--max-num-batched-tokens", "64",
+                     "--allow-device-subset"],
+        ["all-to-all"], seed=0, quantization="int8",
+        prompt_lens=(9, 20, 40, 12), parity_ks=(0, 2, 5),
+        cfg_overrides=TINY, op_sizes=(8, 64))
+    assert info["parity"] <= smoke.SHARDED_LOGPROB_TOL
+    assert info["moe_op_parity"] <= smoke.MOE_A2A_OP_REL_RMS_TOL
+
+
+def test_main_without_tpu_fails_with_ok_false(smoke, capsys):
+    assert jax.devices()[0].platform != "tpu"
+    rc = smoke.main([])
+    last = capsys.readouterr().out.strip().splitlines()[-1]
+    doc = json.loads(last)
+    assert rc != 0 and doc["ok"] is False
+    assert set(doc["device"]) == {"platform", "kind", "count"}
+    assert doc["device"]["platform"] == "cpu"
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_compile_cache_helper(monkeypatch, env_set):
+    from llm_d_tpu.utils import compile_cache as cc
+    updates = {}
+    monkeypatch.setattr(cc.jax.config, "update",
+                        lambda k, v: updates.__setitem__(k, v))
+    if env_set:
+        monkeypatch.setenv(cc.ENV_VAR, "/somewhere/else")
+        assert cc.configure_compile_cache("/flag/dir") == "/somewhere/else"
+        # JAX reads the variable itself: no directory is set in code.
+        assert "jax_compilation_cache_dir" not in updates
+    else:
+        monkeypatch.delenv(cc.ENV_VAR, raising=False)
+        want = str(REPO / ".jax_cache")
+        assert cc.configure_compile_cache() == want
+        assert updates["jax_compilation_cache_dir"] == want
+        # An operator's flag is honoured when the variable is unset.
+        assert cc.configure_compile_cache("/flag/dir") == "/flag/dir"

@@ -36,7 +36,6 @@ from llm_d_tpu.parallel.quant_collectives import (
     quantized_psum,
     resolve_collective_dtype,
 )
-from llm_d_tpu.utils.jax_compat import shard_map
 
 
 @pytest.fixture(scope="module")
@@ -227,7 +226,7 @@ def test_quantized_psum_single_tp_axis(mesh):
                     jax.lax.psum(xl, "tp"))
 
         from jax.sharding import PartitionSpec as P
-        got, want = shard_map(
+        got, want = jax.shard_map(
             body, mesh=mesh, in_specs=(P("tp"),), out_specs=(P(), P()),
             check_vma=False)(xs)
         assert _rel_rms(got, want, want) <= 2e-2

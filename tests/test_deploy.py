@@ -91,7 +91,7 @@ def test_dockerfile_tpu_sanity():
     text = open(path).read()
     assert re.search(r"^ENTRYPOINT", text, re.M)
     assert "jax[tpu]" in text
-    assert "libkvtransfer.so" in text          # native transport prebuilt
+    assert "libkvtransfer-$h.so" in text       # native transport prebuilt
     assert re.search(r"^USER 2000", text, re.M)  # non-root, reference style
     # Two-stage: runtime must not need a toolchain.
     runtime = text.split("# ---------- runtime ----------")[1]

@@ -38,12 +38,8 @@ def greedy_req(rid, prompt, n=8, **kw):
 
 @pytest.mark.parametrize("server_cls,fetch,release", [
     (transport.PyTransferServer, transport.py_fetch, transport.py_release),
-    pytest.param(
-        transport.NativeTransferServer, transport.native_fetch,
-        transport.native_release,
-        marks=pytest.mark.skipif(
-            transport._load_native() is None,
-            reason="native transport toolchain unavailable")),
+    (transport.NativeTransferServer, transport.native_fetch,
+     transport.native_release),
 ])
 def test_transport_roundtrip(server_cls, fetch, release):
     server = server_cls("127.0.0.1", 0)
@@ -68,8 +64,6 @@ def test_transport_roundtrip(server_cls, fetch, release):
 
 def test_native_and_python_interoperate():
     """Python client against native server and vice versa (same protocol)."""
-    if transport._load_native() is None:
-        pytest.skip("native transport toolchain unavailable")
     native = transport.NativeTransferServer("127.0.0.1", 0)
     try:
         native.register("x", b"abc" * 10)

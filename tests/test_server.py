@@ -1,4 +1,4 @@
-"""OpenAI server contract: probes, metrics taxonomy, completions, streaming.
+"""OpenAI server contract: probes, metric names, completions, streaming.
 
 Runs the real aiohttp app (tiny model on CPU) in a background thread and
 talks to it over real HTTP — the same surface Envoy/EPP would see.
@@ -69,7 +69,7 @@ def test_probes(server_url):
     assert requests.get(server_url + "/version").status_code == 200
 
 
-def test_metrics_taxonomy(server_url):
+def test_metric_names(server_url):
     text = requests.get(server_url + "/metrics").text
     for name in ["vllm:kv_cache_usage_perc", "vllm:num_requests_waiting",
                  "vllm:num_requests_running", "vllm:time_to_first_token_seconds",
