@@ -14,10 +14,14 @@ import asyncio
 import logging
 import queue
 import threading
+import time
 from typing import AsyncIterator, Dict, Optional
+
+from jax.profiler import TraceAnnotation
 
 from llm_d_tpu.engine.engine import EngineCore
 from llm_d_tpu.engine.request import Request, RequestOutput
+from llm_d_tpu.utils import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -32,6 +36,9 @@ class AsyncEngine:
         self._stop = False
         self._thread: Optional[threading.Thread] = None
         self.dead: Optional[BaseException] = None
+        # The engine component's ring (EngineCore's own; a DP group has
+        # one engine per rank and no tracer of its own).
+        self._tracer = tracing.get_tracer("engine")
 
     # ---------- lifecycle ----------
 
@@ -57,7 +64,11 @@ class AsyncEngine:
                     continue
                 outputs = self.engine.step()
                 if outputs and self._loop is not None:
-                    self._loop.call_soon_threadsafe(self._dispatch, outputs)
+                    # Stamped here, read in _dispatch: how long a step's
+                    # tokens wait for the event loop (engine.emit).
+                    self._loop.call_soon_threadsafe(
+                        self._dispatch, outputs, time.time(),
+                        self.engine.step_count)
                 if not self.engine.scheduler.has_work():
                     # Only connector work pending (KV pulls in flight /
                     # producer pins awaiting release): poll, don't spin.
@@ -94,13 +105,23 @@ class AsyncEngine:
             self._loop.call_soon_threadsafe(self._dispatch, [
                 RequestOutput(request_id, [], True, finish_reason="abort")])
 
-    def _dispatch(self, outputs) -> None:
-        for out in outputs:
-            q = self._streams.get(out.request_id)
-            if q is not None:
-                q.put_nowait(out)
-                if out.finished:
-                    self._streams.pop(out.request_id, None)
+    def _dispatch(self, outputs, handed_over: Optional[float] = None,
+                  step: Optional[int] = None) -> None:
+        """Put a step's outputs on their streams' queues.  With the engine
+        thread's hand-over stamp, records one ``engine.emit`` span: from
+        the hand-over to the outputs queued, the time a step's tokens
+        waited for this loop."""
+        with TraceAnnotation("llmd.emit"):
+            for out in outputs:
+                q = self._streams.get(out.request_id)
+                if q is not None:
+                    q.put_nowait(out)
+                    if out.finished:
+                        self._streams.pop(out.request_id, None)
+        if handed_over is not None:
+            self._tracer.record_span(
+                "engine.emit", handed_over, time.time(),
+                n_outputs=len(outputs), step=step)
 
     def _fail_all(self, exc: BaseException) -> None:
         for q in self._streams.values():

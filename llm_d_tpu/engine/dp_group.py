@@ -202,6 +202,11 @@ class DPEngineGroup:
     def has_work(self) -> bool:
         return any(e.has_work() for e in self.engines)
 
+    @property
+    def step_count(self) -> int:
+        """Engine steps run so far, over all ranks."""
+        return sum(e.step_count for e in self.engines)
+
     def step(self) -> List[RequestOutput]:
         outputs: List[RequestOutput] = []
         busy = [e for e in self.engines if e.has_work()]

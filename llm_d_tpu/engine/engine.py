@@ -29,6 +29,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from llm_d_tpu.engine.kv_cache import KVCacheManager
 from llm_d_tpu.engine.request import Request, RequestOutput, RequestState
 from llm_d_tpu.engine.scheduler import Scheduler, SchedulerOutput
+from llm_d_tpu.engine.step_clock import StepClock
 from llm_d_tpu.models import get_model
 from llm_d_tpu.models.config import ModelConfig, get_config
 from llm_d_tpu.ops import sampling as sampling_ops
@@ -350,6 +351,10 @@ class EngineCore:
         # never add a device sync to the hot loop (the JIT llmd-check
         # pass and the tests/test_tracing.py guard pin this).
         self.tracer = tracing.get_tracer("engine")
+        # Phase clock of the step loop (engine/step_clock.py), and what
+        # _note_step said of the iteration under way, for step() to write.
+        self._clock = StepClock()
+        self._step_note: Optional[Tuple[float, float, Any, Dict]] = None
         # EP interconnect accounting (round 10): on a multi-device mesh
         # every computed token's k routed copies cross the dispatch and
         # combine exchanges once per MoE layer — estimate the wire bytes
@@ -908,14 +913,14 @@ class EngineCore:
                 {k: (v if isinstance(v, jax.Array) else jnp.asarray(v))
                  for k, v in meta.items()},
                 self._replicated)
+        t0 = self._clock.mark("dispatch")
         self._rng, step_key = jax.random.split(self._rng)
         ids_ks, self.kv_cache, routed_ks = self._multistep_fn(
             self.params, self.kv_cache, mbatch, step_key)
         self._dispatch_count += 1
         self.metrics.engine_dispatches.inc()
         return dict(scheduled=list(scheduled), K=K, meta=meta, rows=rows,
-                    ids_dev=ids_ks, routed_dev=routed_ks,
-                    t0=time.monotonic())
+                    ids_dev=ids_ks, routed_dev=routed_ks, t0=t0)
 
     def _ms_retire(self, inflight: Dict[str, Any]) -> List[RequestOutput]:
         """Synchronize one in-flight block and advance request state."""
@@ -925,22 +930,17 @@ class EngineCore:
         # sync point: retire() exists to materialize this block's tokens,
         # and the successor block is already dispatched so the device
         # stays busy while the host syncs.
+        self._clock.mark("fetch")
         # llmd: ignore[JIT] the one intended multistep-retire host sync
         ids_ks = np.asarray(jax.device_get(inflight["ids_dev"]))
+        now = self._clock.mark("post")
         ids_ks = ids_ks.reshape(K, -1)
         self._step_count += K
         self.metrics.engine_steps.inc(K)
-        # Fused-decode step span (K engine steps in one device program),
-        # stamped from the dispatch/retire clock reads that already
-        # bracket the sync above — no new sync for tracing.
-        traced = next((sr.request for sr in scheduled
-                       if sr.request.trace_ctx is not None), None)
-        if traced is not None:
-            self.tracer.record_span(
-                "engine.step", self._mono_to_epoch(inflight["t0"]),
-                self._mono_to_epoch(time.monotonic()),
-                parent=traced.trace_ctx, step=self._step_count,
-                kind="decode", fused=K, n_seqs=len(scheduled))
+        # K engine steps in one device program, one span.
+        self._note_step(inflight["t0"], now,
+                        [sr.request for sr in scheduled], 0,
+                        K * len(scheduled), fused=True, rounds=K)
         if self.eplb is not None:
             # Fused decode is EXACTLY the traffic EPLB exists to balance;
             # only real sequences' rows count.  (A successor block already
@@ -956,7 +956,6 @@ class EngineCore:
                 routed_ms, self._step_count, self.params, self.mesh)
 
         outputs: List[RequestOutput] = []
-        now = time.monotonic()
         for s, sr in zip(rows, scheduled):
             req = sr.request
             if req.state is not RequestState.RUNNING:
@@ -1367,7 +1366,6 @@ class EngineCore:
         (slot-0 logprob arrays from the fused program's variant) without
         demoting any other row."""
         scheduled = sched.scheduled
-        step_t0 = time.monotonic()
         want_top = any((sr.request.sampling.logprobs or 0) > 0
                        for sr in scheduled)
         want_lp = any(sr.request.sampling.logprobs is not None
@@ -1379,6 +1377,7 @@ class EngineCore:
             self._fused_fns[(want_lp, want_top)] = fn
         batch, scheduled, rows, tok_offs, t_flat = \
             self._build_fused_batch(scheduled)
+        step_t0 = self._clock.mark("dispatch")
         self._rng, step_key = jax.random.split(self._rng)
         (ids_dev, acc_dev, drafts_dev, lp_dev, top_dev, routed_dev,
          self.kv_cache) = fn(
@@ -1391,8 +1390,10 @@ class EngineCore:
         fetch = [ids_dev, acc_dev, drafts_dev] \
             + ([lp_dev] if want_lp else []) \
             + (list(top_dev) if top_dev is not None else [])
+        self._clock.mark("fetch")
         # llmd: ignore[JIT] the one intended fused-step host sync (batched)
         fetched = jax.device_get(fetch)
+        now = self._clock.mark("post")
         ids = np.asarray(fetched[0])
         accepted = np.asarray(fetched[1])
         drafts = np.asarray(fetched[2])
@@ -1421,7 +1422,6 @@ class EngineCore:
                 self.params, self.mesh)
 
         outputs: List[RequestOutput] = []
-        now = time.monotonic()
         total_drafted = total_accepted = 0
         for i, sr in enumerate(scheduled):
             s = int(rows[i])
@@ -1596,22 +1596,10 @@ class EngineCore:
             self.metrics.step_decode_tokens.inc(decode_load)
         self.step_time_model.observe(
             sched.prefill_tokens, decode_load, (now - step_t0) * 1e3)
-        # Step-boundary span from the clock reads already bracketing the
-        # one batched fetch — drafted/accepted and prefill/decode token
-        # attribution ride the span, no extra sync.
-        traced = next((sr.request for sr in scheduled
-                       if sr.request.trace_ctx is not None), None)
-        if traced is not None:
-            kind = ("decode" if sched.prefill_tokens == 0
-                    else "prefill" if decode_load == 0 else "mixed")
-            self.tracer.record_span(
-                "engine.step", self._mono_to_epoch(step_t0),
-                self._mono_to_epoch(now), parent=traced.trace_ctx,
-                step=self._step_count, kind=kind, spec=True, fused=True,
-                n_seqs=len(scheduled),
-                prefill_tokens=sched.prefill_tokens,
-                decode_tokens=decode_load,
-                drafted=total_drafted, accepted=total_accepted)
+        self._note_step(step_t0, now, [sr.request for sr in scheduled],
+                        sched.prefill_tokens, decode_load, fused=True,
+                        spec=True, drafted=total_drafted,
+                        accepted=total_accepted)
         self._update_queue_metrics()
         return outputs
 
@@ -2044,6 +2032,7 @@ class EngineCore:
             xs = jax.device_put(plan["xs"], self._replicated)
             carry0 = (carry_dev if carry_dev is not None
                       else jax.device_put(plan["carry"], self._replicated))
+        t0 = self._clock.mark("dispatch")
         self._rng, step_key = jax.random.split(self._rng)
         ys, carry_out, self.kv_cache = fn(
             self.params, self.draft_params, self.kv_cache, carry0,
@@ -2051,8 +2040,7 @@ class EngineCore:
         self._dispatch_count += 1
         self.metrics.engine_dispatches.inc()
         return dict(kind="fms", plan=plan, ys=ys, carry=carry_out,
-                    want_lp=want_lp, want_top=want_top,
-                    t0=time.monotonic())
+                    want_lp=want_lp, want_top=want_top, t0=t0)
 
     def _fms_retire(self, rec: Dict[str, Any],
                     successor: Optional[Dict[str, Any]] = None
@@ -2074,8 +2062,10 @@ class EngineCore:
             fetch.append(ys["lp"])
         if rec["want_top"]:
             fetch += [ys["top_ids"], ys["top_lps"]]
+        self._clock.mark("fetch")
         # llmd: ignore[JIT] the one intended fused-multistep retire host sync
         fetched = jax.device_get(fetch)
+        now = self._clock.mark("post")
         ids = np.asarray(fetched[0])          # [N, S_flat, K+1]
         acc = np.asarray(fetched[1])          # [N, S_flat]
         drafts_f = np.asarray(fetched[2]).reshape(-1, K)
@@ -2087,7 +2077,6 @@ class EngineCore:
         self.metrics.engine_steps.inc(N)
 
         outputs: List[RequestOutput] = []
-        now = time.monotonic()
         total_drafted = total_accepted = 0
         pre_toks = dec_toks = 0
         valid = (np.zeros((N, plan["T_flat"]), bool)
@@ -2242,20 +2231,11 @@ class EngineCore:
         # budget, not the whole dispatch's wall time.
         self.step_time_model.observe(
             pre_toks / N, dec_toks / N, (now - rec["t0"]) * 1e3 / N)
-        traced = next(
-            (sp_["req"] for sp_ in plan["specs"]
-             if sp_["active"] and sp_["req"].trace_ctx is not None), None)
-        if traced is not None:
-            self.tracer.record_span(
-                "engine.step", self._mono_to_epoch(rec["t0"]),
-                self._mono_to_epoch(now), parent=traced.trace_ctx,
-                step=self._step_count,
-                kind=("decode" if pre_toks == 0
-                      else "prefill" if dec_toks == 0 else "mixed"),
-                spec=True, fused=N,
-                n_seqs=sum(1 for sp_ in plan["specs"] if sp_["active"]),
-                prefill_tokens=pre_toks, decode_tokens=dec_toks,
-                drafted=total_drafted, accepted=total_accepted)
+        self._note_step(
+            rec["t0"], now,
+            [sp_["req"] for sp_ in plan["specs"] if sp_["active"]],
+            pre_toks, dec_toks, fused=True, rounds=N, spec=True,
+            drafted=total_drafted, accepted=total_accepted)
         self._update_queue_metrics()
         return outputs
 
@@ -2375,6 +2355,12 @@ class EngineCore:
             # Consumer side: the request may only exist as an in-flight KV
             # pull; mark it so poll() drops instead of admitting it.
             self.kv_connector.abort(request_id)
+
+    @property
+    def step_count(self) -> int:
+        """Engine steps run so far: the ``step`` of the last ``engine.step``
+        span (``AsyncEngine`` stamps it on ``engine.emit``)."""
+        return self._step_count
 
     def has_work(self) -> bool:
         if self.scheduler.has_work() or self._rejected \
@@ -2528,13 +2514,54 @@ class EngineCore:
 
     # ---------- step ----------
 
+    def _note_step(self, t0: float, fetched: float, requests: List[Request],
+                   prefill_tokens: int, decode_tokens: int, *,
+                   fused: bool, rounds: int = 1, **spec_attrs) -> None:
+        """Describe the ``engine.step`` span of the iteration under way:
+        the one place all four step paths do, so they share one extent
+        (``t0``, the clock read before the RNG split, to tokens
+        ``fetched``) and one attribute set.  Only clock reads already
+        taken around the one batched fetch: no new sync.  ``rounds`` is
+        the engine steps the one dispatch ran."""
+        parent = next((r.trace_ctx for r in requests
+                       if r.trace_ctx is not None), None)
+        self._step_note = (t0, fetched, parent, dict(
+            step=self._step_count,
+            kind=("decode" if prefill_tokens == 0
+                  else "prefill" if decode_tokens == 0 else "mixed"),
+            n_seqs=len(requests), prefill_tokens=prefill_tokens,
+            decode_tokens=decode_tokens, fused=fused, rounds=rounds,
+            **spec_attrs))
+
     def step(self) -> List[RequestOutput]:
-        # Chaos fault point: simulated engine death (a raised fault
-        # propagates exactly like a real step crash — AsyncEngine marks
-        # the engine dead, fails all streams, /health turns 500).  No-op
-        # dict miss unless rules are installed.  Keyed by model name so a
-        # multi-engine chaos harness can kill one replica via match=.
-        get_injector().check("engine.step", key=str(self.config.model))
+        """One iteration of the engine loop.  The phase clock runs over
+        all of it; an iteration that fetched tokens writes its
+        ``engine.step`` span here, as it returns, with the phase times
+        (parented on the first traced request; none traced, no span)."""
+        self._clock.enter()
+        self._step_note = None
+        try:
+            # Chaos fault point: simulated engine death (a raised fault
+            # propagates exactly like a real step crash — AsyncEngine marks
+            # the engine dead, fails all streams, /health turns 500).  No-op
+            # dict miss unless rules are installed.  Keyed by model name so
+            # a multi-engine chaos harness can kill one replica via match=.
+            get_injector().check("engine.step", key=str(self.config.model))
+            return self._step()
+        finally:
+            self._clock.leave(self.scheduler.has_work())
+            if self._step_note is not None:
+                t0, fetched, parent, attrs = self._step_note
+                phases = self._clock.flush()
+                if parent is not None:
+                    # One conversion for both ends: the duration is exactly
+                    # the clock's fetched - t0 (= dispatch_ms + fetch_ms).
+                    start = self._mono_to_epoch(t0)
+                    self.tracer.record_span(
+                        "engine.step", start, start + fetched - t0,
+                        parent=parent, **attrs, **phases)
+
+    def _step(self) -> List[RequestOutput]:
         outputs: List[RequestOutput] = []
         if self._rejected:
             outputs.extend(self._rejected)
@@ -2577,6 +2604,7 @@ class EngineCore:
         if sched.empty:
             self._update_queue_metrics()
             return outputs
+        self._clock.mark("build")
 
         if self._spec_fn is not None:
             # Fused mixed round: whatever this pass scheduled — prefill
@@ -2610,7 +2638,7 @@ class EngineCore:
             return outputs
 
         batch, scheduled, rows = self._build_batch(sched)
-        step_t0 = time.monotonic()
+        step_t0 = self._clock.mark("dispatch")
         self._rng, step_key = jax.random.split(self._rng)
         # top_logprobs=0 means chosen-token logprob only (no alternatives).
         want_top = any((sr.request.sampling.logprobs or 0) > 0
@@ -2629,30 +2657,19 @@ class EngineCore:
                       for sr in sched.scheduled)
         fetch = [ids] + ([logprobs] if want_lp else []) \
             + (list(top) if top is not None else [])
+        self._clock.mark("fetch")
         # llmd: ignore[JIT] the one intended per-step host sync (batched)
         fetched = jax.device_get(fetch)
+        now = self._clock.mark("post")
         ids = np.asarray(fetched[0])
         logprobs = np.asarray(fetched[1]) if want_lp else None
         if top is not None:
             top = (np.asarray(fetched[-2]), np.asarray(fetched[-1]))
         self._step_count += 1
         self.metrics.engine_steps.inc()
-        # Step-boundary span: stamped AFTER the batched fetch (the one
-        # intended sync point above) from plain clock reads — tracing
-        # adds no sync of its own.  Parented on the first traced request
-        # in the batch; phase tells prefill-heavy from decode steps.
-        traced = next((sr.request for sr in scheduled
-                       if sr.request.trace_ctx is not None), None)
-        if traced is not None:
-            max_new = max(sr.num_new_tokens for sr in scheduled)
-            self.tracer.record_span(
-                "engine.step", self._mono_to_epoch(step_t0),
-                self._mono_to_epoch(time.monotonic()),
-                parent=traced.trace_ctx, step=self._step_count,
-                kind="decode" if max_new == 1 else "prefill",
-                n_seqs=len(scheduled), n_tokens=sched.total_tokens,
-                prefill_tokens=sched.prefill_tokens,
-                decode_tokens=sched.decode_tokens, fused=False)
+        self._note_step(step_t0, now, [sr.request for sr in scheduled],
+                        sched.prefill_tokens, sched.decode_tokens,
+                        fused=False)
         if self.eplb is not None:
             # Record routed logical ids (sampled; padding rows excluded so
             # the zero-embedding's favorite expert doesn't skew the stats)
@@ -2665,7 +2682,6 @@ class EngineCore:
             self.params = self.eplb.on_step(
                 routed, self._step_count, self.params, self.mesh)
 
-        now = time.monotonic()
         for i, sr in enumerate(scheduled):
             s = int(rows[i])
             req, n = sr.request, sr.num_new_tokens

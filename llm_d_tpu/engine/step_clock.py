@@ -1,0 +1,91 @@
+"""Phase clock of the engine loop: where one iteration's host time goes.
+
+``EngineCore.step`` reads the clock at its phase boundaries (plain
+``time.monotonic()`` reads, the tracing module's hot-path idiom) and the
+times ride the ``engine.step`` span as attributes, in ms:
+
+  between_ms   return of the previous ``step()`` -> entry of this one, when
+               the previous step left work for the scheduler (hand-over of
+               the outputs, inbox drain, ``has_work``, GIL waits)
+  starved_ms   the same gap when it left none: the loop slept for lack of
+               work
+  schedule_ms  entry -> ``scheduler.schedule()`` and the queue-wait
+               bookkeeping done
+  build_ms     -> the step's batch assembled and copied to the device
+  dispatch_ms  -> the step program enqueued (RNG split, key unpack, call)
+  fetch_ms     the one blocking ``jax.device_get``: waiting for the device
+  post_ms      fetch done -> ``step()`` returns
+  host_cpu_ms  ``time.thread_time()`` of the stepping thread over all of
+               the above; wall less this less ``fetch_ms`` is time the
+               thread held no CPU (GIL, preemption)
+
+Phases are contiguous, so they add up to the iteration.  An iteration that
+fetched nothing (an empty schedule, the first dispatch of a pipelined
+block) writes no span; its times stay in the accumulator and ride the next
+span, so consecutive spans still account for all of the loop's time.
+
+Each phase is also a ``jax.profiler.TraceAnnotation("llmd.<phase>")``: an
+atomic load when no profiler session is on, an event on the ``/host:CPU``
+plane beside the device plane when one is, which puts the loop's phases on
+the device trace's clock.  Host-side only: no device value is touched here.
+"""
+
+from __future__ import annotations
+
+import threading
+import time
+from typing import Dict, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
+
+
+class StepClock:
+    def __init__(self) -> None:
+        self._acc: Dict[str, float] = {}     # phase -> s since last flush
+        self._phase: Optional[str] = None
+        self._since = 0.0
+        self._note: Optional[TraceAnnotation] = None
+        self._left: Optional[Tuple[float, bool]] = None
+        self._cpu: Optional[Tuple[int, float]] = None   # (thread, CPU s)
+
+    def _switch(self, phase: Optional[str], now: float) -> None:
+        if self._phase is not None:
+            self._acc[self._phase] = (
+                self._acc.get(self._phase, 0.0) + now - self._since)
+            self._note.__exit__(None, None, None)
+        self._phase, self._since, self._note = phase, now, None
+        if phase is not None:
+            self._note = TraceAnnotation("llmd." + phase)
+            self._note.__enter__()
+
+    def enter(self) -> None:
+        """``step()`` entered: the gap since the last return is booked."""
+        now = time.monotonic()
+        if self._left is not None:
+            left_at, left_work = self._left
+            gap = "between" if left_work else "starved"
+            self._acc[gap] = self._acc.get(gap, 0.0) + now - left_at
+        self._switch("schedule", now)
+
+    def mark(self, phase: str) -> float:
+        """The running phase ends and ``phase`` begins; returns the read."""
+        now = time.monotonic()
+        self._switch(phase, now)
+        return now
+
+    def leave(self, left_work: bool) -> None:
+        """``step()`` returns; ``left_work``: the scheduler still has some."""
+        now = time.monotonic()
+        self._switch(None, now)
+        self._left = (now, left_work)
+
+    def flush(self) -> Dict[str, float]:
+        """``<phase>_ms`` accumulated since the last flush, and
+        ``host_cpu_ms`` where the same thread flushed last time."""
+        out = {f"{k}_ms": round(v * 1e3, 4) for k, v in self._acc.items()}
+        self._acc.clear()
+        thread, cpu = threading.get_ident(), time.thread_time()
+        if self._cpu is not None and self._cpu[0] == thread:
+            out["host_cpu_ms"] = round((cpu - self._cpu[1]) * 1e3, 4)
+        self._cpu = (thread, cpu)
+        return out

@@ -264,7 +264,7 @@ async def one_request(session, args, rng, stats, tenant: str = "",
                 "temperature": args.temperature}
     else:
         body, headers, criticality = make_body(args, rng, tenant)
-    if args.trace_out is not None:
+    if getattr(args, "trace_out", None) is not None:
         stats.setdefault("_trace", []).append({
             "at_s": round(time.monotonic() - stats["_t0"], 4),
             "tenant": tenant, "prompt": body.get("prompt"),
