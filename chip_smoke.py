@@ -182,18 +182,26 @@ class StepRecorder:
 
     def __call__(self, *args):
         import jax
+        from llm_d_tpu.engine.packed_batch import BatchLayout
 
         def sds(x):
-            # Uncommitted arrays (the rng key) follow the others.
+            # Uncommitted arrays follow the others.
             return jax.ShapeDtypeStruct(
                 x.shape, x.dtype,
                 sharding=x.sharding if x.committed else None)
-        batch = args[-2]
-        q = batch["qtok_idx"].shape[-1] if "qtok_idx" in batch else 1
-        rows = (batch.get("token_ids", batch.get("last_ids"))).shape
-        key = f"rows{tuple(rows)}_Q{q}"
+        if isinstance(args[-1], BatchLayout):
+            # The classic step: one packed buffer and, as a static
+            # argument, its layout (the buffer's length alone does not
+            # name the bucket).
+            layout = args[-1]
+            q, key = layout.Q, str(layout)
+            shapes = (*jax.tree.map(sds, args[:-1]), layout)
+        else:
+            q, rows = 1, args[-2]["last_ids"].shape    # multistep decode
+            key = f"rows{tuple(rows)}_Q{q}"
+            shapes = jax.tree.map(sds, args)
         if key not in self.served:
-            self.served[key] = (q, jax.tree.map(sds, args))
+            self.served[key] = (q, shapes)
         return self.fn(*args)
 
 

@@ -27,6 +27,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from llm_d_tpu.engine.kv_cache import KVCacheManager
+from llm_d_tpu.engine.packed_batch import BatchLayout
 from llm_d_tpu.engine.request import Request, RequestOutput, RequestState
 from llm_d_tpu.engine.scheduler import Scheduler, SchedulerOutput
 from llm_d_tpu.engine.step_clock import StepClock
@@ -485,7 +486,11 @@ class EngineCore:
         self._dp_sharded = NamedSharding(self.mesh, P("dp"))
 
         self.max_blocks_per_seq = -(-c.max_model_len // config.block_size)
-        self._rng = jax.random.PRNGKey(config.seed)
+        # Lives on the device: the classic step program splits it itself
+        # and hands the successor back (never fetched).  Committed here so
+        # the first step's program is the one every later step reuses.
+        self._rng = jax.device_put(jax.random.PRNGKey(config.seed),
+                                   self._replicated)
         self._step_count = 0
         # Device dispatches (one program launch + one host fetch each):
         # step_count / dispatch_count is the N-round amortization ratio
@@ -579,7 +584,7 @@ class EngineCore:
                             f"{config.spec_fixed_accept})"
                             if config.spec_fixed_accept is not None else "")
 
-        self._step_fn = self._build_step_fn()
+        self._step_fn = self._build_step_fn(packed=True)
         # Variant computing top-N logprobs, compiled on first use (steps
         # with no logprobs request never pay the extra top_k).
         self._step_fn_top = None
@@ -689,7 +694,14 @@ class EngineCore:
             opts["stub_components"] = tuple(self.config.stub_components)
         return opts
 
-    def _build_step_fn(self, want_top_logprobs: bool = False):
+    def _build_step_fn(self, want_top_logprobs: bool = False,
+                       packed: bool = False):
+        """The classic step program.  ``packed`` (what the engine serves):
+        ``step_fn(params, kv_cache, buffer, rng, layout)`` takes the batch
+        as the one int32 buffer of the static ``layout`` (packed_batch.py),
+        splits ``rng`` itself and returns the successor key as one more
+        output: one copy and one launch a step.  Otherwise the same body
+        over a dict batch and a ready step key, for tools that lower it."""
         c = self.model_config
         block_size = self.config.block_size
         backend = self.config.attn_backend
@@ -698,8 +710,7 @@ class EngineCore:
 
         collect_routed = self.eplb is not None
 
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        def step_fn(params, kv_cache, batch, rng):
+        def step_body(params, kv_cache, batch, rng):
             if collect_routed:
                 hidden, kv_cache, routed = model.forward(
                     params, kv_cache, batch, c, block_size, backend,
@@ -730,6 +741,25 @@ class EngineCore:
                 logprobs = sampling_ops.compute_logprobs(logits, ids)
                 top = None
             return ids, logprobs, kv_cache, routed, top
+
+        if not packed:
+            return jax.jit(step_body, donate_argnums=(1,))
+
+        # The key's split, lowered ONCE and called from every step program
+        # as a ready StableHLO module: traced into each of the 110-130
+        # bucket programs, ``jax.random.split`` cost a tenth of a second of
+        # Python lowering apiece on the chip's host (a sixth of set-up).
+        split = jax.export.export(
+            jax.jit(jax.random.split),
+            platforms=(mesh.devices.flat[0].platform,))(
+                jax.ShapeDtypeStruct((2,), jnp.uint32))
+
+        @functools.partial(jax.jit, static_argnums=(4,), donate_argnums=(1,))
+        def step_fn(params, kv_cache, buffer, rng, layout):
+            # Bit-identical to the host-side ``rng, key = split(rng)``.
+            rng, step_key = split.call(rng)
+            return (*step_body(params, kv_cache, layout.unpack(buffer),
+                               step_key), rng)
 
         return step_fn
 
@@ -2404,21 +2434,9 @@ class EngineCore:
     # ---------- batch building ----------
 
     def _empty_batch_np(self, T: int, S: int, Q: int, B: int) -> Dict[str, np.ndarray]:
-        return dict(
-            token_ids=np.zeros(T, np.int32),
-            positions=np.zeros(T, np.int32),
-            token_seq_ids=np.zeros(T, np.int32),
-            token_qpos=np.zeros(T, np.int32),
-            slot_mapping=np.zeros(T, np.int32),  # local block 0 = trash
-            block_tables=np.zeros((S, B), np.int32),
-            seq_lens=np.zeros(S, np.int32),
-            sample_idx=np.zeros(S, np.int32),
-            qtok_idx=np.full((S, Q), T, np.int32),  # T = padded-q sentinel
-            temperature=np.zeros(S, np.float32),
-            top_k=np.zeros(S, np.int32),
-            top_p=np.ones(S, np.float32),
-            seeds=np.full(S, -1, np.int32),
-            gen_idx=np.zeros(S, np.int32))
+        """An empty (all padded) batch: views of one fresh packed buffer."""
+        layout = BatchLayout(T, S, Q, B)
+        return layout.views(layout.new_buffer())
 
     def _fill_batch(self, arrs: Dict[str, np.ndarray], scheduled,
                     block_offset: int = 0) -> None:
@@ -2462,13 +2480,17 @@ class EngineCore:
         return per
 
     def _build_batch(self, out: SchedulerOutput
-                     ) -> Tuple[Dict[str, jax.Array], List, np.ndarray]:
-        """Returns (device batch, scheduled list, flat sample-row index per
-        scheduled entry).  Stacked mode groups requests by their KV shard
-        and pads every shard to common [T_l]/[S_l] buckets."""
+                     ) -> Tuple[jax.Array, BatchLayout, List, np.ndarray]:
+        """Returns (packed device batch, its layout, scheduled list, flat
+        sample-row index per scheduled entry).  Stacked mode groups requests
+        by their KV shard and pads every shard to common [T_l]/[S_l]
+        buckets, one row of the buffer a shard."""
         cfg = self.config
         B = self.max_blocks_per_seq
         max_q = max((sr.num_new_tokens for sr in out.scheduled), default=1)
+        # Per-seq query-slot bucket: 1 on pure-decode steps, else pow2.
+        Q = 1 if max_q == 1 else _next_bucket(
+            max_q, cfg.min_token_bucket, cfg.max_num_batched_tokens)
 
         if self.dp == 1:
             S_real = len(out.scheduled)
@@ -2476,41 +2498,39 @@ class EngineCore:
                              cfg.max_num_batched_tokens)
             S = _next_bucket(S_real, min(cfg.min_seq_bucket, cfg.max_num_seqs),
                              cfg.max_num_seqs)
-            # Per-seq query-slot bucket: 1 on pure-decode steps, else pow2.
-            Q = 1 if max_q == 1 else _next_bucket(
-                max_q, cfg.min_token_bucket, cfg.max_num_batched_tokens)
-            arrs = self._empty_batch_np(T, S, Q, B)
-            self._fill_batch(arrs, out.scheduled)
-            batch = jax.device_put(arrs, self._replicated)
-            return batch, out.scheduled, np.arange(S_real)
-
-        per = self._split_by_shard(out.scheduled)
-        T_l = _next_bucket(
-            max(sum(sr.num_new_tokens for sr in shard) for shard in per),
-            cfg.min_token_bucket, cfg.max_num_batched_tokens)
-        S_l = _next_bucket(
-            max(len(shard) for shard in per),
-            min(cfg.min_seq_bucket, cfg.max_num_seqs), cfg.max_num_seqs)
-        Q = 1 if max_q == 1 else _next_bucket(
-            max_q, cfg.min_token_bucket, cfg.max_num_batched_tokens)
-        B_l = self.kv_manager.blocks_per_region
-        shard_arrs = []
-        scheduled_flat: List = []
-        rows: List[int] = []
-        valid = np.zeros(self.dp * T_l, bool)
-        for r, shard in enumerate(per):
-            arrs = self._empty_batch_np(T_l, S_l, Q, B)
-            self._fill_batch(arrs, shard, block_offset=r * B_l)
-            shard_arrs.append(arrs)
-            scheduled_flat.extend(shard)
-            rows.extend(r * S_l + s for s in range(len(shard)))
-            n_real = sum(sr.num_new_tokens for sr in shard)
-            valid[r * T_l:r * T_l + n_real] = True
-        self._routed_valid = valid     # EPLB: mask pad rows per shard
-        stacked_np = {k: np.stack([a[k] for a in shard_arrs])
-                      for k in shard_arrs[0]}
-        batch = jax.device_put(stacked_np, self._dp_sharded)
-        return batch, scheduled_flat, np.asarray(rows, np.int32)
+            layout = BatchLayout(T, S, Q, B)
+            buf = layout.new_buffer()
+            self._fill_batch(layout.views(buf), out.scheduled)
+            scheduled, rows = out.scheduled, np.arange(S_real)
+        else:
+            per = self._split_by_shard(out.scheduled)
+            T_l = _next_bucket(
+                max(sum(sr.num_new_tokens for sr in shard) for shard in per),
+                cfg.min_token_bucket, cfg.max_num_batched_tokens)
+            S_l = _next_bucket(
+                max(len(shard) for shard in per),
+                min(cfg.min_seq_bucket, cfg.max_num_seqs), cfg.max_num_seqs)
+            B_l = self.kv_manager.blocks_per_region
+            layout = BatchLayout(T_l, S_l, Q, B, dp=self.dp)
+            buf = layout.new_buffer()
+            scheduled = []
+            rows = []
+            valid = np.zeros(self.dp * T_l, bool)
+            for r, shard in enumerate(per):
+                self._fill_batch(layout.views(buf[r]), shard,
+                                 block_offset=r * B_l)
+                scheduled.extend(shard)
+                rows.extend(r * S_l + s for s in range(len(shard)))
+                n_real = sum(sr.num_new_tokens for sr in shard)
+                valid[r * T_l:r * T_l + n_real] = True
+            self._routed_valid = valid     # EPLB: mask pad rows per shard
+            rows = np.asarray(rows, np.int32)
+        # ONE host-to-device copy a step, made here so that it is booked
+        # under ``build``, not hidden in the program call.
+        packed = jax.device_put(
+            buf, self._replicated if self.dp == 1 else self._dp_sharded)
+        self._clock.count("h2d_copies")
+        return packed, layout, scheduled, rows
 
     # ---------- step ----------
 
@@ -2637,17 +2657,19 @@ class EngineCore:
             outputs.extend(self._run_multistep(sched, K))
             return outputs
 
-        batch, scheduled, rows = self._build_batch(sched)
+        packed, layout, scheduled, rows = self._build_batch(sched)
         step_t0 = self._clock.mark("dispatch")
-        self._rng, step_key = jax.random.split(self._rng)
         # top_logprobs=0 means chosen-token logprob only (no alternatives).
         want_top = any((sr.request.sampling.logprobs or 0) > 0
                        for sr in sched.scheduled)
         if want_top and self._step_fn_top is None:
-            self._step_fn_top = self._build_step_fn(want_top_logprobs=True)
+            self._step_fn_top = self._build_step_fn(
+                want_top_logprobs=True, packed=True)
         fn = self._step_fn_top if want_top else self._step_fn
-        ids, logprobs, self.kv_cache, routed, top = fn(
-            self.params, self.kv_cache, batch, step_key)
+        # ONE launch: the program splits the key and returns its successor.
+        ids, logprobs, self.kv_cache, routed, top, self._rng = fn(
+            self.params, self.kv_cache, packed, self._rng, layout)
+        self._clock.count("launches")
         self._dispatch_count += 1
         self.metrics.engine_dispatches.inc()
         # ONE batched fetch: each device_get is a blocking PCIe transfer

@@ -1,0 +1,113 @@
+"""The classic step's batch as ONE int32 buffer: one host-to-device copy.
+
+The step program takes 14 small arrays (12 int32, 2 float32).  Handed to
+``jax.device_put`` as a dict they cost 14 copies of about 0.24 ms each on
+the chip's host, whatever their size.  ``BatchLayout`` lays them out back to
+back in one int32 buffer at offsets that are a pure function of the bucket
+``(T, S, Q, B)``: the engine fills named numpy views of the buffer (no
+second host copy), copies it once, and the step program takes it apart
+again with static slices and reshapes, which XLA folds away.  The two float
+arrays travel by bit pattern (``ndarray.view`` on the host,
+``lax.bitcast_convert_type`` in the program), so they arrive bit-exact.
+
+The buffer's length does not determine the bucket (T + ... can collide), so
+the layout itself reaches the program as a static, hashable argument.
+
+Stacked (SPMD dp) mode: ``dp > 1`` gives a ``[dp, size]`` buffer, one row a
+shard, sharded over the leading axis; every array comes out ``[dp, ...]``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+_I32, _F32 = np.dtype(np.int32), np.dtype(np.float32)
+
+
+def _fields(T: int, S: int, Q: int, B: int):
+    """(name, shape, dtype, value of a padded slot) of the step's batch."""
+    return (
+        ("token_ids", (T,), _I32, 0),
+        ("positions", (T,), _I32, 0),
+        ("token_seq_ids", (T,), _I32, 0),
+        ("token_qpos", (T,), _I32, 0),
+        ("slot_mapping", (T,), _I32, 0),      # local block 0 = trash
+        ("block_tables", (S, B), _I32, 0),
+        ("seq_lens", (S,), _I32, 0),
+        ("sample_idx", (S,), _I32, 0),
+        ("qtok_idx", (S, Q), _I32, T),        # T = padded-q sentinel
+        ("temperature", (S,), _F32, 0.0),
+        ("top_k", (S,), _I32, 0),
+        ("top_p", (S,), _F32, 1.0),
+        ("seeds", (S,), _I32, -1),
+        ("gen_idx", (S,), _I32, 0),
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _slots(T: int, S: int, Q: int, B: int):
+    """((name, start, stop, shape, dtype), ...), the buffer's length, and
+    ((start, stop, int32 bit pattern), ...) of the defaults that are not 0."""
+    slots, fills, at = [], [], 0
+    for name, shape, dtype, pad in _fields(T, S, Q, B):
+        stop = at + math.prod(shape)
+        slots.append((name, at, stop, shape, dtype))
+        bits = int(np.array(pad, dtype).view(np.int32))
+        if bits:
+            fills.append((at, stop, bits))
+        at = stop
+    return tuple(slots), at, tuple(fills)
+
+
+@dataclasses.dataclass(frozen=True)
+class BatchLayout:
+    """Bucket of one step program: ``T`` token rows, ``S`` sequence rows,
+    ``Q`` query slots a sequence, ``B`` block-table columns, per shard."""
+    T: int
+    S: int
+    Q: int
+    B: int
+    dp: int = 1
+
+    def _slots(self):
+        return _slots(self.T, self.S, self.Q, self.B)
+
+    @property
+    def shape(self) -> Tuple[int, ...]:
+        size = self._slots()[1]
+        return (size,) if self.dp == 1 else (self.dp, size)
+
+    def new_buffer(self) -> np.ndarray:
+        """A fresh host buffer holding an empty batch (every slot padded).
+        Fresh each step: the copy of the last one may still be in flight."""
+        buf = np.zeros(self.shape, np.int32)
+        for start, stop, bits in self._slots()[2]:
+            buf[..., start:stop] = bits
+        return buf
+
+    def views(self, row: np.ndarray) -> Dict[str, np.ndarray]:
+        """The batch's arrays as views of one shard's 1-D ``row`` of the
+        buffer: what ``_fill_batch`` writes lands in the buffer."""
+        return {name: row[start:stop].view(dtype).reshape(shape)
+                for name, start, stop, shape, dtype in self._slots()[0]}
+
+    def unpack(self, packed: jax.Array) -> Dict[str, jax.Array]:
+        """Inside the step program: the dict ``model.forward`` and
+        ``sampling_ops.sample`` take, from the one buffer."""
+        if packed.shape != self.shape or packed.dtype != jnp.int32:
+            raise ValueError(f"packed batch {packed.dtype}{packed.shape} "
+                             f"is not {self}'s int32{self.shape}")
+        out = {}
+        for name, start, stop, shape, dtype in self._slots()[0]:
+            x = jax.lax.slice_in_dim(packed, start, stop, axis=-1)
+            x = x.reshape(packed.shape[:-1] + shape)
+            out[name] = (x if dtype == _I32 else
+                         jax.lax.bitcast_convert_type(x, jnp.float32))
+        return out

@@ -19,6 +19,11 @@ times ride the ``engine.step`` span as attributes, in ms:
                the above; wall less this less ``fetch_ms`` is time the
                thread held no CPU (GIL, preemption)
 
+and, counted by the classic step path where it does them (``count``):
+
+  h2d_copies   arrays handed to ``jax.device_put`` during ``build``
+  launches     programs enqueued from the span's start to the fetch
+
 Phases are contiguous, so they add up to the iteration.  An iteration that
 fetched nothing (an empty schedule, the first dispatch of a pipelined
 block) writes no span; its times stay in the accumulator and ride the next
@@ -42,6 +47,7 @@ from jax.profiler import TraceAnnotation
 class StepClock:
     def __init__(self) -> None:
         self._acc: Dict[str, float] = {}     # phase -> s since last flush
+        self._counts: Dict[str, int] = {}    # event -> n since last flush
         self._phase: Optional[str] = None
         self._since = 0.0
         self._note: Optional[TraceAnnotation] = None
@@ -73,6 +79,10 @@ class StepClock:
         self._switch(phase, now)
         return now
 
+    def count(self, what: str, n: int = 1) -> None:
+        """``n`` more of ``what`` happened in this iteration."""
+        self._counts[what] = self._counts.get(what, 0) + n
+
     def leave(self, left_work: bool) -> None:
         """``step()`` returns; ``left_work``: the scheduler still has some."""
         now = time.monotonic()
@@ -80,10 +90,13 @@ class StepClock:
         self._left = (now, left_work)
 
     def flush(self) -> Dict[str, float]:
-        """``<phase>_ms`` accumulated since the last flush, and
-        ``host_cpu_ms`` where the same thread flushed last time."""
-        out = {f"{k}_ms": round(v * 1e3, 4) for k, v in self._acc.items()}
+        """``<phase>_ms`` and the counts accumulated since the last flush,
+        and ``host_cpu_ms`` where the same thread flushed last time."""
+        out: Dict[str, float] = {
+            f"{k}_ms": round(v * 1e3, 4) for k, v in self._acc.items()}
+        out.update(self._counts)
         self._acc.clear()
+        self._counts.clear()
         thread, cpu = threading.get_ident(), time.thread_time()
         if self._cpu is not None and self._cpu[0] == thread:
             out["host_cpu_ms"] = round((cpu - self._cpu[1]) * 1e3, 4)

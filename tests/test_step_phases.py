@@ -127,6 +127,17 @@ def test_every_path_writes_one_attribute_set_and_extent(path):
                s["attrs"]["rounds"] == 1 for s in steps)
     assert all(("accepted" in s["attrs"]) == ("fused" in path)
                for s in steps)
+    # The classic step (every step of the classic path, and the prefill
+    # steps of the multistep path) counts its copies and launches: one of
+    # each.
+    classic = [s["attrs"] for s in steps
+               if not s["attrs"]["fused"] and s["attrs"]["rounds"] == 1]
+    assert (len(classic) == len(steps)) == (path == "classic")
+    assert bool(classic) == ("fused" not in path)
+    assert all(a["h2d_copies"] == 1 and a["launches"] == 1 for a in classic)
+    assert all(("h2d_copies" in s["attrs"]) == (s["attrs"] in classic)
+               and ("launches" in s["attrs"]) == (s["attrs"] in classic)
+               for s in steps)
 
 
 @pytest.mark.parametrize("path", ["classic", "fused"])
