@@ -32,7 +32,7 @@ from llm_d_tpu.engine.request import Request, RequestOutput, RequestState
 from llm_d_tpu.engine.scheduler import Scheduler, SchedulerOutput
 from llm_d_tpu.engine.step_clock import StepClock
 from llm_d_tpu.models import get_model
-from llm_d_tpu.models.config import ModelConfig, get_config
+from llm_d_tpu.models.config import SLIDING, ModelConfig, get_config
 from llm_d_tpu.ops import sampling as sampling_ops
 from llm_d_tpu.parallel.mesh import MeshConfig, make_mesh
 from llm_d_tpu.parallel.sharding import logical_to_sharding, shard_pytree
@@ -207,6 +207,11 @@ class EngineConfig:
     # (do_remote_decode rows) stay counter-only either way.  None
     # resolves LLMD_SPEC_STRICT (default 0).
     spec_strict: Optional[bool] = None
+    # Compile (and run once, on an all-padding batch) every classic step
+    # program the bucket scheme can reach before serving the first
+    # request, instead of on first use: no request then waits for a
+    # compile, whatever prompt lengths arrive.
+    precompile_step_shapes: bool = False
 
     def resolve_model(self) -> ModelConfig:
         return self.model_config or get_config(self.model)
@@ -591,6 +596,57 @@ class EngineCore:
         self._multistep_fn = (
             self._build_multistep_fn(config.num_scheduler_steps)
             if config.num_scheduler_steps > 1 else None)
+        if config.precompile_step_shapes:
+            self.precompile_step_shapes()
+
+    def step_shapes(self) -> List[Tuple[int, int, int]]:
+        """Every (T, S, Q) bucket triple a classic step can have: T tokens
+        in all, S rows, Q = the longest row's tokens, each rounded up as
+        ``_build_batch`` rounds it.  A triple is reachable when some n rows
+        in S's range, the longest of q tokens in Q's range, hold a total
+        in T's range: q + (n - 1) <= total <= n q."""
+        cfg = self.config
+
+        def ranges(lo: int, hi: int) -> List[Tuple[int, int]]:
+            """(smallest count, bucket) of each bucket from ``lo`` up."""
+            out, b, prev = [], lo, 0
+            while b < hi:
+                out.append((prev + 1, b))
+                prev, b = b, b * 2
+            return out + [(prev + 1, hi)]
+
+        tokens = ranges(cfg.min_token_bucket, cfg.max_num_batched_tokens)
+        shapes = []
+        for n_lo, S in ranges(min(cfg.min_seq_bucket, cfg.max_num_seqs),
+                              cfg.max_num_seqs):
+            for t_lo, T in tokens:
+                if max(t_lo, n_lo) <= min(T, S):     # decode: total = rows
+                    shapes.append((T, S, 1))
+                for q_lo, Q in tokens:
+                    if Q <= T and max(t_lo, max(q_lo, 2) + n_lo - 1) \
+                            <= min(T, S * Q):
+                        shapes.append((T, S, Q))
+        return shapes
+
+    def precompile_step_shapes(self) -> int:
+        """Run the classic step program of every reachable bucket triple on
+        an all-padding batch (every write lands in the trash block, the
+        key is not advanced).  The other step paths (speculative, fused
+        multi-step) still compile on first use."""
+        shapes = self.step_shapes()
+        t0 = time.monotonic()
+        for T, S, Q in shapes:
+            layout = BatchLayout(T, S, Q, self.max_blocks_per_seq,
+                                 dp=self.dp)
+            packed = jax.device_put(
+                layout.new_buffer(),
+                self._replicated if self.dp == 1 else self._dp_sharded)
+            self.kv_cache = self._step_fn(
+                self.params, self.kv_cache, packed, self._rng, layout)[2]
+        jax.block_until_ready(self.kv_cache)
+        logger.info("precompiled %d step programs in %.1fs", len(shapes),
+                    time.monotonic() - t0)
+        return len(shapes)
 
     # ---------- feature-composition accounting ----------
 
@@ -970,7 +1026,10 @@ class EngineCore:
         # K engine steps in one device program, one span.
         self._note_step(inflight["t0"], now,
                         [sr.request for sr in scheduled], 0,
-                        K * len(scheduled), fused=True, rounds=K)
+                        K * len(scheduled), fused=True, rounds=K,
+                        kv=self._kv_counts(
+                            [sr.request.num_computed_tokens + K
+                             for sr in scheduled], K))
         if self.eplb is not None:
             # Fused decode is EXACTLY the traffic EPLB exists to balance;
             # only real sequences' rows count.  (A successor block already
@@ -1626,10 +1685,16 @@ class EngineCore:
             self.metrics.step_decode_tokens.inc(decode_load)
         self.step_time_model.observe(
             sched.prefill_tokens, decode_load, (now - step_t0) * 1e3)
+        # (the tokens a row was scheduled, drafts included, ending at the
+        # context it kept)
         self._note_step(step_t0, now, [sr.request for sr in scheduled],
                         sched.prefill_tokens, decode_load, fused=True,
                         spec=True, drafted=total_drafted,
-                        accepted=total_accepted)
+                        accepted=total_accepted,
+                        kv=self._kv_counts(
+                            [sr.request.num_computed_tokens
+                             for sr in scheduled],
+                            [sr.num_new_tokens for sr in scheduled]))
         self._update_queue_metrics()
         return outputs
 
@@ -2261,11 +2326,15 @@ class EngineCore:
         # budget, not the whole dispatch's wall time.
         self.step_time_model.observe(
             pre_toks / N, dec_toks / N, (now - rec["t0"]) * 1e3 / N)
+        live = [sp_ for sp_ in plan["specs"] if sp_["active"]]
         self._note_step(
-            rec["t0"], now,
-            [sp_["req"] for sp_ in plan["specs"] if sp_["active"]],
+            rec["t0"], now, [sp_["req"] for sp_ in live],
             pre_toks, dec_toks, fused=True, rounds=N, spec=True,
-            drafted=total_drafted, accepted=total_accepted)
+            drafted=total_drafted, accepted=total_accepted,
+            kv=self._kv_counts(
+                [sp_["req"].num_computed_tokens for sp_ in live],
+                [sum(v + (kind == "dec") for kind, v in sp_["rounds"])
+                 for sp_ in live]))
         self._update_queue_metrics()
         return outputs
 
@@ -2500,8 +2569,12 @@ class EngineCore:
                              cfg.max_num_seqs)
             layout = BatchLayout(T, S, Q, B)
             buf = layout.new_buffer()
-            self._fill_batch(layout.views(buf), out.scheduled)
+            views = layout.views(buf)
+            self._fill_batch(views, out.scheduled)
             scheduled, rows = out.scheduled, np.arange(S_real)
+            # Per row: context at the end of the step, tokens of the step.
+            ends = views["seq_lens"][:S_real]
+            news = np.diff(views["sample_idx"][:S_real] + 1, prepend=0)
         else:
             per = self._split_by_shard(out.scheduled)
             T_l = _next_bucket(
@@ -2525,18 +2598,49 @@ class EngineCore:
                 valid[r * T_l:r * T_l + n_real] = True
             self._routed_valid = valid     # EPLB: mask pad rows per shard
             rows = np.asarray(rows, np.int32)
+            news = [sr.num_new_tokens for sr in scheduled]
+            ends = [sr.request.num_computed_tokens + sr.num_new_tokens
+                    for sr in scheduled]
         # ONE host-to-device copy a step, made here so that it is booked
         # under ``build``, not hidden in the program call.
         packed = jax.device_put(
             buf, self._replicated if self.dp == 1 else self._dp_sharded)
         self._clock.count("h2d_copies")
+        self._step_kv = self._kv_counts(ends, news)
         return packed, layout, scheduled, rows
 
     # ---------- step ----------
 
+    def _kv_counts(self, ends, news) -> Dict[str, int]:
+        """What a dispatch asks of the KV cache (step_clock.py): ``news[r]``
+        tokens of row r computed, one after the other or as one causal
+        chunk (the same keys either way), ending at context ``ends[r]``."""
+        c = self.model_config
+        ends = np.asarray(ends, np.int64)
+        news = np.minimum(np.asarray(news, np.int64), ends)
+        # A full layer: queries at positions L - n .. L - 1 read p + 1 keys.
+        full = int((news * (2 * ends - news + 1)).sum()) // 2
+        counts = {"kv_ctx_tokens": c.num_layers * full,
+                  "kv_read_tokens": c.num_layers * full,
+                  "kv_held_tokens": c.num_layers * int(ends.sum()),
+                  "kv_dead_tokens": 0}
+        n_window = c.layer_types.count(SLIDING)
+        if n_window:
+            w = c.sliding_window
+
+            def upto(x):      # sum of min(p + 1, w) over positions p < x
+                y = np.minimum(x, w)
+                return y * (y + 1) // 2 + (x - y) * w
+            windowed = int((upto(ends) - upto(ends - news)).sum())
+            counts["kv_read_tokens"] -= n_window * (full - windowed)
+            counts["kv_dead_tokens"] = n_window * int(
+                np.maximum(ends - w + 1, 0).sum())
+        return counts
+
     def _note_step(self, t0: float, fetched: float, requests: List[Request],
                    prefill_tokens: int, decode_tokens: int, *,
-                   fused: bool, rounds: int = 1, **spec_attrs) -> None:
+                   fused: bool, rounds: int = 1, kv: Dict[str, int],
+                   **spec_attrs) -> None:
         """Describe the ``engine.step`` span of the iteration under way:
         the one place all four step paths do, so they share one extent
         (``t0``, the clock read before the RNG split, to tokens
@@ -2551,7 +2655,7 @@ class EngineCore:
                   else "prefill" if decode_tokens == 0 else "mixed"),
             n_seqs=len(requests), prefill_tokens=prefill_tokens,
             decode_tokens=decode_tokens, fused=fused, rounds=rounds,
-            **spec_attrs))
+            **kv, **spec_attrs))
 
     def step(self) -> List[RequestOutput]:
         """One iteration of the engine loop.  The phase clock runs over
@@ -2658,7 +2762,8 @@ class EngineCore:
             return outputs
 
         packed, layout, scheduled, rows = self._build_batch(sched)
-        step_t0 = self._clock.mark("dispatch")
+        step_t0 = self._clock.mark(
+            "dispatch", prefill_tokens=sched.prefill_tokens, **self._step_kv)
         # top_logprobs=0 means chosen-token logprob only (no alternatives).
         want_top = any((sr.request.sampling.logprobs or 0) > 0
                        for sr in sched.scheduled)
@@ -2691,7 +2796,7 @@ class EngineCore:
         self.metrics.engine_steps.inc()
         self._note_step(step_t0, now, [sr.request for sr in scheduled],
                         sched.prefill_tokens, sched.decode_tokens,
-                        fused=False)
+                        fused=False, kv=self._step_kv)
         if self.eplb is not None:
             # Record routed logical ids (sampled; padding rows excluded so
             # the zero-embedding's favorite expert doesn't skew the stats)

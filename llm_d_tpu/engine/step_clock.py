@@ -24,6 +24,18 @@ and, counted by the classic step path where it does them (``count``):
   h2d_copies   arrays handed to ``jax.device_put`` during ``build``
   launches     programs enqueued from the span's start to the fetch
 
+and, described by ``EngineCore._kv_counts`` (all four step paths; the
+classic path also hands them to its ``llmd.dispatch`` annotation):
+
+  kv_ctx_tokens   sum over the step's rows and attention layers of the keys
+                  a full-attention layer reads (a chunk of n queries ending
+                  at context L reads n L - n (n - 1) / 2)
+  kv_read_tokens  the same with each layer's window applied (equal to
+                  ``kv_ctx_tokens`` for a model without a window)
+  kv_held_tokens  sum over rows and layers of the tokens the cache holds
+  kv_dead_tokens  sum over rows and window layers of those no later query
+                  of the row can see: what a pool per layer kind would free
+
 Phases are contiguous, so they add up to the iteration.  An iteration that
 fetched nothing (an empty schedule, the first dispatch of a pipelined
 block) writes no span; its times stay in the accumulator and ride the next
@@ -54,14 +66,14 @@ class StepClock:
         self._left: Optional[Tuple[float, bool]] = None
         self._cpu: Optional[Tuple[int, float]] = None   # (thread, CPU s)
 
-    def _switch(self, phase: Optional[str], now: float) -> None:
+    def _switch(self, phase: Optional[str], now: float, **args) -> None:
         if self._phase is not None:
             self._acc[self._phase] = (
                 self._acc.get(self._phase, 0.0) + now - self._since)
             self._note.__exit__(None, None, None)
         self._phase, self._since, self._note = phase, now, None
         if phase is not None:
-            self._note = TraceAnnotation("llmd." + phase)
+            self._note = TraceAnnotation("llmd." + phase, **args)
             self._note.__enter__()
 
     def enter(self) -> None:
@@ -73,10 +85,13 @@ class StepClock:
             self._acc[gap] = self._acc.get(gap, 0.0) + now - left_at
         self._switch("schedule", now)
 
-    def mark(self, phase: str) -> float:
-        """The running phase ends and ``phase`` begins; returns the read."""
+    def mark(self, phase: str, **args) -> float:
+        """The running phase ends and ``phase`` begins; returns the read.
+        ``args`` ride the phase's annotation (read only while a profiler
+        session is on): what the step under way asks of the device, on
+        the clock of the kernels that do it."""
         now = time.monotonic()
-        self._switch(phase, now)
+        self._switch(phase, now, **args)
         return now
 
     def count(self, what: str, n: int = 1) -> None:

@@ -11,9 +11,14 @@ models the reference's well-lit paths deploy: Qwen3-0.6B
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
+
+SLIDING, FULL = "sliding_attention", "full_attention"
+# The window of a full-attention layer: one that never binds (above any
+# position, with room to add a position without overflowing int32).
+NO_WINDOW = 1 << 30
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,6 +60,18 @@ class ModelConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # --- mixed attention stacks (GQA path; empty = every layer full) ---
+    # Kind of each layer: SLIDING layers see the last ``sliding_window``
+    # keys only, FULL layers the whole context.
+    layer_types: Tuple[str, ...] = ()
+    sliding_window: int = 0
+    # False: FULL layers carry no rotary embedding (SLIDING ones always do).
+    rope_on_full_attention: bool = True
+    # sigmoid(h W_gate) multiplies the attention output before o_proj.
+    attn_output_gate: bool = False
+    # RMS norms on the attention and MLP outputs too, before the residual.
+    sandwich_norm: bool = False
+    embed_scale: float = 1.0                # multiplies the token embedding
 
     @property
     def use_mla(self) -> bool:
@@ -65,6 +82,32 @@ class ModelConfig:
             raise ValueError(
                 f"scoring_func must be 'softmax' or 'sigmoid', "
                 f"got {self.scoring_func!r}")
+        # A JSON list arrives here: a tuple keeps the config hashable.
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        kinds = self.layer_types
+        if kinds:
+            if len(kinds) != self.num_layers or set(kinds) - {SLIDING, FULL}:
+                raise ValueError(
+                    f"layer_types must name {self.num_layers} layers as "
+                    f"{SLIDING!r} or {FULL!r}, got {len(kinds)}: {kinds}")
+            if SLIDING in kinds and self.sliding_window < 1:
+                raise ValueError("sliding layers need sliding_window >= 1")
+        if self.use_mla and (kinds or self.attn_output_gate
+                             or self.sandwich_norm):
+            raise ValueError(
+                "layer_types, attn_output_gate and sandwich_norm belong to "
+                "the GQA attention block; the MLA path has none of them")
+
+    @property
+    def layer_windows(self) -> Tuple[int, ...]:
+        """Per layer, how many keys a query sees (itself included)."""
+        return tuple(self.sliding_window if t == SLIDING else NO_WINDOW
+                     for t in self.layer_types)
+
+    @property
+    def layer_rope(self) -> Tuple[bool, ...]:
+        return tuple(t == SLIDING or self.rope_on_full_attention
+                     for t in self.layer_types)
 
     @property
     def head_dim_(self) -> int:
@@ -160,6 +203,20 @@ PRESETS = {
         routed_scaling_factor=2.5, scoring_func="sigmoid",
         q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=128,
         qk_rope_head_dim=64, v_head_dim=128),
+    # Tiny mixed-stack MoE for CPU tests: sliding and full layers 3:1, a
+    # window that is no multiple of the block size, full layers without a
+    # rotary embedding, gated attention, four norms a layer, scaled
+    # embedding, sigmoid routing with a shared expert, two dense layers.
+    "tiny-swa-moe": ModelConfig(
+        name="tiny-swa-moe", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_layers=8, num_heads=4, num_kv_heads=2,
+        rope_theta=10000.0, max_model_len=512, qk_norm=True,
+        num_experts=8, num_experts_per_tok=2, moe_intermediate_size=96,
+        num_shared_experts=1, first_dense_layers=2, scoring_func="sigmoid",
+        routed_scaling_factor=2.0,
+        layer_types=(SLIDING, SLIDING, SLIDING, FULL) * 2, sliding_window=48,
+        rope_on_full_attention=False, attn_output_gate=True,
+        sandwich_norm=True, embed_scale=8.0),
     # Tiny MLA+MoE config for CPU tests.
     "tiny-mla": ModelConfig(
         name="tiny-mla", vocab_size=512, hidden_size=64,

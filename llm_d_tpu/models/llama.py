@@ -65,6 +65,12 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
     if c.qk_norm:
         params["layers"]["q_norm"] = jnp.ones((Lc, dh), dt)
         params["layers"]["k_norm"] = jnp.ones((Lc, dh), dt)
+    if c.attn_output_gate:
+        params["layers"]["attn_gate"] = stacked(
+            (c.hidden_size, c.num_heads * dh), next(k))
+    if c.sandwich_norm:
+        params["layers"]["attn_out_norm"] = jnp.ones((Lc, c.hidden_size), dt)
+        params["layers"]["mlp_out_norm"] = jnp.ones((Lc, c.hidden_size), dt)
     if not c.tie_word_embeddings:
         params["lm_head"] = w((c.hidden_size, c.vocab_size), next(k))
     return params
@@ -80,7 +86,11 @@ def attention_block(
     ``caches`` is (k, v) for the bf16 cache or (k, v, k_scale, v_scale)
     when ``kv_cache_dtype=int8`` (int8 payloads + f32 per-row scale planes).
     With ``layer`` the caches are the full stacked [L, slots, F] buffers
-    updated in place (see ops.attention.attention_with_kv_update)."""
+    updated in place (see ops.attention.attention_with_kv_update).
+
+    A mixed stack (``config.layer_types``) runs every layer through this
+    one traced body: the window and the rotary rule of layer ``layer`` are
+    looked up in per-layer tables by the traced index."""
     c = config
     dh = c.head_dim_
     T = x.shape[0]
@@ -93,16 +103,50 @@ def attention_block(
         kx = L.rms_norm(kx, lp["k_norm"], c.rms_norm_eps)
 
     cos, sin = L.rope_cos_sin(batch["positions"], dh, c.rope_theta)
-    q = L.apply_rope(q, cos, sin)
-    kx = L.apply_rope(kx, cos, sin)
+    window = None
+    if c.layer_types:
+        window = jnp.asarray(c.layer_windows, jnp.int32)[layer]
+    if all(c.layer_rope):
+        q = L.apply_rope(q, cos, sin)
+        kx = L.apply_rope(kx, cos, sin)
+    else:
+        rope = jnp.asarray(c.layer_rope)[layer]
+        q = jnp.where(rope, L.apply_rope(q, cos, sin), q)
+        kx = jnp.where(rope, L.apply_rope(kx, cos, sin), kx)
 
     k_scale, v_scale = caches[2:] if len(caches) == 4 else (None, None)
     attn, *new_caches = attention_with_kv_update(
         q, kx, vx, caches[0], caches[1], batch,
         block_size=block_size, backend=attn_backend, layer=layer,
-        k_scale=k_scale, v_scale=v_scale, mesh=mesh)
-    out = L.linear(attn.reshape(T, c.num_heads * dh), lp["o_proj"])
+        k_scale=k_scale, v_scale=v_scale, mesh=mesh, window=window)
+    attn = attn.reshape(T, c.num_heads * dh)
+    if "attn_gate" in lp:
+        attn = attn * jax.nn.sigmoid(L.linear(x, lp["attn_gate"]))
+    out = L.linear(attn, lp["o_proj"])
+    if "attn_out_norm" in lp:
+        out = L.rms_norm(out, lp["attn_out_norm"], c.rms_norm_eps)
     return out, tuple(new_caches)
+
+
+def embed_tokens(params: Params, token_ids: jax.Array,
+                 config: ModelConfig) -> jax.Array:
+    x = params["embed"][token_ids]
+    if config.embed_scale != 1.0:
+        x = (x * config.embed_scale).astype(x.dtype)
+    return x
+
+
+def mlp_out(lp: Params, config: ModelConfig, m: jax.Array) -> jax.Array:
+    """What the MLP (dense or experts) adds to the residual stream."""
+    if "mlp_out_norm" in lp:
+        return L.rms_norm(m, lp["mlp_out_norm"], config.rms_norm_eps)
+    return m
+
+
+def dense_mlp(lp: Params, config: ModelConfig, h: jax.Array) -> jax.Array:
+    return mlp_out(lp, config, L.swiglu_mlp(
+        L.rms_norm(h, lp["post_attn_norm"], config.rms_norm_eps),
+        lp["gate_proj"], lp["up_proj"], lp["down_proj"]))
 
 
 def forward(
@@ -127,7 +171,7 @@ def forward(
     """
     c = config
     stacked = batch["token_ids"].ndim == 2
-    x = params["embed"][batch["token_ids"]]          # [T, D] / [dp, T_l, D]
+    x = embed_tokens(params, batch["token_ids"], c)  # [T, D] / [dp, T_l, D]
 
     # int8 KV: the f32 scale planes ride the scan carry right next to their
     # payload buffers (name order fixed so the returned dict matches the
@@ -154,10 +198,7 @@ def forward(
         else:
             a, caches = attend(lp, hn, caches, batch, li)
         h = h + a
-        m = L.swiglu_mlp(
-            L.rms_norm(h, lp["post_attn_norm"], c.rms_norm_eps),
-            lp["gate_proj"], lp["up_proj"], lp["down_proj"])
-        h = h + m
+        h = h + dense_mlp(lp, c, h)
         return (h, caches, li + 1), None
 
     (x, caches, _), _ = jax.lax.scan(
@@ -252,7 +293,7 @@ def sharding_rules(config: ModelConfig):
     """
     return [
         (r"embed", P(None, "tp")),
-        (r"layers/(q|k|v)_proj", P(None, None, "tp")),
+        (r"layers/((q|k|v)_proj|attn_gate)", P(None, None, "tp")),
         (r"layers/(q|k|v)_bias", P(None, "tp")),
         (r"layers/(gate|up)_proj", P(None, None, "tp")),
         (r"layers/o_proj", P(None, "tp", None)),

@@ -24,7 +24,8 @@ from llm_d_tpu.models.llama import (  # noqa: F401  (re-exports: the MoE
     # model shares the dense family's logits head and MTP drafter — the
     # drafter reads only embed/lm_head from the target params, which both
     # families carry identically)
-    attention_block, compute_logits, draft_propose, init_draft_params)
+    attention_block, compute_logits, dense_mlp, draft_propose, embed_tokens,
+    init_draft_params, mlp_out)
 from llm_d_tpu.ops import layers as L
 from llm_d_tpu.ops import moe as moe_ops
 from llm_d_tpu.parallel.mesh import AXIS_EP
@@ -55,6 +56,11 @@ def _attn_params(c: ModelConfig, n: int, key, dt) -> Params:
     if c.qk_norm:
         p["q_norm"] = jnp.ones((n, dh), dt)
         p["k_norm"] = jnp.ones((n, dh), dt)
+    if c.attn_output_gate:
+        p["attn_gate"] = stacked((c.hidden_size, c.num_heads * dh), next(k))
+    if c.sandwich_norm:
+        p["attn_out_norm"] = jnp.ones((n, c.hidden_size), dt)
+        p["mlp_out_norm"] = jnp.ones((n, c.hidden_size), dt)
     return p
 
 
@@ -129,7 +135,7 @@ def forward(
     c = config
     Ld = c.first_dense_layers
     stacked = batch["token_ids"].ndim == 2
-    x = params["embed"][batch["token_ids"]]   # [T, D] / [dp, T_l, D]
+    x = embed_tokens(params, batch["token_ids"], c)   # [T, D] / [dp, T_l, D]
     # int8 KV: scale planes ride the scan carry with the payloads — for
     # dense models per K/V buffer, for MLA one ``kv_scale`` plane next to
     # the int8 latent rows (kv_cache_dtype=int8 covers both families).
@@ -189,10 +195,7 @@ def forward(
         a, caches = attend(
             lp, L.rms_norm(h, lp["input_norm"], c.rms_norm_eps), caches, li)
         h = h + a
-        m = L.swiglu_mlp(
-            L.rms_norm(h, lp["post_attn_norm"], c.rms_norm_eps),
-            lp["gate_proj"], lp["up_proj"], lp["down_proj"])
-        return (h + m, caches, li + 1), None
+        return (h + dense_mlp(lp, c, h), caches, li + 1), None
 
     def moe_body(carry, lp):
         h, caches, li = carry
@@ -240,6 +243,7 @@ def forward(
         if "shared_gate" in lp and "shared_expert" not in stub:
             m = m + L.swiglu_mlp(hn, lp["shared_gate"], lp["shared_up"],
                                  lp["shared_down"])
+        m = mlp_out(lp, c, m)
         if collect_moe_trace:
             # The EXACT operands the EP dispatch ships: the rms-normed
             # hidden rows plus the routing the combine applies — what the
@@ -286,7 +290,7 @@ def sharding_rules(config: ModelConfig):
     ("TPxDP in attention, EP in MoE layers"; reference decode.yaml:76,87)."""
     rules = [
         (r"embed", P(None, "tp")),
-        (r"layers/(q|k|v)_proj", P(None, None, "tp")),
+        (r"layers/((q|k|v)_proj|attn_gate)", P(None, None, "tp")),
         (r"layers/(q|k|v)_bias", P(None, "tp")),
         (r"layers/o_proj", P(None, "tp", None)),
         (r"dense_layers/(gate|up)_proj", P(None, None, "tp")),

@@ -69,6 +69,7 @@ def ragged_paged_attention_reference(
     layer: Optional[jax.Array] = None,
     k_scale: Optional[jax.Array] = None,   # int8 caches: f32 scale planes
     v_scale: Optional[jax.Array] = None,
+    window: Optional[jax.Array] = None,    # i32: keys a query sees (None=all)
 ) -> jax.Array:               # [T, H, D]
     T, H, D = q.shape
     S, B = block_tables.shape
@@ -100,6 +101,8 @@ def ragged_paged_attention_reference(
     key_pos = jnp.arange(C)[None, :]                       # [1, C]
     valid = (key_pos <= positions[:, None]) & (
         key_pos < seq_lens[token_seq_ids][:, None])        # [T, C]
+    if window is not None:
+        valid &= key_pos > positions[:, None] - window
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
 
     probs = jax.nn.softmax(scores, axis=-1)
@@ -162,6 +165,7 @@ def _flash_over_kv_chunks(
     layer: Optional[jax.Array] = None,
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
+    window: Optional[jax.Array] = None,
 ) -> jax.Array:           # [S, Q, H, D]
     """Online-softmax attention scanning the context in kv_chunk slices.
 
@@ -191,6 +195,8 @@ def _flash_over_kv_chunks(
         key_pos = ci * kv_chunk + jnp.arange(kv_chunk)
         valid = (key_pos[None, None, :] <= q_pos[:, :, None]) & (
             key_pos[None, None, :] < seq_lens[:, None, None])
+        if window is not None:
+            valid &= key_pos[None, None, :] > q_pos[:, :, None] - window
         s = jnp.where(valid[:, :, None, None, :], s, NEG_INF)
         # Clamp the running max to a finite floor so fully-masked rows/chunks
         # yield p = exp(NEG_INF - floor) = 0 instead of exp(0) = 1.
@@ -216,7 +222,12 @@ def _flash_over_kv_chunks(
         m, l, acc = compute_chunk((m, l, acc), ci)
         return ci + 1, m, l, acc
 
-    init = (jnp.int32(0),
+    # Under a window the walk starts at the chunk that holds the oldest key
+    # any live query still sees.
+    first = jnp.int32(0) if window is None else jnp.maximum(
+        jnp.min(jnp.where(q_pos >= 0, q_pos, jnp.iinfo(jnp.int32).max))
+        - window + 1, 0) // kv_chunk
+    init = (first.astype(jnp.int32),
             jnp.full((S, Q, KVH, G), -1e29, jnp.float32),
             jnp.zeros((S, Q, KVH, G), jnp.float32),
             jnp.zeros((S, Q, KVH, G, D), jnp.float32))
@@ -249,6 +260,7 @@ def _flash_batched_q_chunks(
     layer: Optional[jax.Array] = None,
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
+    window: Optional[jax.Array] = None,
 ) -> jax.Array:           # [S, Q, H, D]
     """All-sequences-batched prefill attention.
 
@@ -275,7 +287,7 @@ def _flash_batched_q_chunks(
         return _flash_over_kv_chunks(
             qs, q_pos, slot_ids, seq_lens, k_cache, v_cache,
             kv_chunk, scale, soft_cap, layer=layer,
-            k_scale=k_scale, v_scale=v_scale)
+            k_scale=k_scale, v_scale=v_scale, window=window)
 
     def one_q_chunk(_, qi):
         qs_i = jax.lax.dynamic_slice_in_dim(qs, qi * qc, qc, 1)
@@ -283,7 +295,7 @@ def _flash_batched_q_chunks(
         out_i = _flash_over_kv_chunks(
             qs_i, qp_i, slot_ids, seq_lens, k_cache, v_cache,
             kv_chunk, scale, soft_cap, layer=layer,
-            k_scale=k_scale, v_scale=v_scale)
+            k_scale=k_scale, v_scale=v_scale, window=window)
         return None, out_i
 
     _, outs = jax.lax.scan(one_q_chunk, None,
@@ -315,6 +327,7 @@ def ragged_paged_attention_chunked(
     layer: Optional[jax.Array] = None,
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
+    window: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Memory-bounded ragged attention (XLA flash recurrence).
 
@@ -335,11 +348,12 @@ def ragged_paged_attention_chunked(
         out = _flash_over_kv_chunks(
             qs, q_pos, slot_ids, seq_lens, k_cache, v_cache,
             _chunk_size_for(C), scale, soft_cap, layer=layer,
-            k_scale=k_scale, v_scale=v_scale)                  # [S, 1, H, D]
+            k_scale=k_scale, v_scale=v_scale, window=window)   # [S, 1, H, D]
     else:
         out = _flash_batched_q_chunks(
             qs, q_pos, slot_ids, seq_lens, k_cache, v_cache,
-            scale, soft_cap, layer=layer, k_scale=k_scale, v_scale=v_scale)
+            scale, soft_cap, layer=layer, k_scale=k_scale, v_scale=v_scale,
+            window=window)
 
     return out[token_seq_ids, token_qpos]       # [T, H, D]
 
@@ -409,6 +423,8 @@ def attention_with_kv_update(
     k_scale: Optional[jax.Array] = None,  # int8 caches: f32 scale planes
     v_scale: Optional[jax.Array] = None,  # ([num_slots, SW] / [L, slots, SW])
     mesh=None,               # multi-device mesh: Pallas runs per tp shard
+    window: Optional[jax.Array] = None,   # i32 scalar (traced per layer):
+                                          # keys a query sees; None = all
 ):
     """Write this step's KV into the paged cache and attend over it.
 
@@ -434,6 +450,13 @@ def attention_with_kv_update(
     On a multi-device ``mesh`` the Pallas backend runs per tp shard under
     ``manual_over_mesh``: heads (and the folded cache rows) split over
     ``tp``, every shard attends its own heads with no cross-shard traffic.
+
+    ``window``: query i sees keys j with i - window < j <= i.  Every
+    backend masks by it, and the kernels and the chunked path start their
+    walk at the first page (chunk) that holds a visible key.  The cache is
+    written whole all the same.  ``None`` lowers to the programs without
+    the operand; a full layer of a mixed stack passes a window that never
+    binds (``models.config.NO_WINDOW``).
     """
     backend = resolve_backend(backend)
     if backend == "pallas" and mesh is not None and mesh.devices.size > 1:
@@ -442,15 +465,16 @@ def attention_with_kv_update(
         rows = P(*(None,) * (k_cache.ndim - 1), "tp")
         scales = () if k_scale is None else (k_scale, v_scale)
         s_spec = rows if scales and k_scale.shape[-1] > 1 else P()
-        largs = () if layer is None else (layer,)
+        # The replicated scalars, those that are given.
+        largs = {name: v for name, v in (("layer", layer), ("window", window))
+                 if v is not None}
 
         def local(q, k_new, v_new, k_cache, v_cache, ab, *rest):
-            lyr = rest[0] if largs else None
             ks, vs = rest[len(largs):] or (None, None)
             return attention_with_kv_update(
                 q, k_new, v_new, k_cache, v_cache, ab, block_size,
                 scale=scale, soft_cap=soft_cap, backend=backend,
-                layer=lyr, k_scale=ks, v_scale=vs)
+                k_scale=ks, v_scale=vs, **dict(zip(largs, rest)))
 
         return manual_over_mesh(
             local, mesh,
@@ -458,7 +482,7 @@ def attention_with_kv_update(
                       {k: P() for k in ab}) + (P(),) * len(largs)
             + (s_spec,) * len(scales),
             out_specs=(heads, rows, rows) + (s_spec,) * len(scales),
-        )(q, k_new, v_new, k_cache, v_cache, ab, *largs, *scales)
+        )(q, k_new, v_new, k_cache, v_cache, ab, *largs.values(), *scales)
     quantized = k_scale is not None
     T, H, D = q.shape
     F = k_cache.shape[-1]
@@ -491,14 +515,15 @@ def attention_with_kv_update(
                     block_size=block_size, num_kv_heads=F // D,
                     scale=scale, layer=layer,
                     k_scale=k_scale, v_scale=v_scale,
-                    k_scale_new=k_s[rows], v_scale_new=v_s[rows])
+                    k_scale_new=k_s[rows], v_scale_new=v_s[rows],
+                    window=window)
         else:
             out, k_cache, v_cache = paged_attention_decode_update(
                 q[rows], k_new.reshape(T, F)[rows].astype(k_cache.dtype),
                 v_new.reshape(T, F)[rows].astype(v_cache.dtype),
                 k_cache, v_cache, batch["block_tables"], batch["seq_lens"],
                 block_size=block_size,
-                num_kv_heads=F // D, scale=scale, layer=layer)
+                num_kv_heads=F // D, scale=scale, layer=layer, window=window)
         return _ret(out[batch["token_seq_ids"]],
                     k_cache, v_cache, k_scale, v_scale)
 
@@ -525,7 +550,7 @@ def attention_with_kv_update(
             batch["block_tables"], batch["seq_lens"],
             block_size=block_size, num_kv_heads=F // D,
             scale=scale, soft_cap=soft_cap, layer=layer,
-            k_scale=k_scale, v_scale=v_scale)
+            k_scale=k_scale, v_scale=v_scale, window=window)
         return _ret(out_s[batch["token_seq_ids"], batch["token_qpos"]],
                     k_cache, v_cache, k_scale, v_scale)
     if backend in ("pallas", "chunked") and qtok_idx is not None:
@@ -534,11 +559,11 @@ def attention_with_kv_update(
             batch["block_tables"], batch["seq_lens"], qtok_idx,
             batch["token_qpos"], block_size=block_size,
             scale=scale, soft_cap=soft_cap, layer=layer,
-            k_scale=k_scale, v_scale=v_scale)
+            k_scale=k_scale, v_scale=v_scale, window=window)
     else:
         out = ragged_paged_attention_reference(
             q, k_cache, v_cache, batch["token_seq_ids"], batch["positions"],
             batch["block_tables"], batch["seq_lens"],
             block_size=block_size, scale=scale, soft_cap=soft_cap,
-            layer=layer, k_scale=k_scale, v_scale=v_scale)
+            layer=layer, k_scale=k_scale, v_scale=v_scale, window=window)
     return _ret(out, k_cache, v_cache, k_scale, v_scale)

@@ -41,7 +41,7 @@ def _prefill_kernel(
     # scalar prefetch
     block_tables_ref,   # [S, B] SMEM
     seq_lens_ref,       # [S]    SMEM
-    layer_ref,          # [1]    SMEM
+    layer_ref,          # [1]    SMEM; [2] when ``windowed``: (layer, window)
     # inputs / outputs / scratch — layout depends on ``quantized``:
     #   bf16:  q, qpos, k_hbm, v_hbm | o | k_buf, v_buf, sems
     #   int8:  q, qpos, k_hbm, v_hbm, ks_hbm, vs_hbm | o
@@ -56,6 +56,7 @@ def _prefill_kernel(
     scale: float,
     soft_cap: float | None,
     quantized: bool,
+    windowed: bool,
 ):
     if quantized:
         (q_ref, qpos_ref, k_hbm, v_hbm, ks_hbm, vs_hbm,
@@ -77,6 +78,15 @@ def _prefill_kernel(
     # Causal bound: keys at positions > qmax never score for this tile.
     live = jnp.minimum(seq_len, qmax + 1)
     n_pages = pl.cdiv(jnp.maximum(live, 0), bs)
+    if windowed:
+        # Window: query i sees keys j > i - window, so the tile's walk
+        # starts at the page of its first real query's oldest key (pad
+        # slots carry position -1).
+        window = layer_ref[1]
+        qmin = jnp.min(jnp.where(q_pos >= 0, q_pos, jnp.iinfo(jnp.int32).max))
+        first = jnp.minimum(jnp.maximum(qmin - window + 1, 0) // bs, n_pages)
+    else:
+        first = 0
 
     def page_dma(slot, j):
         b = block_tables_ref[s, j]
@@ -101,9 +111,9 @@ def _prefill_kernel(
     if quantized:
         dequant = make_page_dequant(ks_hbm.shape[2], F)
 
-    @pl.when(n_pages > 0)
+    @pl.when(n_pages > first)
     def _():
-        for dma in page_dma(0, 0):
+        for dma in page_dma(first % 2, first):
             dma.start()
 
     # Zero-expanded queries in fused row space: row r belongs to head r % H,
@@ -144,6 +154,8 @@ def _prefill_kernel(
         key_pos = j * bs + jax.lax.broadcasted_iota(
             jnp.int32, (1, bs), 1)                            # [1, bs]
         valid = (key_pos <= q_pos) & (key_pos < seq_len)      # [R, bs]
+        if windowed:
+            valid &= key_pos > q_pos - window
         s_hb = jnp.where(valid, s_hb, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s_hb, axis=-1, keepdims=True))
         p = jnp.exp(s_hb - m_new)
@@ -160,7 +172,7 @@ def _prefill_kernel(
         jnp.zeros((R, 1), jnp.float32),
         jnp.zeros((R, F), jnp.float32),
     )
-    m, l, acc = jax.lax.fori_loop(0, n_pages, body, init)
+    m, l, acc = jax.lax.fori_loop(first, n_pages, body, init)
     masked = acc * block_mask                                 # [R, F]
     out = masked[:, 0:D]
     for kk in range(1, KVH):
@@ -209,6 +221,8 @@ def flash_prefill_paged(
     q_tile: int | None = None,
     k_scale: jax.Array | None = None,   # int8 caches: [L, slots, SW] f32
     v_scale: jax.Array | None = None,   # scale planes (per page row)
+    window: jax.Array | None = None,    # i32 scalar: keys a query sees
+                                        # (itself included); None = all
 ):
     """Returns attention outputs [S, Q, H, D] (caches already written —
     int8 caches with their scale planes scattered by the caller)."""
@@ -227,7 +241,8 @@ def flash_prefill_paged(
     Qt = q_tile if q_tile is not None else _pick_q_tile(Q, H, F)
     if Q % Qt:
         raise ValueError(f"q_tile={Qt} must divide Q={Q}")
-    layer_arr = jnp.asarray([0 if layer is None else layer], jnp.int32)
+    layer_arr = jnp.asarray([0 if layer is None else layer]
+                            + ([] if window is None else [window]), jnp.int32)
 
     # Fused row space (slot-major, head-minor), shaped OUTSIDE the kernel so
     # Mosaic never sees a vector reshape.
@@ -263,7 +278,7 @@ def flash_prefill_paged(
     kernel = functools.partial(
         _prefill_kernel, block_size=block_size, num_heads=H,
         num_kv_heads=num_kv_heads, scale=scale, soft_cap=soft_cap,
-        quantized=quantized)
+        quantized=quantized, windowed=window is not None)
     operands = [block_tables, seq_lens, layer_arr, q_fused, qpos_fused,
                 k_cache, v_cache]
     if quantized:

@@ -42,7 +42,8 @@ def _decode_kernel(
     # scalar prefetch
     block_tables_ref,   # [S, B] SMEM
     seq_lens_ref,       # [S]    SMEM (context length INCLUDING the new token)
-    layer_ref,          # [1]    SMEM (layer plane of the stacked cache)
+    layer_ref,          # [1]    SMEM (layer plane of the stacked cache);
+                        # [2] when ``windowed``: (layer, window)
     # inputs / outputs / scratch — layout depends on ``quantized``:
     #   bf16:  q, kn, vn, k_hbm, v_hbm | o, k_out, v_out
     #          | k_buf, v_buf, sems, wsems
@@ -57,6 +58,7 @@ def _decode_kernel(
     scale: float,
     group: int,
     quantized: bool,
+    windowed: bool,
 ):
     """Fused decode attention + KV update on the STACKED cache.
 
@@ -81,6 +83,12 @@ def _decode_kernel(
     and the new row's pre-quantized bytes + scale row are spliced and
     written back exactly like the bf16 page.  The flash recurrence itself
     is unchanged: bf16 MXU operands, f32 statistics.
+
+    ``windowed``: the query (position seq_len - 1) sees only the last
+    ``window`` keys, so each sequence's walk STARTS at the page that holds
+    key seq_len - window; step j of the lockstep loop is that sequence's
+    page start + j, and the pages before it are neither fetched nor
+    multiplied.  A window that never binds starts every walk at page 0.
     """
     if quantized:
         (q_ref, kn_ref, vn_ref, ksn_ref, vsn_ref,
@@ -102,9 +110,20 @@ def _decode_kernel(
 
     seq_len_g = [seq_lens_ref[base + g] for g in range(G)]
     n_pages_g = [pl.cdiv(sl, bs) for sl in seq_len_g]
-    n_max = n_pages_g[0]
+    if windowed:
+        window = layer_ref[1]
+        start_g = [jnp.maximum(sl - window, 0) // bs for sl in seq_len_g]
+        steps_g = [n - s for n, s in zip(n_pages_g, start_g)]
+    else:
+        steps_g = n_pages_g
+    n_max = steps_g[0]
     for g in range(1, G):
-        n_max = jnp.maximum(n_max, n_pages_g[g])
+        n_max = jnp.maximum(n_max, steps_g[g])
+
+    def page_of(g, j):
+        """Logical page of sequence ``g`` at step ``j`` of the loop."""
+        return start_g[g] + j if windowed else j
+
     # Decode invariant: the new token sits at position seq_len - 1, i.e. in
     # LOGICAL page n_pages - 1, row (seq_len - 1) % bs.
     write_page_g = [(sl - 1) // bs for sl in seq_len_g]
@@ -115,7 +134,8 @@ def _decode_kernel(
         for g in range(G):
             # Clamp for sequences whose pages ran out (and 0-length pad
             # rows): a dead re-read of a valid page, masked at compute.
-            jj = jnp.clip(j, 0, jnp.maximum(n_pages_g[g] - 1, 0))
+            jj = jnp.clip(page_of(g, j), 0,
+                          jnp.maximum(n_pages_g[g] - 1, 0))
             b = block_tables_ref[base + g, jj]
             start = pl.multiple_of(b * bs, bs)
             copies.append(pltpu.make_async_copy(
@@ -153,6 +173,10 @@ def _decode_kernel(
     sl_arr = jnp.zeros((G, 1, bs), jnp.int32)
     for g in range(G):
         sl_arr = jnp.where(g_ids == g, seq_len_g[g], sl_arr)
+    if windowed:
+        start_arr = jnp.zeros((G, 1, bs), jnp.int32)
+        for g in range(G):
+            start_arr = jnp.where(g_ids == g, start_g[g], start_arr)
 
     if quantized:
         SW = ksn_ref.shape[2]
@@ -174,12 +198,12 @@ def _decode_kernel(
         # On each sequence's write page (exactly once per call): splice the
         # new-token row into the resident page and write the page back.
         for g in range(G):
-            @pl.when(j == write_page_g[g])
+            @pl.when(page_of(g, j) == write_page_g[g])
             def _(g=g):
                 is_wr = row_ids2 == w_row_g[g]
                 k_buf[slot, g] = jnp.where(is_wr, kn_ref[g], k_buf[slot, g])
                 v_buf[slot, g] = jnp.where(is_wr, vn_ref[g], v_buf[slot, g])
-                b = block_tables_ref[base + g, j]
+                b = block_tables_ref[base + g, page_of(g, j)]
                 start = pl.multiple_of(b * bs, bs)
                 writes = [
                     pltpu.make_async_copy(
@@ -222,9 +246,12 @@ def _decode_kernel(
         s_hb = jax.lax.dot_general(
             q_full.astype(jnp.bfloat16), k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)               # [G, H, bs]
-        key_pos = j * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (G, 1, bs), 2)
-        s_hb = jnp.where(key_pos < sl_arr, s_hb, NEG_INF)
+        key_pos = (start_arr + j if windowed else j) * bs \
+            + jax.lax.broadcasted_iota(jnp.int32, (G, 1, bs), 2)
+        valid = key_pos < sl_arr
+        if windowed:
+            valid &= key_pos >= sl_arr - window
+        s_hb = jnp.where(valid, s_hb, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s_hb, axis=-1, keepdims=True))
         p = jnp.exp(s_hb - m_new)                             # [G, H, bs]
         corr = jnp.exp(m - m_new)
@@ -297,6 +324,8 @@ def paged_attention_decode_update(
     v_scale: jax.Array | None = None,   # scale planes (per page row)
     k_scale_new: jax.Array | None = None,   # [S, SW] new rows' scales
     v_scale_new: jax.Array | None = None,
+    window: jax.Array | None = None,   # i32 scalar: keys the query sees
+                                       # (itself included); None = all
 ):
     """Returns (attn_out [S, H, D], k_cache', v_cache') — plus
     (k_scale', v_scale') appended when the cache is int8-quantized
@@ -328,7 +357,8 @@ def paged_attention_decode_update(
         4 * block_size * F * k_cache.dtype.itemsize
         + 16 * block_size * SW + 8 * H * F)
     layer_arr = jnp.asarray(
-        [0 if layer is None else layer], jnp.int32)
+        [0 if layer is None else layer]
+        + ([] if window is None else [window]), jnp.int32)
 
     def vspec(shape):
         return pl.BlockSpec(shape, lambda i, *_: (i,) + (0,) * (len(shape) - 1),
@@ -362,7 +392,8 @@ def paged_attention_decode_update(
     )
     kernel = functools.partial(
         _decode_kernel, block_size=block_size, num_kv_heads=num_kv_heads,
-        scale=scale, group=G, quantized=quantized)
+        scale=scale, group=G, quantized=quantized,
+        windowed=window is not None)
     out_shape = [jax.ShapeDtypeStruct((S, H, D), q.dtype),
                  jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
                  jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)]

@@ -63,8 +63,13 @@ def ring_attention(
     mesh: Mesh,
     scale: Optional[float] = None,
     causal: bool = True,
+    window: Optional[int] = None,
 ) -> jax.Array:            # [T, H, D]
     """Exact attention over a sequence sharded across the sp axis."""
+    if window is not None:
+        raise NotImplementedError(
+            "ring_attention has no attention window: a sliding-window layer "
+            "must run through ops.attention.attention_with_kv_update")
     T, H, D = q.shape
     scale = scale if scale is not None else D ** -0.5
     sp = mesh.shape[AXIS_SP]

@@ -1162,7 +1162,8 @@ def engine_config_from_args(args) -> EngineConfig:
         enable_eplb=args.enable_eplb,
         eplb_config=json.loads(args.eplb_config) if args.eplb_config else None,
         spec_k=args.spec_k,
-        spec_strict=(True if args.spec_strict else None))
+        spec_strict=(True if args.spec_strict else None),
+        precompile_step_shapes=args.precompile_step_shapes)
 
 
 def build_arg_parser() -> argparse.ArgumentParser:
@@ -1317,6 +1318,12 @@ def build_arg_parser() -> argparse.ArgumentParser:
              "per-request demotions still only count "
              "llmd_tpu:engine_feature_disabled_total.  Default: "
              "LLMD_SPEC_STRICT (0 = demote-and-count)")
+    p.add_argument(
+        "--precompile-step-shapes", action="store_true",
+        help="compile every classic step program the bucket scheme can "
+             "reach (tokens x rows x longest chunk) before the first "
+             "request instead of on first use: a longer start, and no "
+             "request ever waits for a compile")
     p.add_argument(
         "--kv-transfer-config", default=None,
         help="JSON KV-connector config for PD disaggregation, e.g. "

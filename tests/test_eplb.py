@@ -5,6 +5,8 @@ The invariant that matters: routing through an EPLB physical placement
 the same model output as the logical layout — replicas are copies.
 """
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -183,6 +185,11 @@ def test_migration_respects_budget_and_flips_atomically(mesh4):
     ticks = 1
     while ctrl.migrating and ticks < 100:
         assert params["moe_layers"]["w_gate"] is before["w_gate"]
+        if not ctrl._migration.moves:
+            # Everything is staged and the flip waits for the async
+            # copies: give them time, as a served step between two ticks
+            # does (bare ticks outran them on a loaded machine).
+            time.sleep(0.01)
         params = ctrl.on_step(None, 4 + ticks, params, mesh4)
         ticks += 1
     assert not ctrl.migrating

@@ -69,14 +69,15 @@ def _sds(shape, dtype):
 
 # ---- case builders: each returns (fn, [ShapeDtypeStruct, ...]) ----------
 
-def _dense_decode(H, KVH, D, S=64, B=64, L=16, sw=0):
+def _dense_decode(H, KVH, D, S=64, B=64, L=16, sw=0, window=False):
+    """``window``: the traced per-layer window operand of a mixed stack."""
     from llm_d_tpu.ops.pallas.paged_attention import (
         paged_attention_decode_update as kern)
     F = KVH * D
     cdt = jnp.int8 if sw else jnp.bfloat16
 
     def fn(q, kn, vn, kc, vc, bt, sl, layer, *scales):
-        kw = {}
+        kw = {"window": layer + 2048} if window else {}
         if sw:
             kw = dict(k_scale=scales[0], v_scale=scales[1],
                       k_scale_new=scales[2], v_scale_new=scales[3])
@@ -93,13 +94,15 @@ def _dense_decode(H, KVH, D, S=64, B=64, L=16, sw=0):
     return fn, args
 
 
-def _dense_prefill(H, KVH, D, S=8, Q=256, B=64, L=16, sw=0):
+def _dense_prefill(H, KVH, D, S=8, Q=256, B=64, L=16, sw=0, window=False):
     from llm_d_tpu.ops.pallas.flash_prefill import flash_prefill_paged as kern
     F = KVH * D
     cdt = jnp.int8 if sw else jnp.bfloat16
 
     def fn(qs, qp, kc, vc, bt, sl, layer, *scales):
         kw = dict(k_scale=scales[0], v_scale=scales[1]) if sw else {}
+        if window:
+            kw["window"] = layer + 2048
         return kern(qs, qp, kc, vc, bt, sl, block_size=BS,
                     num_kv_heads=KVH, layer=layer, **kw)
 
@@ -215,6 +218,40 @@ CASES = [
                  id="streamed_moe_int8-a2a-EP4-T32768"),
     pytest.param(functools.partial(_moe, "grouped", 2048),
                  id="grouped_moe_int8-T2048"),
+    # A mixed sliding-window / full stack at 32 / 4 x 128 heads, the window
+    # a traced scalar, contexts to 32768 (B = 1024 pages), 8 layers: the
+    # sequence and query buckets its long-document cell reaches.
+    pytest.param(functools.partial(_dense_decode, 32, 4, 128, B=1024, L=8,
+                                   window=True),
+                 id="paged_decode-bf16-window-S64"),
+    pytest.param(functools.partial(_dense_decode, 32, 4, 128, S=8, B=1024,
+                                   L=8, window=True),
+                 id="paged_decode-bf16-window-S8"),
+    pytest.param(functools.partial(_dense_prefill, 32, 4, 128, S=64, Q=2048,
+                                   B=1024, L=8, window=True),
+                 id="flash_prefill-bf16-window-S64-Q2048"),
+    pytest.param(functools.partial(_dense_prefill, 32, 4, 128, S=8, Q=128,
+                                   B=1024, L=8, window=True),
+                 id="flash_prefill-bf16-window-S8-Q128"),
+    # The int8 expert kernels a served step program holds at expert width
+    # 1024 (128 experts, top-8, 6 MoE layers): dense to 64 tokens, routed
+    # to 512, streamed above (ops/moe.py).  The sorted+padded grouped
+    # kernel, an A/B lever no configuration selects, compiles there too.
+    pytest.param(functools.partial(_moe, "dense", 64, I=1024, E=128, Lm=6),
+                 id="dense_moe_int8-T64-I1024-E128"),
+    pytest.param(functools.partial(_moe, "routed", 128, I=1024, E=128, Lm=6),
+                 id="routed_moe_int8-T128-I1024-E128"),
+    pytest.param(functools.partial(_moe, "routed", 512, I=1024, E=128, Lm=6),
+                 id="routed_moe_int8-T512-I1024-E128"),
+    pytest.param(functools.partial(_moe, "streamed", 1024, I=1024, E=128,
+                                   Lm=6),
+                 id="streamed_moe_int8-T1024-I1024-E128"),
+    pytest.param(functools.partial(_moe, "streamed", 2048, I=1024, E=128,
+                                   Lm=6),
+                 id="streamed_moe_int8-T2048-I1024-E128"),
+    pytest.param(functools.partial(_moe, "grouped", 2048, I=1024, E=128,
+                                   Lm=6),
+                 id="grouped_moe_int8-T2048-I1024-E128"),
     # int8 KV / latent caches: refused (see _SCALE_DMA).
     pytest.param(functools.partial(_dense_decode, 32, 8, 64, sw=1),
                  id="paged_decode-int8-token", marks=_xfail(_SCALE_DMA)),
