@@ -663,19 +663,26 @@ class EngineCore:
         logger.info("attention backend: %s (%s requested, %s platform)",
                     backend, self.config.attn_backend,
                     jax.default_backend())
+        # (heads, cache row width) one shard's Pallas prefill kernels see:
+        # what sets their query tile.  None: another path serves prefill.
+        self._prefill_tile_dims: Optional[Tuple[int, int]] = None
         if backend != "pallas":
             return
-        # A tp shard sees its slice of the folded dense rows (the MLA
-        # latent row is replicated over tp).
-        tp = (self.config.mesh.tp if self.config.mesh else 1) \
-            if not self.model_config.use_mla else 1
+        # A tp shard sees its slice of the heads and of the folded dense
+        # rows (the MLA latent row is replicated over tp).
+        heads_tp = self.config.mesh.tp if self.config.mesh else 1
+        tp = heads_tp if not self.model_config.use_mla else 1
         reason = next(filter(None, (
             pallas_ineligible_reason(
                 self.config.block_size, w // tp, self.kv_quantized)
             for w in layout.values())), None)
         if reason is not None:
             self._disable_feature("pallas_attention", reason)
-        elif self.kv_quantized and jax.default_backend() == "tpu":
+            return
+        self._prefill_tile_dims = (
+            self.model_config.num_heads // heads_tp,
+            next(iter(layout.values())) // tp)
+        if self.kv_quantized and jax.default_backend() == "tpu":
             raise ValueError(
                 f"kv_cache_dtype=int8 with the Pallas attention backend "
                 f"cannot run on a TPU: {INT8_CACHE_KERNEL_REFUSAL}.  Use "
@@ -2607,6 +2614,9 @@ class EngineCore:
             buf, self._replicated if self.dp == 1 else self._dp_sharded)
         self._clock.count("h2d_copies")
         self._step_kv = self._kv_counts(ends, news)
+        if Q > 1:
+            self._step_kv.update(
+                self._attn_q_counts(int(np.sum(news)), layout))
         return packed, layout, scheduled, rows
 
     # ---------- step ----------
@@ -2636,6 +2646,20 @@ class EngineCore:
             counts["kv_dead_tokens"] = n_window * int(
                 np.maximum(ends - w + 1, 0).sum())
         return counts
+
+    def _attn_q_counts(self, real: int, layout: BatchLayout) -> Dict[str, int]:
+        """What a prefill or mixed dispatch hands prefill attention
+        (step_clock.py): ``real`` query tokens in the slots its grid holds,
+        the Pallas kernels' query tiles or the other paths' [S, Q]
+        rectangle."""
+        slots = layout.S * layout.Q
+        if self._prefill_tile_dims is not None:
+            from llm_d_tpu.ops.attention import (
+                num_query_tiles, prefill_q_tile)
+            qt = prefill_q_tile(layout.Q, *self._prefill_tile_dims,
+                                self.model_config.use_mla)
+            slots = num_query_tiles(layout.T, layout.S, qt) * qt
+        return {"attn_q_real": real, "attn_q_slots": layout.dp * slots}
 
     def _note_step(self, t0: float, fetched: float, requests: List[Request],
                    prefill_tokens: int, decode_tokens: int, *,

@@ -36,6 +36,14 @@ classic path also hands them to its ``llmd.dispatch`` annotation):
   kv_dead_tokens  sum over rows and window layers of those no later query
                   of the row can see: what a pool per layer kind would free
 
+and, for the classic path's steps with prefill tokens
+(``EngineCore._attn_q_counts``):
+
+  attn_q_real     query tokens the step computes
+  attn_q_slots    query slots the prefill attention grid holds for them: the
+                  Pallas kernels' query tiles (tiles x slots a tile), the
+                  padded [S, Q] rectangle where another path serves
+
 Phases are contiguous, so they add up to the iteration.  An iteration that
 fetched nothing (an empty schedule, the first dispatch of a pipelined
 block) writes no span; its times stay in the accumulator and ride the next

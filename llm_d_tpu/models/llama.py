@@ -20,7 +20,8 @@ from jax.sharding import PartitionSpec as P
 
 from llm_d_tpu.models.config import ModelConfig
 from llm_d_tpu.ops import layers as L
-from llm_d_tpu.ops.attention import attention_with_kv_update
+from llm_d_tpu.ops.attention import (
+    attention_with_kv_update, with_query_tiles)
 
 Params = Dict[str, Any]
 
@@ -179,6 +180,10 @@ def forward(
     cache_names = ("k", "v", "k_scale", "v_scale") \
         if "k_scale" in kv_cache else ("k", "v")
     caches0 = tuple(kv_cache[n] for n in cache_names)
+    # Once a step program, outside the layer scan: the query tile list the
+    # Pallas prefill kernels walk in every layer.
+    batch = with_query_tiles(batch, c.num_heads, caches0[0].shape[-1],
+                             attn_backend, mesh)
 
     # The FULL stacked KV cache rides the scan carry and each layer updates
     # its plane in place (Pallas aliasing / scatter-at-layer): slicing the

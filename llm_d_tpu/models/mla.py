@@ -220,8 +220,9 @@ def _mla_attend(q_eff, row, kv_cache, batch, layer, kv_scale=None, *,
                 seq_group=sg)
         out_lat = out[batch["token_seq_ids"]][..., :R].astype(jnp.float32)
     elif kernel_ok:
-        # Prefill / mixed batches: MLA flash kernel — the latent page is
-        # DMA'd once per tile and serves both the score and value dots
+        # Prefill / mixed batches: MLA flash kernel over the step's query
+        # tiles — the latent page is DMA'd once per tile and serves both
+        # the score and value dots
         # (ops/pallas/mla_prefill.py; the chunked XLA path below cost
         # ~90% of the MoE prefill step, round-4 verdict Weak #4).
         from llm_d_tpu.ops.pallas.mla_prefill import mla_flash_prefill
@@ -231,13 +232,13 @@ def _mla_attend(q_eff, row, kv_cache, batch, layer, kv_scale=None, *,
         if quantized:
             kv_scale = A.write_scales(
                 kv_scale, row_s, batch["slot_mapping"], layer=layer)
-        qs, q_pos = A.gather_per_seq_queries(
-            q_eff, batch["positions"], qtok_idx)            # [S, Q, H, F]
-        out_s = mla_flash_prefill(
-            qs, q_pos, kv_cache, batch["block_tables"], batch["seq_lens"],
-            block_size=block_size, scale=scale, layer=layer,
-            kv_scale=kv_scale)
-        out_lat = out_s[batch["token_seq_ids"], batch["token_qpos"]]
+        q_tiles, batch = A.gather_query_tiles(
+            q_eff, batch, F_cache, mla=True)                # [NT, Qt, H, F]
+        out_t = mla_flash_prefill(
+            q_tiles, batch["tile_pos"], kv_cache, batch["block_tables"],
+            batch["seq_lens"], block_size=block_size, scale=scale,
+            layer=layer, kv_scale=kv_scale, tile_seq=batch["tile_seq"])
+        out_lat = out_t[batch["tok_tile"], batch["tok_slot"]]
         out_lat = out_lat[..., :R].astype(jnp.float32)      # attended c_kv
     else:
         # KVH=1 (every head reads the same latent row); the v-cache aliases

@@ -28,6 +28,7 @@ from llm_d_tpu.models.llama import (  # noqa: F401  (re-exports: the MoE
     init_draft_params, mlp_out)
 from llm_d_tpu.ops import layers as L
 from llm_d_tpu.ops import moe as moe_ops
+from llm_d_tpu.ops.attention import with_query_tiles
 from llm_d_tpu.parallel.mesh import AXIS_EP
 
 Params = Dict[str, Any]
@@ -146,6 +147,11 @@ def forward(
         cache_keys = ("k", "v", "k_scale", "v_scale")
     else:
         cache_keys = ("k", "v")
+    # Once a step program, outside both layer scans: the query tile list
+    # the Pallas prefill kernels walk in every layer.
+    batch = with_query_tiles(
+        batch, c.num_heads, kv_cache[cache_keys[0]].shape[-1], attn_backend,
+        mesh, mla=c.use_mla)
     # DBO threshold by phase: the program's query width is static under jit,
     # and Q == 1 holds exactly for pure-decode programs (single-step or
     # fused).  None (no opts) lets the op consult its standalone env vars;

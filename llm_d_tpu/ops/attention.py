@@ -307,13 +307,114 @@ def gather_per_seq_queries(q, positions, qtok_idx):
     """[T, H, D] ragged queries -> ([S, Q, H, D], [S, Q] positions).
 
     qtok_idx's pad sentinel is T: one zero query row / -1 position is
-    appended so pad slots gather a fully-masked row.  Shared by the chunked
-    XLA path and the Pallas prefill kernel dispatch."""
+    appended so pad slots gather a fully-masked row.  The rectangle of the
+    chunked XLA path; the Pallas prefill kernels take ``gather_query_tiles``."""
     T, H, D = q.shape
     q_pad = jnp.concatenate([q, jnp.zeros((1, H, D), q.dtype)])
     pos_pad = jnp.concatenate(
         [positions, jnp.full((1,), -1, positions.dtype)])
     return q_pad[qtok_idx], pos_pad[qtok_idx]
+
+
+# What ``query_tiles`` derives from a batch (``ATTN_BATCH_KEYS`` carries
+# them to the shard-local attention call).
+QUERY_TILE_KEYS = ("tile_seq", "tile_tok", "tile_pos", "tok_tile", "tok_slot")
+
+
+def prefill_q_tile(Q: int, H: int, F: int, mla: bool = False) -> int:
+    """Query slots a tile of the Pallas prefill kernels holds, for a step
+    of query bucket ``Q`` and ``H`` heads over cache rows ``F`` wide as ONE
+    shard sees them (``ops.pallas.flash_prefill.pick_q_tile``)."""
+    if mla:
+        from llm_d_tpu.ops.pallas.mla_prefill import _pick_q_tile
+    else:
+        from llm_d_tpu.ops.pallas.flash_prefill import _pick_q_tile
+    return _pick_q_tile(Q, H, F)
+
+
+def num_query_tiles(T: int, S: int, q_tile: int) -> int:
+    """Tiles that hold any ``S`` rows of ``T`` tokens together: a row of n
+    tokens fills ceil(n / q_tile), so the sum stays UNDER this count and
+    the last tile is always dead."""
+    return -(-T // q_tile) + S
+
+
+def query_tiles(batch, q_tile: int):
+    """The compact list of query tiles the Pallas prefill kernels walk in
+    place of the padded [S, Q] rectangle, from the batch's ``qtok_idx``
+    [S, Q] (token per (row, slot), T = pad; a row's n queries are its
+    leading n entries), ``token_seq_ids``, ``token_qpos`` and ``positions``
+    [T].  A row with n queries takes ceil(n / q_tile) tiles, rows in order,
+    dead tiles at the end.
+
+      tile_seq [NT]      row a tile belongs to (a dead tile: the last row)
+      tile_tok [NT, Qt]  flat token index per slot, T = pad (as qtok_idx)
+      tile_pos [NT, Qt]  its position, pad -> -1
+      tok_tile, tok_slot [T]  where each token's output lands; a token that
+                         is in no row's list (padding, a fused round's dead
+                         slot) reads the dead last tile: zeros
+
+    A few integer ops on [S]-, [T]- and [NT, Qt]-sized arrays, derived once
+    a step program (``with_query_tiles``), not once a layer."""
+    qtok_idx, seq, qpos = (batch[k] for k in (
+        "qtok_idx", "token_seq_ids", "token_qpos"))
+    S, Q = qtok_idx.shape
+    T = seq.shape[0]
+    NT = num_query_tiles(T, S, q_tile)
+    n_row = jnp.sum(qtok_idx < T, axis=1, dtype=jnp.int32)          # [S]
+    row_tiles = -(-n_row // q_tile)
+    ends = jnp.cumsum(row_tiles)                 # tiles of rows 0 .. s
+    starts = ends - row_tiles
+    n = jnp.arange(NT, dtype=jnp.int32)
+    tile_seq = jnp.minimum(
+        jnp.sum(n[:, None] >= ends[None, :], axis=1, dtype=jnp.int32), S - 1)
+    slot = ((n - starts[tile_seq])[:, None] * q_tile
+            + jnp.arange(q_tile, dtype=jnp.int32)[None, :])   # [NT, Qt] in row
+    live = (n < ends[-1])[:, None] & (slot < Q)
+    tile_tok = jnp.where(
+        live, qtok_idx[tile_seq[:, None], jnp.minimum(slot, Q - 1)], T)
+    pos_pad = jnp.concatenate(
+        [batch["positions"], jnp.full((1,), -1, batch["positions"].dtype)])
+    real = qpos < n_row[seq]
+    return dict(
+        tile_seq=tile_seq, tile_tok=tile_tok, tile_pos=pos_pad[tile_tok],
+        tok_tile=jnp.where(real, starts[seq] + qpos // q_tile, NT - 1),
+        tok_slot=jnp.where(real, qpos % q_tile, 0))
+
+
+def with_query_tiles(batch, num_heads: int, row_width: int, backend: str,
+                     mesh=None, mla: bool = False):
+    """``batch`` plus its query tile list (``QUERY_TILE_KEYS``) where the
+    Pallas prefill kernels will serve it: the models' ``forward`` calls this
+    before the layer scan, so the list is derived once a step program.
+    ``num_heads`` and ``row_width`` are the model's; a tp shard sees its
+    slice of the heads (and of the folded GQA row; the MLA latent row is
+    replicated).  Stacked dp batches get one list per shard."""
+    qtok_idx = batch.get("qtok_idx")
+    if (qtok_idx is None or qtok_idx.shape[-1] == 1
+            or resolve_backend(backend) != "pallas"):
+        return batch
+    tp = mesh.shape.get("tp", 1) if mesh is not None else 1
+    derive = functools.partial(query_tiles, q_tile=prefill_q_tile(
+        qtok_idx.shape[-1], num_heads // tp,
+        row_width if mla else row_width // tp, mla))
+    if qtok_idx.ndim == 3:
+        derive = jax.vmap(derive)
+    return dict(batch, **derive({k: batch[k] for k in (
+        "qtok_idx", "token_seq_ids", "token_qpos", "positions")}))
+
+
+def gather_query_tiles(q, batch, row_width: int, mla: bool = False):
+    """[T, H, D] ragged queries -> (tiles [NT, Qt, H, D], the batch with
+    its tile list).  A batch that did not come through ``with_query_tiles``
+    (a caller outside the models' ``forward``) gets its list derived here,
+    from the shapes this shard sees."""
+    T, H, D = q.shape
+    if "tile_tok" not in batch:
+        batch = dict(batch, **query_tiles(batch, prefill_q_tile(
+            batch["qtok_idx"].shape[1], H, row_width, mla)))
+    q_pad = jnp.concatenate([q, jnp.zeros((1, H, D), q.dtype)])
+    return q_pad[batch["tile_tok"]], batch
 
 
 def ragged_paged_attention_chunked(
@@ -405,7 +506,8 @@ def manual_over_mesh(fn, mesh, in_specs, out_specs):
 
 # Batch arrays attention consumes (replicated over tp under a TP mesh).
 ATTN_BATCH_KEYS = ("positions", "token_seq_ids", "token_qpos",
-                   "slot_mapping", "block_tables", "seq_lens", "qtok_idx")
+                   "slot_mapping", "block_tables", "seq_lens", "qtok_idx",
+                   *QUERY_TILE_KEYS)
 
 
 def attention_with_kv_update(
@@ -450,6 +552,11 @@ def attention_with_kv_update(
     On a multi-device ``mesh`` the Pallas backend runs per tp shard under
     ``manual_over_mesh``: heads (and the folded cache rows) split over
     ``tp``, every shard attends its own heads with no cross-shard traffic.
+
+    Prefill and mixed steps hand the Pallas kernel the step's query TILES
+    (``gather_query_tiles``: Qt slots of one row each, only the tiles that
+    hold a real query), not the padded [S, Q] rectangle; the chunked XLA
+    path keeps the rectangle.
 
     ``window``: query i sees keys j with i - window < j <= i.  Every
     backend masks by it, and the kernels and the chunked path start their
@@ -540,18 +647,18 @@ def attention_with_kv_update(
             layer=layer)
     if kernel_ok and qtok_idx.shape[1] > 1:
         # Prefill / mixed batches: flash kernel streaming KV pages through
-        # VMEM (scatter-then-read; no aliasing needed).  Same geometry
-        # gate as the decode kernel.
+        # VMEM (scatter-then-read; no aliasing needed), over the step's
+        # query tiles.  Same geometry gate as the decode kernel.
         from llm_d_tpu.ops.pallas.flash_prefill import flash_prefill_paged
-        qs, q_pos = gather_per_seq_queries(
-            q, batch["positions"], qtok_idx)
-        out_s = flash_prefill_paged(
-            qs, q_pos, k_cache, v_cache,
+        q_tiles, batch = gather_query_tiles(q, batch, F)
+        out_t = flash_prefill_paged(
+            q_tiles, batch["tile_pos"], k_cache, v_cache,
             batch["block_tables"], batch["seq_lens"],
             block_size=block_size, num_kv_heads=F // D,
             scale=scale, soft_cap=soft_cap, layer=layer,
-            k_scale=k_scale, v_scale=v_scale, window=window)
-        return _ret(out_s[batch["token_seq_ids"], batch["token_qpos"]],
+            k_scale=k_scale, v_scale=v_scale, window=window,
+            tile_seq=batch["tile_seq"])
+        return _ret(out_t[batch["tok_tile"], batch["tok_slot"]],
                     k_cache, v_cache, k_scale, v_scale)
     if backend in ("pallas", "chunked") and qtok_idx is not None:
         out = ragged_paged_attention_chunked(

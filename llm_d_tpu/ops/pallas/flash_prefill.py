@@ -4,14 +4,24 @@ The chunked XLA prefill path materializes [S, Q, KVH, G, kv_chunk] f32
 score tensors in HBM (~134 MB per (layer, q-chunk) at the 64x128 bench
 shape) and pays several elementwise passes over them — measured ~48% of the
 prefill step on v5e.  This kernel runs the flash recurrence entirely in
-VMEM: each grid program owns one sequence's q-tile, streams that sequence's
-KV pages through a double buffer (same DMA pattern as the decode kernel),
-and leaves only the tile's outputs in HBM.
+VMEM: each grid program owns one query tile (Qt query slots of ONE
+sequence), streams that sequence's KV pages through a double buffer (same
+DMA pattern as the decode kernel), and leaves only the tile's outputs in
+HBM.
+
+The grid walks a compact LIST of query tiles, not the padded [S bucket x
+Q bucket] rectangle: ``tile_seq[n]`` (scalar prefetch) names the sequence
+row of tile n, so a mixed step of 63 one-query decode rows and one prompt
+costs 63 + ceil(prompt / Qt) tiles and a few dead ones
+(``ops.attention.query_tiles``), not S * Q / Qt.  The rectangle call
+``flash_prefill_paged(qs [S, Q, H, D], q_pos [S, Q], ...)`` is the special
+case ``tile_seq = repeat(arange(S), Q / Qt)`` of the same body.
 
 Everything inside the kernel lives in the FUSED row space [Qt*H, *] (row
 r = query-slot r//H, head r%H), so there are no vector reshapes for Mosaic
-to reject: the wrapper pre-shapes queries to [S, Q*H, D] and positions to
-[S, Q*H, 1], and un-fuses the [S, Q*H, D] output outside the kernel.  GQA
+to reject: the wrapper pre-shapes queries to [NT, Qt*H, D] and un-fuses the
+[NT, Qt*H, D] output outside the kernel; the slots' positions arrive as
+NT*Qt scalars and are spread to the fused rows in VMEM.  GQA
 uses the zero-expansion trick (see paged_attention.py): queries fold to
 [Qt*H, KVH*D] with one nonzero D-block per head, scores for the whole tile
 come from ONE MXU dot per page, and values accumulate in folded space,
@@ -42,10 +52,12 @@ def _prefill_kernel(
     block_tables_ref,   # [S, B] SMEM
     seq_lens_ref,       # [S]    SMEM
     layer_ref,          # [1]    SMEM; [2] when ``windowed``: (layer, window)
+    tile_seq_ref,       # [NT]   SMEM: the sequence row of each query tile
+    tile_pos_ref,       # [NT*Qt] SMEM: position of each query slot (pad -1)
     # inputs / outputs / scratch — layout depends on ``quantized``:
-    #   bf16:  q, qpos, k_hbm, v_hbm | o | k_buf, v_buf, sems
-    #   int8:  q, qpos, k_hbm, v_hbm, ks_hbm, vs_hbm | o
-    #          | k_buf, v_buf, ks_buf, vs_buf, sems
+    #   bf16:  q, k_hbm, v_hbm | o | k_buf, v_buf, sems, qpos
+    #   int8:  q, k_hbm, v_hbm, ks_hbm, vs_hbm | o
+    #          | k_buf, v_buf, ks_buf, vs_buf, sems, qpos
     # (ks/vs are the [L, num_slots, SW] f32 per-page-row scale planes; the
     #  int8 pages are dequantized in VMEM right after the DMA — this kernel
     #  only READS the cache, the caller scattered rows + scales already.)
@@ -59,11 +71,11 @@ def _prefill_kernel(
     windowed: bool,
 ):
     if quantized:
-        (q_ref, qpos_ref, k_hbm, v_hbm, ks_hbm, vs_hbm,
-         o_ref, k_buf, v_buf, ks_buf, vs_buf, sems) = refs
+        (q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm,
+         o_ref, k_buf, v_buf, ks_buf, vs_buf, sems, qpos_buf) = refs
     else:
-        (q_ref, qpos_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems) = refs
-    s = pl.program_id(0)
+        (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, qpos_buf) = refs
+    s = tile_seq_ref[pl.program_id(0)]
     R, D = q_ref.shape[1], q_ref.shape[2]     # R = Qt * H
     H = num_heads
     KVH = num_kv_heads
@@ -73,7 +85,7 @@ def _prefill_kernel(
     li = layer_ref[0]
     seq_len = seq_lens_ref[s]
 
-    q_pos = qpos_ref[0]                                       # [R, 1] i32
+    q_pos = slot_positions(tile_pos_ref, qpos_buf, H)         # [R, 1] i32
     qmax = jnp.max(q_pos)
     # Causal bound: keys at positions > qmax never score for this tile.
     live = jnp.minimum(seq_len, qmax + 1)
@@ -181,33 +193,79 @@ def _prefill_kernel(
     o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _pick_q_tile(Q: int, H: int, F: int, budget: int = 8 << 20) -> int:
-    """Largest DIVISOR of Q whose per-program VMEM fits the budget
-    (divisor search, not halving: Q buckets can be non-powers-of-two when
-    ``--max-num-batched-tokens`` clamps them, and an odd-but-oversized
-    tile would fail Mosaic compilation).
+def slot_positions(tile_pos_ref, qpos_buf, num_heads: int):
+    """This tile's query positions as the [Qt*H, 1] column of the fused row
+    space, spread from its Qt scalars in SMEM ([NT*Qt], flat: a second
+    dimension would lane-pad there) through a VMEM scratch.  (As an input
+    block the column lane-pads 128-fold: [NT, Qt*H, 1] i32 was 64 MB a
+    layer at 128 tiles of 32 slots x 32 heads.)"""
+    q_tile = qpos_buf.shape[0] // num_heads
+    first = pl.program_id(0) * q_tile
+    for j in range(q_tile):
+        qpos_buf[j * num_heads:(j + 1) * num_heads, :] = jnp.full(
+            (num_heads, 1), tile_pos_ref[first + j], jnp.int32)
+    return qpos_buf[...]
 
-    Per fused row (Qt*H rows): the f32 accumulator + zero-expanded query
-    pair (8*F bytes) PLUS the blocks whose minor dim lane-pads to 128 —
-    the [rows, 1] i32 position column, the [rows, D] q/out blocks (double
-    buffered) and the [rows, block_size] f32 score/probability pair.  The
-    padded terms dominate when F is small (a tp shard's F = KVH*D/tp):
-    leaving them out let a 4096-row tile through at F=128, which the v5e
-    compiler refused (16.17 MB of scoped VMEM)."""
-    per_row = 8 * F + 3072
-    best = 1
-    for qt in range(1, Q + 1):
-        if Q % qt == 0 and qt * H * per_row <= budget:
-            best = qt
-    return best
+
+def rectangle_as_tiles(qs, q_pos, q_tile: int):
+    """The padded rectangle (``qs`` [S, Q, H, D], ``q_pos`` [S, Q]) as the
+    tile list that holds all of it: every row cut into ceil(Q / q_tile)
+    tiles (its last padded up), ``tile_seq = repeat(arange(S), ...)``."""
+    S, Q = q_pos.shape
+    per_row = -(-Q // q_tile)
+    pad = per_row * q_tile - Q
+    tiles = jnp.pad(qs, ((0, 0), (0, pad), (0, 0), (0, 0))).reshape(
+        S * per_row, q_tile, *qs.shape[2:])
+    tile_pos = jnp.pad(q_pos, ((0, 0), (0, pad)), constant_values=-1).reshape(
+        S * per_row, q_tile)
+    return tiles, tile_pos, jnp.repeat(
+        jnp.arange(S, dtype=jnp.int32), per_row)
+
+
+def pick_q_tile(Q: int, H: int, row_bytes: int, budget: int) -> int:
+    """Query slots a tile holds, a function of the step's query bucket Q
+    and of what one shard's kernel sees (heads, VMEM bytes a fused row):
+
+      - at most the largest power of two whose Qt*H fused rows fit the VMEM
+        ``budget``, and at most Q (a step whose query bucket is small,
+        ``--spec-k``'s k + 1 slots a row, gets no larger tile than its rows
+        can fill);
+      - under that bound, the longest row's Q slots in 64 tiles, but no
+        fewer than 256 fused rows a tile.  A page costs a tile about
+        0.27 us + 3.3 ns a fused row (v5e, PERF.md PR 28), whether the rows
+        hold queries or padding: a one-query decode row of a mixed step
+        pays for the whole tile, a prompt cut into twice the tiles pays
+        7-11 % more.  With 63 decode rows beside a 400-token prompt 8 slots
+        took 2.3 ms where 16 took 3.8 (H = 32, MLA) and 1.9 where 32 took
+        5.7 (GQA); a 2,048-token chunk alone wants the largest tile.
+
+    The tile list needs no divisor of Q."""
+    qt = 1
+    while 2 * qt * H * row_bytes <= budget:
+        qt *= 2
+    return max(1, min(qt, max(Q // 64, 256 // H), Q))
+
+
+def _pick_q_tile(Q: int, H: int, F: int, budget: int = 8 << 20) -> int:
+    """``pick_q_tile`` with this kernel's VMEM bytes per fused row (Qt*H
+    rows): the f32 accumulator + zero-expanded query pair (8*F bytes) PLUS
+    the blocks whose minor dim lane-pads to 128 — the [rows, 1] i32
+    position column, the [rows, D] q/out blocks (double buffered) and the
+    [rows, block_size] f32 score/probability pair.  The padded terms
+    dominate when F is small (a tp shard's F = KVH*D/tp): leaving them out
+    let a 4096-row tile through at F=128, which the v5e compiler refused
+    (16.17 MB of scoped VMEM)."""
+    return pick_q_tile(Q, H, 8 * F + 3072, budget)
 
 
 @functools.partial(
     jax.jit, static_argnames=("block_size", "num_kv_heads", "scale",
                               "soft_cap", "interpret", "q_tile"))
 def flash_prefill_paged(
-    qs: jax.Array,            # [S, Q, H, D] per-seq padded queries
-    q_pos: jax.Array,         # [S, Q] i32 absolute positions (pad -> -1)
+    qs: jax.Array,            # [S, Q, H, D] per-seq padded queries, or
+                              # [NT, Qt, H, D] query tiles with ``tile_seq``
+    q_pos: jax.Array,         # [S, Q] / [NT, Qt] i32 absolute positions
+                              # (pad -> -1)
     k_cache: jax.Array,       # [L, num_slots, KVH*D] (or [num_slots, KVH*D])
     v_cache: jax.Array,
     block_tables: jax.Array,  # [S, B]
@@ -223,12 +281,35 @@ def flash_prefill_paged(
     v_scale: jax.Array | None = None,   # scale planes (per page row)
     window: jax.Array | None = None,    # i32 scalar: keys a query sees
                                         # (itself included); None = all
+    tile_seq: jax.Array | None = None,  # [NT] i32: the row of block_tables /
+                                        # seq_lens each query tile belongs to
 ):
-    """Returns attention outputs [S, Q, H, D] (caches already written —
-    int8 caches with their scale planes scattered by the caller)."""
-    S, Q, H, D = qs.shape
+    """Attention outputs in the layout of ``qs`` (caches already written —
+    int8 caches with their scale planes scattered by the caller).
+
+    With ``tile_seq`` the queries are the step's compact tile list
+    (``ops.attention.gather_query_tiles``): all real slots of a tile belong
+    to row ``tile_seq[n]``.  Without it they are the [S, Q] rectangle, cut
+    here into ``q_tile`` slots a tile (rows padded up to a multiple)."""
+    F = k_cache.shape[-1]
+    if tile_seq is None:
+        S, Q, H, D = qs.shape
+        tiles, tile_pos, tile_seq = rectangle_as_tiles(
+            qs, q_pos, q_tile if q_tile is not None
+            else _pick_q_tile(Q, H, F))
+        out = flash_prefill_paged(
+            tiles, tile_pos, k_cache, v_cache, block_tables, seq_lens,
+            block_size=block_size, num_kv_heads=num_kv_heads, scale=scale,
+            soft_cap=soft_cap, layer=layer, interpret=interpret,
+            k_scale=k_scale, v_scale=v_scale, window=window,
+            tile_seq=tile_seq)
+        return out.reshape(S, -1, H, D)[:, :Q]
+    NT, Qt, H, D = qs.shape
     scale = scale if scale is not None else D ** -0.5
     quantized = k_scale is not None
+    if quantized and block_size % 32:
+        raise ValueError(f"an int8 cache packs 32 rows a sublane tile: "
+                         f"block_size {block_size} is no multiple of 32")
     squeeze = k_cache.ndim == 2
     if squeeze:
         k_cache = k_cache[None]
@@ -236,24 +317,17 @@ def flash_prefill_paged(
         if quantized:
             k_scale = k_scale[None]
             v_scale = v_scale[None]
-    F = k_cache.shape[2]
     SW = k_scale.shape[2] if quantized else 0
-    Qt = q_tile if q_tile is not None else _pick_q_tile(Q, H, F)
-    if Q % Qt:
-        raise ValueError(f"q_tile={Qt} must divide Q={Q}")
     layer_arr = jnp.asarray([0 if layer is None else layer]
                             + ([] if window is None else [window]), jnp.int32)
 
     # Fused row space (slot-major, head-minor), shaped OUTSIDE the kernel so
     # Mosaic never sees a vector reshape.
-    q_fused = qs.reshape(S, Q * H, D)
-    qpos_fused = jnp.repeat(q_pos, H, axis=1)[..., None]      # [S, Q*H, 1]
+    q_fused = qs.reshape(NT, Qt * H, D)
 
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [
-        pl.BlockSpec((1, Qt * H, D), lambda s, t, *_: (s, t, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, Qt * H, 1), lambda s, t, *_: (s, t, 0),
+        pl.BlockSpec((1, Qt * H, D), lambda n, *_: (n, 0, 0),
                      memory_space=pltpu.VMEM),
         any_spec, any_spec,
     ] + ([any_spec, any_spec] if quantized else [])
@@ -265,12 +339,13 @@ def flash_prefill_paged(
         scratch += [pltpu.VMEM((2, block_size, SW), jnp.float32),
                     pltpu.VMEM((2, block_size, SW), jnp.float32)]
     scratch.append(pltpu.SemaphoreType.DMA((2, 4 if quantized else 2)))
+    scratch.append(pltpu.VMEM((Qt * H, 1), jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(S, Q // Qt),
+        num_scalar_prefetch=5,
+        grid=(NT,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, Qt * H, D), lambda s, t, *_: (s, t, 0),
+            pl.BlockSpec((1, Qt * H, D), lambda n, *_: (n, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         scratch_shapes=scratch,
@@ -279,16 +354,16 @@ def flash_prefill_paged(
         _prefill_kernel, block_size=block_size, num_heads=H,
         num_kv_heads=num_kv_heads, scale=scale, soft_cap=soft_cap,
         quantized=quantized, windowed=window is not None)
-    operands = [block_tables, seq_lens, layer_arr, q_fused, qpos_fused,
-                k_cache, v_cache]
+    operands = [block_tables, seq_lens, layer_arr, tile_seq,
+                q_pos.reshape(-1), q_fused, k_cache, v_cache]
     if quantized:
         operands += [k_scale, v_scale]
     (out,) = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((S, Q * H, D), qs.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((NT, Qt * H, D), qs.dtype)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*operands)
-    return out.reshape(S, Q, H, D)
+    return out.reshape(NT, Qt, H, D)

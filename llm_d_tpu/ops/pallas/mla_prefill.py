@@ -19,7 +19,13 @@ This kernel runs the flash recurrence in VMEM like
     half the DMA traffic of reusing the dense prefill kernel with
     v_cache aliased to k_cache.
 
-Causality bounds the page loop per tile; pad query rows carry position
+The grid walks the step's compact LIST of query tiles (Qt query slots of
+one sequence each; ``tile_seq[n]`` names tile n's sequence row), not the
+padded [S bucket x Q bucket] rectangle: see ``flash_prefill.py``.  The
+rectangle call ``mla_flash_prefill(qs [S, Q, H, F], q_pos [S, Q], ...)`` is
+the special case ``tile_seq = repeat(arange(S), Q / Qt)`` of the same body.
+
+Causality bounds the page loop per tile; pad query slots carry position
 -1 and produce zeros.  KV rows for the tokens being computed are
 scattered by the caller (write_kv) BEFORE the kernel runs — read-only,
 no aliasing contract.
@@ -41,6 +47,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from llm_d_tpu.ops.pallas.flash_prefill import (
+    pick_q_tile, rectangle_as_tiles, slot_positions)
 from llm_d_tpu.ops.pallas.quant_util import make_page_dequant
 
 NEG_INF = -1e30
@@ -51,25 +59,28 @@ def _mla_prefill_kernel(
     block_tables_ref,   # [S, B] SMEM
     seq_lens_ref,       # [S]    SMEM
     layer_ref,          # [1]    SMEM
+    tile_seq_ref,       # [NT]   SMEM: the sequence row of each query tile
+    tile_pos_ref,       # [NT*Qt] SMEM: position of each query slot (pad -1)
     # inputs / outputs / scratch — layout depends on ``quantized``:
-    #   bf16: q, qpos, kv_hbm | o | kv_buf, sems
-    #   int8: q, qpos, kv_hbm, ks_hbm | o | kv_buf, ks_buf, sems
+    #   bf16: q, kv_hbm | o | kv_buf, sems, qpos
+    #   int8: q, kv_hbm, ks_hbm | o | kv_buf, ks_buf, sems, qpos
     *refs,
     block_size: int,
+    num_heads: int,
     scale: float,
     quantized: bool,
 ):
     if quantized:
-        (q_ref, qpos_ref, kv_hbm, ks_hbm,
-         o_ref, kv_buf, ks_buf, sems) = refs
+        (q_ref, kv_hbm, ks_hbm,
+         o_ref, kv_buf, ks_buf, sems, qpos_buf) = refs
     else:
-        (q_ref, qpos_ref, kv_hbm, o_ref, kv_buf, sems) = refs
-    s = pl.program_id(0)
+        (q_ref, kv_hbm, o_ref, kv_buf, sems, qpos_buf) = refs
+    s = tile_seq_ref[pl.program_id(0)]
     bs = block_size
     li = layer_ref[0]
     seq_len = seq_lens_ref[s]
 
-    q_pos = qpos_ref[0]                                       # [R, 1] i32
+    q_pos = slot_positions(tile_pos_ref, qpos_buf, num_heads)  # [R, 1] i32
     qmax = jnp.max(q_pos)
     # Causal bound: keys at positions > qmax never score for this tile.
     live = jnp.minimum(seq_len, qmax + 1)
@@ -143,27 +154,21 @@ def _mla_prefill_kernel(
 
 
 def _pick_q_tile(Q: int, H: int, F: int, budget: int = 3 << 20) -> int:
-    """Largest DIVISOR of Q whose f32 accumulator + query pair fits the
-    VMEM budget (~3 MB — tighter than the dense prefill's 6 MB: the MLA
-    row F is wide, 640 for V3, and at the bench shape H=16/F=640 a 6 MB
-    tile put the scoped stack 0.4 MB over the 16 MB limit).
-
-    Divisor search, not halving: Q buckets can be non-powers-of-two
-    (``--max-num-batched-tokens`` clamps the bucket), and stopping at an
-    odd qt that is still 10x over budget would fail Mosaic compilation at
-    serve time."""
-    best = 1
-    for qt in range(1, Q + 1):
-        if Q % qt == 0 and qt * H * F * 8 <= budget:
-            best = qt
-    return best
+    """``flash_prefill.pick_q_tile`` with this kernel's VMEM bytes per
+    fused row, the f32 accumulator + query pair, under a budget of ~3 MB
+    (tighter than the dense prefill's: the MLA row F is wide, 640 for V3,
+    and at the bench shape H=16/F=640 a 6 MB tile put the scoped stack
+    0.4 MB over the 16 MB limit)."""
+    return pick_q_tile(Q, H, 8 * F, budget)
 
 
 @functools.partial(
     jax.jit, static_argnames=("block_size", "scale", "interpret", "q_tile"))
 def mla_flash_prefill(
-    qs: jax.Array,            # [S, Q, H, F] per-seq padded absorbed queries
-    q_pos: jax.Array,         # [S, Q] i32 absolute positions (pad -> -1)
+    qs: jax.Array,            # [S, Q, H, F] per-seq padded absorbed queries,
+                              # or [NT, Qt, H, F] query tiles with ``tile_seq``
+    q_pos: jax.Array,         # [S, Q] / [NT, Qt] i32 absolute positions
+                              # (pad -> -1)
     kv_cache: jax.Array,      # [L, num_slots, F] (or [num_slots, F])
     block_tables: jax.Array,  # [S, B]
     seq_lens: jax.Array,      # [S]
@@ -173,14 +178,35 @@ def mla_flash_prefill(
     interpret: bool = False,
     q_tile: int | None = None,
     kv_scale: jax.Array | None = None,   # int8 latent: [L, slots, SW] f32
+    tile_seq: jax.Array | None = None,   # [NT] i32: the row of block_tables /
+                                         # seq_lens each query tile belongs to
 ):
-    """Returns attended latent rows [S, Q, H, F] (cache already written —
-    including, for the int8 latent, the new rows' scales in ``kv_scale``).
+    """Attended latent rows in the layout of ``qs`` (cache already written
+    — including, for the int8 latent, the new rows' scales in
+    ``kv_scale``).
+
+    With ``tile_seq`` the queries are the step's compact tile list
+    (``ops.attention.gather_query_tiles``): all real slots of a tile belong
+    to row ``tile_seq[n]``.  Without it they are the [S, Q] rectangle, cut
+    here into ``q_tile`` slots a tile (rows padded up to a multiple).
 
     The caller slices the first ``kv_lora_rank`` columns (attended values)
     and absorbs W_uv, exactly as with the chunked path."""
-    S, Q, H, F = qs.shape
+    if tile_seq is None:
+        S, Q, H, F = qs.shape
+        tiles, tile_pos, tile_seq = rectangle_as_tiles(
+            qs, q_pos, q_tile if q_tile is not None
+            else _pick_q_tile(Q, H, F))
+        out = mla_flash_prefill(
+            tiles, tile_pos, kv_cache, block_tables, seq_lens,
+            block_size=block_size, scale=scale, layer=layer,
+            interpret=interpret, kv_scale=kv_scale, tile_seq=tile_seq)
+        return out.reshape(S, -1, H, F)[:, :Q]
+    NT, Qt, H, F = qs.shape
     quantized = kv_scale is not None
+    if quantized and block_size % 32:
+        raise ValueError(f"an int8 cache packs 32 rows a sublane tile: "
+                         f"block_size {block_size} is no multiple of 32")
     squeeze = kv_cache.ndim == 2
     if squeeze:
         kv_cache = kv_cache[None]
@@ -188,20 +214,14 @@ def mla_flash_prefill(
             kv_scale = kv_scale[None]
     assert kv_cache.shape[2] == F, (kv_cache.shape, F)
     SW = kv_scale.shape[2] if quantized else 0
-    Qt = q_tile if q_tile is not None else _pick_q_tile(Q, H, F)
-    if Q % Qt:
-        raise ValueError(f"q_tile={Qt} must divide Q={Q}")
     layer_arr = jnp.asarray([0 if layer is None else layer], jnp.int32)
 
     # Fused row space (slot-major, head-minor), shaped OUTSIDE the kernel so
     # Mosaic never sees a vector reshape.
-    q_fused = qs.reshape(S, Q * H, F)
-    qpos_fused = jnp.repeat(q_pos, H, axis=1)[..., None]      # [S, Q*H, 1]
+    q_fused = qs.reshape(NT, Qt * H, F)
 
     in_specs = [
-        pl.BlockSpec((1, Qt * H, F), lambda s, t, *_: (s, t, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, Qt * H, 1), lambda s, t, *_: (s, t, 0),
+        pl.BlockSpec((1, Qt * H, F), lambda n, *_: (n, 0, 0),
                      memory_space=pltpu.VMEM),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
@@ -211,29 +231,30 @@ def mla_flash_prefill(
     if quantized:
         scratch.append(pltpu.VMEM((2, block_size, SW), jnp.float32))
     scratch.append(pltpu.SemaphoreType.DMA((2, 2 if quantized else 1)))
+    scratch.append(pltpu.VMEM((Qt * H, 1), jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(S, Q // Qt),
+        num_scalar_prefetch=5,
+        grid=(NT,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, Qt * H, F), lambda s, t, *_: (s, t, 0),
+            pl.BlockSpec((1, Qt * H, F), lambda n, *_: (n, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         scratch_shapes=scratch,
     )
     kernel = functools.partial(
-        _mla_prefill_kernel, block_size=block_size, scale=scale,
+        _mla_prefill_kernel, block_size=block_size, num_heads=H, scale=scale,
         quantized=quantized)
-    operands = [block_tables, seq_lens, layer_arr, q_fused, qpos_fused,
-                kv_cache]
+    operands = [block_tables, seq_lens, layer_arr, tile_seq,
+                q_pos.reshape(-1), q_fused, kv_cache]
     if quantized:
         operands.append(kv_scale)
     (out,) = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((S, Q * H, F), qs.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((NT, Qt * H, F), qs.dtype)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*operands)
-    return out.reshape(S, Q, H, F)
+    return out.reshape(NT, Qt, H, F)

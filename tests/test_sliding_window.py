@@ -366,8 +366,14 @@ def test_step_span_carries_the_counts_and_shapes_precompile():
         before = req.num_computed_tokens
         engine.step()
         ends.append(req.num_computed_tokens)
-        assert engine._step_kv == _by_hand(
-            SWA, [ends[-1]], [ends[-1] - before])
+        new = ends[-1] - before
+        kv = dict(engine._step_kv)
+        # A step with prefill tokens also says how full its attention grid
+        # is; off the chip that grid is the [S, Q] rectangle of its bucket.
+        grid = (kv.pop("attn_q_real", None), kv.pop("attn_q_slots", None))
+        assert grid == {64: (64, 4 * 64), 22: (22, 4 * 32),
+                        1: (None, None)}[new]
+        assert kv == _by_hand(SWA, [ends[-1]], [new])
     assert ends == [64, 128, 150, 151, 152]
     # Serving compiled nothing that the start had not.
     assert engine._step_fn._cache_size() == len(shapes)

@@ -151,6 +151,37 @@ def _mla_prefill(H, F=640, S=8, Q=256, B=64, L=16, sw=0):
     return fn, args
 
 
+def _prefill_tiles(H, KVH, D, T, S, Q, B=64, L=16, window=False,
+                   mla=False):
+    """Either prefill kernel over a step's query TILE LIST, as the step
+    programs call it: ``ceil(T / Qt) + S`` tiles of the ``Qt`` slots the
+    kernel family picks for (Q, H, F).  ``fn.hlo_name``: the name the
+    benchmark's readers find the kernel by in a trace."""
+    from llm_d_tpu.ops import attention as A
+    from llm_d_tpu.ops.pallas.flash_prefill import flash_prefill_paged
+    from llm_d_tpu.ops.pallas.mla_prefill import mla_flash_prefill
+    F = KVH * D
+    qt = A.prefill_q_tile(Q, H, F, mla)
+    NT = A.num_query_tiles(T, S, qt)
+
+    def fn(qs, qp, ts, kc, vc, bt, sl, layer):
+        if mla:
+            return mla_flash_prefill(qs, qp, kc, bt, sl, block_size=BS,
+                                     scale=0.07, layer=layer, tile_seq=ts)
+        kw = {"window": layer + 2048} if window else {}
+        return flash_prefill_paged(qs, qp, kc, vc, bt, sl, block_size=BS,
+                                   num_kv_heads=KVH, layer=layer,
+                                   tile_seq=ts, **kw)
+
+    fn.hlo_name = "mla_flash_prefill" if mla else "flash_prefill_paged"
+    return fn, [_sds((NT, qt, H, D), jnp.bfloat16), _sds((NT, qt), jnp.int32),
+                _sds((NT,), jnp.int32),
+                _sds((L, SLOTS, F), jnp.bfloat16),
+                _sds((L, SLOTS, F), jnp.bfloat16),
+                _sds((S, B), jnp.int32), _sds((S,), jnp.int32),
+                _sds((), jnp.int32)]
+
+
 def _moe(path, T, H=2048, I=512, E=64, k=8, Lm=15):
     """One of ops/moe.py's ``_*_int8_kernel_path`` wrappers — the exact
     glue the TPU branch of ``expert_ffn`` calls (the branch itself asks
@@ -252,6 +283,27 @@ CASES = [
     pytest.param(functools.partial(_moe, "grouped", 2048, I=1024, E=128,
                                    Lm=6),
                  id="grouped_moe_int8-T2048-I1024-E128"),
+    # The query tile list at the shapes the benchmark's cells serve (token
+    # bucket / sequence bucket): kanana-2-30b-a3b's MLA latent row (192
+    # tiles of 8 slots; 192 of 16 for a 2,048-token chunk), trinity-mini's
+    # windowed layers at 32768 context (72 tiles of 32), qwen3-30b-a3b at a
+    # full sequence bucket (128 of 32), and a tp shard's 8 heads (TP = 4:
+    # 96 tiles of 32).
+    pytest.param(functools.partial(_prefill_tiles, 32, 1, 640, T=1024, S=64,
+                                   Q=512, L=9, mla=True),
+                 id="mla_prefill-tiles-kanana-T1024-S64"),
+    pytest.param(functools.partial(_prefill_tiles, 32, 1, 640, T=2048, S=64,
+                                   Q=2048, L=9, mla=True),
+                 id="mla_prefill-tiles-kanana-T2048-S64"),
+    pytest.param(functools.partial(_prefill_tiles, 32, 4, 128, T=2048, S=8,
+                                   Q=2048, B=1024, L=8, window=True),
+                 id="flash_prefill-tiles-trinity-window-T2048-S8"),
+    pytest.param(functools.partial(_prefill_tiles, 32, 4, 128, T=2048, S=64,
+                                   Q=2048, B=128, L=8),
+                 id="flash_prefill-tiles-qwen3-T2048-S64"),
+    pytest.param(functools.partial(_prefill_tiles, 8, 1, 640, T=1024, S=64,
+                                   Q=512, L=9, mla=True),
+                 id="mla_prefill-tiles-tp4-T1024-S64"),
     # int8 KV / latent caches: refused (see _SCALE_DMA).
     pytest.param(functools.partial(_dense_decode, 32, 8, 64, sw=1),
                  id="paged_decode-int8-token", marks=_xfail(_SCALE_DMA)),
@@ -271,5 +323,6 @@ def test_kernel_compiles_for_v5e(build, one_chip, no_compile_cache):
     fn, args = build()
     args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
             for a in args]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert f"%{getattr(fn, 'hlo_name', '')}" in text
