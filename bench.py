@@ -46,8 +46,8 @@ from llm_d_tpu.ops.sampling import SamplingParams
 
 BASELINE_TOK_S_PER_CHIP = 2200.0
 # Round-5 verdict bar: MoE decode must reach this share of its own HBM
-# roofline at bs256 (the yield target the int8 latent + weight-DMA overlap
-# exist to clear; 36.9% measured pre-int8-latent).
+# roofline at bs256 (the yield target the weight-DMA overlap exists to
+# clear; 36.9% measured in round 5).
 MOE_ROOFLINE_TARGET_PCT = 55.0
 
 # (bf16 peak FLOP/s, HBM bytes/s) per chip, keyed by a lower-cased
@@ -158,8 +158,7 @@ def _make_reqs(tag, n, prompt_len, decode_steps, offset):
 
 
 def bench_model(model: str, batch_sizes, prompt_len=128, decode_steps=128,
-                quantization=None, repeats=None, stub=(),
-                kv_cache_dtype=None):
+                quantization=None, repeats=None, stub=()):
     """One engine, a workload per batch size (warmup + timed).  Returns
     {bs: {prefill_tok_s, decode_tok_s, ...}} plus roofline attribution.
 
@@ -167,10 +166,7 @@ def bench_model(model: str, batch_sizes, prompt_len=128, decode_steps=128,
     headline numbers use median-of-N with a printed min/max band so the
     regression gate can tell a real drop from the chip's measured ±4-6%
     run-to-run variance (VERDICT r5 #4).  ``stub`` drops components from
-    the compiled program for the attribution harness (--stub).
-    ``kv_cache_dtype`` ("bf16"/"int8") sets the paged-cache dtype — the
-    roofline's KV byte term and the reported ``kv_bytes_per_step`` follow
-    it (int8 halves the stream; scale planes are counted)."""
+    the compiled program for the attribution harness (--stub)."""
     max_bs = max(batch_sizes)
     # KV sized to the workload + slack: expert weights take most of the
     # chip's 16 GB, so a fixed large pool OOMs the MoE run.
@@ -190,7 +186,6 @@ def bench_model(model: str, batch_sizes, prompt_len=128, decode_steps=128,
         # removes any chance the warmup pass warms more than the compiles.
         enable_prefix_caching=False,
         quantization=quantization,
-        kv_cache_dtype=kv_cache_dtype,
         stub_components=tuple(stub),
     )
     engine = EngineCore(cfg)
@@ -239,8 +234,7 @@ def bench_model(model: str, batch_sizes, prompt_len=128, decode_steps=128,
         step_bytes = param_bytes - embed_bytes + kv_bytes_per_step
         roofline_tok_s = hbm_bw / step_bytes * bs
         out[bs] = {
-            # The KV byte stream one decode step reads at avg context —
-            # the component kv_cache_dtype=int8 exists to halve.
+            # The KV byte stream one decode step reads at avg context.
             "kv_bytes_per_step": kv_bytes_per_step,
             "prefill_tok_s": round(prefill_tok_s, 1),
             "decode_tok_s": round(decode_tok_s, 1),
@@ -278,7 +272,6 @@ def bench_model(model: str, batch_sizes, prompt_len=128, decode_steps=128,
                 100 * (max(prefill_runs) - min(prefill_runs))
                 / max(prefill_tok_s, 1e-9), 1)
     out["param_bytes"] = param_bytes
-    out["kv_cache_dtype"] = engine.kv_cache_dtype
     out["kv_bytes_per_token_layer"] = kv_row
     out["num_blocks"] = engine.config.num_blocks
     return out
@@ -297,8 +290,7 @@ SPEC_BENCH_ACCEPT = 0.7
 
 def bench_spec(model: str, bs: int, K: int, fixed_accept: float,
                prompt_len: int = 128, decode_steps: int = 128,
-               quantization=None, kv_cache_dtype=None,
-               repeats: int = 1) -> dict:
+               quantization=None, repeats: int = 1) -> dict:
     """Accepted tok/s through the draft-and-verify engine at a fixed
     seeded acceptance rate.
 
@@ -319,7 +311,6 @@ def bench_spec(model: str, bs: int, K: int, fixed_accept: float,
         num_scheduler_steps=1,          # spec owns the multi-token step
         enable_prefix_caching=False,
         quantization=quantization,
-        kv_cache_dtype=kv_cache_dtype,
         spec_k=K,
         spec_fixed_accept=fixed_accept,
     )
@@ -365,8 +356,7 @@ MIXED_BENCH_SHARE = 0.25
 
 def bench_mixed(model: str, bs: int, K: int, fixed_accept: float,
                 prompt_len: int = 128, decode_steps: int = 128,
-                quantization=None, kv_cache_dtype=None,
-                repeats: int = 1,
+                quantization=None, repeats: int = 1,
                 shares=(0.0, MIXED_BENCH_SHARE, 0.5)) -> dict:
     """Fused mixed-round throughput: a bs-wide spec-decode batch with
     prefill requests JOINING mid-decode (round 15).
@@ -391,7 +381,6 @@ def bench_mixed(model: str, bs: int, K: int, fixed_accept: float,
         num_scheduler_steps=1,          # spec owns the multi-token step
         enable_prefix_caching=False,
         quantization=quantization,
-        kv_cache_dtype=kv_cache_dtype,
         spec_k=K,
         spec_fixed_accept=fixed_accept,
     )
@@ -470,8 +459,7 @@ EVERYTHING_ROUNDS_SWEEP = (1, 2, 4, 8)
 
 def bench_everything_on(model: str, bs: int, K: int, fixed_accept: float,
                         prompt_len: int = 128, decode_steps: int = 128,
-                        quantization=None, kv_cache_dtype=None,
-                        repeats: int = 1,
+                        quantization=None, repeats: int = 1,
                         rounds_sweep=EVERYTHING_ROUNDS_SWEEP) -> tuple:
     """ACCEPTED tok/s with the whole round-16 composition on at once:
     spec decode + mixed fusion + fused multistep (num_scheduler_steps=N)
@@ -504,8 +492,7 @@ def bench_everything_on(model: str, bs: int, K: int, fixed_accept: float,
             enable_eplb=True,
             enable_prefix_caching=False,
             quantization=quantization,
-            kv_cache_dtype=kv_cache_dtype,
-            spec_k=K,
+                spec_k=K,
             spec_fixed_accept=fixed_accept,
         )
         engine = EngineCore(cfg)
@@ -562,8 +549,7 @@ EPLB_BENCH_ZIPF = 1.2
 
 def bench_eplb_skew(model: str, bs: int, K: int, fixed_accept: float,
                     prompt_len: int = 128, decode_steps: int = 128,
-                    quantization=None, kv_cache_dtype=None,
-                    repeats: int = 1) -> dict:
+                    quantization=None, repeats: int = 1) -> dict:
     """ACCEPTED tok/s with online EPLB live-migrating under a
     Zipf(EPLB_BENCH_ZIPF) routing skew.
 
@@ -592,7 +578,6 @@ def bench_eplb_skew(model: str, bs: int, K: int, fixed_accept: float,
         eplb_config={"window_size": 512, "step_interval": 32},
         enable_prefix_caching=False,
         quantization=quantization,
-        kv_cache_dtype=kv_cache_dtype,
         spec_k=K,
         spec_fixed_accept=fixed_accept,
     )
@@ -693,7 +678,7 @@ def _spec_acceptance_table(model: str, bs: int, fixed_accept: float,
     table = {}
     for K in k_sweep:
         row = bench_spec(model, bs, K, fixed_accept, decode_steps=64,
-                         quantization="int8", kv_cache_dtype="int8")[bs]
+                         quantization="int8")[bs]
         table[str(K)] = {
             "accepted_tok_s": row["decode_tok_s"],
             "spec_acceptance_pct": row["spec_acceptance_pct"],
@@ -766,9 +751,8 @@ def project_v5p256(measured_roofline_frac: float,
     other_bytes_chip = other_params * 2 / tp
     bs = decode_bs_per_chip
     # --- per-step HBM bytes/chip ---
-    # int8 latent cache (round 9): 1 B/value + one f32 scale per row —
-    # the same dtype the measured single-chip roofline fraction ran at.
-    kv_row = (kv_lora + rope) * 1 + 4
+    # bf16 latent cache: 2 B/value, the dtype the engine serves.
+    kv_row = (kv_lora + rope) * 2
     kv_bytes = bs * context_len * kv_row * L
     hbm_bytes = expert_bytes_chip + other_bytes_chip + kv_bytes
     t_hbm = hbm_bytes / HBM_BW
@@ -851,14 +835,14 @@ def v5p256_sensitivity(measured_roofline_frac: float,
             "bar_tok_s_chip": bar, "collective_dtype": collective_dtype}
 
 
-def _regression_gate(dense: dict, moe: dict, longctx: dict = None,
+def _regression_gate(dense: dict, moe: dict,
                      spec: dict = None, mixed: dict = None,
                      everything_on: dict = None,
                      eplb_skew: dict = None) -> dict:
-    """Band-aware regression gate over the FIVE headline metrics (two
-    decode, one prefill, one long-context int8-KV decode, one decode
-    roofline YIELD — prefill, KV-byte and yield regressions used to land
-    silently; the yield one could hide behind batch inflation).
+    """Band-aware regression gate over the headline metrics (two decode,
+    one prefill, one decode roofline YIELD — prefill and yield regressions
+    used to land silently; the yield one could hide behind batch
+    inflation).
 
     ``*_delta_pct`` is the MEDIAN's delta vs the best recorded number;
     ``*_regressed`` is True only when the run band's MAX is below it —
@@ -873,15 +857,10 @@ def _regression_gate(dense: dict, moe: dict, longctx: dict = None,
             # BENCH_r05 moe bs64 prefill (the 11.46%-MFU number the
             # streamed kernel exists to beat).
             ("moe_prefill_tok_s_bs64", moe, 64, "prefill", 17105.1),
-            # Long-context (ctx 2048) dense decode with the int8 KV cache:
-            # the regime where the KV stream dominates step bytes, so a
-            # quantization-path regression shows here first.  First chip
-            # run after the int8-KV PR records the best.
-            ("dense_longctx_int8_bs64", longctx or {}, 64, "decode", None),
             # MoE decode HBM-roofline YIELD at bs256 — first-class and
             # band-gated so a yield drop fails even when a bigger batch
-            # inflates raw tok/s (r5 measured 36.9% here pre-int8-latent;
-            # the round-9 target is >= 55%).
+            # inflates raw tok/s (r5 measured 36.9% here; the round-9
+            # target is >= 55%).
             ("moe_decode_roofline_bs256", moe, 256, "roofline", 36.9),
             # Speculative decode (round 12): ACCEPTED tok/s through the
             # MTP draft-and-verify engine at bs256, fixed seeded
@@ -999,23 +978,6 @@ def _wire_delta(measured_roofline_frac: float) -> dict:
     }
 
 
-def _kv_block_pool_table(budget_bytes: int = 4 << 30) -> dict:
-    """Capacity half of the int8-KV win: blocks a fixed HBM budget holds
-    per cache dtype (dense llama3-1b layout, block_size 64) — the larger
-    pool IS the larger max batch / longer max context at the same chip."""
-    from llm_d_tpu.engine.engine import derive_num_blocks
-    from llm_d_tpu.models import get_model
-    from llm_d_tpu.models.config import get_config
-    c = get_config("llama3-1b")
-    layout = get_model(c).kv_cache_layout(c)
-    bf16 = derive_num_blocks(budget_bytes, layout, c.num_layers, 64, "bf16")
-    int8 = derive_num_blocks(budget_bytes, layout, c.num_layers, 64,
-                             "int8", 1)
-    return {"budget_gb": round(budget_bytes / 2**30, 1),
-            "bf16_blocks": bf16, "int8_blocks": int8,
-            "ratio": round(int8 / bf16, 3)}
-
-
 # Components the attribution sweep stubs one at a time ("none" is the
 # unstubbed baseline the differences are taken against).
 STUB_COMPONENTS = ("attn", "moe_ffn", "shared_expert")
@@ -1127,7 +1089,7 @@ def main() -> None:
         sizes = [64, 256]
         stub = () if args.stub == "none" else (args.stub,)
         moe = bench_model("deepseek-v3-bench", sizes, quantization="int8",
-                          kv_cache_dtype="int8", stub=stub)
+                          stub=stub)
         print(json.dumps({
             "metric": "attribution_stub",
             "stub": args.stub,
@@ -1143,36 +1105,16 @@ def main() -> None:
     n = 1 if args.quick else max(1, args.gate_repeats)
 
     # bs64 repeats feed the prefill gate metric's band; bs256 the decode
-    # headline's AND the roofline-yield gate's.  The flagship MoE bench
-    # runs on the int8 LATENT cache (kv_cache_dtype=int8 + MLA, round 9):
-    # the latent stream is the only per-step byte term that grows with
-    # batch/context, and both the tok/s and the roofline it is judged
-    # against account the halved bytes.
+    # headline's AND the roofline-yield gate's.
     moe = bench_model("deepseek-v3-bench", moe_sizes, quantization="int8",
-                      kv_cache_dtype="int8", repeats={256: n, 64: n})
+                      repeats={256: n, 64: n})
     dense = bench_model("llama3-1b", dense_sizes, repeats={64: n})
-    # Long-context decode (ctx 2048, bs64) on the int8 KV cache — the
-    # regime where the KV stream dominates step bytes, so this is the
-    # gated canary for the kv_cache_dtype path — plus one bf16 point at
-    # the same shape so "no worse than bf16" and the ~2x kv_bytes_per_step
-    # reduction are visible side by side in extras.
-    # --quick skips the long-context pair entirely: the metric is
-    # band-gated (a single sample can't gate) and the ctx-2048 engine
-    # build + sweep would dominate the dev loop.
-    longctx_prompt, longctx_decode = 2048 - 128, 128
-    longctx_i8 = (None if args.quick else bench_model(
-        "llama3-1b", [64], prompt_len=longctx_prompt,
-        decode_steps=longctx_decode, kv_cache_dtype="int8",
-        repeats={64: n}))
-    longctx_bf = (None if args.quick else bench_model(
-        "llama3-1b", [64], prompt_len=longctx_prompt,
-        decode_steps=longctx_decode, kv_cache_dtype="bf16"))
     # Speculative decode (round 12): the gated accepted-tok/s point at
     # bs256 plus the per-K acceptance table.  --quick skips both (the
     # metric is band-gated; the table builds one engine per K).
     spec = (None if args.quick else bench_spec(
         "deepseek-v3-bench", 256, SPEC_BENCH_K, SPEC_BENCH_ACCEPT,
-        quantization="int8", kv_cache_dtype="int8", repeats=n))
+        quantization="int8", repeats=n))
     spec_table = (None if args.quick else _spec_acceptance_table(
         "deepseek-v3-bench", 256, SPEC_BENCH_ACCEPT))
     # Mixed-round fusion (round 15): the gated emitted-tok/s point at
@@ -1180,7 +1122,7 @@ def main() -> None:
     # table.  --quick skips it (band-gated; one engine, three shares).
     mixed = (None if args.quick else bench_mixed(
         "deepseek-v3-bench", 256, SPEC_BENCH_K, SPEC_BENCH_ACCEPT,
-        quantization="int8", kv_cache_dtype="int8", repeats=n))
+        quantization="int8", repeats=n))
     # Everything-on (round 16): the gated accepted-tok/s point at bs256
     # with the full composition (spec + mixed fusion + fused multistep +
     # async + EPLB) plus the rounds-per-dispatch sweep.  --quick skips
@@ -1189,14 +1131,14 @@ def main() -> None:
                        bench_everything_on(
                            "deepseek-v3-bench", 256, SPEC_BENCH_K,
                            SPEC_BENCH_ACCEPT, quantization="int8",
-                           kv_cache_dtype="int8", repeats=n))
+                           repeats=n))
     # Live EPLB under skew (round 17): the gated accepted-tok/s point
     # at bs256 with a real delta migration staged and flipped inside the
     # timed window.  --quick skips it (band-gated); the sim-backed
     # balanced-vs-static table is cheap and always included.
     eplb_skew = (None if args.quick else bench_eplb_skew(
         "deepseek-v3-bench", 256, SPEC_BENCH_K, SPEC_BENCH_ACCEPT,
-        quantization="int8", kv_cache_dtype="int8", repeats=n))
+        quantization="int8", repeats=n))
 
     best_bs = max(moe_sizes, key=lambda b: moe[b]["decode_tok_s"])
     headline = moe[best_bs]["decode_tok_s"]
@@ -1205,36 +1147,20 @@ def main() -> None:
         "backend": jax.default_backend(),
         "device_kind": getattr(jax.devices()[0], "device_kind", "?"),
         "moe_model": "deepseek-v3-bench (MLA + sigmoid top-8/64 + int8 "
-                     "experts + int8 latent cache, scaled DeepSeek-V3)",
+                     "experts, scaled DeepSeek-V3)",
         "moe_batch_size": best_bs,
         "decode_steps": 128,
         "moe_param_gb": round(moe["param_bytes"] / 1e9, 2),
         "moe_sweep": {str(b): moe[b] for b in moe_sizes},
         # The latent KV byte accounting the roofline divides by (per-row
         # sweep entries carry kv_bytes_per_step at each batch size):
-        # 576·1B payload (lane-padded to 640) + one f32 scale vs 576·2B.
+        # 576 values lane-padded to 640, 2 B each.
         "moe_latent": {
-            "kv_cache_dtype": moe["kv_cache_dtype"],
             "kv_bytes_per_token_layer": moe["kv_bytes_per_token_layer"],
         },
         "dense_model": "llama3-1b",
         "dense_param_gb": round(dense["param_bytes"] / 1e9, 2),
         "dense_sweep": {str(b): dense[b] for b in dense_sizes},
-        # int8 paged-KV cache: long-context decode side-by-side (the
-        # kv_bytes_per_step ratio is the HBM win; the block-pool table is
-        # the capacity win at a fixed 4 GiB budget).
-        "longctx_sweep": {
-            "context_len": longctx_prompt + longctx_decode,
-            "int8": (None if longctx_i8 is None else
-                     {"64": longctx_i8[64],
-                      "kv_bytes_per_token_layer":
-                          longctx_i8["kv_bytes_per_token_layer"]}),
-            "bf16": (None if longctx_bf is None else
-                     {"64": longctx_bf[64],
-                      "kv_bytes_per_token_layer":
-                          longctx_bf["kv_bytes_per_token_layer"]}),
-        },
-        "kv_block_pool": _kv_block_pool_table(),
         # Speculative decode: the gated bs256 point (accepted tok/s at
         # fixed seeded acceptance — every emitted token passed target
         # verification, so directly comparable to moe decode_tok_s) and
@@ -1309,8 +1235,8 @@ def main() -> None:
         # band.  A metric REGRESSES only when its whole band sits below
         # the best recorded number — a point sample inside the chip's
         # measured ±4-6% variance is noise, not a regression.
-        "regression_gate": _regression_gate(dense, moe, longctx_i8, spec,
-                                            mixed, eon, eplb_skew),
+        "regression_gate": _regression_gate(dense, moe, spec, mixed, eon,
+                                            eplb_skew),
     }
     result = {
         "metric": "decode_output_tok_s_per_chip_moe",

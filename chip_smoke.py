@@ -566,7 +566,6 @@ def server_phase(model: str, serve_args: Sequence[str],
 
 def moe_phase(model: str, n_seqs: int, prompt_len: int, max_tokens: int,
               seed: int, expect_kernels: bool,
-              latent_dtype: str = "bf16",
               cfg_overrides: Optional[Dict[str, Any]] = None,
               small_wave: int = 8, n_parity: int = 8,
               parity_ks: Sequence[int] = (0, 2, 4, 8, 12),
@@ -574,8 +573,7 @@ def moe_phase(model: str, n_seqs: int, prompt_len: int, max_tokens: int,
     from llm_d_tpu.engine.engine import EngineConfig, EngineCore
 
     cfg = EngineConfig(
-        model=model, quantization="int8", mla_latent_dtype=latent_dtype,
-        seed=seed, max_num_seqs=max(256, n_seqs),
+        model=model, quantization="int8", seed=seed, max_num_seqs=max(256, n_seqs),
         max_num_batched_tokens=2048, num_scheduler_steps=8,
         num_blocks=n_seqs * (-(-(prompt_len + max_tokens + 16) // 32)) + 64)
     cfg = dataclasses.replace(cfg, **(cfg_overrides or {}))
@@ -583,7 +581,7 @@ def moe_phase(model: str, n_seqs: int, prompt_len: int, max_tokens: int,
     engine = EngineCore(cfg)
     vocab = engine.model_config.vocab_size
     log(f"   engine built in {time.time() - t0:.1f}s: {model} int8 experts, "
-        f"{latent_dtype} latent, {cfg.num_blocks} blocks")
+        f"{cfg.num_blocks} blocks")
     recs = record_steps(engine)
     prompts = seeded_prompts(seed + 10, [prompt_len] * n_seqs, vocab)
 
@@ -773,22 +771,10 @@ def run_one_chip(seed: int) -> None:
                          200],
             max_tokens=64, seed=seed, expect_kernels=True)
     settle("server phase")
-    with phase("MoE + MLA phase [deepseek-v3-bench, int8 experts, bf16 "
-               "latent]"):
+    with phase("MoE + MLA phase [deepseek-v3-bench, int8 experts]"):
         moe_phase("deepseek-v3-bench", n_seqs=136, prompt_len=24,
                   max_tokens=64, seed=seed, expect_kernels=True)
     settle("MoE + MLA phase")
-    with phase("MoE + MLA phase [int8 latent]"):
-        try:
-            moe_phase("deepseek-v3-bench", n_seqs=136, prompt_len=24,
-                      max_tokens=64, seed=seed, expect_kernels=True,
-                      latent_dtype="int8")
-        except ValueError as e:
-            # Tier-3 exit of ISSUE 21: the engine refuses the int8-cache
-            # kernels on a TPU with the compiler's message.  Any OTHER
-            # failure (SmokeFailure is not a ValueError) still fails.
-            check("do not compile for TPU" in str(e), f"unexpected: {e}")
-            log(f"   SKIPPED int8-latent sub-phase — engine refused: {e}")
 
 
 def run_four_chips(seed: int) -> None:

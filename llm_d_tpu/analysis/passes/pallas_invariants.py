@@ -1,7 +1,7 @@
 """PAL: Pallas TPU kernel invariants.
 
-The hand-rolled DMA chains (PR 5/6: int8 page + scale-page streaming,
-the double-buffered expert-weight slabs) are the exact code where a
+The hand-rolled DMA chains (KV page streaming, the double-buffered
+int8 expert-weight slabs) are the exact code where a
 missing ``.wait()`` deadlocks a semaphore or races a slot overwrite, and
 where an int8 tiling that doesn't divide the page silently corrupts the
 byte splice.  These rules pin the structural invariants a numerics test

@@ -36,28 +36,12 @@ from llm_d_tpu.models.config import SLIDING, ModelConfig, get_config
 from llm_d_tpu.ops import sampling as sampling_ops
 from llm_d_tpu.parallel.mesh import MeshConfig, make_mesh
 from llm_d_tpu.parallel.sharding import logical_to_sharding, shard_pytree
-from llm_d_tpu.ops.quant import (
-    KV_CACHE_DTYPES, KV_SCALE_GRANULARITIES, MLA_LATENT_DTYPES,
-    kv_scale_width)
 from llm_d_tpu.utils import tracing
 from llm_d_tpu.utils.config import env_choice, env_float, env_int
 from llm_d_tpu.utils.faultinject import get_injector
 from llm_d_tpu.utils.metrics import EngineMetrics
 
 logger = logging.getLogger(__name__)
-
-# What the v5e compiler says to the four int8-cache Pallas kernels
-# (paged_attention_decode_update / flash_prefill_paged with k_scale,
-# mla_paged_decode_update / mla_flash_prefill with kv_scale): the
-# [block_size, SW] page DMA out of the [L, slots, SW] f32 scale plane
-# puts SW (1 or KVH) on the 128-lane axis.  Until the scale planes get a
-# lane-major layout (ROADMAP A10) an engine that would select them on a
-# TPU refuses to start; tests/test_tpu_compile.py holds the same message
-# as strict xfails.
-INT8_CACHE_KERNEL_REFUSAL = (
-    "Mosaic failed to compile TPU kernel: Slice shape along dimension 2 "
-    "must be aligned to tiling (128), but is 1 (resp. 8): the int8 KV / "
-    "MLA-latent cache's Pallas kernels do not compile for TPU")
 
 # Speculative-decode master modes (LLMD_SPEC_DECODE): "auto" = run the
 # draft+verify program whenever spec_k > 0, "off" = kill switch.
@@ -71,38 +55,19 @@ def _next_bucket(n: int, lo: int, hi: int) -> int:
     return min(b, hi)
 
 
-def kv_bytes_per_token(layout: Dict[str, int], kv_cache_dtype: str = "bf16",
-                       scale_width: int = 1) -> int:
-    """Bytes one token's KV costs per layer at a cache dtype: payload rows
-    plus, for int8, a per-page-row f32 scale column group (``scale_width``
-    columns per buffer).  The single source of the byte accounting shared
-    by pool sizing and the bench's roofline/kv_bytes_per_step terms."""
-    per = sum(layout.values()) * (1 if kv_cache_dtype == "int8" else 2)
-    if kv_cache_dtype == "int8":
-        per += len(layout) * scale_width * 4
-    return per
-
-
-def kv_block_bytes(layout: Dict[str, int], num_layers: int, block_size: int,
-                   kv_cache_dtype: str = "bf16", scale_width: int = 1) -> int:
-    """HBM bytes one KV block costs across all layers and cache buffers —
-    the int8 scale overhead is what keeps the capacity gain at ~1.95x
-    rather than exactly 2x."""
-    return num_layers * block_size * kv_bytes_per_token(
-        layout, kv_cache_dtype, scale_width)
+def kv_bytes_per_token(layout: Dict[str, int]) -> int:
+    """Bytes one token's KV costs per layer: the bf16 rows of every cache
+    buffer.  The single source of the byte accounting shared by pool sizing
+    and the bench's roofline/kv_bytes_per_step terms."""
+    return sum(layout.values()) * 2
 
 
 def derive_num_blocks(hbm_budget_bytes: int, layout: Dict[str, int],
-                      num_layers: int, block_size: int,
-                      kv_cache_dtype: str = "bf16",
-                      scale_width: int = 1) -> int:
-    """Dtype-aware block-pool sizing: how many paged-KV blocks fit a fixed
-    HBM budget.  The int8 cache roughly DOUBLES the pool at the same budget
-    (same chip serves ~2x the batch or context), which is the capacity half
-    of the kv_cache_dtype=int8 win alongside the halved decode DMA bytes."""
-    per_block = kv_block_bytes(layout, num_layers, block_size,
-                               kv_cache_dtype, scale_width)
-    return max(hbm_budget_bytes // per_block, 2)
+                      num_layers: int, block_size: int) -> int:
+    """Block-pool sizing: how many paged-KV blocks (one block's rows across
+    all layers and cache buffers) fit a fixed HBM budget."""
+    block_bytes = num_layers * block_size * kv_bytes_per_token(layout)
+    return max(hbm_budget_bytes // block_bytes, 2)
 
 
 @dataclasses.dataclass
@@ -155,24 +120,8 @@ class EngineConfig:
     kv_shared_tier_peers: Tuple[str, ...] = ()  # "host:port" peer servers
     # MoE expert-weight quantization (DeepGEMM role; "int8" or None).
     quantization: Optional[str] = None
-    # Paged-KV cache dtype: "bf16" (classic) or "int8" (per-page-row-scaled
-    # payloads + f32 scale planes — halves decode HBM/DMA bytes, ~doubles
-    # the block pool at the same budget, halves P->D and offload payloads).
-    # None resolves LLMD_KV_CACHE_DTYPE (default bf16) at engine build.
-    kv_cache_dtype: Optional[str] = None
-    # int8 scale granularity: "token" (one f32 scale per cache row) or
-    # "head" (one per KV head's D-block — finer, shard-local under
-    # tp-sharded KV heads).  None resolves LLMD_KV_SCALE_GRAN.
-    kv_scale_granularity: Optional[str] = None
-    # MLA latent-row cache dtype gate, separate from the dense KV knob:
-    # "auto" (follow kv_cache_dtype — the default), "bf16" (pin the latent
-    # to bf16 even under kv_cache_dtype=int8 — the escape hatch if a
-    # model's absorption accuracy falls outside the tested bound) or
-    # "int8" (quantize the latent even when the config default is bf16).
-    # None resolves LLMD_MLA_LATENT_DTYPE.  Ignored for non-MLA models.
-    mla_latent_dtype: Optional[str] = None
-    # Auto-size the block pool from an HBM budget instead of num_blocks:
-    # dtype-aware (int8 fits ~2x the blocks), see derive_num_blocks.
+    # Auto-size the block pool from an HBM budget instead of num_blocks,
+    # see derive_num_blocks.
     kv_cache_hbm_bytes: Optional[int] = None
     # Perf-attribution harness (docs/perf-notes methodology): components
     # to STUB OUT of the step program so their cost can be measured by
@@ -231,49 +180,7 @@ class EngineCore:
         self.config = config
         self.model_config = config.resolve_model()
         c = self.model_config
-        # KV cache dtype: explicit config wins; None resolves the env knob
-        # (invalid ENV values fall back with a warning, an invalid EXPLICIT
-        # value is a misconfiguration and raises).
-        self.kv_cache_dtype = config.kv_cache_dtype or env_choice(
-            "LLMD_KV_CACHE_DTYPE", "bf16", KV_CACHE_DTYPES)
-        if self.kv_cache_dtype not in KV_CACHE_DTYPES:
-            raise ValueError(
-                f"unknown kv_cache_dtype {self.kv_cache_dtype!r} "
-                f"(choices: {KV_CACHE_DTYPES})")
-        if c.use_mla:
-            # The MLA latent row IS the whole cache (576 values/token vs
-            # 32768 materialized for V3), so its dtype gate resolves the
-            # effective kv_cache_dtype for the engine: "auto" follows the
-            # dense knob, "bf16"/"int8" pin the latent explicitly (the
-            # escape hatch / force lever around the absorption-accuracy
-            # contract tests/test_mla_quant.py gates).
-            latent = config.mla_latent_dtype or env_choice(
-                "LLMD_MLA_LATENT_DTYPE", "auto", MLA_LATENT_DTYPES)
-            if latent not in MLA_LATENT_DTYPES:
-                raise ValueError(
-                    f"unknown mla_latent_dtype {latent!r} "
-                    f"(choices: {MLA_LATENT_DTYPES})")
-            if latent != "auto":
-                self.kv_cache_dtype = latent
-        self.kv_quantized = self.kv_cache_dtype == "int8"
-        gran = config.kv_scale_granularity or env_choice(
-            "LLMD_KV_SCALE_GRAN", "token", KV_SCALE_GRANULARITIES)
-        if gran not in KV_SCALE_GRANULARITIES:
-            raise ValueError(
-                f"unknown kv_scale_granularity {gran!r} "
-                f"(choices: {KV_SCALE_GRANULARITIES})")
-        self.kv_scale_granularity = gran
-        # MLA's latent row is MQA-shared (no per-head substructure), so its
-        # scale plane is always one f32 per row; dense K/V may refine to
-        # per-KV-head scales under LLMD_KV_SCALE_GRAN=head.
-        if not self.kv_quantized:
-            self.kv_scale_width = 0
-        elif c.use_mla:
-            self.kv_scale_width = 1
-        else:
-            self.kv_scale_width = kv_scale_width(c.num_kv_heads, gran)
         if config.kv_cache_hbm_bytes:
-            # Dtype-aware pool sizing: same budget, ~2x the int8 blocks.
             # The budget is PER DEVICE: stacked (SPMD dp) engines split the
             # pool 1/dp per shard, so the global count scales by dp to keep
             # each chip's residency at the budget.
@@ -281,11 +188,10 @@ class EngineCore:
             derived = dp * derive_num_blocks(
                 config.kv_cache_hbm_bytes,
                 get_model(c).kv_cache_layout(c), c.num_layers,
-                config.block_size, self.kv_cache_dtype, self.kv_scale_width)
+                config.block_size)
             logger.info(
-                "kv pool auto-sized: %d blocks (%s, %.2f GiB/device budget"
-                ", dp=%d)", derived, self.kv_cache_dtype,
-                config.kv_cache_hbm_bytes / 2**30, dp)
+                "kv pool auto-sized: %d blocks (%.2f GiB/device budget"
+                ", dp=%d)", derived, config.kv_cache_hbm_bytes / 2**30, dp)
             config = dataclasses.replace(config, num_blocks=derived)
             self.config = config
         if config.async_scheduling and config.num_scheduler_steps <= 1:
@@ -453,40 +359,25 @@ class EngineCore:
         # and contiguous scatter rows (see ops/attention.py docstring).
         # Buffer names/widths come from the model: dense models carry
         # {k, v} of KVH*D each; MLA models ONE latent buffer (models/mla).
-        # kv_cache_dtype=int8 stores int8 payloads and adds a sibling
-        # "<name>_scale" f32 plane per buffer (per-page-row scales) — the
-        # scale planes are ordinary cache buffers, so the offload tier and
-        # the P->D wire stage/ship them through the same generic machinery.
         # Stacked mode prepends a [dp] dim sharded over the dp axis: each
         # shard owns slots_local = num_slots/dp rows — per-device KV
         # capacity scales 1/dp, the wide-EP memory profile.
         specs = self.model.kv_cache_spec(c)
-        payload_dtype = jnp.int8 if self.kv_quantized else jnp.bfloat16
-        buffers = {}   # name -> (width, dtype, PartitionSpec)
-        for name, width in layout.items():
-            buffers[name] = (width, payload_dtype, specs[name])
-            if self.kv_quantized:
-                # "head" granularity shards scales like the payload's folded
-                # head dim; "token" has one column, necessarily replicated.
-                s_spec = (P(None, None, "tp")
-                          if self.kv_scale_width > 1 else P())
-                buffers[f"{name}_scale"] = (
-                    self.kv_scale_width, jnp.float32, s_spec)
         if self.dp > 1:
             slots_local = num_slots // self.dp
             # Allocated sharded (device=): the whole pool never lands on
             # the first device on its way to the mesh.
             self.kv_cache = {
                 name: jnp.zeros(
-                    (self.dp, c.num_layers, slots_local, width), dtype,
-                    device=NamedSharding(self.mesh, P("dp", *spec)))
-                for name, (width, dtype, spec) in buffers.items()}
+                    (self.dp, c.num_layers, slots_local, width), jnp.bfloat16,
+                    device=NamedSharding(self.mesh, P("dp", *specs[name])))
+                for name, width in layout.items()}
         else:
             self.kv_cache = {
                 name: jnp.zeros(
-                    (c.num_layers, num_slots, width), dtype,
-                    device=NamedSharding(self.mesh, spec))
-                for name, (width, dtype, spec) in buffers.items()}
+                    (c.num_layers, num_slots, width), jnp.bfloat16,
+                    device=NamedSharding(self.mesh, specs[name]))
+                for name, width in layout.items()}
         self._replicated = NamedSharding(self.mesh, P())
         self._dp_sharded = NamedSharding(self.mesh, P("dp"))
 
@@ -655,8 +546,7 @@ class EngineCore:
         step programs will contain — the per-call gates in ops/attention.py
         and models/mla.py are static per engine, so a drop to the chunked
         XLA path is a property of the config, not something to discover
-        from a profile.  A kernel the chip's compiler refuses is not left
-        selectable: the engine refuses to start instead."""
+        from a profile."""
         from llm_d_tpu.ops.attention import (
             pallas_ineligible_reason, resolve_backend)
         backend = resolve_backend(self.config.attn_backend)
@@ -673,8 +563,7 @@ class EngineCore:
         heads_tp = self.config.mesh.tp if self.config.mesh else 1
         tp = heads_tp if not self.model_config.use_mla else 1
         reason = next(filter(None, (
-            pallas_ineligible_reason(
-                self.config.block_size, w // tp, self.kv_quantized)
+            pallas_ineligible_reason(self.config.block_size, w // tp)
             for w in layout.values())), None)
         if reason is not None:
             self._disable_feature("pallas_attention", reason)
@@ -682,11 +571,6 @@ class EngineCore:
         self._prefill_tile_dims = (
             self.model_config.num_heads // heads_tp,
             next(iter(layout.values())) // tp)
-        if self.kv_quantized and jax.default_backend() == "tpu":
-            raise ValueError(
-                f"kv_cache_dtype=int8 with the Pallas attention backend "
-                f"cannot run on a TPU: {INT8_CACHE_KERNEL_REFUSAL}.  Use "
-                f"the bf16 cache (ROADMAP A10 tracks the repair).")
 
     def _spec_blockers(self) -> List[str]:
         """Startup conditions that would force spec decode off.  Empty
@@ -2500,12 +2384,11 @@ class EngineCore:
             request_id=req.request_id, phase=phase, **attrs)
 
     def kv_bytes_per_token_layer(self) -> int:
-        """Bytes one token's KV costs per layer at the configured cache
-        dtype — the byte term bench's HBM-roofline accounting streams per
-        decode step (same accounting the pool sizing charges)."""
+        """Bytes one token's KV costs per layer — the byte term bench's
+        HBM-roofline accounting streams per decode step (same accounting
+        the pool sizing charges)."""
         return kv_bytes_per_token(
-            self.model.kv_cache_layout(self.model_config),
-            self.kv_cache_dtype, self.kv_scale_width)
+            self.model.kv_cache_layout(self.model_config))
 
     # ---------- batch building ----------
 

@@ -47,12 +47,11 @@ from llm_d_tpu.utils.faultinject import FaultInjected, get_injector
 
 logger = logging.getLogger(__name__)
 
-# Slab version 2 (kv_cache_dtype era): per-buffer dtype codes — int8
-# caches stage int8 rows + f32 scale planes (half the host RAM and wire
-# bytes per block), and a pod whose cache dtype differs REJECTS the blob
-# instead of reinterpreting it (shared-tier peers may be rolled at
-# different configs).  Codes live in transfer/transport.py — the same
-# registry the P->D wire uses.
+# Slab version 2: per-buffer dtype codes — a pod whose cache dtype or
+# buffer set differs REJECTS the blob instead of reinterpreting it
+# (shared-tier peers may be rolled at different builds: an older one may
+# still hold int8 rows + f32 scale planes).  Codes live in
+# transfer/transport.py — the same registry the P->D wire uses.
 _SLAB_VERSION = 2
 _SLAB_HEADER = struct.Struct("<IIII")   # version, num_buffers, L, bs
 _SLAB_BUF = struct.Struct("<IB")        # (row width, dtype code)
@@ -103,11 +102,11 @@ def _unpack_block_slab(blob: bytes, layout: List[tuple],
         except transport.TransferError as e:
             raise ValueError(str(e)) from e
         if blob_dtype != dtype:
-            # A bf16 pod must not reinterpret an int8 peer's blocks (and
-            # vice versa): kv_cache_dtype is part of the tier contract.
+            # A bf16 pod must not reinterpret an int8 peer's blocks: the
+            # cache dtype is part of the tier contract.
             raise ValueError(
                 f"buffer {name!r}: slab holds {blob_dtype} but this pod's "
-                f"cache is {dtype} — kv_cache_dtype mismatch, rejecting")
+                f"cache is {dtype} — cache dtype mismatch, rejecting")
         count = L * bs * w
         out[name] = np.frombuffer(blob, dtype=blob_dtype, offset=off,
                                   count=count).reshape(L, bs, w)

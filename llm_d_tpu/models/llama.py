@@ -79,15 +79,14 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
 
 def attention_block(
     lp: Params, config: ModelConfig, x: jax.Array, batch: Dict[str, jax.Array],
-    caches: Tuple[jax.Array, ...], block_size: int, attn_backend: str,
+    caches: Tuple[jax.Array, jax.Array], block_size: int, attn_backend: str,
     layer: jax.Array = None, mesh=None,
-) -> Tuple[jax.Array, Tuple[jax.Array, ...]]:
+) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """Shared by dense and MoE models. Returns (attn_out, caches').
 
-    ``caches`` is (k, v) for the bf16 cache or (k, v, k_scale, v_scale)
-    when ``kv_cache_dtype=int8`` (int8 payloads + f32 per-row scale planes).
-    With ``layer`` the caches are the full stacked [L, slots, F] buffers
-    updated in place (see ops.attention.attention_with_kv_update).
+    ``caches`` is (k, v).  With ``layer`` the caches are the full stacked
+    [L, slots, F] buffers updated in place (see
+    ops.attention.attention_with_kv_update).
 
     A mixed stack (``config.layer_types``) runs every layer through this
     one traced body: the window and the rotary rule of layer ``layer`` are
@@ -115,11 +114,10 @@ def attention_block(
         q = jnp.where(rope, L.apply_rope(q, cos, sin), q)
         kx = jnp.where(rope, L.apply_rope(kx, cos, sin), kx)
 
-    k_scale, v_scale = caches[2:] if len(caches) == 4 else (None, None)
     attn, *new_caches = attention_with_kv_update(
         q, kx, vx, caches[0], caches[1], batch,
         block_size=block_size, backend=attn_backend, layer=layer,
-        k_scale=k_scale, v_scale=v_scale, mesh=mesh, window=window)
+        mesh=mesh, window=window)
     attn = attn.reshape(T, c.num_heads * dh)
     if "attn_gate" in lp:
         attn = attn * jax.nn.sigmoid(L.linear(x, lp["attn_gate"]))
@@ -174,12 +172,7 @@ def forward(
     stacked = batch["token_ids"].ndim == 2
     x = embed_tokens(params, batch["token_ids"], c)  # [T, D] / [dp, T_l, D]
 
-    # int8 KV: the f32 scale planes ride the scan carry right next to their
-    # payload buffers (name order fixed so the returned dict matches the
-    # engine's buffer set exactly).
-    cache_names = ("k", "v", "k_scale", "v_scale") \
-        if "k_scale" in kv_cache else ("k", "v")
-    caches0 = tuple(kv_cache[n] for n in cache_names)
+    caches0 = (kv_cache["k"], kv_cache["v"])
     # Once a step program, outside the layer scan: the query tile list the
     # Pallas prefill kernels walk in every layer.
     batch = with_query_tiles(batch, c.num_heads, caches0[0].shape[-1],
@@ -216,7 +209,7 @@ def forward(
             x, batch["sample_idx"][..., None], axis=1)   # [dp, S_l, D]
     else:
         sample_hidden = x[batch["sample_idx"]]           # [S, D]
-    return sample_hidden, dict(zip(cache_names, caches))
+    return sample_hidden, dict(zip(("k", "v"), caches))
 
 
 def compute_logits(params: Params, hidden: jax.Array, config: ModelConfig) -> jax.Array:

@@ -84,16 +84,11 @@ def mla_attention_block(
     block_size: int,
     attn_backend: str,
     layer: jax.Array,
-    kv_scale: jax.Array = None,   # int8 latent: [L, slots, SW] f32 scales
     mesh=None,                    # multi-device: Pallas runs per tp shard
-) -> Tuple[jax.Array, ...]:
+) -> Tuple[jax.Array, jax.Array]:
     """Weight-absorbed MLA over the paged latent cache.
 
-    Returns (attn_out [T, Hm], kv_cache') — plus kv_scale' appended when
-    the latent cache is int8-quantized (``kv_scale`` given: the payload
-    cache holds int8 rows, each with one symmetric f32 scale; every reader
-    dequantizes before the two absorbed-weight dots, so kernel and XLA
-    fallback share one dequantize-then-attend numerics contract)."""
+    Returns (attn_out [T, Hm], kv_cache')."""
     c = config
     T = x.shape[0]
     H = c.num_heads
@@ -147,7 +142,6 @@ def mla_attention_block(
     attend = functools.partial(_mla_attend, block_size=block_size,
                                backend=backend, scale=scale, R=R)
     ab = {k: batch[k] for k in A.ATTN_BATCH_KEYS if k in batch}
-    scales = () if kv_scale is None else (kv_scale,)
     if backend == "pallas":
         # Per tp shard on a multi-device mesh: heads split, the latent
         # cache replicated (every shard splices the same row into its own
@@ -156,39 +150,27 @@ def mla_attention_block(
         heads = P(None, "tp", None)
         attend = A.manual_over_mesh(
             attend, mesh,
-            in_specs=(heads, P(), P(), {k: P() for k in ab}, P())
-            + (P(),) * len(scales),
-            out_specs=(heads, P()) + (P(),) * len(scales))
-    out_lat, kv_cache, *new_scales = attend(
-        q_eff, row, kv_cache, ab, layer, *scales)
+            in_specs=(heads, P(), P(), {k: P() for k in ab}, P()),
+            out_specs=(heads, P()))
+    out_lat, kv_cache = attend(q_eff, row, kv_cache, ab, layer)
 
     # --- absorb W_uv: latent -> per-head value space, then output proj ---
     attn = jnp.einsum("thr,rhv->thv", out_lat,
                       w_uv.astype(jnp.float32)).astype(x.dtype)
-    return (L.linear(attn.reshape(T, H * vdim), lp["o_proj"]),
-            kv_cache, *new_scales)
+    return L.linear(attn.reshape(T, H * vdim), lp["o_proj"]), kv_cache
 
 
-def _mla_attend(q_eff, row, kv_cache, batch, layer, kv_scale=None, *,
+def _mla_attend(q_eff, row, kv_cache, batch, layer, *,
                 block_size: int, backend: str, scale: float, R: int):
     """Latent-cache update + attention over one tp shard's heads (or all):
-    (out_lat [T, H_local, R] f32, kv_cache'[, kv_scale'])."""
+    (out_lat [T, H_local, R] f32, kv_cache')."""
     T = q_eff.shape[0]
     F_cache = kv_cache.shape[-1]
-    quantized = kv_scale is not None
-    if quantized:
-        # One symmetric f32 scale per latent row (SW = 1 — the row is
-        # MQA-shared, there is no per-head substructure to refine over);
-        # pad columns quantize to exact zeros, so lane padding stays
-        # score-neutral under int8 too.
-        from llm_d_tpu.ops.quant import quantize_kv_block
-        row_q, row_s = quantize_kv_block(row, kv_scale.shape[-1])
-
     qtok_idx = batch["qtok_idx"]
     # An ineligible geometry takes the chunked XLA path; the engine
     # announced that at construction (A.pallas_ineligible_reason).
     kernel_ok = backend == "pallas" and A.pallas_ineligible_reason(
-        block_size, F_cache, quantized) is None
+        block_size, F_cache) is None
     if kernel_ok and qtok_idx.shape[1] == 1:
         # Decode hot path: single-buffer MQA kernel — each latent page is
         # DMA'd once and used for both the score and value dots, with the
@@ -198,67 +180,46 @@ def _mla_attend(q_eff, row, kv_cache, batch, layer, kv_scale=None, *,
         rows_idx = qtok_idx[:, 0].clip(0, T - 1)
         # Per-batch-size retune knob: override the auto sequence grouping
         # (0 = auto).  The group trades grid-program launch overhead
-        # against VMEM residency; re-derive on chip per batch size with
-        # scripts/kernel_bench.py --mla.  Env-knob contract: a value that
-        # does not divide THIS program's sequence bucket (S varies with
-        # load) degrades to auto instead of crashing the serving path.
+        # against VMEM residency.  Env-knob contract: a value that does
+        # not divide THIS program's sequence bucket (S varies with load)
+        # degrades to auto instead of crashing the serving path.
         sg = env_int("LLMD_MLA_SEQ_GROUP", 0)
         S_b = qtok_idx.shape[0]
         sg = sg if sg >= 1 and S_b % sg == 0 else None
-        if quantized:
-            out, kv_cache, kv_scale = mla_paged_decode_update(
-                q_eff[rows_idx], row_q[rows_idx], kv_cache,
-                batch["block_tables"], batch["seq_lens"],
-                block_size=block_size, scale=scale, layer=layer,
-                seq_group=sg, kv_scale=kv_scale,
-                row_scale_new=row_s[rows_idx])
-        else:
-            out, kv_cache = mla_paged_decode_update(
-                q_eff[rows_idx], row[rows_idx], kv_cache,
-                batch["block_tables"], batch["seq_lens"],
-                block_size=block_size, scale=scale, layer=layer,
-                seq_group=sg)
+        out, kv_cache = mla_paged_decode_update(
+            q_eff[rows_idx], row[rows_idx], kv_cache,
+            batch["block_tables"], batch["seq_lens"],
+            block_size=block_size, scale=scale, layer=layer,
+            seq_group=sg)
         out_lat = out[batch["token_seq_ids"]][..., :R].astype(jnp.float32)
-    elif kernel_ok:
-        # Prefill / mixed batches: MLA flash kernel over the step's query
-        # tiles — the latent page is DMA'd once per tile and serves both
-        # the score and value dots
-        # (ops/pallas/mla_prefill.py; the chunked XLA path below cost
-        # ~90% of the MoE prefill step, round-4 verdict Weak #4).
-        from llm_d_tpu.ops.pallas.mla_prefill import mla_flash_prefill
-        wr = (row_q if quantized else row).reshape(T, 1, F_cache)
-        kv_cache, _ = A.write_kv(
-            kv_cache, kv_cache, wr, wr, batch["slot_mapping"], layer=layer)
-        if quantized:
-            kv_scale = A.write_scales(
-                kv_scale, row_s, batch["slot_mapping"], layer=layer)
-        q_tiles, batch = A.gather_query_tiles(
-            q_eff, batch, F_cache, mla=True)                # [NT, Qt, H, F]
-        out_t = mla_flash_prefill(
-            q_tiles, batch["tile_pos"], kv_cache, batch["block_tables"],
-            batch["seq_lens"], block_size=block_size, scale=scale,
-            layer=layer, kv_scale=kv_scale, tile_seq=batch["tile_seq"])
-        out_lat = out_t[batch["tok_tile"], batch["tok_slot"]]
-        out_lat = out_lat[..., :R].astype(jnp.float32)      # attended c_kv
     else:
-        # KVH=1 (every head reads the same latent row); the v-cache aliases
-        # the k-cache — attended "values" are the row's first R columns.
-        wr = (row_q if quantized else row).reshape(T, 1, F_cache)
+        # Scatter-then-read.  KVH=1 (every head reads the same latent row);
+        # the v-cache aliases the k-cache — attended "values" are the row's
+        # first R columns.
+        wr = row.reshape(T, 1, F_cache)
         kv_cache, _ = A.write_kv(
             kv_cache, kv_cache, wr, wr, batch["slot_mapping"], layer=layer)
-        if quantized:
-            kv_scale = A.write_scales(
-                kv_scale, row_s, batch["slot_mapping"], layer=layer)
-        out_lat = A.ragged_paged_attention_chunked(
-            q_eff, kv_cache, kv_cache, batch["token_seq_ids"],
-            batch["positions"], batch["block_tables"], batch["seq_lens"],
-            qtok_idx, batch["token_qpos"], block_size=block_size,
-            scale=scale, layer=layer, k_scale=kv_scale,
-            v_scale=kv_scale)                               # [T, H, F_cache]
+        if kernel_ok:
+            # Prefill / mixed batches: MLA flash kernel over the step's
+            # query tiles — the latent page is DMA'd once per tile and
+            # serves both the score and value dots
+            # (ops/pallas/mla_prefill.py; the chunked XLA path below cost
+            # ~90% of the MoE prefill step, round-4 verdict Weak #4).
+            from llm_d_tpu.ops.pallas.mla_prefill import mla_flash_prefill
+            q_tiles, batch = A.gather_query_tiles(
+                q_eff, batch, F_cache, mla=True)            # [NT, Qt, H, F]
+            out_t = mla_flash_prefill(
+                q_tiles, batch["tile_pos"], kv_cache, batch["block_tables"],
+                batch["seq_lens"], block_size=block_size, scale=scale,
+                layer=layer, tile_seq=batch["tile_seq"])
+            out_lat = out_t[batch["tok_tile"], batch["tok_slot"]]
+        else:
+            out_lat = A.ragged_paged_attention_chunked(
+                q_eff, kv_cache, kv_cache, batch["token_seq_ids"],
+                batch["positions"], batch["block_tables"], batch["seq_lens"],
+                qtok_idx, batch["token_qpos"], block_size=block_size,
+                scale=scale, layer=layer)                   # [T, H, F_cache]
         out_lat = out_lat[..., :R].astype(jnp.float32)      # attended c_kv
-
-    if quantized:
-        return out_lat, kv_cache, kv_scale
     return out_lat, kv_cache
 
 

@@ -137,16 +137,7 @@ def forward(
     Ld = c.first_dense_layers
     stacked = batch["token_ids"].ndim == 2
     x = embed_tokens(params, batch["token_ids"], c)   # [T, D] / [dp, T_l, D]
-    # int8 KV: scale planes ride the scan carry with the payloads — for
-    # dense models per K/V buffer, for MLA one ``kv_scale`` plane next to
-    # the int8 latent rows (kv_cache_dtype=int8 covers both families).
-    if c.use_mla:
-        cache_keys = (("kv", "kv_scale") if "kv_scale" in kv_cache
-                      else ("kv",))
-    elif "k_scale" in kv_cache:
-        cache_keys = ("k", "v", "k_scale", "v_scale")
-    else:
-        cache_keys = ("k", "v")
+    cache_keys = ("kv",) if c.use_mla else ("k", "v")
     # Once a step program, outside both layer scans: the query tile list
     # the Pallas prefill kernels walk in every layer.
     batch = with_query_tiles(
@@ -165,15 +156,13 @@ def forward(
     stub = frozenset((moe_opts or {}).get("stub_components") or ())
 
     def attend_local(lp, hn, caches, ab, li):
-        """Attention dispatch: MLA (single latent buffer, optionally int8
-        + scale plane) or classic GQA."""
+        """Attention dispatch: MLA (single latent buffer) or classic GQA."""
         if c.use_mla:
             from llm_d_tpu.models.mla import mla_attention_block
-            a, *new_caches = mla_attention_block(
+            a, kv = mla_attention_block(
                 lp, c, hn, ab, caches[0], block_size, attn_backend,
-                layer=li, kv_scale=caches[1] if len(caches) > 1 else None,
-                mesh=mesh)
-            return a, tuple(new_caches)
+                layer=li, mesh=mesh)
+            return a, (kv,)
         return attention_block(
             lp, c, hn, ab, caches, block_size, attn_backend, layer=li,
             mesh=mesh)

@@ -34,25 +34,15 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from llm_d_tpu.ops.quant import dequantize_kv_block, quantize_kv_block
-
 NEG_INF = -1e30
 
 
-def _gather_rows(cache: jax.Array, scale: "Optional[jax.Array]",
-                 idx: jax.Array, layer: Optional[jax.Array]):
-    """Row gather with optional int8 dequantization.
-
-    ``cache`` is ``[num_slots, W]`` (or stacked ``[L, slots, W]`` with
-    ``layer``); int8 caches carry a sibling f32 ``scale`` plane
-    ``[..., slots, SW]`` and gathered rows come back dequantized to f32 —
-    the XLA fallback's dequantize-then-attend path, numerically identical
-    to the in-VMEM dequant the Pallas kernels do after the page DMA."""
+def _gather_rows(cache: jax.Array, idx: jax.Array,
+                 layer: Optional[jax.Array]):
+    """f32 rows ``idx`` of ``cache`` ``[num_slots, W]`` (or of plane
+    ``layer`` of a stacked ``[L, slots, W]``)."""
     rows = cache[idx] if layer is None else cache[layer, idx]
-    if scale is None:
-        return rows.astype(jnp.float32)
-    s = scale[idx] if layer is None else scale[layer, idx]
-    return dequantize_kv_block(rows, s, jnp.float32)
+    return rows.astype(jnp.float32)
 
 
 def ragged_paged_attention_reference(
@@ -67,8 +57,6 @@ def ragged_paged_attention_reference(
     scale: Optional[float] = None,
     soft_cap: Optional[float] = None,
     layer: Optional[jax.Array] = None,
-    k_scale: Optional[jax.Array] = None,   # int8 caches: f32 scale planes
-    v_scale: Optional[jax.Array] = None,
     window: Optional[jax.Array] = None,    # i32: keys a query sees (None=all)
 ) -> jax.Array:               # [T, H, D]
     T, H, D = q.shape
@@ -81,10 +69,8 @@ def ragged_paged_attention_reference(
     slot_ids = (block_tables[:, :, None] * block_size
                 + jnp.arange(block_size)[None, None, :]).reshape(S, B * block_size)
     C = B * block_size
-    k_seq = _gather_rows(k_cache, k_scale, slot_ids, layer).reshape(
-        S, C, KVH, D)
-    v_seq = _gather_rows(v_cache, v_scale, slot_ids, layer).reshape(
-        S, C, KVH, D)
+    k_seq = _gather_rows(k_cache, slot_ids, layer).reshape(S, C, KVH, D)
+    v_seq = _gather_rows(v_cache, slot_ids, layer).reshape(S, C, KVH, D)
 
     # Per-token context: [T, C, KVH, D].
     k_tok = k_seq[token_seq_ids]
@@ -140,21 +126,6 @@ def write_kv(
     return k_cache, v_cache
 
 
-def write_scales(
-    scale_cache: jax.Array,   # [num_slots, SW] or stacked [L, slots, SW]
-    scales_new: jax.Array,    # [T, SW] f32 per-row scales
-    slot_mapping: jax.Array,
-    layer: Optional[jax.Array] = None,
-):
-    """Scatter this step's per-row KV scales next to their int8 rows (the
-    scale plane mirrors the payload cache's slot addressing exactly)."""
-    if layer is None:
-        return scale_cache.at[slot_mapping].set(
-            scales_new.astype(scale_cache.dtype))
-    return scale_cache.at[layer, slot_mapping].set(
-        scales_new.astype(scale_cache.dtype))
-
-
 def _flash_over_kv_chunks(
     qs: jax.Array,        # [S, Q, H, D] padded per-seq queries
     q_pos: jax.Array,     # [S, Q] absolute positions (pad -> -1)
@@ -163,8 +134,6 @@ def _flash_over_kv_chunks(
     k_cache: jax.Array, v_cache: jax.Array,
     kv_chunk: int, scale: float, soft_cap: Optional[float],
     layer: Optional[jax.Array] = None,
-    k_scale: Optional[jax.Array] = None,
-    v_scale: Optional[jax.Array] = None,
     window: Optional[jax.Array] = None,
 ) -> jax.Array:           # [S, Q, H, D]
     """Online-softmax attention scanning the context in kv_chunk slices.
@@ -185,10 +154,8 @@ def _flash_over_kv_chunks(
     def compute_chunk(carry, ci):
         m, l, acc = carry
         sl = jax.lax.dynamic_slice_in_dim(slot_ids, ci * kv_chunk, kv_chunk, 1)
-        k = _gather_rows(k_cache, k_scale, sl, layer).reshape(
-            S, kv_chunk, KVH, D)
-        v = _gather_rows(v_cache, v_scale, sl, layer).reshape(
-            S, kv_chunk, KVH, D)
+        k = _gather_rows(k_cache, sl, layer).reshape(S, kv_chunk, KVH, D)
+        v = _gather_rows(v_cache, sl, layer).reshape(S, kv_chunk, KVH, D)
         s = jnp.einsum("sqkgd,sckd->sqkgc", qf, k)   # [S, Q, KVH, G, kc]
         if soft_cap is not None:
             s = soft_cap * jnp.tanh(s / soft_cap)
@@ -258,8 +225,6 @@ def _flash_batched_q_chunks(
     k_cache: jax.Array, v_cache: jax.Array,
     scale: float, soft_cap: Optional[float],
     layer: Optional[jax.Array] = None,
-    k_scale: Optional[jax.Array] = None,
-    v_scale: Optional[jax.Array] = None,
     window: Optional[jax.Array] = None,
 ) -> jax.Array:           # [S, Q, H, D]
     """All-sequences-batched prefill attention.
@@ -286,16 +251,14 @@ def _flash_batched_q_chunks(
     if qc == Q:
         return _flash_over_kv_chunks(
             qs, q_pos, slot_ids, seq_lens, k_cache, v_cache,
-            kv_chunk, scale, soft_cap, layer=layer,
-            k_scale=k_scale, v_scale=v_scale, window=window)
+            kv_chunk, scale, soft_cap, layer=layer, window=window)
 
     def one_q_chunk(_, qi):
         qs_i = jax.lax.dynamic_slice_in_dim(qs, qi * qc, qc, 1)
         qp_i = jax.lax.dynamic_slice_in_dim(q_pos, qi * qc, qc, 1)
         out_i = _flash_over_kv_chunks(
             qs_i, qp_i, slot_ids, seq_lens, k_cache, v_cache,
-            kv_chunk, scale, soft_cap, layer=layer,
-            k_scale=k_scale, v_scale=v_scale, window=window)
+            kv_chunk, scale, soft_cap, layer=layer, window=window)
         return None, out_i
 
     _, outs = jax.lax.scan(one_q_chunk, None,
@@ -426,8 +389,6 @@ def ragged_paged_attention_chunked(
     token_qpos: jax.Array,     # [T] q slot of each token within its seq
     block_size: int, scale=None, soft_cap=None,
     layer: Optional[jax.Array] = None,
-    k_scale: Optional[jax.Array] = None,
-    v_scale: Optional[jax.Array] = None,
     window: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Memory-bounded ragged attention (XLA flash recurrence).
@@ -449,12 +410,11 @@ def ragged_paged_attention_chunked(
         out = _flash_over_kv_chunks(
             qs, q_pos, slot_ids, seq_lens, k_cache, v_cache,
             _chunk_size_for(C), scale, soft_cap, layer=layer,
-            k_scale=k_scale, v_scale=v_scale, window=window)   # [S, 1, H, D]
+            window=window)                                     # [S, 1, H, D]
     else:
         out = _flash_batched_q_chunks(
             qs, q_pos, slot_ids, seq_lens, k_cache, v_cache,
-            scale, soft_cap, layer=layer, k_scale=k_scale, v_scale=v_scale,
-            window=window)
+            scale, soft_cap, layer=layer, window=window)
 
     return out[token_seq_ids, token_qpos]       # [T, H, D]
 
@@ -466,8 +426,8 @@ def resolve_backend(backend: str) -> str:
     return backend
 
 
-def pallas_ineligible_reason(block_size: int, row_width: int,
-                             quantized: bool = False) -> Optional[str]:
+def pallas_ineligible_reason(block_size: int,
+                             row_width: int) -> Optional[str]:
     """Why the Pallas attention kernels (dense and MLA, decode and prefill)
     cannot serve a cache geometry; None when they can.  TPU DMA slices need
     sublane-aligned pages and 128-lane-aligned rows — an ineligible shape
@@ -481,9 +441,6 @@ def pallas_ineligible_reason(block_size: int, row_width: int,
     if row_width % 128:
         return (f"cache row width {row_width} is not a multiple of 128 "
                 f"lanes")
-    if quantized and block_size % 32:
-        return (f"block_size {block_size} is not a multiple of 32 "
-                f"(int8 sublane tile)")
     return None
 
 
@@ -522,8 +479,6 @@ def attention_with_kv_update(
     soft_cap=None,
     backend: str = "auto",
     layer: Optional[jax.Array] = None,   # i32 plane of a stacked cache
-    k_scale: Optional[jax.Array] = None,  # int8 caches: f32 scale planes
-    v_scale: Optional[jax.Array] = None,  # ([num_slots, SW] / [L, slots, SW])
     mesh=None,               # multi-device mesh: Pallas runs per tp shard
     window: Optional[jax.Array] = None,   # i32 scalar (traced per layer):
                                           # keys a query sees; None = all
@@ -541,13 +496,7 @@ def attention_with_kv_update(
     per-layer slice/copy traffic (measured ~10 ms/step of pure HBM copies
     at 1B scale otherwise).
 
-    ``kv_cache_dtype=int8``: the payload caches are int8 and ``k_scale`` /
-    ``v_scale`` hold per-page-row f32 scales.  New rows are quantized here
-    (symmetric, per row or per KV head — the scale plane's width decides),
-    every reader dequantizes after the gather/DMA, and the flash recurrence
-    itself stays bf16/f32.  Returns a 5-tuple
-    (attn_out, k_cache', v_cache', k_scale', v_scale') in that mode;
-    the classic 3-tuple otherwise.
+    Returns (attn_out, k_cache', v_cache').
 
     On a multi-device ``mesh`` the Pallas backend runs per tp shard under
     ``manual_over_mesh``: heads (and the folded cache rows) split over
@@ -570,81 +519,44 @@ def attention_with_kv_update(
         ab = {k: batch[k] for k in ATTN_BATCH_KEYS if k in batch}
         heads = P(None, "tp", None)
         rows = P(*(None,) * (k_cache.ndim - 1), "tp")
-        scales = () if k_scale is None else (k_scale, v_scale)
-        s_spec = rows if scales and k_scale.shape[-1] > 1 else P()
         # The replicated scalars, those that are given.
         largs = {name: v for name, v in (("layer", layer), ("window", window))
                  if v is not None}
 
         def local(q, k_new, v_new, k_cache, v_cache, ab, *rest):
-            ks, vs = rest[len(largs):] or (None, None)
             return attention_with_kv_update(
                 q, k_new, v_new, k_cache, v_cache, ab, block_size,
                 scale=scale, soft_cap=soft_cap, backend=backend,
-                k_scale=ks, v_scale=vs, **dict(zip(largs, rest)))
+                **dict(zip(largs, rest)))
 
         return manual_over_mesh(
             local, mesh,
             in_specs=(heads, heads, heads, rows, rows,
-                      {k: P() for k in ab}) + (P(),) * len(largs)
-            + (s_spec,) * len(scales),
-            out_specs=(heads, rows, rows) + (s_spec,) * len(scales),
-        )(q, k_new, v_new, k_cache, v_cache, ab, *largs.values(), *scales)
-    quantized = k_scale is not None
+                      {k: P() for k in ab}) + (P(),) * len(largs),
+            out_specs=(heads, rows, rows),
+        )(q, k_new, v_new, k_cache, v_cache, ab, *largs.values())
     T, H, D = q.shape
     F = k_cache.shape[-1]
-
-    if quantized:
-        sw = k_scale.shape[-1]
-        k_q, k_s = quantize_kv_block(k_new.reshape(T, F), sw)
-        v_q, v_s = quantize_kv_block(v_new.reshape(T, F), sw)
-
-    def _ret(out, k_cache, v_cache, k_scale, v_scale):
-        if quantized:
-            return out, k_cache, v_cache, k_scale, v_scale
-        return out, k_cache, v_cache
 
     qtok_idx = batch.get("qtok_idx")
     # An ineligible cache geometry takes the chunked XLA path; the engine
     # announced that at construction.
     kernel_ok = (backend == "pallas" and qtok_idx is not None
-                 and pallas_ineligible_reason(
-                     block_size, F, quantized) is None)
+                 and pallas_ineligible_reason(block_size, F) is None)
     if kernel_ok and soft_cap is None and qtok_idx.shape[1] == 1:
         from llm_d_tpu.ops.pallas.paged_attention import (
             paged_attention_decode_update)
         rows = qtok_idx[:, 0].clip(0, T - 1)
-        if quantized:
-            out, k_cache, v_cache, k_scale, v_scale = \
-                paged_attention_decode_update(
-                    q[rows], k_q[rows], v_q[rows], k_cache, v_cache,
-                    batch["block_tables"], batch["seq_lens"],
-                    block_size=block_size, num_kv_heads=F // D,
-                    scale=scale, layer=layer,
-                    k_scale=k_scale, v_scale=v_scale,
-                    k_scale_new=k_s[rows], v_scale_new=v_s[rows],
-                    window=window)
-        else:
-            out, k_cache, v_cache = paged_attention_decode_update(
-                q[rows], k_new.reshape(T, F)[rows].astype(k_cache.dtype),
-                v_new.reshape(T, F)[rows].astype(v_cache.dtype),
-                k_cache, v_cache, batch["block_tables"], batch["seq_lens"],
-                block_size=block_size,
-                num_kv_heads=F // D, scale=scale, layer=layer, window=window)
-        return _ret(out[batch["token_seq_ids"]],
-                    k_cache, v_cache, k_scale, v_scale)
+        out, k_cache, v_cache = paged_attention_decode_update(
+            q[rows], k_new.reshape(T, F)[rows].astype(k_cache.dtype),
+            v_new.reshape(T, F)[rows].astype(v_cache.dtype),
+            k_cache, v_cache, batch["block_tables"], batch["seq_lens"],
+            block_size=block_size,
+            num_kv_heads=F // D, scale=scale, layer=layer, window=window)
+        return out[batch["token_seq_ids"]], k_cache, v_cache
 
-    if quantized:
-        k_cache, v_cache = write_kv(
-            k_cache, v_cache, k_q, v_q, batch["slot_mapping"], layer=layer)
-        k_scale = write_scales(k_scale, k_s, batch["slot_mapping"],
-                               layer=layer)
-        v_scale = write_scales(v_scale, v_s, batch["slot_mapping"],
-                               layer=layer)
-    else:
-        k_cache, v_cache = write_kv(
-            k_cache, v_cache, k_new, v_new, batch["slot_mapping"],
-            layer=layer)
+    k_cache, v_cache = write_kv(
+        k_cache, v_cache, k_new, v_new, batch["slot_mapping"], layer=layer)
     if kernel_ok and qtok_idx.shape[1] > 1:
         # Prefill / mixed batches: flash kernel streaming KV pages through
         # VMEM (scatter-then-read; no aliasing needed), over the step's
@@ -655,22 +567,19 @@ def attention_with_kv_update(
             q_tiles, batch["tile_pos"], k_cache, v_cache,
             batch["block_tables"], batch["seq_lens"],
             block_size=block_size, num_kv_heads=F // D,
-            scale=scale, soft_cap=soft_cap, layer=layer,
-            k_scale=k_scale, v_scale=v_scale, window=window,
+            scale=scale, soft_cap=soft_cap, layer=layer, window=window,
             tile_seq=batch["tile_seq"])
-        return _ret(out_t[batch["tok_tile"], batch["tok_slot"]],
-                    k_cache, v_cache, k_scale, v_scale)
+        return out_t[batch["tok_tile"], batch["tok_slot"]], k_cache, v_cache
     if backend in ("pallas", "chunked") and qtok_idx is not None:
         out = ragged_paged_attention_chunked(
             q, k_cache, v_cache, batch["token_seq_ids"], batch["positions"],
             batch["block_tables"], batch["seq_lens"], qtok_idx,
             batch["token_qpos"], block_size=block_size,
-            scale=scale, soft_cap=soft_cap, layer=layer,
-            k_scale=k_scale, v_scale=v_scale, window=window)
+            scale=scale, soft_cap=soft_cap, layer=layer, window=window)
     else:
         out = ragged_paged_attention_reference(
             q, k_cache, v_cache, batch["token_seq_ids"], batch["positions"],
             batch["block_tables"], batch["seq_lens"],
             block_size=block_size, scale=scale, soft_cap=soft_cap,
-            layer=layer, k_scale=k_scale, v_scale=v_scale, window=window)
-    return _ret(out, k_cache, v_cache, k_scale, v_scale)
+            layer=layer, window=window)
+    return out, k_cache, v_cache

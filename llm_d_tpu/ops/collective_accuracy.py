@@ -1,9 +1,8 @@
 """Accuracy harness for the int8 EP collectives (per-collective bounds).
 
 Quantizing the MoE exchange wire (parallel/quant_collectives.py) injects
-error at TWO distinct points with different amplification paths, so —
-exactly like the MLA absorption harness (ops/mla_accuracy.py) — each is
-measured and bounded separately before ``LLMD_COLLECTIVE_DTYPE=auto``
+error at TWO distinct points with different amplification paths, so each
+is measured and bounded separately before ``LLMD_COLLECTIVE_DTYPE=auto``
 may resolve to int8:
 
   1. **Dispatch** (rows quantized BEFORE the expert FFN): the per-row

@@ -42,8 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from llm_d_tpu.ops.pallas.quant_util import make_page_dequant
-
 NEG_INF = -1e30
 
 
@@ -54,27 +52,20 @@ def _prefill_kernel(
     layer_ref,          # [1]    SMEM; [2] when ``windowed``: (layer, window)
     tile_seq_ref,       # [NT]   SMEM: the sequence row of each query tile
     tile_pos_ref,       # [NT*Qt] SMEM: position of each query slot (pad -1)
-    # inputs / outputs / scratch — layout depends on ``quantized``:
-    #   bf16:  q, k_hbm, v_hbm | o | k_buf, v_buf, sems, qpos
-    #   int8:  q, k_hbm, v_hbm, ks_hbm, vs_hbm | o
-    #          | k_buf, v_buf, ks_buf, vs_buf, sems, qpos
-    # (ks/vs are the [L, num_slots, SW] f32 per-page-row scale planes; the
-    #  int8 pages are dequantized in VMEM right after the DMA — this kernel
-    #  only READS the cache, the caller scattered rows + scales already.)
-    *refs,
+    # inputs (this kernel only READS the cache)
+    q_ref, k_hbm, v_hbm,
+    # outputs
+    o_ref,
+    # scratch
+    k_buf, v_buf, sems, qpos_buf,
+    *,
     block_size: int,
     num_heads: int,
     num_kv_heads: int,
     scale: float,
     soft_cap: float | None,
-    quantized: bool,
     windowed: bool,
 ):
-    if quantized:
-        (q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm,
-         o_ref, k_buf, v_buf, ks_buf, vs_buf, sems, qpos_buf) = refs
-    else:
-        (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, qpos_buf) = refs
     s = tile_seq_ref[pl.program_id(0)]
     R, D = q_ref.shape[1], q_ref.shape[2]     # R = Qt * H
     H = num_heads
@@ -103,7 +94,7 @@ def _prefill_kernel(
     def page_dma(slot, j):
         b = block_tables_ref[s, j]
         start = pl.multiple_of(b * bs, bs)
-        copies = [
+        return [
             pltpu.make_async_copy(
                 k_hbm.at[li, pl.ds(start, bs)], k_buf.at[slot],
                 sems.at[slot, 0]),
@@ -111,17 +102,6 @@ def _prefill_kernel(
                 v_hbm.at[li, pl.ds(start, bs)], v_buf.at[slot],
                 sems.at[slot, 1]),
         ]
-        if quantized:
-            copies.append(pltpu.make_async_copy(
-                ks_hbm.at[li, pl.ds(start, bs)], ks_buf.at[slot],
-                sems.at[slot, 2]))
-            copies.append(pltpu.make_async_copy(
-                vs_hbm.at[li, pl.ds(start, bs)], vs_buf.at[slot],
-                sems.at[slot, 3]))
-        return copies
-
-    if quantized:
-        dequant = make_page_dequant(ks_hbm.shape[2], F)
 
     @pl.when(n_pages > first)
     def _():
@@ -150,14 +130,9 @@ def _prefill_kernel(
             dma.wait()
 
         # bf16 operands, f32 accumulation: 2x MXU rate and no VPU convert
-        # of the page (the flash statistics stay f32).  Int8 pages pay one
-        # dequant pass for half the DMA bytes.
-        if quantized:
-            k = dequant(k_buf[slot], ks_buf[slot])            # [bs, F] bf16
-            v = dequant(v_buf[slot], vs_buf[slot])
-        else:
-            k = k_buf[slot]                                   # [bs, F] bf16
-            v = v_buf[slot]
+        # of the page (the flash statistics stay f32).
+        k = k_buf[slot]                                       # [bs, F] bf16
+        v = v_buf[slot]
         s_hb = jax.lax.dot_general(
             q2.astype(jnp.bfloat16), k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)               # [R, bs]
@@ -277,15 +252,12 @@ def flash_prefill_paged(
     layer: jax.Array | None = None,
     interpret: bool = False,
     q_tile: int | None = None,
-    k_scale: jax.Array | None = None,   # int8 caches: [L, slots, SW] f32
-    v_scale: jax.Array | None = None,   # scale planes (per page row)
     window: jax.Array | None = None,    # i32 scalar: keys a query sees
                                         # (itself included); None = all
     tile_seq: jax.Array | None = None,  # [NT] i32: the row of block_tables /
                                         # seq_lens each query tile belongs to
 ):
-    """Attention outputs in the layout of ``qs`` (caches already written —
-    int8 caches with their scale planes scattered by the caller).
+    """Attention outputs in the layout of ``qs`` (caches already written).
 
     With ``tile_seq`` the queries are the step's compact tile list
     (``ops.attention.gather_query_tiles``): all real slots of a tile belong
@@ -301,23 +273,13 @@ def flash_prefill_paged(
             tiles, tile_pos, k_cache, v_cache, block_tables, seq_lens,
             block_size=block_size, num_kv_heads=num_kv_heads, scale=scale,
             soft_cap=soft_cap, layer=layer, interpret=interpret,
-            k_scale=k_scale, v_scale=v_scale, window=window,
-            tile_seq=tile_seq)
+            window=window, tile_seq=tile_seq)
         return out.reshape(S, -1, H, D)[:, :Q]
     NT, Qt, H, D = qs.shape
     scale = scale if scale is not None else D ** -0.5
-    quantized = k_scale is not None
-    if quantized and block_size % 32:
-        raise ValueError(f"an int8 cache packs 32 rows a sublane tile: "
-                         f"block_size {block_size} is no multiple of 32")
-    squeeze = k_cache.ndim == 2
-    if squeeze:
+    if k_cache.ndim == 2:
         k_cache = k_cache[None]
         v_cache = v_cache[None]
-        if quantized:
-            k_scale = k_scale[None]
-            v_scale = v_scale[None]
-    SW = k_scale.shape[2] if quantized else 0
     layer_arr = jnp.asarray([0 if layer is None else layer]
                             + ([] if window is None else [window]), jnp.int32)
 
@@ -326,38 +288,29 @@ def flash_prefill_paged(
     q_fused = qs.reshape(NT, Qt * H, D)
 
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [
-        pl.BlockSpec((1, Qt * H, D), lambda n, *_: (n, 0, 0),
-                     memory_space=pltpu.VMEM),
-        any_spec, any_spec,
-    ] + ([any_spec, any_spec] if quantized else [])
-    scratch = [
-        pltpu.VMEM((2, block_size, F), k_cache.dtype),
-        pltpu.VMEM((2, block_size, F), v_cache.dtype),
-    ]
-    if quantized:
-        scratch += [pltpu.VMEM((2, block_size, SW), jnp.float32),
-                    pltpu.VMEM((2, block_size, SW), jnp.float32)]
-    scratch.append(pltpu.SemaphoreType.DMA((2, 4 if quantized else 2)))
-    scratch.append(pltpu.VMEM((Qt * H, 1), jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(NT,),
-        in_specs=in_specs,
+        in_specs=[
+            pl.BlockSpec((1, Qt * H, D), lambda n, *_: (n, 0, 0),
+                         memory_space=pltpu.VMEM),
+            any_spec, any_spec,
+        ],
         out_specs=[
             pl.BlockSpec((1, Qt * H, D), lambda n, *_: (n, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
-        scratch_shapes=scratch,
+        scratch_shapes=[
+            pltpu.VMEM((2, block_size, F), k_cache.dtype),
+            pltpu.VMEM((2, block_size, F), v_cache.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((Qt * H, 1), jnp.int32),
+        ],
     )
     kernel = functools.partial(
         _prefill_kernel, block_size=block_size, num_heads=H,
         num_kv_heads=num_kv_heads, scale=scale, soft_cap=soft_cap,
-        quantized=quantized, windowed=window is not None)
-    operands = [block_tables, seq_lens, layer_arr, tile_seq,
-                q_pos.reshape(-1), q_fused, k_cache, v_cache]
-    if quantized:
-        operands += [k_scale, v_scale]
+        windowed=window is not None)
     (out,) = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -365,5 +318,6 @@ def flash_prefill_paged(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(*operands)
+    )(block_tables, seq_lens, layer_arr, tile_seq,
+      q_pos.reshape(-1), q_fused, k_cache, v_cache)
     return out.reshape(NT, Qt, H, D)

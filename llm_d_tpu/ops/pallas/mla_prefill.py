@@ -29,13 +29,6 @@ Causality bounds the page loop per tile; pad query slots carry position
 -1 and produce zeros.  KV rows for the tokens being computed are
 scattered by the caller (write_kv) BEFORE the kernel runs — read-only,
 no aliasing contract.
-
-``kv_cache_dtype=int8`` (the latent cache): the page payload is int8 and
-each page's per-row f32 scales ride a parallel DMA chain from the sibling
-scale plane (read-side of the same treatment the decode kernel gets); the
-page is dequantized in VMEM after the DMA and both dots read bf16.  The
-caller quantizes and scatters the new rows + scales before the kernel
-runs, exactly like the bf16 scatter-then-read contract.
 """
 
 from __future__ import annotations
@@ -49,7 +42,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from llm_d_tpu.ops.pallas.flash_prefill import (
     pick_q_tile, rectangle_as_tiles, slot_positions)
-from llm_d_tpu.ops.pallas.quant_util import make_page_dequant
 
 NEG_INF = -1e30
 
@@ -61,20 +53,17 @@ def _mla_prefill_kernel(
     layer_ref,          # [1]    SMEM
     tile_seq_ref,       # [NT]   SMEM: the sequence row of each query tile
     tile_pos_ref,       # [NT*Qt] SMEM: position of each query slot (pad -1)
-    # inputs / outputs / scratch — layout depends on ``quantized``:
-    #   bf16: q, kv_hbm | o | kv_buf, sems, qpos
-    #   int8: q, kv_hbm, ks_hbm | o | kv_buf, ks_buf, sems, qpos
-    *refs,
+    # inputs
+    q_ref, kv_hbm,
+    # outputs
+    o_ref,
+    # scratch
+    kv_buf, sems, qpos_buf,
+    *,
     block_size: int,
     num_heads: int,
     scale: float,
-    quantized: bool,
 ):
-    if quantized:
-        (q_ref, kv_hbm, ks_hbm,
-         o_ref, kv_buf, ks_buf, sems, qpos_buf) = refs
-    else:
-        (q_ref, kv_hbm, o_ref, kv_buf, sems, qpos_buf) = refs
     s = tile_seq_ref[pl.program_id(0)]
     bs = block_size
     li = layer_ref[0]
@@ -86,26 +75,16 @@ def _mla_prefill_kernel(
     live = jnp.minimum(seq_len, qmax + 1)
     n_pages = pl.cdiv(jnp.maximum(live, 0), bs)
 
-    if quantized:
-        SW = ks_buf.shape[-1]
-        dequant = make_page_dequant(SW, q_ref.shape[2])
-
     def page_dma(slot, j):
         b = block_tables_ref[s, j]
         start = pl.multiple_of(b * bs, bs)
-        copies = [pltpu.make_async_copy(
+        return pltpu.make_async_copy(
             kv_hbm.at[li, pl.ds(start, bs)], kv_buf.at[slot],
-            sems.at[slot, 0])]
-        if quantized:
-            copies.append(pltpu.make_async_copy(
-                ks_hbm.at[li, pl.ds(start, bs)], ks_buf.at[slot],
-                sems.at[slot, 1]))
-        return copies
+            sems.at[slot, 0])
 
     @pl.when(n_pages > 0)
     def _():
-        for dma in page_dma(0, 0):
-            dma.start()
+        page_dma(0, 0).start()
 
     # bf16 operands, f32 accumulation (flash statistics stay f32).
     q2 = (q_ref[0].astype(jnp.float32) * scale).astype(jnp.bfloat16)
@@ -116,15 +95,10 @@ def _mla_prefill_kernel(
 
         @pl.when(j + 1 < n_pages)
         def _():
-            for dma in page_dma((j + 1) % 2, j + 1):
-                dma.start()
+            page_dma((j + 1) % 2, j + 1).start()
 
-        for dma in page_dma(slot, j):
-            dma.wait()
-        if quantized:
-            kv = dequant(kv_buf[slot], ks_buf[slot])          # [bs, F] bf16
-        else:
-            kv = kv_buf[slot]                                 # [bs, F] bf16
+        page_dma(slot, j).wait()
+        kv = kv_buf[slot]                                     # [bs, F] bf16
         s_hb = jax.lax.dot_general(
             q2, kv, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)               # [R, bs]
@@ -177,13 +151,11 @@ def mla_flash_prefill(
     layer: jax.Array | None = None,
     interpret: bool = False,
     q_tile: int | None = None,
-    kv_scale: jax.Array | None = None,   # int8 latent: [L, slots, SW] f32
     tile_seq: jax.Array | None = None,   # [NT] i32: the row of block_tables /
                                          # seq_lens each query tile belongs to
 ):
-    """Attended latent rows in the layout of ``qs`` (cache already written
-    — including, for the int8 latent, the new rows' scales in
-    ``kv_scale``).
+    """Attended latent rows in the layout of ``qs`` (cache already
+    written).
 
     With ``tile_seq`` the queries are the step's compact tile list
     (``ops.attention.gather_query_tiles``): all real slots of a tile belong
@@ -200,55 +172,38 @@ def mla_flash_prefill(
         out = mla_flash_prefill(
             tiles, tile_pos, kv_cache, block_tables, seq_lens,
             block_size=block_size, scale=scale, layer=layer,
-            interpret=interpret, kv_scale=kv_scale, tile_seq=tile_seq)
+            interpret=interpret, tile_seq=tile_seq)
         return out.reshape(S, -1, H, F)[:, :Q]
     NT, Qt, H, F = qs.shape
-    quantized = kv_scale is not None
-    if quantized and block_size % 32:
-        raise ValueError(f"an int8 cache packs 32 rows a sublane tile: "
-                         f"block_size {block_size} is no multiple of 32")
-    squeeze = kv_cache.ndim == 2
-    if squeeze:
+    if kv_cache.ndim == 2:
         kv_cache = kv_cache[None]
-        if quantized:
-            kv_scale = kv_scale[None]
     assert kv_cache.shape[2] == F, (kv_cache.shape, F)
-    SW = kv_scale.shape[2] if quantized else 0
     layer_arr = jnp.asarray([0 if layer is None else layer], jnp.int32)
 
     # Fused row space (slot-major, head-minor), shaped OUTSIDE the kernel so
     # Mosaic never sees a vector reshape.
     q_fused = qs.reshape(NT, Qt * H, F)
 
-    in_specs = [
-        pl.BlockSpec((1, Qt * H, F), lambda n, *_: (n, 0, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec(memory_space=pl.ANY),
-    ]
-    if quantized:
-        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-    scratch = [pltpu.VMEM((2, block_size, F), kv_cache.dtype)]
-    if quantized:
-        scratch.append(pltpu.VMEM((2, block_size, SW), jnp.float32))
-    scratch.append(pltpu.SemaphoreType.DMA((2, 2 if quantized else 1)))
-    scratch.append(pltpu.VMEM((Qt * H, 1), jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(NT,),
-        in_specs=in_specs,
+        in_specs=[
+            pl.BlockSpec((1, Qt * H, F), lambda n, *_: (n, 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
         out_specs=[
             pl.BlockSpec((1, Qt * H, F), lambda n, *_: (n, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
-        scratch_shapes=scratch,
+        scratch_shapes=[
+            pltpu.VMEM((2, block_size, F), kv_cache.dtype),
+            pltpu.SemaphoreType.DMA((2, 1)),
+            pltpu.VMEM((Qt * H, 1), jnp.int32),
+        ],
     )
     kernel = functools.partial(
-        _mla_prefill_kernel, block_size=block_size, num_heads=H, scale=scale,
-        quantized=quantized)
-    operands = [block_tables, seq_lens, layer_arr, tile_seq,
-                q_pos.reshape(-1), q_fused, kv_cache]
-    if quantized:
-        operands.append(kv_scale)
+        _mla_prefill_kernel, block_size=block_size, num_heads=H, scale=scale)
     (out,) = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -256,5 +211,6 @@ def mla_flash_prefill(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(*operands)
+    )(block_tables, seq_lens, layer_arr, tile_seq,
+      q_pos.reshape(-1), q_fused, kv_cache)
     return out.reshape(NT, Qt, H, F)

@@ -33,8 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from llm_d_tpu.ops.pallas.quant_util import make_page_dequant
-
 NEG_INF = -1e30
 
 
@@ -44,20 +42,17 @@ def _decode_kernel(
     seq_lens_ref,       # [S]    SMEM (context length INCLUDING the new token)
     layer_ref,          # [1]    SMEM (layer plane of the stacked cache);
                         # [2] when ``windowed``: (layer, window)
-    # inputs / outputs / scratch — layout depends on ``quantized``:
-    #   bf16:  q, kn, vn, k_hbm, v_hbm | o, k_out, v_out
-    #          | k_buf, v_buf, sems, wsems
-    #   int8:  q, kn, vn, ksn, vsn, k_hbm, v_hbm, ks_hbm, vs_hbm
-    #          | o, k_out, v_out, ks_out, vs_out
-    #          | k_buf, v_buf, ks_buf, vs_buf, sems, wsems
-    # (ksn/vsn are the new rows' [G, 1, SW] f32 scales; ks/vs the
-    #  [L, num_slots, SW] scale planes riding next to the int8 payload.)
-    *refs,
+    # inputs
+    q_ref, kn_ref, vn_ref, k_hbm, v_hbm,
+    # outputs
+    o_ref, k_out, v_out,
+    # scratch
+    k_buf, v_buf, sems, wsems,
+    *,
     block_size: int,
     num_kv_heads: int,
     scale: float,
     group: int,
-    quantized: bool,
     windowed: bool,
 ):
     """Fused decode attention + KV update on the STACKED cache.
@@ -76,28 +71,12 @@ def _decode_kernel(
     attention, and the whole (DMA-aligned) page is written back —
     single-row HBM scatters are not expressible as aligned TPU DMAs.
 
-    ``quantized``: the payload pages are int8 and each page's per-row f32
-    scales ([bs, SW]) ride a parallel DMA chain from the scale planes; the
-    page is dequantized in VMEM right after the DMA (one VPU convert+mul —
-    the price of halving the page bytes, a win while decode is DMA-bound)
-    and the new row's pre-quantized bytes + scale row are spliced and
-    written back exactly like the bf16 page.  The flash recurrence itself
-    is unchanged: bf16 MXU operands, f32 statistics.
-
     ``windowed``: the query (position seq_len - 1) sees only the last
     ``window`` keys, so each sequence's walk STARTS at the page that holds
     key seq_len - window; step j of the lockstep loop is that sequence's
     page start + j, and the pages before it are neither fetched nor
     multiplied.  A window that never binds starts every walk at page 0.
     """
-    if quantized:
-        (q_ref, kn_ref, vn_ref, ksn_ref, vsn_ref,
-         k_hbm, v_hbm, ks_hbm, vs_hbm,
-         o_ref, k_out, v_out, ks_out, vs_out,
-         k_buf, v_buf, ks_buf, vs_buf, sems, wsems) = refs
-    else:
-        (q_ref, kn_ref, vn_ref, k_hbm, v_hbm,
-         o_ref, k_out, v_out, k_buf, v_buf, sems, wsems) = refs
     i = pl.program_id(0)
     G = group
     H, D = q_ref.shape[1], q_ref.shape[2]
@@ -144,13 +123,6 @@ def _decode_kernel(
             copies.append(pltpu.make_async_copy(
                 v_hbm.at[li, pl.ds(start, bs)], v_buf.at[slot, g],
                 sems.at[slot, g, 1]))
-            if quantized:
-                copies.append(pltpu.make_async_copy(
-                    ks_hbm.at[li, pl.ds(start, bs)], ks_buf.at[slot, g],
-                    sems.at[slot, g, 2]))
-                copies.append(pltpu.make_async_copy(
-                    vs_hbm.at[li, pl.ds(start, bs)], vs_buf.at[slot, g],
-                    sems.at[slot, g, 3]))
         return copies
 
     @pl.when(n_max > 0)
@@ -177,11 +149,6 @@ def _decode_kernel(
         start_arr = jnp.zeros((G, 1, bs), jnp.int32)
         for g in range(G):
             start_arr = jnp.where(g_ids == g, start_g[g], start_arr)
-
-    if quantized:
-        SW = ksn_ref.shape[2]
-        row_ids_sw = jax.lax.broadcasted_iota(jnp.int32, (bs, SW), 0)
-        dequant = make_page_dequant(SW, F)
 
     def body(j, carry):
         m, l, acc = carry
@@ -213,20 +180,6 @@ def _decode_kernel(
                         v_buf.at[slot, g], v_out.at[li, pl.ds(start, bs)],
                         wsems.at[g, 1]),
                 ]
-                if quantized:
-                    # The new row's scale splices into the resident scale
-                    # page and rides the same whole-page write-back.
-                    is_wr_s = row_ids_sw == w_row_g[g]
-                    ks_buf[slot, g] = jnp.where(
-                        is_wr_s, ksn_ref[g], ks_buf[slot, g])
-                    vs_buf[slot, g] = jnp.where(
-                        is_wr_s, vsn_ref[g], vs_buf[slot, g])
-                    writes.append(pltpu.make_async_copy(
-                        ks_buf.at[slot, g], ks_out.at[li, pl.ds(start, bs)],
-                        wsems.at[g, 2]))
-                    writes.append(pltpu.make_async_copy(
-                        vs_buf.at[slot, g], vs_out.at[li, pl.ds(start, bs)],
-                        wsems.at[g, 3]))
                 for w in writes:
                     w.start()
                 for w in writes:
@@ -235,14 +188,8 @@ def _decode_kernel(
         # bf16 operands, f32 accumulation: the MXU runs bf16 at 2x the
         # f32 rate and the page buffers skip a VPU convert pass; the f32
         # flash statistics (m, l, acc) keep the recurrence numerics.
-        # (int8 pages pay one VPU dequant pass here — the DMA-byte halving
-        # dominates in the memory-bound decode regime.)
-        if quantized:
-            k = dequant(k_buf[slot], ks_buf[slot])            # [G, bs, F]
-            v = dequant(v_buf[slot], vs_buf[slot])
-        else:
-            k = k_buf[slot]                                   # [G, bs, F] bf16
-            v = v_buf[slot]
+        k = k_buf[slot]                                       # [G, bs, F] bf16
+        v = v_buf[slot]
         s_hb = jax.lax.dot_general(
             q_full.astype(jnp.bfloat16), k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)               # [G, H, bs]
@@ -320,17 +267,10 @@ def paged_attention_decode_update(
     layer: jax.Array | None = None,   # i32 scalar; None -> 2D caches
     interpret: bool = False,  # CPU emulation for kernel parity tests
     seq_group: int | None = None,   # sequences per grid program (None = auto)
-    k_scale: jax.Array | None = None,   # int8 caches: [L, slots, SW] f32
-    v_scale: jax.Array | None = None,   # scale planes (per page row)
-    k_scale_new: jax.Array | None = None,   # [S, SW] new rows' scales
-    v_scale_new: jax.Array | None = None,
     window: jax.Array | None = None,   # i32 scalar: keys the query sees
                                        # (itself included); None = all
 ):
-    """Returns (attn_out [S, H, D], k_cache', v_cache') — plus
-    (k_scale', v_scale') appended when the cache is int8-quantized
-    (``k_scale`` given; payload caches int8, new rows pre-quantized by the
-    caller alongside ``k_scale_new``/``v_scale_new``).
+    """Returns (attn_out [S, H, D], k_cache', v_cache').
 
     Caches may be per-layer 2D ([slots, F], ``layer=None``) or the engine's
     full stacked 3D buffer with a traced ``layer`` index — the stacked form
@@ -340,22 +280,15 @@ def paged_attention_decode_update(
     S, H, D = q.shape
     scale = scale if scale is not None else D ** -0.5
     del soft_cap  # not yet supported in the kernel (no current model needs it)
-    quantized = k_scale is not None
     squeeze = k_cache.ndim == 2
     if squeeze:
         k_cache = k_cache[None]
         v_cache = v_cache[None]
-        if quantized:
-            k_scale = k_scale[None]
-            v_scale = v_scale[None]
     F = k_cache.shape[2]
-    SW = k_scale.shape[2] if quantized else 0
-    # Per-sequence VMEM: K+V page double-buffers (+ scale pages) + f32
-    # q_full/acc pair.
+    # Per-sequence VMEM: K+V page double-buffers + f32 q_full/acc pair.
     G = pick_seq_group(
         S, seq_group,
-        4 * block_size * F * k_cache.dtype.itemsize
-        + 16 * block_size * SW + 8 * H * F)
+        4 * block_size * F * k_cache.dtype.itemsize + 8 * H * F)
     layer_arr = jnp.asarray(
         [0 if layer is None else layer]
         + ([] if window is None else [window]), jnp.int32)
@@ -365,67 +298,35 @@ def paged_attention_decode_update(
                             memory_space=pltpu.VMEM)
 
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [vspec((G, H, D)), vspec((G, 1, F)), vspec((G, 1, F))]
-    if quantized:
-        in_specs += [vspec((G, 1, SW)), vspec((G, 1, SW))]
-    in_specs += [any_spec, any_spec] + ([any_spec, any_spec]
-                                        if quantized else [])
-    out_specs = [vspec((G, H, D)), any_spec, any_spec] \
-        + ([any_spec, any_spec] if quantized else [])
-    scratch = [
-        pltpu.VMEM((2, G, block_size, F), k_cache.dtype),
-        pltpu.VMEM((2, G, block_size, F), v_cache.dtype),
-    ]
-    n_chan = 2
-    if quantized:
-        scratch += [pltpu.VMEM((2, G, block_size, SW), jnp.float32),
-                    pltpu.VMEM((2, G, block_size, SW), jnp.float32)]
-        n_chan = 4
-    scratch += [pltpu.SemaphoreType.DMA((2, G, n_chan)),
-                pltpu.SemaphoreType.DMA((G, n_chan))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(S // G,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch,
+        in_specs=[vspec((G, H, D)), vspec((G, 1, F)), vspec((G, 1, F)),
+                  any_spec, any_spec],
+        out_specs=[vspec((G, H, D)), any_spec, any_spec],
+        scratch_shapes=[
+            pltpu.VMEM((2, G, block_size, F), k_cache.dtype),
+            pltpu.VMEM((2, G, block_size, F), v_cache.dtype),
+            pltpu.SemaphoreType.DMA((2, G, 2)),
+            pltpu.SemaphoreType.DMA((G, 2)),
+        ],
     )
     kernel = functools.partial(
         _decode_kernel, block_size=block_size, num_kv_heads=num_kv_heads,
-        scale=scale, group=G, quantized=quantized,
-        windowed=window is not None)
-    out_shape = [jax.ShapeDtypeStruct((S, H, D), q.dtype),
-                 jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
-                 jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)]
-    operands = [block_tables, seq_lens, layer_arr, q,
-                k_new.reshape(S, 1, F), v_new.reshape(S, 1, F)]
-    if quantized:
-        operands += [k_scale_new.reshape(S, 1, SW),
-                     v_scale_new.reshape(S, 1, SW)]
-    operands += [k_cache, v_cache]
-    if quantized:
-        operands += [k_scale, v_scale]
-        out_shape += [jax.ShapeDtypeStruct(k_scale.shape, k_scale.dtype),
-                      jax.ShapeDtypeStruct(v_scale.shape, v_scale.dtype)]
-        # Operand indices in input_output_aliases include scalar prefetch.
-        aliases = {8: 1, 9: 2, 10: 3, 11: 4}
-    else:
-        aliases = {6: 1, 7: 2}
-    results = pl.pallas_call(
+        scale=scale, group=G, windowed=window is not None)
+    out, k_cache, v_cache = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=out_shape,
-        input_output_aliases=aliases,
+        out_shape=[jax.ShapeDtypeStruct((S, H, D), q.dtype),
+                   jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
+                   jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)],
+        # Operand indices in input_output_aliases include scalar prefetch.
+        input_output_aliases={6: 1, 7: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), has_side_effects=True),
         interpret=interpret,
-    )(*operands)
-    if quantized:
-        out, k_cache, v_cache, k_scale, v_scale = results
-        if squeeze:
-            return out, k_cache[0], v_cache[0], k_scale[0], v_scale[0]
-        return out, k_cache, v_cache, k_scale, v_scale
-    out, k_cache, v_cache = results
+    )(block_tables, seq_lens, layer_arr, q,
+      k_new.reshape(S, 1, F), v_new.reshape(S, 1, F), k_cache, v_cache)
     if squeeze:
         k_cache = k_cache[0]
         v_cache = v_cache[0]

@@ -48,69 +48,6 @@ def dequantize(q: jax.Array, scale: jax.Array, dtype=jnp.bfloat16) -> jax.Array:
     return (q.astype(jnp.float32) * scale).astype(dtype)
 
 
-# ---------------------------------------------------------------------------
-# Int8 KV-cache quantization (the kv_cache_dtype=int8 role).
-#
-# Decode is KV-byte bound (BENCH_r05: ~60% of HBM roofline at bs64 with the
-# KV stream the only byte term that grows with batch and context), so the
-# paged cache stores int8 rows plus a small f32 scale plane and every reader
-# dequantizes after the page DMA — trading cheap requant math for HBM/wire
-# bytes, the same lever the int8 expert weights pull above.
-#
-# Granularity is PER PAGE ROW (one token's folded [KVH*D] row), optionally
-# refined per KV head: a new decode row is quantized once when written and
-# never requantized when later rows join its block, which keeps the fused
-# decode kernel's page splice a pure byte splice (a per-block-shared scale
-# would force an in-kernel requantization of resident rows on every append).
-# ---------------------------------------------------------------------------
-
-# Engine-facing knob values (engine/engine.py resolves LLMD_KV_CACHE_DTYPE /
-# LLMD_KV_SCALE_GRAN / LLMD_MLA_LATENT_DTYPE through these).
-KV_CACHE_DTYPES = ("bf16", "int8")
-KV_SCALE_GRANULARITIES = ("token", "head")
-# MLA latent-row gate: "auto" follows kv_cache_dtype; "bf16"/"int8" pin
-# the latent dtype independently of the dense knob (the latent feeds TWO
-# weight absorptions, so its quantization is gated by its own accuracy
-# harness — ops/mla_accuracy.py, asserted in tests/test_mla_quant.py).
-MLA_LATENT_DTYPES = ("auto", "bf16", "int8")
-
-
-def kv_scale_width(num_kv_heads: int, granularity: str) -> int:
-    """Scale columns per cache row: 1 ("token", one scale for the whole
-    folded row) or KVH ("head", one per KV head's D-block — finer, and
-    shard-local under tp-sharded KV heads)."""
-    return num_kv_heads if granularity == "head" else 1
-
-
-def quantize_kv_block(rows: jax.Array, scale_width: int
-                      ) -> Tuple[jax.Array, jax.Array]:
-    """Symmetric int8 over KV rows ``[..., N, F]`` (F = KVH*D folded).
-
-    Returns (q int8 ``[..., N, F]``, scales f32 ``[..., N, SW]``) where each
-    scale covers one contiguous F/SW column group of its row (SW == KVH maps
-    groups onto KV heads' D-blocks).  Shape-polymorphic over leading dims so
-    the same helper serves new-row quantization ([T, F]), whole-block
-    staging ([L, bs, F]) and test oracles."""
-    f32 = rows.astype(jnp.float32)
-    *lead, n, f = f32.shape
-    g = f32.reshape(*lead, n, scale_width, f // scale_width)
-    amax = jnp.max(jnp.abs(g), axis=-1)                  # [..., N, SW]
-    scales = jnp.maximum(amax, 1e-8) / 127.0
-    q = jnp.clip(jnp.round(g / scales[..., None]), -127, 127)
-    return q.reshape(f32.shape).astype(jnp.int8), scales
-
-
-def dequantize_kv_block(q: jax.Array, scales: jax.Array,
-                        dtype=jnp.bfloat16) -> jax.Array:
-    """Inverse of :func:`quantize_kv_block`: ``[..., N, F]`` int8 + scales
-    ``[..., N, SW]`` -> rows in ``dtype``."""
-    *lead, n, f = q.shape
-    sw = scales.shape[-1]
-    g = q.astype(jnp.float32).reshape(*lead, n, sw, f // sw)
-    return (g * scales[..., None].astype(jnp.float32)).reshape(
-        q.shape).astype(dtype)
-
-
 def quantize_moe_experts(params: Dict[str, Any],
                          donate: bool = False) -> Dict[str, Any]:
     """Replace moe_layers expert weights with int8 payload + scale pairs.

@@ -1,15 +1,13 @@
 """EQuARX-style quantized collectives for the wide-EP / TP path.
 
-PRs 5-6 made int8 first-class for every HBM and storage surface (paged
-KV, MLA latent, offload slabs, P->D wire) but the *interconnect* still
-moved full-width activations: the EP dispatch shipped bf16 rows and the
-combine return shipped f32 rows — 2-4x the ICI bytes the payload needs.
+The expert weights are int8, but the *interconnect* moved full-width
+activations: the EP dispatch shipped bf16 rows and the combine return
+shipped f32 rows — 2-4x the ICI bytes the payload needs.
 EQuARX (PAPERS.md) shows block-scaled int8 AllReduce at negligible
 quality cost; this module is that trade expressed over JAX collectives:
 
   - :func:`quantize_rows` / :func:`dequantize_rows` — the per-row
-    symmetric f32-scale wire format every quantized collective ships
-    (the same scale machinery as the int8 KV cache, ``ops.quant``).
+    symmetric f32-scale wire format every quantized collective ships.
     The scale plane rides the SAME collective primitive as the payload
     (a sibling exchange), so ragged and dense fallbacks stay byte-wise
     identical in what they deliver per row.
@@ -49,7 +47,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from llm_d_tpu.ops.quant import dequantize_kv_block, quantize_kv_block
+from llm_d_tpu.ops.quant import dequantize
 from llm_d_tpu.utils.config import env_choice
 
 # Engine/env-facing knob values (``auto`` follows the backend: int8 on
@@ -96,18 +94,19 @@ def resolve_collective_dtype(explicit: Optional[str] = None,
 def quantize_rows(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """``[..., N, H]`` rows -> (int8 payload, f32 scales ``[..., N]``).
 
-    Symmetric per-row quantization — the identical scale machinery the
-    int8 KV cache uses (one scale covers the whole row), flattened to a
-    1-D scale vector so it rides the same exchange primitives as the
+    Symmetric per-row quantization (one scale covers the whole row), the
+    scales a 1-D vector so they ride the same exchange primitives as the
     1-D index plane."""
-    q, s = quantize_kv_block(x, 1)
-    return q, s[..., 0]
+    f32 = x.astype(jnp.float32)
+    scales = jnp.maximum(jnp.max(jnp.abs(f32), axis=-1), 1e-8) / 127.0
+    q = jnp.clip(jnp.round(f32 / scales[..., None]), -127, 127)
+    return q.astype(jnp.int8), scales
 
 
 def dequantize_rows(q: jax.Array, scales: jax.Array,
                     dtype=jnp.float32) -> jax.Array:
     """Inverse of :func:`quantize_rows` (scales ``[..., N]``)."""
-    return dequantize_kv_block(q, scales[..., None], dtype)
+    return dequantize(q, scales[..., None], dtype)
 
 
 def quantized_psum(x: jax.Array, axis_name, num_shards: int,

@@ -1153,7 +1153,6 @@ def engine_config_from_args(args) -> EngineConfig:
             s.strip() for s in args.kv_shared_tier_peers.split(",")
             if s.strip()),
         quantization=args.quantization,
-        kv_cache_dtype=args.kv_cache_dtype,
         kv_cache_hbm_bytes=(int(args.kv_cache_hbm_gb * 2**30)
                             if args.kv_cache_hbm_gb else None),
         enable_dbo=args.enable_dbo,
@@ -1271,17 +1270,9 @@ def build_arg_parser() -> argparse.ArgumentParser:
         help="MoE expert-weight quantization (DeepGEMM role; halves "
              "expert HBM residency)")
     p.add_argument(
-        "--kv-cache-dtype", default=None, choices=[None, "bf16", "int8"],
-        help="paged-KV cache dtype: int8 stores per-page-row-scaled "
-             "payloads + f32 scale planes — halves decode HBM/DMA bytes, "
-             "~doubles the block pool at a fixed budget, halves P->D and "
-             "offload payloads (dense K/V AND the MLA latent row; "
-             "LLMD_MLA_LATENT_DTYPE gates the latent separately). "
-             "Default: LLMD_KV_CACHE_DTYPE (bf16)")
-    p.add_argument(
         "--kv-cache-hbm-gb", type=float, default=None,
-        help="auto-size --num-blocks from this HBM budget (dtype-aware: "
-             "int8 fits ~2x the blocks); overrides --num-blocks")
+        help="auto-size --num-blocks from this HBM budget; overrides "
+             "--num-blocks")
     p.add_argument(
         "--enable-dbo", action="store_true",
         help="MoE dual-batch overlap: >=2 dispatch chunks above the token "

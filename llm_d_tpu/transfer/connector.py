@@ -46,10 +46,10 @@ from llm_d_tpu.utils.faultinject import FaultInjected, get_injector
 logger = logging.getLogger(__name__)
 
 _MAGIC = 0x4B565442  # "KVTB"
-# Wire version 2 (kv_cache_dtype era): every buffer segment carries a
-# dtype code so a consumer REJECTS a producer whose cache dtype differs
-# (a bf16 decoder must never silently reinterpret an int8+scales slab —
-# wrong page bytes would decode as garbage attention, not an error).
+# Wire version 2: every buffer segment carries a dtype code so a consumer
+# REJECTS a producer whose cache dtype differs (a bf16 decoder must never
+# silently reinterpret the int8+scales slab of a peer from an older build
+# — wrong page bytes would decode as garbage attention, not an error).
 _WIRE_VERSION = 2
 # magic, version, num_layers, block_size, num_buffers, nb
 _HEADER = struct.Struct("<IIIIII")
@@ -479,9 +479,6 @@ def _pack_blocks(engine, block_ids: List[int]) -> bytes:
     items = _cache_items(engine)
     L = items[0][1].shape[0] if shard is None else items[0][1].shape[1]
     parts = [_HEADER.pack(_MAGIC, _WIRE_VERSION, L, bs, len(items), nb)]
-    # int8 caches ship int8 rows + their f32 scale planes as ordinary
-    # buffer segments (the scale planes live in engine.kv_cache) — the
-    # P->D payload is ~half the bf16 bytes, the NetKV lever.
     for _, buf in items:
         if shard is None:
             slab = _gather_fn(nb_pad, bs)(buf, ids_dev)
@@ -511,8 +508,8 @@ def _scatter_blocks(engine, block_ids: List[int], blob: bytes) -> None:
     if (bL, bbs, n_bufs) != (L, bs, len(items)):
         raise ValueError(
             f"slab layout {(bL, bbs, n_bufs)} != cache layout "
-            f"{(L, bs, len(items))} (kv_cache_dtype mismatch between "
-            "producer and consumer changes the buffer set)")
+            f"{(L, bs, len(items))} (a producer of another cache dtype "
+            "ships another buffer set)")
     nb = len(block_ids)
     if bnb < nb:
         raise ValueError(f"slab has {bnb} blocks, need {nb}")
@@ -539,11 +536,11 @@ def _scatter_blocks(engine, block_ids: List[int], blob: bytes) -> None:
             raise ValueError(str(e)) from e
         if dtype != np.dtype(buf.dtype):
             # Explicit dtype-mismatch rejection: a bf16 decoder never
-            # silently reinterprets an int8 producer's blocks (or vice
-            # versa) — kv_cache_dtype must match across the P->D pair.
+            # silently reinterprets an int8 producer's blocks — the cache
+            # dtype must match across the P->D pair.
             raise ValueError(
                 f"buffer {name!r}: producer shipped {dtype} but the local "
-                f"cache is {np.dtype(buf.dtype)} — kv_cache_dtype "
+                f"cache is {np.dtype(buf.dtype)} — cache dtype "
                 "mismatch, refusing to reinterpret")
         count = L * bnb * bs * width
         payload = np.frombuffer(blob, dtype=dtype, offset=off, count=count)

@@ -111,9 +111,10 @@ def _load_native() -> ctypes.CDLL:
 # transport (the PD slab wire in transfer/connector.py and the offload
 # tier's packed-block format in engine/offload.py).  A one-byte code per
 # buffer segment lets a receiver REJECT a dtype-mismatched producer —
-# an int8+scales cache must never be silently reinterpreted as bf16 rows
-# (kv_cache_dtype=int8 ships half the bytes; the byte count alone would
-# already misparse, but the code makes the failure a named error).
+# a peer of an older build may still send int8 rows + f32 scale planes,
+# which must never be silently reinterpreted as bf16 rows (the byte count
+# alone would already misparse, but the code makes the failure a named
+# error).
 # ---------------------------------------------------------------------------
 
 WIRE_DTYPE_BF16 = 0

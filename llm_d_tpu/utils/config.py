@@ -55,7 +55,7 @@ def env_float(name: str, default: float) -> float:
 
 def env_choice(name: str, default: str, choices: Sequence[str]) -> str:
     """Enumerated string env knob with invalid-value fallback: an unknown
-    value (``LLMD_KV_CACHE_DTYPE=fp4``) must degrade to the shipped default
+    value (``LLMD_COLLECTIVE_DTYPE=fp4``) must degrade to the shipped default
     with a warning, not crash the serving path (see :func:`env_int`)."""
     raw = os.environ.get(name)
     if raw is None:

@@ -37,14 +37,10 @@ python -m pytest tests/test_stream_recovery.py -q
 # decomposition summing to measured TTFT within 5%, sampling knobs,
 # the TRACE coverage rules, and the no-host-sync JIT meta-guard.
 python -m pytest tests/test_tracing.py -q
-# int8 paged-KV contract fail-fast (kv_cache_dtype=int8: kernel/fallback
-# parity bounds, offload scale round-trip, wire dtype rejection, pool
-# sizing): a silent KV-numerics or wire-format break must not merge.
-python -m pytest tests/test_kv_quant.py -q
-# int8 MLA LATENT contract fail-fast (round 9: quantized MLA kernels,
-# per-absorption accuracy bounds on real traces, latent wire/offload
-# round-trips): the flagship MoE bench serves on this cache.
-python -m pytest tests/test_mla_quant.py -q
+# Attention numerics fail-fast: the served attention entry points against
+# plain f32 attention at the cells' head geometry, pool sizing from an HBM
+# budget: a silent KV-numerics break must not merge.
+python -m pytest tests/test_attention_oplevel.py tests/test_kv_cache.py -q
 # Quantized EP/TP collective contract fail-fast (round 10: int8
 # dispatch/combine wire + quantized allreduce parity, scale-plane
 # alignment, per-collective accuracy bounds on real routed traces,

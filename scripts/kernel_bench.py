@@ -135,213 +135,6 @@ def _recommend(points: list) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# Paged-attention sweep (context-length x cache dtype): the decode kernel's
-# bf16-vs-int8 crossover table, the KV-bytes analogue of the MoE table
-# above.  Int8 halves the per-page DMA bytes but pays a VPU dequant pass
-# per page, so the win grows with context (more pages per step) — this
-# sweep measures where it starts on a real chip; --interpret runs the same
-# glue on CPU for tier-1 (timings flagged invalid).
-# ---------------------------------------------------------------------------
-
-def _paged_case(key, S, KVH, D, bs, ctx, num_layers=2, plane=1):
-    """Engine-shaped decode case over a stacked cache at context ``ctx``."""
-    import numpy as np
-    rng = np.random.default_rng(int(jax.random.randint(key, (), 0, 1 << 30)))
-    H = KVH * 4
-    F = KVH * D
-    B = -(-ctx // bs)
-    num_blocks = S * B + 1
-    shape = (num_layers, num_blocks * bs, F)
-    k_cache = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-    v_cache = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-    perm = rng.permutation(num_blocks - 1)[: S * B] + 1
-    bt = jnp.asarray(perm.reshape(S, B), jnp.int32)
-    lens = jnp.asarray(
-        np.clip(ctx - rng.integers(0, bs, S), 1, ctx), jnp.int32)
-    q = jnp.asarray(rng.standard_normal((S, H, D)), jnp.bfloat16)
-    k_new = jnp.asarray(rng.standard_normal((S, F)), jnp.bfloat16)
-    v_new = jnp.asarray(rng.standard_normal((S, F)), jnp.bfloat16)
-    return q, k_new, v_new, k_cache, v_cache, bt, lens, \
-        jnp.asarray(plane, jnp.int32)
-
-
-def _paged_thunks(case, bs, KVH, interpret):
-    """dtype -> thunk running the REAL decode kernel at that cache dtype."""
-    from llm_d_tpu.ops.pallas.paged_attention import (
-        paged_attention_decode_update)
-    from llm_d_tpu.ops.quant import quantize_kv_block
-    q, k_new, v_new, k_cache, v_cache, bt, lens, plane = case
-
-    def bf16():
-        return paged_attention_decode_update(
-            q, k_new, v_new, k_cache, v_cache, bt, lens, block_size=bs,
-            num_kv_heads=KVH, layer=plane, interpret=interpret)[0]
-
-    kq, ks = quantize_kv_block(k_cache, 1)
-    vq, vs = quantize_kv_block(v_cache, 1)
-    knq, kns = quantize_kv_block(k_new, 1)
-    vnq, vns = quantize_kv_block(v_new, 1)
-
-    def int8():
-        return paged_attention_decode_update(
-            q, knq, vnq, kq, vq, bt, lens, block_size=bs,
-            num_kv_heads=KVH, layer=plane, interpret=interpret,
-            k_scale=ks, v_scale=vs, k_scale_new=kns, v_scale_new=vns)[0]
-
-    return {"bf16": bf16, "int8": int8}
-
-
-def run_paged(args) -> dict:
-    if args.interpret:
-        S, KVH, D, bs = 4, 2, 64, 32
-        sweep = [64, 128]
-        iters = args.iters or 1
-    else:
-        S, KVH, D, bs = 64, 8, 128, 64       # llama3-1b bench shapes
-        sweep = [256, 512, 1024, 2048, 4096]
-        iters = args.iters or 10
-    if args.ctx_sweep:
-        sweep = [int(t) for t in args.ctx_sweep.split(",") if t]
-    points = []
-    for i, ctx in enumerate(sweep):
-        case = _paged_case(jax.random.PRNGKey(i), S, KVH, D, bs, ctx)
-        thunks = _paged_thunks(case, bs, KVH, args.interpret)
-        from llm_d_tpu.engine.engine import kv_bytes_per_token
-        F = KVH * D
-        layout = {"k": F, "v": F}
-        ms = {name: round(_time_ms(t, iters), 3)
-              for name, t in thunks.items()}
-        points.append({
-            "ctx": ctx, "ms": ms,
-            # Per-step KV bytes each dtype streams at this context (pages
-            # + int8 scale plane, same accounting the engine's pool sizing
-            # charges) — the denominator of the crossover.
-            "kv_mb_per_step": {
-                dtype: round(
-                    S * ctx * kv_bytes_per_token(layout, dtype, 1) / 1e6, 3)
-                for dtype in ("bf16", "int8")
-            }})
-    crossover = None
-    for p in points:
-        if p["ms"]["int8"] <= p["ms"]["bf16"]:
-            crossover = p["ctx"]
-            break
-    return {
-        "mode": "paged_attention",
-        "backend": jax.default_backend(),
-        "interpret": args.interpret,
-        "timings_valid": not args.interpret,
-        "shapes": {"S": S, "KVH": KVH, "D": D, "block_size": bs},
-        "iters": iters,
-        "points": points,
-        "crossover": {"int8_faster_from_ctx": crossover,
-                      "LLMD_KV_CACHE_DTYPE":
-                          "int8" if crossover is not None else "bf16"},
-    }
-
-
-# ---------------------------------------------------------------------------
-# MLA decode sweep (context-length x latent dtype): the MLA decode kernel's
-# bf16-vs-int8 LATENT crossover table, mirroring --paged for the single
-# latent buffer.  The latent stream is the only per-step byte term that
-# grows with batch and context on the MoE bench model, so this table is
-# where the LLMD_MLA_* knobs (and the kv_cache_dtype=int8 default for MLA)
-# get re-derived on a real chip; --interpret runs the same glue on CPU for
-# tier-1 (timings flagged invalid).
-# ---------------------------------------------------------------------------
-
-def _mla_case(key, S, H, F, bs, ctx, num_layers=2, plane=1):
-    """Engine-shaped MLA decode case over a stacked latent cache."""
-    import numpy as np
-    rng = np.random.default_rng(int(jax.random.randint(key, (), 0, 1 << 30)))
-    B = -(-ctx // bs)
-    num_blocks = S * B + 1
-    kv = jnp.asarray(
-        rng.standard_normal((num_layers, num_blocks * bs, F)), jnp.bfloat16)
-    perm = rng.permutation(num_blocks - 1)[: S * B] + 1
-    bt = jnp.asarray(perm.reshape(S, B), jnp.int32)
-    lens = jnp.asarray(
-        np.clip(ctx - rng.integers(0, bs, S), 1, ctx), jnp.int32)
-    q = jnp.asarray(rng.standard_normal((S, H, F)), jnp.bfloat16)
-    row = jnp.asarray(rng.standard_normal((S, F)), jnp.bfloat16)
-    return q, row, kv, bt, lens, jnp.asarray(plane, jnp.int32)
-
-
-def _mla_thunks(case, bs, interpret):
-    """dtype -> thunk running the REAL MLA decode kernel at that latent
-    dtype (int8: pre-quantized rows + the sibling scale plane)."""
-    from llm_d_tpu.ops.pallas.mla_attention import mla_paged_decode_update
-    from llm_d_tpu.ops.quant import quantize_kv_block
-    q, row, kv, bt, lens, plane = case
-    scale = q.shape[-1] ** -0.5
-
-    def bf16():
-        return mla_paged_decode_update(
-            q, row, kv, bt, lens, block_size=bs, scale=scale, layer=plane,
-            interpret=interpret)[0]
-
-    kq, ks = quantize_kv_block(kv, 1)
-    rq, rs = quantize_kv_block(row, 1)
-
-    def int8():
-        return mla_paged_decode_update(
-            q, rq, kq, bt, lens, block_size=bs, scale=scale, layer=plane,
-            interpret=interpret, kv_scale=ks, row_scale_new=rs)[0]
-
-    return {"bf16": bf16, "int8": int8}
-
-
-def run_mla(args) -> dict:
-    if args.interpret:
-        S, H, F, bs = 4, 4, 128, 32
-        sweep = [64, 128]
-        iters = args.iters or 1
-    else:
-        # deepseek-v3-bench decode shapes at the gated bs256 point:
-        # H=16 heads, F = 512 + 64 lane-padded to 640.
-        S, H, F, bs = 256, 16, 640, 64
-        sweep = [256, 512, 1024, 2048, 4096]
-        iters = args.iters or 10
-    if args.ctx_sweep:
-        sweep = [int(t) for t in args.ctx_sweep.split(",") if t]
-    points = []
-    from llm_d_tpu.engine.engine import kv_bytes_per_token
-    layout = {"kv": F}
-    for i, ctx in enumerate(sweep):
-        case = _mla_case(jax.random.PRNGKey(i), S, H, F, bs, ctx)
-        thunks = _mla_thunks(case, bs, args.interpret)
-        ms = {name: round(_time_ms(t, iters), 3)
-              for name, t in thunks.items()}
-        points.append({
-            "ctx": ctx, "ms": ms,
-            # Per-step latent bytes each dtype streams at this context
-            # (pages + the int8 scale plane; same accounting the engine's
-            # pool sizing and bench's roofline charge).
-            "kv_mb_per_step": {
-                dtype: round(
-                    S * ctx * kv_bytes_per_token(layout, dtype, 1) / 1e6, 3)
-                for dtype in ("bf16", "int8")
-            }})
-    crossover = None
-    for p in points:
-        if p["ms"]["int8"] <= p["ms"]["bf16"]:
-            crossover = p["ctx"]
-            break
-    return {
-        "mode": "mla_decode",
-        "backend": jax.default_backend(),
-        "interpret": args.interpret,
-        "timings_valid": not args.interpret,
-        "shapes": {"S": S, "H": H, "F": F, "block_size": bs},
-        "iters": iters,
-        "points": points,
-        "crossover": {"int8_faster_from_ctx": crossover,
-                      "LLMD_MLA_LATENT_DTYPE":
-                          "int8" if crossover is not None else "bf16"},
-    }
-
-
-# ---------------------------------------------------------------------------
 # EP all-to-all sweep (tokens x collective dtype): the quantized-wire
 # crossover table for the wide-EP dispatch/combine (round 10;
 # parallel/quant_collectives.py).  Three wire modes through the REAL
@@ -438,13 +231,13 @@ def run_spec(args) -> dict:
 
     if args.interpret:
         model, bs, prompt_len, decode_steps = "tiny", 4, 16, 12
-        quant = kvd = None
+        quant = None
         sweep = [1, 2, 4]
         vocab = 500
     else:
         model, bs, prompt_len, decode_steps = ("deepseek-v3-bench", 256,
                                                128, 64)
-        quant, kvd = "int8", "int8"
+        quant = "int8"
         sweep = [1, 2, 4, 8]
         vocab = 32000
     if args.k_sweep:
@@ -485,7 +278,7 @@ def run_spec(args) -> dict:
             num_blocks=bs * blocks_per_seq + block_size,
             max_num_seqs=bs, max_num_batched_tokens=8192,
             enable_prefix_caching=False, quantization=quant,
-            kv_cache_dtype=kvd, spec_k=K, spec_fixed_accept=accept))
+            spec_k=K, spec_fixed_accept=accept))
         assert engine.spec_k == K, "spec decode failed to arm"
         run_workload(engine, make_reqs(f"warm{K}", 50000))  # compile pass
         reqs = make_reqs(f"spec{K}", 1000)
@@ -780,14 +573,6 @@ def main(argv=None) -> int:
                     help="tiny shapes through the Pallas interpreter "
                          "(CPU CI: exercises every kernel's dispatch "
                          "glue; timings not meaningful)")
-    ap.add_argument("--paged", action="store_true",
-                    help="run the paged-attention context x dtype sweep "
-                         "(bf16 vs int8 KV cache) instead of the MoE "
-                         "kernel family")
-    ap.add_argument("--mla", action="store_true",
-                    help="run the MLA decode context x latent-dtype sweep "
-                         "(bf16 vs int8 latent cache) instead of the MoE "
-                         "kernel family")
     ap.add_argument("--a2a", action="store_true",
                     help="run the EP all-to-all tokens x collective-dtype "
                          "sweep (bf16 / int8 dispatch-only / int8 both "
@@ -829,9 +614,6 @@ def main(argv=None) -> int:
                     help="spec mode: seeded per-draft acceptance rate "
                          "(bench.py SPEC_BENCH_ACCEPT quotes the gated "
                          "metric at the same rate)")
-    ap.add_argument("--ctx-sweep", type=str, default=None,
-                    help="paged/mla mode: comma-separated context lengths "
-                         "(default: 256..4096 on chip, 64,128 interpreted)")
     ap.add_argument("--t-sweep", type=str, default=None,
                     help="comma-separated token counts (default: "
                          "64..8192 on chip, 8..64 interpreted)")
@@ -855,11 +637,8 @@ def main(argv=None) -> int:
               f"wiring smoke", file=sys.stderr)
         return 1
 
-    if (args.paged or args.mla or args.a2a or args.spec or args.mixed
-            or args.eplb):
-        doc = (run_paged(args) if args.paged
-               else run_mla(args) if args.mla
-               else run_spec(args) if args.spec
+    if args.a2a or args.spec or args.mixed or args.eplb:
+        doc = (run_spec(args) if args.spec
                else run_mixed(args) if args.mixed
                else run_eplb(args) if args.eplb else run_a2a(args))
         text = json.dumps(doc)

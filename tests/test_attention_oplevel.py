@@ -1,0 +1,223 @@
+"""The served attention entry points against plain float32 attention.
+
+``ops.attention.attention_with_kv_update`` (GQA) and ``models.mla``'s
+``_mla_attend`` (the absorbed latent row) are what every step program
+calls once a layer: they write the step's new rows into the paged cache
+and attend.  Here each is handed a filled cache at the head geometry the
+benchmark's cells and ``chip_smoke.py --chips 4`` serve, and its output
+is held against a numpy softmax(q k^T scale + mask) v on the SAME q, k, v
+before any of them was rounded to bf16 — a reference that shares nothing
+with ``ops/``.  The kernel parity tests compare one implementation with
+another; this one bounds what the served path costs against exact
+attention, which is what an end-to-end ``correct`` cannot see (PERF.md
+Open question 14).
+
+The bound is one number a family, set from what bf16 rows, bf16 MXU
+operands and a bf16 result cost against float32 (at most 0.0039 over the
+GQA cases, 0.0048 over the MLA ones, whose row is 576 wide) with about
+half as much again for head-room.  Three cases prove it bites: the same
+check over a cache whose rows went through int8 and back (0.0074 to
+0.0115) must exceed it.
+"""
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from llm_d_tpu.models import mla as mla_mod
+from llm_d_tpu.ops import attention as A
+
+BS = 32           # the cells' page
+LAYERS, LAYER = 2, 1   # a stacked cache, attended at a plane that is not 0
+
+# Head geometry as ONE shard sees it, and the family's bound on
+# rms(out - exact) / rms(exact) over the step's real tokens.
+FAMILIES = {
+    # qwen3-30b-a3b and trinity-mini: 32 heads over 4 KV heads of 128.
+    "gqa": dict(H=32, KVH=4, D=128, tol=5.5e-3),
+    # trinity-mini's sliding layers, the window cut to 64 so that contexts
+    # of a few pages cross it.
+    "gqa-window": dict(H=32, KVH=4, D=128, window=64, tol=5.5e-3),
+    # chip_smoke --chips 4, llama3-1b under --tensor-parallel-size 4.
+    "gqa-tp4": dict(H=8, KVH=1, D=128, tol=5.5e-3),
+    # kanana-2-30b-a3b: 32 heads over one latent row of 512 + 64, padded
+    # to 640 lanes; softmax scale of the unabsorbed 128 + 64 query.
+    "mla": dict(H=32, R=512, ROPE=64, F=640, tol=7e-3),
+    "mla-tp4": dict(H=8, R=512, ROPE=64, F=640, tol=7e-3),
+}
+
+# (cached tokens, new tokens) a row; the buckets (T, S, Q) the engine
+# would pad the step to.
+PHASES = {
+    # contexts 1, 31, 32, 33, 150 with the new token: page edges at 32.
+    "decode": dict(rows=[(0, 1), (30, 1), (31, 1), (32, 1), (149, 1)],
+                   T=8, S=8, Q=1),
+    "prefill": dict(rows=[(0, 70)], T=128, S=4, Q=128),
+    "chunk": dict(rows=[(90, 70)], T=128, S=4, Q=128),
+    "mixed": dict(rows=[(30, 1), (149, 1), (90, 70), (32, 1)],
+                  T=128, S=8, Q=128),
+}
+
+
+def _batch(rows, T, S, Q, bt):
+    """The index arrays ``_fill_batch`` writes for ``rows``, pad rows and
+    pad tokens included."""
+    qtok = np.full((S, Q), T, np.int32)
+    seq, qpos, pos, slot = (np.zeros(T, np.int32) for _ in range(4))
+    lens = np.zeros(S, np.int32)
+    t = 0
+    for s, (cached, n) in enumerate(rows):
+        p = np.arange(cached, cached + n)
+        qtok[s, :n] = np.arange(t, t + n)
+        seq[t:t + n], qpos[t:t + n], pos[t:t + n] = s, np.arange(n), p
+        slot[t:t + n] = bt[s, p // BS] * BS + p % BS
+        lens[s] = cached + n
+        t += n
+    return {k: jnp.asarray(v) for k, v in dict(
+        qtok_idx=qtok, token_seq_ids=seq, token_qpos=qpos, positions=pos,
+        slot_mapping=slot, seq_lens=lens, block_tables=bt).items()}
+
+
+def _through_int8(rows):
+    """Rows through symmetric int8 with one scale a row, and back."""
+    scale = np.maximum(np.abs(rows).max(axis=-1, keepdims=True), 1e-8) / 127.0
+    return np.clip(np.round(rows / scale), -127, 127) * scale
+
+
+def _exact(q, keys, values, pos, scale, group, window):
+    """softmax(q k^T scale + causal / window mask) v in float32: ``q``
+    [n, H, D] at positions ``pos`` over one row's ``keys`` [C, KVH, D] and
+    ``values`` [C, KVH, Dv]; head h reads KV head h // group."""
+    kh = np.repeat(keys, group, axis=1)
+    vh = np.repeat(values, group, axis=1)
+    s = np.einsum("nhd,chd->nhc", q, kh) * scale
+    j = np.arange(keys.shape[0])[None, :]
+    seen = j <= pos[:, None]
+    if window is not None:
+        seen &= j > pos[:, None] - window
+    s = np.where(seen[:, None, :], s, -np.inf)
+    p = np.exp(s - s.max(axis=-1, keepdims=True))
+    p /= p.sum(axis=-1, keepdims=True)
+    return np.einsum("nhc,chd->nhd", p, vh)
+
+
+def _interpreted(monkeypatch):
+    """The Pallas backend on the CPU: all four kernels interpreted.
+    Returns the list their names are appended to, a call each."""
+    import llm_d_tpu.ops.pallas.flash_prefill as fp
+    import llm_d_tpu.ops.pallas.mla_attention as ma
+    import llm_d_tpu.ops.pallas.mla_prefill as mp
+    import llm_d_tpu.ops.pallas.paged_attention as pa
+    calls = []
+    for mod, name in ((pa, "paged_attention_decode_update"),
+                      (fp, "flash_prefill_paged"),
+                      (ma, "mla_paged_decode_update"),
+                      (mp, "mla_flash_prefill")):
+        real = getattr(mod, name)
+
+        def interpreted(*a, _real=real, _name=name, **kw):
+            calls.append(_name)
+            return _real(*a, **{**kw, "interpret": True})
+
+        monkeypatch.setattr(mod, name, interpreted)
+    return calls
+
+
+def _served_against_exact(monkeypatch, family, phase, path, cache="bf16"):
+    """rms(served - exact) / rms(exact) over the step's real tokens."""
+    g, ph = FAMILIES[family], PHASES[phase]
+    mla = family.startswith("mla")
+    rng = np.random.default_rng(sum(map(ord, family + phase)))
+    rows, T, S, Q = ph["rows"], ph["T"], ph["S"], ph["Q"]
+    H = g["H"]
+    KVH, D = (1, g["R"] + g["ROPE"]) if mla else (g["KVH"], g["D"])
+    F = g["F"] if mla else KVH * D
+    window = g.get("window")
+    scale = (128 + g["ROPE"]) ** -0.5 if mla else D ** -0.5
+
+    pages = -(-max(c + n for c, n in rows) // BS)
+    bt = np.zeros((S, pages), np.int32)
+    bt[:len(rows)] = (rng.permutation(len(rows) * pages) + 1).reshape(
+        len(rows), pages)
+    batch = _batch(rows, T, S, Q, bt)
+    calls = None
+    if path == "pallas":
+        calls = _interpreted(monkeypatch)
+        batch = A.with_query_tiles(batch, H, F, "pallas", mla=mla)
+
+    # float32 q, k, v of every row's whole context; the cache holds the
+    # cached part, the step is handed the new part, both rounded to bf16.
+    n_caches = 1 if mla else 2
+    caches = [np.zeros((LAYERS, (len(rows) * pages + 1) * BS, F), np.float32)
+              for _ in range(n_caches)]
+    q_new = np.zeros((T, H, D), np.float32)
+    kv_new = [np.zeros((T, KVH, D), np.float32) for _ in range(n_caches)]
+    exact = []
+    t = 0
+    for s, (cached, n) in enumerate(rows):
+        ctx = cached + n
+        q = rng.standard_normal((n, H, D)).astype(np.float32)
+        kv = [rng.standard_normal((ctx, KVH, D)).astype(np.float32)
+              for _ in range(n_caches)]
+        pos = np.arange(cached, ctx)
+        exact.append(_exact(
+            q, kv[0], kv[0][..., :g["R"]] if mla else kv[1], pos, scale,
+            H // KVH, window))
+        slots = bt[s, np.arange(cached) // BS] * BS + np.arange(cached) % BS
+        for c, new, x in zip(caches, kv_new, kv):
+            old = x[:cached].reshape(cached, KVH * D)
+            if cache == "int8":
+                old = _through_int8(old)
+            c[LAYER, slots, :KVH * D] = old
+            new[t:t + n] = x[cached:]
+        q_new[t:t + n] = q
+        t += n
+    exact = np.concatenate(exact)
+
+    caches = [jnp.asarray(c, jnp.bfloat16) for c in caches]
+    layer = jnp.int32(LAYER)
+    if mla:
+        pad = ((0, 0), (0, 0), (0, F - D))
+        out, _ = mla_mod._mla_attend(
+            jnp.asarray(np.pad(q_new, pad), jnp.bfloat16),
+            jnp.asarray(np.pad(kv_new[0], pad)[:, 0], jnp.bfloat16),
+            caches[0], batch, layer, block_size=BS, backend=path,
+            scale=scale, R=g["R"])
+    else:
+        out, _, _ = A.attention_with_kv_update(
+            jnp.asarray(q_new, jnp.bfloat16),
+            jnp.asarray(kv_new[0], jnp.bfloat16),
+            jnp.asarray(kv_new[1], jnp.bfloat16), caches[0], caches[1],
+            batch, block_size=BS, backend=path, layer=layer,
+            window=None if window is None else jnp.int32(window))
+    if calls is not None:       # the kernel served it, not a fallback
+        assert calls == [{
+            (False, True): "paged_attention_decode_update",
+            (False, False): "flash_prefill_paged",
+            (True, True): "mla_paged_decode_update",
+            (True, False): "mla_flash_prefill"}[mla, Q == 1]]
+    out = np.asarray(out, np.float32)[:t]
+    assert out.shape == exact.shape and np.all(np.isfinite(out))
+    return float(np.sqrt(np.mean((out - exact) ** 2) / np.mean(exact ** 2)))
+
+
+CASES = [(family, phase, path, "bf16")
+         for family in FAMILIES for phase in PHASES
+         for path in ("pallas", "chunked")]
+# The guard that the bound bites: rows that went through int8 exceed it.
+CASES += [(family, "chunk", "chunked", "int8")
+          for family in ("gqa", "gqa-window", "mla")]
+
+
+@pytest.mark.parametrize(
+    "family,phase,path,cache", CASES,
+    ids=["-".join(c[:3]) + ("-int8-rows" if c[3] == "int8" else "")
+         for c in CASES])
+def test_served_attention_against_exact(monkeypatch, family, phase, path,
+                                        cache):
+    err = _served_against_exact(monkeypatch, family, phase, path, cache)
+    tol = FAMILIES[family]["tol"]
+    if cache == "bf16":
+        assert err < tol, (err, tol)
+    else:
+        assert err > tol, (err, tol)

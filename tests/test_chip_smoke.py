@@ -41,11 +41,10 @@ def test_server_phase_tiny(smoke):
     assert info["attn_parity"] <= smoke.ATTN_LOGPROB_TOL
 
 
-@pytest.mark.parametrize("latent", ["bf16", "int8"])
-def test_moe_phase_tiny_mla(smoke, latent):
+def test_moe_phase_tiny_mla(smoke):
     info = smoke.moe_phase(
         "tiny-mla", n_seqs=12, prompt_len=6, max_tokens=10, seed=0,
-        expect_kernels=False, latent_dtype=latent,
+        expect_kernels=False,
         cfg_overrides=dict(TINY, block_size=8, num_blocks=128,
                            max_num_seqs=16, max_num_batched_tokens=64,
                            num_scheduler_steps=4),

@@ -46,55 +46,6 @@ def test_kernel_bench_interpret_exercises_all_paths(tmp_path, capsys):
         assert key in xo
 
 
-def test_kernel_bench_paged_sweep_interpret(tmp_path, capsys):
-    """--paged: the context x dtype decode-kernel sweep runs both cache
-    dtypes through the REAL paged_attention_decode_update glue (bf16 and
-    int8+scales) on the interpreter and derives the crossover block."""
-    mod = _kernel_bench()
-    out = tmp_path / "paged.json"
-    rc = mod.main(["--paged", "--interpret", "--ctx-sweep", "48,96",
-                   "--out", str(out)])
-    assert rc == 0
-    doc = json.loads(out.read_text())
-    assert doc == json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert doc["mode"] == "paged_attention"
-    assert doc["timings_valid"] is False
-    assert [p["ctx"] for p in doc["points"]] == [48, 96]
-    for p in doc["points"]:
-        for dtype in ("bf16", "int8"):
-            assert isinstance(p["ms"][dtype], float) and p["ms"][dtype] > 0
-        # The byte accounting the crossover explains: int8 streams about
-        # half the page bytes (+ the f32 scale plane).
-        assert p["kv_mb_per_step"]["int8"] < 0.6 * p["kv_mb_per_step"]["bf16"]
-    assert "int8_faster_from_ctx" in doc["crossover"]
-    assert "LLMD_KV_CACHE_DTYPE" in doc["crossover"]
-
-
-def test_kernel_bench_mla_sweep_interpret(tmp_path, capsys):
-    """--mla: the context x latent-dtype MLA decode sweep runs both cache
-    dtypes through the REAL mla_paged_decode_update glue (bf16 and int8
-    latent + scale plane) on the interpreter and derives the crossover
-    block."""
-    mod = _kernel_bench()
-    out = tmp_path / "mla.json"
-    rc = mod.main(["--mla", "--interpret", "--ctx-sweep", "48,96",
-                   "--out", str(out)])
-    assert rc == 0
-    doc = json.loads(out.read_text())
-    assert doc == json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert doc["mode"] == "mla_decode"
-    assert doc["timings_valid"] is False
-    assert [p["ctx"] for p in doc["points"]] == [48, 96]
-    for p in doc["points"]:
-        for dtype in ("bf16", "int8"):
-            assert isinstance(p["ms"][dtype], float) and p["ms"][dtype] > 0
-        # The byte accounting the crossover explains: the int8 latent
-        # streams about half the page bytes (+ the f32 scale plane).
-        assert p["kv_mb_per_step"]["int8"] < 0.6 * p["kv_mb_per_step"]["bf16"]
-    assert "int8_faster_from_ctx" in doc["crossover"]
-    assert "LLMD_MLA_LATENT_DTYPE" in doc["crossover"]
-
-
 def test_kernel_bench_a2a_sweep_interpret(tmp_path, capsys):
     """--a2a: the tokens x collective-dtype EP exchange sweep runs all
     three wire modes (bf16 / int8 dispatch-only / int8 both ways)

@@ -1,8 +1,29 @@
-"""KV block allocator + prefix cache semantics."""
+"""KV block allocator + prefix cache semantics, and the pool's size.
 
+Every benchmark cell passes ``--kv-cache-hbm-gb``, so the pool a cell
+serves with is what ``derive_num_blocks`` makes of its budget, layout and
+depth: the counts here are PERF.md section 4's.  An engine handed a budget
+sizes its pool from it and not from ``num_blocks``.
+"""
+
+import pathlib
+import sys
+
+import jax.numpy as jnp
+import pytest
+
+from llm_d_tpu.engine.engine import EngineConfig, EngineCore, derive_num_blocks
 from llm_d_tpu.engine.kv_cache import KVCacheManager
 from llm_d_tpu.engine.request import Request
+from llm_d_tpu.models import get_model
+from llm_d_tpu.models.config import ModelConfig
 from llm_d_tpu.ops.sampling import SamplingParams
+from llm_d_tpu.server.openai import build_arg_parser, engine_config_from_args
+
+sys.path.insert(
+    0, str(pathlib.Path(__file__).resolve().parent.parent / "benchmarks"))
+
+import modelcfg  # noqa: E402
 
 
 def mk_req(rid, tokens):
@@ -93,3 +114,47 @@ def test_allocation_failure():
     assert kv.allocate(r2, 4) is None   # exhausted
     kv.free(r1)
     assert kv.allocate(r2, 4) is not None
+
+
+# ---- the pool sized from an HBM budget -----------------------------------
+
+@pytest.mark.parametrize("name,blocks,row_bytes", [
+    ("qwen3-30b-a3b", 10240, 2 * 4 * 128 * 2),      # k + v, 4 KV heads x 128
+    ("kanana-2-30b-a3b", 14563, 640 * 2),           # one latent row of 640
+    ("trinity-mini", 10240, 2 * 4 * 128 * 2),
+])
+def test_configuration_pool_from_its_serve_args(name, blocks, row_bytes):
+    conf = modelcfg.load_config(name)
+    mc = ModelConfig(**modelcfg.model_config_fields(conf))
+    cfg = engine_config_from_args(build_arg_parser().parse_args(
+        ["--model", name, *modelcfg.serve_args(conf)]))
+    assert (cfg.kv_cache_hbm_bytes, cfg.block_size) == (5 << 30, 32)
+    layout = get_model(mc).kv_cache_layout(mc)
+    assert sum(layout.values()) * 2 == row_bytes
+    got = derive_num_blocks(cfg.kv_cache_hbm_bytes, layout, mc.num_layers,
+                            cfg.block_size)
+    assert got == blocks
+    # The most whole blocks the budget holds: one more would not fit.
+    block_bytes = mc.num_layers * cfg.block_size * row_bytes
+    assert got * block_bytes <= cfg.kv_cache_hbm_bytes < (got + 1) * block_bytes
+
+
+@pytest.mark.parametrize("model", ["tiny", "tiny-mla"])
+def test_engine_sizes_its_pool_from_the_budget(model):
+    budget = 1 << 20
+    engine = EngineCore(EngineConfig(
+        model=model, block_size=4, num_blocks=7, max_num_seqs=4,
+        max_num_batched_tokens=64, min_token_bucket=16, min_seq_bucket=4,
+        kv_cache_hbm_bytes=budget))
+    c = engine.model_config
+    want = derive_num_blocks(
+        budget, engine.model.kv_cache_layout(c), c.num_layers, 4)
+    assert want > 7
+    assert engine.config.num_blocks == engine.kv_manager.num_blocks == want
+    held = 0
+    for buf in engine.kv_cache.values():
+        assert buf.dtype == jnp.bfloat16
+        assert buf.shape[:2] == (c.num_layers, want * 4)
+        held += buf.nbytes
+    # The pool fills the budget to within one block.
+    assert budget - held // want < held <= budget

@@ -12,6 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from llm_d_tpu.engine.engine import EngineConfig, EngineCore
+from llm_d_tpu.models.config import get_config
 from llm_d_tpu.ops import attention as A
 from llm_d_tpu.ops.pallas.mla_attention import mla_paged_decode_update
 
@@ -125,3 +127,52 @@ def test_lane_padding_is_score_neutral():
     np.testing.assert_allclose(
         np.asarray(out_p[..., :F], np.float32),
         np.asarray(base[..., :F], np.float32), atol=2e-2, rtol=2e-2)
+
+
+ENGINE_KW = dict(model="tiny-mla", block_size=4, num_blocks=64,
+                 max_num_seqs=4, max_num_batched_tokens=64,
+                 min_token_bucket=16, min_seq_bucket=4)
+
+
+def test_mla_seq_group_env_non_divisor_degrades_to_auto(monkeypatch):
+    """Env-knob contract: LLMD_MLA_SEQ_GROUP that does not divide the
+    current sequence bucket falls back to auto grouping instead of
+    crashing the decode path (S varies with load, the knob must not)."""
+    import llm_d_tpu.models.mla as mla_mod
+    import llm_d_tpu.ops.pallas.mla_attention as ma
+
+    monkeypatch.setenv("LLMD_MLA_SEQ_GROUP", "7")    # divides no pow2 S
+    monkeypatch.setattr(A, "resolve_backend", lambda b: "pallas")
+    real = ma.mla_paged_decode_update
+    seen = {}
+
+    def spy(*a, **kw):
+        seen["seq_group"] = kw.get("seq_group")
+        kw["interpret"] = True
+        return real(*a, **kw)
+
+    monkeypatch.setattr(ma, "mla_paged_decode_update", spy)
+    c = get_config("tiny-mla")
+    lp = {k: v[:1] for k, v in EngineCore(
+        EngineConfig(**ENGINE_KW)).params["moe_layers"].items()}
+    lp = {k: v[0] for k, v in lp.items()}
+    S, bs = 2, 16
+    F = -(-(c.kv_lora_rank + c.qk_rope_head_dim) // 128) * 128
+    kv = jnp.zeros((1, 8 * bs, F), jnp.bfloat16)
+    lens = jnp.asarray([3, 5], jnp.int32)
+    batch = dict(
+        token_ids=jnp.zeros(S, jnp.int32),
+        positions=lens - 1,
+        token_seq_ids=jnp.arange(S, dtype=jnp.int32),
+        token_qpos=jnp.zeros(S, jnp.int32),
+        slot_mapping=jnp.asarray([1 * bs + 2, 2 * bs + 4], jnp.int32),
+        block_tables=jnp.asarray([[1], [2]], jnp.int32),
+        seq_lens=lens,
+        qtok_idx=jnp.arange(S, dtype=jnp.int32)[:, None],
+    )
+    x = jnp.asarray(np.random.default_rng(0).standard_normal(
+        (S, c.hidden_size)), jnp.bfloat16)
+    out, _ = mla_mod.mla_attention_block(
+        lp, c, x, batch, kv, bs, "pallas", layer=jnp.int32(0))
+    assert out.shape == (S, c.hidden_size)
+    assert seen["seq_group"] is None       # non-divisor degraded to auto

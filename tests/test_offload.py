@@ -112,6 +112,54 @@ def test_shared_tier_cross_pod_prefix_hit():
         pod_a.host_tier.close()
 
 
+@pytest.mark.parametrize("peer_cache", ["int8+scales", "f32"])
+def test_shared_tier_refuses_slab_of_another_cache_dtype(peer_cache):
+    """The slab's dtype codes are checked, never reinterpreted: a peer
+    whose blocks are not this pod's bf16 k and v (an older build's int8
+    rows + f32 scale planes; f32 rows) is refused with the mismatch error,
+    nothing of it enters the local tier, and the request recomputes at
+    parity."""
+    import numpy as np
+    from llm_d_tpu.engine import offload
+    from llm_d_tpu.transfer import transport
+    prompt = [7, 3, 9, 1, 4, 6, 2, 8, 5, 0, 11, 13]   # 3 full blocks
+    pod_a = _mk_engine(kv_shared_tier_port=0)
+    try:
+        want = pod_a.generate([greedy_req("a", prompt, 4)])["a"]
+        L, bs = pod_a.model_config.num_layers, pod_a.config.block_size
+        w = pod_a.kv_cache["k"].shape[-1]
+        foreign = offload._pack_block_slab({
+            "int8+scales": {"k": np.zeros((L, bs, w), np.int8),
+                            "k.scales": np.ones((L, bs, 1), np.float32),
+                            "v": np.zeros((L, bs, w), np.int8),
+                            "v.scales": np.ones((L, bs, 1), np.float32)},
+            "f32": {"k": np.zeros((L, bs, w), np.float32),
+                    "v": np.zeros((L, bs, w), np.float32)},
+        }[peer_cache])
+        with pytest.raises(ValueError, match={
+                "int8+scales": "slab layout",
+                "f32": "slab holds float32 but this pod's cache is "
+                       "bfloat16"}[peer_cache]):
+            offload._unpack_block_slab(
+                foreign, offload._slab_layout(pod_a), L, bs)
+        # Pod A now serves every block it holds in the foreign format.
+        for block_hash in list(pod_a.host_tier._store):
+            pod_a.host_tier.server.register(
+                offload._shared_key(block_hash), foreign)
+        pod_b = _mk_engine(
+            kv_shared_tier_peers=(f"127.0.0.1:{pod_a.host_tier.port}",))
+        try:
+            rb = greedy_req("b", prompt, 4)
+            assert pod_b.generate([rb])["b"] == want       # recomputed
+            assert pod_b.host_tier.remote_hits == 0
+            assert rb.num_cached_prompt_tokens == 0
+            assert pod_b.host_tier._peer_health, "the refusal was not counted"
+        finally:
+            pod_b.host_tier.close()
+    finally:
+        pod_a.host_tier.close()
+
+
 def test_shared_tier_peer_down_degrades_to_recompute():
     """A dead peer must cost a timeout per block chain at worst, never an
     error: the request recomputes locally."""

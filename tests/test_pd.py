@@ -234,6 +234,74 @@ def test_kv_load_failure_policy_recompute(baseline_engine):
         consumer.kv_connector.close()
 
 
+def _foreign_blob(engine, nb, segments):
+    """A structurally valid wire-version-2 blob for ``nb`` blocks of
+    ``engine``'s geometry whose buffer segments are ``segments``:
+    ``[(row width, numpy dtype), ...]``, zero-filled — what a producer with
+    another cache would ship."""
+    import numpy as np
+    from llm_d_tpu.transfer.connector import (
+        _BUF_HEADER, _HEADER, _MAGIC, _WIRE_VERSION)
+    L = engine.model_config.num_layers
+    bs = engine.config.block_size
+    parts = [_HEADER.pack(_MAGIC, _WIRE_VERSION, L, bs, len(segments), nb)]
+    for width, dtype in segments:
+        parts.append(_BUF_HEADER.pack(width, transport.wire_dtype_code(dtype)))
+        parts.append(np.zeros((L, nb * bs, width), dtype).tobytes())
+    return b"".join(parts)
+
+
+@pytest.mark.parametrize("producer_cache,error", [
+    # A peer of an older build with the int8 cache: int8 rows and an f32
+    # scale plane per buffer, sorted by name as the wire sorts them.
+    ("int8+scales", "ships another buffer set"),
+    # The consumer's own buffer set and widths under another dtype code.
+    ("int8", "producer shipped int8 but the local cache is bfloat16"),
+    ("f32", "producer shipped float32 but the local cache is bfloat16"),
+])
+def test_consumer_refuses_foreign_cache_dtype_and_recomputes(
+        baseline_engine, caplog, producer_cache, error):
+    """The wire's dtype codes are checked, never reinterpreted: a slab
+    whose segments are not this cache's bf16 k and v is refused with the
+    mismatch error, and under policy=recompute the request is served by a
+    local prefill at parity."""
+    import numpy as np
+    from llm_d_tpu.transfer.connector import _scatter_blocks
+    prompt = [5, 4, 3, 2, 1, 7, 7]
+    expected = baseline_engine.generate([greedy_req("b", prompt, 4)])["b"]
+    w = baseline_engine.kv_cache["k"].shape[-1]
+    segments = {
+        "int8+scales": [(w, np.int8), (1, np.float32)] * 2,
+        "int8": [(w, np.int8)] * 2,
+        "f32": [(w, np.float32)] * 2,
+    }[producer_cache]
+    blob = _foreign_blob(baseline_engine, 2, segments)
+    with pytest.raises(ValueError, match=error):
+        _scatter_blocks(baseline_engine, [1, 2], blob)
+
+    server = transport.PyTransferServer("127.0.0.1", 0)
+    server.register("foreign", blob)
+    consumer = EngineCore(EngineConfig(**ENGINE_KW),
+                          params=baseline_engine.params)
+    consumer.kv_connector = TpuConnector(KVConnectorConfig(
+        kv_role="kv_consumer", kv_load_failure_policy="recompute",
+        timeout_ms=2000))
+    try:
+        req = greedy_req("foreign", prompt, 4, do_remote_prefill=True,
+                         kv_transfer_params={
+                             "remote_host": "127.0.0.1",
+                             "remote_port": server.port, "uuid": "foreign",
+                             "remote_block_ids": [1, 2]})
+        with caplog.at_level("WARNING", logger="llm_d_tpu.transfer.connector"):
+            out = consumer.generate([req])
+        assert out["foreign"] == expected
+        assert any("bad slab" in r.getMessage() and error in r.getMessage()
+                   for r in caplog.records), caplog.text
+    finally:
+        consumer.kv_connector.close()
+        server.close()
+
+
 def test_producer_pin_timeout_releases_blocks(baseline_engine):
     """A consumer that never pulls must not leak the producer's cache."""
     producer = EngineCore(EngineConfig(**ENGINE_KW),

@@ -16,9 +16,8 @@ import pytest
 from llm_d_tpu.ops import attention as A
 from llm_d_tpu.ops.pallas.flash_prefill import flash_prefill_paged
 from llm_d_tpu.ops.pallas.mla_prefill import mla_flash_prefill
-from llm_d_tpu.ops.quant import quantize_kv_block
 
-BS = 32          # the int8 kernels' page (a multiple of 32 rows)
+BS = 32          # the cells' page
 
 
 def _batch(news, ctx, T, Q):
@@ -118,9 +117,7 @@ def _paged(rng, F, L=2, B=3):
 
 
 @pytest.mark.parametrize("q_tile", [5, 8, 16])   # 5: divides neither Q nor a row
-@pytest.mark.parametrize("family", [
-    "gqa", "gqa-window", "gqa-int8-token", "gqa-int8-head", "mla",
-    "mla-int8"])
+@pytest.mark.parametrize("family", ["gqa", "gqa-window", "mla"])
 def test_tile_list_equals_rectangle(family, q_tile):
     rng = np.random.default_rng(sum(map(ord, family)))
     mla = family.startswith("mla")
@@ -134,15 +131,7 @@ def test_tile_list_equals_rectangle(family, q_tile):
     v_cache = k_cache if mla else _paged(rng, F)[0]
     batch["block_tables"] = bt
 
-    kw, ref_kw = {}, {}
-    if "int8" in family:
-        sw = KVH if family.endswith("head") else 1
-        k_cache, ks = quantize_kv_block(k_cache, sw)
-        v_cache, vs = (k_cache, ks) if mla else quantize_kv_block(v_cache, sw)
-        kw = dict(kv_scale=ks) if mla else dict(k_scale=ks, v_scale=vs)
-        ref_kw = dict(k_scale=ks, v_scale=vs)
-    if family == "gqa-window":
-        kw["window"] = ref_kw["window"] = jnp.int32(24)
+    kw = dict(window=jnp.int32(24)) if family == "gqa-window" else {}
 
     if mla:
         def kernel(qs, q_pos, **more):
@@ -175,7 +164,7 @@ def test_tile_list_equals_rectangle(family, q_tile):
 
     ref = A.ragged_paged_attention_reference(
         q, k_cache, v_cache, batch["token_seq_ids"], batch["positions"], bt,
-        batch["seq_lens"], block_size=BS, scale=0.11, layer=layer, **ref_kw)
+        batch["seq_lens"], block_size=BS, scale=0.11, layer=layer, **kw)
     np.testing.assert_allclose(out[:real], np.asarray(ref, np.float32)[:real],
                                atol=3e-2, rtol=3e-2)
 

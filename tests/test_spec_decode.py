@@ -612,13 +612,13 @@ def test_chaos_spec_decode_resume_zero_stream_breaks(inject=None):
 def test_bench_gate_includes_spec_metric():
     import bench
     gate = bench._regression_gate(
-        {}, {}, None,
+        {}, {},
         {256: {"decode_tok_s": 123.0, "decode_tok_s_band": [120.0, 125.0]}})
     assert gate["moe_decode_spec_bs256_best_recorded"] is None
     assert gate["moe_decode_spec_bs256_recorded"] == 123.0
     assert gate["moe_decode_spec_bs256_regressed"] is None   # first record
     # No spec sweep (e.g. --quick): the metric degrades to no-verdict.
-    gate = bench._regression_gate({}, {}, None, None)
+    gate = bench._regression_gate({}, {}, None)
     assert gate["moe_decode_spec_bs256_delta_pct"] is None
 
 

@@ -313,16 +313,15 @@ def test_engine_resume_kv_restore_fault_degrades_to_recompute(inject):
 
 
 @pytest.mark.parametrize("model", ["tiny", "tiny-mla"])
-def test_engine_resume_int8_kv_cache_parity(model):
-    """Resume is dtype-clean: kv_cache_dtype=int8 (dense K/V and the MLA
-    int8 latent row) resumes to parity with its own int8 baseline, over
-    both the restore and recompute admission paths."""
-    kw = dict(ENGINE_KW, model=model, kv_cache_dtype="int8",
-              num_blocks=32, kv_offload_blocks=64)
+def test_engine_resume_parity_by_family(model):
+    """Resume is cache-layout-clean: dense K/V and the single MLA latent
+    buffer both resume to parity with their own baseline, over both the
+    restore and recompute admission paths."""
+    kw = dict(ENGINE_KW, model=model, num_blocks=32, kv_offload_blocks=64)
     a = EngineCore(EngineConfig(**dict(kw, kv_shared_tier_port=0)))
     try:
         want = a.generate([greedy_req("base", PROMPT, 8)])["base"]
-        # Restore path (int8 slab + scale planes over the wire).
+        # Restore path (the family's slab over the shared tier's wire).
         b = EngineCore(EngineConfig(**dict(
             kw, kv_shared_tier_peers=(
                 f"127.0.0.1:{a.host_tier.port}",))), params=a.params)
@@ -333,8 +332,7 @@ def test_engine_resume_int8_kv_cache_parity(model):
         finally:
             b.host_tier.close()
         # Recompute path (no tier).
-        c = EngineCore(EngineConfig(**dict(ENGINE_KW, model=model,
-                                           kv_cache_dtype="int8")),
+        c = EngineCore(EngineConfig(**dict(ENGINE_KW, model=model)),
                        params=a.params)
         creq = resume_req("res2", PROMPT, want[:4], 8)
         assert c.generate([creq])["res2"] == want

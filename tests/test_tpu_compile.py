@@ -28,13 +28,6 @@ from llm_d_tpu.ops import moe as moe_ops
 BS = 32                    # default engine block size
 SLOTS = 256 * BS           # KV pool rows per layer in these cases
 
-# The Mosaic refusal shared by the four int8-cache kernels: the
-# [1, block_size, SW] DMA out of the [L, slots, SW] f32 scale plane.
-# Engine construction raises this on a TPU (engine.py
-# INT8_CACHE_KERNEL_REFUSAL); ROADMAP A10 carries the repair.
-_SCALE_DMA = ("Slice shape along dimension 2 must be aligned to tiling "
-              "(128)")
-
 
 @pytest.fixture(scope="module")
 def topo():
@@ -69,86 +62,61 @@ def _sds(shape, dtype):
 
 # ---- case builders: each returns (fn, [ShapeDtypeStruct, ...]) ----------
 
-def _dense_decode(H, KVH, D, S=64, B=64, L=16, sw=0, window=False):
+def _dense_decode(H, KVH, D, S=64, B=64, L=16, window=False):
     """``window``: the traced per-layer window operand of a mixed stack."""
     from llm_d_tpu.ops.pallas.paged_attention import (
         paged_attention_decode_update as kern)
     F = KVH * D
-    cdt = jnp.int8 if sw else jnp.bfloat16
 
-    def fn(q, kn, vn, kc, vc, bt, sl, layer, *scales):
+    def fn(q, kn, vn, kc, vc, bt, sl, layer):
         kw = {"window": layer + 2048} if window else {}
-        if sw:
-            kw = dict(k_scale=scales[0], v_scale=scales[1],
-                      k_scale_new=scales[2], v_scale_new=scales[3])
         return kern(q, kn, vn, kc, vc, bt, sl, block_size=BS,
                     num_kv_heads=KVH, layer=layer, **kw)
 
-    args = [_sds((S, H, D), jnp.bfloat16), _sds((S, F), cdt),
-            _sds((S, F), cdt), _sds((L, SLOTS, F), cdt),
-            _sds((L, SLOTS, F), cdt), _sds((S, B), jnp.int32),
-            _sds((S,), jnp.int32), _sds((), jnp.int32)]
-    if sw:
-        args += [_sds((L, SLOTS, sw), jnp.float32)] * 2 \
-            + [_sds((S, sw), jnp.float32)] * 2
-    return fn, args
+    return fn, [_sds((S, H, D), jnp.bfloat16), _sds((S, F), jnp.bfloat16),
+                _sds((S, F), jnp.bfloat16), _sds((L, SLOTS, F), jnp.bfloat16),
+                _sds((L, SLOTS, F), jnp.bfloat16), _sds((S, B), jnp.int32),
+                _sds((S,), jnp.int32), _sds((), jnp.int32)]
 
 
-def _dense_prefill(H, KVH, D, S=8, Q=256, B=64, L=16, sw=0, window=False):
+def _dense_prefill(H, KVH, D, S=8, Q=256, B=64, L=16, window=False):
     from llm_d_tpu.ops.pallas.flash_prefill import flash_prefill_paged as kern
     F = KVH * D
-    cdt = jnp.int8 if sw else jnp.bfloat16
 
-    def fn(qs, qp, kc, vc, bt, sl, layer, *scales):
-        kw = dict(k_scale=scales[0], v_scale=scales[1]) if sw else {}
-        if window:
-            kw["window"] = layer + 2048
+    def fn(qs, qp, kc, vc, bt, sl, layer):
+        kw = {"window": layer + 2048} if window else {}
         return kern(qs, qp, kc, vc, bt, sl, block_size=BS,
                     num_kv_heads=KVH, layer=layer, **kw)
 
-    args = [_sds((S, Q, H, D), jnp.bfloat16), _sds((S, Q), jnp.int32),
-            _sds((L, SLOTS, F), cdt), _sds((L, SLOTS, F), cdt),
-            _sds((S, B), jnp.int32), _sds((S,), jnp.int32),
-            _sds((), jnp.int32)]
-    if sw:
-        args += [_sds((L, SLOTS, sw), jnp.float32)] * 2
-    return fn, args
+    return fn, [_sds((S, Q, H, D), jnp.bfloat16), _sds((S, Q), jnp.int32),
+                _sds((L, SLOTS, F), jnp.bfloat16),
+                _sds((L, SLOTS, F), jnp.bfloat16), _sds((S, B), jnp.int32),
+                _sds((S,), jnp.int32), _sds((), jnp.int32)]
 
 
-def _mla_decode(H, F=640, S=64, B=64, L=16, sw=0):
+def _mla_decode(H, F=640, S=64, B=64, L=16):
     from llm_d_tpu.ops.pallas.mla_attention import (
         mla_paged_decode_update as kern)
-    cdt = jnp.int8 if sw else jnp.bfloat16
 
-    def fn(q, row, kc, bt, sl, layer, *scales):
-        kw = dict(kv_scale=scales[0], row_scale_new=scales[1]) if sw else {}
+    def fn(q, row, kc, bt, sl, layer):
         return kern(q, row, kc, bt, sl, block_size=BS, scale=0.07,
-                    layer=layer, **kw)
+                    layer=layer)
 
-    args = [_sds((S, H, F), jnp.bfloat16), _sds((S, F), cdt),
-            _sds((L, SLOTS, F), cdt), _sds((S, B), jnp.int32),
-            _sds((S,), jnp.int32), _sds((), jnp.int32)]
-    if sw:
-        args += [_sds((L, SLOTS, sw), jnp.float32),
-                 _sds((S, sw), jnp.float32)]
-    return fn, args
+    return fn, [_sds((S, H, F), jnp.bfloat16), _sds((S, F), jnp.bfloat16),
+                _sds((L, SLOTS, F), jnp.bfloat16), _sds((S, B), jnp.int32),
+                _sds((S,), jnp.int32), _sds((), jnp.int32)]
 
 
-def _mla_prefill(H, F=640, S=8, Q=256, B=64, L=16, sw=0):
+def _mla_prefill(H, F=640, S=8, Q=256, B=64, L=16):
     from llm_d_tpu.ops.pallas.mla_prefill import mla_flash_prefill as kern
-    cdt = jnp.int8 if sw else jnp.bfloat16
 
-    def fn(qs, qp, kc, bt, sl, layer, *scales):
-        kw = dict(kv_scale=scales[0]) if sw else {}
+    def fn(qs, qp, kc, bt, sl, layer):
         return kern(qs, qp, kc, bt, sl, block_size=BS, scale=0.07,
-                    layer=layer, **kw)
+                    layer=layer)
 
-    args = [_sds((S, Q, H, F), jnp.bfloat16), _sds((S, Q), jnp.int32),
-            _sds((L, SLOTS, F), cdt), _sds((S, B), jnp.int32),
-            _sds((S,), jnp.int32), _sds((), jnp.int32)]
-    if sw:
-        args += [_sds((L, SLOTS, sw), jnp.float32)]
-    return fn, args
+    return fn, [_sds((S, Q, H, F), jnp.bfloat16), _sds((S, Q), jnp.int32),
+                _sds((L, SLOTS, F), jnp.bfloat16), _sds((S, B), jnp.int32),
+                _sds((S,), jnp.int32), _sds((), jnp.int32)]
 
 
 def _prefill_tiles(H, KVH, D, T, S, Q, B=64, L=16, window=False,
@@ -199,10 +167,6 @@ def _moe(path, T, H=2048, I=512, E=64, k=8, Lm=15):
             _sds((Lm, E, H, I), jnp.int8), _sds((Lm, E, 1, I), jnp.float32),
             _sds((Lm, E, I, H), jnp.int8), _sds((Lm, E, 1, H), jnp.float32)]
     return fn, args
-
-
-def _xfail(msg):
-    return pytest.mark.xfail(strict=True, reason=msg)
 
 
 CASES = [
@@ -304,17 +268,6 @@ CASES = [
     pytest.param(functools.partial(_prefill_tiles, 8, 1, 640, T=1024, S=64,
                                    Q=512, L=9, mla=True),
                  id="mla_prefill-tiles-tp4-T1024-S64"),
-    # int8 KV / latent caches: refused (see _SCALE_DMA).
-    pytest.param(functools.partial(_dense_decode, 32, 8, 64, sw=1),
-                 id="paged_decode-int8-token", marks=_xfail(_SCALE_DMA)),
-    pytest.param(functools.partial(_dense_decode, 32, 8, 64, sw=8),
-                 id="paged_decode-int8-head", marks=_xfail(_SCALE_DMA)),
-    pytest.param(functools.partial(_dense_prefill, 32, 8, 64, sw=1),
-                 id="flash_prefill-int8-token", marks=_xfail(_SCALE_DMA)),
-    pytest.param(functools.partial(_mla_decode, 16, sw=1),
-                 id="mla_decode-int8-latent", marks=_xfail(_SCALE_DMA)),
-    pytest.param(functools.partial(_mla_prefill, 16, sw=1),
-                 id="mla_prefill-int8-latent", marks=_xfail(_SCALE_DMA)),
 ]
 
 
