@@ -32,7 +32,8 @@ from llm_d_tpu.engine.request import Request, RequestOutput, RequestState
 from llm_d_tpu.engine.scheduler import Scheduler, SchedulerOutput
 from llm_d_tpu.engine.step_clock import StepClock
 from llm_d_tpu.models import get_model
-from llm_d_tpu.models.config import SLIDING, ModelConfig, get_config
+from llm_d_tpu.models.config import (
+    NO_WINDOW, SLIDING, ModelConfig, get_config)
 from llm_d_tpu.ops import sampling as sampling_ops
 from llm_d_tpu.parallel.mesh import MeshConfig, make_mesh
 from llm_d_tpu.parallel.sharding import logical_to_sharding, shard_pytree
@@ -2500,6 +2501,7 @@ class EngineCore:
         if Q > 1:
             self._step_kv.update(
                 self._attn_q_counts(int(np.sum(news)), layout))
+            self._step_kv.update(self._attn_k_counts(ends, news, layout))
         return packed, layout, scheduled, rows
 
     # ---------- step ----------
@@ -2543,6 +2545,40 @@ class EngineCore:
                                 self.model_config.use_mla)
             slots = num_query_tiles(layout.T, layout.S, qt) * qt
         return {"attn_q_real": real, "attn_q_slots": layout.dp * slots}
+
+    def _attn_k_counts(self, ends, news, layout: BatchLayout) -> Dict[str, int]:
+        """The keys the Pallas prefill kernels' inner loop covers for the
+        same dispatch (step_clock.py), summed over its query tiles and the
+        attention layers: row r's ``news[r]`` queries end at context
+        ``ends[r]`` and fill ceil(n / Qt) tiles; a tile walks key blocks
+        from the page of the first key its first query sees to the page of
+        its last query.  Nothing where another path serves prefill."""
+        if self._prefill_tile_dims is None:
+            return {}
+        from llm_d_tpu.ops.attention import prefill_key_block, prefill_q_tile
+        c = self.model_config
+        bs = self.config.block_size
+        qt = prefill_q_tile(layout.Q, *self._prefill_tile_dims, c.use_mla)
+        kb = prefill_key_block(qt, *self._prefill_tile_dims, c.head_dim_,
+                               bs, c.use_mla)
+        ends = np.asarray(ends, np.int64)
+        news = np.minimum(np.asarray(news, np.int64), ends)
+        tiles = -(-news // qt)                          # of each row
+        row = np.repeat(np.arange(len(news)), tiles)
+        nth = np.arange(len(row)) - np.repeat(np.cumsum(tiles) - tiles, tiles)
+        q_first = (ends - news)[row] + nth * qt         # a tile's positions
+        q_last = np.minimum(q_first + qt, ends[row]) - 1
+        real = slots = 0
+        n_window = c.layer_types.count(SLIDING)
+        for window, layers in ((c.sliding_window, n_window),
+                               (NO_WINDOW, c.num_layers - n_window)):
+            if not layers:
+                continue
+            k_first = np.maximum(q_first - window + 1, 0)
+            real += layers * int((q_last + 1 - k_first).sum())
+            pages = -(-(q_last + 1) // bs) - k_first // bs
+            slots += layers * int((-(-pages * bs // kb)).sum()) * kb
+        return {"attn_k_real": real, "attn_k_slots": slots}
 
     def _note_step(self, t0: float, fetched: float, requests: List[Request],
                    prefill_tokens: int, decode_tokens: int, *,

@@ -44,6 +44,17 @@ and, for the classic path's steps with prefill tokens
                   Pallas kernels' query tiles (tiles x slots a tile), the
                   padded [S, Q] rectangle where another path serves
 
+and, where the Pallas prefill kernels serve them
+(``EngineCore._attn_k_counts``), summed over the step's query tiles and
+attention layers:
+
+  attn_k_real     keys from the first one the tile's first query sees (under
+                  the layer's window) to its last query's own
+  attn_k_slots    keys the kernel's inner loop covers for the tile: the key
+                  blocks it walks x the keys a block (several pages in the
+                  GQA kernel, one in the MLA kernel); the rest lie before
+                  the window, past the causal diagonal or past the context
+
 Phases are contiguous, so they add up to the iteration.  An iteration that
 fetched nothing (an empty schedule, the first dispatch of a pipelined
 block) writes no span; its times stay in the accumulator and ride the next

@@ -295,6 +295,19 @@ def prefill_q_tile(Q: int, H: int, F: int, mla: bool = False) -> int:
     return _pick_q_tile(Q, H, F)
 
 
+def prefill_key_block(q_tile: int, H: int, F: int, D: int, block_size: int,
+                      mla: bool = False) -> int:
+    """Keys one step of a Pallas prefill kernel's inner loop covers for
+    tiles of ``q_tile`` slots, with ``H`` heads of size ``D`` over cache
+    rows ``F`` wide as ONE shard sees them: a block of several pages in the
+    GQA kernel (``ops.pallas.flash_prefill.pick_key_block``), one page in
+    the MLA kernel."""
+    if mla:
+        return block_size
+    from llm_d_tpu.ops.pallas.flash_prefill import dot_rows, pick_key_block
+    return pick_key_block(block_size, F, dot_rows(q_tile, H, F // D, D))
+
+
 def num_query_tiles(T: int, S: int, q_tile: int) -> int:
     """Tiles that hold any ``S`` rows of ``T`` tokens together: a row of n
     tokens fills ceil(n / q_tile), so the sum stays UNDER this count and

@@ -56,6 +56,11 @@ PHASES = {
     "chunk": dict(rows=[(90, 70)], T=128, S=4, Q=128),
     "mixed": dict(rows=[(30, 1), (149, 1), (90, 70), (32, 1)],
                   T=128, S=8, Q=128),
+    # A continuing chunk whose context crosses an edge of the GQA prefill
+    # kernel's key block (512 keys at these geometries), beside rows that
+    # end on the edge and one key past it.
+    "chunk-over-key-block": dict(rows=[(470, 90), (511, 1), (512, 1)],
+                                 T=128, S=4, Q=128),
 }
 
 
@@ -221,3 +226,72 @@ def test_served_attention_against_exact(monkeypatch, family, phase, path,
         assert err < tol, (err, tol)
     else:
         assert err > tol, (err, tol)
+
+
+# ---- the keys the prefill kernels' inner loop covers ----------------------
+
+def _walk_by_hand(ends, news, qt, kb, bs, windows):
+    """The kernels' walk, a tile and a layer at a time: ``attn_k_real``
+    counts the keys from the first one the tile's first query sees to its
+    last query's own, ``attn_k_slots`` the blocks walked times their keys
+    (the loop bounds of ``ops.pallas.flash_prefill._prefill_kernel``)."""
+    real = slots = 0
+    for end, n in zip(ends, news):
+        for lo in range(end - n, end, qt):
+            q = range(lo, min(lo + qt, end))
+            for w in windows:
+                seen = [k for k in range(end)
+                        if q[0] - w < k and k <= q[-1]]
+                real += len(seen)
+                n_pages = -(-min(end, q[-1] + 1) // bs)
+                first = min(max(q[0] - w + 1, 0) // bs, n_pages)
+                slots += -(-(n_pages - first) // (kb // bs)) * kb
+    return {"attn_k_real": real, "attn_k_slots": slots}
+
+
+@pytest.mark.parametrize("seed", range(4))
+@pytest.mark.parametrize("model,dims,bs,Q,qt,kb", [
+    # the cells' 32 / 4 x 128 under a window-and-full stack: 512 keys a
+    # block at a chunk's 32 slots a tile and at a mixed step's 8
+    ("tiny-swa-moe", (32, 512), 32, 2048, 32, 512),
+    ("tiny-swa-moe", (32, 512), 32, 256, 8, 512),
+    # a 16-key page
+    ("tiny-swa-moe", (32, 512), 16, 2048, 32, 512),
+    # every layer full
+    ("tiny", (32, 512), 32, 1024, 16, 512),
+    # the MLA kernel walks a page at a time
+    ("tiny-mla", (32, 640), 32, 512, 8, 32),
+])
+def test_engine_counts_the_keys_its_prefill_walks(model, dims, bs, Q, qt,
+                                                   kb, seed):
+    import dataclasses
+    from types import SimpleNamespace
+
+    from llm_d_tpu.engine.engine import EngineCore
+    from llm_d_tpu.engine.packed_batch import BatchLayout
+    from llm_d_tpu.models import get_config
+    c = get_config(model)
+    if c.sliding_window:        # a window that several tiles' walks cross
+        c = dataclasses.replace(c, sliding_window=300, head_dim=128)
+    elif not c.use_mla:
+        c = dataclasses.replace(c, head_dim=128)
+    engine = EngineCore.__new__(EngineCore)
+    engine.model_config = c
+    engine.config = SimpleNamespace(block_size=bs)
+    engine._prefill_tile_dims = dims
+    assert A.prefill_q_tile(Q, *dims, c.use_mla) == qt
+    assert A.prefill_key_block(qt, *dims, c.head_dim_, bs, c.use_mla) == kb
+    rng = np.random.default_rng(seed)
+    # Decode rows, fresh prompts, continuing chunks: contexts to 3,000.
+    news = [int(rng.choice([1, 1, int(rng.integers(1, Q + 1))]))
+            for _ in range(6)]
+    ends = [n + int(rng.choice([0, int(rng.integers(0, 3000 - n))]))
+            for n in news]
+    layout = BatchLayout(Q, 8, Q, B=4)
+    got = engine._attn_k_counts(ends, news, layout)
+    assert got == _walk_by_hand(ends, news, qt, kb, bs, c.layer_windows or (
+        (1 << 30,) * c.num_layers))
+    assert 0 < got["attn_k_real"] <= got["attn_k_slots"]
+    # Another path serves prefill: no walk to count.
+    engine._prefill_tile_dims = None
+    assert engine._attn_k_counts(ends, news, layout) == {}

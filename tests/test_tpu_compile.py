@@ -268,6 +268,30 @@ CASES = [
     pytest.param(functools.partial(_prefill_tiles, 8, 1, 640, T=1024, S=64,
                                    Q=512, L=9, mla=True),
                  id="mla_prefill-tiles-tp4-T1024-S64"),
+    # The GQA kernel's key blocks (several pages a step of the inner loop,
+    # one KV-head group a dot where the head size is whole lane tiles):
+    # trinity-mini's full layers, one of four tp shards of the cells'
+    # geometry and of llama3-8b's (8 / 1 and 8 / 2 x 128, F 128 / 256),
+    # llama3-1b's zero-expanded 32 / 8 x 64 at its largest tile, and the
+    # small query bucket of --spec-k 7 (8 slots a row).
+    pytest.param(functools.partial(_prefill_tiles, 32, 4, 128, T=2048, S=8,
+                                   Q=2048, B=1024, L=8),
+                 id="flash_prefill-tiles-trinity-full-T2048-S8"),
+    pytest.param(functools.partial(_prefill_tiles, 8, 1, 128, T=2048, S=64,
+                                   Q=2048, B=128, L=8),
+                 id="flash_prefill-tiles-tp4-8x1x128-T2048-S64"),
+    pytest.param(functools.partial(_prefill_tiles, 8, 2, 128, T=1024, S=16,
+                                   Q=1024, B=256, L=32),
+                 id="flash_prefill-tiles-tp4-8x2x128-T1024-S16"),
+    pytest.param(functools.partial(_prefill_tiles, 32, 8, 64, T=2048, S=8,
+                                   Q=2048, B=256),
+                 id="flash_prefill-tiles-llama3-1b-T2048-S8"),
+    pytest.param(functools.partial(_prefill_tiles, 32, 4, 128, T=64, S=8,
+                                   Q=8, B=1024, L=8, window=True),
+                 id="flash_prefill-tiles-trinity-window-Q8"),
+    pytest.param(functools.partial(_prefill_tiles, 32, 8, 64, T=64, S=8,
+                                   Q=8, B=256),
+                 id="flash_prefill-tiles-llama3-1b-Q8"),
 ]
 
 
