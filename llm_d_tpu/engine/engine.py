@@ -232,7 +232,8 @@ class EngineCore:
             self.kv_manager,
             max_num_seqs=config.max_num_seqs,
             max_num_batched_tokens=config.max_num_batched_tokens,
-            max_model_len=c.max_model_len)
+            max_model_len=c.max_model_len,
+            block_length=self.block_length)
         # Decode-priority chunk budgeting (round 15): the scheduler funds
         # decode entries (plus spec lookahead) first and asks this engine
         # for a per-chunk prefill token cap.  LLMD_PREFILL_CHUNK pins a
@@ -258,6 +259,7 @@ class EngineCore:
         # demotions (e.g. a do_remote_decode row every schedule pass)
         # count on every occurrence but log once.
         self._disabled_seen: set = set()
+        self._check_block_diffusion()
         # llmd-trace: engine phase spans (queue/prefill/decode + step
         # boundaries).  Everything recorded here is host-side clock
         # arithmetic materialized AFTER the jitted dispatch — tracing can
@@ -491,13 +493,56 @@ class EngineCore:
         if config.precompile_step_shapes:
             self.precompile_step_shapes()
 
+    @property
+    def block_length(self) -> int:
+        """The model's diffusion block length B (0: autoregressive): a row
+        that generates brings a whole block of queries to every step and
+        gets candidates for every slot of it back (models/config.py)."""
+        return self.model_config.diffusion_block_length
+
+    def _check_block_diffusion(self) -> None:
+        """What a block-diffusion model cannot be served with is refused
+        here, at start-up, not silently dropped: only the classic step path
+        knows a step that yields 0..B tokens a row."""
+        B, cfg = self.block_length, self.config
+        if not B:
+            return
+        if cfg.block_size % B:
+            # A prefix-cache hit is a multiple of the page and must be one
+            # of the block: cached keys were computed under the block mask.
+            raise ValueError(
+                f"block_size {cfg.block_size} is no multiple of the model's "
+                f"diffusion block length {B}")
+        if cfg.max_num_batched_tokens < B:
+            raise ValueError(
+                f"max_num_batched_tokens {cfg.max_num_batched_tokens} holds "
+                f"no block of {B}")
+        spec_on = ((cfg.spec_decode or env_choice(
+            "LLMD_SPEC_DECODE", "auto", SPEC_DECODE_MODES)) != "off"
+            and (cfg.spec_k if cfg.spec_k is not None
+                 else env_int("LLMD_SPEC_K", 0)) > 0)
+        blocker = self._spec_blockers()[0]
+        for feature, asked in (
+                ("multistep", cfg.num_scheduler_steps > 1),
+                ("spec_decode", spec_on),
+                ("stacked_dp", self.dp > 1)):
+            if asked:
+                self.metrics.inc_feature_disabled(feature, blocker)
+                raise ValueError(
+                    f"{feature} requested but unavailable ({blocker}): "
+                    f"refusing to start")
+
     def step_shapes(self) -> List[Tuple[int, int, int]]:
         """Every (T, S, Q) bucket triple a classic step can have: T tokens
         in all, S rows, Q = the longest row's tokens, each rounded up as
         ``_build_batch`` rounds it.  A triple is reachable when some n rows
         in S's range, the longest of q tokens in Q's range, hold a total
-        in T's range: q + (n - 1) <= total <= n q."""
+        in T's range: q + (n - 1) <= total <= n q.  A block-diffusion
+        engine's rows hold whole blocks of B tokens each (prompt chunks,
+        denoising and commit passes alike), so no row has one token and
+        q + (n - 1) B <= total."""
         cfg = self.config
+        unit = self.block_length or 1
 
         def ranges(lo: int, hi: int) -> List[Tuple[int, int]]:
             """(smallest count, bucket) of each bucket from ``lo`` up."""
@@ -512,11 +557,11 @@ class EngineCore:
         for n_lo, S in ranges(min(cfg.min_seq_bucket, cfg.max_num_seqs),
                               cfg.max_num_seqs):
             for t_lo, T in tokens:
-                if max(t_lo, n_lo) <= min(T, S):     # decode: total = rows
-                    shapes.append((T, S, 1))
+                if unit == 1 and max(t_lo, n_lo) <= min(T, S):
+                    shapes.append((T, S, 1))         # decode: total = rows
                 for q_lo, Q in tokens:
-                    if Q <= T and max(t_lo, max(q_lo, 2) + n_lo - 1) \
-                            <= min(T, S * Q):
+                    if Q <= T and max(t_lo, max(q_lo, 2, unit)
+                                      + (n_lo - 1) * unit) <= min(T, S * Q):
                         shapes.append((T, S, Q))
         return shapes
 
@@ -529,7 +574,7 @@ class EngineCore:
         t0 = time.monotonic()
         for T, S, Q in shapes:
             layout = BatchLayout(T, S, Q, self.max_blocks_per_seq,
-                                 dp=self.dp)
+                                 dp=self.dp, R=self.block_length or 1)
             packed = jax.device_put(
                 layout.new_buffer(),
                 self._replicated if self.dp == 1 else self._dp_sharded)
@@ -581,7 +626,13 @@ class EngineCore:
         program — kept as the single place a future incompatibility
         must be declared so _disable_feature (strict mode + the
         feature-disabled counter) governs it rather than an ad-hoc log
-        line."""
+        line.  One is declared: a block-diffusion model, whose step yields
+        0..B tokens a row and whose block needs a commit pass; the
+        speculative, multi-step and fused multi-step programs all retire
+        one token (plus drafts) a row (``_check_block_diffusion`` refuses
+        them at start-up, strict mode or not)."""
+        if self.block_length:
+            return ["block_diffusion: the step yields 0..B tokens a row"]
         return []
 
     def _disable_feature(self, feature: str, blocker: str,
@@ -669,6 +720,9 @@ class EngineCore:
                     mesh=mesh, moe_opts=moe_opts)
                 routed = None
             logits = model.compute_logits(params, hidden, c)
+            if c.diffusion_block_length:
+                ids, logprobs, top = reveal_body(logits, batch, rng)
+                return ids, logprobs, kv_cache, routed, top
             if logits.ndim == 3:
                 # Stacked (SPMD dp): flatten [dp, S_l, V] -> [dp*S_l, V] so
                 # sampling is row-wise; the merged dim stays dp-sharded and
@@ -689,6 +743,34 @@ class EngineCore:
                 logprobs = sampling_ops.compute_logprobs(logits, ids)
                 top = None
             return ids, logprobs, kv_cache, routed, top
+
+        def reveal_body(logits, batch, rng):
+            """A block-diffusion step's epilogue: ``logits`` [S * B, V] of
+            every slot of every row's block.  Each slot's candidate (argmax,
+            or a sample under a temperature) and, by the model's reveal
+            rule on the device, which masked slots take theirs now; returns
+            (ids [S, B], -1 where the pass revealed nothing; logprobs
+            [S, B]; top-N or None)."""
+            B = c.diffusion_block_length
+
+            def slots(x):
+                return jnp.repeat(x, B)
+
+            gen_idx = (batch["gen_idx"][:, None]
+                       + jnp.arange(B, dtype=jnp.int32)[None, :]).reshape(-1)
+            x0 = sampling_ops.sample(
+                logits, slots(batch["temperature"]), slots(batch["top_k"]),
+                slots(batch["top_p"]), rng, seeds=slots(batch["seeds"]),
+                gen_idx=gen_idx)
+            ids, logprobs = sampling_ops.reveal(
+                logits, x0, batch["slot_masked"] > 0, batch["reveal_quota"],
+                c.diffusion_remasking, c.diffusion_confidence_threshold)
+            top = None
+            if want_top_logprobs:
+                _, top_ids, top_lps = sampling_ops.compute_top_logprobs(
+                    logits, x0)
+                top = (top_ids, top_lps)
+            return ids, logprobs, top
 
         if not packed:
             return jax.jit(step_body, donate_argnums=(1,))
@@ -2293,6 +2375,20 @@ class EngineCore:
     # ---------- public API ----------
 
     def add_request(self, request: Request) -> None:
+        if self.block_length and (
+                request.do_remote_decode or request.do_remote_prefill
+                or request.kv_transfer_params or request.resume_offset):
+            # A transfer or a resume would hand over a request in mid-block:
+            # neither carries the block's revealed slots (ROADMAP queue B).
+            logger.error(
+                "request %s asks for a KV transfer or a stream resume, which "
+                "a block-diffusion engine does not serve; rejecting",
+                request.request_id)
+            request.state = RequestState.FINISHED_ABORTED
+            self._rejected.append(RequestOutput(
+                request.request_id, [], True,
+                finish_reason=RequestState.FINISHED_ABORTED.value))
+            return
         if request.do_remote_decode and (
                 self.kv_connector is None
                 or getattr(self.kv_connector, "server", None) is None):
@@ -2395,7 +2491,7 @@ class EngineCore:
 
     def _empty_batch_np(self, T: int, S: int, Q: int, B: int) -> Dict[str, np.ndarray]:
         """An empty (all padded) batch: views of one fresh packed buffer."""
-        layout = BatchLayout(T, S, Q, B)
+        layout = BatchLayout(T, S, Q, B, R=self.block_length or 1)
         return layout.views(layout.new_buffer())
 
     def _fill_batch(self, arrs: Dict[str, np.ndarray], scheduled,
@@ -2433,6 +2529,67 @@ class EngineCore:
             arrs["gen_idx"][s] = len(req.output_token_ids)
             t += n
 
+    def _fill_block_batch(self, arrs: Dict[str, np.ndarray],
+                          scheduled) -> None:
+        """``_fill_batch`` for a block-diffusion engine's rows: prompt
+        chunks of whole blocks, and the B slots of an open block (a
+        denoising pass: known tokens, slots revealed ahead of a masked one,
+        the mask token elsewhere; every slot is sampled, the masked ones may
+        be revealed) or of a completed one (its commit pass).  Filled array
+        by array, not row by row: 64 rows of four tokens every step would
+        pay some twenty numpy calls a row (PERF.md section 6, PR 31: the
+        host chain before the launch then outlasts the interpreter's 5 ms
+        switch interval, and the server's thread takes turns with it)."""
+        c, bs, B = self.model_config, self.config.block_size, self.block_length
+        S = len(scheduled)
+        reqs = [sr.request for sr in scheduled]
+        ns = np.fromiter((sr.num_new_tokens for sr in scheduled), np.int64, S)
+        starts = np.fromiter((r.num_computed_tokens for r in reqs),
+                             np.int64, S)
+        firsts = np.cumsum(ns) - ns         # a row's first flat token
+        T = int(ns.sum())
+        flat = np.arange(T)
+        row = np.repeat(np.arange(S), ns)
+        qpos = flat - firsts[row]
+        pos = starts[row] + qpos
+        toks: List[int] = []
+        masked = np.zeros((S, B), np.int32)
+        for s, (sr, req) in enumerate(zip(scheduled, reqs)):
+            start, n = req.num_computed_tokens, sr.num_new_tokens
+            gen = start - req.num_prompt_tokens
+            known = (req.output_token_ids[gen:gen + n] if gen >= 0
+                     else req.all_token_ids[start:start + n])
+            toks += known
+            arrs["block_tables"][s, :len(req.block_ids)] = req.block_ids
+            if sr.denoise:
+                ahead = [req.revealed_ahead.get(start + i)
+                         for i in range(len(known), n)]
+                toks += [c.mask_token_id if a is None else a[0]
+                         for a in ahead]
+                masked[s, len(known):] = [a is None for a in ahead]
+                arrs["reveal_quota"][s] = c.diffusion_quota(req.denoise_step)
+        arrs["token_ids"][:T] = toks
+        arrs["positions"][:T] = pos
+        arrs["token_seq_ids"][:T] = row
+        arrs["token_qpos"][:T] = qpos
+        arrs["qtok_idx"][row, qpos] = flat
+        arrs["slot_mapping"][:T] = (
+            arrs["block_tables"][row, pos // bs] * bs + pos % bs)
+        arrs["seq_lens"][:S] = starts + ns
+        arrs["slot_masked"][:S] = masked
+        den = np.flatnonzero([sr.denoise for sr in scheduled])
+        arrs["sample_idx"].reshape(-1, B)[den] = (
+            firsts[den, None] + np.arange(B))
+        sps = [r.sampling for r in reqs]
+        arrs["temperature"][:S] = [sp.temperature for sp in sps]
+        arrs["top_k"][:S] = [sp.top_k for sp in sps]
+        arrs["top_p"][:S] = [sp.top_p for sp in sps]
+        arrs["seeds"][:S] = [-1 if sp.seed is None
+                             else int(sp.seed) & 0x7FFFFFFF for sp in sps]
+        # Tokens generated before the row's first sampled slot.
+        arrs["gen_idx"][:S] = np.maximum(
+            starts - [r.num_prompt_tokens for r in reqs], 0)
+
     def _split_by_shard(self, scheduled) -> List[List]:
         per: List[List] = [[] for _ in range(self.dp)]
         for sr in scheduled:
@@ -2458,14 +2615,16 @@ class EngineCore:
                              cfg.max_num_batched_tokens)
             S = _next_bucket(S_real, min(cfg.min_seq_bucket, cfg.max_num_seqs),
                              cfg.max_num_seqs)
-            layout = BatchLayout(T, S, Q, B)
+            layout = BatchLayout(T, S, Q, B, R=self.block_length or 1)
             buf = layout.new_buffer()
             views = layout.views(buf)
-            self._fill_batch(views, out.scheduled)
+            (self._fill_block_batch if self.block_length
+             else self._fill_batch)(views, out.scheduled)
             scheduled, rows = out.scheduled, np.arange(S_real)
             # Per row: context at the end of the step, tokens of the step.
             ends = views["seq_lens"][:S_real]
-            news = np.diff(views["sample_idx"][:S_real] + 1, prepend=0)
+            news = np.fromiter((sr.num_new_tokens for sr in scheduled),
+                               np.int64, S_real)
         else:
             per = self._split_by_shard(out.scheduled)
             T_l = _next_bucket(
@@ -2513,8 +2672,18 @@ class EngineCore:
         c = self.model_config
         ends = np.asarray(ends, np.int64)
         news = np.minimum(np.asarray(news, np.int64), ends)
-        # A full layer: queries at positions L - n .. L - 1 read p + 1 keys.
-        full = int((news * (2 * ends - news + 1)).sum()) // 2
+        B = c.diffusion_block_length
+        if B:
+            # Block visibility: each of a block's B queries reads every key
+            # to the block's end; rows hold whole blocks.
+            def blocks_upto(x):      # sum of (b + 1) B over blocks b < x / B
+                return x // B * (x // B + 1) // 2 * B
+            full = B * int((blocks_upto(ends)
+                            - blocks_upto(ends - news)).sum())
+        else:
+            # A full layer: queries at positions L - n .. L - 1 read p + 1
+            # keys.
+            full = int((news * (2 * ends - news + 1)).sum()) // 2
         counts = {"kv_ctx_tokens": c.num_layers * full,
                   "kv_read_tokens": c.num_layers * full,
                   "kv_held_tokens": c.num_layers * int(ends.sum()),
@@ -2568,6 +2737,9 @@ class EngineCore:
         nth = np.arange(len(row)) - np.repeat(np.cumsum(tiles) - tiles, tiles)
         q_first = (ends - news)[row] + nth * qt         # a tile's positions
         q_last = np.minimum(q_first + qt, ends[row]) - 1
+        if c.diffusion_block_length:    # the last key its last query sees
+            B = c.diffusion_block_length
+            q_last = (q_last // B + 1) * B - 1
         real = slots = 0
         n_window = c.layer_types.count(SLIDING)
         for window, layers in ((c.sliding_window, n_window),
@@ -2739,7 +2911,9 @@ class EngineCore:
         self.metrics.engine_steps.inc()
         self._note_step(step_t0, now, [sr.request for sr in scheduled],
                         sched.prefill_tokens, sched.decode_tokens,
-                        fused=False, kv=self._step_kv)
+                        fused=False, kv=self._step_kv,
+                        **(self._block_pass_counts(scheduled, ids)
+                           if self.block_length else {}))
         if self.eplb is not None:
             # Record routed logical ids (sampled; padding rows excluded so
             # the zero-embedding's favorite expert doesn't skew the stats)
@@ -2752,6 +2926,10 @@ class EngineCore:
             self.params = self.eplb.on_step(
                 routed, self._step_count, self.params, self.mesh)
 
+        if self.block_length:
+            self._retire_block_rows(scheduled, rows, ids, logprobs, top, now,
+                                    outputs)
+            scheduled = ()      # nothing is left for the one-token rows below
         for i, sr in enumerate(scheduled):
             s = int(rows[i])
             req, n = sr.request, sr.num_new_tokens
@@ -2811,16 +2989,7 @@ class EngineCore:
                 top_logprobs=top_lp)
             outputs.append(out)
             if finish is not None:
-                self.scheduler.finish(req, RequestState(finish))
-                self._spec_forget(req.request_id)
-                self.metrics.request_success.labels(
-                    model_name=self.metrics.model_name,
-                    finished_reason=finish).inc()
-                self.metrics.e2e_request_latency.observe(now - req.arrival_time)
-                self._trace_phase(
-                    req, "engine.decode", "decode",
-                    req.first_token_time or now, now,
-                    n_tokens=len(req.output_token_ids), finish=finish)
+                self._finish_request(req, finish, now)
 
         # Step composition counters + the step-latency model's sample,
         # all from scheduler metadata and the clock reads already taken
@@ -2834,6 +3003,108 @@ class EngineCore:
             (now - step_t0) * 1e3)
         self._update_queue_metrics()
         return outputs
+
+    def _finish_request(self, req: Request, finish: str, now: float) -> None:
+        """A classic step's row stopped (``_check_stop``): out of the
+        scheduler, into the request metrics and the trace."""
+        self.scheduler.finish(req, RequestState(finish))
+        self._spec_forget(req.request_id)
+        self.metrics.request_success.labels(
+            model_name=self.metrics.model_name,
+            finished_reason=finish).inc()
+        self.metrics.e2e_request_latency.observe(now - req.arrival_time)
+        self._trace_phase(
+            req, "engine.decode", "decode",
+            req.first_token_time or now, now,
+            n_tokens=len(req.output_token_ids), finish=finish)
+
+    # ---------- generation by diffusion over blocks ----------
+
+    def _block_pass_counts(self, scheduled, ids: np.ndarray) -> Dict[str, int]:
+        """What a block-diffusion step did (``engine.step`` attributes, and
+        the /metrics counters): rows that ran a denoising pass and rows that
+        committed a block, the slots those passes forwarded (masked and
+        revealed, commit passes included: what the device computed), and
+        the tokens the denoising passes revealed (``ids`` is the fetched
+        [S, B], -1 where nothing was revealed, padding included)."""
+        denoise = sum(sr.denoise for sr in scheduled)
+        commit = sum(sr.commit for sr in scheduled)
+        revealed = int(np.count_nonzero(ids >= 0))
+        self.metrics.add_diffusion_passes("denoise", denoise)
+        self.metrics.add_diffusion_passes("commit", commit)
+        self.metrics.diffusion_revealed_tokens.inc(revealed)
+        return {"denoise_rows": denoise, "commit_rows": commit,
+                "denoise_slots": (denoise + commit) * self.block_length,
+                "denoise_revealed": revealed}
+
+    def _retire_block_rows(self, scheduled, rows, ids, logprobs, top,
+                           now: float, outputs: List[RequestOutput]) -> None:
+        """The rows of a block-diffusion step.  A chunk's or a commit
+        pass's keys are final: the request's computed tokens advance, full
+        pages join the prefix cache.  A denoising pass's ``ids[row]`` holds
+        the slots it revealed; those that continue the request's known
+        tokens without a gap are its output of this step (0..B tokens, each
+        with the logprob of the pass that revealed it), the others wait in
+        ``revealed_ahead``.  A stop is checked token by token as the prefix
+        grows, so an EOS counts once every slot before it is revealed and
+        whatever was revealed behind it is dropped."""
+        B = self.block_length
+        for i, sr in enumerate(scheduled):
+            s = int(rows[i])
+            req, n = sr.request, sr.num_new_tokens
+            self._account_collective_bytes(n)
+            if not sr.denoise:
+                req.num_computed_tokens += n
+                self.kv_manager.cache_full_blocks(req)
+                continue
+            start = req.num_computed_tokens
+            want_lp = req.sampling.logprobs is not None
+            n_top = min(int(req.sampling.logprobs or 0),
+                        top[0].shape[1] if top is not None else 0)
+            for j in np.flatnonzero(ids[s] >= 0):
+                r = s * B + int(j)
+                req.revealed_ahead[start + int(j)] = (
+                    int(ids[s, j]),
+                    float(logprobs[s, j]) if want_lp else None,
+                    {int(top[0][r, m]): float(top[1][r, m])
+                     for m in range(n_top)} if n_top else None)
+            req.denoise_step += 1
+            new: List[Any] = []
+            finish = None
+            while finish is None and req.num_tokens in req.revealed_ahead:
+                new.append(req.revealed_ahead.pop(req.num_tokens))
+                req.output_token_ids.append(new[-1][0])
+                finish = self._check_stop(req, new[-1][0])
+            if req.num_tokens >= start + B:
+                req.reset_block()      # complete: the next pass commits it
+            if not new:
+                continue               # revealed nothing that can stream yet
+            if req.first_token_time is None:
+                req.first_token_time = now
+                self.metrics.prompt_tokens.inc(req.num_prompt_tokens)
+                if req.num_cached_prompt_tokens:
+                    self.metrics.prefix_cache_hits.inc(
+                        req.num_cached_prompt_tokens)
+                self.metrics.prefix_cache_queries.inc(req.num_prompt_tokens)
+                self.metrics.time_to_first_token.observe(
+                    now - req.arrival_time)
+                self._trace_phase(
+                    req, "engine.prefill", "prefill",
+                    req.first_schedule_time or req.arrival_time, now,
+                    cached_tokens=req.num_cached_prompt_tokens or None)
+            elif req.last_token_time is not None:
+                gap = (now - req.last_token_time) / len(new)
+                for _ in new:
+                    self.metrics.inter_token_latency.observe(gap)
+            req.last_token_time = now
+            self.metrics.generation_tokens.inc(len(new))
+            outputs.append(RequestOutput(
+                req.request_id, [t for t, _, _ in new], finish is not None,
+                finish_reason=finish,
+                logprobs=[lp for _, lp, _ in new] if want_lp else None,
+                top_logprobs=[tp for _, _, tp in new] if n_top else None))
+            if finish is not None:
+                self._finish_request(req, finish, now)
 
     def _finish_remote_prefill(self, req: Request, first_token: int) -> RequestOutput:
         req.state = RequestState.FINISHED_REMOTE_PREFILL

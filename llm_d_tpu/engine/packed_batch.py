@@ -13,6 +13,11 @@ arrays travel by bit pattern (``ndarray.view`` on the host,
 The buffer's length does not determine the bucket (T + ... can collide), so
 the layout itself reaches the program as a static, hashable argument.
 
+A block-diffusion model's step (``R`` > 1: the slots of a block) samples at
+every slot of every row's block: ``sample_idx`` grows to ``S * R`` entries
+and two arrays join the buffer, which slots are masked and how many the pass
+reveals a row.  ``R`` = 1 is the autoregressive layout, field for field.
+
 Stacked (SPMD dp) mode: ``dp > 1`` gives a ``[dp, size]`` buffer, one row a
 shard, sharded over the leading axis; every array comes out ``[dp, ...]``.
 """
@@ -31,9 +36,9 @@ import numpy as np
 _I32, _F32 = np.dtype(np.int32), np.dtype(np.float32)
 
 
-def _fields(T: int, S: int, Q: int, B: int):
+def _fields(T: int, S: int, Q: int, B: int, R: int = 1):
     """(name, shape, dtype, value of a padded slot) of the step's batch."""
-    return (
+    fields = (
         ("token_ids", (T,), _I32, 0),
         ("positions", (T,), _I32, 0),
         ("token_seq_ids", (T,), _I32, 0),
@@ -41,7 +46,7 @@ def _fields(T: int, S: int, Q: int, B: int):
         ("slot_mapping", (T,), _I32, 0),      # local block 0 = trash
         ("block_tables", (S, B), _I32, 0),
         ("seq_lens", (S,), _I32, 0),
-        ("sample_idx", (S,), _I32, 0),
+        ("sample_idx", (S * R,), _I32, 0),
         ("qtok_idx", (S, Q), _I32, T),        # T = padded-q sentinel
         ("temperature", (S,), _F32, 0.0),
         ("top_k", (S,), _I32, 0),
@@ -49,14 +54,20 @@ def _fields(T: int, S: int, Q: int, B: int):
         ("seeds", (S,), _I32, -1),
         ("gen_idx", (S,), _I32, 0),
     )
+    if R > 1:
+        fields += (
+            ("slot_masked", (S, R), _I32, 0),     # 1 = the slot holds the mask
+            ("reveal_quota", (S,), _I32, 0),      # slots the pass reveals
+        )
+    return fields
 
 
 @functools.lru_cache(maxsize=None)
-def _slots(T: int, S: int, Q: int, B: int):
+def _slots(T: int, S: int, Q: int, B: int, R: int = 1):
     """((name, start, stop, shape, dtype), ...), the buffer's length, and
     ((start, stop, int32 bit pattern), ...) of the defaults that are not 0."""
     slots, fills, at = [], [], 0
-    for name, shape, dtype, pad in _fields(T, S, Q, B):
+    for name, shape, dtype, pad in _fields(T, S, Q, B, R):
         stop = at + math.prod(shape)
         slots.append((name, at, stop, shape, dtype))
         bits = int(np.array(pad, dtype).view(np.int32))
@@ -69,15 +80,17 @@ def _slots(T: int, S: int, Q: int, B: int):
 @dataclasses.dataclass(frozen=True)
 class BatchLayout:
     """Bucket of one step program: ``T`` token rows, ``S`` sequence rows,
-    ``Q`` query slots a sequence, ``B`` block-table columns, per shard."""
+    ``Q`` query slots a sequence, ``B`` block-table columns, per shard;
+    ``R`` sampled slots a sequence (a block-diffusion model's block)."""
     T: int
     S: int
     Q: int
     B: int
     dp: int = 1
+    R: int = 1
 
     def _slots(self):
-        return _slots(self.T, self.S, self.Q, self.B)
+        return _slots(self.T, self.S, self.Q, self.B, self.R)
 
     @property
     def shape(self) -> Tuple[int, ...]:

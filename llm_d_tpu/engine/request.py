@@ -108,6 +108,25 @@ class Request:
     spec_drafted: int = 0
     spec_accepted: int = 0
 
+    # --- generation by diffusion over blocks ---
+    # The block being denoised is the one that holds position
+    # ``num_computed_tokens`` (its keys are not final yet).  Its slots below
+    # ``num_tokens`` are revealed and live in the token lists like any
+    # other token; ``revealed_ahead`` holds the slots a pass revealed BEYOND
+    # a slot that is still masked (position -> (token, logprob, top-N or
+    # None)), which cannot be streamed yet and join ``output_token_ids``
+    # when the gap closes.  Every other slot of the block is masked.  The
+    # engine tracks this itself and never compares ids with the mask token:
+    # a prompt may contain that id.  ``denoise_step`` counts the block's
+    # passes so far (it sets a pass's quota).  Preemption drops both: the
+    # request resumes from its token lists alone.
+    revealed_ahead: Dict[int, Any] = dataclasses.field(default_factory=dict)
+    denoise_step: int = 0
+
+    def reset_block(self) -> None:
+        self.revealed_ahead = {}
+        self.denoise_step = 0
+
     @property
     def slo_tier(self) -> int:
         """Criticality as a priority tier (critical=-1 < standard=0 <

@@ -16,6 +16,24 @@ Lifecycle: requests carry an optional absolute deadline.  Every
 ``schedule()`` pass first expires deadlines — queued requests whose
 budget passed are refused, running ones are evicted at the step boundary
 — and frees their KV blocks the same step (the server renders the 504).
+
+Generation by diffusion over blocks (``block_length`` B > 0).  A request no
+longer asks for one token a step.  Its keys are final ("computed") in whole
+blocks only, and what it asks for follows from its token lists:
+
+  - whole blocks of known tokens that are not computed yet (the prompt's,
+    a preempted request's answer so far, or the block the last denoising
+    pass completed) are a CHUNK whose keys are kept: a multiple of B tokens,
+    so every chunk ends on a block boundary, and the prompt's chunks stop at
+    its last whole block.  The chunk of one just-completed block is the
+    block's COMMIT pass;
+  - otherwise the block that holds ``num_computed_tokens`` is open, and the
+    request asks for a DENOISING pass over its B slots: KV is allocated to
+    the block's end, nothing of the pass is kept, and
+    ``num_computed_tokens`` stays where it is until the block commits.
+
+Denoising and commit passes are the decode entries (B tokens each, funded
+first, counted as ``decode_tokens``); every other chunk is prefill.
 """
 
 from __future__ import annotations
@@ -39,6 +57,11 @@ class ScheduledRequest:
     # the engine's draft+verify program appends up to this many extra
     # tokens and rolls the rejected tail's blocks back the same step).
     num_draft_tokens: int = 0
+    # Block diffusion: a denoising pass over the request's open block
+    # (nothing of it is kept), or the commit pass of the block just
+    # completed.  Neither: a chunk of prefill.
+    denoise: bool = False
+    commit: bool = False
 
 
 @dataclasses.dataclass
@@ -67,8 +90,11 @@ class Scheduler:
         max_num_seqs: int = 64,
         max_num_batched_tokens: int = 1024,
         max_model_len: int = 32000,
+        block_length: int = 0,
     ) -> None:
         self.kv = kv
+        # Block diffusion (module docstring); 0 = autoregressive.
+        self.block_length = block_length
         self.max_num_seqs = max_num_seqs
         self.max_num_batched_tokens = max_num_batched_tokens
         self.max_model_len = max_model_len
@@ -154,6 +180,7 @@ class Scheduler:
             self.running.remove(victim)
             self.kv.free(victim)
             victim.num_computed_tokens = 0
+            victim.reset_block()
             victim.num_preemptions += 1
             victim.state = RequestState.PREEMPTED
             self.waiting.appendleft(victim)
@@ -189,12 +216,11 @@ class Scheduler:
         scheduled (``(0, 0)`` when nothing fit).  Only what is returned
         may be charged to the budget — a request that bails leaves its
         slack for later chunks (budget conservation)."""
-        remaining = req.num_tokens - req.num_computed_tokens
-        if remaining <= 0:
-            remaining = 1       # decode: compute the next token's KV
-        n = min(remaining, budget)
+        unit = self.block_length or 1
+        remaining, denoise = self._wanted_tokens(req)
+        n = min(remaining, budget // unit * unit)
         if cap is not None:
-            n = min(n, max(int(cap), 1))
+            n = min(n, max(int(cap) // unit * unit, unit))
         # Terminal path: a request whose block demand exceeds the whole
         # pool can never run — fail it instead of livelocking with n=0
         # forever (has_work() true, no progress, no client error).
@@ -255,9 +281,38 @@ class Scheduler:
             while spec_n > 0 and self.kv.allocate(
                     req, req.num_computed_tokens + n + spec_n) is None:
                 spec_n -= 1
-        scheduled.append(ScheduledRequest(req, n, num_draft_tokens=spec_n))
+        scheduled.append(ScheduledRequest(
+            req, n, num_draft_tokens=spec_n, denoise=denoise,
+            commit=bool(self.block_length) and not denoise
+            and self._is_decode(req)))
         scheduled_ids.add(req.request_id)
         return n, spec_n
+
+    def _wanted_tokens(self, req: Request) -> Tuple[int, bool]:
+        """(tokens the request asks of a step before budget and cap, whether
+        that is a denoising pass).  Autoregressive: what is left of its
+        known tokens, or the next token's KV.  Block diffusion: its whole
+        blocks of known tokens not computed yet, else a pass over its open
+        block."""
+        B = self.block_length
+        if not B:
+            return max(req.num_tokens - req.num_computed_tokens, 1), False
+        whole = req.num_tokens // B * B - req.num_computed_tokens
+        return (whole, False) if whole > 0 else (B, True)
+
+    def _is_decode(self, r: Request) -> bool:
+        """A decode entry, funded before any prefill chunk.  Autoregressive:
+        the request has emitted output and only its last token's KV is left
+        to compute (the engine's per-row predicate).  Block diffusion: its
+        prompt's whole blocks are computed and it asks for one block, a
+        denoising pass or a commit."""
+        B = self.block_length
+        if B:
+            return (r.num_computed_tokens >= r.num_prompt_tokens // B * B
+                    and self._wanted_tokens(r)[0] <= B)
+        return (bool(r.output_token_ids)
+                and r.num_tokens - r.num_computed_tokens <= 1
+                and not r.do_remote_decode)
 
     def schedule(self) -> SchedulerOutput:
         scheduled: List[ScheduledRequest] = []
@@ -279,16 +334,14 @@ class Scheduler:
         # left to compute (the engine's per-row is_decode predicate);
         # everything else running is an in-flight prefill chunk.
         running = list(self.running)
-
-        def is_decode(r):
-            return (bool(r.output_token_ids)
-                    and r.num_tokens - r.num_computed_tokens <= 1
-                    and not r.do_remote_decode)
+        is_decode = self._is_decode
+        # What the smallest entry costs: a token, or a block.
+        unit = self.block_length or 1
 
         decodes = [r for r in running if is_decode(r)]
         chunks = [r for r in running if not is_decode(r)]
         for req in decodes:
-            if budget <= 0:
+            if budget < unit:
                 break
             if req.request_id in preempted_now:
                 continue        # evicted by an earlier request in this pass
@@ -306,7 +359,7 @@ class Scheduler:
         if self.prefill_chunk_cap is not None:
             cap = self.prefill_chunk_cap(decode_tokens + spec_tokens)
         for req in chunks:
-            if budget <= 0:
+            if budget < unit:
                 break
             if req.request_id in preempted_now:
                 continue
@@ -323,7 +376,7 @@ class Scheduler:
                          key=lambda r: (r.slo_tier, r.priority,
                                         r.arrival_time))
         for req in pending:
-            if budget <= 0 or len(self.running) >= self.max_num_seqs:
+            if budget < unit or len(self.running) >= self.max_num_seqs:
                 break
             if req.request_id in preempted_now:
                 continue
@@ -350,11 +403,18 @@ class Scheduler:
                 if req.resume_offset:
                     req.resume_restored_tokens = max(
                         0, n_cached - req.num_prompt_tokens)
-            remaining = req.num_tokens - req.num_computed_tokens
-            n = min(remaining, budget)
-            if cap is not None:
+            if unit > 1:
+                # Nothing to prefill (a prompt shorter than a block, or one
+                # whose whole blocks the cache holds): straight to the first
+                # denoising pass, a decode entry.
+                remaining, denoise = self._wanted_tokens(req)
+            else:
+                remaining, denoise = (
+                    req.num_tokens - req.num_computed_tokens, False)
+            n = min(remaining, budget // unit * unit)
+            if cap is not None and not denoise:
                 # First chunks obey the same per-chunk cap as running ones.
-                n = min(n, max(int(cap), 1))
+                n = min(n, max(int(cap) // unit * unit, unit))
             if n <= 0:
                 continue
             ok = self.kv.allocate(req, req.num_computed_tokens + n, reuse)
@@ -376,8 +436,12 @@ class Scheduler:
             self.running.append(req)
             req.state = RequestState.RUNNING
             budget -= n
-            prefill_tokens += n
-            scheduled.append(ScheduledRequest(req, n, is_first_schedule=first))
+            if denoise:
+                decode_tokens += n
+            else:
+                prefill_tokens += n
+            scheduled.append(ScheduledRequest(
+                req, n, is_first_schedule=first, denoise=denoise))
 
         self.last_schedule_stats = {
             "decode_tokens": decode_tokens,

@@ -19,6 +19,11 @@ SLIDING, FULL = "sliding_attention", "full_attention"
 # The window of a full-attention layer: one that never binds (above any
 # position, with room to add a position without overflowing int32).
 NO_WINDOW = 1 << 30
+# Which masked slots of a block a denoising pass reveals, q the pass's quota:
+# the first q from the left; the q of highest confidence; every slot whose
+# confidence passes the threshold if they are at least q, else the q highest.
+DIFFUSION_REMASKING = ("sequential", "low_confidence_static",
+                       "low_confidence_dynamic")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,6 +77,19 @@ class ModelConfig:
     # RMS norms on the attention and MLP outputs too, before the residual.
     sandwich_norm: bool = False
     embed_scale: float = 1.0                # multiplies the token embedding
+    # --- generation by diffusion over blocks (0 = autoregressive) ---
+    # Attention is block-causal (the query at position p sees key j iff
+    # j // B <= p // B) and a block of B tokens is generated by denoising
+    # passes over its B slots, revealed or holding ``mask_token_id``, then
+    # one commit pass whose keys and values are kept.  The model's
+    # generation settings, not flags of the server: pass s of a block
+    # reveals B // steps masked slots (+1 in the first B % steps passes),
+    # chosen by ``diffusion_remasking`` (DIFFUSION_REMASKING).
+    diffusion_block_length: int = 0
+    mask_token_id: int = 0
+    diffusion_steps: int = 0                # 0 = one pass a slot (B)
+    diffusion_remasking: str = "sequential"
+    diffusion_confidence_threshold: float = 0.9
 
     @property
     def use_mla(self) -> bool:
@@ -97,6 +115,31 @@ class ModelConfig:
             raise ValueError(
                 "layer_types, attn_output_gate and sandwich_norm belong to "
                 "the GQA attention block; the MLA path has none of them")
+
+        B = self.diffusion_block_length
+        if B:
+            if self.use_mla or kinds:
+                raise ValueError(
+                    "block diffusion is served by the GQA attention block "
+                    "with every layer full; MLA and layer_types are not")
+            if not 1 <= (self.diffusion_steps or B) <= B:
+                raise ValueError(
+                    f"diffusion_steps must lie in 1..{B} (a pass reveals at "
+                    f"least one slot), got {self.diffusion_steps}")
+            if self.diffusion_remasking not in DIFFUSION_REMASKING:
+                raise ValueError(
+                    f"diffusion_remasking must be one of "
+                    f"{DIFFUSION_REMASKING}, got {self.diffusion_remasking!r}")
+            if not 0 <= self.mask_token_id < self.vocab_size:
+                raise ValueError(
+                    f"mask_token_id {self.mask_token_id} is outside the "
+                    f"vocabulary of {self.vocab_size}")
+
+    def diffusion_quota(self, step: int) -> int:
+        """Masked slots the ``step``-th denoising pass of a block reveals."""
+        B, steps = self.diffusion_block_length, (
+            self.diffusion_steps or self.diffusion_block_length)
+        return max(B // steps + (step < B % steps), 1)
 
     @property
     def layer_windows(self) -> Tuple[int, ...]:
@@ -168,6 +211,16 @@ PRESETS = {
         intermediate_size=6144, num_layers=48, num_heads=32, num_kv_heads=4,
         head_dim=128, rope_theta=1000000.0, qk_norm=True, max_model_len=32768,
         num_experts=128, num_experts_per_tok=8, moe_intermediate_size=768),
+    # SDAR: Qwen3-MoE's weight tree, generated by diffusion over blocks of 4
+    # (block length, passes a block and mask id are the family's generation
+    # settings: benchmarks/configs/sdar-30b-a3b.json, ``assumed``).
+    "sdar-30b-a3b": ModelConfig(
+        name="sdar-30b-a3b", vocab_size=151936, hidden_size=2048,
+        intermediate_size=6144, num_layers=48, num_heads=32, num_kv_heads=4,
+        head_dim=128, rope_theta=1000000.0, rms_norm_eps=1e-6, qk_norm=True,
+        max_model_len=32768, num_experts=128, num_experts_per_tok=8,
+        moe_intermediate_size=768, diffusion_block_length=4,
+        diffusion_steps=4, mask_token_id=151669),
     "mixtral-8x22b": ModelConfig(
         name="mixtral-8x22b", vocab_size=32768, hidden_size=6144,
         intermediate_size=16384, num_layers=56, num_heads=48, num_kv_heads=8,
@@ -217,6 +270,14 @@ PRESETS = {
         layer_types=(SLIDING, SLIDING, SLIDING, FULL) * 2, sliding_window=48,
         rope_on_full_attention=False, attn_output_gate=True,
         sandwich_norm=True, embed_scale=8.0),
+    # Tiny block-diffusion MoE for CPU tests: sdar-30b-a3b's mechanisms
+    # (per-head q/k norm, softmax top-2 of 8, no shared expert, blocks of 4).
+    "tiny-sdar": ModelConfig(
+        name="tiny-sdar", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_layers=2, num_heads=4, num_kv_heads=2,
+        rope_theta=10000.0, max_model_len=512, qk_norm=True, num_experts=8,
+        num_experts_per_tok=2, moe_intermediate_size=96,
+        diffusion_block_length=4, diffusion_steps=4, mask_token_id=511),
     # Tiny MLA+MoE config for CPU tests.
     "tiny-mla": ModelConfig(
         name="tiny-mla", vocab_size=512, hidden_size=64,

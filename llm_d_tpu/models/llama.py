@@ -21,7 +21,7 @@ from jax.sharding import PartitionSpec as P
 from llm_d_tpu.models.config import ModelConfig
 from llm_d_tpu.ops import layers as L
 from llm_d_tpu.ops.attention import (
-    attention_with_kv_update, with_query_tiles)
+    attention_with_kv_update, with_block_visibility, with_query_tiles)
 
 Params = Dict[str, Any]
 
@@ -160,7 +160,9 @@ def forward(
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One engine step over a ragged batch.
 
-    Returns (hidden states for sampling positions [S, D], updated kv cache).
+    Returns (hidden states for sampling positions [S, D], updated kv cache);
+    a block-diffusion model's batch names every slot of every row's block in
+    ``sample_idx``, so [S * B, D].
 
     SPMD dp (stacked mode): when batch arrays carry a leading [dp] dim
     (``token_ids.ndim == 2``), attention runs per dp shard under
@@ -173,10 +175,12 @@ def forward(
     x = embed_tokens(params, batch["token_ids"], c)  # [T, D] / [dp, T_l, D]
 
     caches0 = (kv_cache["k"], kv_cache["v"])
-    # Once a step program, outside the layer scan: the query tile list the
-    # Pallas prefill kernels walk in every layer.
-    batch = with_query_tiles(batch, c.num_heads, caches0[0].shape[-1],
-                             attn_backend, mesh)
+    # Once a step program, outside the layer scan: a block-diffusion model's
+    # visibility limits, and the query tile list the Pallas prefill kernels
+    # walk in every layer.
+    batch = with_query_tiles(
+        with_block_visibility(batch, c.diffusion_block_length),
+        c.num_heads, caches0[0].shape[-1], attn_backend, mesh)
 
     # The FULL stacked KV cache rides the scan carry and each layer updates
     # its plane in place (Pallas aliasing / scatter-at-layer): slicing the

@@ -28,7 +28,8 @@ from llm_d_tpu.models.llama import (  # noqa: F401  (re-exports: the MoE
     init_draft_params, mlp_out)
 from llm_d_tpu.ops import layers as L
 from llm_d_tpu.ops import moe as moe_ops
-from llm_d_tpu.ops.attention import with_query_tiles
+from llm_d_tpu.ops.attention import (
+    with_block_visibility, with_query_tiles)
 from llm_d_tpu.parallel.mesh import AXIS_EP
 
 Params = Dict[str, Any]
@@ -138,10 +139,12 @@ def forward(
     stacked = batch["token_ids"].ndim == 2
     x = embed_tokens(params, batch["token_ids"], c)   # [T, D] / [dp, T_l, D]
     cache_keys = ("kv",) if c.use_mla else ("k", "v")
-    # Once a step program, outside both layer scans: the query tile list
-    # the Pallas prefill kernels walk in every layer.
+    # Once a step program, outside both layer scans: a block-diffusion
+    # model's visibility limits, and the query tile list the Pallas prefill
+    # kernels walk in every layer.
     batch = with_query_tiles(
-        batch, c.num_heads, kv_cache[cache_keys[0]].shape[-1], attn_backend,
+        with_block_visibility(batch, c.diffusion_block_length),
+        c.num_heads, kv_cache[cache_keys[0]].shape[-1], attn_backend,
         mesh, mla=c.use_mla)
     # DBO threshold by phase: the program's query width is static under jit,
     # and Q == 1 holds exactly for pure-decode programs (single-step or

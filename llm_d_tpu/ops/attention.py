@@ -58,6 +58,8 @@ def ragged_paged_attention_reference(
     soft_cap: Optional[float] = None,
     layer: Optional[jax.Array] = None,
     window: Optional[jax.Array] = None,    # i32: keys a query sees (None=all)
+    limits: Optional[jax.Array] = None,    # [T] i32 last key a query sees
+                                           # (None: its own position)
 ) -> jax.Array:               # [T, H, D]
     T, H, D = q.shape
     S, B = block_tables.shape
@@ -83,9 +85,11 @@ def ragged_paged_attention_reference(
         scores = soft_cap * jnp.tanh(scores / soft_cap)
 
     # Causal + length mask. key position c is valid for token t iff
-    # c <= positions[t] and c < seq_lens[seq(t)].
+    # c <= positions[t] (its visibility limit, where one is given) and
+    # c < seq_lens[seq(t)].
     key_pos = jnp.arange(C)[None, :]                       # [1, C]
-    valid = (key_pos <= positions[:, None]) & (
+    last = positions if limits is None else limits
+    valid = (key_pos <= last[:, None]) & (
         key_pos < seq_lens[token_seq_ids][:, None])        # [T, C]
     if window is not None:
         valid &= key_pos > positions[:, None] - window
@@ -135,6 +139,7 @@ def _flash_over_kv_chunks(
     kv_chunk: int, scale: float, soft_cap: Optional[float],
     layer: Optional[jax.Array] = None,
     window: Optional[jax.Array] = None,
+    q_lim: Optional[jax.Array] = None,   # [S, Q] last visible key (None: q_pos)
 ) -> jax.Array:           # [S, Q, H, D]
     """Online-softmax attention scanning the context in kv_chunk slices.
 
@@ -150,6 +155,7 @@ def _flash_over_kv_chunks(
     qf = qs.astype(jnp.float32).reshape(S, Q, KVH, G, D) * scale
 
     max_len = jnp.max(seq_lens)   # skip chunks past the longest context
+    q_last = q_pos if q_lim is None else q_lim
 
     def compute_chunk(carry, ci):
         m, l, acc = carry
@@ -160,7 +166,7 @@ def _flash_over_kv_chunks(
         if soft_cap is not None:
             s = soft_cap * jnp.tanh(s / soft_cap)
         key_pos = ci * kv_chunk + jnp.arange(kv_chunk)
-        valid = (key_pos[None, None, :] <= q_pos[:, :, None]) & (
+        valid = (key_pos[None, None, :] <= q_last[:, :, None]) & (
             key_pos[None, None, :] < seq_lens[:, None, None])
         if window is not None:
             valid &= key_pos[None, None, :] > q_pos[:, :, None] - window
@@ -226,6 +232,7 @@ def _flash_batched_q_chunks(
     scale: float, soft_cap: Optional[float],
     layer: Optional[jax.Array] = None,
     window: Optional[jax.Array] = None,
+    q_lim: Optional[jax.Array] = None,
 ) -> jax.Array:           # [S, Q, H, D]
     """All-sequences-batched prefill attention.
 
@@ -251,14 +258,18 @@ def _flash_batched_q_chunks(
     if qc == Q:
         return _flash_over_kv_chunks(
             qs, q_pos, slot_ids, seq_lens, k_cache, v_cache,
-            kv_chunk, scale, soft_cap, layer=layer, window=window)
+            kv_chunk, scale, soft_cap, layer=layer, window=window,
+            q_lim=q_lim)
 
     def one_q_chunk(_, qi):
         qs_i = jax.lax.dynamic_slice_in_dim(qs, qi * qc, qc, 1)
         qp_i = jax.lax.dynamic_slice_in_dim(q_pos, qi * qc, qc, 1)
+        ql_i = None if q_lim is None else jax.lax.dynamic_slice_in_dim(
+            q_lim, qi * qc, qc, 1)
         out_i = _flash_over_kv_chunks(
             qs_i, qp_i, slot_ids, seq_lens, k_cache, v_cache,
-            kv_chunk, scale, soft_cap, layer=layer, window=window)
+            kv_chunk, scale, soft_cap, layer=layer, window=window,
+            q_lim=ql_i)
         return None, out_i
 
     _, outs = jax.lax.scan(one_q_chunk, None,
@@ -277,6 +288,21 @@ def gather_per_seq_queries(q, positions, qtok_idx):
     pos_pad = jnp.concatenate(
         [positions, jnp.full((1,), -1, positions.dtype)])
     return q_pad[qtok_idx], pos_pad[qtok_idx]
+
+
+def with_block_visibility(batch, block_length: int):
+    """``batch`` plus ``vis_limit`` [T], the last key each query sees under
+    block-causal attention with blocks of ``block_length`` aligned on
+    absolute positions: ``(p // B + 1) * B - 1`` (a query sees its whole
+    block and every block before it).  Derived once a step program, by the
+    models' ``forward``; every backend masks by it in place of the query's
+    own position, which stays what the rotary embedding and a window use.
+    ``block_length`` 0 (an autoregressive model) adds nothing: the step
+    programs are the ones without the operand."""
+    if not block_length:
+        return batch
+    B = block_length
+    return dict(batch, vis_limit=(batch["positions"] // B + 1) * B - 1)
 
 
 # What ``query_tiles`` derives from a batch (``ATTN_BATCH_KEYS`` carries
@@ -325,7 +351,8 @@ def query_tiles(batch, q_tile: int):
 
       tile_seq [NT]      row a tile belongs to (a dead tile: the last row)
       tile_tok [NT, Qt]  flat token index per slot, T = pad (as qtok_idx)
-      tile_pos [NT, Qt]  its position, pad -> -1
+      tile_pos [NT, Qt]  the last key it sees: its position, or its
+                         ``vis_limit`` where the batch has one; pad -> -1
       tok_tile, tok_slot [T]  where each token's output lands; a token that
                          is in no row's list (padding, a fused round's dead
                          slot) reads the dead last tile: zeros
@@ -349,8 +376,8 @@ def query_tiles(batch, q_tile: int):
     live = (n < ends[-1])[:, None] & (slot < Q)
     tile_tok = jnp.where(
         live, qtok_idx[tile_seq[:, None], jnp.minimum(slot, Q - 1)], T)
-    pos_pad = jnp.concatenate(
-        [batch["positions"], jnp.full((1,), -1, batch["positions"].dtype)])
+    pos = batch.get("vis_limit", batch["positions"])
+    pos_pad = jnp.concatenate([pos, jnp.full((1,), -1, pos.dtype)])
     real = qpos < n_row[seq]
     return dict(
         tile_seq=tile_seq, tile_tok=tile_tok, tile_pos=pos_pad[tile_tok],
@@ -377,7 +404,8 @@ def with_query_tiles(batch, num_heads: int, row_width: int, backend: str,
     if qtok_idx.ndim == 3:
         derive = jax.vmap(derive)
     return dict(batch, **derive({k: batch[k] for k in (
-        "qtok_idx", "token_seq_ids", "token_qpos", "positions")}))
+        "qtok_idx", "token_seq_ids", "token_qpos", "positions", "vis_limit")
+        if k in batch}))
 
 
 def gather_query_tiles(q, batch, row_width: int, mla: bool = False):
@@ -403,6 +431,7 @@ def ragged_paged_attention_chunked(
     block_size: int, scale=None, soft_cap=None,
     layer: Optional[jax.Array] = None,
     window: Optional[jax.Array] = None,
+    limits: Optional[jax.Array] = None,    # [T] last key a query sees
 ) -> jax.Array:
     """Memory-bounded ragged attention (XLA flash recurrence).
 
@@ -416,6 +445,8 @@ def ragged_paged_attention_chunked(
     C = B * block_size
 
     qs, q_pos = gather_per_seq_queries(q, positions, qtok_idx)
+    q_lim = None if limits is None else gather_per_seq_queries(
+        q, limits, qtok_idx)[1]
     slot_ids = (block_tables[:, :, None] * block_size
                 + jnp.arange(block_size)[None, None, :]).reshape(S, C)
 
@@ -423,11 +454,11 @@ def ragged_paged_attention_chunked(
         out = _flash_over_kv_chunks(
             qs, q_pos, slot_ids, seq_lens, k_cache, v_cache,
             _chunk_size_for(C), scale, soft_cap, layer=layer,
-            window=window)                                     # [S, 1, H, D]
+            window=window, q_lim=q_lim)                        # [S, 1, H, D]
     else:
         out = _flash_batched_q_chunks(
             qs, q_pos, slot_ids, seq_lens, k_cache, v_cache,
-            scale, soft_cap, layer=layer, window=window)
+            scale, soft_cap, layer=layer, window=window, q_lim=q_lim)
 
     return out[token_seq_ids, token_qpos]       # [T, H, D]
 
@@ -477,7 +508,7 @@ def manual_over_mesh(fn, mesh, in_specs, out_specs):
 # Batch arrays attention consumes (replicated over tp under a TP mesh).
 ATTN_BATCH_KEYS = ("positions", "token_seq_ids", "token_qpos",
                    "slot_mapping", "block_tables", "seq_lens", "qtok_idx",
-                   *QUERY_TILE_KEYS)
+                   "vis_limit", *QUERY_TILE_KEYS)
 
 
 def attention_with_kv_update(
@@ -519,6 +550,13 @@ def attention_with_kv_update(
     (``gather_query_tiles``: Qt slots of one row each, only the tiles that
     hold a real query), not the padded [S, Q] rectangle; the chunked XLA
     path keeps the rectangle.
+
+    A batch with ``vis_limit`` (``with_block_visibility``: a block-diffusion
+    model) is masked by it: query i sees keys j <= vis_limit[i].  The tile
+    list already carries the limits as its ``tile_pos``; the reference and
+    chunked paths take them as an operand.  The one-query decode kernel
+    knows no limit and is never reached: every row of such a model brings
+    a whole block of queries.
 
     ``window``: query i sees keys j with i - window < j <= i.  Every
     backend masks by it, and the kernels and the chunked path start their
@@ -583,16 +621,18 @@ def attention_with_kv_update(
             scale=scale, soft_cap=soft_cap, layer=layer, window=window,
             tile_seq=batch["tile_seq"])
         return out_t[batch["tok_tile"], batch["tok_slot"]], k_cache, v_cache
+    limits = batch.get("vis_limit")
     if backend in ("pallas", "chunked") and qtok_idx is not None:
         out = ragged_paged_attention_chunked(
             q, k_cache, v_cache, batch["token_seq_ids"], batch["positions"],
             batch["block_tables"], batch["seq_lens"], qtok_idx,
             batch["token_qpos"], block_size=block_size,
-            scale=scale, soft_cap=soft_cap, layer=layer, window=window)
+            scale=scale, soft_cap=soft_cap, layer=layer, window=window,
+            limits=limits)
     else:
         out = ragged_paged_attention_reference(
             q, k_cache, v_cache, batch["token_seq_ids"], batch["positions"],
             batch["block_tables"], batch["seq_lens"],
             block_size=block_size, scale=scale, soft_cap=soft_cap,
-            layer=layer, window=window)
+            layer=layer, window=window, limits=limits)
     return out, k_cache, v_cache

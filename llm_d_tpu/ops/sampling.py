@@ -13,6 +13,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from llm_d_tpu.models.config import DIFFUSION_REMASKING
+
 
 @dataclasses.dataclass(frozen=True)
 class SamplingParams:
@@ -150,6 +152,49 @@ def spec_verify(
     accepted = jnp.cumprod((match & live).astype(jnp.int32),
                            axis=1).sum(axis=1)
     return ids, accepted
+
+
+def reveal(
+    logits: jax.Array,      # [S*B, V] f32, a row's B slots side by side
+    x0: jax.Array,          # [S*B] i32 the token each slot would take
+    masked: jax.Array,      # [S, B] bool slots that hold the mask token
+    quota: jax.Array,       # [S] i32 slots this pass reveals (0: a row that
+                            # does not generate)
+    strategy: str,          # models.config.DIFFUSION_REMASKING
+    threshold: float,
+) -> tuple:                 # (ids [S, B] i32, -1 = not revealed by this
+                            #  pass; logprobs [S, B] of x0)
+    """One denoising pass's reveal rule of a block-diffusion model, on the
+    device: which masked slots take their candidate ``x0`` now.  A slot's
+    confidence is ``softmax(logits)[x0]``.
+
+      sequential              the first ``quota`` masked slots from the left
+      low_confidence_static   the ``quota`` masked slots of highest
+                              confidence (equal confidences: the leftmost)
+      low_confidence_dynamic  every masked slot whose confidence passes
+                              ``threshold`` if they are at least ``quota``,
+                              else as low_confidence_static
+
+    A quota above the row's masked slots reveals them all.  B is a handful,
+    so ranks are counted pairwise: no sort."""
+    if strategy not in DIFFUSION_REMASKING:
+        raise ValueError(f"unknown remasking strategy {strategy!r}")
+    S, B = masked.shape
+    logprobs = compute_logprobs(logits, x0).reshape(S, B)
+    if strategy == "sequential":
+        rank = jnp.cumsum(masked, axis=1, dtype=jnp.int32) - 1   # from left
+    else:
+        conf = jnp.where(masked, jnp.exp(logprobs), -1.0)
+        ahead = (conf[:, None, :] > conf[:, :, None]) | (
+            (conf[:, None, :] == conf[:, :, None])
+            & (jnp.arange(B)[None, None, :] < jnp.arange(B)[None, :, None]))
+        rank = jnp.sum(ahead, axis=2, dtype=jnp.int32)   # slots ranked before
+    now = masked & (rank < quota[:, None])
+    if strategy == "low_confidence_dynamic":
+        sure = masked & (conf > threshold) & (quota[:, None] > 0)
+        now = jnp.where(
+            jnp.sum(sure, axis=1, keepdims=True) >= quota[:, None], sure, now)
+    return jnp.where(now, x0.reshape(S, B), -1).astype(jnp.int32), logprobs
 
 
 def compute_logprobs(logits: jax.Array, token_ids: jax.Array) -> jax.Array:

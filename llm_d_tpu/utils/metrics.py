@@ -64,6 +64,13 @@ SPEC_ACCEPTED_METRIC = "llmd_tpu:spec_accepted_tokens_total"
 # stream.
 STEP_PREFILL_TOKENS_METRIC = "llmd_tpu:step_prefill_tokens_total"
 STEP_DECODE_TOKENS_METRIC = "llmd_tpu:step_decode_tokens_total"
+# Generation by diffusion over blocks: forward passes over a block by kind
+# (``denoise``: nothing of the pass is kept; ``commit``: the block's final
+# keys and values are written) and the tokens those passes revealed.
+# revealed / passes is what a pass yields: 4 / 5 of a token at a block of 4
+# in 4 steps, more where a confident model reveals several slots a pass.
+DIFFUSION_PASSES_METRIC = "llmd_tpu:diffusion_block_passes_total"
+DIFFUSION_REVEALED_METRIC = "llmd_tpu:diffusion_revealed_tokens_total"
 # Composition demotions (round 16, everything-on): every surviving
 # demotion — a per-request fall-off (a do_remote_decode row leaving the
 # fused spec path, a fused-multistep plan bailing to single-round) or a
@@ -254,6 +261,14 @@ class EngineMetrics:
             STEP_DECODE_TOKENS_METRIC,
             "Decode + speculative-verify tokens computed per engine "
             "step.")
+        self._diffusion_passes = Counter(
+            DIFFUSION_PASSES_METRIC,
+            "Block-diffusion forward passes over one block, by kind "
+            "(denoise, commit).",
+            ["model_name", "kind"], registry=self.registry)
+        self.diffusion_revealed_tokens = counter(
+            DIFFUSION_REVEALED_METRIC,
+            "Tokens revealed by block-diffusion denoising passes.")
         # Composition demotions + dispatch amortization (see the
         # FEATURE_DISABLED / ENGINE_DISPATCH constants above).
         self._feature_disabled = Counter(
@@ -305,6 +320,11 @@ class EngineMetrics:
     def inc_deadline_exceeded(self, criticality: str) -> None:
         self._deadline_exceeded.labels(
             model_name=self.model_name, criticality=criticality).inc()
+
+    def add_diffusion_passes(self, kind: str, n: int) -> None:
+        if n:
+            self._diffusion_passes.labels(
+                model_name=self.model_name, kind=kind).inc(n)
 
     def inc_feature_disabled(self, feature: str, blocker: str) -> None:
         self._feature_disabled.labels(

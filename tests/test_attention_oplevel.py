@@ -42,6 +42,9 @@ FAMILIES = {
     "gqa-tp4": dict(H=8, KVH=1, D=128, tol=5.5e-3),
     # kanana-2-30b-a3b: 32 heads over one latent row of 512 + 64, padded
     # to 640 lanes; softmax scale of the unabsorbed 128 + 64 query.
+    # sdar-30b-a3b: the same heads under block-causal visibility, blocks
+    # of 4 aligned on absolute positions (its own phases: BLOCK_PHASES).
+    "gqa-block4": dict(H=32, KVH=4, D=128, block=4, tol=5.5e-3),
     "mla": dict(H=32, R=512, ROPE=64, F=640, tol=7e-3),
     "mla-tp4": dict(H=8, R=512, ROPE=64, F=640, tol=7e-3),
 }
@@ -61,6 +64,19 @@ PHASES = {
     # end on the edge and one key past it.
     "chunk-over-key-block": dict(rows=[(470, 90), (511, 1), (512, 1)],
                                  T=128, S=4, Q=128),
+}
+
+
+# A block-diffusion engine's steps: every row holds whole blocks of 4, none
+# has one query (Q >= 16, the smallest bucket).
+BLOCK_PHASES = {
+    # denoising / commit passes: contexts of a block, one page less a block,
+    # a page, and past the GQA kernel's key block of 512 keys.
+    "denoise": dict(rows=[(0, 4), (28, 4), (32, 4), (88, 4), (508, 4),
+                          (512, 4)], T=32, S=8, Q=16),
+    # a prompt's chunk (whole blocks) beside passes over single blocks
+    "chunk-and-denoise": dict(rows=[(128, 4), (92, 68), (0, 4), (508, 4)],
+                              T=128, S=8, Q=128),
 }
 
 
@@ -89,15 +105,19 @@ def _through_int8(rows):
     return np.clip(np.round(rows / scale), -127, 127) * scale
 
 
-def _exact(q, keys, values, pos, scale, group, window):
+def _exact(q, keys, values, pos, scale, group, window, block=0):
     """softmax(q k^T scale + causal / window mask) v in float32: ``q``
     [n, H, D] at positions ``pos`` over one row's ``keys`` [C, KVH, D] and
-    ``values`` [C, KVH, Dv]; head h reads KV head h // group."""
+    ``values`` [C, KVH, Dv]; head h reads KV head h // group.  ``block``:
+    the block mask in place of the causal one (key j is seen iff
+    j // block <= pos // block)."""
     kh = np.repeat(keys, group, axis=1)
     vh = np.repeat(values, group, axis=1)
     s = np.einsum("nhd,chd->nhc", q, kh) * scale
     j = np.arange(keys.shape[0])[None, :]
     seen = j <= pos[:, None]
+    if block:
+        seen = j // block <= pos[:, None] // block
     if window is not None:
         seen &= j > pos[:, None] - window
     s = np.where(seen[:, None, :], s, -np.inf)
@@ -130,7 +150,7 @@ def _interpreted(monkeypatch):
 
 def _served_against_exact(monkeypatch, family, phase, path, cache="bf16"):
     """rms(served - exact) / rms(exact) over the step's real tokens."""
-    g, ph = FAMILIES[family], PHASES[phase]
+    g, ph = FAMILIES[family], {**PHASES, **BLOCK_PHASES}[phase]
     mla = family.startswith("mla")
     rng = np.random.default_rng(sum(map(ord, family + phase)))
     rows, T, S, Q = ph["rows"], ph["T"], ph["S"], ph["Q"]
@@ -144,7 +164,8 @@ def _served_against_exact(monkeypatch, family, phase, path, cache="bf16"):
     bt = np.zeros((S, pages), np.int32)
     bt[:len(rows)] = (rng.permutation(len(rows) * pages) + 1).reshape(
         len(rows), pages)
-    batch = _batch(rows, T, S, Q, bt)
+    batch = A.with_block_visibility(_batch(rows, T, S, Q, bt),
+                                    g.get("block", 0))
     calls = None
     if path == "pallas":
         calls = _interpreted(monkeypatch)
@@ -167,7 +188,7 @@ def _served_against_exact(monkeypatch, family, phase, path, cache="bf16"):
         pos = np.arange(cached, ctx)
         exact.append(_exact(
             q, kv[0], kv[0][..., :g["R"]] if mla else kv[1], pos, scale,
-            H // KVH, window))
+            H // KVH, window, g.get("block", 0)))
         slots = bt[s, np.arange(cached) // BS] * BS + np.arange(cached) % BS
         for c, new, x in zip(caches, kv_new, kv):
             old = x[:cached].reshape(cached, KVH * D)
@@ -207,7 +228,9 @@ def _served_against_exact(monkeypatch, family, phase, path, cache="bf16"):
 
 
 CASES = [(family, phase, path, "bf16")
-         for family in FAMILIES for phase in PHASES
+         for family in FAMILIES
+         for phase in (BLOCK_PHASES if "block" in FAMILIES[family]
+                       else PHASES)
          for path in ("pallas", "chunked")]
 # The guard that the bound bites: rows that went through int8 exceed it.
 CASES += [(family, "chunk", "chunked", "int8")
@@ -226,6 +249,50 @@ def test_served_attention_against_exact(monkeypatch, family, phase, path,
         assert err < tol, (err, tol)
     else:
         assert err > tol, (err, tol)
+
+
+def test_block_mask_differs_from_the_causal_mask(monkeypatch):
+    """The guard that the block cases bite: the same step held against the
+    CAUSAL exact attention is far outside the bound."""
+    block = FAMILIES["gqa-block4"]
+    monkeypatch.setitem(FAMILIES, "gqa-block4", dict(block, block=0))
+    real = A.with_block_visibility
+    monkeypatch.setattr(A, "with_block_visibility",
+                        lambda batch, _: real(batch, 4))
+    err = _served_against_exact(monkeypatch, "gqa-block4", "denoise",
+                                "chunked")
+    assert err > 10 * block["tol"], err
+
+
+@pytest.mark.parametrize("path", ["pallas", "chunked", "reference"])
+def test_limits_equal_to_positions_change_nothing(monkeypatch, path):
+    """An autoregressive step is bit-equal to what it was: the visibility
+    operand, handed each query's own position, gives the very same numbers
+    as the program without it (which is what block length 0 lowers to)."""
+    seen = []
+
+    def capture(batch, block):
+        seen.append(batch)
+        return batch
+
+    monkeypatch.setattr(A, "with_block_visibility", capture)
+    real = A.attention_with_kv_update
+    taken = []
+
+    def keep(*args, **kw):
+        taken.append((args, kw))
+        return real(*args, **kw)
+
+    monkeypatch.setattr(A, "attention_with_kv_update", keep)
+    _served_against_exact(monkeypatch, "gqa", "mixed", path)
+    (args, kw), = taken
+    batch = args[5]
+    assert "vis_limit" not in seen[0] and "vis_limit" not in batch
+    limited = real(*args[:5], dict(
+        {k: v for k, v in batch.items() if k not in A.QUERY_TILE_KEYS},
+        vis_limit=batch["positions"]), **kw)
+    np.testing.assert_array_equal(np.asarray(real(*args, **kw)[0], np.float32),
+                                  np.asarray(limited[0], np.float32))
 
 
 # ---- the keys the prefill kernels' inner loop covers ----------------------

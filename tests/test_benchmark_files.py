@@ -97,3 +97,111 @@ def test_pinned_order_keeps_the_median_behind_a_chunk():
     fast = sum(t < order_scan.FAST_MS for t in ttft)
     assert fast <= 16 and len(ttft) - fast >= 18
     assert order_scan.nearest_rank(ttft, 50) > 4 * order_scan.FAST_MS
+
+
+# ---- sdar-30b-a3b / sdar.batch (PR 31) ------------------------------------
+
+def test_sdar_cell_and_its_files():
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    cell = next(w for w in bench["workloads"] if w["name"] == "sdar.batch")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "sdar-30b-a3b", "batch", 1)
+    conf = json.loads((BENCH / "configs" / "sdar-30b-a3b.json").read_text())
+    # every number of the catalog row's config, but the depth
+    qwen = json.loads((BENCH / "configs" / "qwen3-30b-a3b.json").read_text())
+    for key in ("hidden_size", "head_dim", "num_attention_heads",
+                "num_key_value_heads", "num_experts", "num_experts_per_tok",
+                "moe_intermediate_size", "vocab_size", "intermediate_size"):
+        assert conf[key] == qwen[key]
+    assert conf["reduced"] == ["num_hidden_layers"]
+    assert conf["model_type"] == "sdar_moe" and conf["reference"] == "sdar_moe"
+    assert conf["serve_args"] == qwen["serve_args"] + [
+        "--precompile-step-shapes"]
+    chk = conf["correctness"]
+    assert {n % 4 for n in chk["prompt_lens"]} == {0, 1, 2, 3}
+    assert max(chk["prompt_lens"]) > 2048 and any(k % 4 for k in chk["ks"])
+    entry = {m["name"]: m for m in bench["per_layer"]}
+    new = ["step_ms.denoise", "denoise_reveal_share", "itl_p95_ms.sdar",
+           "device_idle_share.sdar", "attn_denoise_mxu_share"]
+    assert all(entry[n]["workloads"] == ["sdar.batch"] for n in new)
+    # the accepted lists are as the parent has them
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    assert e2e["out_tok_s"]["workloads"] == ["kanana2.batch"]
+    assert entry["attn_prefill_mxu_share"]["workloads"] == [
+        "trinity-mini.docqa"]
+    sys.path.insert(0, str(BENCH))
+    import modelcfg
+    from llm_d_tpu.models.config import ModelConfig
+    for rehearse, mask in ((False, 151669), (True, 511)):
+        mc = ModelConfig(**modelcfg.model_config_fields(conf, rehearse))
+        assert (mc.diffusion_block_length, mc.diffusion_steps,
+                mc.mask_token_id, mc.diffusion_remasking) == (
+                    4, 4, mask, "sequential")
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_sdar_cell_rehearses_on_the_cpu(trace):
+    """``run.py --rehearse --workload sdar.batch``: the harness's whole
+    path (server, load generator, the checks (a)-(d) against
+    ``references/sdar_moe.py``) at the tiny preset; with ``--trace 1`` the
+    new span attributes feed the new metrics."""
+    import os
+    import subprocess
+    out = subprocess.run(
+        [sys.executable, str(BENCH / "run.py"), "--workload", "sdar.batch",
+         "--seed", str(2**31 + 4242), "--seconds", "4", "--trace",
+         str(trace), "--rehearse"],
+        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode == 0
+    last = json.loads(out.stdout.strip().splitlines()[-1])
+    assert last["correct"] is True and last["failed"] == 0
+    assert last["attempted"] > 0
+    got = {k.removeprefix("cpu_rehearsal.") for k in last["metrics"]}
+    if trace:
+        assert {"step_ms.denoise", "denoise_reveal_share", "itl_p95_ms.sdar",
+                "step_ms.mixed", "attn_query_fill_share"} <= got
+        share = last["metrics"]["cpu_rehearsal.denoise_reveal_share"]["value"]
+        assert 19.0 < share < 26.0      # 4 of 20 slots; cut last blocks
+    else:
+        assert got == {"ttft_p50_ms", "ttft_p95_ms", "setup_s"}
+
+
+def test_sdar_reference_tail_logprobs_agree_with_its_generation_loop():
+    """``tail_logprobs`` (what correctness.py (d) calls, the token ids
+    alone) gives the logprob the reference's own generation loop revealed
+    each token with, under ``sequential``."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    import references.sdar_moe as ref
+    from llm_d_tpu.models import get_model
+    from llm_d_tpu.models.config import get_config
+    c = dataclasses.replace(get_config("tiny-sdar"), dtype="float32")
+    params = get_model(c).init_params(c, jax.random.PRNGKey(3))
+    for n, n_gen in ((9, 7), (8, 6), (14, 5)):
+        prompt = list(range(3, 3 + n))
+        ids, lps = ref.generate(params, c, prompt, n_gen)
+        tokens = jnp.asarray(prompt + ids[:-1], jnp.int32)
+        lp = ref.tail_logprobs(params, c, tokens, n_gen)
+        assert lp.shape == (n_gen, c.vocab_size)
+        assert lp.argmax(-1).tolist() == ids
+        assert jnp.allclose(lp[jnp.arange(n_gen), jnp.asarray(ids)],
+                            jnp.asarray(lps), atol=1e-4)
+    # what the mechanism check gets wrong is wrong here too
+    try:
+        for wrong in ("causal_mask", "stale_pass", "denoise_keys"):
+            ref.WRONG = wrong
+            bad = ref.tail_logprobs(params, c, tokens, n_gen)
+            assert float(jnp.abs(bad - lp).max()) > 1e-2, wrong
+    finally:
+        ref.WRONG = ""
+
+
+def test_kernel_roofline_all_reads_nothing_without_a_trace():
+    from readers import kernel_roofline_all
+    assert kernel_roofline_all.read(
+        {"trace": None}, "flash_prefill_paged", "prefill_flops",
+        "bf16_flops", "sdar-30b-a3b") is None

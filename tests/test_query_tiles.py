@@ -253,6 +253,77 @@ def test_gqa_call_site_walks_the_tile_list(monkeypatch, derived):
         np.asarray(want, np.float32)[:real], atol=3e-2, rtol=3e-2)
 
 
+# A block-diffusion step: every row holds whole blocks of 4 (passes over one
+# block, prompt chunks), contexts and starts on block boundaries.
+BLOCK = 4
+BLOCK_NEWS = [4, 4, 36, 4, 68, 4, 20, 0, 0, 0]
+BLOCK_CTX = [40, 20, 36, 8, 92, 36, 52, 0, 0, 0]
+
+
+@pytest.mark.parametrize("path", ["pallas", "chunked"])
+def test_block_visibility_through_the_call_site(monkeypatch, path):
+    """The served entry point under block-causal visibility at the cells'
+    head geometry (32 heads over 4 KV heads of 128), on the interpreted
+    tile path and the chunked XLA path, against a numpy float32 softmax
+    under the block mask over the same bf16 rows."""
+    rng = np.random.default_rng(7)
+    H, KVH, D = 32, 4, 128
+    F = KVH * D
+    batch = A.with_block_visibility(_batch(BLOCK_NEWS, BLOCK_CTX, T, Q),
+                                    BLOCK)
+    k_cache, bt = _paged(rng, F)
+    v_cache, _ = _paged(rng, F)
+    batch["block_tables"] = bt
+    pos, seq = np.asarray(batch["positions"]), np.asarray(
+        batch["token_seq_ids"])
+    real = sum(BLOCK_NEWS)
+    slot = np.asarray(bt)[seq, pos // BS] * BS + pos % BS
+    slot[real:] = 0
+    batch["slot_mapping"] = jnp.asarray(slot, jnp.int32)
+    q = jnp.asarray(rng.standard_normal((T, H, D)), jnp.bfloat16)
+    k_new = jnp.asarray(rng.standard_normal((T, KVH, D)), jnp.bfloat16)
+    v_new = jnp.asarray(rng.standard_normal((T, KVH, D)), jnp.bfloat16)
+    if path == "pallas":
+        calls = _interpreted(monkeypatch)
+        batch = A.with_query_tiles(batch, H, F, "pallas")
+        # the tile list carries the limits, not the positions
+        lim = np.append((pos // BLOCK + 1) * BLOCK - 1, -1)
+        np.testing.assert_array_equal(
+            batch["tile_pos"], lim[np.asarray(batch["tile_tok"])])
+    got, kc, vc = A.attention_with_kv_update(
+        q, k_new, v_new, k_cache, v_cache, batch, block_size=BS,
+        backend=path, layer=jnp.int32(1))
+    if path == "pallas":
+        assert [c[0] for c in calls] == ["flash_prefill_paged"]
+    got = np.asarray(got, np.float32)
+    kc, vc = (np.asarray(c[1], np.float32) for c in (kc, vc))
+    qf = np.asarray(q, np.float32)
+    t = 0
+    for s, (n, ctx) in enumerate(zip(BLOCK_NEWS, BLOCK_CTX)):
+        if not n:
+            continue
+        at = np.arange(ctx)
+        rows = np.asarray(bt)[s, at // BS] * BS + at % BS
+        k = np.repeat(kc[rows].reshape(ctx, KVH, D), H // KVH, axis=1)
+        v = np.repeat(vc[rows].reshape(ctx, KVH, D), H // KVH, axis=1)
+        p = np.arange(ctx - n, ctx)
+        sc = np.einsum("nhd,chd->nhc", qf[t:t + n], k) * D ** -0.5
+        seen = at[None, :] // BLOCK <= p[:, None] // BLOCK
+        sc = np.where(seen[:, None, :], sc, -np.inf)
+        w = np.exp(sc - sc.max(-1, keepdims=True))
+        want = np.einsum("nhc,chd->nhd", w / w.sum(-1, keepdims=True), v)
+        np.testing.assert_allclose(got[t:t + n], want, atol=3e-2, rtol=3e-2)
+        # ... and it is not the causal answer: a block's first query sees
+        # the three slots after it
+        causal = np.where((at[None, :] <= p[:, None])[:, None, :], sc,
+                          -np.inf)
+        wc = np.exp(causal - causal.max(-1, keepdims=True))
+        assert np.abs(np.einsum(
+            "nhc,chd->nhd", wc / wc.sum(-1, keepdims=True), v)
+            - got[t:t + n]).max() > 0.1
+        t += n
+
+
 def test_tile_list_is_derived_for_pallas_prefill_steps_only():
     batch = _batch(NEWS, CTX, T, Q)
     assert A.with_query_tiles(batch, 8, 128, "reference") is batch
