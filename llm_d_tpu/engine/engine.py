@@ -10,6 +10,27 @@ TPU-first choices:
   - token/sequence dims bucket to powers of two: no data-dependent shapes;
   - KV cache buffers are donated each step (in-place paged updates);
   - sampling happens on device, only sampled ids travel host-ward.
+
+The loop's order (classic step path, ``EngineCore._step``).  An iteration
+launches a step (schedule, build, one copy, one launch: ``_launch``), then
+waits for its tokens and files them (``_retire``: placeholders replaced,
+full blocks hashed, stops checked, outputs emitted).  Where no arrival could
+join the step after it anyway, the iteration first composes and launches
+THAT step behind the one in flight and only then fetches: the next step's
+decode rows take their input token from the previous step's ids on the
+device (``packed_batch.feed_tokens``), so the device never waits for the
+host's part of an iteration, and each iteration still retires exactly one
+step.  The gate (``_may_run_ahead``) is read off the engine's own state,
+not an option: an autoregressive engine on this path, every sequence slot
+taken once the rows the step in flight finishes by length have left, no KV
+pull pending, and blocks in the pool for every running row's next ask.
+Depth one, no deeper: a stop the host cannot foresee (EOS, a stop string,
+an abort, a deadline) is seen one step late and wastes the one row already
+launched; a second step ahead would waste two and delay a replacement
+request by two steps for no gain, since one step already hides all of the
+host behind the device.  ``async_scheduling`` / ``num_scheduler_steps``
+name the multi-step paths' own double buffering (``_inflight``), which this
+does not touch.
 """
 
 from __future__ import annotations
@@ -27,7 +48,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from llm_d_tpu.engine.kv_cache import KVCacheManager
-from llm_d_tpu.engine.packed_batch import BatchLayout
+from llm_d_tpu.engine.packed_batch import BatchLayout, feed_tokens
 from llm_d_tpu.engine.request import Request, RequestOutput, RequestState
 from llm_d_tpu.engine.scheduler import Scheduler, SchedulerOutput
 from llm_d_tpu.engine.step_clock import StepClock
@@ -165,6 +186,27 @@ class EngineConfig:
 
     def resolve_model(self) -> ModelConfig:
         return self.model_config or get_config(self.model)
+
+
+@dataclasses.dataclass
+class _LaunchedStep:
+    """A classic step between its launch and its retire: what the retire
+    needs of the moment of the launch, since the requests' own state may
+    have moved one step on by then."""
+    sched: SchedulerOutput
+    scheduled: List                 # the rows, in the batch's order
+    rows: np.ndarray                # flat sample row of each
+    # Per row (autoregressive): (the step samples its next token, that is
+    # the first one after its prompt, the step completes a KV block).
+    samples: List[Tuple[bool, bool, bool]]
+    fetch: List[jax.Array]          # ids [, logprobs] [, top ids, top lps]
+    want_lp: bool
+    want_top: bool
+    routed: Any                     # EPLB: routed expert ids, or None
+    routed_valid: Optional[np.ndarray]
+    kv: Dict[str, int]              # the step's ``_kv_counts`` and the like
+    t0: float                       # the clock read before the launch
+    ahead: bool                     # launched with its predecessor in flight
 
 
 class EngineCore:
@@ -483,6 +525,17 @@ class EngineCore:
                             f"{config.spec_fixed_accept})"
                             if config.spec_fixed_accept is not None else "")
 
+        # The classic path one step ahead (module docstring).  ``_fed``:
+        # the ids the last classic step sampled, still on the device, as
+        # the next step program's operand (zeros before the first step;
+        # empty where the program takes none: block diffusion, stacked dp).
+        self._fed: Tuple[jax.Array, ...] = (jax.device_put(
+            np.zeros(config.max_num_seqs, np.int32), self._replicated),
+        ) if self.dp == 1 and not self.block_length else ()
+        # The classic step launched and not yet retired, and the instant
+        # the last one's tokens were fetched (where the next span starts).
+        self._ahead: Optional[_LaunchedStep] = None
+        self._fetched_at = 0.0
         self._step_fn = self._build_step_fn(packed=True)
         # Variant computing top-N logprobs, compiled on first use (steps
         # with no logprobs request never pay the extra top_k).
@@ -490,6 +543,14 @@ class EngineCore:
         self._multistep_fn = (
             self._build_multistep_fn(config.num_scheduler_steps)
             if config.num_scheduler_steps > 1 else None)
+        # What of the gate never changes: the autoregressive classic path
+        # alone, one shard, no EPLB (it reads a step's routing before the
+        # next is placed) and no host tier (its flush would wait for the
+        # step in flight).
+        self._runs_ahead = (
+            bool(self._fed) and self._spec_fn is None
+            and self._multistep_fn is None and self.eplb is None
+            and self.host_tier is None)
         if config.precompile_step_shapes:
             self.precompile_step_shapes()
 
@@ -579,7 +640,8 @@ class EngineCore:
                 layout.new_buffer(),
                 self._replicated if self.dp == 1 else self._dp_sharded)
             self.kv_cache = self._step_fn(
-                self.params, self.kv_cache, packed, self._rng, layout)[2]
+                self.params, self.kv_cache, packed, self._rng, *self._fed,
+                layout)[2]
         jax.block_until_ready(self.kv_cache)
         logger.info("precompiled %d step programs in %.1fs", len(shapes),
                     time.monotonic() - t0)
@@ -699,8 +761,15 @@ class EngineCore:
         ``step_fn(params, kv_cache, buffer, rng, layout)`` takes the batch
         as the one int32 buffer of the static ``layout`` (packed_batch.py),
         splits ``rng`` itself and returns the successor key as one more
-        output: one copy and one launch a step.  Otherwise the same body
-        over a dict batch and a ready step key, for tools that lower it."""
+        output: one copy and one launch a step.  Where the engine may run a
+        step ahead (``_fed``: autoregressive, one shard) the program is
+        ``step_fn(params, kv_cache, buffer, rng, prev_ids, layout)``: it
+        takes the ids the previous step sampled (``[max_num_seqs]``, never
+        donated: the host fetches them later), feeds them to the rows that
+        name one (``feed_tokens``) and returns its own in that shape, so a
+        step that runs ahead and one that does not are one program a
+        bucket.  Otherwise the same body over a dict batch and a ready
+        step key, for tools that lower it."""
         c = self.model_config
         block_size = self.config.block_size
         backend = self.config.attn_backend
@@ -784,12 +853,30 @@ class EngineCore:
             platforms=(mesh.devices.flat[0].platform,))(
                 jax.ShapeDtypeStruct((2,), jnp.uint32))
 
-        @functools.partial(jax.jit, static_argnums=(4,), donate_argnums=(1,))
-        def step_fn(params, kv_cache, buffer, rng, layout):
-            # Bit-identical to the host-side ``rng, key = split(rng)``.
+        if not self._fed:
+            @functools.partial(jax.jit, static_argnums=(4,),
+                               donate_argnums=(1,))
+            def step_fn(params, kv_cache, buffer, rng, layout):
+                # Bit-identical to the host-side ``rng, key = split(rng)``.
+                rng, step_key = split.call(rng)
+                return (*step_body(params, kv_cache, layout.unpack(buffer),
+                                   step_key), rng)
+
+            return step_fn
+
+        replicated = self._replicated
+
+        @functools.partial(jax.jit, static_argnums=(5,), donate_argnums=(1,))
+        def step_fn(params, kv_cache, buffer, rng, prev_ids, layout):
             rng, step_key = split.call(rng)
-            return (*step_body(params, kv_cache, layout.unpack(buffer),
-                               step_key), rng)
+            batch = feed_tokens(layout.unpack(buffer), prev_ids)
+            ids, *rest = step_body(params, kv_cache, batch, step_key)
+            # Every bucket returns its ids in the operand's shape and
+            # placement, so any step's ids feed any bucket's program.
+            ids = jax.lax.with_sharding_constraint(
+                jnp.pad(ids, (0, prev_ids.shape[0] - ids.shape[0])),
+                replicated)
+            return (ids, *rest, rng)
 
         return step_fn
 
@@ -2451,7 +2538,7 @@ class EngineCore:
 
     def has_work(self) -> bool:
         if self.scheduler.has_work() or self._rejected \
-                or self._inflight is not None:
+                or self._inflight is not None or self._ahead is not None:
             return True
         return self.kv_connector is not None and self.kv_connector.has_pending()
 
@@ -2498,36 +2585,56 @@ class EngineCore:
                     block_offset: int = 0) -> None:
         """Fill one (shard's) batch arrays from its scheduled requests.
         ``block_offset`` rebases global block ids to shard-local ones
-        (stacked mode; 0 for the classic single-mesh path)."""
+        (stacked mode; 0 for the classic single-mesh path).  Array by
+        array, not row by row (one numpy call a field over all rows, as
+        ``_fill_block_batch``): since the loop runs a step ahead the engine
+        thread computes through most of a step instead of sleeping in the
+        fetch, and what it spends here it takes from the server's thread
+        (PERF.md section 6, PR 33)."""
         bs = self.config.block_size
-        t = 0
-        for s, sr in enumerate(scheduled):
-            req, n = sr.request, sr.num_new_tokens
-            start = req.num_computed_tokens
-            toks = req.all_token_ids[start:start + n]
-            arrs["token_ids"][t:t + n] = toks
-            pos_arr = np.arange(start, start + n)
-            arrs["positions"][t:t + n] = pos_arr
-            arrs["token_seq_ids"][t:t + n] = s
-            blocks = np.asarray(req.block_ids, np.int32) - block_offset
-            arrs["slot_mapping"][t:t + n] = \
-                blocks[pos_arr // bs] * bs + pos_arr % bs
-            arrs["token_qpos"][t:t + n] = np.arange(n)
-            arrs["qtok_idx"][s, :n] = np.arange(t, t + n)
-            nb = len(req.block_ids)
-            arrs["block_tables"][s, :nb] = blocks
-            arrs["seq_lens"][s] = start + n
-            arrs["sample_idx"][s] = t + n - 1
-            sp = req.sampling
-            arrs["temperature"][s] = sp.temperature
-            arrs["top_k"][s] = sp.top_k
-            arrs["top_p"][s] = sp.top_p
-            if sp.seed is not None:
-                # Mask into int32: a 64-bit seed must not OverflowError the
-                # batch array (and kill the engine loop for the whole server).
-                arrs["seeds"][s] = int(sp.seed) & 0x7FFFFFFF
-            arrs["gen_idx"][s] = len(req.output_token_ids)
-            t += n
+        S = len(scheduled)
+        reqs = [sr.request for sr in scheduled]
+        ns_l = [sr.num_new_tokens for sr in scheduled]
+        starts_l = [r.num_computed_tokens for r in reqs]
+        ns, starts = np.asarray(ns_l, np.int64), np.asarray(starts_l, np.int64)
+        firsts = np.cumsum(ns) - ns         # a row's first flat token
+        T = int(ns.sum())
+        tables = arrs["block_tables"]
+        toks: List[int] = []
+        for s, req in enumerate(reqs):
+            n, start = ns_l[s], starts_l[s]
+            if n == 1:      # a decode row: no list of all its tokens
+                toks.append(req.token_at(start))
+            else:
+                toks += req.all_token_ids[start:start + n]
+            tables[s, :len(req.block_ids)] = (
+                [b - block_offset for b in req.block_ids] if block_offset
+                else req.block_ids)
+        if T == S:          # pure decode: one token a row
+            row, qpos, pos = np.arange(S), np.zeros(S, np.int64), starts
+        else:
+            row = np.repeat(np.arange(S), ns)
+            qpos = np.arange(T) - firsts[row]
+            pos = starts[row] + qpos
+        arrs["token_ids"][:T] = toks
+        arrs["positions"][:T] = pos
+        arrs["token_seq_ids"][:T] = row
+        arrs["token_qpos"][:T] = qpos
+        arrs["qtok_idx"][row, qpos] = np.arange(T)
+        arrs["slot_mapping"][:T] = tables[row, pos // bs] * bs + pos % bs
+        arrs["seq_lens"][:S] = starts + ns
+        arrs["sample_idx"][:S] = firsts + ns - 1
+        sps = [r.sampling for r in reqs]
+        arrs["temperature"][:S] = [sp.temperature for sp in sps]
+        arrs["top_k"][:S] = [sp.top_k for sp in sps]
+        arrs["top_p"][:S] = [sp.top_p for sp in sps]
+        # Masked into int32: a 64-bit seed must not OverflowError the batch
+        # array (and kill the engine loop for the whole server).
+        arrs["seeds"][:S] = [-1 if sp.seed is None
+                             else int(sp.seed) & 0x7FFFFFFF for sp in sps]
+        # Tokens generated so far, the one being sampled included.
+        arrs["gen_idx"][:S] = [r.num_tokens - r.num_prompt_tokens
+                               for r in reqs]
 
     def _fill_block_batch(self, arrs: Dict[str, np.ndarray],
                           scheduled) -> None:
@@ -2755,6 +2862,7 @@ class EngineCore:
     def _note_step(self, t0: float, fetched: float, requests: List[Request],
                    prefill_tokens: int, decode_tokens: int, *,
                    fused: bool, rounds: int = 1, kv: Dict[str, int],
+                   run_ahead: int = 0, wasted_rows: int = 0,
                    **spec_attrs) -> None:
         """Describe the ``engine.step`` span of the iteration under way:
         the one place all four step paths do, so they share one extent
@@ -2770,13 +2878,24 @@ class EngineCore:
                   else "prefill" if decode_tokens == 0 else "mixed"),
             n_seqs=len(requests), prefill_tokens=prefill_tokens,
             decode_tokens=decode_tokens, fused=fused, rounds=rounds,
+            run_ahead=run_ahead, wasted_rows=wasted_rows,
             **kv, **spec_attrs))
 
     def step(self) -> List[RequestOutput]:
-        """One iteration of the engine loop.  The phase clock runs over
-        all of it; an iteration that fetched tokens writes its
-        ``engine.step`` span here, as it returns, with the phase times
-        (parented on the first traced request; none traced, no span)."""
+        """One iteration of the engine loop: at most one step retired, and
+        on the classic path at most one launched behind it (module
+        docstring).  The phase clock runs over all of it; an iteration that
+        fetched tokens writes the ``engine.step`` span of the step it
+        retired here, as it returns, with the iteration's phase times
+        (parented on the first traced request; none traced, no span).  The
+        span runs from the step's launch to its tokens fetched, or, for a
+        step launched while its predecessor was on the device
+        (``run_ahead``), from the predecessor's fetch: the time the step
+        held the pace, so consecutive spans never overlap.  The phases are
+        the ITERATION's (``schedule`` / ``build`` / ``dispatch`` of the
+        step launched in it, ``fetch`` / ``post`` of the step retired), so
+        ``dispatch_ms + fetch_ms`` is the span only where nothing was
+        composed ahead."""
         self._clock.enter()
         self._step_note = None
         try:
@@ -2822,6 +2941,62 @@ class EngineCore:
                 outputs.extend(self._ms_retire(rec))
             self._inflight = nxt
             return outputs
+        rec, self._ahead = self._ahead, None
+        if rec is None:
+            sched = self._schedule(outputs)
+            if sched.empty:
+                self._update_queue_metrics()
+                return outputs
+            self._clock.mark("build")
+
+            if self._spec_fn is not None:
+                # Fused mixed round: whatever this pass scheduled — prefill
+                # chunks, plain decodes, draft-verify rows, logprobs rows —
+                # runs as ONE device program.  There is no classic fallback
+                # anymore (and so no draft-allocation rollback): spec decode
+                # stays on under continuous prefill traffic, and a prefill
+                # chunk rides the same per-layer expert-weight stream the
+                # decodes already pay for.  With num_scheduler_steps > 1 the
+                # mixed round becomes the body of an N-round lax.scan — one
+                # dispatch + one host fetch per N rounds, double-buffered
+                # under async scheduling like the classic multistep path.
+                plan = self._fms_plan(sched)
+                if plan is not None:
+                    rec = self._fms_dispatch(plan)
+                    if self.config.async_scheduling:
+                        self._inflight = rec
+                        return outputs   # this dispatch retires next step
+                    outputs.extend(self._fms_retire(rec))
+                    return outputs
+                outputs.extend(self._run_fused(sched))
+                return outputs
+
+            K = self._try_multistep(sched)
+            if K is not None:
+                if self.config.async_scheduling:
+                    meta, ordered, rows = self._ms_meta(sched.scheduled)
+                    self._inflight = self._ms_dispatch(meta, ordered, K, rows)
+                    return outputs    # this block's tokens arrive next step
+                outputs.extend(self._run_multistep(sched, K))
+                return outputs
+
+            rec = self._launch(sched, ahead=False)
+        # ``rec`` is on the device.  Where no arrival could join the step
+        # after it, compose and launch that one now, behind it, and only
+        # then wait for ``rec``'s tokens: the device never waits for the
+        # host's part of an iteration.  Otherwise today's order.
+        if self._may_run_ahead():
+            self._clock.mark("schedule")
+            sched = self._schedule(outputs)
+            if not sched.empty:
+                self._clock.mark("build")
+                self._ahead = self._launch(sched, ahead=True)
+        self._retire(rec, outputs)
+        return outputs
+
+    def _schedule(self, outputs: List[RequestOutput]) -> SchedulerOutput:
+        """One scheduler pass with its bookkeeping: queue waits observed,
+        the requests the scheduler finished itself surfaced."""
         sched = self.scheduler.schedule()
         sched_now = time.monotonic()
         for sr in sched.scheduled:
@@ -2840,87 +3015,138 @@ class EngineCore:
             self._spec_forget(req.request_id)
             outputs.append(RequestOutput(
                 req.request_id, [], True, finish_reason=req.state.value))
-        if sched.empty:
-            self._update_queue_metrics()
-            return outputs
-        self._clock.mark("build")
+        return sched
 
-        if self._spec_fn is not None:
-            # Fused mixed round: whatever this pass scheduled — prefill
-            # chunks, plain decodes, draft-verify rows, logprobs rows —
-            # runs as ONE device program.  There is no classic fallback
-            # anymore (and so no draft-allocation rollback): spec decode
-            # stays on under continuous prefill traffic, and a prefill
-            # chunk rides the same per-layer expert-weight stream the
-            # decodes already pay for.  With num_scheduler_steps > 1 the
-            # mixed round becomes the body of an N-round lax.scan — one
-            # dispatch + one host fetch per N rounds, double-buffered
-            # under async scheduling like the classic multistep path.
-            plan = self._fms_plan(sched)
-            if plan is not None:
-                rec = self._fms_dispatch(plan)
-                if self.config.async_scheduling:
-                    self._inflight = rec
-                    return outputs   # this dispatch retires next step
-                outputs.extend(self._fms_retire(rec))
-                return outputs
-            outputs.extend(self._run_fused(sched))
-            return outputs
+    def _may_run_ahead(self) -> bool:
+        """The gate, from the engine's own state once a step is launched:
+        may the NEXT step be composed before this one's tokens are known?
+        Only where no arrival could have joined it anyway: every sequence
+        slot stays taken (a row the step in flight finishes by length, or
+        hands to a remote decode, leaves a slot free: the step after a
+        finish is composed in today's order and the replacement joins as
+        early as today), no KV pull is pending, and the pool holds what
+        every running row may ask of the next step, so composing ahead can
+        never preempt a row in flight.  A gate that fails costs nothing:
+        the iteration is today's."""
+        sch = self.scheduler
+        if not self._runs_ahead or len(sch.running) < sch.max_num_seqs:
+            return False
+        if self.kv_connector is not None and self.kv_connector.has_pending():
+            return False
+        bs, budget = self.config.block_size, sch.max_num_batched_tokens
+        need = 0
+        for req in sch.running:
+            if req.inflight_token_ids and (
+                    req.do_remote_decode or self._stops_by_length(
+                        req, req.num_tokens - req.num_prompt_tokens)):
+                return False
+            ask = min(max(req.num_tokens - req.num_computed_tokens, 1),
+                      budget)
+            need += max(-(-(req.num_computed_tokens + ask) // bs)
+                        - len(req.block_ids), 0)
+        return need <= self.kv_manager.num_free_blocks
 
-        K = self._try_multistep(sched)
-        if K is not None:
-            if self.config.async_scheduling:
-                meta, ordered, rows = self._ms_meta(sched.scheduled)
-                self._inflight = self._ms_dispatch(meta, ordered, K, rows)
-                return outputs    # this block's tokens arrive next step
-            outputs.extend(self._run_multistep(sched, K))
-            return outputs
-
+    def _launch(self, sched: SchedulerOutput, ahead: bool) -> _LaunchedStep:
+        """Build, copy and launch one classic step, and advance its rows as
+        the scheduler has to see them next: their tokens computed, and for
+        a row the step samples a PLACEHOLDER ``-(row + 1)`` for the token
+        not known yet (``Request.inflight_token_ids``).  The next step's
+        batch takes it as that row's input like any token and the program
+        reads the real id from this step's ids on the device
+        (``feed_tokens``); ``_retire`` files the real id in its place.  (A
+        block-diffusion step moves its rows when it retires: what a pass
+        reveals decides what the next asks.)"""
         packed, layout, scheduled, rows = self._build_batch(sched)
-        step_t0 = self._clock.mark(
+        t0 = self._clock.mark(
             "dispatch", prefill_tokens=sched.prefill_tokens, **self._step_kv)
         # top_logprobs=0 means chosen-token logprob only (no alternatives).
         want_top = any((sr.request.sampling.logprobs or 0) > 0
-                       for sr in sched.scheduled)
+                       for sr in scheduled)
         if want_top and self._step_fn_top is None:
             self._step_fn_top = self._build_step_fn(
                 want_top_logprobs=True, packed=True)
         fn = self._step_fn_top if want_top else self._step_fn
         # ONE launch: the program splits the key and returns its successor.
         ids, logprobs, self.kv_cache, routed, top, self._rng = fn(
-            self.params, self.kv_cache, packed, self._rng, layout)
+            self.params, self.kv_cache, packed, self._rng, *self._fed,
+            layout)
+        if self._fed:
+            self._fed = (ids,)
         self._clock.count("launches")
         self._dispatch_count += 1
         self.metrics.engine_dispatches.inc()
+        if ahead:
+            self.metrics.run_ahead_steps.inc()
         # ONE batched fetch: each device_get is a blocking PCIe transfer
         # that drains the dispatch queue, and chosen-token logprobs are
         # only materialized when some request asked for them.
         want_lp = any(sr.request.sampling.logprobs is not None
-                      for sr in sched.scheduled)
-        fetch = [ids] + ([logprobs] if want_lp else []) \
-            + (list(top) if top is not None else [])
+                      for sr in scheduled)
+        samples: List[Tuple[bool, bool, bool]] = []
+        if not self.block_length:
+            bs = self.config.block_size
+            for i, sr in enumerate(scheduled):
+                req, before = sr.request, sr.request.num_computed_tokens
+                req.num_computed_tokens += sr.num_new_tokens
+                sampled = req.num_computed_tokens == req.num_tokens
+                samples.append((
+                    sampled,
+                    sampled and req.num_computed_tokens
+                    <= req.num_prompt_tokens,
+                    req.num_computed_tokens // bs > before // bs))
+                if sampled:
+                    req.inflight_token_ids.append(-1 - int(rows[i]))
+        return _LaunchedStep(
+            sched, scheduled, rows, samples,
+            [ids] + ([logprobs] if want_lp else [])
+            + (list(top) if top is not None else []),
+            want_lp, top is not None, routed, self._routed_valid,
+            self._step_kv, t0, ahead)
+
+    def _retire(self, rec: _LaunchedStep,
+                outputs: List[RequestOutput]) -> None:
+        """Fetch a launched step's tokens and file them: placeholders
+        replaced, full blocks of confirmed tokens hashed, stops checked,
+        metrics counted, outputs emitted.  A row whose request stopped
+        while this step was in flight (its predecessor's token was an EOS
+        or closed a stop string, it was aborted, its deadline passed) is
+        dropped whole: nothing is emitted after a stop, and its blocks
+        went back when it stopped.  The device runs programs in order, so
+        a block handed on since is written by its new owner after this
+        step's stale write and never read before."""
+        sched, scheduled, rows = rec.sched, rec.scheduled, rec.rows
         self._clock.mark("fetch")
         # llmd: ignore[JIT] the one intended per-step host sync (batched)
-        fetched = jax.device_get(fetch)
+        fetched = jax.device_get(rec.fetch)
         now = self._clock.mark("post")
         ids = np.asarray(fetched[0])
-        logprobs = np.asarray(fetched[1]) if want_lp else None
-        if top is not None:
-            top = (np.asarray(fetched[-2]), np.asarray(fetched[-1]))
+        logprobs = np.asarray(fetched[1]) if rec.want_lp else None
+        top = ((np.asarray(fetched[-2]), np.asarray(fetched[-1]))
+               if rec.want_top else None)
         self._step_count += 1
         self.metrics.engine_steps.inc()
-        self._note_step(step_t0, now, [sr.request for sr in scheduled],
+        # One extent a step: a step that ran ahead held the pace only from
+        # the instant its predecessor's tokens were fetched.
+        t0 = max(rec.t0, self._fetched_at) if rec.ahead else rec.t0
+        self._fetched_at = now
+        wasted = sum(sr.request.state is not RequestState.RUNNING
+                     for sr in scheduled)
+        if wasted:
+            self.metrics.run_ahead_wasted_rows.inc(wasted)
+        self._note_step(t0, now, [sr.request for sr in scheduled],
                         sched.prefill_tokens, sched.decode_tokens,
-                        fused=False, kv=self._step_kv,
+                        fused=False, kv=rec.kv, run_ahead=int(rec.ahead),
+                        wasted_rows=wasted,
                         **(self._block_pass_counts(scheduled, ids)
                            if self.block_length else {}))
         if self.eplb is not None:
             # Record routed logical ids (sampled; padding rows excluded so
             # the zero-embedding's favorite expert doesn't skew the stats)
             # and rebalance the physical placement on the interval.
+            routed = rec.routed
             if routed is not None:
-                if self._routed_valid is not None:   # stacked: ragged pads
-                    routed = routed[:, self._routed_valid, :]
+                if rec.routed_valid is not None:   # stacked: ragged pads
+                    routed = routed[:, rec.routed_valid, :]
                 else:
                     routed = routed[:, :sched.total_tokens, :]
             self.params = self.eplb.on_step(
@@ -2930,16 +3156,27 @@ class EngineCore:
             self._retire_block_rows(scheduled, rows, ids, logprobs, top, now,
                                     outputs)
             scheduled = ()      # nothing is left for the one-token rows below
+        n_tokens, at = 0, len(outputs)
+        first_outputs: List[RequestOutput] = []
         for i, sr in enumerate(scheduled):
-            s = int(rows[i])
             req, n = sr.request, sr.num_new_tokens
-            req.num_computed_tokens += n
+            if req.state is not RequestState.RUNNING:
+                # Stopped while the step was in flight: nothing of the row
+                # is kept, a placeholder least of all.
+                req.inflight_token_ids.clear()
+                continue
+            s = int(rows[i])
             self._account_collective_bytes(n)
-            produced_token = req.num_computed_tokens == req.num_tokens
-            self.kv_manager.cache_full_blocks(req)
-            if not produced_token:
+            sampled, first, block_done = rec.samples[i]
+            if block_done:      # only then is there a new block to hash
+                self.kv_manager.cache_full_blocks(req)
+            if not sampled:
                 continue                  # mid-prefill chunk: no sampling yet
-            if req.num_computed_tokens <= req.num_prompt_tokens:
+            # The oldest placeholder is this step's (the next step's may
+            # wait behind it).
+            req.inflight_token_ids.pop(0)
+            token = int(ids[s])
+            if first:
                 # Prefill just completed.
                 self.metrics.prompt_tokens.inc(req.num_prompt_tokens)
                 if req.num_cached_prompt_tokens:
@@ -2963,8 +3200,9 @@ class EngineCore:
                         resume_offset=req.resume_offset or None,
                         restored_tokens=req.resume_restored_tokens or None)
                 if req.do_remote_decode:
-                    # PD producer: stop here, pin blocks, publish transfer params.
-                    outputs.append(self._finish_remote_prefill(req, int(ids[s])))
+                    # PD producer: stop here, pin blocks, publish transfer
+                    # params (the first token travels in them).
+                    outputs.append(self._finish_remote_prefill(req, token))
                     continue
             else:
                 if req.last_token_time is not None:
@@ -2972,24 +3210,32 @@ class EngineCore:
                         now - req.last_token_time)
             req.last_token_time = now
 
-            token = int(ids[s])
             req.output_token_ids.append(token)
-            self.metrics.generation_tokens.inc()
+            n_tokens += 1
             finish = self._check_stop(req, token)
             top_lp = None
             if (req.sampling.logprobs or 0) > 0 and top is not None:
                 n = min(int(req.sampling.logprobs), top[0].shape[1])
                 top_lp = [{int(top[0][s, j]): float(top[1][s, j])
                            for j in range(n)}]
-            out = RequestOutput(
+            # A first token goes out ahead of the step's other frames: the
+            # server writes a step's frames one after the other.
+            (first_outputs if first else outputs).append(RequestOutput(
                 req.request_id, [token], finish is not None,
                 finish_reason=finish,
                 logprobs=([float(logprobs[s])]
                           if req.sampling.logprobs is not None else None),
-                top_logprobs=top_lp)
-            outputs.append(out)
+                top_logprobs=top_lp))
             if finish is not None:
+                # A row of the step composed behind this one is wasted; its
+                # placeholder goes now, so that ``num_tokens`` is the
+                # request's own count when the server reads its usage.
+                req.inflight_token_ids.clear()
                 self._finish_request(req, finish, now)
+
+        outputs[at:at] = first_outputs
+        if n_tokens:
+            self.metrics.generation_tokens.inc(n_tokens)
 
         # Step composition counters + the step-latency model's sample,
         # all from scheduler metadata and the clock reads already taken
@@ -2999,10 +3245,8 @@ class EngineCore:
         if sched.decode_tokens:
             self.metrics.step_decode_tokens.inc(sched.decode_tokens)
         self.step_time_model.observe(
-            sched.prefill_tokens, sched.decode_tokens,
-            (now - step_t0) * 1e3)
+            sched.prefill_tokens, sched.decode_tokens, (now - t0) * 1e3)
         self._update_queue_metrics()
-        return outputs
 
     def _finish_request(self, req: Request, finish: str, now: float) -> None:
         """A classic step's row stopped (``_check_stop``): out of the
@@ -3143,11 +3387,17 @@ class EngineCore:
             tail = self.tokenizer.decode(window)
             if any(s in tail for s in sp.stop):
                 return RequestState.FINISHED_STOPPED.value
-        if len(req.output_token_ids) >= sp.max_tokens:
-            return RequestState.FINISHED_LENGTH.value
-        if req.num_tokens >= self.model_config.max_model_len:
+        if self._stops_by_length(req, len(req.output_token_ids)):
             return RequestState.FINISHED_LENGTH.value
         return None
+
+    def _stops_by_length(self, req: Request, n_out: int) -> bool:
+        """``_check_stop``'s tests that need no token, at ``n_out`` output
+        tokens: known as soon as the step that samples the last of them is
+        launched (the gate reads it)."""
+        return (n_out >= req.sampling.max_tokens
+                or req.num_prompt_tokens + n_out
+                >= self.model_config.max_model_len)
 
     def _account_collective_bytes(self, n_tokens: int) -> None:
         """Charge ``n_tokens`` computed tokens' EP exchange bytes to

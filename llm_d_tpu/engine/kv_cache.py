@@ -109,9 +109,11 @@ class KVCacheManager:
     # ---------- prefix cache ----------
 
     def request_block_hashes(self, request: Request) -> List[bytes]:
-        """Chain hashes of every full block of the request's tokens."""
+        """Chain hashes of every full block of the request's confirmed
+        tokens: the placeholder of a token still being sampled
+        (``Request.inflight_token_ids``) is never hashed."""
         hashes = self._req_hashes.setdefault(request.request_id, [])
-        tokens = request.all_token_ids
+        tokens = request.prompt_token_ids + request.output_token_ids
         n_full = len(tokens) // self.block_size
         parent = hashes[-1] if hashes else None
         for i in range(len(hashes), n_full):

@@ -18,6 +18,14 @@ every slot of every row's block: ``sample_idx`` grows to ``S * R`` entries
 and two arrays join the buffer, which slots are masked and how many the pass
 reveals a row.  ``R`` = 1 is the autoregressive layout, field for field.
 
+Tokens fed on the device (``feed_tokens``): a ``token_ids`` entry
+``-(row + 1)`` names the token the PREVIOUS step sampled for its row
+``row``, which the host does not hold yet when it fills this step's buffer
+(the engine composes a step while its predecessor runs, engine.py).  The
+autoregressive step program takes the previous step's sampled ids as one
+more operand of a fixed shape, replaces such entries before the embedding
+and returns its own ids in that shape: the buffer stays the one copy.
+
 Stacked (SPMD dp) mode: ``dp > 1`` gives a ``[dp, size]`` buffer, one row a
 shard, sharded over the leading axis; every array comes out ``[dp, ...]``.
 """
@@ -124,3 +132,17 @@ class BatchLayout:
             out[name] = (x if dtype == _I32 else
                          jax.lax.bitcast_convert_type(x, jnp.float32))
         return out
+
+
+def feed_tokens(batch: Dict[str, jax.Array],
+                prev_ids: jax.Array) -> Dict[str, jax.Array]:
+    """Inside the step program: ``batch`` with every ``token_ids`` entry
+    ``-(row + 1)`` replaced by ``prev_ids[row]``, the id the previous step
+    sampled for that row.  A batch that names no row comes back as it is
+    (token ids are never negative)."""
+    tok = batch["token_ids"]
+    # A select over the few rows, not a gather: [T, max_num_seqs] compares
+    # that fuse into one reduction.
+    named = tok[:, None] == -1 - jnp.arange(prev_ids.shape[0], dtype=tok.dtype)
+    fed = jnp.sum(jnp.where(named, prev_ids, 0), axis=-1)
+    return dict(batch, token_ids=jnp.where(tok < 0, fed, tok))

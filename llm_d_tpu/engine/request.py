@@ -123,6 +123,18 @@ class Request:
     revealed_ahead: Dict[int, Any] = dataclasses.field(default_factory=dict)
     denoise_step: int = 0
 
+    # --- the classic step path one step ahead (engine.py) ---
+    # Tokens a launched step is sampling that the host does not hold yet:
+    # one placeholder ``-(row + 1)`` each, naming the row of the step in
+    # flight whose sampled id it stands for (at most two: the step in
+    # flight and the one composed behind it).  They count as the request's
+    # tokens wherever the next step is composed (``num_tokens``,
+    # ``all_token_ids``: the scheduler and the batch see a row that has
+    # its next input), and never enter ``output_token_ids``, which holds
+    # confirmed tokens only: the server's thread reads it, and only
+    # confirmed tokens are hashed into the prefix cache.
+    inflight_token_ids: List[int] = dataclasses.field(default_factory=list)
+
     def reset_block(self) -> None:
         self.revealed_ahead = {}
         self.denoise_step = 0
@@ -144,11 +156,21 @@ class Request:
 
     @property
     def num_tokens(self) -> int:
-        return self.num_prompt_tokens + len(self.output_token_ids)
+        return (self.num_prompt_tokens + len(self.output_token_ids)
+                + len(self.inflight_token_ids))
+
+    def token_at(self, i: int) -> int:
+        """``all_token_ids[i]`` without building the list."""
+        i -= len(self.prompt_token_ids)
+        if i < 0:
+            return self.prompt_token_ids[i]
+        out = self.output_token_ids
+        return out[i] if i < len(out) else self.inflight_token_ids[i - len(out)]
 
     @property
     def all_token_ids(self) -> List[int]:
-        return self.prompt_token_ids + self.output_token_ids
+        return (self.prompt_token_ids + self.output_token_ids
+                + self.inflight_token_ids)
 
 
 @dataclasses.dataclass

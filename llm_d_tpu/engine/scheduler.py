@@ -310,7 +310,7 @@ class Scheduler:
         if B:
             return (r.num_computed_tokens >= r.num_prompt_tokens // B * B
                     and self._wanted_tokens(r)[0] <= B)
-        return (bool(r.output_token_ids)
+        return (r.num_tokens > r.num_prompt_tokens
                 and r.num_tokens - r.num_computed_tokens <= 1
                 and not r.do_remote_decode)
 

@@ -85,6 +85,15 @@ FEATURE_DISABLED_METRIC = "llmd_tpu:engine_feature_disabled_total"
 # dashboard proof that host round-trips per decoded token dropped.
 ENGINE_DISPATCH_METRIC = "llmd_tpu:engine_dispatch_total"
 ENGINE_STEP_METRIC = "llmd_tpu:engine_steps_total"
+# The classic step path one step ahead (engine.py module docstring): steps
+# composed and launched while their predecessor was still on the device,
+# and the rows of such steps whose result was dropped because the
+# predecessor's token stopped the request (EOS, a stop string, an abort,
+# a deadline).  rate(run_ahead)/rate(steps) is the share of steps whose
+# host part the device never waited for; wasted rows cost device work
+# only.
+RUN_AHEAD_STEPS_METRIC = "llmd_tpu:run_ahead_steps_total"
+RUN_AHEAD_WASTED_ROWS_METRIC = "llmd_tpu:run_ahead_wasted_rows_total"
 # Live EPLB (round 17, online expert migration): the window imbalance
 # (max/mean per-expert load; 1.0 = even), completed migrations (atomic
 # table+weight flips), slot-weight bytes staged in the background, and
@@ -284,6 +293,14 @@ class EngineMetrics:
             ENGINE_STEP_METRIC,
             "Engine rounds retired (a fused-multistep dispatch retires "
             "N at once).")
+        self.run_ahead_steps = counter(
+            RUN_AHEAD_STEPS_METRIC,
+            "Classic steps launched before their predecessor's tokens "
+            "were fetched (no sequence slot was free).")
+        self.run_ahead_wasted_rows = counter(
+            RUN_AHEAD_WASTED_ROWS_METRIC,
+            "Rows of a step whose request had stopped by the time the "
+            "step was retired: their result is dropped.")
         # Live EPLB (see the EPLB_* constants above).
         self.eplb_imbalance = gauge(
             EPLB_IMBALANCE_METRIC,

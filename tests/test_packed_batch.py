@@ -88,6 +88,38 @@ def _separate_arrays(T, S, Q, B):
         gen_idx=np.zeros(S, np.int32))
 
 
+def _fill_row_by_row(eng, arrs, scheduled, block_offset=0):
+    """``EngineCore._fill_batch`` as it was before the arrays were filled a
+    field at a time (the parent commit's loop, word for word)."""
+    bs = eng.config.block_size
+    t = 0
+    for s, sr in enumerate(scheduled):
+        req, n = sr.request, sr.num_new_tokens
+        start = req.num_computed_tokens
+        toks = req.all_token_ids[start:start + n]
+        arrs["token_ids"][t:t + n] = toks
+        pos_arr = np.arange(start, start + n)
+        arrs["positions"][t:t + n] = pos_arr
+        arrs["token_seq_ids"][t:t + n] = s
+        blocks = np.asarray(req.block_ids, np.int32) - block_offset
+        arrs["slot_mapping"][t:t + n] = \
+            blocks[pos_arr // bs] * bs + pos_arr % bs
+        arrs["token_qpos"][t:t + n] = np.arange(n)
+        arrs["qtok_idx"][s, :n] = np.arange(t, t + n)
+        nb = len(req.block_ids)
+        arrs["block_tables"][s, :nb] = blocks
+        arrs["seq_lens"][s] = start + n
+        arrs["sample_idx"][s] = t + n - 1
+        sp = req.sampling
+        arrs["temperature"][s] = sp.temperature
+        arrs["top_k"][s] = sp.top_k
+        arrs["top_p"][s] = sp.top_p
+        if sp.seed is not None:
+            arrs["seeds"][s] = int(sp.seed) & 0x7FFFFFFF
+        arrs["gen_idx"][s] = len(req.output_token_ids)
+        t += n
+
+
 def _bits(x):
     x = np.asarray(x)
     return x.view(np.int32) if x.dtype == np.float32 else x
@@ -133,14 +165,15 @@ def test_program_unpacks_what_the_engine_used_to_copy(case, devices):
         shards = []
         for r, shard in enumerate(per):
             arrs = _separate_arrays(T, S, Q, B)
-            eng._fill_batch(arrs, shard,
-                            block_offset=r * eng.kv_manager.blocks_per_region)
+            _fill_row_by_row(
+                eng, arrs, shard,
+                block_offset=r * eng.kv_manager.blocks_per_region)
             shards.append(arrs)
         want = {k: np.stack([a[k] for a in shards]) for k in shards[0]}
         assert packed.sharding.is_equivalent_to(eng._dp_sharded, 2)
     else:
         want = _separate_arrays(T, S, Q, B)
-        eng._fill_batch(want, sched.scheduled)
+        _fill_row_by_row(eng, want, sched.scheduled)
         assert list(rows) == list(range(len(scheduled)))
     # The defaults that are not zero are there to be seen in padded rows.
     n_rows = len(scheduled) if case != "stacked" else max(map(len, per))
@@ -184,10 +217,13 @@ def test_layouts_of_one_length_reach_two_programs(monkeypatch):
     rows = []
     for layout in (a, b, a, b):
         buf = jax.device_put(layout.new_buffer(), eng._replicated)
-        out = fn(eng.params, eng.kv_cache, buf, eng._rng, layout)
-        eng.kv_cache, eng._rng = out[2], out[-1]
-        rows.append(out[0].shape)
-    assert rows == [(8,), (4,), (8,), (4,)]
+        out = fn(eng.params, eng.kv_cache, buf, eng._rng, *eng._fed, layout)
+        eng.kv_cache, eng._rng, eng._fed = out[2], out[-1], (out[0],)
+        rows.append((out[0].shape, out[1].shape))
+    # The ids come back in the fed operand's shape whatever the bucket
+    # (any step's ids feed any bucket's program); the rest by sequence row.
+    M = eng.config.max_num_seqs
+    assert rows == [((M,), (8,)), ((M,), (4,))] * 2
     assert traced == [a, b]           # one program a layout, made once
     with pytest.raises(ValueError, match="packed batch"):
         real(a, np.zeros(199, np.int32))
@@ -226,10 +262,14 @@ def test_key_stream_and_sampled_tokens_are_the_host_splits():
     host = _engine(seed=7)
     body = host._build_step_fn()
 
-    def parent_step(params, kv_cache, packed, rng, layout):
+    def parent_step(params, kv_cache, packed, rng, prev_ids, layout):
         rng, step_key = jax.random.split(rng)
         batch = jax.tree.map(np.asarray, layout.unpack(packed))
-        return (*body(params, kv_cache, batch, step_key), rng)
+        # No slot is full here, so no step runs ahead and no row names a
+        # token of the previous step: the parent's program serves as it is.
+        assert (batch["token_ids"] >= 0).all()
+        ids, *rest = body(params, kv_cache, batch, step_key)
+        return (np.pad(ids, (0, len(prev_ids) - len(ids))), *rest, rng)
 
     host._step_fn = parent_step
     assert _sampled(host) == out
@@ -294,3 +334,87 @@ def test_loop_h2d_copies_metric_reads_the_span():
     eng = _engine()
     eng.generate([_req("a", [1, 2, 3, 4, 5], n=4)])
     assert span_stat.read({"spans": eng.tracer.snapshot()}, **m["args"]) == 1
+
+
+# ---------------------------------------------------------------------------
+# tokens fed on the device (the classic path one step ahead, engine.py)
+# ---------------------------------------------------------------------------
+
+def test_one_program_a_bucket_fed_or_not():
+    """A decode step whose rows name tokens of the previous step's ids
+    (``-(row + 1)``) samples what the same step given the ids does, from
+    the SAME program; padding and real tokens pass through as they are."""
+    from llm_d_tpu.engine.packed_batch import feed_tokens
+    prev = np.asarray([17, 4, 250, 99, 3, 0, 0, 0], np.int32)
+    tok = np.asarray([5, -1, -3, 0, -4, 7], np.int32)
+    got = jax.jit(feed_tokens)({"token_ids": tok, "x": tok}, prev)
+    assert np.asarray(got["token_ids"]).tolist() == [5, 17, 250, 0, 99, 7]
+    assert np.array_equal(got["x"], tok)             # nothing else touched
+
+    def step(named: bool):
+        eng = _engine(seed=3)
+        eng.generate([_req("a", [1, 2, 3, 4, 5], n=3),
+                      _req("b", [9, 8, 7], n=3)])      # a cache worth reading
+        layout = BatchLayout(16, 4, 1, eng.max_blocks_per_seq)
+        buf = layout.new_buffer()
+        v = layout.views(buf)
+        v["token_ids"][:2] = [-2, -1] if named else [4, 17]
+        v["positions"][:2] = [3, 5]
+        v["token_seq_ids"][:2] = [0, 1]
+        v["slot_mapping"][:2] = [4 + 3, 8 + 1]
+        v["block_tables"][0, :1], v["block_tables"][1, :2] = [1], [1, 2]
+        v["seq_lens"][:2] = [4, 6]
+        v["sample_idx"][:2] = [0, 1]
+        v["qtok_idx"][:2, 0] = [0, 1]
+        before = eng._step_fn._cache_size()
+        ids, lps, *_ = eng._step_fn(
+            eng.params, eng.kv_cache, jax.device_put(buf, eng._replicated),
+            eng._rng, jax.device_put(prev, eng._replicated), layout)
+        return (np.asarray(ids), np.asarray(lps),
+                eng._step_fn._cache_size() - before)
+
+    ids_n, lps_n, compiled_n = step(named=True)
+    ids_r, lps_r, compiled_r = step(named=False)
+    assert ids_n.shape == (8,) and lps_n.shape == (4,)
+    assert np.array_equal(ids_n, ids_r) and np.array_equal(lps_n, lps_r)
+    # The bucket (16, 4, 1) was compiled by ``generate``'s own decode steps:
+    # neither call made a program.
+    assert compiled_n == compiled_r == 0
+
+
+def test_block_diffusion_program_is_the_parent_s():
+    """A block-diffusion engine's step (R > 1) takes no fed operand: its
+    served program lowers to the text of the parent commit's construction,
+    written out here (the key's split called from the one exported module,
+    the dict-form body over the unpacked buffer)."""
+    import functools
+
+    import jax.numpy as jnp
+    eng = EngineCore(EngineConfig(
+        model="tiny-sdar", block_size=16, num_blocks=64, max_num_seqs=8,
+        max_num_batched_tokens=64))
+    assert eng.block_length == 4 and eng._fed == () and not eng._runs_ahead
+    body = eng._build_step_fn().__wrapped__
+    split = jax.export.export(
+        jax.jit(jax.random.split),
+        platforms=(eng.mesh.devices.flat[0].platform,))(
+            jax.ShapeDtypeStruct((2,), jnp.uint32))
+
+    @functools.partial(jax.jit, static_argnums=(4,), donate_argnums=(1,))
+    def step_fn(params, kv_cache, buffer, rng, layout):
+        rng, step_key = split.call(rng)
+        return (*body(params, kv_cache, layout.unpack(buffer), step_key),
+                rng)
+
+    for bucket in ((16, 4, 16), (64, 8, 32)):
+        layout = BatchLayout(*bucket, eng.max_blocks_per_seq, R=4)
+        args = (eng.params, eng.kv_cache, layout.new_buffer(), eng._rng,
+                layout)
+        served = eng._step_fn.lower(*args)
+        assert served.as_text() == step_fn.lower(*args).as_text()
+        # ids come back [S, B] as the retire of a block-diffusion step
+        # reads them, not padded to the slots.
+        assert served.out_info[0].shape == (bucket[1], 4)
+    # And the stacked autoregressive program takes none either.
+    stacked = _engine(**STACKED)
+    assert stacked._fed == () and not stacked._runs_ahead

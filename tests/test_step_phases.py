@@ -8,7 +8,10 @@ What this pins:
     multistep) write ``engine.step`` through one helper: one attribute set,
     one extent (the read before the RNG split -> tokens fetched), so
     ``dispatch_ms + fetch_ms`` is the span's duration and batch assembly is
-    in ``build_ms``, never in the duration;
+    in ``build_ms``, never in the duration; a classic step launched while
+    its predecessor was on the device (``run_ahead``) starts where that
+    one's tokens were fetched: still one span a step, extents that do not
+    overlap, and the identity holds for the steps composed in order;
   - the phases are contiguous: they add up to the loop iteration;
   - a wait for lack of work is ``starved_ms``, a wait with work pending
     ``between_ms``;
@@ -428,3 +431,62 @@ def test_idle_under_on_a_trace_recorded_on_the_chip():
     # returns nothing, as it does on a program without them.
     assert idle_under.shares(
         str(BENCH / "testdata" / "v5e_slice.xplane.pb")) is None
+
+
+# ---------------------------------------------------------------------------
+# the classic path one step ahead: still one span a step, one extent each
+# ---------------------------------------------------------------------------
+
+def _full_engine(slots):
+    return EngineCore(EngineConfig(**{**ENGINE_KW, "max_num_seqs": slots}))
+
+
+def _prompt(i, n):
+    return [(37 * i + 11 * j) % 250 + 1 for j in range(n)]
+
+
+def test_one_span_a_step_extents_apart_phases_add_up():
+    eng = _full_engine(4)
+    t_in = time.monotonic()
+    eng.generate([_req(f"r{i}", _prompt(i, 5 + i), n=12)
+                  for i in range(4)])
+    wall = (time.monotonic() - t_in) * 1e3
+    steps = _steps(eng)
+    assert [s["attrs"]["step"] for s in steps] == list(
+        range(1, len(steps) + 1)) and len(steps) == eng.step_count == 12
+    ahead = [s["attrs"]["run_ahead"] for s in steps]
+    assert ahead == [0] + [1] * 11
+    # Extents do not overlap: a step that ran ahead starts where its
+    # predecessor's tokens were fetched.
+    for prev, cur in zip(steps, steps[1:]):
+        assert cur["ts"] >= prev["ts"] + prev["dur"] - 2e-4, (prev, cur)
+    # The phases of the iterations add up to the run, launches included.
+    total = sum(s["attrs"].get(k, 0.0) for s in steps
+                for k in PHASES + GAPS)
+    assert 0.85 * wall <= total <= wall + 1.0, (total, wall)
+    for prev, s in zip(steps, steps[1:]):
+        a = s["attrs"]
+        assert all(a.get(k, 0.0) >= 0 for k in PHASES)
+        assert a["run_ahead"] == 1
+        # dispatch_ms + fetch_ms belong to two steps here, so the identity
+        # of a step composed in order does not hold; the extent is the pace:
+        # from the predecessor's fetch (its post, the hand-over) to this
+        # step's own, through the launch of the step behind it.
+        pace = prev["attrs"]["post_ms"] + sum(
+            a.get(k, 0.0) for k in ("between_ms", "schedule_ms", "build_ms",
+                                    "dispatch_ms", "fetch_ms"))
+        assert abs(s["dur"] * 1e3 - pace) < 0.5, (s, pace)
+    # One copy and one launch an iteration in steady state; the iteration
+    # that enters the pipeline makes two of each, the last one none.
+    copies = [s["attrs"].get("h2d_copies", 0) for s in steps]
+    assert copies == [2] + [1] * 10 + [0]
+    assert [s["attrs"].get("launches", 0) for s in steps] == copies
+
+
+def test_in_order_steps_keep_the_span_identity():
+    eng = _full_engine(8)
+    eng.generate([_req(f"r{i}", _prompt(i, 5 + i), n=6) for i in range(3)])
+    for s in _steps(eng):
+        a = s["attrs"]
+        assert a["run_ahead"] == 0 and a["wasted_rows"] == 0
+        assert abs(a["dispatch_ms"] + a["fetch_ms"] - s["dur"] * 1e3) < 0.5
