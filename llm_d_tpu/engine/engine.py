@@ -266,10 +266,17 @@ class EngineCore:
             raise ValueError(
                 "SPMD dp and sp are mutually exclusive in-engine (ring "
                 "attention shards sequences, dp shards requests)")
+        # A stack with recurrent layers (``ModelConfig.has_recurrent_state``):
+        # every running sequence owns a slot of the state pool beside its
+        # pages, taken and dropped with them (kv_cache.py), and no prefix
+        # hit is granted: cached pages without the state at that boundary
+        # would be wrong (state snapshots: ROADMAP queue B).
         self.kv_manager = KVCacheManager(
             config.num_blocks, config.block_size,
-            enable_prefix_caching=config.enable_prefix_caching,
-            num_regions=self.dp)
+            enable_prefix_caching=(config.enable_prefix_caching
+                                   and not self._has_state),
+            num_regions=self.dp,
+            state_slots=config.max_num_seqs if self._has_state else 0)
         self.scheduler = Scheduler(
             self.kv_manager,
             max_num_seqs=config.max_num_seqs,
@@ -302,6 +309,7 @@ class EngineCore:
         # count on every occurrence but log once.
         self._disabled_seen: set = set()
         self._check_block_diffusion()
+        self._check_recurrent_state()
         # llmd-trace: engine phase spans (queue/prefill/decode + step
         # boundaries).  Everything recorded here is host-side clock
         # arithmetic materialized AFTER the jitted dispatch — tracing can
@@ -425,6 +433,14 @@ class EngineCore:
                 for name, width in layout.items()}
         self._replicated = NamedSharding(self.mesh, P())
         self._dp_sharded = NamedSharding(self.mesh, P("dp"))
+        if self._has_state:
+            # The state pool rides the step programs with the caches, as
+            # more entries of the one donated dict: a slot a sequence
+            # (1..max_num_seqs; slot 0 takes the padded rows' writes).
+            self.kv_cache.update({
+                name: jnp.zeros(sd.shape, sd.dtype, device=self._replicated)
+                for name, sd in self.model.state_pool_shapes(
+                    c, config.max_num_seqs + 1).items()})
 
         self.max_blocks_per_seq = -(-c.max_model_len // config.block_size)
         # Lives on the device: the classic step program splits it itself
@@ -446,7 +462,7 @@ class EngineCore:
         self.scheduler.external_pinned_blocks = lambda: sum(
             len(r.block_ids) for r in self.pinned_transfers.values())
         # Optional KV connector (set by the server / PD wiring).
-        self.kv_connector = None
+        self._kv_connector = None
         # Requests rejected before scheduling (e.g. kv_transfer_params with
         # no connector); surfaced as outputs on the next step.
         self._rejected: List[RequestOutput] = []
@@ -561,6 +577,15 @@ class EngineCore:
         gets candidates for every slot of it back (models/config.py)."""
         return self.model_config.diffusion_block_length
 
+    def _spec_requested(self) -> bool:
+        """Speculative decoding asked for (configuration or environment),
+        whether or not this engine can serve it."""
+        cfg = self.config
+        return ((cfg.spec_decode or env_choice(
+            "LLMD_SPEC_DECODE", "auto", SPEC_DECODE_MODES)) != "off"
+            and (cfg.spec_k if cfg.spec_k is not None
+                 else env_int("LLMD_SPEC_K", 0)) > 0)
+
     def _check_block_diffusion(self) -> None:
         """What a block-diffusion model cannot be served with is refused
         here, at start-up, not silently dropped: only the classic step path
@@ -578,10 +603,7 @@ class EngineCore:
             raise ValueError(
                 f"max_num_batched_tokens {cfg.max_num_batched_tokens} holds "
                 f"no block of {B}")
-        spec_on = ((cfg.spec_decode or env_choice(
-            "LLMD_SPEC_DECODE", "auto", SPEC_DECODE_MODES)) != "off"
-            and (cfg.spec_k if cfg.spec_k is not None
-                 else env_int("LLMD_SPEC_K", 0)) > 0)
+        spec_on = self._spec_requested()
         blocker = self._spec_blockers()[0]
         for feature, asked in (
                 ("multistep", cfg.num_scheduler_steps > 1),
@@ -592,6 +614,56 @@ class EngineCore:
                 raise ValueError(
                     f"{feature} requested but unavailable ({blocker}): "
                     f"refusing to start")
+
+    def _check_recurrent_state(self) -> None:
+        """What a stack with recurrent state beside its paged KV cannot be
+        served with is refused here, at start-up: only the classic step
+        path (run ahead included) carries the state pool, on one shard;
+        the prefix cache is switched off and counted, not refused."""
+        cfg = self.config
+        if not self._has_state:
+            return
+        blocker = ("recurrent_state: a sequence's state is overwritten "
+                   "every token, beside its pages")
+        if cfg.enable_prefix_caching:
+            self._disable_feature("prefix_caching", blocker)
+        spec_on = self._spec_requested()
+        mesh = cfg.mesh
+        for feature, asked in (
+                ("multistep", cfg.num_scheduler_steps > 1),
+                ("spec_decode", spec_on),
+                ("stacked_dp", self.dp > 1),
+                ("tensor_parallel", bool(mesh) and (mesh.tp or 1) > 1),
+                ("sequence_parallel", bool(mesh) and (mesh.sp or 1) > 1),
+                ("kv_offload", cfg.kv_offload_blocks > 0)):
+            if asked:
+                self.metrics.inc_feature_disabled(feature, blocker)
+                raise ValueError(
+                    f"{feature} requested but unavailable ({blocker}): "
+                    f"refusing to start")
+
+    @property
+    def _has_state(self) -> bool:
+        return self.model_config.has_recurrent_state
+
+    @property
+    def kv_connector(self):
+        """The KV connector (set by the server / PD wiring), or None."""
+        return self._kv_connector
+
+    @kv_connector.setter
+    def kv_connector(self, connector) -> None:
+        if connector is not None and self._has_state:
+            # A pull or a PD hand-over moves pages; the recurrent state
+            # would stay behind.
+            self.metrics.inc_feature_disabled(
+                "kv_transfer", "recurrent_state: the state pool is not "
+                "transferred")
+            raise ValueError(
+                "a KV connector moves pages only; this model's sequences "
+                "also own recurrent state, which is not transferred: "
+                "refusing to attach it")
+        self._kv_connector = connector
 
     def step_shapes(self) -> List[Tuple[int, int, int]]:
         """Every (T, S, Q) bucket triple a classic step can have: T tokens
@@ -635,7 +707,8 @@ class EngineCore:
         t0 = time.monotonic()
         for T, S, Q in shapes:
             layout = BatchLayout(T, S, Q, self.max_blocks_per_seq,
-                                 dp=self.dp, R=self.block_length or 1)
+                                 dp=self.dp, R=self.block_length or 1,
+                                 state=self._has_state)
             packed = jax.device_put(
                 layout.new_buffer(),
                 self._replicated if self.dp == 1 else self._dp_sharded)
@@ -2578,7 +2651,8 @@ class EngineCore:
 
     def _empty_batch_np(self, T: int, S: int, Q: int, B: int) -> Dict[str, np.ndarray]:
         """An empty (all padded) batch: views of one fresh packed buffer."""
-        layout = BatchLayout(T, S, Q, B, R=self.block_length or 1)
+        layout = BatchLayout(T, S, Q, B, R=self.block_length or 1,
+                             state=self._has_state)
         return layout.views(layout.new_buffer())
 
     def _fill_batch(self, arrs: Dict[str, np.ndarray], scheduled,
@@ -2635,6 +2709,12 @@ class EngineCore:
         # Tokens generated so far, the one being sampled included.
         arrs["gen_idx"][:S] = [r.num_tokens - r.num_prompt_tokens
                                for r in reqs]
+        if self._has_state:
+            # Each row's slot of the state pool and its chunk's place in
+            # the packed batch (ops/ssm.py).
+            arrs["state_slot"][:S] = [r.state_slot for r in reqs]
+            arrs["query_start"][:S] = firsts
+            arrs["query_len"][:S] = ns
 
     def _fill_block_batch(self, arrs: Dict[str, np.ndarray],
                           scheduled) -> None:
@@ -2722,7 +2802,8 @@ class EngineCore:
                              cfg.max_num_batched_tokens)
             S = _next_bucket(S_real, min(cfg.min_seq_bucket, cfg.max_num_seqs),
                              cfg.max_num_seqs)
-            layout = BatchLayout(T, S, Q, B, R=self.block_length or 1)
+            layout = BatchLayout(T, S, Q, B, R=self.block_length or 1,
+                                 state=self._has_state)
             buf = layout.new_buffer()
             views = layout.views(buf)
             (self._fill_block_batch if self.block_length
@@ -2764,6 +2845,8 @@ class EngineCore:
             buf, self._replicated if self.dp == 1 else self._dp_sharded)
         self._clock.count("h2d_copies")
         self._step_kv = self._kv_counts(ends, news)
+        if self._has_state:
+            self._step_kv.update(self._state_counts(ends, news))
         if Q > 1:
             self._step_kv.update(
                 self._attn_q_counts(int(np.sum(news)), layout))
@@ -2807,6 +2890,19 @@ class EngineCore:
             counts["kv_dead_tokens"] = n_window * int(
                 np.maximum(ends - w + 1, 0).sum())
         return counts
+
+    def _state_counts(self, ends, news) -> Dict[str, int]:
+        """What a dispatch asks of the state pool (step_clock.py): rows
+        that take the one-token update, tokens that go through the chunked
+        scan, rows whose state the program zeroes (a chunk from position
+        0)."""
+        ends, news = np.asarray(ends, np.int64), np.asarray(news, np.int64)
+        resets = int((ends == news).sum())
+        self.metrics.ssm_state_resets.inc(resets)
+        return {"ssm_decode_rows": int((news == 1).sum()),
+                "ssm_prefill_rows": int((news > 1).sum()),
+                "ssm_prefill_tokens": int(news[news > 1].sum()),
+                "ssm_resets": resets}
 
     def _attn_q_counts(self, real: int, layout: BatchLayout) -> Dict[str, int]:
         """What a prefill or mixed dispatch hands prefill attention
@@ -3416,6 +3512,9 @@ class EngineCore:
         self.metrics.num_requests_waiting.set(self.scheduler.num_waiting)
         self.metrics.num_requests_running.set(self.scheduler.num_running)
         self.metrics.kv_cache_usage_perc.set(self.kv_manager.usage)
+        if self._has_state:
+            self.metrics.ssm_state_slots_in_use.set(
+                self.kv_manager.state_slots_in_use)
         if self.kv_manager.eviction_count > self._last_evictions:
             self.metrics.kv_cache_evictions.inc(
                 self.kv_manager.eviction_count - self._last_evictions)

@@ -41,6 +41,7 @@ class KVCacheManager:
         enable_prefix_caching: bool = True,
         hash_seed: str = "42",
         num_regions: int = 1,
+        state_slots: int = 0,
     ) -> None:
         assert num_blocks >= 2 * num_regions
         assert num_blocks % num_regions == 0, \
@@ -75,8 +76,21 @@ class KVCacheManager:
         self.secondary_lookup: Optional[
             Callable[[bytes, frozenset, int], Optional[int]]] = None
         self.eviction_count = 0
+        # Slots of the engine's recurrent-state pool (a stack with a
+        # state-space mixer; 0: none): a request takes one with its first
+        # pages and drops it with them, at finish, abort and preemption
+        # alike, so a slot's life is the life of the request's block list.
+        # Slot 0 is the pool's trash slot.  Nothing is cleaned: the step
+        # program zeroes a row's state where its chunk starts at position
+        # 0, which a preempted request's recompute does too.
+        self.num_state_slots = state_slots
+        self._free_state_slots: List[int] = list(range(state_slots, 0, -1))
 
     # ---------- introspection ----------
+
+    @property
+    def state_slots_in_use(self) -> int:
+        return self.num_state_slots - len(self._free_state_slots)
 
     def region_of_block(self, block_id: int) -> int:
         return block_id // self.blocks_per_region
@@ -259,6 +273,8 @@ class KVCacheManager:
         needed_blocks = -(-num_tokens_after // self.block_size)
         new_needed = needed_blocks - len(request.block_ids)
         if new_needed <= 0:
+            if self.num_state_slots:
+                self._take_state_slot(request)
             return []
         attach: List[int] = []
         if reuse_blocks:
@@ -283,7 +299,15 @@ class KVCacheManager:
             self._ref[b] = 1
             attach.append(b)
         request.block_ids.extend(attach)
+        if self.num_state_slots:
+            self._take_state_slot(request)
         return attach
+
+    def _take_state_slot(self, request: Request) -> None:
+        # (One is always free: the scheduler runs at most as many requests
+        # as the pool has slots.)
+        if request.state_slot == 0:
+            request.state_slot = self._free_state_slots.pop()
 
     def _release(self, b: int) -> None:
         self._ref[b] -= 1
@@ -299,6 +323,9 @@ class KVCacheManager:
         for b in reversed(request.block_ids):
             self._release(b)
         request.block_ids = []
+        if request.state_slot:
+            self._free_state_slots.append(request.state_slot)
+            request.state_slot = 0
         self._req_hashes.pop(request.request_id, None)
         self._region_of_req.pop(request.request_id, None)
 

@@ -17,6 +17,8 @@ A block-diffusion model's step (``R`` > 1: the slots of a block) samples at
 every slot of every row's block: ``sample_idx`` grows to ``S * R`` entries
 and two arrays join the buffer, which slots are masked and how many the pass
 reveals a row.  ``R`` = 1 is the autoregressive layout, field for field.
+A stack with recurrent state beside its pages (``state``) adds three arrays
+a row; without it the layout is the one above, field for field.
 
 Tokens fed on the device (``feed_tokens``): a ``token_ids`` entry
 ``-(row + 1)`` names the token the PREVIOUS step sampled for its row
@@ -44,7 +46,8 @@ import numpy as np
 _I32, _F32 = np.dtype(np.int32), np.dtype(np.float32)
 
 
-def _fields(T: int, S: int, Q: int, B: int, R: int = 1):
+def _fields(T: int, S: int, Q: int, B: int, R: int = 1,
+            state: bool = False):
     """(name, shape, dtype, value of a padded slot) of the step's batch."""
     fields = (
         ("token_ids", (T,), _I32, 0),
@@ -67,15 +70,22 @@ def _fields(T: int, S: int, Q: int, B: int, R: int = 1):
             ("slot_masked", (S, R), _I32, 0),     # 1 = the slot holds the mask
             ("reveal_quota", (S,), _I32, 0),      # slots the pass reveals
         )
+    if state:
+        fields += (
+            ("state_slot", (S,), _I32, 0),        # 0 = the pool's trash slot
+            ("query_start", (S,), _I32, 0),       # the row's first token
+            ("query_len", (S,), _I32, 0),         # tokens of its chunk
+        )
     return fields
 
 
 @functools.lru_cache(maxsize=None)
-def _slots(T: int, S: int, Q: int, B: int, R: int = 1):
+def _slots(T: int, S: int, Q: int, B: int, R: int = 1,
+           state: bool = False):
     """((name, start, stop, shape, dtype), ...), the buffer's length, and
     ((start, stop, int32 bit pattern), ...) of the defaults that are not 0."""
     slots, fills, at = [], [], 0
-    for name, shape, dtype, pad in _fields(T, S, Q, B, R):
+    for name, shape, dtype, pad in _fields(T, S, Q, B, R, state):
         stop = at + math.prod(shape)
         slots.append((name, at, stop, shape, dtype))
         bits = int(np.array(pad, dtype).view(np.int32))
@@ -89,16 +99,20 @@ def _slots(T: int, S: int, Q: int, B: int, R: int = 1):
 class BatchLayout:
     """Bucket of one step program: ``T`` token rows, ``S`` sequence rows,
     ``Q`` query slots a sequence, ``B`` block-table columns, per shard;
-    ``R`` sampled slots a sequence (a block-diffusion model's block)."""
+    ``R`` sampled slots a sequence (a block-diffusion model's block);
+    ``state``: the rows also name their slot of the recurrent-state pool
+    and their chunk's place in the batch (a stack with a state-space
+    mixer, ops/ssm.py)."""
     T: int
     S: int
     Q: int
     B: int
     dp: int = 1
     R: int = 1
+    state: bool = False
 
     def _slots(self):
-        return _slots(self.T, self.S, self.Q, self.B, self.R)
+        return _slots(self.T, self.S, self.Q, self.B, self.R, self.state)
 
     @property
     def shape(self) -> Tuple[int, ...]:

@@ -134,6 +134,9 @@ class Request:
     # confirmed tokens only: the server's thread reads it, and only
     # confirmed tokens are hashed into the prefix cache.
     inflight_token_ids: List[int] = dataclasses.field(default_factory=list)
+    # A stack with recurrent layers: the request's slot of the engine's
+    # state pool, held while it holds pages (``KVCacheManager``); 0 = none.
+    state_slot: int = 0
 
     def reset_block(self) -> None:
         self.revealed_ahead = {}

@@ -55,6 +55,17 @@ attention layers:
                   GQA kernel, one in the MLA kernel); the rest lie before
                   the window, past the causal diagonal or past the context
 
+and, for a stack with recurrent state beside its pages
+(``EngineCore._state_counts``; the classic path, also on its
+``llmd.dispatch`` annotation):
+
+  ssm_decode_rows     rows of one token: the one-token update of the state
+                      pool, in place
+  ssm_prefill_rows    rows of more, and
+  ssm_prefill_tokens  their tokens: the chunked scan
+  ssm_resets          rows whose chunk starts at position 0: the program
+                      zeroes their state (new, or preempted and recomputed)
+
 Phases are contiguous, so they add up to the iteration.  An iteration that
 fetched nothing (an empty schedule, the first dispatch of a pipelined
 block) writes no span; its times stay in the accumulator and ride the next

@@ -3,8 +3,12 @@ from llm_d_tpu.models.config import ModelConfig, PRESETS, get_config
 
 def get_model(config: ModelConfig):
     """Model module for a config: ``models.moe`` for MoE configs
-    (num_experts > 0), ``models.llama`` for dense.  Each module exposes
+    (num_experts > 0), ``models.ssm`` for a dense stack with a state-space
+    mixer beside attention, ``models.llama`` for dense.  Each module exposes
     init_params / forward / compute_logits / sharding_rules / kv_cache_spec."""
+    if config.has_recurrent_state:
+        from llm_d_tpu.models import ssm
+        return ssm
     if config.is_moe:
         from llm_d_tpu.models import moe
         return moe

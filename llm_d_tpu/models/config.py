@@ -90,17 +90,63 @@ class ModelConfig:
     diffusion_steps: int = 0                # 0 = one pass a slot (B)
     diffusion_remasking: str = "sequential"
     diffusion_confidence_threshold: float = 0.9
+    # --- a state-space mixer beside attention in every layer (0 = none) ---
+    # Mamba-2: the layer's normed input goes through attention AND through
+    # a selective state-space mixer, and both outputs join the residual.
+    # A sequence then owns, besides its keys and values in pages, a
+    # recurrent state per layer that every token overwrites in place:
+    # ``ssm_num_heads`` x ``ssm_state_size`` x ``ssm_head_dim`` float32
+    # values and the last ``ssm_conv_kernel - 1`` inputs of a depthwise
+    # causal convolution over ``ssm_inner_size + 2 * groups * state``
+    # channels (engine: the state pool; ops/ssm.py).
+    ssm_state_size: int = 0                 # N
+    ssm_num_heads: int = 0
+    ssm_head_dim: int = 0                   # P
+    ssm_num_groups: int = 1                 # heads of a group share B and C
+    ssm_conv_kernel: int = 4
+    ssm_chunk_size: int = 128               # tokens a piece of the scan
+    ssm_inner_size: int = 0                 # 0: heads x head size
+    # --- muP multipliers, applied where the published code applies them ---
+    # (``embed_scale`` above is the embedding's.)
+    lm_head_multiplier: float = 1.0         # on the logits
+    attention_in_multiplier: float = 1.0    # on the attention block's input
+    attention_out_multiplier: float = 1.0   # on its output
+    key_multiplier: float = 1.0             # on the keys, before the rotary
+    ssm_in_multiplier: float = 1.0          # on the mixer's input
+    ssm_out_multiplier: float = 1.0         # on its output
+    # On the five segments of the mixer's input projection: gate, x, B, C, dt.
+    ssm_multipliers: Tuple[float, ...] = (1.0,) * 5
+    # On the MLP's gate pre-activation and on its output.
+    mlp_multipliers: Tuple[float, ...] = (1.0, 1.0)
 
     @property
     def use_mla(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def has_recurrent_state(self) -> bool:
+        """A sequence's state is not keys and values alone."""
+        return self.ssm_state_size > 0
+
+    @property
+    def ssm_inner_size_(self) -> int:
+        return self.ssm_inner_size or self.ssm_num_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_channels(self) -> int:
+        """x, B and C: what the mixer's causal convolution runs over."""
+        return (self.ssm_inner_size_
+                + 2 * self.ssm_num_groups * self.ssm_state_size)
 
     def __post_init__(self):
         if self.scoring_func not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"scoring_func must be 'softmax' or 'sigmoid', "
                 f"got {self.scoring_func!r}")
-        # A JSON list arrives here: a tuple keeps the config hashable.
+        # A JSON list arrives here: a tuple keeps the config hashable.  And
+        # a JSON integer: a rope_theta of 1e11 does not fit the int32 a
+        # weakly typed Python int becomes inside a traced function.
+        object.__setattr__(self, "rope_theta", float(self.rope_theta))
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         kinds = self.layer_types
         if kinds:
@@ -115,6 +161,33 @@ class ModelConfig:
             raise ValueError(
                 "layer_types, attn_output_gate and sandwich_norm belong to "
                 "the GQA attention block; the MLA path has none of them")
+
+        for name in ("ssm_multipliers", "mlp_multipliers"):
+            object.__setattr__(self, name, tuple(getattr(self, name)))
+        if self.has_recurrent_state:
+            if (self.use_mla or kinds or self.diffusion_block_length
+                    or self.is_moe):
+                raise ValueError(
+                    "a state-space mixer is served beside the GQA attention "
+                    "block of a dense autoregressive stack with every layer "
+                    "full; MLA, layer_types, block diffusion and experts "
+                    "are not")
+            H, G = self.ssm_num_heads, self.ssm_num_groups
+            if (H < 1 or self.ssm_head_dim < 1 or G < 1 or H % G
+                    or self.ssm_inner_size_ != H * self.ssm_head_dim):
+                raise ValueError(
+                    f"ssm_num_heads {H} x ssm_head_dim {self.ssm_head_dim} "
+                    f"must be ssm_inner_size {self.ssm_inner_size_}, in "
+                    f"{G} equal groups")
+            if self.ssm_conv_kernel < 2 or self.ssm_chunk_size < 1:
+                raise ValueError(
+                    "ssm_conv_kernel must be at least 2 and ssm_chunk_size "
+                    "at least 1")
+            if len(self.ssm_multipliers) != 5 \
+                    or len(self.mlp_multipliers) != 2:
+                raise ValueError(
+                    "ssm_multipliers names five segments (gate, x, B, C, "
+                    "dt) and mlp_multipliers two (gate, output)")
 
         B = self.diffusion_block_length
         if B:
@@ -221,6 +294,24 @@ PRESETS = {
         max_model_len=32768, num_experts=128, num_experts_per_tok=8,
         moe_intermediate_size=768, diffusion_block_length=4,
         diffusion_steps=4, mask_token_id=151669),
+    # Falcon-H1: a Mamba-2 mixer in parallel with GQA attention in every
+    # layer, dense, muP multipliers as published (the benchmark's file:
+    # benchmarks/configs/falcon-h1-34b.json, where ``assumed`` lists what
+    # is not a key of the published config).
+    "falcon-h1-34b": ModelConfig(
+        name="falcon-h1-34b", vocab_size=261120, hidden_size=5120,
+        intermediate_size=21504, num_layers=72, num_heads=20, num_kv_heads=4,
+        head_dim=128, rope_theta=1e11, rms_norm_eps=1e-5,
+        max_model_len=32768, ssm_state_size=256, ssm_num_heads=32,
+        ssm_head_dim=128, ssm_num_groups=2, ssm_conv_kernel=4,
+        ssm_chunk_size=128, ssm_inner_size=4096,
+        embed_scale=5.656854249492381, lm_head_multiplier=0.0078125,
+        attention_in_multiplier=1.0, attention_out_multiplier=0.0375,
+        key_multiplier=0.011048543456039804, ssm_in_multiplier=0.25,
+        ssm_out_multiplier=0.08838834764831845,
+        ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                         0.3535533905932738),
+        mlp_multipliers=(0.1767766952966369, 0.011160714285714284)),
     "mixtral-8x22b": ModelConfig(
         name="mixtral-8x22b", vocab_size=32768, hidden_size=6144,
         intermediate_size=16384, num_layers=56, num_heads=48, num_kv_heads=8,
@@ -278,6 +369,22 @@ PRESETS = {
         rope_theta=10000.0, max_model_len=512, qk_norm=True, num_experts=8,
         num_experts_per_tok=2, moe_intermediate_size=96,
         diffusion_block_length=4, diffusion_steps=4, mask_token_id=511),
+    # Tiny hybrid for CPU tests: a Mamba-2 mixer beside GQA attention in
+    # every layer, two groups, a query group of 3 (no power of two), a scan
+    # piece of 8 tokens so short prompts cross several, every multiplier
+    # set and none equal to another.
+    "tiny-ssm": ModelConfig(
+        name="tiny-ssm", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_layers=2, num_heads=6, num_kv_heads=2,
+        head_dim=16, rope_theta=10000.0, max_model_len=512,
+        ssm_state_size=16, ssm_num_heads=4, ssm_head_dim=8,
+        ssm_num_groups=2, ssm_conv_kernel=4, ssm_chunk_size=8,
+        embed_scale=5.5, lm_head_multiplier=0.125,
+        attention_in_multiplier=1.25, attention_out_multiplier=0.0625,
+        key_multiplier=0.25, ssm_in_multiplier=0.5,
+        ssm_out_multiplier=0.09375,
+        ssm_multipliers=(0.375, 0.25, 0.1875, 0.5, 0.3125),
+        mlp_multipliers=(0.1875, 0.03125)),
     # Tiny MLA+MoE config for CPU tests.
     "tiny-mla": ModelConfig(
         name="tiny-mla", vocab_size=512, hidden_size=64,

@@ -101,6 +101,8 @@ def attention_block(
     if c.qk_norm:
         q = L.rms_norm(q, lp["q_norm"], c.rms_norm_eps)
         kx = L.rms_norm(kx, lp["k_norm"], c.rms_norm_eps)
+    if c.key_multiplier != 1.0:
+        kx = (kx * c.key_multiplier).astype(kx.dtype)
 
     cos, sin = L.rope_cos_sin(batch["positions"], dh, c.rope_theta)
     window = None
@@ -143,9 +145,16 @@ def mlp_out(lp: Params, config: ModelConfig, m: jax.Array) -> jax.Array:
 
 
 def dense_mlp(lp: Params, config: ModelConfig, h: jax.Array) -> jax.Array:
-    return mlp_out(lp, config, L.swiglu_mlp(
-        L.rms_norm(h, lp["post_attn_norm"], config.rms_norm_eps),
-        lp["gate_proj"], lp["up_proj"], lp["down_proj"]))
+    x = L.rms_norm(h, lp["post_attn_norm"], config.rms_norm_eps)
+    gate_mult, out_mult = config.mlp_multipliers
+    if (gate_mult, out_mult) == (1.0, 1.0):
+        return mlp_out(lp, config, L.swiglu_mlp(
+            x, lp["gate_proj"], lp["up_proj"], lp["down_proj"]))
+    # muP: the gate's pre-activation and the MLP's output are scaled.
+    gate = (L.linear(x, lp["gate_proj"]) * gate_mult).astype(x.dtype)
+    m = L.linear(jax.nn.silu(gate) * L.linear(x, lp["up_proj"]),
+                 lp["down_proj"])
+    return mlp_out(lp, config, (m * out_mult).astype(m.dtype))
 
 
 def forward(
@@ -220,7 +229,10 @@ def compute_logits(params: Params, hidden: jax.Array, config: ModelConfig) -> ja
     head = params.get("lm_head")
     if head is None:                                  # tied embeddings
         head = params["embed"].T
-    return jnp.dot(hidden, head, preferred_element_type=jnp.float32)
+    logits = jnp.dot(hidden, head, preferred_element_type=jnp.float32)
+    if config.lm_head_multiplier != 1.0:
+        logits = logits * config.lm_head_multiplier
+    return logits
 
 
 def init_draft_params(config: ModelConfig, key: jax.Array) -> Params:

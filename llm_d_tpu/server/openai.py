@@ -967,12 +967,15 @@ class ModelServer:
     async def _generate_stream(self, req: Request, chat: bool,
                                created: int, write_frame) -> None:
         """The token-generation loop of :meth:`_stream_tokens_into`."""
+        # The whole answer's text a frame, as the stop strings need it; a
+        # tokenizer that can decodes only the frame's new tokens.
+        decode = self.tokenizer.stream_decoder()
         all_text_len = 0
         if req.resume_offset:
-            all_text_len = len(self.tokenizer.decode(req.output_token_ids))
+            all_text_len = len(decode(req.output_token_ids))
         first_meta_pending = req.resume_offset > 0
         async for out in self.async_engine.generate(req):
-            text = self.tokenizer.decode(req.output_token_ids)
+            text = decode(req.output_token_ids)
             delta, all_text_len = text[all_text_len:], len(text)
             delta, stopped = self._apply_stop_strings(req, delta, text)
             finished = out.finished or stopped

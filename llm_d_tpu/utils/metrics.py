@@ -94,6 +94,11 @@ ENGINE_STEP_METRIC = "llmd_tpu:engine_steps_total"
 # only.
 RUN_AHEAD_STEPS_METRIC = "llmd_tpu:run_ahead_steps_total"
 RUN_AHEAD_WASTED_ROWS_METRIC = "llmd_tpu:run_ahead_wasted_rows_total"
+# The state pool of a stack with recurrent layers (a state-space mixer
+# beside attention): slots held by running sequences, and rows whose state
+# the step program zeroed (a chunk from position 0: new or recomputed).
+SSM_STATE_SLOTS_METRIC = "llmd_tpu:ssm_state_slots_in_use"
+SSM_STATE_RESETS_METRIC = "llmd_tpu:ssm_state_resets_total"
 # Live EPLB (round 17, online expert migration): the window imbalance
 # (max/mean per-expert load; 1.0 = even), completed migrations (atomic
 # table+weight flips), slot-weight bytes staged in the background, and
@@ -301,6 +306,13 @@ class EngineMetrics:
             RUN_AHEAD_WASTED_ROWS_METRIC,
             "Rows of a step whose request had stopped by the time the "
             "step was retired: their result is dropped.")
+        self.ssm_state_slots_in_use = gauge(
+            SSM_STATE_SLOTS_METRIC,
+            "Slots of the recurrent-state pool held by running sequences.")
+        self.ssm_state_resets = counter(
+            SSM_STATE_RESETS_METRIC,
+            "Rows whose recurrent state a step program zeroed: their "
+            "chunk started at position 0.")
         # Live EPLB (see the EPLB_* constants above).
         self.eplb_imbalance = gauge(
             EPLB_IMBALANCE_METRIC,

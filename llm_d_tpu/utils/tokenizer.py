@@ -7,7 +7,7 @@ EPP"), so this module must be importable without JAX or model weights.
 
 from __future__ import annotations
 
-from typing import List, Optional, Sequence
+from typing import Callable, List, Optional, Sequence
 
 
 class ByteTokenizer:
@@ -31,6 +31,28 @@ class ByteTokenizer:
         data = bytes(i for i in ids if i < 256)
         return data.decode("utf-8", errors="replace")
 
+    def stream_decoder(self) -> Callable[[Sequence[int]], str]:
+        """``decode`` for ONE id list that only grows (a streamed answer,
+        called a frame after a frame with the whole list): the full
+        decode's text, letter for letter."""
+        return _ByteStream()
+
+
+class _ByteStream:
+    """``ByteTokenizer.decode`` of an id list that only grows: each call
+    filters the ids added since the last one and decodes the bytes kept so
+    far, so a frame of a long answer costs its new tokens, not the answer."""
+
+    def __init__(self) -> None:
+        self._data = bytearray()
+        self._seen = 0
+
+    def __call__(self, ids: Sequence[int]) -> str:
+        n = len(ids)            # another thread may append while this runs
+        self._data.extend(i for i in ids[self._seen:n] if i < 256)
+        self._seen = n
+        return self._data.decode("utf-8", errors="replace")
+
 
 class HFTokenizer:
     """Thin wrapper over ``transformers.AutoTokenizer``."""
@@ -49,6 +71,11 @@ class HFTokenizer:
 
     def decode(self, ids: Sequence[int]) -> str:
         return self._tok.decode(ids, skip_special_tokens=True)
+
+    def stream_decoder(self) -> Callable[[Sequence[int]], str]:
+        # A merge-based vocabulary's text is not the sum of its tokens'
+        # texts: the whole list is decoded every time.
+        return self.decode
 
 
 def get_tokenizer(name_or_path: Optional[str]):

@@ -205,3 +205,179 @@ def test_kernel_roofline_all_reads_nothing_without_a_trace():
     assert kernel_roofline_all.read(
         {"trace": None}, "flash_prefill_paged", "prefill_flops",
         "bf16_flops", "sdar-30b-a3b") is None
+
+
+# ---- falcon-h1-34b / falcon-h1.batch (PR 34) -------------------------------
+
+FALCON_METRICS = ["step_ms.decode.falcon", "device_idle_share.falcon",
+                  "itl_p95_ms.falcon", "run_ahead_share.falcon",
+                  "ssm_decode_hbm_share", "ssm_scan_roofline_share"]
+
+
+def _catalog_row(name):
+    path = pathlib.Path("/opt/skills/guides/model-configs/architectures.jsonl")
+    if not path.exists():
+        pytest.skip("no catalog beside the model-configs guide here")
+    return next(r for r in map(json.loads, path.read_text().splitlines())
+                if r.get("name") == name)
+
+
+def test_falcon_cell_and_its_files():
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    cell = bench["workloads"][-1]
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
+        "falcon-h1.batch", "falcon-h1-34b", "batch", 1)
+    assert len(cell["why"]) <= 200
+    entry = bench["configs"][-1]
+    conf = json.loads((REPO / entry["file"]).read_text())
+    assert entry["name"] == conf["name"] == "falcon-h1-34b"
+    assert entry["reduced"] == conf["reduced"] == ["num_hidden_layers"]
+    assert entry["source"] == conf["source"] and len(entry["why"]) <= 200
+    assert conf["reference"] == conf["model_type"] == "falcon_h1"
+    assert "--quantization" not in conf["serve_args"]       # bf16 as published
+    assert conf["num_hidden_layers"] in (5, 6)              # floor 4
+    for key in ("reduced_why", "assumed", "deployment", "memory_account"):
+        assert conf[key], key
+    assert {"state_dtype", "init_scales"} <= set(conf["assumed"])
+    chk = conf["correctness"]
+    assert max(chk["prompt_lens"]) > 2048       # a chunk boundary mid-prompt
+    assert min(chk["prompt_lens"]) < 128        # shorter than a scan piece
+    per_layer = {m["name"]: m for m in bench["per_layer"]}
+    assert [m["name"] for m in bench["per_layer"][-6:]] == FALCON_METRICS
+    for name in FALCON_METRICS:
+        assert per_layer[name]["workloads"] == ["falcon-h1.batch"]
+        d = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())
+        assert (BENCH / "readers" / f"{d['reader']}.py").exists()
+    # the accepted lists are as the parent has them
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    assert e2e["out_tok_s"]["workloads"] == ["kanana2.batch"]
+    assert e2e["itl_p95_ms"]["workloads"] == ["kanana2.batch"]
+    assert per_layer["run_ahead_share"]["workloads"] == ["kanana2.batch"]
+    assert per_layer["step_ms.denoise"]["workloads"] == ["sdar.batch"]
+
+
+def test_falcon_config_holds_every_published_key():
+    row = _catalog_row("Falcon-H1-34B-Instruct")
+    conf = json.loads((BENCH / "configs" / "falcon-h1-34b.json").read_text())
+    assert conf["source"] == row["source_url"]
+    for key, value in row["config"].items():
+        if key not in conf["reduced"]:
+            assert conf[key] == value, key
+    assert row["config"]["num_hidden_layers"] == 72
+
+
+@pytest.mark.parametrize("rehearse", [False, True])
+def test_falcon_config_maps_onto_the_program(rehearse):
+    import modelcfg
+    from llm_d_tpu.models import get_model
+    from llm_d_tpu.models.config import ModelConfig
+    conf = json.loads((BENCH / "configs" / "falcon-h1-34b.json").read_text())
+    mc = ModelConfig(**modelcfg.model_config_fields(conf, rehearse))
+    assert mc.has_recurrent_state and not mc.is_moe
+    assert get_model(mc).__name__.endswith("models.ssm")
+    assert mc.ssm_multipliers == tuple(conf["ssm_multipliers"])
+    assert mc.mlp_multipliers == tuple(conf["mlp_multipliers"])
+    assert (mc.embed_scale, mc.key_multiplier, mc.lm_head_multiplier) == (
+        conf["embedding_multiplier"], conf["key_multiplier"],
+        conf["lm_head_multiplier"])
+    if not rehearse:
+        assert (mc.num_heads, mc.num_kv_heads, mc.head_dim_) == (20, 4, 128)
+        assert (mc.ssm_num_heads, mc.ssm_head_dim, mc.ssm_state_size,
+                mc.ssm_num_groups, mc.ssm_conv_kernel, mc.ssm_chunk_size) == (
+                    32, 128, 256, 2, 4, 128)
+        assert mc.ssm_conv_channels == 5120 and mc.rope_theta == 1e11
+        # the program's own preset is the published model, all 72 layers
+        import dataclasses
+        from llm_d_tpu.models.config import get_config
+        assert dataclasses.replace(get_config("falcon-h1-34b"),
+                                   num_layers=mc.num_layers) == mc
+        from llm_d_tpu.ops.ssm import pallas_ineligible_reason
+        assert not pallas_ineligible_reason(32, 128, 256, 2, 128)
+
+
+def test_ssm_work_counts_real_rows_and_tokens():
+    import ssmwork
+    from readers import ssm_roofline
+    conf = json.loads((BENCH / "configs" / "falcon-h1-34b.json").read_text())
+    L = conf["num_hidden_layers"]
+    assert ssmwork.state_bytes(conf) == 32 * 128 * 256 * 4 == 4 << 20
+    assert ssmwork.conv_tail_bytes(conf) == 3 * 5120 * 2
+    assert ssmwork.decode_state_bytes(conf, 64) == 64 * L * (
+        (8 << 20) + 30720)
+    assert ssmwork.scan_flops(conf, 1000) == 1000 * L * (
+        4 * 32 * 256 * 128 + 2 * 2 * 256 + 2 * 32 * 128)
+    assert ssmwork.scan_bytes(conf, 2, 0) == 2 * L * (4 << 20)
+    peaks = json.loads((BENCH / "peaks.json").read_text())["TPU v5 lite"]
+    counts = {"ssm_decode_rows": 64, "ssm_prefill_rows": 1,
+              "ssm_prefill_tokens": 300}
+    # a 64-row decode step's floor: 3.9 ms at six layers (ISSUE 34's 3.2 GB)
+    assert 3.5e-3 < ssm_roofline.least_seconds(
+        "decode", conf, counts, peaks) * 6 / L < 4.2e-3
+    assert ssm_roofline.least_seconds("scan", conf, counts, peaks) > 0
+    # without a trace a reader reads nothing (the parent, a CPU rehearsal)
+    assert ssm_roofline.read({"trace": None}, "ssm_decode_update", "decode",
+                             "falcon-h1-34b") is None
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_falcon_cell_rehearses_on_the_cpu(trace):
+    """``run.py --rehearse --workload falcon-h1.batch``: the harness's whole
+    path (server, load generator, the checks (a)-(d) against
+    ``references/falcon_h1.py``) at the tiny preset, every slot taken so
+    that steps run ahead; with ``--trace 1`` the new span attributes feed
+    the new metrics."""
+    import os
+    import subprocess
+    out = subprocess.run(
+        [sys.executable, str(BENCH / "run.py"), "--workload",
+         "falcon-h1.batch", "--seed", str(2**31 + 3434), "--seconds", "4",
+         "--trace", str(trace), "--rehearse"],
+        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode == 0
+    last = json.loads(out.stdout.strip().splitlines()[-1])
+    assert last["correct"] is True and last["failed"] == 0
+    assert last["attempted"] > 0
+    got = {k.removeprefix("cpu_rehearsal."): v["value"]
+           for k, v in last["metrics"].items()}
+    if trace:
+        assert {"step_ms.decode.falcon", "itl_p95_ms.falcon",
+                "run_ahead_share.falcon", "step_ms.mixed",
+                "attn_query_fill_share", "prefix_hit_share"} <= set(got)
+        assert got["run_ahead_share.falcon"] > 10.0
+        assert got["prefix_hit_share"] == 0.0       # no hit is granted
+        # device metrics are read from a device trace only
+        assert not {"ssm_decode_hbm_share", "ssm_scan_roofline_share",
+                    "device_idle_share.falcon"} & set(got)
+    else:
+        assert set(got) == {"ttft_p50_ms", "ttft_p95_ms", "setup_s"}
+
+
+def test_ssm_mechanism_check_names_each_fault():
+    import references.falcon_h1 as ref
+    import ssm_mechanism_check
+    faults = {f for _, f in ssm_mechanism_check.WRONG if f}
+    assert faults == {"bf16_state", "zero_conv_tail", "no_mup_vector",
+                      "no_key_multiplier", "swap_groups", "no_softplus",
+                      "int8_weights", "int8_kv"}
+    assert ssm_mechanism_check.WRONG[0] == ("as published", None)
+    # what the chip's readings cannot refuse is held by tests/test_ssm_hybrid.py
+    assert faults - ssm_mechanism_check.MUST_REFUSE == {
+        "bf16_state", "zero_conv_tail", "int8_kv"}
+    tol = json.loads((BENCH / "configs" / "falcon-h1-34b.json").read_text())[
+        "correctness"]["reference_tolerance"]
+    # between the largest served reading and the int8-weights one (PR 34)
+    assert 0.0075 < tol["median"] < 0.0126 and 0.0158 < tol["p90"] < 0.0464
+    assert ref.FAULTS == set()          # nothing wrong in a served comparison
+    # the convolution's fault touches only tokens just behind a boundary
+    import jax.numpy as jnp
+    u = jnp.ones((10, 3))
+    w, b = jnp.ones((3, 4)), jnp.zeros((3,))
+    right = ref.causal_conv(u, w, b)
+    try:
+        ref.FAULTS, ref.FAULT_CHUNK = {"zero_conv_tail"}, 6
+        wrong = ref.causal_conv(u, w, b)
+    finally:
+        ref.FAULTS, ref.FAULT_CHUNK = set(), 2048
+    assert right[:, 0].tolist() == [1, 2, 3, 4, 4, 4, 4, 4, 4, 4]
+    assert wrong[:, 0].tolist() == [1, 2, 3, 4, 4, 4, 1, 2, 3, 4]

@@ -113,6 +113,55 @@ def test_streaming(server_url):
     assert events[-2]["choices"][0]["finish_reason"] == "length"
 
 
+def test_streamed_text_is_the_whole_answers_text(server_url):
+    """The frames' text deltas, which the server decodes a frame's new
+    tokens at a time, add up to the text of the same answer not streamed."""
+    body = {"model": "tiny", "prompt": "stream me", "max_tokens": 24,
+            "temperature": 0.0, "ignore_eos": True}
+    whole = requests.post(server_url + "/v1/completions", json=body).json()
+    r = requests.post(server_url + "/v1/completions",
+                      json=dict(body, stream=True), stream=True)
+    frames = [json.loads(line[len(b"data: "):]) for line in r.iter_lines()
+              if line.startswith(b"data: ") and b"[DONE]" not in line]
+    assert sum(len(f["llmd"]["tok"]) for f in frames) == 24
+    assert "".join(f["choices"][0]["text"] for f in frames) \
+        == whole["choices"][0]["text"]
+
+
+@pytest.mark.parametrize("kind", ["bytes", "multibyte", "specials", "whole"])
+def test_stream_decoder_is_the_full_decode(kind):
+    """``stream_decoder`` over a list that grows, a call a frame: the full
+    decode's text every time, also where a multi-byte character is split
+    between frames, where ids past the bytes are dropped, where several
+    tokens arrive at once, and for the tokenizer that decodes the whole list."""
+    import random
+
+    from llm_d_tpu.utils.tokenizer import ByteTokenizer, HFTokenizer
+    tok = ByteTokenizer()
+    rng = random.Random(7)
+    if kind == "multibyte":
+        ids = list("añ€𝄞 z".encode("utf-8")) * 5
+    elif kind == "specials":
+        ids = [rng.choice([65, 66, 256, 257, 258, 70000]) for _ in range(60)]
+    else:
+        ids = [rng.randrange(256) for _ in range(80)]
+    if kind == "whole":
+        class Inner:
+            def decode(self, ids, skip_special_tokens):
+                return tok.decode(ids)
+        hf = HFTokenizer.__new__(HFTokenizer)
+        hf._tok = Inner()
+        decode = hf.stream_decoder()
+    else:
+        decode = tok.stream_decoder()
+    seen, at = [], 0
+    while at < len(ids):
+        at += rng.choice([1, 1, 1, 2, 5])
+        seen = ids[:at]
+        assert decode(seen) == tok.decode(seen)
+    assert decode(seen) == tok.decode(ids)      # a frame with nothing new
+
+
 def test_chat_completion(server_url):
     r = requests.post(server_url + "/v1/chat/completions", json={
         "model": "tiny",

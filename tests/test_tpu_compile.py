@@ -119,6 +119,32 @@ def _mla_prefill(H, F=640, S=8, Q=256, B=64, L=16):
                 _sds((S,), jnp.int32), _sds((), jnp.int32)]
 
 
+def _ssm_update(H=32, P=128, N=256, G=2, S=64, L=6):
+    """The state-space mixer's one-token update, in place on the state pool
+    of ``S`` slots and the trash slot (falcon-h1-34b's geometry)."""
+    from llm_d_tpu.ops.pallas.ssm_update import ssm_decode_update as fn
+    fn.hlo_name = "ssm_decode_update"
+    return fn, [_sds((S, H, P), jnp.float32), _sds((S, H), jnp.float32),
+                _sds((S, G, N), jnp.bfloat16), _sds((S, G, N), jnp.bfloat16),
+                _sds((L, S + 1, H, N, P), jnp.float32), _sds((), jnp.int32),
+                _sds((S,), jnp.int32), _sds((S,), jnp.bool_)]
+
+
+def _ssm_scan(T, S, H=32, P=128, N=256, G=2, L=6, c=128, slots=65):
+    """The chunked scan over the pieces of a step of ``T`` tokens in ``S``
+    rows (ceil(T / c) + S of them)."""
+    from llm_d_tpu.ops.pallas.ssm_scan import ssm_chunk_scan as fn
+    fn.hlo_name = "ssm_chunk_scan"
+    NT = -(-T // c) + S
+    return fn, [_sds((NT, c, H, P), jnp.bfloat16),
+                _sds((NT, c, G, N), jnp.bfloat16),
+                _sds((NT, c, G, N), jnp.bfloat16),
+                _sds((NT, c, H), jnp.float32),
+                _sds((L, slots, H, N, P), jnp.float32), _sds((), jnp.int32),
+                _sds((NT,), jnp.int32), _sds((NT,), jnp.bool_),
+                _sds((NT,), jnp.bool_), _sds((NT,), jnp.bool_)]
+
+
 def _prefill_tiles(H, KVH, D, T, S, Q, B=64, L=16, window=False,
                    mla=False):
     """Either prefill kernel over a step's query TILE LIST, as the step
@@ -292,6 +318,23 @@ CASES = [
     pytest.param(functools.partial(_prefill_tiles, 32, 8, 64, T=64, S=8,
                                    Q=8, B=256),
                  id="flash_prefill-tiles-llama3-1b-Q8"),
+    # falcon-h1-34b: the state-space mixer's two kernels over the state
+    # pool (32 heads x 256 x 128 float32 a slot and layer), and the GQA
+    # kernels at a query group of 5 (20 heads over 4 KV heads: 60 fused
+    # rows a group in a prefill tile, no multiple of 8).
+    pytest.param(_ssm_update, id="ssm_decode_update-falcon-h1-S64"),
+    pytest.param(functools.partial(_ssm_scan, T=2048, S=64),
+                 id="ssm_chunk_scan-falcon-h1-T2048-S64"),
+    pytest.param(functools.partial(_ssm_scan, T=16, S=8),
+                 id="ssm_chunk_scan-falcon-h1-T16-S8"),
+    pytest.param(functools.partial(_dense_decode, 20, 4, 128, L=6),
+                 id="dense_decode-falcon-h1-20x4x128"),
+    pytest.param(functools.partial(_prefill_tiles, 20, 4, 128, T=2048, S=64,
+                                   Q=512, B=1024, L=6),
+                 id="flash_prefill-tiles-falcon-h1-T2048-S64"),
+    pytest.param(functools.partial(_prefill_tiles, 20, 4, 128, T=64, S=8,
+                                   Q=16, B=1024, L=6),
+                 id="flash_prefill-tiles-falcon-h1-Q16"),
 ]
 
 
