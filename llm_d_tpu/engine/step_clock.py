@@ -51,8 +51,8 @@ attention layers:
   attn_k_real     keys from the first one the tile's first query sees (under
                   the layer's window) to its last query's own
   attn_k_slots    keys the kernel's inner loop covers for the tile: the key
-                  blocks it walks x the keys a block (several pages in the
-                  GQA kernel, one in the MLA kernel); the rest lie before
+                  blocks it walks x the keys a block (several pages,
+                  ``ops.attention.prefill_key_block``); the rest lie before
                   the window, past the causal diagonal or past the context
 
 and, for a stack with recurrent state beside its pages

@@ -325,11 +325,11 @@ def prefill_key_block(q_tile: int, H: int, F: int, D: int, block_size: int,
                       mla: bool = False) -> int:
     """Keys one step of a Pallas prefill kernel's inner loop covers for
     tiles of ``q_tile`` slots, with ``H`` heads of size ``D`` over cache
-    rows ``F`` wide as ONE shard sees them: a block of several pages in the
-    GQA kernel (``ops.pallas.flash_prefill.pick_key_block``), one page in
-    the MLA kernel."""
+    rows ``F`` wide: a block of several pages, by the rows of a dot (the GQA
+    kernel's ``pick_key_block``, the MLA kernel's ``_pick_key_block``)."""
     if mla:
-        return block_size
+        from llm_d_tpu.ops.pallas.mla_prefill import _pick_key_block
+        return _pick_key_block(block_size, F, q_tile * H)
     from llm_d_tpu.ops.pallas.flash_prefill import dot_rows, pick_key_block
     return pick_key_block(block_size, F, dot_rows(q_tile, H, F // D, D))
 

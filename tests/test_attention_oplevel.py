@@ -14,7 +14,7 @@ Open question 14).
 
 The bound is one number a family, set from what bf16 rows, bf16 MXU
 operands and a bf16 result cost against float32 (at most 0.0039 over the
-GQA cases, 0.0048 over the MLA ones, whose row is 576 wide) with about
+GQA cases, 0.0049 over the MLA ones, whose row is 576 wide) with about
 half as much again for head-room.  Three cases prove it bites: the same
 check over a cache whose rows went through int8 and back (0.0074 to
 0.0115) must exceed it.
@@ -64,6 +64,12 @@ PHASES = {
     # end on the edge and one key past it.
     "chunk-over-key-block": dict(rows=[(470, 90), (511, 1), (512, 1)],
                                  T=128, S=4, Q=128),
+    # A mixed step around an edge of the MLA prefill kernel's key block (256
+    # keys): decode rows that end one key short of it, on it and past it
+    # beside a prompt's chunk whose causal diagonal crosses it.
+    "mixed-over-mla-key-block": dict(
+        rows=[(254, 1), (255, 1), (256, 1), (200, 100), (30, 1)],
+        T=128, S=8, Q=128),
 }
 
 
@@ -301,7 +307,8 @@ def _walk_by_hand(ends, news, qt, kb, bs, windows):
     """The kernels' walk, a tile and a layer at a time: ``attn_k_real``
     counts the keys from the first one the tile's first query sees to its
     last query's own, ``attn_k_slots`` the blocks walked times their keys
-    (the loop bounds of ``ops.pallas.flash_prefill._prefill_kernel``)."""
+    (the loop bounds of ``ops.pallas.flash_prefill._prefill_kernel``, and of
+    ``ops.pallas.mla_prefill._mla_prefill_kernel`` with no window)."""
     real = slots = 0
     for end, n in zip(ends, news):
         for lo in range(end - n, end, qt):
@@ -326,8 +333,12 @@ def _walk_by_hand(ends, news, qt, kb, bs, windows):
     ("tiny-swa-moe", (32, 512), 16, 2048, 32, 512),
     # every layer full
     ("tiny", (32, 512), 32, 1024, 16, 512),
-    # the MLA kernel walks a page at a time
-    ("tiny-mla", (32, 640), 32, 512, 8, 32),
+    # the MLA kernel: 256 keys a block at kanana-2-30b-a3b's 4 x 32 fused
+    # rows, at a 2,048-token chunk's 16 slots and at a tp-4 shard's 8 heads
+    ("tiny-mla", (32, 640), 32, 512, 4, 256),
+    ("tiny-mla", (32, 640), 32, 2048, 16, 256),
+    ("tiny-mla", (8, 640), 32, 512, 16, 256),
+    ("tiny-mla", (32, 640), 16, 512, 4, 256),
 ])
 def test_engine_counts_the_keys_its_prefill_walks(model, dims, bs, Q, qt,
                                                    kb, seed):

@@ -243,7 +243,9 @@ def test_falcon_cell_and_its_files():
     assert max(chk["prompt_lens"]) > 2048       # a chunk boundary mid-prompt
     assert min(chk["prompt_lens"]) < 128        # shorter than a scan piece
     per_layer = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"][-6:]] == FALCON_METRICS
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(FALCON_METRICS[0])     # appended together, in order
+    assert names[at:at + len(FALCON_METRICS)] == FALCON_METRICS
     for name in FALCON_METRICS:
         assert per_layer[name]["workloads"] == ["falcon-h1.batch"]
         d = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())
@@ -381,3 +383,23 @@ def test_ssm_mechanism_check_names_each_fault():
         ref.FAULTS, ref.FAULT_CHUNK = set(), 2048
     assert right[:, 0].tolist() == [1, 2, 3, 4, 4, 4, 4, 4, 4, 4]
     assert wrong[:, 0].tolist() == [1, 2, 3, 4, 4, 4, 1, 2, 3, 4]
+
+
+def test_mla_key_fill_share_is_a_data_file():
+    """PR 35's one per-layer metric: an entry appended to BENCHMARK.json and
+    a file for the reader that is there, no reader code."""
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    entry = bench["per_layer"][-1]
+    assert entry == {
+        "name": "attn_key_fill_share.mla", "unit": "%", "better": "higher",
+        "source": "program_span", "layer": "attention kernels",
+        "moves": "itl_p95_ms", "workloads": ["kanana2.batch"]}
+    d = json.loads(
+        (BENCH / "layer_metrics" / "attn_key_fill_share.mla.json").read_text())
+    assert all(d[k] == entry[k] for k in (
+        "name", "unit", "better", "source", "layer", "moves"))
+    assert d["reader"] == "span_ratio" and d["args"] == {
+        "span": "engine.step", "numerator": "attn_k_real",
+        "denominator": ["attn_k_slots"]}
+    moved = next(m for m in bench["end_to_end"] if m["name"] == entry["moves"])
+    assert set(entry["workloads"]) <= set(moved["workloads"])

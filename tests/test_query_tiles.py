@@ -170,15 +170,19 @@ def test_tile_list_equals_rectangle(family, q_tile):
 
 
 @pytest.mark.parametrize("Q,H,F,mla,want", [
-    (512, 32, 640, True, 8),      # kanana-2-30b-a3b: 256 fused rows a tile
+    (512, 32, 640, True, 4),      # kanana-2-30b-a3b: 128 fused rows a tile
+    (256, 32, 640, True, 4),      # ... at every bucket a prompt of the
+    (1024, 32, 640, True, 8),     #     batch mix lands in; 128 tiles a row
     (2048, 32, 640, True, 16),    # ... a long chunk: its VMEM bound
-    (512, 8, 640, True, 32),      # ... one of four tp shards: 256 rows too
+    (512, 8, 640, True, 16),      # ... one of four tp shards: 128 rows too
+    (128, 128, 640, True, 1),     # DeepSeek's 128 heads: one slot is a pass
+    (2048, 128, 640, True, 4),    # ... their VMEM bound
     (2048, 32, 512, False, 32),   # qwen3-30b-a3b / trinity-mini: 64 tiles
     (1024, 32, 512, False, 16),   #     a row
     (128, 32, 512, False, 8),
     (1024, 8, 128, False, 32),    # llama3-1b, one of four tp shards
     (4, 32, 512, False, 4),       # --spec-k 3: k + 1 slots a row
-    (5, 32, 640, True, 5),        # ... a bucket that is no power of two
+    (3, 32, 640, True, 3),        # ... a bucket that is no power of two
     (512, 512, 640, True, 1),     # more heads than rows: one slot
 ])
 def test_tile_height_follows_the_query_bucket_under_the_vmem_bound(
@@ -376,9 +380,9 @@ def test_mla_forward_derives_the_list_once_a_step(monkeypatch):
 # ---- the counters on ``engine.step`` -------------------------------------
 
 @pytest.mark.parametrize("model,dims,bucket,dp,slots", [
-    # kanana2.batch's mixed step on the chip: (64 + 64) tiles of 8 slots
+    # kanana2.batch's mixed step on the chip: (128 + 64) tiles of 4 slots
     # where the rectangle had 64 x 512.
-    ("tiny-mla", (32, 640), (512, 64, 512), 1, 128 * 8),
+    ("tiny-mla", (32, 640), (512, 64, 512), 1, 192 * 4),
     # trinity-mini.docqa's chunk: (64 + 8) tiles of 32.
     ("tiny", (32, 512), (2048, 8, 2048), 1, 72 * 32),
     # Two dp shards, each its own list.

@@ -274,11 +274,11 @@ CASES = [
                                    Lm=6),
                  id="grouped_moe_int8-T2048-I1024-E128"),
     # The query tile list at the shapes the benchmark's cells serve (token
-    # bucket / sequence bucket): kanana-2-30b-a3b's MLA latent row (192
-    # tiles of 8 slots; 192 of 16 for a 2,048-token chunk), trinity-mini's
+    # bucket / sequence bucket): kanana-2-30b-a3b's MLA latent row (320
+    # tiles of 4 slots; 192 of 16 for a 2,048-token chunk), trinity-mini's
     # windowed layers at 32768 context (72 tiles of 32), qwen3-30b-a3b at a
     # full sequence bucket (128 of 32), and a tp shard's 8 heads (TP = 4:
-    # 96 tiles of 32).
+    # 128 tiles of 16).
     pytest.param(functools.partial(_prefill_tiles, 32, 1, 640, T=1024, S=64,
                                    Q=512, L=9, mla=True),
                  id="mla_prefill-tiles-kanana-T1024-S64"),
