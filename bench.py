@@ -3,8 +3,7 @@
 Two models run through the full engine (continuous batching, paged KV,
 on-device sampling, fused async decode) on ONE TPU chip.  Without a TPU the
 run fails (``_require_tpu``): a CPU timing is not a measurement of this
-system.  One process per chip: ``--attribution`` spawns children, so its
-parent stays off the JAX backend.
+system.  One process per chip.
 
   - ``deepseek-v3-bench`` — the north-star proxy: DeepSeek-V3's serving
     structure (MLA latent cache, sigmoid group-limited routing, shared
@@ -158,15 +157,14 @@ def _make_reqs(tag, n, prompt_len, decode_steps, offset):
 
 
 def bench_model(model: str, batch_sizes, prompt_len=128, decode_steps=128,
-                quantization=None, repeats=None, stub=()):
+                quantization=None, repeats=None):
     """One engine, a workload per batch size (warmup + timed).  Returns
     {bs: {prefill_tok_s, decode_tok_s, ...}} plus roofline attribution.
 
     ``repeats`` maps batch size -> N timed runs (default 1): gated
     headline numbers use median-of-N with a printed min/max band so the
     regression gate can tell a real drop from the chip's measured ±4-6%
-    run-to-run variance (VERDICT r5 #4).  ``stub`` drops components from
-    the compiled program for the attribution harness (--stub)."""
+    run-to-run variance (VERDICT r5 #4)."""
     max_bs = max(batch_sizes)
     # KV sized to the workload + slack: expert weights take most of the
     # chip's 16 GB, so a fixed large pool OOMs the MoE run.
@@ -186,7 +184,6 @@ def bench_model(model: str, batch_sizes, prompt_len=128, decode_steps=128,
         # removes any chance the warmup pass warms more than the compiles.
         enable_prefix_caching=False,
         quantization=quantization,
-        stub_components=tuple(stub),
     )
     engine = EngineCore(cfg)
     c = engine.model_config
@@ -244,8 +241,8 @@ def bench_model(model: str, batch_sizes, prompt_len=128, decode_steps=128,
                 100 * decode_tok_s / roofline_tok_s, 1),
             "decode_ms_per_step": round(1000 * t_decode / decode_steps, 2),
             # Per-ENGINE-step prefill cost (chunked prefill: a step is
-            # one max_num_batched_tokens-bounded forward) — the unit the
-            # attribution table differences, matching decode_ms_per_step.
+            # one max_num_batched_tokens-bounded forward), matching
+            # decode_ms_per_step.
             "prefill_ms_per_step": round(
                 1000 * t_prefill / max(n_prefill_steps, 1), 2),
             "prefill_steps": n_prefill_steps,
@@ -978,78 +975,6 @@ def _wire_delta(measured_roofline_frac: float) -> dict:
     }
 
 
-# Components the attribution sweep stubs one at a time ("none" is the
-# unstubbed baseline the differences are taken against).
-STUB_COMPONENTS = ("attn", "moe_ffn", "shared_expert")
-
-
-def _attribution_table(baseline_sweep: dict, stub_sweeps: dict) -> dict:
-    """Per-component decode/prefill ms/step by difference.
-
-    ``component cost = baseline ms/step − stubbed ms/step`` per phase and
-    batch size (the r5/r6 methodology, now computed by the harness
-    instead of by hand); ``residual_ms`` is what no stub accounts for
-    (embed/norms/router/glue/sampling).  Sweeps are keyed by batch size
-    as STRINGS (JSON round-trip safe — subprocess outputs arrive
-    parsed)."""
-    metrics = (("decode_ms_per_step", "decode"),
-               ("prefill_ms_per_step", "prefill"))
-    components = {}
-    for stub, sweep in stub_sweeps.items():
-        row = {}
-        for bs, base_row in baseline_sweep.items():
-            if not isinstance(base_row, dict) or bs not in sweep:
-                continue
-            for key, phase in metrics:
-                if key in base_row and key in sweep[bs]:
-                    row[f"{phase}_bs{bs}_ms"] = round(
-                        base_row[key] - sweep[bs][key], 2)
-        components[stub] = row
-    residual = {}
-    for bs, base_row in baseline_sweep.items():
-        if not isinstance(base_row, dict):
-            continue
-        for key, phase in metrics:
-            if key not in base_row:
-                continue
-            cell = f"{phase}_bs{bs}_ms"
-            attributed = sum(c.get(cell, 0.0) for c in components.values())
-            residual[cell] = round(base_row[key] - attributed, 2)
-    return {"components": components, "residual_ms": residual}
-
-
-def _run_attribution() -> dict:
-    """Run the full stub sweep, each run in a FRESH subprocess (a stub
-    changes the compiled program; sharing a process would mix compile
-    caches and XLA live buffers across variants), and emit the completed
-    per-component table."""
-    import subprocess
-    import sys
-
-    def run_one(stub: str) -> dict:
-        cmd = [sys.executable, __file__, "--stub", stub]
-        proc = subprocess.run(cmd, capture_output=True, text=True)
-        if proc.returncode != 0:
-            raise RuntimeError(
-                f"attribution run --stub {stub} failed "
-                f"(rc={proc.returncode}): {proc.stderr[-2000:]}")
-        for line in reversed(proc.stdout.strip().splitlines()):
-            try:
-                return json.loads(line)["extras"]["moe_sweep"]
-            except (json.JSONDecodeError, KeyError, TypeError):
-                continue   # TypeError: a line holding non-dict JSON
-        raise RuntimeError(
-            f"attribution run --stub {stub} printed no result JSON")
-
-    baseline = run_one("none")
-    stub_sweeps = {s: run_one(s) for s in STUB_COMPONENTS}
-    return {
-        "baseline_sweep": baseline,
-        "stub_sweeps": stub_sweeps,
-        "attribution": _attribution_table(baseline, stub_sweeps),
-    }
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
@@ -1057,46 +982,9 @@ def main() -> None:
     ap.add_argument("--gate-repeats", type=int, default=5,
                     help="median-of-N runs for the gated headline "
                          "numbers (>=5 for the band to mean anything)")
-    ap.add_argument("--stub",
-                    choices=["none", *STUB_COMPONENTS],
-                    help="attribution mode: run ONLY the MoE model with "
-                         "this component stubbed out of the compiled "
-                         "program ('none' = unstubbed baseline at the "
-                         "same sizes; compare ms/step against it) — "
-                         "covers prefill AND decode")
-    ap.add_argument("--attribution", action="store_true",
-                    help="run the FULL stub sweep (none + each "
-                         "component), one fresh subprocess per run, and "
-                         "print the completed per-component decode/"
-                         "prefill ms/step table as one JSON line")
     args = ap.parse_args()
 
-    if args.attribution:
-        # Children each need the chip: this parent must not hold it.
-        from jax._src import xla_bridge
-        if xla_bridge.backends_are_initialized():
-            raise RuntimeError("--attribution parent touched the JAX "
-                               "backend before spawning its children")
-        print(json.dumps({
-            "metric": "attribution",
-            "unit": "ms/step",
-            "extras": _run_attribution(),
-        }))
-        return
-
     _require_tpu()
-    if args.stub:
-        sizes = [64, 256]
-        stub = () if args.stub == "none" else (args.stub,)
-        moe = bench_model("deepseek-v3-bench", sizes, quantization="int8",
-                          stub=stub)
-        print(json.dumps({
-            "metric": "attribution_stub",
-            "stub": args.stub,
-            "unit": "ms/step",
-            "extras": {"moe_sweep": {str(b): moe[b] for b in sizes}},
-        }))
-        return
 
     moe_sizes = [256] if args.quick else [64, 256, 512]
     dense_sizes = [64] if args.quick else [64, 128, 256]

@@ -56,6 +56,7 @@ from llm_d_tpu.models import get_model
 from llm_d_tpu.models.config import (
     NO_WINDOW, SLIDING, ModelConfig, get_config)
 from llm_d_tpu.ops import sampling as sampling_ops
+from llm_d_tpu.ops.parts import part
 from llm_d_tpu.parallel.mesh import MeshConfig, make_mesh
 from llm_d_tpu.parallel.sharding import logical_to_sharding, shard_pytree
 from llm_d_tpu.utils import tracing
@@ -145,12 +146,6 @@ class EngineConfig:
     # Auto-size the block pool from an HBM budget instead of num_blocks,
     # see derive_num_blocks.
     kv_cache_hbm_bytes: Optional[int] = None
-    # Perf-attribution harness (docs/perf-notes methodology): components
-    # to STUB OUT of the step program so their cost can be measured by
-    # difference, in a fresh process, on BOTH phases (prefill + decode —
-    # the r5 harness covered decode only).  Values: "attn", "moe_ffn",
-    # "shared_expert".  Changes model output — bench/diagnostics only.
-    stub_components: Tuple[str, ...] = ()
     # Speculative decoding (MTP draft-and-verify): "auto" runs the fused
     # draft+verify program on pure-decode rounds whenever spec_k > 0;
     # "off" is a kill switch that restores today's engine byte for byte.
@@ -163,7 +158,7 @@ class EngineConfig:
     # rolls rejected KV back the same step; output stays byte-identical
     # to non-spec decode for greedy and seeded sampling.
     spec_k: Optional[int] = None
-    # Bench/diagnostics only (like stub_components): replace draft
+    # Bench/diagnostics only: replace draft
     # verification with a SEEDED per-draft acceptance coin at this rate,
     # so accepted-tok/s is measurable at a controlled acceptance whatever
     # the drafter's real hit rate on random-init weights.  Changes model
@@ -199,9 +194,8 @@ class _LaunchedStep:
     # Per row (autoregressive): (the step samples its next token, that is
     # the first one after its prompt, the step completes a KV block).
     samples: List[Tuple[bool, bool, bool]]
-    fetch: List[jax.Array]          # ids [, logprobs] [, top ids, top lps]
-    want_lp: bool
-    want_top: bool
+    # ids [, logprobs] [, top (ids, lps)] [, touched]: the ONE fetch
+    fetch: Dict[str, Any]
     routed: Any                     # EPLB: routed expert ids, or None
     routed_valid: Optional[np.ndarray]
     kv: Dict[str, int]              # the step's ``_kv_counts`` and the like
@@ -819,14 +813,10 @@ class EngineCore:
         if not self.model_config.is_moe:
             return None
         if not self.config.enable_dbo:
-            opts = dict(dbo_decode_min_tokens=-1, dbo_prefill_min_tokens=-1)
-        else:
-            opts = dict(
-                dbo_decode_min_tokens=self.config.dbo_decode_token_threshold,
-                dbo_prefill_min_tokens=self.config.dbo_prefill_token_threshold)
-        if self.config.stub_components:
-            opts["stub_components"] = tuple(self.config.stub_components)
-        return opts
+            return dict(dbo_decode_min_tokens=-1, dbo_prefill_min_tokens=-1)
+        return dict(
+            dbo_decode_min_tokens=self.config.dbo_decode_token_threshold,
+            dbo_prefill_min_tokens=self.config.dbo_prefill_token_threshold)
 
     def _build_step_fn(self, want_top_logprobs: bool = False,
                        packed: bool = False):
@@ -849,42 +839,49 @@ class EngineCore:
         model, mesh = self.model, self.mesh
         moe_opts = self._moe_opts()
 
-        collect_routed = self.eplb is not None
+        # What the forward returns beside the hidden rows and the cache:
+        # EPLB's routed ids, and on one device the experts the step touches
+        # (an EP mesh leaves the count out).
+        collect = {}
+        if self.eplb is not None:
+            collect["collect_routed"] = True
+        if c.is_moe and mesh.devices.size == 1:
+            collect["count_touched"] = True
 
         def step_body(params, kv_cache, batch, rng):
-            if collect_routed:
-                hidden, kv_cache, routed = model.forward(
-                    params, kv_cache, batch, c, block_size, backend,
-                    mesh=mesh, collect_routed=True, moe_opts=moe_opts)
-            else:
-                hidden, kv_cache = model.forward(
-                    params, kv_cache, batch, c, block_size, backend,
-                    mesh=mesh, moe_opts=moe_opts)
-                routed = None
+            hidden, kv_cache, *extra = model.forward(
+                params, kv_cache, batch, c, block_size, backend,
+                mesh=mesh, moe_opts=moe_opts, **collect)
+            extra = dict(zip(collect, extra))
+            routed = extra.get("collect_routed")
+            touched = extra.get("count_touched")
             logits = model.compute_logits(params, hidden, c)
-            if c.diffusion_block_length:
-                ids, logprobs, top = reveal_body(logits, batch, rng)
-                return ids, logprobs, kv_cache, routed, top
-            if logits.ndim == 3:
-                # Stacked (SPMD dp): flatten [dp, S_l, V] -> [dp*S_l, V] so
-                # sampling is row-wise; the merged dim stays dp-sharded and
-                # the host indexes outputs by flat row (shard * S_l + s).
-                logits = logits.reshape(-1, logits.shape[-1])
-                batch = dict(batch, **{
-                    k: batch[k].reshape(-1)
-                    for k in ("temperature", "top_k", "top_p",
-                              "seeds", "gen_idx")})
-            ids = sampling_ops.sample(
-                logits, batch["temperature"], batch["top_k"], batch["top_p"],
-                rng, seeds=batch["seeds"], gen_idx=batch["gen_idx"])
-            if want_top_logprobs:
-                logprobs, top_ids, top_lps = \
-                    sampling_ops.compute_top_logprobs(logits, ids)
-                top = (top_ids, top_lps)
-            else:
-                logprobs = sampling_ops.compute_logprobs(logits, ids)
-                top = None
-            return ids, logprobs, kv_cache, routed, top
+            with part("sample"):
+                if c.diffusion_block_length:
+                    ids, logprobs, top = reveal_body(logits, batch, rng)
+                    return ids, logprobs, kv_cache, routed, touched, top
+                if logits.ndim == 3:
+                    # Stacked (SPMD dp): flatten [dp, S_l, V] -> [dp*S_l, V]
+                    # so sampling is row-wise; the merged dim stays
+                    # dp-sharded and the host indexes outputs by flat row
+                    # (shard * S_l + s).
+                    logits = logits.reshape(-1, logits.shape[-1])
+                    batch = dict(batch, **{
+                        k: batch[k].reshape(-1)
+                        for k in ("temperature", "top_k", "top_p",
+                                  "seeds", "gen_idx")})
+                ids = sampling_ops.sample(
+                    logits, batch["temperature"], batch["top_k"],
+                    batch["top_p"], rng, seeds=batch["seeds"],
+                    gen_idx=batch["gen_idx"])
+                if want_top_logprobs:
+                    logprobs, top_ids, top_lps = \
+                        sampling_ops.compute_top_logprobs(logits, ids)
+                    top = (top_ids, top_lps)
+                else:
+                    logprobs = sampling_ops.compute_logprobs(logits, ids)
+                    top = None
+            return ids, logprobs, kv_cache, routed, touched, top
 
         def reveal_body(logits, batch, rng):
             """A block-diffusion step's epilogue: ``logits`` [S * B, V] of
@@ -931,9 +928,11 @@ class EngineCore:
                                donate_argnums=(1,))
             def step_fn(params, kv_cache, buffer, rng, layout):
                 # Bit-identical to the host-side ``rng, key = split(rng)``.
-                rng, step_key = split.call(rng)
-                return (*step_body(params, kv_cache, layout.unpack(buffer),
-                                   step_key), rng)
+                with part("sample"):
+                    rng, step_key = split.call(rng)
+                with part("tiles"):
+                    batch = layout.unpack(buffer)
+                return (*step_body(params, kv_cache, batch, step_key), rng)
 
             return step_fn
 
@@ -941,14 +940,19 @@ class EngineCore:
 
         @functools.partial(jax.jit, static_argnums=(5,), donate_argnums=(1,))
         def step_fn(params, kv_cache, buffer, rng, prev_ids, layout):
-            rng, step_key = split.call(rng)
-            batch = feed_tokens(layout.unpack(buffer), prev_ids)
+            with part("sample"):
+                rng, step_key = split.call(rng)
+            with part("tiles"):
+                batch = layout.unpack(buffer)
+            with part("embed"):
+                batch = feed_tokens(batch, prev_ids)
             ids, *rest = step_body(params, kv_cache, batch, step_key)
             # Every bucket returns its ids in the operand's shape and
             # placement, so any step's ids feed any bucket's program.
-            ids = jax.lax.with_sharding_constraint(
-                jnp.pad(ids, (0, prev_ids.shape[0] - ids.shape[0])),
-                replicated)
+            with part("sample"):
+                ids = jax.lax.with_sharding_constraint(
+                    jnp.pad(ids, (0, prev_ids.shape[0] - ids.shape[0])),
+                    replicated)
             return (ids, *rest, rng)
 
         return step_fn
@@ -2904,6 +2908,18 @@ class EngineCore:
                 "ssm_prefill_tokens": int(news[news > 1].sum()),
                 "ssm_resets": resets}
 
+    def _moe_counts(self, tokens: int, touched) -> Dict[str, int]:
+        """What a retired step asked of the routed experts (step_clock.py):
+        ``touched`` is the program's own count, fetched with the step's
+        ids; nothing where the program has none."""
+        if touched is None:
+            return {}
+        c = self.model_config
+        moe_layers = c.num_layers - c.first_dense_layers
+        return {"moe_experts_touched": int(touched),
+                "moe_experts_held": moe_layers * c.num_experts,
+                "moe_pairs": tokens * c.num_experts_per_tok * moe_layers}
+
     def _attn_q_counts(self, real: int, layout: BatchLayout) -> Dict[str, int]:
         """What a prefill or mixed dispatch hands prefill attention
         (step_clock.py): ``real`` query tokens in the slots its grid holds,
@@ -3154,7 +3170,8 @@ class EngineCore:
         reveals decides what the next asks.)"""
         packed, layout, scheduled, rows = self._build_batch(sched)
         t0 = self._clock.mark(
-            "dispatch", prefill_tokens=sched.prefill_tokens, **self._step_kv)
+            "dispatch", prefill_tokens=sched.prefill_tokens,
+            sample_rows=layout.dp * layout.S * layout.R, **self._step_kv)
         # top_logprobs=0 means chosen-token logprob only (no alternatives).
         want_top = any((sr.request.sampling.logprobs or 0) > 0
                        for sr in scheduled)
@@ -3163,7 +3180,7 @@ class EngineCore:
                 want_top_logprobs=True, packed=True)
         fn = self._step_fn_top if want_top else self._step_fn
         # ONE launch: the program splits the key and returns its successor.
-        ids, logprobs, self.kv_cache, routed, top, self._rng = fn(
+        ids, logprobs, self.kv_cache, routed, touched, top, self._rng = fn(
             self.params, self.kv_cache, packed, self._rng, *self._fed,
             layout)
         if self._fed:
@@ -3176,8 +3193,13 @@ class EngineCore:
         # ONE batched fetch: each device_get is a blocking PCIe transfer
         # that drains the dispatch queue, and chosen-token logprobs are
         # only materialized when some request asked for them.
-        want_lp = any(sr.request.sampling.logprobs is not None
-                      for sr in scheduled)
+        fetch = {"ids": ids}
+        if any(sr.request.sampling.logprobs is not None for sr in scheduled):
+            fetch["logprobs"] = logprobs
+        if top is not None:
+            fetch["top"] = top
+        if touched is not None:
+            fetch["touched"] = touched
         samples: List[Tuple[bool, bool, bool]] = []
         if not self.block_length:
             bs = self.config.block_size
@@ -3193,11 +3215,8 @@ class EngineCore:
                 if sampled:
                     req.inflight_token_ids.append(-1 - int(rows[i]))
         return _LaunchedStep(
-            sched, scheduled, rows, samples,
-            [ids] + ([logprobs] if want_lp else [])
-            + (list(top) if top is not None else []),
-            want_lp, top is not None, routed, self._routed_valid,
-            self._step_kv, t0, ahead)
+            sched, scheduled, rows, samples, fetch, routed,
+            self._routed_valid, self._step_kv, t0, ahead)
 
     def _retire(self, rec: _LaunchedStep,
                 outputs: List[RequestOutput]) -> None:
@@ -3214,11 +3233,13 @@ class EngineCore:
         self._clock.mark("fetch")
         # llmd: ignore[JIT] the one intended per-step host sync (batched)
         fetched = jax.device_get(rec.fetch)
-        now = self._clock.mark("post")
-        ids = np.asarray(fetched[0])
-        logprobs = np.asarray(fetched[1]) if rec.want_lp else None
-        top = ((np.asarray(fetched[-2]), np.asarray(fetched[-1]))
-               if rec.want_top else None)
+        moe = self._moe_counts(sched.total_tokens, fetched.get("touched"))
+        now = self._clock.mark("post", **moe)
+        ids = np.asarray(fetched["ids"])
+        logprobs = (np.asarray(fetched["logprobs"])
+                    if "logprobs" in fetched else None)
+        top = (tuple(np.asarray(a) for a in fetched["top"])
+               if "top" in fetched else None)
         self._step_count += 1
         self.metrics.engine_steps.inc()
         # One extent a step: a step that ran ahead held the pace only from
@@ -3231,8 +3252,8 @@ class EngineCore:
             self.metrics.run_ahead_wasted_rows.inc(wasted)
         self._note_step(t0, now, [sr.request for sr in scheduled],
                         sched.prefill_tokens, sched.decode_tokens,
-                        fused=False, kv=rec.kv, run_ahead=int(rec.ahead),
-                        wasted_rows=wasted,
+                        fused=False, kv={**rec.kv, **moe},
+                        run_ahead=int(rec.ahead), wasted_rows=wasted,
                         **(self._block_pass_counts(scheduled, ids)
                            if self.block_length else {}))
         if self.eplb is not None:

@@ -66,6 +66,28 @@ and, for a stack with recurrent state beside its pages
   ssm_resets          rows whose chunk starts at position 0: the program
                       zeroes their state (new, or preempted and recomputed)
 
+and, counted by the step program itself and fetched with the step's ids
+(the classic path of an MoE stack on one device; ``EngineCore._moe_counts``;
+also on the ``llmd.post`` annotation of the iteration that retired the step,
+so the count lies on the trace's clock beside the kernels):
+
+  moe_experts_touched  sum over the MoE layers of the DISTINCT routed
+                       experts the step's real token rows select (padded
+                       rows of the token bucket masked out; logical ids)
+  moe_experts_held     MoE layers x routed experts: what a stream of every
+                       expert reads
+  moe_pairs            real token rows x experts a token x MoE layers
+
+and, on the classic path's ``llmd.dispatch`` annotation only, beside
+``prefill_tokens``:
+
+  sample_rows     rows x slots the output head and the sampler run on (the
+                  sequence bucket, padded rows included)
+
+The DEVICE's side of a step is named by part, not by phase: every operation
+of a step program lies in one ``llmd.<part>`` scope (ops/parts.py has the
+vocabulary), which the profiler's trace carries beside these annotations.
+
 Phases are contiguous, so they add up to the iteration.  An iteration that
 fetched nothing (an empty schedule, the first dispatch of a pipelined
 block) writes no span; its times stay in the accumulator and ride the next

@@ -22,6 +22,7 @@ from llm_d_tpu.models.config import ModelConfig
 from llm_d_tpu.ops import layers as L
 from llm_d_tpu.ops.attention import (
     attention_with_kv_update, with_block_visibility, with_query_tiles)
+from llm_d_tpu.ops.parts import attn_part, part
 
 Params = Dict[str, Any]
 
@@ -95,45 +96,52 @@ def attention_block(
     dh = c.head_dim_
     T = x.shape[0]
 
-    q = L.linear(x, lp["q_proj"], lp.get("q_bias")).reshape(T, c.num_heads, dh)
-    kx = L.linear(x, lp["k_proj"], lp.get("k_bias")).reshape(T, c.num_kv_heads, dh)
-    vx = L.linear(x, lp["v_proj"], lp.get("v_bias")).reshape(T, c.num_kv_heads, dh)
-    if c.qk_norm:
-        q = L.rms_norm(q, lp["q_norm"], c.rms_norm_eps)
-        kx = L.rms_norm(kx, lp["k_norm"], c.rms_norm_eps)
-    if c.key_multiplier != 1.0:
-        kx = (kx * c.key_multiplier).astype(kx.dtype)
+    with part("attn.proj"):
+        q = L.linear(x, lp["q_proj"], lp.get("q_bias")).reshape(
+            T, c.num_heads, dh)
+        kx = L.linear(x, lp["k_proj"], lp.get("k_bias")).reshape(
+            T, c.num_kv_heads, dh)
+        vx = L.linear(x, lp["v_proj"], lp.get("v_bias")).reshape(
+            T, c.num_kv_heads, dh)
+        if c.qk_norm:
+            q = L.rms_norm(q, lp["q_norm"], c.rms_norm_eps)
+            kx = L.rms_norm(kx, lp["k_norm"], c.rms_norm_eps)
+        if c.key_multiplier != 1.0:
+            kx = (kx * c.key_multiplier).astype(kx.dtype)
 
-    cos, sin = L.rope_cos_sin(batch["positions"], dh, c.rope_theta)
-    window = None
-    if c.layer_types:
-        window = jnp.asarray(c.layer_windows, jnp.int32)[layer]
-    if all(c.layer_rope):
-        q = L.apply_rope(q, cos, sin)
-        kx = L.apply_rope(kx, cos, sin)
-    else:
-        rope = jnp.asarray(c.layer_rope)[layer]
-        q = jnp.where(rope, L.apply_rope(q, cos, sin), q)
-        kx = jnp.where(rope, L.apply_rope(kx, cos, sin), kx)
+        cos, sin = L.rope_cos_sin(batch["positions"], dh, c.rope_theta)
+        window = None
+        if c.layer_types:
+            window = jnp.asarray(c.layer_windows, jnp.int32)[layer]
+        if all(c.layer_rope):
+            q = L.apply_rope(q, cos, sin)
+            kx = L.apply_rope(kx, cos, sin)
+        else:
+            rope = jnp.asarray(c.layer_rope)[layer]
+            q = jnp.where(rope, L.apply_rope(q, cos, sin), q)
+            kx = jnp.where(rope, L.apply_rope(kx, cos, sin), kx)
 
-    attn, *new_caches = attention_with_kv_update(
-        q, kx, vx, caches[0], caches[1], batch,
-        block_size=block_size, backend=attn_backend, layer=layer,
-        mesh=mesh, window=window)
-    attn = attn.reshape(T, c.num_heads * dh)
-    if "attn_gate" in lp:
-        attn = attn * jax.nn.sigmoid(L.linear(x, lp["attn_gate"]))
-    out = L.linear(attn, lp["o_proj"])
-    if "attn_out_norm" in lp:
-        out = L.rms_norm(out, lp["attn_out_norm"], c.rms_norm_eps)
+    with part(attn_part(batch)):
+        attn, *new_caches = attention_with_kv_update(
+            q, kx, vx, caches[0], caches[1], batch,
+            block_size=block_size, backend=attn_backend, layer=layer,
+            mesh=mesh, window=window)
+    with part("attn.proj"):
+        attn = attn.reshape(T, c.num_heads * dh)
+        if "attn_gate" in lp:
+            attn = attn * jax.nn.sigmoid(L.linear(x, lp["attn_gate"]))
+        out = L.linear(attn, lp["o_proj"])
+        if "attn_out_norm" in lp:
+            out = L.rms_norm(out, lp["attn_out_norm"], c.rms_norm_eps)
     return out, tuple(new_caches)
 
 
 def embed_tokens(params: Params, token_ids: jax.Array,
                  config: ModelConfig) -> jax.Array:
-    x = params["embed"][token_ids]
-    if config.embed_scale != 1.0:
-        x = (x * config.embed_scale).astype(x.dtype)
+    with part("embed"):
+        x = params["embed"][token_ids]
+        if config.embed_scale != 1.0:
+            x = (x * config.embed_scale).astype(x.dtype)
     return x
 
 
@@ -155,6 +163,31 @@ def dense_mlp(lp: Params, config: ModelConfig, h: jax.Array) -> jax.Array:
     m = L.linear(jax.nn.silu(gate) * L.linear(x, lp["up_proj"]),
                  lp["down_proj"])
     return mlp_out(lp, config, (m * out_mult).astype(m.dtype))
+
+
+def dense_layer(lp: Params, config: ModelConfig, h: jax.Array, attend):
+    """One dense layer around ``attend(normed input) -> (out, aux)``, each
+    operation in its part (ops/parts.py): the norm that feeds attention is
+    ``attn.proj``'s, the residual additions are ``mlp``'s.  Returns (h',
+    aux)."""
+    with part("attn.proj"):
+        hn = L.rms_norm(h, lp["input_norm"], config.rms_norm_eps)
+    a, aux = attend(hn)
+    with part("mlp"):
+        h = h + a
+        return h + dense_mlp(lp, config, h), aux
+
+
+def sampled_hidden(params: Params, x: jax.Array, batch, config: ModelConfig,
+                   stacked: bool = False) -> jax.Array:
+    """The final norm and the rows the head runs on: only sampling
+    positions need logits."""
+    with part("head"):
+        x = L.rms_norm(x, params["final_norm"], config.rms_norm_eps)
+        if stacked:
+            return jnp.take_along_axis(
+                x, batch["sample_idx"][..., None], axis=1)   # [dp, S_l, D]
+        return x[batch["sample_idx"]]                        # [S, D]
 
 
 def forward(
@@ -187,9 +220,10 @@ def forward(
     # Once a step program, outside the layer scan: a block-diffusion model's
     # visibility limits, and the query tile list the Pallas prefill kernels
     # walk in every layer.
-    batch = with_query_tiles(
-        with_block_visibility(batch, c.diffusion_block_length),
-        c.num_heads, caches0[0].shape[-1], attn_backend, mesh)
+    with part("tiles"):
+        batch = with_query_tiles(
+            with_block_visibility(batch, c.diffusion_block_length),
+            c.num_heads, caches0[0].shape[-1], attn_backend, mesh)
 
     # The FULL stacked KV cache rides the scan carry and each layer updates
     # its plane in place (Pallas aliasing / scatter-at-layer): slicing the
@@ -202,36 +236,32 @@ def forward(
 
     def layer_body(carry, lp):
         h, caches, li = carry
-        hn = L.rms_norm(h, lp["input_norm"], c.rms_norm_eps)
-        if stacked:
-            from llm_d_tpu.parallel.dp_attention import dp_attend
-            a, caches = dp_attend(attend, mesh, lp, hn, caches, batch, li)
-        else:
-            a, caches = attend(lp, hn, caches, batch, li)
-        h = h + a
-        h = h + dense_mlp(lp, c, h)
+
+        def attend_normed(hn):
+            if stacked:
+                from llm_d_tpu.parallel.dp_attention import dp_attend
+                return dp_attend(attend, mesh, lp, hn, caches, batch, li)
+            return attend(lp, hn, caches, batch, li)
+
+        h, caches = dense_layer(lp, c, h, attend_normed)
         return (h, caches, li + 1), None
 
-    (x, caches, _), _ = jax.lax.scan(
-        layer_body, (x, caches0, jnp.int32(0)), params["layers"])
+    with part("scan"):
+        (x, caches, _), _ = jax.lax.scan(
+            layer_body, (x, caches0, jnp.int32(0)), params["layers"])
 
-    x = L.rms_norm(x, params["final_norm"], c.rms_norm_eps)
-    # Only sampling positions need logits: gather last-token rows per sequence.
-    if stacked:
-        sample_hidden = jnp.take_along_axis(
-            x, batch["sample_idx"][..., None], axis=1)   # [dp, S_l, D]
-    else:
-        sample_hidden = x[batch["sample_idx"]]           # [S, D]
-    return sample_hidden, dict(zip(("k", "v"), caches))
+    return (sampled_hidden(params, x, batch, c, stacked),
+            dict(zip(("k", "v"), caches)))
 
 
 def compute_logits(params: Params, hidden: jax.Array, config: ModelConfig) -> jax.Array:
-    head = params.get("lm_head")
-    if head is None:                                  # tied embeddings
-        head = params["embed"].T
-    logits = jnp.dot(hidden, head, preferred_element_type=jnp.float32)
-    if config.lm_head_multiplier != 1.0:
-        logits = logits * config.lm_head_multiplier
+    with part("head"):
+        head = params.get("lm_head")
+        if head is None:                              # tied embeddings
+            head = params["embed"].T
+        logits = jnp.dot(hidden, head, preferred_element_type=jnp.float32)
+        if config.lm_head_multiplier != 1.0:
+            logits = logits * config.lm_head_multiplier
     return logits
 
 

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from llm_d_tpu.models.config import ModelConfig
 from llm_d_tpu.ops import attention as A
 from llm_d_tpu.ops import layers as L
+from llm_d_tpu.ops.parts import attn_part, part
 
 Params = Dict[str, Any]
 
@@ -97,46 +98,51 @@ def mla_attention_block(
     R = c.kv_lora_rank
     F = R + rope
 
-    # --- queries: low-rank down, norm, up (V3) or direct q_proj (V2-Lite) ---
-    if "q_a_proj" in lp:
-        cq = L.rms_norm(L.linear(x, lp["q_a_proj"]), lp["q_a_norm"],
-                        c.rms_norm_eps)
-        q = L.linear(cq, lp["q_b_proj"]).reshape(T, H, nope + rope)
-    else:
-        q = L.linear(x, lp["q_proj"]).reshape(T, H, nope + rope)
-    q_nope, q_pe = q[..., :nope], q[..., nope:]
+    with part("attn.proj"):
+        # --- queries: low-rank down, norm, up (V3) or direct q_proj
+        # (V2-Lite) ---
+        if "q_a_proj" in lp:
+            cq = L.rms_norm(L.linear(x, lp["q_a_proj"]), lp["q_a_norm"],
+                            c.rms_norm_eps)
+            q = L.linear(cq, lp["q_b_proj"]).reshape(T, H, nope + rope)
+        else:
+            q = L.linear(x, lp["q_proj"]).reshape(T, H, nope + rope)
+        q_nope, q_pe = q[..., :nope], q[..., nope:]
 
-    # --- latent KV row: c_kv (normed) | k_pe (RoPE, shared across heads) ---
-    kv_a = L.linear(x, lp["kv_a_proj"])                     # [T, R + rope]
-    c_kv = L.rms_norm(kv_a[:, :R], lp["kv_a_norm"], c.rms_norm_eps)
-    k_pe = kv_a[:, R:].reshape(T, 1, rope)
+        # --- latent KV row: c_kv (normed) | k_pe (RoPE, shared across
+        # heads) ---
+        kv_a = L.linear(x, lp["kv_a_proj"])                 # [T, R + rope]
+        c_kv = L.rms_norm(kv_a[:, :R], lp["kv_a_norm"], c.rms_norm_eps)
+        k_pe = kv_a[:, R:].reshape(T, 1, rope)
 
-    cos, sin = L.rope_cos_sin(batch["positions"], rope, c.rope_theta)
-    q_pe = L.apply_rope(q_pe, cos, sin)
-    k_pe = L.apply_rope(k_pe, cos, sin)[:, 0, :]            # [T, rope]
+        cos, sin = L.rope_cos_sin(batch["positions"], rope, c.rope_theta)
+        q_pe = L.apply_rope(q_pe, cos, sin)
+        k_pe = L.apply_rope(k_pe, cos, sin)[:, 0, :]            # [T, rope]
 
-    # --- absorb W_uk into the query: scores become one dot per cached row ---
-    # kv_b columns are head-major [h0:(nope|v), h1:(nope|v), ...] (HF
-    # layout) — reshape before splitting, never column-slice.
-    w_kv = lp["kv_b_proj"].reshape(R, H, nope + vdim)
-    w_uk, w_uv = w_kv[..., :nope], w_kv[..., nope:]
-    q_lat = jnp.einsum("thn,rhn->thr", q_nope.astype(jnp.float32),
-                       w_uk.astype(jnp.float32))            # [T, H, R]
-    q_eff = jnp.concatenate(
-        [q_lat, q_pe.astype(jnp.float32)], axis=-1).astype(x.dtype)  # [T,H,F]
+        # --- absorb W_uk into the query: scores become one dot per cached
+        # row ---
+        # kv_b columns are head-major [h0:(nope|v), h1:(nope|v), ...] (HF
+        # layout) — reshape before splitting, never column-slice.
+        w_kv = lp["kv_b_proj"].reshape(R, H, nope + vdim)
+        w_uk, w_uv = w_kv[..., :nope], w_kv[..., nope:]
+        q_lat = jnp.einsum("thn,rhn->thr", q_nope.astype(jnp.float32),
+                           w_uk.astype(jnp.float32))            # [T, H, R]
+        q_eff = jnp.concatenate(
+            [q_lat, q_pe.astype(jnp.float32)],
+            axis=-1).astype(x.dtype)                            # [T, H, F]
 
-    row = jnp.concatenate([c_kv, k_pe], axis=-1)            # [T, F]
-    # Softmax scale comes from the UNABSORBED query dim (nope + rope).
-    scale = (nope + rope) ** -0.5
+        row = jnp.concatenate([c_kv, k_pe], axis=-1)            # [T, F]
+        # Softmax scale comes from the UNABSORBED query dim (nope + rope).
+        scale = (nope + rope) ** -0.5
 
-    # The engine may lane-pad the cache row (F -> multiple of 128) so the
-    # Pallas decode kernel's page DMAs stay aligned; zero-padded query
-    # columns contribute exactly nothing to the scores.
-    F_cache = kv_cache.shape[-1]
-    if F_cache > F:
-        pad = F_cache - F
-        row = jnp.pad(row, ((0, 0), (0, pad)))
-        q_eff = jnp.pad(q_eff, ((0, 0), (0, 0), (0, pad)))
+        # The engine may lane-pad the cache row (F -> multiple of 128) so the
+        # Pallas decode kernel's page DMAs stay aligned; zero-padded query
+        # columns contribute exactly nothing to the scores.
+        F_cache = kv_cache.shape[-1]
+        if F_cache > F:
+            pad = F_cache - F
+            row = jnp.pad(row, ((0, 0), (0, pad)))
+            q_eff = jnp.pad(q_eff, ((0, 0), (0, 0), (0, pad)))
 
     backend = A.resolve_backend(attn_backend)
     attend = functools.partial(_mla_attend, block_size=block_size,
@@ -152,12 +158,14 @@ def mla_attention_block(
             attend, mesh,
             in_specs=(heads, P(), P(), {k: P() for k in ab}, P()),
             out_specs=(heads, P()))
-    out_lat, kv_cache = attend(q_eff, row, kv_cache, ab, layer)
+    with part(attn_part(batch)):
+        out_lat, kv_cache = attend(q_eff, row, kv_cache, ab, layer)
 
     # --- absorb W_uv: latent -> per-head value space, then output proj ---
-    attn = jnp.einsum("thr,rhv->thv", out_lat,
-                      w_uv.astype(jnp.float32)).astype(x.dtype)
-    return L.linear(attn.reshape(T, H * vdim), lp["o_proj"]), kv_cache
+    with part("attn.proj"):
+        attn = jnp.einsum("thr,rhv->thv", out_lat,
+                          w_uv.astype(jnp.float32)).astype(x.dtype)
+        return L.linear(attn.reshape(T, H * vdim), lp["o_proj"]), kv_cache
 
 
 def _mla_attend(q_eff, row, kv_cache, batch, layer, *,

@@ -24,12 +24,13 @@ from llm_d_tpu.models.llama import (  # noqa: F401  (re-exports: the MoE
     # model shares the dense family's logits head and MTP drafter — the
     # drafter reads only embed/lm_head from the target params, which both
     # families carry identically)
-    attention_block, compute_logits, dense_mlp, draft_propose, embed_tokens,
-    init_draft_params, mlp_out)
+    attention_block, compute_logits, dense_layer, draft_propose,
+    embed_tokens, init_draft_params, mlp_out, sampled_hidden)
 from llm_d_tpu.ops import layers as L
 from llm_d_tpu.ops import moe as moe_ops
 from llm_d_tpu.ops.attention import (
     with_block_visibility, with_query_tiles)
+from llm_d_tpu.ops.parts import part
 from llm_d_tpu.parallel.mesh import AXIS_EP
 
 Params = Dict[str, Any]
@@ -133,6 +134,9 @@ def forward(
     collect_moe_trace: bool = False,   # also return per-MoE-layer dispatch
                                        # inputs (the collective accuracy
                                        # harness's real-trace capture)
+    count_touched: bool = False,    # also return, last, the distinct routed
+                                    # experts the step's real token rows
+                                    # select, summed over the MoE layers
 ):
     c = config
     Ld = c.first_dense_layers
@@ -142,10 +146,17 @@ def forward(
     # Once a step program, outside both layer scans: a block-diffusion
     # model's visibility limits, and the query tile list the Pallas prefill
     # kernels walk in every layer.
-    batch = with_query_tiles(
-        with_block_visibility(batch, c.diffusion_block_length),
-        c.num_heads, kv_cache[cache_keys[0]].shape[-1], attn_backend,
-        mesh, mla=c.use_mla)
+    with part("tiles"):
+        batch = with_query_tiles(
+            with_block_visibility(batch, c.diffusion_block_length),
+            c.num_heads, kv_cache[cache_keys[0]].shape[-1], attn_backend,
+            mesh, mla=c.use_mla)
+        real = None
+        if count_touched:
+            # The token bucket's real rows lie first; a padded query slot
+            # names row T.
+            T = batch["token_ids"].shape[-1]
+            real = jnp.arange(T) < jnp.sum(batch["qtok_idx"] < T)
     # DBO threshold by phase: the program's query width is static under jit,
     # and Q == 1 holds exactly for pure-decode programs (single-step or
     # fused).  None (no opts) lets the op consult its standalone env vars;
@@ -153,10 +164,6 @@ def forward(
     is_decode = batch["qtok_idx"].shape[-1] == 1
     dbo_min_tokens = (moe_opts or {}).get(
         "dbo_decode_min_tokens" if is_decode else "dbo_prefill_min_tokens")
-    # Attribution stubs (EngineConfig.stub_components): drop a component
-    # from the compiled program so its cost is measurable by difference
-    # on either phase.  Shapes and the rest of the program are unchanged.
-    stub = frozenset((moe_opts or {}).get("stub_components") or ())
 
     def attend_local(lp, hn, caches, ab, li):
         """Attention dispatch: MLA (single latent buffer) or classic GQA."""
@@ -173,8 +180,6 @@ def forward(
     def attend(lp, hn, caches, li):
         """Stacked mode: per-dp-shard attention (manual dp, auto tp) —
         the dp half of the wide-EP regime; see parallel.dp_attention."""
-        if "attn" in stub:
-            return jnp.zeros_like(hn), caches
         if stacked:
             from llm_d_tpu.parallel.dp_attention import dp_attend
             return dp_attend(attend_local, mesh, lp, hn, caches, batch, li)
@@ -190,31 +195,33 @@ def forward(
     # plane in place (see models.llama.forward) — no split/concat copies.
     def dense_body(carry, lp):
         h, caches, li = carry
-        a, caches = attend(
-            lp, L.rms_norm(h, lp["input_norm"], c.rms_norm_eps), caches, li)
-        h = h + a
-        return (h + dense_mlp(lp, c, h), caches, li + 1), None
+        h, caches = dense_layer(
+            lp, c, h, lambda hn: attend(lp, hn, caches, li))
+        return (h, caches, li + 1), None
 
     def moe_body(carry, lp):
         h, caches, li = carry
-        a, caches = attend(
-            lp, L.rms_norm(h, lp["input_norm"], c.rms_norm_eps), caches, li)
-        h = h + a
-        hn = L.rms_norm(h, lp["post_attn_norm"], c.rms_norm_eps)
-        ht = moe_tokens(hn)                       # [T, D] (dp-sharded rows)
-        weights, idx = moe_ops.route(
-            jnp.dot(ht.astype(jnp.float32), lp["router"]), c,
-            e_bias=lp.get("e_bias"))
-        if "replica_table" in lp:
-            # EPLB: route to a physical replica of the logical expert
-            # (round-robin over its replicas; parallel.eplb plans the
-            # table per layer — the layer index phases the walk so every
-            # layer doesn't start on replica 0).
-            phys_idx = moe_ops.to_physical_experts(
-                idx, lp["replica_table"], lp["num_replicas"],
-                phase=li - Ld)
-        else:
+        with part("attn.proj"):
+            hn = L.rms_norm(h, lp["input_norm"], c.rms_norm_eps)
+        a, caches = attend(lp, hn, caches, li)
+        with part("router"):
+            h = h + a
+            hn = L.rms_norm(h, lp["post_attn_norm"], c.rms_norm_eps)
+            ht = moe_tokens(hn)                   # [T, D] (dp-sharded rows)
+            weights, idx = moe_ops.route(
+                jnp.dot(ht.astype(jnp.float32), lp["router"]), c,
+                e_bias=lp.get("e_bias"))
+            touched = (moe_ops.experts_touched(idx, real, c.num_experts)
+                       if real is not None else None)
             phys_idx = idx
+            if "replica_table" in lp:
+                # EPLB: route to a physical replica of the logical expert
+                # (round-robin over its replicas; parallel.eplb plans the
+                # table per layer — the layer index phases the walk so every
+                # layer doesn't start on replica 0).
+                phys_idx = moe_ops.to_physical_experts(
+                    idx, lp["replica_table"], lp["num_replicas"],
+                    phase=li - Ld)
         if quant_stacked is not None:
             # int8 payloads travel to the op STACKED (closure, not scan
             # xs — a scan slice feeding pallas_call would materialize a
@@ -230,26 +237,26 @@ def forward(
         else:
             quant = None
             w_gate, w_up, w_down = lp["w_gate"], lp["w_up"], lp["w_down"]
-        if "moe_ffn" in stub:
-            m = jnp.zeros_like(ht)   # routing still runs (EPLB collect)
-        else:
+        with part("experts"):
             m = moe_ops.expert_ffn(
                 ht, weights, phys_idx, w_gate, w_up, w_down, mesh=mesh,
                 dbo_min_tokens=dbo_min_tokens, quant=quant)
-        if stacked:
-            m = m.reshape(hn.shape)
-        if "shared_gate" in lp and "shared_expert" not in stub:
-            m = m + L.swiglu_mlp(hn, lp["shared_gate"], lp["shared_up"],
-                                 lp["shared_down"])
-        m = mlp_out(lp, c, m)
+            if stacked:
+                m = m.reshape(hn.shape)
+        if "shared_gate" in lp:
+            with part("shared"):
+                m = m + L.swiglu_mlp(hn, lp["shared_gate"], lp["shared_up"],
+                                     lp["shared_down"])
+        with part("mlp"):
+            h = h + mlp_out(lp, c, m)
         if collect_moe_trace:
             # The EXACT operands the EP dispatch ships: the rms-normed
             # hidden rows plus the routing the combine applies — what the
             # collective accuracy harness measures quantization against
             # (ops/collective_accuracy.py).
-            return (h + m, caches, li + 1), {
-                "x": ht, "weights": weights, "idx": phys_idx}
-        return (h + m, caches, li + 1), idx
+            return (h, caches, li + 1), ({
+                "x": ht, "weights": weights, "idx": phys_idx}, touched)
+        return (h, caches, li + 1), (idx, touched)
 
     ml = params["moe_layers"]
     quant_keys = ("w_gate_q", "w_gate_s", "w_up_q", "w_up_s",
@@ -260,26 +267,23 @@ def forward(
                        if quant_stacked is not None else ml)
 
     caches0 = tuple(kv_cache[k] for k in cache_keys)
-    (x, caches, li), _ = jax.lax.scan(
-        dense_body, (x, caches0, jnp.int32(0)), params["dense_layers"])
-    (x, caches, _), routed = jax.lax.scan(
-        moe_body, (x, caches, li), moe_scan_params)
+    with part("scan"):
+        (x, caches, li), _ = jax.lax.scan(
+            dense_body, (x, caches0, jnp.int32(0)), params["dense_layers"])
+        (x, caches, _), (routed, touched) = jax.lax.scan(
+            moe_body, (x, caches, li), moe_scan_params)
 
-    x = L.rms_norm(x, params["final_norm"], c.rms_norm_eps)
-    if stacked:
-        sample_hidden = jnp.take_along_axis(
-            x, batch["sample_idx"][..., None], axis=1)   # [dp, S_l, D]
-    else:
-        sample_hidden = x[batch["sample_idx"]]
-    out_cache = dict(zip(cache_keys, caches))
-    if collect_moe_trace:
-        # {"x": [Lm, T, H], "weights": [Lm, T, k], "idx": [Lm, T, k]} —
-        # the harness's real routed trace (see moe_body).
-        return sample_hidden, out_cache, routed
-    if collect_routed:
-        # [Lm, T, k] logical ids for the engine's EPLB LoadTracker.
-        return sample_hidden, out_cache, routed
-    return sample_hidden, out_cache
+    out = (sampled_hidden(params, x, batch, c, stacked),
+           dict(zip(cache_keys, caches)))
+    if collect_moe_trace or collect_routed:
+        # The harness's real routed trace {"x": [Lm, T, H], "weights":
+        # [Lm, T, k], "idx": [Lm, T, k]} (see moe_body), or the [Lm, T, k]
+        # logical ids for the engine's EPLB LoadTracker.
+        out += (routed,)
+    if count_touched:
+        with part("router"):
+            out += (jnp.sum(touched),)
+    return out
 
 
 def sharding_rules(config: ModelConfig):

@@ -36,6 +36,7 @@ from llm_d_tpu.models.llama import (  # noqa: F401  (the model interface)
 from llm_d_tpu.ops import layers as L
 from llm_d_tpu.ops import ssm as ssm_ops
 from llm_d_tpu.ops.attention import with_query_tiles
+from llm_d_tpu.ops.parts import part
 
 F32 = jnp.float32
 # Keys of the state pool in the ``kv_cache`` dict, beside ``k`` and ``v``.
@@ -142,25 +143,29 @@ def mixer_block(lp: Params, config: ModelConfig, x: jax.Array,
                   c.ssm_num_groups)
     di, gn = c.ssm_inner_size_, G * N
     ssm, conv = state
-    u = (L.linear(x, lp["ssm_in_proj"])
-         * jnp.asarray(mup_vector(c), x.dtype)).astype(x.dtype)
-    z, xbc, dt = u[:, :di], u[:, di:2 * di + 2 * gn], u[:, 2 * di + 2 * gn:]
-    xbc, conv = ssm_ops.causal_conv(
-        xbc, lp["ssm_conv_w"], lp["ssm_conv_b"], conv, batch, layer)
-    xs = xbc[:, :di].reshape(T, H, P)
-    B = xbc[:, di:di + gn].reshape(T, G, N)
-    C = xbc[:, di + gn:].reshape(T, G, N)
-    dt = jax.nn.softplus(dt.astype(F32) + lp["ssm_dt_bias"].astype(F32))
-    y, ssm = ssm_ops.state_update(
-        xs, dt, -jnp.exp(lp["ssm_A_log"].astype(F32)), B, C, lp["ssm_D"],
-        ssm, batch, layer, c.ssm_chunk_size, backend)
-    # Gate, then an RMS norm over each group's share of the inner width.
-    y = y.reshape(T, di).astype(F32) * jax.nn.silu(z.astype(F32))
-    yg = y.reshape(T, G, di // G)
-    yg = yg * jax.lax.rsqrt(
-        jnp.mean(yg * yg, axis=-1, keepdims=True) + c.rms_norm_eps)
-    y = (yg.reshape(T, di) * lp["ssm_norm"].astype(F32)).astype(x.dtype)
-    return L.linear(y, lp["ssm_out_proj"]), (ssm, conv)
+    with part("ssm.proj"):
+        u = (L.linear(x, lp["ssm_in_proj"])
+             * jnp.asarray(mup_vector(c), x.dtype)).astype(x.dtype)
+        z, xbc, dt = (u[:, :di], u[:, di:2 * di + 2 * gn],
+                      u[:, 2 * di + 2 * gn:])
+    with part("ssm.state"):
+        xbc, conv = ssm_ops.causal_conv(
+            xbc, lp["ssm_conv_w"], lp["ssm_conv_b"], conv, batch, layer)
+        xs = xbc[:, :di].reshape(T, H, P)
+        B = xbc[:, di:di + gn].reshape(T, G, N)
+        C = xbc[:, di + gn:].reshape(T, G, N)
+        dt = jax.nn.softplus(dt.astype(F32) + lp["ssm_dt_bias"].astype(F32))
+        y, ssm = ssm_ops.state_update(
+            xs, dt, -jnp.exp(lp["ssm_A_log"].astype(F32)), B, C, lp["ssm_D"],
+            ssm, batch, layer, c.ssm_chunk_size, backend)
+    with part("ssm.proj"):
+        # Gate, then an RMS norm over each group's share of the inner width.
+        y = y.reshape(T, di).astype(F32) * jax.nn.silu(z.astype(F32))
+        yg = y.reshape(T, G, di // G)
+        yg = yg * jax.lax.rsqrt(
+            jnp.mean(yg * yg, axis=-1, keepdims=True) + c.rms_norm_eps)
+        y = (yg.reshape(T, di) * lp["ssm_norm"].astype(F32)).astype(x.dtype)
+        return L.linear(y, lp["ssm_out_proj"]), (ssm, conv)
 
 
 def forward(
@@ -178,31 +183,35 @@ def forward(
     state pool updated)."""
     c = config
     x = llama.embed_tokens(params, batch["token_ids"], c)
-    batch = with_query_tiles(batch, c.num_heads, kv_cache["k"].shape[-1],
-                             attn_backend, mesh)
+    with part("tiles"):
+        batch = with_query_tiles(
+            batch, c.num_heads, kv_cache["k"].shape[-1], attn_backend, mesh)
 
     def scaled(h, m):
         return h if m == 1.0 else (h * m).astype(h.dtype)
 
     def layer_body(carry, lp):
         h, caches, state, li = carry
-        hn = L.rms_norm(h, lp["input_norm"], c.rms_norm_eps)
+        with part("attn.proj"):
+            hn = L.rms_norm(h, lp["input_norm"], c.rms_norm_eps)
+            ha = scaled(hn, c.attention_in_multiplier)
         a, caches = llama.attention_block(
-            lp, c, scaled(hn, c.attention_in_multiplier), batch, caches,
-            block_size, attn_backend, layer=li, mesh=mesh)
-        m, state = mixer_block(
-            lp, c, scaled(hn, c.ssm_in_multiplier), batch, state, li,
-            attn_backend)
-        h = h + scaled(a, c.attention_out_multiplier) \
-            + scaled(m, c.ssm_out_multiplier)
-        h = h + llama.dense_mlp(lp, c, h)
+            lp, c, ha, batch, caches, block_size, attn_backend, layer=li,
+            mesh=mesh)
+        with part("ssm.proj"):
+            hs = scaled(hn, c.ssm_in_multiplier)
+        m, state = mixer_block(lp, c, hs, batch, state, li, attn_backend)
+        with part("mlp"):
+            h = h + scaled(a, c.attention_out_multiplier) \
+                + scaled(m, c.ssm_out_multiplier)
+            h = h + llama.dense_mlp(lp, c, h)
         return (h, caches, state, li + 1), None
 
-    (x, caches, state, _), _ = jax.lax.scan(
-        layer_body,
-        (x, (kv_cache["k"], kv_cache["v"]),
-         tuple(kv_cache[name] for name in STATE_KEYS), jnp.int32(0)),
-        params["layers"])
-    x = L.rms_norm(x, params["final_norm"], c.rms_norm_eps)
-    return x[batch["sample_idx"]], dict(
+    with part("scan"):
+        (x, caches, state, _), _ = jax.lax.scan(
+            layer_body,
+            (x, (kv_cache["k"], kv_cache["v"]),
+             tuple(kv_cache[name] for name in STATE_KEYS), jnp.int32(0)),
+            params["layers"])
+    return llama.sampled_hidden(params, x, batch, c), dict(
         zip(("k", "v") + STATE_KEYS, caches + state))

@@ -949,6 +949,17 @@ def expert_ffn(
     return out.astype(x.dtype)
 
 
+def experts_touched(idx: jax.Array, real: jax.Array,
+                    num_experts: int) -> jax.Array:
+    """How many DISTINCT experts the rows ``real`` [T] (bool: not padding
+    of the token bucket) select in ``idx`` [T, k] (logical ids): what a
+    stream of only the touched experts would read, an int32 scalar.  A
+    compare against every expert id and one reduction, no scatter."""
+    hit = (idx[:, :, None] == jnp.arange(num_experts, dtype=idx.dtype)) \
+        & real[:, None, None]
+    return jnp.sum(jnp.any(hit, axis=(0, 1)), dtype=jnp.int32)
+
+
 def to_physical_experts(
     idx: jax.Array,            # [T, k] logical expert ids
     replica_table: jax.Array,  # [E, max_r] physical slots per logical expert

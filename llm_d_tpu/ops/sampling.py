@@ -125,7 +125,7 @@ def spec_verify(
     whatever the drafter proposed.  Drafter quality moves throughput
     only, never output.
 
-    ``fixed_accept`` (bench/diagnostics only, like stub components):
+    ``fixed_accept`` (bench/diagnostics only):
     replace the equality check with a SEEDED per-draft coin at this rate
     keyed on (step, row) — deterministic accepted-length schedules for
     the accepted-tok/s bench metric.  Changes model output (accepted

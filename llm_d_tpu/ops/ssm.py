@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from llm_d_tpu.ops.attention import resolve_backend
+from llm_d_tpu.ops.parts import part
 
 F32 = jnp.float32
 
@@ -132,24 +133,25 @@ def scan_pieces(batch: Dict[str, jax.Array], T: int, chunk: int):
     per token
     ``tok_piece`` and ``tok_off`` (where a token of such a row lies);
     ``count``."""
-    qstart, qlen = batch["query_start"], batch["query_len"]
-    S = qlen.shape[0]
-    pieces = jnp.where(qlen > 1, -(-qlen // chunk), 0)
-    ends = jnp.cumsum(pieces)
-    NT = -(-T // chunk) + S
-    i = jnp.arange(NT)
-    row = jnp.minimum(jnp.searchsorted(ends, i, side="right"), S - 1)
-    live = i < ends[-1]
-    off = (i - (ends - pieces)[row]) * chunk
-    rows, qpos = batch["token_seq_ids"], batch["token_qpos"]
-    return {
-        "start": qstart[row] + off,
-        "length": jnp.where(live, jnp.clip(qlen[row] - off, 0, chunk), 0),
-        "slot": jnp.where(live, batch["state_slot"][row], 0),
-        "first": live & (off == 0), "fresh": fresh_rows(batch)[row],
-        "live": live,
-        "tok_piece": (ends - pieces)[rows] + qpos // chunk,
-        "tok_off": qpos % chunk, "count": ends[-1]}
+    with part("tiles"):     # the same list in every layer
+        qstart, qlen = batch["query_start"], batch["query_len"]
+        S = qlen.shape[0]
+        pieces = jnp.where(qlen > 1, -(-qlen // chunk), 0)
+        ends = jnp.cumsum(pieces)
+        NT = -(-T // chunk) + S
+        i = jnp.arange(NT)
+        row = jnp.minimum(jnp.searchsorted(ends, i, side="right"), S - 1)
+        live = i < ends[-1]
+        off = (i - (ends - pieces)[row]) * chunk
+        rows, qpos = batch["token_seq_ids"], batch["token_qpos"]
+        return {
+            "start": qstart[row] + off,
+            "length": jnp.where(live, jnp.clip(qlen[row] - off, 0, chunk), 0),
+            "slot": jnp.where(live, batch["state_slot"][row], 0),
+            "first": live & (off == 0), "fresh": fresh_rows(batch)[row],
+            "live": live,
+            "tok_piece": (ends - pieces)[rows] + qpos // chunk,
+            "tok_off": qpos % chunk, "count": ends[-1]}
 
 
 def chunk_scan_pallas(x, dt, A, B, C, pool, layer, batch, chunk: int):

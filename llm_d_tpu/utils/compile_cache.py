@@ -11,6 +11,14 @@ calls :func:`configure_compile_cache` before its first compile.
     ``<checkout>/.jax_cache`` resolved from this package's ``__file__``.
     Never the cwd, a temp name, a pid or a time: the path is part of the
     cache key, so a directory that moves never hits.
+
+The key covers a program's debug info (``jax_compilation_cache_include_
+metadata_in_key``): the ``llmd.<part>`` scopes (ops/parts.py) are debug info,
+and the profiler reads them off the executable that ran.  Under JAX's
+default, a key blind to them, a cache filled by another tree hands back
+executables with that tree's names, or none.  The price is the one programs
+with a Pallas kernel paid already (a Mosaic payload holds its source
+locations): a tree that moves a traced line compiles its programs once.
 """
 
 from __future__ import annotations
@@ -34,6 +42,7 @@ def configure_compile_cache(flag_dir: Optional[str] = None) -> str:
     # Cache small programs too: a serving engine compiles dozens of
     # sub-second bucket variants.  (Not a directory: safe in both cases.)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env_dir = os.environ.get(ENV_VAR)
     if env_dir:
         if flag_dir and flag_dir != env_dir:

@@ -389,7 +389,8 @@ def test_mla_key_fill_share_is_a_data_file():
     """PR 35's one per-layer metric: an entry appended to BENCHMARK.json and
     a file for the reader that is there, no reader code."""
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    entry = bench["per_layer"][-1]
+    entry = next(m for m in bench["per_layer"]
+                 if m["name"] == "attn_key_fill_share.mla")
     assert entry == {
         "name": "attn_key_fill_share.mla", "unit": "%", "better": "higher",
         "source": "program_span", "layer": "attention kernels",

@@ -283,7 +283,7 @@ def test_scheduler_invariants():
                 == before[sr.request.request_id]
         packed, layout, scheduled, rows_ = eng._build_batch(sched)
         assert layout.Q >= B and layout.R == B
-        ids, lps, eng.kv_cache, _, top, eng._rng = eng._step_fn(
+        ids, lps, eng.kv_cache, _, _, top, eng._rng = eng._step_fn(
             eng.params, eng.kv_cache, packed, eng._rng, layout)
         eng._retire_block_rows(scheduled, rows_, np.asarray(ids),
                                np.asarray(lps), None, 0.0, [])

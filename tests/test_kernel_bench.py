@@ -196,47 +196,6 @@ def test_kernel_bench_respects_path_caps(tmp_path):
         "streamed", "grouped")
 
 
-def test_attribution_table_differences_and_residual():
-    """component cost = baseline − stubbed per phase/bs; residual is the
-    unattributed remainder — computed by the harness, not by hand."""
-    import bench
-
-    baseline = {"64": {"decode_ms_per_step": 10.0,
-                       "prefill_ms_per_step": 100.0},
-                "256": {"decode_ms_per_step": 16.0,
-                        "prefill_ms_per_step": 240.0}}
-    stubs = {
-        "attn": {"64": {"decode_ms_per_step": 7.0,
-                        "prefill_ms_per_step": 60.0},
-                 "256": {"decode_ms_per_step": 11.0,
-                         "prefill_ms_per_step": 150.0}},
-        "moe_ffn": {"64": {"decode_ms_per_step": 6.0,
-                           "prefill_ms_per_step": 55.0},
-                    "256": {"decode_ms_per_step": 7.0,
-                            "prefill_ms_per_step": 130.0}},
-    }
-    table = bench._attribution_table(baseline, stubs)
-    assert table["components"]["attn"]["decode_bs64_ms"] == 3.0
-    assert table["components"]["attn"]["prefill_bs256_ms"] == 90.0
-    assert table["components"]["moe_ffn"]["prefill_bs64_ms"] == 45.0
-    # residual = baseline − sum(component costs)
-    assert table["residual_ms"]["decode_bs64_ms"] == 10.0 - (3.0 + 4.0)
-    assert table["residual_ms"]["prefill_bs256_ms"] == 240.0 - (90.0 + 110.0)
-
-
-def test_attribution_table_tolerates_missing_cells():
-    """A stub run that lost a batch size (OOM, timeout) must not crash
-    the table; the cell is just absent and the residual skips it."""
-    import bench
-
-    baseline = {"64": {"decode_ms_per_step": 10.0,
-                       "prefill_ms_per_step": 100.0}}
-    stubs = {"attn": {}}
-    table = bench._attribution_table(baseline, stubs)
-    assert table["components"]["attn"] == {}
-    assert table["residual_ms"]["decode_bs64_ms"] == 10.0
-
-
 def test_regression_gate_three_metrics_band_verdict():
     """The gate covers dense-bs64 decode, moe-bs256 decode AND
     moe-bs64 prefill; a metric regresses only when its whole band sits
