@@ -2855,6 +2855,8 @@ class EngineCore:
             self._step_kv.update(
                 self._attn_q_counts(int(np.sum(news)), layout))
             self._step_kv.update(self._attn_k_counts(ends, news, layout))
+        else:
+            self._step_kv.update(self._attn_dk_counts(ends, layout))
         return packed, layout, scheduled, rows
 
     # ---------- step ----------
@@ -2970,6 +2972,26 @@ class EngineCore:
             pages = -(-(q_last + 1) // bs) - k_first // bs
             slots += layers * int((-(-pages * bs // kb)).sum()) * kb
         return {"attn_k_real": real, "attn_k_slots": slots}
+
+    def _attn_dk_counts(self, ends, layout: BatchLayout) -> Dict[str, int]:
+        """The keys the MLA decode kernel's inner loop covers for a
+        pure-decode dispatch (step_clock.py), summed over its rows and the
+        attention layers: the kernel takes the rows of the sequence bucket
+        in the order of their contexts ``ends[r]`` (pad rows 0), G to a grid
+        program, and a program walks key blocks to its longest row's last.
+        Nothing where another path serves decode."""
+        c = self.model_config
+        if (self._prefill_tile_dims is None or not c.use_mla
+                or layout.dp != 1):
+            return {}
+        from llm_d_tpu.ops.attention import mla_decode_walk
+        kb, group = mla_decode_walk(
+            layout.S, *self._prefill_tile_dims, self.config.block_size)
+        lens = np.zeros(layout.S, np.int64)
+        lens[:len(ends)] = ends
+        blocks = -(-np.sort(lens).reshape(-1, group).max(axis=1) // kb)
+        return {"attn_dk_real": c.num_layers * int(lens.sum()),
+                "attn_dk_slots": c.num_layers * int(blocks.sum()) * group * kb}
 
     def _note_step(self, t0: float, fetched: float, requests: List[Request],
                    prefill_tokens: int, decode_tokens: int, *,

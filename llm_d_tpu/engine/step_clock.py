@@ -55,6 +55,18 @@ attention layers:
                   ``ops.attention.prefill_key_block``); the rest lie before
                   the window, past the causal diagonal or past the context
 
+and, for the classic path's pure-decode steps of a stack the MLA decode
+kernel serves (``EngineCore._attn_dk_counts``), summed over the step's rows
+and attention layers:
+
+  attn_dk_real    keys the rows can see: their contexts, the new token's
+                  own key included
+  attn_dk_slots   keys the kernel's inner loop covers for them: the key
+                  blocks a grid program walks (to its longest row's last;
+                  rows in the order of their lengths, pad rows of the
+                  sequence bucket first) x its rows x the keys a block
+                  (``ops.attention.mla_decode_walk``)
+
 and, for a stack with recurrent state beside its pages
 (``EngineCore._state_counts``; the classic path, also on its
 ``llmd.dispatch`` annotation):

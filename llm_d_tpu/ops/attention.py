@@ -334,6 +334,18 @@ def prefill_key_block(q_tile: int, H: int, F: int, D: int, block_size: int,
     return pick_key_block(block_size, F, dot_rows(q_tile, H, F // D, D))
 
 
+@functools.lru_cache(maxsize=None)
+def mla_decode_walk(S: int, H: int, F: int, block_size: int):
+    """(keys a step of the MLA decode kernel's inner loop covers for a
+    sequence, sequences a grid program owns) for ``S`` rows of ``H`` heads
+    over cache rows ``F`` wide as ONE shard sees them: the kernel's own
+    picks (``ops.pallas.mla_attention``)."""
+    from llm_d_tpu.ops.pallas.mla_attention import (
+        decode_key_block, decode_seq_group)
+    kb = decode_key_block(H, F, block_size)
+    return kb, decode_seq_group(S, H, F, kb)
+
+
 def num_query_tiles(T: int, S: int, q_tile: int) -> int:
     """Tiles that hold any ``S`` rows of ``T`` tokens together: a row of n
     tokens fills ceil(n / q_tile), so the sum stays UNDER this count and

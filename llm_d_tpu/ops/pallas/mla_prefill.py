@@ -150,29 +150,7 @@ def _mla_prefill_kernel(
         key_pos = i * KB + col                                # [1, KB]
         valid = (key_pos <= q_pos) & (key_pos < seq_len)      # [R, KB]
         s_hb = jnp.where(valid, s_hb, NEG_INF)
-        # Every key is weighed against the running max at the END OF ITS
-        # PAGE, as the loop a page a step did and the decode kernel does:
-        # the probabilities round to the same bf16 there and here.
-        ref, m_new = _max_to_page_end(s_hb, col, bs, m)       # [R, KB]
-        p = jnp.exp(s_hb - ref)
-        # ... and carried to the block's max in f32 afterwards, as that
-        # loop's corrections of the accumulator did: c p in three bf16
-        # terms, f32's 24 bits.
-        c = jnp.exp(ref - m_new)
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + jnp.sum(p * c, axis=-1, keepdims=True)
-        w = p.astype(jnp.bfloat16).astype(jnp.float32) * c
-        terms = []
-        for _ in range(3):
-            terms.append(w.astype(jnp.bfloat16))
-            w = w - terms[-1].astype(jnp.float32)
-        # Value dot on the SAME block buffer — no second DMA; the terms
-        # stream through one load of the block.
-        pv = jax.lax.dot_general(
-            jnp.concatenate(terms, axis=0), kv, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [3 R, F]
-        acc_new = acc * corr + (pv[:R] + pv[R:2 * R] + pv[2 * R:])
-        return m_new, l_new, acc_new
+        return weigh_key_block(s_hb, kv, col, bs, m, l, acc)
 
     init = (
         jnp.full((R, 1), -1e29, jnp.float32),
@@ -181,6 +159,40 @@ def _mla_prefill_kernel(
     )
     m, l, acc = jax.lax.fori_loop(0, n_blocks, body, init)
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def weigh_key_block(s_hb, kv, col, page: int, m, l, acc):
+    """One key block of the flash recurrence, rounded as a loop a page a
+    step rounds: ``s_hb`` [R, KB] the block's masked f32 scores, ``kv``
+    [KB, F] its bf16 rows (keys and values at once), ``col`` [1, KB] the
+    column index, ``m`` / ``l`` [R, 1] and ``acc`` [R, F] the f32
+    statistics before the block; returns them after it.  The ONE body of
+    both MLA kernels (this file's and ``mla_attention.py``'s decode kernel),
+    so that the two round alike whatever block either picks."""
+    R = s_hb.shape[0]
+    # Every key is weighed against the running max at the END OF ITS
+    # PAGE, as a loop a page a step does: the probabilities round to the
+    # same bf16 there and here.
+    ref, m_new = _max_to_page_end(s_hb, col, page, m)         # [R, KB]
+    p = jnp.exp(s_hb - ref)
+    # ... and carried to the block's max in f32 afterwards, as that
+    # loop's corrections of the accumulator did: c p in three bf16
+    # terms, f32's 24 bits.
+    c = jnp.exp(ref - m_new)
+    corr = jnp.exp(m - m_new)
+    l_new = l * corr + jnp.sum(p * c, axis=-1, keepdims=True)
+    w = p.astype(jnp.bfloat16).astype(jnp.float32) * c
+    terms = []
+    for _ in range(3):
+        terms.append(w.astype(jnp.bfloat16))
+        w = w - terms[-1].astype(jnp.float32)
+    # Value dot on the SAME block buffer — no second DMA; the terms
+    # stream through one load of the block.
+    pv = jax.lax.dot_general(
+        jnp.concatenate(terms, axis=0), kv, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                   # [3 R, F]
+    acc_new = acc * corr + (pv[:R] + pv[R:2 * R] + pv[2 * R:])
+    return m_new, l_new, acc_new
 
 
 def _max_to_page_end(x, col, page: int, before):

@@ -33,6 +33,32 @@ def devices():
     assert len(devs) == 8, devs
     return devs
 
+
+@pytest.fixture(scope="session")
+def under_jit():
+    """``under_jit(fn, *args, **kw)``: ``fn(*args, **kw)`` inside ONE jitted
+    program, as the models call the sharded ops.  Called eagerly, a
+    ``shard_map`` runs its body a primitive at a time on every device: 15-40
+    s a call of ``ops.moe.expert_ffn_a2a`` on the 8 CPU devices, every call
+    anew, where the program compiles in 2-6 s and runs in none.  Arrays,
+    dicts of arrays and ``None`` are traced; a mesh, a string or a number
+    is closed over."""
+    import numpy as np
+
+    def traced(v):
+        return v is None or isinstance(v, (jax.Array, np.ndarray, dict))
+
+    def call(fn, *args, **kw):
+        pos = {i: a for i, a in enumerate(args) if traced(a)}
+        named = {k: v for k, v in kw.items() if traced(v)}
+
+        def program(pos, named):
+            return fn(*(pos.get(i, a) for i, a in enumerate(args)),
+                      **{**kw, **named})
+        return jax.jit(program)(pos, named)
+    return call
+
+
 # Persistent compile cache: the suite's cost is dominated by XLA CPU
 # compiles of near-identical programs; warm runs skip them.  Placed by the
 # one helper every entry point uses (JAX_COMPILATION_CACHE_DIR if set,

@@ -373,3 +373,45 @@ def test_engine_counts_the_keys_its_prefill_walks(model, dims, bs, Q, qt,
     # Another path serves prefill: no walk to count.
     engine._prefill_tile_dims = None
     assert engine._attn_k_counts(ends, news, layout) == {}
+
+
+# ---- the keys the MLA decode kernel's inner loop covers -------------------
+
+@pytest.mark.parametrize("seed", range(3))
+@pytest.mark.parametrize("dims,bs,S,kb,G", [
+    ((32, 640), 32, 64, 512, 4),     # kanana-2-30b-a3b: a full decode batch
+    ((32, 640), 32, 8, 512, 4),      #   ... its smallest sequence bucket
+    ((8, 640), 32, 64, 512, 4),      # a tp-4 shard's 8 heads
+    ((32, 640), 16, 16, 512, 4),     # a 16-key page
+])
+def test_engine_counts_the_keys_its_mla_decode_walks(dims, bs, S, kb, G,
+                                                      seed):
+    from types import SimpleNamespace
+
+    from llm_d_tpu.engine.engine import EngineCore
+    from llm_d_tpu.engine.packed_batch import BatchLayout
+    from llm_d_tpu.models import get_config
+    c = get_config("tiny-mla")
+    engine = EngineCore.__new__(EngineCore)
+    engine.model_config = c
+    engine.config = SimpleNamespace(block_size=bs)
+    engine._prefill_tile_dims = dims
+    assert A.mla_decode_walk(S, *dims, bs) == (kb, G)
+    rng = np.random.default_rng(seed)
+    # The cell's contexts in most of the bucket's rows, the rest padding.
+    ends = rng.integers(1, 1537, size=S - int(rng.integers(0, G + 1))).tolist()
+    got = engine._attn_dk_counts(ends, BatchLayout(S, S, 1, B=48))
+    # The kernel's walk by hand: rows by length (pad rows, context 0,
+    # first), G to a program, every program to its longest row's last block.
+    rows = sorted(ends + [0] * (S - len(ends)))
+    slots = sum(-(-max(rows[i:i + G]) // kb) * kb * G
+                for i in range(0, S, G))
+    assert got == {"attn_dk_real": c.num_layers * sum(ends),
+                   "attn_dk_slots": c.num_layers * slots}
+    assert 0 < got["attn_dk_real"] <= got["attn_dk_slots"]
+    # Another path serves decode, or another attention family: no walk.
+    engine._prefill_tile_dims = None
+    assert engine._attn_dk_counts(ends, BatchLayout(S, S, 1, B=48)) == {}
+    engine._prefill_tile_dims = dims
+    engine.model_config = get_config("tiny")
+    assert engine._attn_dk_counts(ends, BatchLayout(S, S, 1, B=48)) == {}

@@ -385,22 +385,24 @@ def test_ssm_mechanism_check_names_each_fault():
     assert wrong[:, 0].tolist() == [1, 2, 3, 4, 4, 4, 1, 2, 3, 4]
 
 
-def test_mla_key_fill_share_is_a_data_file():
-    """PR 35's one per-layer metric: an entry appended to BENCHMARK.json and
+@pytest.mark.parametrize("name,moves,counts", [
+    ("attn_key_fill_share.mla", "itl_p95_ms", "attn_k"),           # PR 35
+    ("attn_decode_key_fill_share.mla", "out_tok_s", "attn_dk"),    # PR 37
+])
+def test_mla_key_fill_share_is_a_data_file(name, moves, counts):
+    """A PR's one per-layer metric: an entry appended to BENCHMARK.json and
     a file for the reader that is there, no reader code."""
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    entry = next(m for m in bench["per_layer"]
-                 if m["name"] == "attn_key_fill_share.mla")
+    entry = next(m for m in bench["per_layer"] if m["name"] == name)
     assert entry == {
-        "name": "attn_key_fill_share.mla", "unit": "%", "better": "higher",
+        "name": name, "unit": "%", "better": "higher",
         "source": "program_span", "layer": "attention kernels",
-        "moves": "itl_p95_ms", "workloads": ["kanana2.batch"]}
-    d = json.loads(
-        (BENCH / "layer_metrics" / "attn_key_fill_share.mla.json").read_text())
+        "moves": moves, "workloads": ["kanana2.batch"]}
+    d = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())
     assert all(d[k] == entry[k] for k in (
         "name", "unit", "better", "source", "layer", "moves"))
     assert d["reader"] == "span_ratio" and d["args"] == {
-        "span": "engine.step", "numerator": "attn_k_real",
-        "denominator": ["attn_k_slots"]}
+        "span": "engine.step", "numerator": f"{counts}_real",
+        "denominator": [f"{counts}_slots"]}
     moved = next(m for m in bench["end_to_end"] if m["name"] == entry["moves"])
     assert set(entry["workloads"]) <= set(moved["workloads"])

@@ -67,14 +67,14 @@ def _rel_rms(a, b, ref):
 # Wire-mode parity (the 2% rel-RMS per-collective acceptance bound)
 # ---------------------------------------------------------------------------
 
-def test_int8_wire_parity_per_collective(mesh):
+def test_int8_wire_parity_per_collective(mesh, under_jit):
     """Each collective's quantization error, isolated by differencing
     wire modes against the SAME routing, is bounded at 2% rel-RMS of the
     oracle output — the acceptance bound, asserted on the op itself."""
     x, w, idx, wg, wu, wd = _case(7, 32, 16)
-    oracle = moe_ops.expert_ffn(x, w, idx, wg, wu, wd, mesh=mesh,
+    oracle = under_jit(moe_ops.expert_ffn, x, w, idx, wg, wu, wd, mesh=mesh,
                                 dispatch="psum")
-    outs = {mode: moe_ops.expert_ffn_a2a(
+    outs = {mode: under_jit(moe_ops.expert_ffn_a2a,
         x, w, idx, wg, wu, wd, mesh, collective_dtype=mode)
         for mode in ("bf16", "int8-dispatch", "int8")}
     # Dispatch collective: int8 outbound vs bf16 outbound, same combine.
@@ -87,22 +87,22 @@ def test_int8_wire_parity_per_collective(mesh):
                                atol=6e-2, rtol=6e-2)
 
 
-def test_bf16_combine_downcast_parity(mesh):
+def test_bf16_combine_downcast_parity(mesh, under_jit):
     """The round-10 quick win: the bf16 baseline combine no longer ships
     f32 rows.  Parity vs the psum oracle pins the downcast's tolerance —
     one bf16 rounding of the expert output, inside the pre-existing
     dispatch tolerance."""
     x, w, idx, wg, wu, wd = _case(11, 16, 8)
-    oracle = moe_ops.expert_ffn(x, w, idx, wg, wu, wd, mesh=mesh,
+    oracle = under_jit(moe_ops.expert_ffn, x, w, idx, wg, wu, wd, mesh=mesh,
                                 dispatch="psum")
-    a2a = moe_ops.expert_ffn_a2a(x, w, idx, wg, wu, wd, mesh,
+    a2a = under_jit(moe_ops.expert_ffn_a2a, x, w, idx, wg, wu, wd, mesh,
                                  collective_dtype="bf16")
     np.testing.assert_allclose(np.asarray(a2a, np.float32),
                                np.asarray(oracle, np.float32),
                                atol=3e-2, rtol=3e-2)
 
 
-def test_int8_wire_feeds_streamed_kernel_interpret(mesh):
+def test_int8_wire_feeds_streamed_kernel_interpret(mesh, under_jit):
     """Quantized wire + quantized EXPERTS together: the dequantized
     arrival rows feed the chunk-streamed int8 kernel (interpret mode)
     exactly like bf16 arrivals do — the wide-EP serving configuration,
@@ -124,10 +124,10 @@ def test_int8_wire_feeds_streamed_kernel_interpret(mesh):
             jax.random.normal(kk, shape, jnp.float32) * 0.05)
         quant[f"{name}_q"], quant[f"{name}_s"] = stack(q), stack(s)
         deq.append(dequantize(q, s))
-    got = moe_ops.expert_ffn_a2a(x, w, idx, None, None, None, mesh,
+    got = under_jit(moe_ops.expert_ffn_a2a, x, w, idx, None, None, None, mesh,
                                  quant=quant, interpret=True,
                                  collective_dtype="int8")
-    want = moe_ops.expert_ffn_a2a(x, w, idx, *deq, mesh,
+    want = under_jit(moe_ops.expert_ffn_a2a, x, w, idx, *deq, mesh,
                                   collective_dtype="bf16")
     scale = float(jnp.max(jnp.abs(np.asarray(want, np.float32)))) + 1e-9
     np.testing.assert_allclose(np.asarray(got, np.float32) / scale,
@@ -153,7 +153,7 @@ def _exact_rows(rng, T, H):
     return jnp.asarray(m / 64.0, jnp.bfloat16)
 
 
-def test_scale_plane_alignment_byte_exact_under_skew(mesh):
+def test_scale_plane_alignment_byte_exact_under_skew(mesh, under_jit):
     """Dispatch-only quantization on exactly-representable rows must equal
     the bf16 wire BIT-FOR-BIT, under worst-case routing skew (every token
     to one shard's experts) and multi-chunk dispatch — the scale plane
@@ -172,10 +172,10 @@ def test_scale_plane_alignment_byte_exact_under_skew(mesh):
         w = jnp.abs(jnp.asarray(rng.standard_normal((T, k)),
                                 jnp.float32)) * 0.5
         for chunk in (None, 2):
-            a = moe_ops.expert_ffn_a2a(x, w, idx, wg, wu, wd, mesh,
+            a = under_jit(moe_ops.expert_ffn_a2a, x, w, idx, wg, wu, wd, mesh,
                                        chunk_tokens=chunk,
                                        collective_dtype="bf16")
-            b = moe_ops.expert_ffn_a2a(x, w, idx, wg, wu, wd, mesh,
+            b = under_jit(moe_ops.expert_ffn_a2a, x, w, idx, wg, wu, wd, mesh,
                                        chunk_tokens=chunk,
                                        collective_dtype="int8-dispatch")
             np.testing.assert_array_equal(
@@ -198,14 +198,14 @@ def test_quantize_rows_round_trip_shapes():
 # Quantized allreduce (psum fallback / TP)
 # ---------------------------------------------------------------------------
 
-def test_quantized_psum_matches_psum_on_ep_axes(mesh):
+def test_quantized_psum_matches_psum_on_ep_axes(mesh, under_jit):
     """expert_ffn dispatch='psum' under the int8 wire == the exact psum
     oracle within the combine bound — the EQuARX allreduce swap is
     numerically invisible at the documented tolerance."""
     x, w, idx, wg, wu, wd = _case(13, 16, 16)
-    exact = moe_ops.expert_ffn(x, w, idx, wg, wu, wd, mesh=mesh,
+    exact = under_jit(moe_ops.expert_ffn, x, w, idx, wg, wu, wd, mesh=mesh,
                                dispatch="psum", collective_dtype="bf16")
-    quant = moe_ops.expert_ffn(x, w, idx, wg, wu, wd, mesh=mesh,
+    quant = under_jit(moe_ops.expert_ffn, x, w, idx, wg, wu, wd, mesh=mesh,
                                dispatch="psum", collective_dtype="int8")
     assert _rel_rms(quant, exact, exact) <= 2e-2
     np.testing.assert_allclose(np.asarray(quant, np.float32),

@@ -73,7 +73,8 @@ def test_dbo_forces_two_chunks_and_matches(mesh, dbo_env, monkeypatch):
                                atol=3e-2, rtol=3e-2)
 
 
-def test_dbo_below_threshold_single_chunk(mesh, dbo_env, monkeypatch):
+def test_dbo_below_threshold_single_chunk(mesh, dbo_env, monkeypatch,
+                                          under_jit):
     os.environ["LLMD_DBO_TOKEN_THRESHOLD"] = "128"   # above the T=64 batch
     cfg = ModelConfig(name="dbo-test", num_experts=16, num_experts_per_tok=2,
                       moe_renormalize=True)
@@ -84,7 +85,8 @@ def test_dbo_below_threshold_single_chunk(mesh, dbo_env, monkeypatch):
     real = moe_ops._a2a_moe_chunk
     monkeypatch.setattr(moe_ops, "_a2a_moe_chunk",
                         lambda *a, **k: calls.append(1) or real(*a, **k))
-    moe_ops.expert_ffn_a2a(x, weights, idx, w_gate, w_up, w_down, mesh)
+    under_jit(moe_ops.expert_ffn_a2a, x, weights, idx, w_gate, w_up, w_down,
+              mesh)
     assert len(calls) == 1
 
 
@@ -242,7 +244,7 @@ def test_dbo_chunks_are_data_independent(mesh):
             "chunk 1 dispatch depends on chunk 0 - DBO overlap impossible"
 
 
-def test_dbo_chunked_parity_fast(mesh, dbo_env):
+def test_dbo_chunked_parity_fast(mesh, dbo_env, under_jit):
     """GATING-TIER parity representative (advisor r4): chunked dispatch ==
     single-chunk numerics on one tiny case; full coverage stays slow."""
     cfg = ModelConfig(name="dbo-fast", num_experts=8, num_experts_per_tok=2,
@@ -250,10 +252,11 @@ def test_dbo_chunked_parity_fast(mesh, dbo_env):
     x, router, w_gate, w_up, w_down = _case(11, 16, 8)
     weights, idx = moe_ops.route(
         jnp.dot(x.astype(jnp.float32), router), cfg)
-    chunked = moe_ops.expert_ffn_a2a(
-        x, weights, idx, w_gate, w_up, w_down, mesh, chunk_tokens=1)
-    single = moe_ops.expert_ffn_a2a(
-        x, weights, idx, w_gate, w_up, w_down, mesh)
+    chunked = under_jit(moe_ops.expert_ffn_a2a,
+                        x, weights, idx, w_gate, w_up, w_down, mesh,
+                        chunk_tokens=1)
+    single = under_jit(moe_ops.expert_ffn_a2a,
+                       x, weights, idx, w_gate, w_up, w_down, mesh)
     np.testing.assert_allclose(np.asarray(chunked, np.float32),
                                np.asarray(single, np.float32),
                                atol=3e-2, rtol=3e-2)

@@ -257,6 +257,8 @@ def test_key_blocks_round_as_the_decode_kernel(key_block):
     (32, 640, 128, 256),     # kanana-2-30b-a3b: 4 slots x 32 heads
     (32, 640, 512, 256),     #   ... a 2,048-token chunk's 16 slots
     (32, 640, 64, 256),      # a tile of 64 fused rows
+    (32, 640, 32, 256),      # the decode kernel: a sequence's 32 heads,
+    (32, 640, 8, 256),       #   ... a tp-4 shard's 8
     (32, 640, 2048, 64),     # what a key costs bounds it: 32 B a fused row
     (16, 640, 256, 256),     # a 16-key page
     (48, 640, 256, 192),     # a page that is no power of two
@@ -268,6 +270,11 @@ def test_key_block_is_a_function_of_shapes(bs, F, rows, want):
     assert kb == want and kb % bs == 0
     assert A.prefill_key_block(rows // 32 or 1, min(rows, 32), F, 576, bs,
                                mla=True) == kb
+    # The decode kernel picks by the same rule, its rows the heads, and
+    # goes on to 512 keys (tests/test_mla_kernel.py has its table).
+    from llm_d_tpu.ops.pallas.mla_attention import decode_key_block
+    assert decode_key_block(rows, F, bs) == _pick_key_block(bs, F, rows,
+                                                            most=512)
 
 
 def test_key_block_must_be_whole_pages():

@@ -165,7 +165,7 @@ def test_a2a_in_moe_model_forward(mesh):
                                atol=5e-2, rtol=5e-2)
 
 
-def test_a2a_matches_psum_oracle_fast(mesh):
+def test_a2a_matches_psum_oracle_fast(mesh, under_jit):
     """GATING-TIER parity representative (advisor r4): one tiny a2a-vs-psum
     case so a dispatch-math regression cannot merge green; the full sweep
     stays in the slow tier."""
@@ -174,10 +174,10 @@ def test_a2a_matches_psum_oracle_fast(mesh):
                       moe_renormalize=True)
     x, router, w_gate, w_up, w_down = _case(99, 16, 8)
     weights, idx = _route(x, router, cfg)
-    psum = moe_ops.expert_ffn(x, weights, idx, w_gate, w_up, w_down,
-                              mesh=mesh, dispatch="psum")
-    a2a = moe_ops.expert_ffn(x, weights, idx, w_gate, w_up, w_down,
-                             mesh=mesh, dispatch="a2a")
+    psum = under_jit(moe_ops.expert_ffn, x, weights, idx, w_gate, w_up, w_down,
+                     mesh=mesh, dispatch="psum")
+    a2a = under_jit(moe_ops.expert_ffn, x, weights, idx, w_gate, w_up, w_down,
+                    mesh=mesh, dispatch="a2a")
     np.testing.assert_allclose(np.asarray(a2a, np.float32),
                                np.asarray(psum, np.float32),
                                atol=3e-2, rtol=3e-2)

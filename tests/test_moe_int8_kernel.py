@@ -395,7 +395,7 @@ def test_streamed_kernel_eplb_physical_layout():
                                atol=8e-3)
 
 
-def test_streamed_a2a_matches_dequant_a2a(devices):
+def test_streamed_a2a_matches_dequant_a2a(devices, under_jit):
     """Wide-EP per-chunk GEMM through the streamed int8 kernel
     (expert_ffn_a2a with quant payloads sharded over the expert dim)
     == the bf16 dequant a2a path — the prefill-regime win carries to
@@ -413,9 +413,9 @@ def test_streamed_a2a_matches_dequant_a2a(devices):
     w = jnp.abs(jax.random.normal(ks[2], (T, k), jnp.float32)) * 0.3
     quant, deq = _rand_quant(ks[3], E, H, I)
 
-    got = moe_ops.expert_ffn_a2a(x, w, idx, None, None, None, mesh,
-                                 quant=quant, interpret=True)
-    want = moe_ops.expert_ffn_a2a(x, w, idx, *deq, mesh)
+    got = under_jit(moe_ops.expert_ffn_a2a, x, w, idx, None, None, None, mesh,
+                    quant=quant, interpret=True)
+    want = under_jit(moe_ops.expert_ffn_a2a, x, w, idx, *deq, mesh)
     scale = float(jnp.max(jnp.abs(np.asarray(want, np.float32)))) + 1e-9
     np.testing.assert_allclose(np.asarray(got, np.float32) / scale,
                                np.asarray(want, np.float32) / scale,

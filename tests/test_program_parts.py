@@ -466,7 +466,9 @@ def _new_metrics():
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     new = [m for m in bench["per_layer"] if m["name"].startswith(
         ("device_part_share.", "moe_", "mla_"))]
-    assert len(new) == 13 and new == bench["per_layer"][-13:]
+    # Appended together (PR 36); later PRs append after them.
+    first = bench["per_layer"].index(new[0])
+    assert len(new) == 13 and new == bench["per_layer"][first:first + 13]
     return new
 
 

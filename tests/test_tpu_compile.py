@@ -217,6 +217,21 @@ CASES = [
                  id="mla_decode-bf16-16h"),
     pytest.param(functools.partial(_mla_decode, 128),
                  id="mla_decode-bf16-128h"),
+    # ... over key blocks of several pages, at the block and the group
+    # the shapes pick: a tp-4 shard's 8 heads, kanana-2-30b-a3b's 32, and
+    # every head count at the smallest sequence bucket.
+    pytest.param(functools.partial(_mla_decode, 8),
+                 id="mla_decode-bf16-8h"),
+    pytest.param(functools.partial(_mla_decode, 32, L=9),
+                 id="mla_decode-bf16-32h-kanana"),
+    pytest.param(functools.partial(_mla_decode, 8, S=8),
+                 id="mla_decode-bf16-8h-S8"),
+    pytest.param(functools.partial(_mla_decode, 16, S=8),
+                 id="mla_decode-bf16-16h-S8"),
+    pytest.param(functools.partial(_mla_decode, 32, S=8, L=9),
+                 id="mla_decode-bf16-32h-kanana-S8"),
+    pytest.param(functools.partial(_mla_decode, 128, S=8),
+                 id="mla_decode-bf16-128h-S8"),
     pytest.param(functools.partial(_mla_prefill, 16),
                  id="mla_prefill-bf16-16h"),
     pytest.param(functools.partial(_mla_prefill, 128, Q=64),
