@@ -54,7 +54,7 @@ from llm_d_tpu.engine.scheduler import Scheduler, SchedulerOutput
 from llm_d_tpu.engine.step_clock import StepClock
 from llm_d_tpu.models import get_model
 from llm_d_tpu.models.config import (
-    NO_WINDOW, SLIDING, ModelConfig, get_config)
+    FULL, NO_WINDOW, SLIDING, ModelConfig, get_config)
 from llm_d_tpu.ops import sampling as sampling_ops
 from llm_d_tpu.ops.parts import part
 from llm_d_tpu.parallel.mesh import MeshConfig, make_mesh
@@ -86,10 +86,16 @@ def kv_bytes_per_token(layout: Dict[str, int]) -> int:
 
 
 def derive_num_blocks(hbm_budget_bytes: int, layout: Dict[str, int],
-                      num_layers: int, block_size: int) -> int:
+                      num_layers: int, block_size: int,
+                      layers: Optional[Dict[str, int]] = None) -> int:
     """Block-pool sizing: how many paged-KV blocks (one block's rows across
-    all layers and cache buffers) fit a fixed HBM budget."""
-    block_bytes = num_layers * block_size * kv_bytes_per_token(layout)
+    all layers and cache buffers) fit a fixed HBM budget.  ``layers``: the
+    layers each buffer holds rows of, where not all hold ``num_layers``
+    (buffers by layer kind: the model's ``kv_cache_layers``)."""
+    if layers is None:
+        layers = dict.fromkeys(layout, num_layers)
+    block_bytes = block_size * 2 * sum(
+        layers[name] * width for name, width in layout.items())
     return max(hbm_budget_bytes // block_bytes, 2)
 
 
@@ -225,7 +231,7 @@ class EngineCore:
             derived = dp * derive_num_blocks(
                 config.kv_cache_hbm_bytes,
                 get_model(c).kv_cache_layout(c), c.num_layers,
-                config.block_size)
+                config.block_size, get_model(c).kv_cache_layers(c))
             logger.info(
                 "kv pool auto-sized: %d blocks (%.2f GiB/device budget"
                 ", dp=%d)", derived, config.kv_cache_hbm_bytes / 2**30, dp)
@@ -304,6 +310,7 @@ class EngineCore:
         self._disabled_seen: set = set()
         self._check_block_diffusion()
         self._check_recurrent_state()
+        self._check_layer_kinds()
         # llmd-trace: engine phase spans (queue/prefill/decode + step
         # boundaries).  Everything recorded here is host-side clock
         # arithmetic materialized AFTER the jitted dispatch — tracing can
@@ -410,19 +417,20 @@ class EngineCore:
         # shard owns slots_local = num_slots/dp rows — per-device KV
         # capacity scales 1/dp, the wide-EP memory profile.
         specs = self.model.kv_cache_spec(c)
+        planes = self.model.kv_cache_layers(c)
         if self.dp > 1:
             slots_local = num_slots // self.dp
             # Allocated sharded (device=): the whole pool never lands on
             # the first device on its way to the mesh.
             self.kv_cache = {
                 name: jnp.zeros(
-                    (self.dp, c.num_layers, slots_local, width), jnp.bfloat16,
+                    (self.dp, planes[name], slots_local, width), jnp.bfloat16,
                     device=NamedSharding(self.mesh, P("dp", *specs[name])))
                 for name, width in layout.items()}
         else:
             self.kv_cache = {
                 name: jnp.zeros(
-                    (c.num_layers, num_slots, width), jnp.bfloat16,
+                    (planes[name], num_slots, width), jnp.bfloat16,
                     device=NamedSharding(self.mesh, specs[name]))
                 for name, width in layout.items()}
         self._replicated = NamedSharding(self.mesh, P())
@@ -636,6 +644,37 @@ class EngineCore:
                     f"{feature} requested but unavailable ({blocker}): "
                     f"refusing to start")
 
+    def _check_layer_kinds(self) -> None:
+        """What an MLA stack of two layer kinds, a learned key selection or
+        a share of the routed experts cannot be served with is refused
+        here, at start-up: only the classic step path (run ahead included)
+        on one device knows cache buffers by layer kind, and the int8
+        expert kernels assume that every expert is held."""
+        c, cfg = self.model_config, self.config
+        mesh = cfg.mesh
+        sharded = bool(mesh) and max(
+            mesh.tp or 1, mesh.sp or 1, mesh.dp or 1) > 1
+        for mechanism, on, unserved in (
+                ("layer_kinds: cache buffers and head counts by layer kind",
+                 c.mla_by_kind, (
+                     ("multistep", cfg.num_scheduler_steps > 1),
+                     ("spec_decode", self._spec_requested()),
+                     ("sharded_mesh", sharded),
+                     ("kv_offload", cfg.kv_offload_blocks > 0),
+                     ("int8_experts", cfg.quantization == "int8"))),
+                ("expert_share: a slice of the experts under a full-width "
+                 "router",
+                 bool(c.num_local_experts), (
+                     ("sharded_mesh", sharded),
+                     ("eplb", cfg.enable_eplb),
+                     ("int8_experts", cfg.quantization == "int8")))):
+            for feature, asked in unserved if on else ():
+                if asked:
+                    self.metrics.inc_feature_disabled(feature, mechanism)
+                    raise ValueError(
+                        f"{feature} requested but unavailable "
+                        f"({mechanism}): refusing to start")
+
     @property
     def _has_state(self) -> bool:
         return self.model_config.has_recurrent_state
@@ -647,6 +686,15 @@ class EngineCore:
 
     @kv_connector.setter
     def kv_connector(self, connector) -> None:
+        c = self.model_config
+        if connector is not None and c.mla_by_kind:
+            self.metrics.inc_feature_disabled(
+                "kv_transfer", "layer_kinds: the wire carries one row "
+                "width for every layer")
+            raise ValueError(
+                "a KV connector moves rows of one width for every layer; "
+                "this model's cache buffers go by layer kind: refusing to "
+                "attach it")
         if connector is not None and self._has_state:
             # A pull or a PD hand-over moves pages; the recurrent state
             # would stay behind.
@@ -743,9 +791,28 @@ class EngineCore:
         if reason is not None:
             self._disable_feature("pallas_attention", reason)
             return
+        c = self.model_config
+        if c.mla_by_kind:
+            # Layers that see a window are served by XLA, layers that
+            # select keys by a kernel of their own, dense under the
+            # selection (ops/sparse_mla.py, ops/pallas/mla_masked.py): the
+            # tile and key block accounting of the two MLA kernels
+            # describes neither.
+            from llm_d_tpu.ops.pallas import mla_masked
+            for kind in c.mla_layer_kinds or (FULL,):
+                g = c.mla_geometry(kind)
+                reason = ("no MLA kernel takes a window" if g.window
+                          else mla_masked.ineligible_reason(
+                              g.num_heads, g.kv_lora_rank,
+                              -(-c.max_model_len // self.config.block_size)
+                              * self.config.block_size)
+                          if g.index_topk else None)
+                if reason is not None:
+                    self._disable_feature("pallas_attention",
+                                          f"{kind}: {reason}")
+            return
         self._prefill_tile_dims = (
-            self.model_config.num_heads // heads_tp,
-            next(iter(layout.values())) // tp)
+            c.num_heads // heads_tp, next(iter(layout.values())) // tp)
 
     def _spec_blockers(self) -> List[str]:
         """Startup conditions that would force spec decode off.  Empty
@@ -2884,17 +2951,26 @@ class EngineCore:
                   "kv_read_tokens": c.num_layers * full,
                   "kv_held_tokens": c.num_layers * int(ends.sum()),
                   "kv_dead_tokens": 0}
+        def upto(x, w):      # sum of min(p + 1, w) over positions p < x
+            y = np.minimum(x, w)
+            return y * (y + 1) // 2 + (x - y) * w
+
         n_window = c.layer_types.count(SLIDING)
         if n_window:
             w = c.sliding_window
-
-            def upto(x):      # sum of min(p + 1, w) over positions p < x
-                y = np.minimum(x, w)
-                return y * (y + 1) // 2 + (x - y) * w
-            windowed = int((upto(ends) - upto(ends - news)).sum())
+            windowed = int((upto(ends, w) - upto(ends - news, w)).sum())
             counts["kv_read_tokens"] -= n_window * (full - windowed)
             counts["kv_dead_tokens"] = n_window * int(
                 np.maximum(ends - w + 1, 0).sum())
+        if c.index_topk:
+            # Full layers score every visible key (index_pairs) and attend
+            # to the index_topk best of them (kv_selected_tokens).
+            n_full = c.num_layers - n_window
+            k = c.index_topk
+            selected = int((upto(ends, k) - upto(ends - news, k)).sum())
+            counts["kv_read_tokens"] -= n_full * (full - selected)
+            counts["kv_selected_tokens"] = n_full * selected
+            counts["index_pairs"] = n_full * full
         return counts
 
     def _state_counts(self, ends, news) -> Dict[str, int]:
@@ -2919,7 +2995,7 @@ class EngineCore:
         c = self.model_config
         moe_layers = c.num_layers - c.first_dense_layers
         return {"moe_experts_touched": int(touched),
-                "moe_experts_held": moe_layers * c.num_experts,
+                "moe_experts_held": moe_layers * c.num_held_experts,
                 "moe_pairs": tokens * c.num_experts_per_tok * moe_layers}
 
     def _attn_q_counts(self, real: int, layout: BatchLayout) -> Dict[str, int]:
@@ -2928,7 +3004,19 @@ class EngineCore:
         the Pallas kernels' query tiles or the other paths' [S, Q]
         rectangle."""
         slots = layout.S * layout.Q
-        if self._prefill_tile_dims is not None:
+        c = self.model_config
+        if c.mla_by_kind:
+            # ops/sparse_mla.py: a layer that selects and a window layer
+            # each walk tiles of their own; a mean over the layers.
+            from llm_d_tpu.ops import sparse_mla
+            from llm_d_tpu.ops.attention import num_query_tiles
+            n_window = c.layer_types.count(SLIDING)
+            slots = sum(
+                n * num_query_tiles(layout.T, layout.S, min(qt, layout.Q))
+                * min(qt, layout.Q) for n, qt in (
+                    (c.num_layers - n_window, sparse_mla.SELECT_Q_TILE),
+                    (n_window, sparse_mla.WINDOW_Q_TILE))) // c.num_layers
+        elif self._prefill_tile_dims is not None:
             from llm_d_tpu.ops.attention import (
                 num_query_tiles, prefill_q_tile)
             qt = prefill_q_tile(layout.Q, *self._prefill_tile_dims,

@@ -36,6 +36,15 @@ classic path also hands them to its ``llmd.dispatch`` annotation):
   kv_dead_tokens  sum over rows and window layers of those no later query
                   of the row can see: what a pool per layer kind would free
 
+and, for a stack whose full layers select their keys
+(``ModelConfig.index_topk``; ``kv_read_tokens`` then counts what is
+attended to, not what is visible):
+
+  index_pairs         sum over rows and full layers of the visible keys a
+                      query: every (query, key) pair the indexer scores
+  kv_selected_tokens  of those, the pairs the full layers attend to:
+                      min(visible, index_topk) a query
+
 and, for the classic path's steps with prefill tokens
 (``EngineCore._attn_q_counts``):
 

@@ -11,7 +11,7 @@ models the reference's well-lit paths deploy: Qwen3-0.6B
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -24,6 +24,24 @@ NO_WINDOW = 1 << 30
 # confidence passes the threshold if they are at least q, else the q highest.
 DIFFUSION_REMASKING = ("sequential", "low_confidence_static",
                        "low_confidence_dynamic")
+
+
+class MLAGeometry(NamedTuple):
+    """Latent attention as one layer kind runs it (``mla_geometry``)."""
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float
+    window: int            # keys a query sees, itself included; 0 = all
+    index_topk: int        # keys the indexer keeps for a query; 0 = all
+
+    @property
+    def row_width(self) -> int:
+        """The cached row: latent | rotary key, padded to whole lanes."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,6 +95,42 @@ class ModelConfig:
     # RMS norms on the attention and MLP outputs too, before the residual.
     sandwich_norm: bool = False
     embed_scale: float = 1.0                # multiplies the token embedding
+    # --- mixed stacks on the MLA path ---
+    # With ``layer_types`` on an MLA model the SLIDING layers are latent
+    # attention of a geometry of their own (``mla_geometry``): every
+    # ``swa_*`` field left at 0 takes the full layers' value.  Parameter
+    # shapes and cache rows then differ by layer kind, so each kind has its
+    # parameter stacks and its cache buffers (models/moe.py).
+    swa_num_heads: int = 0
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 0.0
+    # The normed query and key-value latents are multiplied by
+    # sqrt(hidden_size / rank), each kind by its own ranks; the cached row
+    # is the rescaled one.
+    mla_lora_rescale: bool = False
+    # sigmoid(h W_g), one scalar a head, multiplies each head's attention
+    # output before o_proj (MLA path; the GQA path's gate is elementwise:
+    # ``attn_output_gate``).
+    attn_head_gate: bool = False
+    # --- learned key selection in FULL MLA layers (0 = attend to all) ---
+    # An indexer scores every visible key for every query from
+    # ``index_n_heads`` small heads on the query latent and one cached index
+    # key a token (``index_head_dim`` wide, a cache buffer of its own), and
+    # the layer attends to the ``index_topk`` best keys only (all of them
+    # while fewer are visible).  ops/sparse_mla.py.
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    # --- one rank's share of a wider expert-parallel deployment ---
+    # The router scores all ``num_experts``; this process holds
+    # ``num_local_experts`` of them (0 = all), ids ``first_local_expert``
+    # onwards, and a token's slots routed elsewhere add nothing here.
+    num_local_experts: int = 0
+    first_local_expert: int = 0
     # --- generation by diffusion over blocks (0 = autoregressive) ---
     # Attention is block-causal (the query at position p sees key j iff
     # j // B <= p // B) and a block of B tokens is generated by denoising
@@ -124,6 +178,44 @@ class ModelConfig:
         return self.kv_lora_rank > 0
 
     @property
+    def num_held_experts(self) -> int:
+        """Routed experts whose weights this process holds."""
+        return self.num_local_experts or self.num_experts
+
+    @property
+    def mla_layer_kinds(self) -> Tuple[str, ...]:
+        """The kinds of an MLA stack whose layers differ, in the order of
+        their cache buffers; empty where one geometry serves every layer."""
+        if not (self.use_mla and self.layer_types):
+            return ()
+        return tuple(k for k in (FULL, SLIDING) if k in self.layer_types)
+
+    @property
+    def mla_by_kind(self) -> bool:
+        """Latent attention that ops/sparse_mla.py serves over cache
+        buffers by layer kind: two geometries, or a selection of keys."""
+        return bool(self.mla_layer_kinds or self.index_topk)
+
+    def mla_geometry(self, kind: str = FULL) -> "MLAGeometry":
+        """Latent attention as the layers of ``kind`` run it."""
+        if kind == SLIDING:
+            def own(name, full):
+                return getattr(self, "swa_" + name) or full
+            return MLAGeometry(
+                own("num_heads", self.num_heads),
+                own("q_lora_rank", self.q_lora_rank),
+                own("kv_lora_rank", self.kv_lora_rank),
+                own("qk_nope_head_dim", self.qk_nope_head_dim),
+                own("qk_rope_head_dim", self.qk_rope_head_dim),
+                own("v_head_dim", self.v_head_dim),
+                own("rope_theta", self.rope_theta),
+                self.sliding_window, 0)
+        return MLAGeometry(
+            self.num_heads, self.q_lora_rank, self.kv_lora_rank,
+            self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim,
+            self.rope_theta, 0, self.index_topk)
+
+    @property
     def has_recurrent_state(self) -> bool:
         """A sequence's state is not keys and values alone."""
         return self.ssm_state_size > 0
@@ -147,6 +239,7 @@ class ModelConfig:
         # a JSON integer: a rope_theta of 1e11 does not fit the int32 a
         # weakly typed Python int becomes inside a traced function.
         object.__setattr__(self, "rope_theta", float(self.rope_theta))
+        object.__setattr__(self, "swa_rope_theta", float(self.swa_rope_theta))
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         kinds = self.layer_types
         if kinds:
@@ -156,11 +249,36 @@ class ModelConfig:
                     f"{SLIDING!r} or {FULL!r}, got {len(kinds)}: {kinds}")
             if SLIDING in kinds and self.sliding_window < 1:
                 raise ValueError("sliding layers need sliding_window >= 1")
-        if self.use_mla and (kinds or self.attn_output_gate
-                             or self.sandwich_norm):
+        if self.use_mla and (self.attn_output_gate or self.sandwich_norm
+                             or not self.rope_on_full_attention):
             raise ValueError(
-                "layer_types, attn_output_gate and sandwich_norm belong to "
-                "the GQA attention block; the MLA path has none of them")
+                "attn_output_gate, sandwich_norm and rope_on_full_attention "
+                "belong to the GQA attention block; the MLA path gates by "
+                "head (attn_head_gate) and has neither of the others")
+        if not self.use_mla and (
+                self.attn_head_gate or self.mla_lora_rescale
+                or self.index_topk
+                or any(getattr(self, f.name) for f in
+                       dataclasses.fields(self)
+                       if f.name.startswith("swa_"))):
+            raise ValueError(
+                "swa_*, attn_head_gate, mla_lora_rescale and index_topk "
+                "belong to the MLA attention block (kv_lora_rank > 0)")
+        if self.index_topk and (self.index_n_heads < 1
+                                or self.index_head_dim < 1
+                                or self.q_lora_rank < 1):
+            raise ValueError(
+                "index_topk needs index_n_heads, index_head_dim and a "
+                "query latent (q_lora_rank) for the indexer to read")
+        E_loc = self.num_local_experts
+        if E_loc or self.first_local_expert:
+            if not (0 < E_loc and 0 <= self.first_local_expert
+                    and self.first_local_expert + E_loc
+                    <= self.num_experts):
+                raise ValueError(
+                    f"experts {self.first_local_expert}.."
+                    f"{self.first_local_expert + E_loc - 1} are no share "
+                    f"of {self.num_experts}")
 
         for name in ("ssm_multipliers", "mlp_multipliers"):
             object.__setattr__(self, name, tuple(getattr(self, name)))
@@ -385,6 +503,25 @@ PRESETS = {
         ssm_out_multiplier=0.09375,
         ssm_multipliers=(0.375, 0.25, 0.1875, 0.5, 0.3125),
         mlp_multipliers=(0.1875, 0.03125)),
+    # Tiny mixed MLA stack for CPU tests: kinds F F S S S F with a leading
+    # dense layer, two latent geometries (heads, ranks, head sizes and rotary
+    # base all differ), a top-k and a window both under the tests' contexts,
+    # headwise gates, rescaled latents, a quarter of 8 experts held.
+    "tiny-sparse-mla": ModelConfig(
+        name="tiny-sparse-mla", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_layers=6, num_heads=4, num_kv_heads=4,
+        rope_theta=10000.0, max_model_len=512, num_experts=8,
+        num_experts_per_tok=2, moe_intermediate_size=96,
+        num_shared_experts=1, first_dense_layers=1, scoring_func="sigmoid",
+        num_local_experts=2, first_local_expert=2,
+        q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16,
+        layer_types=(FULL, FULL, SLIDING, SLIDING, SLIDING, FULL),
+        sliding_window=21, swa_num_heads=2, swa_q_lora_rank=24,
+        swa_kv_lora_rank=48, swa_qk_nope_head_dim=24,
+        swa_qk_rope_head_dim=8, swa_v_head_dim=16, swa_rope_theta=500.0,
+        mla_lora_rescale=True, attn_head_gate=True,
+        index_topk=16, index_n_heads=4, index_head_dim=16),
     # Tiny MLA+MoE config for CPU tests.
     "tiny-mla": ModelConfig(
         name="tiny-mla", vocab_size=512, hidden_size=64,

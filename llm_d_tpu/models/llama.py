@@ -353,6 +353,11 @@ def kv_cache_layout(config: ModelConfig) -> Dict[str, int]:
     return {"k": w, "v": w}
 
 
+def kv_cache_layers(config: ModelConfig) -> Dict[str, int]:
+    """Layers each cache buffer holds rows of: every buffer all of them."""
+    return dict.fromkeys(kv_cache_layout(config), config.num_layers)
+
+
 def kv_cache_spec(config: ModelConfig = None) -> Dict[str, P]:
     """KV cache sharding: folded head dim over tp (per-head D-blocks stay
     contiguous when tp divides num_kv_heads), slots replicated."""

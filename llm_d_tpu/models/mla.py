@@ -17,8 +17,22 @@ What the reference serves on GPUs through vLLM's MLA kernels, TPU-first:
   - One paged buffer ("kv") instead of k+v: the engine builds caches from
     ``kv_cache_layout`` so MLA models literally allocate half the buffers.
 
-RoPE here is the base rotary scheme (YaRN long-context scaling is a
-config-level extension, tracked separately).
+A stack may mix two kinds of latent attention (``ModelConfig.layer_types``
+on an MLA model): FULL layers and SLIDING layers that see a window of keys,
+each kind with its own heads, ranks, head sizes and rotary base
+(``ModelConfig.mla_geometry``), hence its own parameter stacks and cache
+buffers (models/moe.py).  What the block holds besides the projections, each
+by a field of the config and none by a model's name: the latents rescaled by
+sqrt(hidden / rank) (``mla_lora_rescale``), a sigmoid gate a head on the
+output (``attn_head_gate``), a window (``ops.sparse_mla.attend_window``,
+XLA: neither MLA kernel takes one yet), and in FULL layers a learned
+selection of the keys a query attends to (``index_topk``;
+ops/sparse_mla.py, dense under the selection as a mask:
+ops/pallas/mla_masked.py on the TPU) from an index key a token, cached in a
+buffer of its own.
+
+RoPE is the base rotary scheme, rotate-half; the MLA path has no YaRN
+scaling.
 """
 
 from __future__ import annotations
@@ -29,7 +43,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from llm_d_tpu.models.config import ModelConfig
+from llm_d_tpu.models.config import FULL, ModelConfig
 from llm_d_tpu.ops import attention as A
 from llm_d_tpu.ops import layers as L
 from llm_d_tpu.ops.parts import attn_part, part
@@ -37,39 +51,63 @@ from llm_d_tpu.ops.parts import attn_part, part
 Params = Dict[str, Any]
 
 
-def mla_param_shapes(c: ModelConfig, n_layers: int) -> Dict[str, Tuple[int, ...]]:
-    """Stacked-per-layer MLA projection shapes (HF DeepSeek naming).
+def mla_param_shapes(c: ModelConfig, n_layers: int,
+                     kind: str = FULL) -> Dict[str, Tuple[int, ...]]:
+    """Stacked-per-layer MLA projection shapes (HF DeepSeek naming) of the
+    layers of ``kind``.
 
     ``q_lora_rank == 0`` (DeepSeek-V2-Lite) has no query low-rank path:
     a single ``q_proj`` replaces q_a/q_a_norm/q_b."""
-    H = c.num_heads
-    qk = c.qk_nope_head_dim + c.qk_rope_head_dim
+    g = c.mla_geometry(kind)
+    H = g.num_heads
+    qk = g.qk_nope_head_dim + g.qk_rope_head_dim
     shapes: Dict[str, Tuple[int, ...]] = {
         "kv_a_proj": (n_layers, c.hidden_size,
-                      c.kv_lora_rank + c.qk_rope_head_dim),
-        "kv_a_norm": (n_layers, c.kv_lora_rank),
-        "kv_b_proj": (n_layers, c.kv_lora_rank,
-                      H * (c.qk_nope_head_dim + c.v_head_dim)),
-        "o_proj": (n_layers, H * c.v_head_dim, c.hidden_size),
+                      g.kv_lora_rank + g.qk_rope_head_dim),
+        "kv_a_norm": (n_layers, g.kv_lora_rank),
+        "kv_b_proj": (n_layers, g.kv_lora_rank,
+                      H * (g.qk_nope_head_dim + g.v_head_dim)),
+        "o_proj": (n_layers, H * g.v_head_dim, c.hidden_size),
     }
-    if c.q_lora_rank > 0:
+    if g.q_lora_rank > 0:
         shapes.update({
-            "q_a_proj": (n_layers, c.hidden_size, c.q_lora_rank),
-            "q_a_norm": (n_layers, c.q_lora_rank),
-            "q_b_proj": (n_layers, c.q_lora_rank, H * qk),
+            "q_a_proj": (n_layers, c.hidden_size, g.q_lora_rank),
+            "q_a_norm": (n_layers, g.q_lora_rank),
+            "q_b_proj": (n_layers, g.q_lora_rank, H * qk),
         })
     else:
         shapes["q_proj"] = (n_layers, c.hidden_size, H * qk)
+    if c.attn_head_gate:
+        shapes["head_gate"] = (n_layers, c.hidden_size, H)
+    if g.index_topk:
+        Hi, Di = c.index_n_heads, c.index_head_dim
+        shapes.update({
+            "index_q_proj": (n_layers, g.q_lora_rank, Hi * Di),
+            "index_k_proj": (n_layers, c.hidden_size, Di),
+            "index_k_norm": (n_layers, Di),
+            "index_k_norm_bias": (n_layers, Di),
+            "index_w_proj": (n_layers, c.hidden_size, Hi),
+        })
     return shapes
 
 
-def init_mla_params(c: ModelConfig, n_layers: int, key, dt) -> Params:
-    shapes = mla_param_shapes(c, n_layers)
+def init_mla_params(c: ModelConfig, n_layers: int, key, dt,
+                    kind: str = FULL) -> Params:
+    shapes = mla_param_shapes(c, n_layers, kind)
     keys = iter(jax.random.split(key, len(shapes)))
     out: Params = {}
     for name, shape in shapes.items():
         if name.endswith("_norm"):
             out[name] = jnp.ones(shape, dt)
+            if c.mla_lora_rescale and name in ("q_a_norm", "kv_a_norm"):
+                # Random weights only: the norm a rescale follows starts
+                # at the rescale's inverse, so that the latent that comes
+                # out is of order one as in a trained model (at sqrt(10)
+                # times that the softmax of random projections is an
+                # argmax, and every rounding flips it).
+                out[name] = out[name] * (shape[-1] / c.hidden_size) ** 0.5
+        elif name.endswith("_bias"):
+            out[name] = jnp.zeros(shape, dt)
         else:
             out[name] = (jax.random.normal(next(keys), shape, jnp.float32)
                          * (shape[-2] ** -0.5)).astype(dt)
@@ -81,22 +119,28 @@ def mla_attention_block(
     config: ModelConfig,
     x: jax.Array,                 # [T, Hm]
     batch: Dict[str, jax.Array],
-    kv_cache: jax.Array,          # [L, slots, kv_lora_rank + rope] stacked
+    caches: Tuple[jax.Array, ...],   # the kind's buffers, stacked by layer
+                                  # of the kind: the latent rows [L, slots,
+                                  # kv_lora_rank + rope, lane-padded], then
+                                  # the index keys where the kind selects
     block_size: int,
     attn_backend: str,
-    layer: jax.Array,
+    layer: jax.Array,             # plane of the kind's buffers
     mesh=None,                    # multi-device: Pallas runs per tp shard
-) -> Tuple[jax.Array, jax.Array]:
+    kind: str = FULL,
+) -> Tuple[jax.Array, Tuple[jax.Array, ...]]:
     """Weight-absorbed MLA over the paged latent cache.
 
-    Returns (attn_out [T, Hm], kv_cache')."""
+    Returns (attn_out [T, Hm], caches')."""
     c = config
+    g = c.mla_geometry(kind)
     T = x.shape[0]
-    H = c.num_heads
-    nope, rope = c.qk_nope_head_dim, c.qk_rope_head_dim
-    vdim = c.v_head_dim
-    R = c.kv_lora_rank
+    H = g.num_heads
+    nope, rope = g.qk_nope_head_dim, g.qk_rope_head_dim
+    vdim = g.v_head_dim
+    R = g.kv_lora_rank
     F = R + rope
+    kv_cache = caches[0]
 
     with part("attn.proj"):
         # --- queries: low-rank down, norm, up (V3) or direct q_proj
@@ -104,6 +148,8 @@ def mla_attention_block(
         if "q_a_proj" in lp:
             cq = L.rms_norm(L.linear(x, lp["q_a_proj"]), lp["q_a_norm"],
                             c.rms_norm_eps)
+            if c.mla_lora_rescale:
+                cq = cq * (c.hidden_size / g.q_lora_rank) ** 0.5
             q = L.linear(cq, lp["q_b_proj"]).reshape(T, H, nope + rope)
         else:
             q = L.linear(x, lp["q_proj"]).reshape(T, H, nope + rope)
@@ -113,9 +159,11 @@ def mla_attention_block(
         # heads) ---
         kv_a = L.linear(x, lp["kv_a_proj"])                 # [T, R + rope]
         c_kv = L.rms_norm(kv_a[:, :R], lp["kv_a_norm"], c.rms_norm_eps)
+        if c.mla_lora_rescale:
+            c_kv = c_kv * (c.hidden_size / R) ** 0.5
         k_pe = kv_a[:, R:].reshape(T, 1, rope)
 
-        cos, sin = L.rope_cos_sin(batch["positions"], rope, c.rope_theta)
+        cos, sin = L.rope_cos_sin(batch["positions"], rope, g.rope_theta)
         q_pe = L.apply_rope(q_pe, cos, sin)
         k_pe = L.apply_rope(k_pe, cos, sin)[:, 0, :]            # [T, rope]
 
@@ -144,28 +192,93 @@ def mla_attention_block(
             row = jnp.pad(row, ((0, 0), (0, pad)))
             q_eff = jnp.pad(q_eff, ((0, 0), (0, 0), (0, pad)))
 
-    backend = A.resolve_backend(attn_backend)
-    attend = functools.partial(_mla_attend, block_size=block_size,
-                               backend=backend, scale=scale, R=R)
-    ab = {k: batch[k] for k in A.ATTN_BATCH_KEYS if k in batch}
-    if backend == "pallas":
-        # Per tp shard on a multi-device mesh: heads split, the latent
-        # cache replicated (every shard splices the same row into its own
-        # replica).
-        from jax.sharding import PartitionSpec as P
-        heads = P(None, "tp", None)
-        attend = A.manual_over_mesh(
-            attend, mesh,
-            in_specs=(heads, P(), P(), {k: P() for k in ab}, P()),
-            out_specs=(heads, P()))
-    with part(attn_part(batch)):
-        out_lat, kv_cache = attend(q_eff, row, kv_cache, ab, layer)
+    if g.index_topk:
+        out_lat, caches = _mla_attend_selected(
+            lp, c, g, x, cq, q_eff, row, caches, batch, layer,
+            block_size=block_size, backend=A.resolve_backend(attn_backend),
+            scale=scale, cos=cos, sin=sin)
+    elif g.window:
+        from llm_d_tpu.ops import sparse_mla
+        with part(attn_part(batch)):
+            kv_cache = kv_cache.at[layer, batch["slot_mapping"]].set(
+                row.astype(kv_cache.dtype))
+            out_lat = sparse_mla.attend_window(
+                q_eff, kv_cache, batch, g.window, block_size, layer, scale, R)
+        caches = (kv_cache,)
+    else:
+        backend = A.resolve_backend(attn_backend)
+        attend = functools.partial(_mla_attend, block_size=block_size,
+                                   backend=backend, scale=scale, R=R)
+        ab = {k: batch[k] for k in A.ATTN_BATCH_KEYS if k in batch}
+        if backend == "pallas":
+            # Per tp shard on a multi-device mesh: heads split, the latent
+            # cache replicated (every shard splices the same row into its
+            # own replica).
+            from jax.sharding import PartitionSpec as P
+            heads = P(None, "tp", None)
+            attend = A.manual_over_mesh(
+                attend, mesh,
+                in_specs=(heads, P(), P(), {k: P() for k in ab}, P()),
+                out_specs=(heads, P()))
+        with part(attn_part(batch)):
+            out_lat, kv_cache = attend(q_eff, row, kv_cache, ab, layer)
+        caches = (kv_cache,)
 
     # --- absorb W_uv: latent -> per-head value space, then output proj ---
     with part("attn.proj"):
         attn = jnp.einsum("thr,rhv->thv", out_lat,
                           w_uv.astype(jnp.float32)).astype(x.dtype)
-        return L.linear(attn.reshape(T, H * vdim), lp["o_proj"]), kv_cache
+        if "head_gate" in lp:
+            attn = attn * jax.nn.sigmoid(
+                L.linear(x, lp["head_gate"]))[:, :, None]
+        return L.linear(attn.reshape(T, H * vdim), lp["o_proj"]), caches
+
+
+def _mla_attend_selected(lp, c, g, x, cq, q_eff, row, caches, batch, layer,
+                         *, block_size: int, backend: str, scale: float,
+                         cos, sin):
+    """A FULL layer that selects: write the latent row and the index key,
+    score the step's queries against the sequence's cached index keys, keep
+    ``index_topk`` of them a query, attend to those keys
+    (ops/sparse_mla.py): (out_lat [T, H, R] f32, caches')."""
+    from llm_d_tpu.ops import sparse_mla
+    from llm_d_tpu.ops.pallas import mla_masked
+    T = x.shape[0]
+    kv_cache, idx_cache = caches
+    Hi, Di, rope = c.index_n_heads, c.index_head_dim, g.qk_rope_head_dim
+    with part("attn.index"):
+        # The rotary embedding turns the FIRST ``rope`` columns of each
+        # index head and of the index key (DeepSeek-V3.2's indexer).
+        q_idx = L.linear(cq, lp["index_q_proj"]).reshape(T, Hi, Di)
+        q_idx = jnp.concatenate(
+            [L.apply_rope(q_idx[..., :rope], cos, sin), q_idx[..., rope:]],
+            axis=-1)
+        k_idx = L.linear(x, lp["index_k_proj"]).astype(jnp.float32)
+        k_idx = k_idx - jnp.mean(k_idx, axis=-1, keepdims=True)
+        k_idx = (k_idx * jax.lax.rsqrt(
+            jnp.mean(k_idx * k_idx, axis=-1, keepdims=True) + c.rms_norm_eps)
+            * lp["index_k_norm"].astype(jnp.float32)
+            + lp["index_k_norm_bias"].astype(jnp.float32)).astype(x.dtype)
+        k_idx = jnp.concatenate(
+            [L.apply_rope(k_idx[:, None, :rope], cos, sin)[:, 0],
+             k_idx[:, rope:]], axis=-1)                         # [T, Di]
+        w = (jnp.dot(x, lp["index_w_proj"],
+                     preferred_element_type=jnp.float32)
+             * (Hi * Di) ** -0.5)                               # [T, Hi]
+        idx_cache = idx_cache.at[layer, batch["slot_mapping"]].set(
+            k_idx.astype(idx_cache.dtype))
+        chosen = sparse_mla.index_select(
+            q_idx, w, idx_cache, batch, block_size, layer, g.index_topk)
+    with part(attn_part(batch)):
+        kv_cache = kv_cache.at[layer, batch["slot_mapping"]].set(
+            row.astype(kv_cache.dtype))
+        out_lat = sparse_mla.attend_chosen(
+            q_eff, kv_cache, chosen, batch, block_size, layer, scale,
+            g.kv_lora_rank, kernel=backend == "pallas" and (
+                A.pallas_ineligible_reason(block_size, kv_cache.shape[-1])
+                or mla_masked.ineligible_reason(
+                    g.num_heads, g.kv_lora_rank, chosen.shape[-1])) is None)
+    return out_lat, (kv_cache, idx_cache)
 
 
 def _mla_attend(q_eff, row, kv_cache, batch, layer, *,

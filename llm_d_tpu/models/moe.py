@@ -6,6 +6,13 @@ their first ``first_dense_layers`` layers dense, so the stack scans two
 parameter groups: ``dense_layers`` then ``moe_layers`` (the KV cache is one
 [L, slots, KVH*D] buffer split at the boundary).
 
+An MLA stack whose layers are of two kinds (``ModelConfig.mla_layer_kinds``)
+has parameter SHAPES that differ by kind, so each kind has its own pair of
+groups (the SLIDING layers' are ``swa_dense_layers`` / ``swa_moe_layers``)
+and its own cache buffers, stacked over the layers of the kind.  ``forward``
+walks the stack as RUNS of consecutive layers of one group (``layer_runs``),
+one ``lax.scan`` a run; a stack of one kind is the two runs it always was.
+
 This is the model half of the wide-EP path (reference:
 guides/wide-ep-lws/manifests/modelserver/base/decode.yaml:76-132 — EP flags,
 EPLB, DeepEP backends; the engine equivalents live in ``ops.moe``).
@@ -13,13 +20,15 @@ EPLB, DeepEP backends; the engine equivalents live in ``ops.moe``).
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
+
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from llm_d_tpu.models.config import ModelConfig
+from llm_d_tpu.models.config import FULL, SLIDING, ModelConfig
 from llm_d_tpu.models.llama import (  # noqa: F401  (re-exports: the MoE
     # model shares the dense family's logits head and MTP drafter — the
     # drafter reads only embed/lm_head from the target params, which both
@@ -74,6 +83,8 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
     Lm = c.num_layers - Ld
     E, Im = c.num_experts, c.moe_intermediate_size
     Ish = Im * c.num_shared_experts
+    if c.mla_layer_kinds:
+        return _init_params_by_kind(c, key)
     k = iter(jax.random.split(key, 16))
 
     def w(shape, kk):
@@ -121,6 +132,97 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
     return params
 
 
+def _init_params_by_kind(c: ModelConfig, key: jax.Array) -> Params:
+    """An MLA stack of two layer kinds: a dense and a MoE group a kind
+    (``group_name``), each stacked over its own layers, the routed experts
+    held here only (``ModelConfig.num_held_experts``) under a router of
+    full width."""
+    from llm_d_tpu.models.mla import init_mla_params
+    dt = c.jax_dtype
+    E, E_held, Im = c.num_experts, c.num_held_experts, c.moe_intermediate_size
+    Ish = Im * c.num_shared_experts
+    k = iter(jax.random.split(key, 40))
+
+    def w(shape):
+        return (jax.random.normal(next(k), shape, jnp.float32)
+                * (shape[-2] ** -0.5)).astype(dt)
+
+    params: Params = {"embed": w((c.vocab_size, c.hidden_size)),
+                      "final_norm": jnp.ones((c.hidden_size,), dt)}
+    if not c.tie_word_embeddings:
+        params["lm_head"] = w((c.hidden_size, c.vocab_size))
+    for kind in c.mla_layer_kinds:
+        for moe in (False, True):
+            n = sum(run.stop - run.start for run in layer_runs(c)
+                    if (run.kind, run.moe) == (kind, moe))
+            if not n:
+                continue
+            p = init_mla_params(c, n, next(k), dt, kind)
+            p["input_norm"] = jnp.ones((n, c.hidden_size), dt)
+            p["post_attn_norm"] = jnp.ones((n, c.hidden_size), dt)
+            if moe:
+                p.update({
+                    "router": w((n, c.hidden_size, E)).astype(jnp.float32),
+                    "w_gate": w((n, E_held, c.hidden_size, Im)),
+                    "w_up": w((n, E_held, c.hidden_size, Im)),
+                    "w_down": w((n, E_held, Im, c.hidden_size)),
+                })
+                if c.scoring_func == "sigmoid":
+                    p["e_bias"] = jnp.zeros((n, E), jnp.float32)
+                if c.num_shared_experts > 0:
+                    p.update({
+                        "shared_gate": w((n, c.hidden_size, Ish)),
+                        "shared_up": w((n, c.hidden_size, Ish)),
+                        "shared_down": w((n, Ish, c.hidden_size)),
+                    })
+            else:
+                p.update({
+                    "gate_proj": w((n, c.hidden_size, c.intermediate_size)),
+                    "up_proj": w((n, c.hidden_size, c.intermediate_size)),
+                    "down_proj": w((n, c.intermediate_size, c.hidden_size)),
+                })
+            params[group_name(kind, moe)] = p
+    return params
+
+
+def group_name(kind: str, moe: bool) -> str:
+    """The parameter group of the layers of ``kind`` with (``moe``) or
+    without routed experts."""
+    return (("swa_" if kind == SLIDING else "")
+            + ("moe_layers" if moe else "dense_layers"))
+
+
+class LayerRun(NamedTuple):
+    """Consecutive layers of one parameter group."""
+    kind: str        # FULL | SLIDING (FULL where the stack has one geometry)
+    moe: bool
+    start: int       # [start, stop) within the group's stack
+    stop: int
+    layer0: int      # the run's first layer, in the stack
+    plane0: int      # and in the cache buffers of its kind
+
+
+def layer_runs(c: ModelConfig) -> Tuple[LayerRun, ...]:
+    """The stack as runs of consecutive layers of one group.  A stack of
+    one geometry (``mla_layer_kinds`` empty: GQA with or without
+    ``layer_types``, plain MLA) is its dense layers, then its MoE layers."""
+    Ld = c.first_dense_layers
+    if not c.mla_layer_kinds:
+        return (LayerRun(FULL, False, 0, Ld, 0, 0),
+                LayerRun(FULL, True, 0, c.num_layers - Ld, Ld, Ld))
+    runs, seen, planes = [], {}, {}
+    for li, kind in enumerate(c.layer_types):
+        group = (kind, li >= Ld)
+        at = seen.get(group, 0)
+        if runs and (runs[-1].kind, runs[-1].moe) == group:
+            runs[-1] = runs[-1]._replace(stop=at + 1)
+        else:
+            runs.append(LayerRun(*group, at, at + 1, li, planes.get(kind, 0)))
+        seen[group] = at + 1
+        planes[kind] = planes.get(kind, 0) + 1
+    return tuple(runs)
+
+
 def forward(
     params: Params,
     kv_cache: Dict[str, jax.Array],   # {"k","v": [L, slots, KVH*dh]}
@@ -142,15 +244,32 @@ def forward(
     Ld = c.first_dense_layers
     stacked = batch["token_ids"].ndim == 2
     x = embed_tokens(params, batch["token_ids"], c)   # [T, D] / [dp, T_l, D]
-    cache_keys = ("kv",) if c.use_mla else ("k", "v")
-    # Once a step program, outside both layer scans: a block-diffusion
+    cache_keys = tuple(kv_cache_layout(c))
+    kinds = c.mla_layer_kinds or (FULL,)
+    # Once a step program, outside the layer scans: a block-diffusion
     # model's visibility limits, and the query tile list the Pallas prefill
-    # kernels walk in every layer.
+    # kernels walk in every layer (one a kind that they serve: its heads
+    # and row width set the tile).
     with part("tiles"):
-        batch = with_query_tiles(
-            with_block_visibility(batch, c.diffusion_block_length),
-            c.num_heads, kv_cache[cache_keys[0]].shape[-1], attn_backend,
-            mesh, mla=c.use_mla)
+        batch = with_block_visibility(batch, c.diffusion_block_length)
+        if c.use_mla:
+            from llm_d_tpu.ops import sparse_mla
+            batches = {}
+            for kind in kinds:
+                g = c.mla_geometry(kind)
+                if g.index_topk or g.window:
+                    # Served over tiles of its own (ops/sparse_mla.py).
+                    batches[kind] = sparse_mla.with_tiles(
+                        batch, sparse_mla.SELECT_Q_TILE if g.index_topk
+                        else sparse_mla.WINDOW_Q_TILE)
+                else:
+                    batches[kind] = with_query_tiles(
+                        batch, g.num_heads, g.row_width, attn_backend, mesh,
+                        mla=True)
+        else:
+            batches = {FULL: with_query_tiles(
+                batch, c.num_heads, kv_cache[cache_keys[0]].shape[-1],
+                attn_backend, mesh)}
         real = None
         if count_touched:
             # The token bucket's real rows lie first; a padded query slot
@@ -164,26 +283,42 @@ def forward(
     is_decode = batch["qtok_idx"].shape[-1] == 1
     dbo_min_tokens = (moe_opts or {}).get(
         "dbo_decode_min_tokens" if is_decode else "dbo_prefill_min_tokens")
+    # The share of the routed experts held here (None: all of them).
+    held = ((c.first_local_expert, c.num_local_experts)
+            if c.num_local_experts else None)
 
-    def attend_local(lp, hn, caches, ab, li):
-        """Attention dispatch: MLA (single latent buffer) or classic GQA."""
+    def attend_local(lp, hn, caches, ab, li, kind=FULL):
+        """Attention dispatch: MLA (the kind's latent buffers) or classic
+        GQA."""
         if c.use_mla:
             from llm_d_tpu.models.mla import mla_attention_block
-            a, kv = mla_attention_block(
-                lp, c, hn, ab, caches[0], block_size, attn_backend,
-                layer=li, mesh=mesh)
-            return a, (kv,)
+            return mla_attention_block(
+                lp, c, hn, ab, caches, block_size, attn_backend,
+                layer=li, mesh=mesh, kind=kind)
         return attention_block(
             lp, c, hn, ab, caches, block_size, attn_backend, layer=li,
             mesh=mesh)
 
-    def attend(lp, hn, caches, li):
-        """Stacked mode: per-dp-shard attention (manual dp, auto tp) —
-        the dp half of the wide-EP regime; see parallel.dp_attention."""
+    def attend(lp, hn, caches, li, run):
+        """The run's attention over the buffers of its kind, ``li`` the
+        layer in the stack.  Stacked mode: per-dp-shard attention (manual
+        dp, auto tp) — the dp half of the wide-EP regime; see
+        parallel.dp_attention."""
         if stacked:
             from llm_d_tpu.parallel.dp_attention import dp_attend
             return dp_attend(attend_local, mesh, lp, hn, caches, batch, li)
-        return attend_local(lp, hn, caches, batch, li)
+        if not c.mla_layer_kinds:
+            return attend_local(lp, hn, caches, batches[FULL], li)
+        own = [cache_keys.index(name)
+               for name in kind_buffers(c, run.kind)]
+        off = run.layer0 - run.plane0
+        a, new = attend_local(
+            lp, hn, tuple(caches[i] for i in own), batches[run.kind],
+            li - off if off else li, run.kind)
+        caches = list(caches)
+        for i, buf in zip(own, new):
+            caches[i] = buf
+        return a, tuple(caches)
 
     def moe_tokens(hn):
         """[dp, T_l, D] -> [dp*T_l, D] for EP dispatch: the merged token
@@ -193,17 +328,17 @@ def forward(
 
     # Full stacked KV cache rides both scans' carries; each layer updates its
     # plane in place (see models.llama.forward) — no split/concat copies.
-    def dense_body(carry, lp):
+    def dense_body(run, carry, lp):
         h, caches, li = carry
         h, caches = dense_layer(
-            lp, c, h, lambda hn: attend(lp, hn, caches, li))
+            lp, c, h, lambda hn: attend(lp, hn, caches, li, run))
         return (h, caches, li + 1), None
 
-    def moe_body(carry, lp):
+    def moe_body(run, quant_stacked, held_stacked, carry, lp):
         h, caches, li = carry
         with part("attn.proj"):
             hn = L.rms_norm(h, lp["input_norm"], c.rms_norm_eps)
-        a, caches = attend(lp, hn, caches, li)
+        a, caches = attend(lp, hn, caches, li, run)
         with part("router"):
             h = h + a
             hn = L.rms_norm(h, lp["post_attn_norm"], c.rms_norm_eps)
@@ -211,8 +346,11 @@ def forward(
             weights, idx = moe_ops.route(
                 jnp.dot(ht.astype(jnp.float32), lp["router"]), c,
                 e_bias=lp.get("e_bias"))
-            touched = (moe_ops.experts_touched(idx, real, c.num_experts)
-                       if real is not None else None)
+            # Of the experts HELD: ids elsewhere match none of them.
+            touched = (moe_ops.experts_touched(
+                idx - c.first_local_expert if c.first_local_expert else idx,
+                real, c.num_held_experts)
+                if real is not None else None)
             phys_idx = idx
             if "replica_table" in lp:
                 # EPLB: route to a physical replica of the logical expert
@@ -233,14 +371,23 @@ def forward(
             # moe_routed_stream.py), and the chunk-streamed kernel per
             # dispatch chunk on the a2a EP mesh path.
             quant = dict(quant_stacked, layer=li - Ld)
-            w_gate = w_up = w_down = None
-        else:
+            w_gate = w_up = w_down = held_plane = None
+        elif held_stacked is not None:
+            # A share's bf16 experts travel STACKED too, with the layer's
+            # plane in its group: the grouped product's operands are
+            # buffers, and a scan slice handed to it is a copy of the
+            # layer's experts (ops.moe._held_expert_ffn).
             quant = None
+            w_gate, w_up, w_down = held_stacked
+            held_plane = li - (run.layer0 - run.start)
+        else:
+            quant = held_plane = None
             w_gate, w_up, w_down = lp["w_gate"], lp["w_up"], lp["w_down"]
         with part("experts"):
             m = moe_ops.expert_ffn(
                 ht, weights, phys_idx, w_gate, w_up, w_down, mesh=mesh,
-                dbo_min_tokens=dbo_min_tokens, quant=quant)
+                dbo_min_tokens=dbo_min_tokens, quant=quant, held=held,
+                held_plane=held_plane)
             if stacked:
                 m = m.reshape(hn.shape)
         if "shared_gate" in lp:
@@ -258,20 +405,43 @@ def forward(
                 "x": ht, "weights": weights, "idx": phys_idx}, touched)
         return (h, caches, li + 1), (idx, touched)
 
-    ml = params["moe_layers"]
     quant_keys = ("w_gate_q", "w_gate_s", "w_up_q", "w_up_s",
                   "w_down_q", "w_down_s")
-    quant_stacked = ({k: ml[k] for k in quant_keys}
-                     if "w_gate_q" in ml else None)
-    moe_scan_params = ({k: v for k, v in ml.items() if k not in quant_keys}
-                       if quant_stacked is not None else ml)
-
-    caches0 = tuple(kv_cache[k] for k in cache_keys)
+    held_keys = ("w_gate", "w_up", "w_down")
+    carry = (x, tuple(kv_cache[k] for k in cache_keys), jnp.int32(0))
+    per_moe_layer = []
     with part("scan"):
-        (x, caches, li), _ = jax.lax.scan(
-            dense_body, (x, caches0, jnp.int32(0)), params["dense_layers"])
-        (x, caches, _), (routed, touched) = jax.lax.scan(
-            moe_body, (x, caches, li), moe_scan_params)
+        for run in layer_runs(c):
+            gp = params[group_name(run.kind, run.moe)]
+            if not run.moe:
+                body = functools.partial(dense_body, run)
+            else:
+                quant_stacked = ({k: gp[k] for k in quant_keys}
+                                 if "w_gate_q" in gp else None)
+                if quant_stacked is not None:
+                    gp = {k: v for k, v in gp.items() if k not in quant_keys}
+                held_stacked = None
+                if held is not None and quant_stacked is None:
+                    held_stacked = tuple(gp[k] for k in held_keys)
+                    gp = {k: v for k, v in gp.items() if k not in held_keys}
+                body = functools.partial(moe_body, run, quant_stacked,
+                                         held_stacked)
+            n = jax.tree.leaves(gp)[0].shape[0]
+            if (run.start, run.stop) == (0, n):
+                carry, ys = jax.lax.scan(body, carry, gp)
+            else:
+                # Some layers of the group: the body takes its layer's
+                # slices from the whole stacks, which stay where they are.
+                carry, ys = jax.lax.scan(
+                    lambda cr, i, body=body, gp=gp: body(
+                        cr, jax.tree.map(lambda a: a[i], gp)),
+                    carry, jnp.arange(run.start, run.stop))
+            if run.moe:
+                per_moe_layer.append(ys)
+    x, caches, _ = carry
+    routed, touched = (
+        per_moe_layer[0] if len(per_moe_layer) == 1 else jax.tree.map(
+            lambda *a: jnp.concatenate(a), *per_moe_layer))
 
     out = (sampled_hidden(params, x, batch, c, stacked),
            dict(zip(cache_keys, caches)))
@@ -309,6 +479,16 @@ def sharding_rules(config: ModelConfig):
     return rules
 
 
+def kind_buffers(config: ModelConfig, kind: str = FULL) -> Tuple[str, ...]:
+    """The cache buffers the layers of ``kind`` read and write, the latent
+    rows first."""
+    if not config.use_mla:
+        return ("k", "v")
+    if kind == SLIDING and config.mla_layer_kinds:
+        return ("kv_swa",)
+    return ("kv", "idx") if config.index_topk else ("kv",)
+
+
 def kv_cache_layout(config: ModelConfig) -> Dict[str, int]:
     """Per-buffer cache row widths.  MLA caches ONE latent row per token
     (kv_lora_rank + rope, lane-padded) — for V3 that is 640 values vs
@@ -319,16 +499,36 @@ def kv_cache_layout(config: ModelConfig) -> Dict[str, int]:
     +11%): the Pallas decode kernel's page DMAs need the alignment, zero
     columns are score-neutral (models/mla.py), and deriving the width
     from config alone keeps the PD KV-transfer wire format identical
-    across backends (a CPU prefiller can feed a TPU decoder)."""
+    across backends (a CPU prefiller can feed a TPU decoder).
+
+    An MLA stack of two kinds has a latent buffer a kind, each as wide as
+    the kind's own row, and layers that select keys an index key buffer
+    beside theirs; ``kv_cache_layers`` says how many layers each holds."""
     if config.use_mla:
-        w = config.kv_lora_rank + config.qk_rope_head_dim
-        return {"kv": -(-w // 128) * 128}
+        layout = {}
+        for kind in config.mla_layer_kinds or (FULL,):
+            g = config.mla_geometry(kind)
+            names = kind_buffers(config, kind)
+            layout[names[0]] = g.row_width
+            if len(names) > 1:
+                layout[names[1]] = config.index_head_dim
+        return layout
     return {"k": config.num_kv_heads * config.head_dim_,
             "v": config.num_kv_heads * config.head_dim_}
+
+
+def kv_cache_layers(config: ModelConfig) -> Dict[str, int]:
+    """Layers each cache buffer holds rows of: all of them, or where the
+    buffers go by layer kind the layers of the buffer's kind."""
+    kinds = config.mla_layer_kinds
+    if not kinds:
+        return dict.fromkeys(kv_cache_layout(config), config.num_layers)
+    return {name: config.layer_types.count(kind) for kind in kinds
+            for name in kind_buffers(config, kind)}
 
 
 def kv_cache_spec(config: Optional[ModelConfig] = None) -> Dict[str, P]:
     if config is not None and config.use_mla:
         # The latent row is shared by all (tp-sharded) heads: replicate.
-        return {"kv": P()}
+        return dict.fromkeys(kv_cache_layout(config), P())
     return {"k": P(None, None, "tp"), "v": P(None, None, "tp")}

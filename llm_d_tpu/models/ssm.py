@@ -32,7 +32,7 @@ from llm_d_tpu.models import llama
 from llm_d_tpu.models.config import ModelConfig
 from llm_d_tpu.models.llama import (  # noqa: F401  (the model interface)
     Params, compute_logits, draft_propose, init_draft_params,
-    kv_cache_layout, kv_cache_spec, sharding_rules)
+    kv_cache_layers, kv_cache_layout, kv_cache_spec, sharding_rules)
 from llm_d_tpu.ops import layers as L
 from llm_d_tpu.ops import ssm as ssm_ops
 from llm_d_tpu.ops.attention import with_query_tiles

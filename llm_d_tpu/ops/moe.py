@@ -156,6 +156,58 @@ def _local_expert_ffn(
     return _unsort_combine(y * wslot, order, T, k, inv=inv)
 
 
+def _held_expert_ffn(
+    x: jax.Array,          # [T, H]
+    weights: jax.Array,    # [T, k] combine weights
+    idx: jax.Array,        # [T, k] expert ids over the router's width
+    w_gate: jax.Array,     # [E_held, H, I] experts e0 .. e0 + E_held - 1,
+    w_up: jax.Array,       # or with ``plane`` the layers' stacks
+    w_down: jax.Array,     # [L, E_held, ...]
+    e0: int,
+    plane: Optional[jax.Array] = None,
+) -> jax.Array:            # [T, H] f32: the held experts' part of the sum
+    """One rank's share of the routed experts on one device: the slots
+    routed to held experts, sorted by expert, go through the grouped
+    product; the rest (at 8 slots a token over 8 ranks, 7 of 8) lie past
+    the last group and are not multiplied by anything.  The product is
+    handed all the sorted slots, as many as the router may send here; what
+    it costs goes by the experts' bytes, not by the rows (on the v5e, one
+    layer's call at 2,048 tokens, 32 of 256 experts of 5120 x 1536: 10.0
+    ms, and 8.8 when handed only the first quarter of the slots, which a
+    conditional on the count chose until PR 39's review: 2 % of a step for
+    a second compiled body).
+
+    ``plane``: the weights are whole stacks over layers.  The grouped
+    product's operands are buffers: a layer's slice handed to it is a copy
+    of the layer's experts a step (compiled for a described v5e: 2.8 GiB of
+    temporaries at 32 experts of 5120 x 1536, 1.4 GB copied a layer), the
+    whole stack is the parameter itself."""
+    T = x.shape[0]
+    k = idx.shape[1]
+    E_held = w_gate.shape[-3]
+    S = T * k
+    lid = idx.reshape(S) - e0
+    is_held = (lid >= 0) & (lid < E_held)
+    order, inv, counts = _stable_argsort_bounded(
+        jnp.where(is_held, lid, E_held), E_held + 1)
+    group_sizes = counts[:E_held]
+    if plane is not None:
+        # Every layer's experts are groups of ONE grouped product, all
+        # empty but this layer's: the stacks are its operands as they lie
+        # (a reshape), no layer's slice is copied out.
+        w_gate, w_up, w_down = (w.reshape((-1,) + w.shape[2:])
+                                for w in (w_gate, w_up, w_down))
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((w_gate.shape[0],), group_sizes.dtype), group_sizes,
+            (plane * E_held,))
+    y = _swiglu_grouped(x[order // k], w_gate, w_up, w_down,
+                        group_sizes)                              # [S, H] f32
+    held_sorted = is_held[order]
+    y = jnp.where(held_sorted[:, None],
+                  y * weights.reshape(S)[order][:, None], 0.0)
+    return _unsort_combine(y, order, T, k, inv=inv)
+
+
 def _unsort_combine(y: jax.Array, order: jax.Array, T: int, k: int,
                     dest: Optional[jax.Array] = None,
                     inv: Optional[jax.Array] = None) -> jax.Array:
@@ -836,8 +888,19 @@ def expert_ffn(
     dbo_min_tokens: Optional[int] = None,   # DBO: force >= 2 chunks at this T
     quant: Optional[dict] = None,   # int8 payloads {w_gate_q, w_gate_s, ...}
     collective_dtype: Optional[str] = None,  # None -> LLMD_COLLECTIVE_DTYPE
+    held: Optional[Tuple[int, int]] = None,  # (first id, count): the share
+                                             # of the experts the weights
+                                             # hold; None = all of them
+    held_plane: Optional[jax.Array] = None,  # the weights are stacks over
+                                             # layers: this layer's plane
 ) -> jax.Array:            # [T, H] in x.dtype
     """Routed-expert FFN, expert-parallel over the flattened mesh.
+
+    ``held`` (single device, bf16 weights): ``idx`` ranges over the
+    router's whole width and the weights hold experts ``first id`` ..
+    ``first id + count - 1`` only, one rank's share of a wider
+    expert-parallel deployment; slots routed elsewhere add nothing and
+    cost no expert FLOPs (``_held_expert_ffn``).
 
     Single-device: dense all-experts batched GEMM below
     ``DENSE_DISPATCH_MAX_T`` tokens (decode regime — see
@@ -859,6 +922,14 @@ def expert_ffn(
     per dispatch chunk; every other path dequantizes here, which is
     numerically identical to dequantizing in the model.
     """
+    if held is not None:
+        if quant is not None or not (mesh is None or mesh.devices.size == 1):
+            raise ValueError(
+                "a share of the experts is served on one device from bf16 "
+                "weights: the int8 kernels and the mesh paths assume that "
+                "every expert is held")
+        return _held_expert_ffn(x, weights, idx, w_gate, w_up, w_down,
+                                held[0], held_plane).astype(x.dtype)
     if mesh is None or mesh.devices.size == 1:
         if dispatch == "auto":
             dispatch = os.environ.get("LLMD_MOE_DISPATCH", "auto")

@@ -17,6 +17,9 @@ operation, whatever kernel or fusion implements the part.
   attn.decode   the cache write and the attention of a program whose query
   attn.prefill  width is 1 (pure decode), or wider (prefill, mixed): chosen
                 at trace time, kernel and XLA glue or the chunked XLA path
+  attn.index    a layer that selects its keys (ops/sparse_mla.py): the
+                indexer's projections, the index key's norm and cache write,
+                the scores against the cached index keys and the top-k
   router        the router's dot, the top-k, the count of experts touched,
                 and the norm that feeds the MoE block
   experts       ``ops.moe.expert_ffn``: every kernel of the family and its
@@ -42,7 +45,7 @@ import jax
 
 PREFIX = "llmd."
 PARTS = ("embed", "tiles", "attn.proj", "attn.decode", "attn.prefill",
-         "router", "experts", "shared", "mlp", "ssm.proj", "ssm.state",
+         "attn.index", "router", "experts", "shared", "mlp", "ssm.proj", "ssm.state",
          "scan", "head", "sample")
 
 

@@ -224,11 +224,12 @@ def _catalog_row(name):
 
 def test_falcon_cell_and_its_files():
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    cell = bench["workloads"][-1]
+    cell = next(w for w in bench["workloads"]
+                if w["name"] == "falcon-h1.batch")
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         "falcon-h1.batch", "falcon-h1-34b", "batch", 1)
     assert len(cell["why"]) <= 200
-    entry = bench["configs"][-1]
+    entry = next(c for c in bench["configs"] if c["name"] == "falcon-h1-34b")
     conf = json.loads((REPO / entry["file"]).read_text())
     assert entry["name"] == conf["name"] == "falcon-h1-34b"
     assert entry["reduced"] == conf["reduced"] == ["num_hidden_layers"]
@@ -406,3 +407,185 @@ def test_mla_key_fill_share_is_a_data_file(name, moves, counts):
         "denominator": [f"{counts}_slots"]}
     moved = next(m for m in bench["end_to_end"] if m["name"] == entry["moves"])
     assert set(entry["workloads"]) <= set(moved["workloads"])
+
+
+# ---------------------------------------------------------------------------
+# dots3-note-prev and dots3.longdoc (PR 39)
+# ---------------------------------------------------------------------------
+
+DOTS_METRICS = ["device_idle_share.dots3", "device_part_share.index",
+                "device_part_share.experts.dots3", "dsa_selected_share",
+                "dsa_index_roofline_share", "mla_sparse_roofline_share"]
+
+
+def test_dots3_cell_and_its_files():
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    cell = bench["workloads"][-1]
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
+        "dots3.longdoc", "dots3-note-prev", "longdoc", 1)
+    assert len(cell["why"]) <= 200
+    entry = bench["configs"][-1]
+    conf = json.loads((REPO / entry["file"]).read_text())
+    assert entry["name"] == conf["name"] == "dots3-note-prev"
+    assert entry["reduced"] == conf["reduced"] == [
+        "num_hidden_layers", "layer_types", "n_routed_experts", "vocab_size"]
+    assert entry["source"] == conf["source"] and len(entry["why"]) <= 200
+    assert conf["reference"] == "dots3_note" == conf["model_type"]
+    assert "--quantization" not in conf["serve_args"]       # bf16 as published
+    assert conf["num_hidden_layers"] in (5, 6)              # floor 4 + dense
+    assert len(conf["layer_types"]) == conf["num_hidden_layers"]
+    assert conf["published"]["n_routed_experts"] == conf["router_experts"] == 256
+    assert conf["published"]["vocab_size"] == 152064
+    assert conf["published"]["num_hidden_layers"] == 46
+    for key in ("reduced_why", "assumed", "deployment", "memory_account"):
+        assert conf[key], key
+    assert {"lora_rescale", "headwise_gate", "indexer"} <= set(conf["assumed"])
+    chk = conf["correctness"]
+    assert min(chk["prompt_lens"]) < 2048 < sorted(chk["prompt_lens"])[1]
+    assert max(chk["prompt_lens"]) >= 6000
+    mix = json.loads((BENCH / "traffic" / "longdoc.json").read_text())
+    assert (mix["loop"], mix["clients"]) == ("closed", 16)
+    assert mix["clients"] == int(conf["serve_args"][
+        conf["serve_args"].index("--max-num-seqs") + 1])
+    assert mix["prompt_tokens"] == {"dist": "loguniform", "min": 4096,
+                                    "max": 16384}
+    assert mix["output_tokens"] == {"dist": "lognormal", "median": 128,
+                                    "sigma": 0.5, "min": 32, "max": 256}
+    assert (mix["shared_prefix_tokens"], mix["warmup_seconds"],
+            mix["drain_seconds"]) == (0, 20, 60)
+    assert "sessions" not in mix and "order_seed" in mix
+    # every prompt is 2 to 8 times index_topk
+    assert mix["prompt_tokens"]["min"] == 2 * conf["index_topk"]
+    per_layer = {m["name"]: m for m in bench["per_layer"]}
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names[-len(DOTS_METRICS):] == DOTS_METRICS   # appended, in order
+    for name in DOTS_METRICS:
+        assert per_layer[name]["workloads"] == ["dots3.longdoc"]
+        d = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())
+        assert (BENCH / "readers" / f"{d['reader']}.py").exists()
+    # the accepted lists are as the parent has them
+    assert per_layer["device_part_share.experts"]["workloads"] == [
+        "qwen3moe.chat", "kanana2.batch", "trinity-mini.docqa", "sdar.batch"]
+    assert per_layer["device_idle_share.falcon"]["workloads"] == [
+        "falcon-h1.batch"]
+
+
+def test_dots3_config_holds_every_published_key():
+    row = _catalog_row("dots3-note-prev")
+    conf = json.loads((BENCH / "configs" / "dots3-note-prev.json").read_text())
+    assert conf["source"] == row["source_url"]
+    for key, value in row["config"].items():
+        if key not in conf["reduced"]:
+            assert conf[key] == value, key
+    assert conf["layer_types"] == row["config"]["layer_types"][
+        :conf["num_hidden_layers"]]
+    assert row["config"]["n_routed_experts"] == 8 * conf["n_routed_experts"]
+    assert row["config"]["vocab_size"] == 8 * conf["vocab_size"]
+
+
+@pytest.mark.parametrize("rehearse", [False, True])
+def test_dots3_config_maps_onto_the_program(rehearse):
+    import modelcfg
+    from llm_d_tpu.models import get_model
+    from llm_d_tpu.models.config import FULL, SLIDING, ModelConfig
+    conf = json.loads((BENCH / "configs" / "dots3-note-prev.json").read_text())
+    mc = ModelConfig(**modelcfg.model_config_fields(conf, rehearse))
+    model = get_model(mc)
+    assert model.__name__.endswith("models.moe") and mc.use_mla
+    assert mc.mla_layer_kinds == (FULL, SLIDING)
+    assert mc.layer_types == tuple(conf["layer_types"])
+    assert mc.attn_head_gate and mc.mla_lora_rescale
+    assert mc.first_dense_layers == 1 and mc.first_local_expert == 0
+    if not rehearse:
+        assert (mc.num_experts, mc.num_held_experts,
+                mc.num_experts_per_tok) == (256, 32, 8)
+        full, swa = mc.mla_geometry(FULL), mc.mla_geometry(SLIDING)
+        assert full == (128, 1024, 512, 128, 64, 128, 8e7, 0, 2048)
+        assert swa == (64, 1024, 1024, 192, 64, 128, 5e4, 513, 0)
+        assert model.kv_cache_layout(mc) == {"kv": 640, "idx": 128,
+                                             "kv_swa": 1152}
+        assert model.kv_cache_layers(mc) == {"kv": 3, "idx": 3, "kv_swa": 3}
+        assert (mc.index_n_heads, mc.index_head_dim) == (64, 128)
+        assert mc.vocab_size == 19008 and mc.max_model_len == 32768
+
+
+def test_dsa_work_counts_real_pairs():
+    import dsawork
+    from readers import dsa_roofline, scope_share
+    conf = json.loads((BENCH / "configs" / "dots3-note-prev.json").read_text())
+    assert dsawork.layers(conf) == (3, 3)
+    peaks = json.loads((BENCH / "peaks.json").read_text())["TPU v5 lite"]
+    zero = dict.fromkeys(dsawork.COUNTS, 0)
+    # one decode row at a context of 8,192 in 3 + 3 layers
+    dec = {"index_pairs": 3 * 8192, "kv_selected_tokens": 3 * 2048,
+           "kv_read_tokens": 3 * 2048 + 3 * 513, "kv_held_tokens": 6 * 8192}
+    counts = {"decode": dec, "prefill": zero}
+    flops = 3 * 8192 * 64 * 128 * 2
+    key_bytes = 3 * 8192 * 128 * 2
+    assert dsawork.index(conf, counts, peaks) == max(
+        flops / peaks["bf16_flops"], key_bytes / peaks["hbm_bytes_per_s"])
+    row_bytes = 3 * 2048 * 576 * 2 + 3 * 513 * 1088 * 2
+    pair_flops = (3 * 2048 * 128 * (2 * 576 + 2 * 512)
+                  + 3 * 513 * 64 * (2 * 1088 + 2 * 1024))
+    assert dsawork.sparse_attention(conf, counts, peaks) == max(
+        pair_flops / peaks["bf16_flops"],
+        row_bytes / peaks["hbm_bytes_per_s"])
+    # a prefill chunk is held to its dots alone
+    counts = {"decode": zero, "prefill": dec}
+    assert dsawork.sparse_attention(conf, counts, peaks) == (
+        pair_flops / peaks["bf16_flops"])
+    # without a trace a reader reads nothing (the parent, a CPU rehearsal)
+    assert dsa_roofline.read({"trace": None}, "index", ["llmd.attn.index"],
+                             "dots3-note-prev") is None
+    assert scope_share.read({"trace": None}, ["llmd.attn.index"]) is None
+
+
+def test_dots3_cell_rehearses_on_the_cpu():
+    """``run.py --rehearse --workload dots3.longdoc --trace 1``: the
+    harness's whole path (server, load generator, the checks (a)-(d)
+    against ``references/dots3_note.py``) at the tiny preset; the new span
+    attributes feed ``dsa_selected_share``."""
+    import os
+    import subprocess
+    out = subprocess.run(
+        [sys.executable, str(BENCH / "run.py"), "--workload",
+         "dots3.longdoc", "--seed", str(2**31 + 3939), "--seconds", "4",
+         "--trace", "1", "--rehearse"],
+        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), timeout=600)
+    assert out.returncode == 0
+    last = json.loads(out.stdout.strip().splitlines()[-1])
+    assert last["correct"] is True and last["failed"] == 0
+    assert last["attempted"] > 0
+    got = {k.removeprefix("cpu_rehearsal."): v["value"]
+           for k, v in last["metrics"].items()}
+    assert {"dsa_selected_share", "step_ms.mixed", "attn_query_fill_share",
+            "prefix_hit_share", "queue_wait_p95_ms"} <= set(got)
+    # prompts of 40-150 tokens at a top-k of 16
+    assert 10.0 < got["dsa_selected_share"] < 60.0
+    # device metrics are read from a device trace only
+    assert not {"device_part_share.index", "dsa_index_roofline_share",
+                "mla_sparse_roofline_share",
+                "device_idle_share.dots3"} & set(got)
+
+
+def test_dsa_mechanism_check_names_each_fault():
+    import references.dots3_note as ref
+    import dsa_mechanism_check
+    faults = {f for _, f in dsa_mechanism_check.WRONG if f}
+    assert faults == {"no_rescale", "no_gate", "dense_full",
+                      "window_plus_one", "int8_weights", "int8_kv"}
+    assert dsa_mechanism_check.WRONG[0] == ("as published", None)
+    assert dsa_mechanism_check.MUST_REFUSE <= faults
+    assert ref.FAULTS == set()          # nothing wrong in a served comparison
+    tol = json.loads((BENCH / "configs" / "dots3-note-prev.json").read_text())[
+        "correctness"]["reference_tolerance"]
+    # PR 39, on the chip: twenty seeds served (median to 0.0559, p90 to
+    # 0.2422) pass both limits, and each of the ten int8-weights readings
+    # (median, p90) is refused by one of them
+    assert 0.0559 < tol["median"] and 0.2422 < tol["p90"]
+    int8 = [(0.1069, 0.3207), (0.0842, 0.3781), (0.1098, 0.3424),
+            (0.0822, 0.1833), (0.0764, 0.3402), (0.1024, 0.3018),
+            (0.1077, 0.3600), (0.0984, 0.3700), (0.1095, 0.3290),
+            (0.0705, 0.3177)]
+    assert all(m > tol["median"] or p > tol["p90"] for m, p in int8)

@@ -476,6 +476,6 @@ def test_mla_seq_group_env_non_divisor_degrades_to_auto(monkeypatch):
     x = jnp.asarray(np.random.default_rng(0).standard_normal(
         (S, c.hidden_size)), jnp.bfloat16)
     out, _ = mla_mod.mla_attention_block(
-        lp, c, x, batch, kv, bs, "pallas", layer=jnp.int32(0))
+        lp, c, x, batch, (kv,), bs, "pallas", layer=jnp.int32(0))
     assert out.shape == (S, c.hidden_size)
     assert seen["seq_group"] is None       # non-divisor degraded to auto

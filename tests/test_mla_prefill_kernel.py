@@ -325,6 +325,6 @@ def test_mla_model_routes_prefill_to_kernel(monkeypatch):
     x = jnp.asarray(np.random.default_rng(0).standard_normal(
         (T, c.hidden_size)), jnp.bfloat16)
     out, _ = mla_mod.mla_attention_block(
-        lp, c, x, batch, kv, bs, "pallas", layer=jnp.int32(0))
+        lp, c, x, batch, (kv,), bs, "pallas", layer=jnp.int32(0))
     assert calls.get("hit"), "prefill batch did not reach the MLA kernel"
     assert out.shape == (T, c.hidden_size)

@@ -176,7 +176,10 @@ def test_decode_and_mixed_attention_are_told_apart(family, kind):
 def test_the_vocabulary_is_the_one_the_reader_groups():
     grouped = [s for scopes in device_parts.GROUPS.values() for s in scopes]
     assert len(grouped) == len(set(grouped))
-    assert set(grouped) - {device_parts.UNSCOPED} == SCOPES
+    # llmd.attn.index (PR 39) is in no group of the accepted reader: its
+    # share is read by readers/scope_share.py (device_part_share.index).
+    assert set(grouped) - {device_parts.UNSCOPED} == SCOPES - {
+        "llmd.attn.index"}
     assert device_parts.scope_of(
         "jit(step_fn)/while/body/closed_call/llmd.ssm.state/llmd.tiles/"
         "cumsum:") == "llmd.tiles"
@@ -468,7 +471,8 @@ def _new_metrics():
         ("device_part_share.", "moe_", "mla_"))]
     # Appended together (PR 36); later PRs append after them.
     first = bench["per_layer"].index(new[0])
-    assert len(new) == 13 and new == bench["per_layer"][first:first + 13]
+    new = new[:13]          # PR 39 appended three more of these prefixes
+    assert new == bench["per_layer"][first:first + 13]
     return new
 
 

@@ -177,8 +177,12 @@ def test_model_config_layer_types():
         ModelConfig(num_layers=1, layer_types=["chunked_attention"])
     with pytest.raises(ValueError, match="sliding_window"):
         ModelConfig(num_layers=1, layer_types=[SLIDING])
+    # MLA with layer kinds is served since PR 39; the GQA block's gate is not
     with pytest.raises(ValueError, match="MLA"):
-        ModelConfig(num_layers=1, layer_types=[FULL], kv_lora_rank=8)
+        ModelConfig(num_layers=1, layer_types=[FULL], kv_lora_rank=8,
+                    attn_output_gate=True)
+    assert ModelConfig(num_layers=1, layer_types=[FULL],
+                       kv_lora_rank=8).mla_layer_kinds == (FULL,)
     assert get_model(c).__name__.endswith("llama")
     assert get_model(SWA).__name__.endswith("moe")
 

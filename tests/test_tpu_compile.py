@@ -119,6 +119,27 @@ def _mla_prefill(H, F=640, S=8, Q=256, B=64, L=16):
                 _sds((S,), jnp.int32), _sds((), jnp.int32)]
 
 
+def _mla_masked(T, S, Q, H=128, F=640, R=512, B=1024, L=3):
+    """``mla_masked_attention`` over the tile list a step of T tokens in S
+    rows brings at ``ops.sparse_mla.SELECT_Q_TILE`` slots a tile."""
+    from llm_d_tpu.ops.attention import num_query_tiles
+    from llm_d_tpu.ops.pallas.mla_masked import (
+        KEY_BLOCK, mla_masked_attention as kern)
+    from llm_d_tpu.ops.sparse_mla import SELECT_Q_TILE
+    qt = min(SELECT_Q_TILE, Q)
+    NT = num_query_tiles(T, S, qt)
+
+    def fn(q, bias, ts, live, kc, bt, layer):
+        return kern(q, bias, ts, live, kc, bt, layer, block_size=BS,
+                    scale=0.07, value_width=R)
+
+    return fn, [_sds((NT, qt, H, F), jnp.bfloat16),
+                _sds((NT, B * BS // KEY_BLOCK, qt, KEY_BLOCK), jnp.float32),
+                _sds((NT,), jnp.int32), _sds((NT,), jnp.int32),
+                _sds((L, SLOTS, F), jnp.bfloat16), _sds((S, B), jnp.int32),
+                _sds((), jnp.int32)]
+
+
 def _ssm_update(H=32, P=128, N=256, G=2, S=64, L=6):
     """The state-space mixer's one-token update, in place on the state pool
     of ``S`` slots and the trash slot (falcon-h1-34b's geometry)."""
@@ -350,6 +371,13 @@ CASES = [
     pytest.param(functools.partial(_prefill_tiles, 20, 4, 128, T=64, S=8,
                                    Q=16, B=1024, L=6),
                  id="flash_prefill-tiles-falcon-h1-Q16"),
+    # dots3-note-prev's full layers: attention dense under the selection
+    # as a mask, 128 heads over the 640-wide latent row, a 2,048-token
+    # mixed step (tiles of 8 slots) and a pure-decode step (tiles of one).
+    pytest.param(functools.partial(_mla_masked, T=2048, S=16, Q=2048),
+                 id="mla_masked-dots3-T2048-S16"),
+    pytest.param(functools.partial(_mla_masked, T=16, S=16, Q=1),
+                 id="mla_masked-dots3-decode-S16"),
 ]
 
 
