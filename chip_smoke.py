@@ -20,6 +20,13 @@ holds the chip, at the full width of the models the repo benchmarks
      (streamed kernel), a small wave (dense kernel), fused multistep decode;
      same presence check, logprobs against the dequantize-then-XLA path.
 
+  4. window phase — the masked flash kernel's windowed walk
+     (``ops/pallas/mla_masked.py`` under ``tile_first``) against
+     ``ops.sparse_mla.attend_window``'s XLA form, both on the chip, at
+     ``dots3-note-prev``'s sliding geometry (64 heads, rows of 1,152, values
+     of 1,024, a window of 513); a query as a decode tile of one slot
+     bit-equal to the same query inside its prefill tile.
+
 ``--chips 4`` runs ONLY the four-chip phase (TP=4 dense, DP=4/EP=4 MoE, each
 against ``devices[0]`` alone; ``llama3-8b`` TP=4) and reports ``count: 4``.
 
@@ -70,6 +77,10 @@ MOE_OP_REL_RMS_TOL = 2e-2
 # test_collective_quant.py): a2a dispatch + combine vs the psum oracle's
 # quantized allreduce.  A wrong ragged_all_to_all offset is an O(1) error.
 MOE_A2A_OP_REL_RMS_TOL = 5e-2
+# The windowed flash walk against the XLA band attention, same bf16 rows:
+# mean |difference| over mean |value| (the two round the probabilities to
+# bf16 against different maxima; a wrong mask is an O(1) error).
+WINDOW_KERNEL_REL_TOL = 1e-2
 # Per-device bytes_in_use on the four-chip host: max/min at most this.
 MEMORY_BALANCE_FACTOR = 1.5
 
@@ -655,6 +666,91 @@ def moe_phase(model: str, n_seqs: int, prompt_len: int, max_tokens: int,
 
 
 # --------------------------------------------------------------------------
+# phase 4: the masked flash kernel's windowed walk, at op level
+# --------------------------------------------------------------------------
+
+def window_phase(heads: int, row_width: int, value_width: int, window: int,
+                 rows: Sequence[Sequence[int]], seed: int,
+                 block_size: int = 32, table_blocks: int = 1024,
+                 interpret: bool = False) -> Dict[str, float]:
+    """``rows``: (context end, new tokens) of each row of one step, the
+    first a prefill chunk.  The kernel path of ``attend_window`` against
+    its XLA form on the same cache, and the first row's queries as tiles
+    of one slot (a pure-decode step's) against the tiles the geometry
+    picks.  ``interpret``: the CPU rehearsal's Pallas interpreter."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llm_d_tpu.ops import sparse_mla
+    from llm_d_tpu.ops.pallas import mla_masked
+
+    rng = np.random.default_rng(seed)
+    S, T = len(rows), sum(n for _, n in rows)
+    need = [-(-end // block_size) for end, _ in rows]
+    pages = rng.permutation(sum(need)) + 1
+    tables = np.zeros((S, table_blocks), np.int32)
+    qtok = np.full((S, max(n for _, n in rows)), T, np.int32)
+    seq, pos, qpos = [], [], []
+    for s, (end, n) in enumerate(rows):
+        tables[s, :need[s]] = pages[sum(need[:s]):sum(need[:s + 1])]
+        qtok[s, :n] = len(seq) + np.arange(n)
+        seq += [s] * n
+        pos += range(end - n, end)
+        qpos += range(n)
+    batch = {k: jnp.asarray(v, jnp.int32) for k, v in dict(
+        block_tables=tables, token_seq_ids=seq, positions=pos,
+        token_qpos=qpos, qtok_idx=qtok,
+        seq_lens=[end for end, _ in rows]).items()}
+    kq, kc = jax.random.split(jax.random.PRNGKey(seed))
+    q = jax.random.normal(kq, (T, heads, row_width), jnp.bfloat16)
+    cache = 0.3 * jax.random.normal(
+        kc, (1, (sum(need) + 1) * block_size, row_width), jnp.bfloat16)
+
+    real = mla_masked.mla_masked_attention
+    if interpret:
+        mla_masked.mla_masked_attention = functools.partial(
+            real, interpret=True)
+    try:
+        attend = {kernel: jax.jit(functools.partial(
+            sparse_mla.attend_window, window=window, block_size=block_size,
+            scale=0.07, R=value_width, kernel=kernel))
+            for kernel in (True, False)}
+        qt = mla_masked.pick_q_tile(heads, row_width, value_width)
+        got = attend[True](q, cache, sparse_mla.with_tiles(batch, qt),
+                           layer=jnp.int32(0))
+        want = attend[False](q, cache, batch, layer=jnp.int32(0))
+        alone = attend[True](q, cache, sparse_mla.with_tiles(batch, 1),
+                             layer=jnp.int32(0))
+    finally:
+        mla_masked.mla_masked_attention = real
+    n = rows[0][1]
+    size = float(jnp.mean(jnp.abs(want)))
+    check(size > 0, "the XLA form attended to nothing")
+    info = {
+        "window_rel_diff": float(jnp.mean(jnp.abs(got - want))) / size,
+        "window_decode_vs_prefill": float(
+            jnp.max(jnp.abs(got[:n] - alone[:n]))),
+    }
+    log(f"   windowed walk, {heads} heads x {row_width}, window {window}, "
+        f"tiles of {qt}: mean |kernel - XLA| / mean |XLA| = "
+        f"{info['window_rel_diff']:.2e} (mean |XLA| {size:.4f}); a query "
+        f"alone in its tile "
+        f"against the same query in a tile of {qt}: max |difference| "
+        f"{info['window_decode_vs_prefill']:.1e}")
+    check(info["window_rel_diff"] <= WINDOW_KERNEL_REL_TOL,
+          f"windowed walk differs from the XLA form by "
+          f"{info['window_rel_diff']:.2e} of the values' scale")
+    # The interpreter's dots of two heights sum in two orders (an f32 ulp).
+    check(info["window_decode_vs_prefill"] <= (2e-6 if interpret else 0.0),
+          f"a decode tile differs from its prefill tile by "
+          f"{info['window_decode_vs_prefill']:.1e}")
+    return info
+
+
+# --------------------------------------------------------------------------
 # --chips 4: one program across the host
 # --------------------------------------------------------------------------
 
@@ -775,6 +871,13 @@ def run_one_chip(seed: int) -> None:
         moe_phase("deepseek-v3-bench", n_seqs=136, prompt_len=24,
                   max_tokens=64, seed=seed, expect_kernels=True)
     settle("MoE + MLA phase")
+    with phase("window phase [dots3-note-prev's sliding geometry]"):
+        # A chunk that continues a cached context across key-block edges,
+        # a fresh short prompt, decode rows at and around block edges.
+        window_phase(64, 1152, 1024, 513,
+                     [(2300, 300), (40, 40), (511, 1), (512, 1), (513, 1),
+                      (514, 1), (7001, 1), (9984, 1)], seed)
+    settle("window phase")
 
 
 def run_four_chips(seed: int) -> None:

@@ -78,6 +78,27 @@ def _next_bucket(n: int, lo: int, hi: int) -> int:
     return min(b, hi)
 
 
+def _keys_seen_upto(x, w):
+    """Sum of min(p + 1, w) over the positions p < x: the keys the queries
+    before position x see under a window (or a top-k) of w."""
+    y = np.minimum(x, w)
+    return y * (y + 1) // 2 + (x - y) * w
+
+
+def _tile_spans(ends, news, qt: int):
+    """(first, last) query position of every query tile of a dispatch whose
+    row r computes ``news[r]`` tokens ending at context ``ends[r]``, ``qt``
+    slots a tile, a row's tiles consecutive
+    (``ops.attention.query_tiles``)."""
+    ends = np.asarray(ends, np.int64)
+    news = np.minimum(np.asarray(news, np.int64), ends)
+    tiles = -(-news // qt)                          # of each row
+    row = np.repeat(np.arange(len(news)), tiles)
+    nth = np.arange(len(row)) - np.repeat(np.cumsum(tiles) - tiles, tiles)
+    q_first = (ends - news)[row] + nth * qt
+    return q_first, np.minimum(q_first + qt, ends[row]) - 1
+
+
 def kv_bytes_per_token(layout: Dict[str, int]) -> int:
     """Bytes one token's KV costs per layer: the bf16 rows of every cache
     buffer.  The single source of the byte accounting shared by pool sizing
@@ -779,6 +800,8 @@ class EngineCore:
         # (heads, cache row width) one shard's Pallas prefill kernels see:
         # what sets their query tile.  None: another path serves prefill.
         self._prefill_tile_dims: Optional[Tuple[int, int]] = None
+        # ``mla_masked_attention`` serves the MLA layers that see a window.
+        self._window_kernel = False
         if backend != "pallas":
             return
         # A tp shard sees its slice of the heads and of the folded dense
@@ -793,23 +816,24 @@ class EngineCore:
             return
         c = self.model_config
         if c.mla_by_kind:
-            # Layers that see a window are served by XLA, layers that
-            # select keys by a kernel of their own, dense under the
-            # selection (ops/sparse_mla.py, ops/pallas/mla_masked.py): the
-            # tile and key block accounting of the two MLA kernels
-            # describes neither.
-            from llm_d_tpu.ops.pallas import mla_masked
+            # Layers that select keys and layers that see a window are
+            # served by a kernel of their own, dense under the selection
+            # or the window as a bias (ops/sparse_mla.py,
+            # ops/pallas/mla_masked.py): the tile and key block accounting
+            # of the two MLA kernels describes neither.
+            from llm_d_tpu.ops import sparse_mla
             for kind in c.mla_layer_kinds or (FULL,):
                 g = c.mla_geometry(kind)
-                reason = ("no MLA kernel takes a window" if g.window
-                          else mla_masked.ineligible_reason(
-                              g.num_heads, g.kv_lora_rank,
-                              -(-c.max_model_len // self.config.block_size)
-                              * self.config.block_size)
-                          if g.index_topk else None)
+                reason = sparse_mla.kernel_refusal(
+                    g, self.config.block_size,
+                    -(-c.max_model_len // self.config.block_size)
+                    * self.config.block_size
+                ) if g.index_topk or g.window else None
                 if reason is not None:
                     self._disable_feature("pallas_attention",
                                           f"{kind}: {reason}")
+                elif g.window:
+                    self._window_kernel = True
             return
         self._prefill_tile_dims = (
             c.num_heads // heads_tp, next(iter(layout.values())) // tp)
@@ -2924,6 +2948,7 @@ class EngineCore:
             self._step_kv.update(self._attn_k_counts(ends, news, layout))
         else:
             self._step_kv.update(self._attn_dk_counts(ends, layout))
+        self._step_kv.update(self._attn_wk_counts(ends, news, layout))
         return packed, layout, scheduled, rows
 
     # ---------- step ----------
@@ -2951,10 +2976,7 @@ class EngineCore:
                   "kv_read_tokens": c.num_layers * full,
                   "kv_held_tokens": c.num_layers * int(ends.sum()),
                   "kv_dead_tokens": 0}
-        def upto(x, w):      # sum of min(p + 1, w) over positions p < x
-            y = np.minimum(x, w)
-            return y * (y + 1) // 2 + (x - y) * w
-
+        upto = _keys_seen_upto
         n_window = c.layer_types.count(SLIDING)
         if n_window:
             w = c.sliding_window
@@ -3015,7 +3037,9 @@ class EngineCore:
                 n * num_query_tiles(layout.T, layout.S, min(qt, layout.Q))
                 * min(qt, layout.Q) for n, qt in (
                     (c.num_layers - n_window, sparse_mla.SELECT_Q_TILE),
-                    (n_window, sparse_mla.WINDOW_Q_TILE))) // c.num_layers
+                    (n_window, sparse_mla.window_q_tile(
+                        c.mla_geometry(SLIDING), self._window_kernel)))
+            ) // c.num_layers
         elif self._prefill_tile_dims is not None:
             from llm_d_tpu.ops.attention import (
                 num_query_tiles, prefill_q_tile)
@@ -3039,13 +3063,7 @@ class EngineCore:
         qt = prefill_q_tile(layout.Q, *self._prefill_tile_dims, c.use_mla)
         kb = prefill_key_block(qt, *self._prefill_tile_dims, c.head_dim_,
                                bs, c.use_mla)
-        ends = np.asarray(ends, np.int64)
-        news = np.minimum(np.asarray(news, np.int64), ends)
-        tiles = -(-news // qt)                          # of each row
-        row = np.repeat(np.arange(len(news)), tiles)
-        nth = np.arange(len(row)) - np.repeat(np.cumsum(tiles) - tiles, tiles)
-        q_first = (ends - news)[row] + nth * qt         # a tile's positions
-        q_last = np.minimum(q_first + qt, ends[row]) - 1
+        q_first, q_last = _tile_spans(ends, news, qt)
         if c.diffusion_block_length:    # the last key its last query sees
             B = c.diffusion_block_length
             q_last = (q_last // B + 1) * B - 1
@@ -3060,6 +3078,32 @@ class EngineCore:
             pages = -(-(q_last + 1) // bs) - k_first // bs
             slots += layers * int((-(-pages * bs // kb)).sum()) * kb
         return {"attn_k_real": real, "attn_k_slots": slots}
+
+    def _attn_wk_counts(self, ends, news, layout: BatchLayout
+                        ) -> Dict[str, int]:
+        """The keys ``mla_masked_attention`` walks for the MLA layers that
+        see a window (step_clock.py), summed over those layers and the
+        dispatch's query tiles: a tile of Qt slots (``window_q_tile``, no
+        wider than the query bucket) walks whole key blocks from the one
+        that holds the first key its first query sees to its last query's
+        own.  Nothing where another path serves those layers."""
+        if not self._window_kernel:
+            return {}
+        from llm_d_tpu.ops import sparse_mla
+        from llm_d_tpu.ops.pallas.mla_masked import KEY_BLOCK
+        c = self.model_config
+        g = c.mla_geometry(SLIDING)
+        qt = min(sparse_mla.window_q_tile(g, True), layout.Q)
+        ends = np.asarray(ends, np.int64)
+        news = np.minimum(np.asarray(news, np.int64), ends)
+        q_first, q_last = _tile_spans(ends, news, qt)
+        blocks = (q_last // KEY_BLOCK + 1
+                  - np.maximum(q_first - g.window + 1, 0) // KEY_BLOCK)
+        n_window = c.layer_types.count(SLIDING)
+        return {"attn_wk_real": n_window * int(
+                    (_keys_seen_upto(ends, g.window)
+                     - _keys_seen_upto(ends - news, g.window)).sum()),
+                "attn_wk_slots": n_window * int(blocks.sum()) * KEY_BLOCK * qt}
 
     def _attn_dk_counts(self, ends, layout: BatchLayout) -> Dict[str, int]:
         """The keys the MLA decode kernel's inner loop covers for a

@@ -76,6 +76,18 @@ and attention layers:
                   sequence bucket first) x its rows x the keys a block
                   (``ops.attention.mla_decode_walk``)
 
+and, for a stack whose MLA layers with a window the masked flash kernel
+serves (``EngineCore._attn_wk_counts``; every step, pure decode too), summed
+over those layers:
+
+  attn_wk_real    keys the window shows the step's queries: min(window,
+                  position + 1) a query
+  attn_wk_slots   keys the kernel's inner loop covers for them: a query
+                  tile's key blocks, from the one that holds the first key
+                  its first query sees to its last query's own, x the keys
+                  a block x the tile's slots (``ops.sparse_mla.
+                  window_q_tile``, ``ops.pallas.mla_masked.KEY_BLOCK``)
+
 and, for a stack with recurrent state beside its pages
 (``EngineCore._state_counts``; the classic path, also on its
 ``llmd.dispatch`` annotation):

@@ -24,12 +24,12 @@ each kind with its own heads, ranks, head sizes and rotary base
 buffers (models/moe.py).  What the block holds besides the projections, each
 by a field of the config and none by a model's name: the latents rescaled by
 sqrt(hidden / rank) (``mla_lora_rescale``), a sigmoid gate a head on the
-output (``attn_head_gate``), a window (``ops.sparse_mla.attend_window``,
-XLA: neither MLA kernel takes one yet), and in FULL layers a learned
-selection of the keys a query attends to (``index_topk``;
-ops/sparse_mla.py, dense under the selection as a mask:
-ops/pallas/mla_masked.py on the TPU) from an index key a token, cached in a
-buffer of its own.
+output (``attn_head_gate``), a window (``ops.sparse_mla.attend_window``),
+and in FULL layers a learned selection of the keys a query attends to
+(``index_topk``; ops/sparse_mla.py) from an index key a token, cached in a
+buffer of its own.  On the TPU one flash kernel serves both, dense under
+the selection or the window as a bias (ops/pallas/mla_masked.py), where
+``ops.sparse_mla.kernel_refusal`` finds nothing against the kind's geometry.
 
 RoPE is the base rotary scheme, rotate-half; the MLA path has no YaRN
 scaling.
@@ -203,7 +203,10 @@ def mla_attention_block(
             kv_cache = kv_cache.at[layer, batch["slot_mapping"]].set(
                 row.astype(kv_cache.dtype))
             out_lat = sparse_mla.attend_window(
-                q_eff, kv_cache, batch, g.window, block_size, layer, scale, R)
+                q_eff, kv_cache, batch, g.window, block_size, layer, scale, R,
+                kernel=sparse_mla.kernel_serves(
+                    g, attn_backend, block_size,
+                    batch["block_tables"].shape[-1] * block_size))
         caches = (kv_cache,)
     else:
         backend = A.resolve_backend(attn_backend)
@@ -242,7 +245,6 @@ def _mla_attend_selected(lp, c, g, x, cq, q_eff, row, caches, batch, layer,
     ``index_topk`` of them a query, attend to those keys
     (ops/sparse_mla.py): (out_lat [T, H, R] f32, caches')."""
     from llm_d_tpu.ops import sparse_mla
-    from llm_d_tpu.ops.pallas import mla_masked
     T = x.shape[0]
     kv_cache, idx_cache = caches
     Hi, Di, rope = c.index_n_heads, c.index_head_dim, g.qk_rope_head_dim
@@ -274,10 +276,8 @@ def _mla_attend_selected(lp, c, g, x, cq, q_eff, row, caches, batch, layer,
             row.astype(kv_cache.dtype))
         out_lat = sparse_mla.attend_chosen(
             q_eff, kv_cache, chosen, batch, block_size, layer, scale,
-            g.kv_lora_rank, kernel=backend == "pallas" and (
-                A.pallas_ineligible_reason(block_size, kv_cache.shape[-1])
-                or mla_masked.ineligible_reason(
-                    g.num_heads, g.kv_lora_rank, chosen.shape[-1])) is None)
+            g.kv_lora_rank, kernel=sparse_mla.kernel_serves(
+                g, backend, block_size, chosen.shape[-1]))
     return out_lat, (kv_cache, idx_cache)
 
 

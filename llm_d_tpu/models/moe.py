@@ -261,7 +261,10 @@ def forward(
                     # Served over tiles of its own (ops/sparse_mla.py).
                     batches[kind] = sparse_mla.with_tiles(
                         batch, sparse_mla.SELECT_Q_TILE if g.index_topk
-                        else sparse_mla.WINDOW_Q_TILE)
+                        else sparse_mla.window_q_tile(
+                            g, sparse_mla.kernel_serves(
+                                g, attn_backend, block_size,
+                                batch["block_tables"].shape[-1] * block_size)))
                 else:
                     batches[kind] = with_query_tiles(
                         batch, g.num_heads, g.row_width, attn_backend, mesh,

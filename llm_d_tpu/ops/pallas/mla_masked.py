@@ -27,8 +27,9 @@ score dot and the value dot.  What differs:
     -1e30 elsewhere (not chosen, after the query, past the context, a pad
     slot), laid out so that a key block's [Qt, KB] tile is a leading-axis
     index; it is spread over a slot's H rows through a VMEM scratch;
-  - ``tile_live`` [NT] says how many keys a tile walks (its last query's
-    position + 1): computed outside, so the kernel holds no positions;
+  - ``tile_live`` [NT] says where a tile's walk ends (its last query's
+    position + 1) and ``tile_first`` [NT] at which key block it starts:
+    computed outside, so the kernel holds no positions;
   - one running max a block and ONE bf16 term of the probabilities: the
     page-end maxima and the three-term carry of ``weigh_key_block`` keep
     two KERNELS rounding alike; here one kernel serves prefill chunks,
@@ -37,6 +38,19 @@ score dot and the value dot.  What differs:
     fresh prefill agree by construction;
   - scores are scaled in f32 after the dot and the attended values leave
     in f32, as the XLA path of ``ops/sparse_mla.py`` has them.
+
+One body, one rounding, two walks.  A layer that SELECTS walks a tile's
+keys from block 0 under the bias ``index_select`` wrote.  A layer with a
+WINDOW (``ops.sparse_mla.attend_window``) walks from the block that holds
+the first key its first query sees: ``tile_first`` = floor((first query's
+position - (window - 1)) / KEY_BLOCK), at least 0, and the bias (0 where
+t - window < s <= t, computed in XLA from the tile's positions) holds the
+``window_bias_blocks`` blocks from there on only: a band of 16 + 512 keys
+is 3 key blocks (4 where it straddles) whatever the context.  Both walks
+start on ABSOLUTE multiples of KEY_BLOCK, so a query's visible keys fall
+into the same blocks whatever tile carries it, and a block wholly masked
+for a row leaves its ``m``, ``l`` and ``acc`` as they were: a decode row
+and its fresh prefill agree by construction in a window layer too.
 
 The cache rows of the step's own tokens are written by the caller BEFORE
 the kernel runs (read-only, no aliasing contract).
@@ -60,17 +74,21 @@ KEY_BLOCK = 256
 # tiles of a whole row, four [Qt*H, KB] f32 temporaries), just over the
 # compiler's default of 16.
 VMEM_LIMIT = 48 << 20
+# Fused rows (slots x heads) a tile aims at: as many as feed the MXU well
+# (8 slots x 128 heads, ``ops.sparse_mla.SELECT_Q_TILE``'s reasoning).
+TILE_ROWS = 1024
 
 
 def _masked_kernel(
     # scalar prefetch
     block_tables_ref,   # [S, B] SMEM
     tile_seq_ref,       # [NT]   SMEM: the sequence row of each query tile
-    tile_live_ref,      # [NT]   SMEM: keys the tile walks
+    tile_live_ref,      # [NT]   SMEM: the key the tile's walk ends before
+    tile_first_ref,     # [NT]   SMEM: the key block the walk starts at
     layer_ref,          # [1]    SMEM
     # inputs
     q_ref,              # [1, Qt*H, F]
-    bias_ref,           # [1, C/KB, Qt, KB] f32
+    bias_ref,           # [1, NB, Qt, KB] f32, from the walk's first block
     kv_hbm,
     # outputs
     o_ref,              # [1, Qt*H, Rv] f32
@@ -93,15 +111,17 @@ def _masked_kernel(
     # The last block is filled up with the walk's last page again (masked
     # by the bias): every row of a walked block holds real cache rows, so
     # p = 0 never meets a NaN in the p v dot.
-    n_blocks = pl.cdiv(n_pages, P)
+    first = tile_first_ref[n]
+    n_blocks = pl.cdiv(n_pages, P) - first
     H = num_heads
     Qt = bias_ref.shape[2]
     Rv = o_ref.shape[2]
 
     def block_dma(slot, i, act):
-        """``act`` ("start" / "wait") the P page copies of block ``i``."""
+        """``act`` ("start" / "wait") the P page copies of the walk's
+        block ``i``."""
         def page(p, _):
-            j = jnp.minimum(i * P + p, n_pages - 1)
+            j = jnp.minimum((first + i) * P + p, n_pages - 1)
             src = pl.ds(pl.multiple_of(block_tables_ref[s, j] * bs, bs), bs)
             dst = pl.ds(pl.multiple_of(p * bs, bs), bs)
             getattr(pltpu.make_async_copy(
@@ -167,14 +187,39 @@ def ineligible_reason(num_heads: int, value_width: int,
     return None
 
 
+def pick_q_tile(num_heads: int, row_width: int, value_width: int) -> int:
+    """Query slots a tile of a windowed layer holds: TILE_ROWS fused rows,
+    halved while the call's VMEM (bf16 query block and f32 output block
+    double buffered, the f32 accumulator, the key buffer, the bias spread
+    and four [rows, KEY_BLOCK] f32 temporaries) passes VMEM_LIMIT: 16 at
+    64 heads over rows of 1,152 with values of 1,024 (23 MB)."""
+    qt = max(TILE_ROWS // num_heads, 1)
+
+    def vmem(rows):
+        return (2 * rows * row_width * 2 + 3 * rows * value_width * 4
+                + 2 * KEY_BLOCK * row_width * 2 + 5 * rows * KEY_BLOCK * 4)
+
+    while qt > 1 and vmem(qt * num_heads) > VMEM_LIMIT:
+        qt //= 2
+    return qt
+
+
+def window_bias_blocks(q_tile: int, window: int) -> int:
+    """Key blocks the band of a windowed tile can touch: its ``q_tile`` +
+    ``window`` - 1 consecutive keys start anywhere in their first block."""
+    return (q_tile + window - 1 + KEY_BLOCK - 2) // KEY_BLOCK + 1
+
+
 @functools.partial(
     jax.jit, static_argnames=("block_size", "scale", "value_width",
                               "interpret"))
 def mla_masked_attention(
     q_tiles: jax.Array,       # [NT, Qt, H, F] absorbed queries by tile
-    bias: jax.Array,          # [NT, C / KEY_BLOCK, Qt, KEY_BLOCK] f32
+    bias: jax.Array,          # [NT, NB, Qt, KEY_BLOCK] f32 over the NB key
+                              # blocks from each tile's first
     tile_seq: jax.Array,      # [NT] i32 row of block_tables of each tile
-    tile_live: jax.Array,     # [NT] i32 keys each tile walks
+    tile_live: jax.Array,     # [NT] i32 the key each tile's walk ends before
+    tile_first: jax.Array,    # [NT] i32 the key block each walk starts at
     kv_cache: jax.Array,      # [L, num_slots, F]
     block_tables: jax.Array,  # [S, B]
     layer: jax.Array,
@@ -184,13 +229,14 @@ def mla_masked_attention(
     interpret: bool = False,
 ) -> jax.Array:               # [NT, Qt, H, value_width] f32
     """Softmax attention of every query slot over the keys of its tile's
-    sequence whose bias is 0 (cache already written)."""
+    sequence whose bias is 0 (cache already written).  A tile walks at most
+    NB blocks: ``tile_live`` <= (``tile_first`` + NB) * KEY_BLOCK."""
     NT, Qt, H, F = q_tiles.shape
     KB = bias.shape[3]
     assert kv_cache.shape[2] == F, (kv_cache.shape, F)
     assert KB % block_size == 0 and bias.shape[2] == Qt, (bias.shape, Qt)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(NT,),
         in_specs=[
             pl.BlockSpec((1, Qt * H, F), lambda n, *_: (n, 0, 0),
@@ -221,7 +267,7 @@ def mla_masked_attention(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(block_tables, tile_seq, tile_live,
+    )(block_tables, tile_seq, tile_live, tile_first,
       jnp.asarray(layer, jnp.int32).reshape(1),
       q_tiles.reshape(NT, Qt * H, F), bias, kv_cache)
     return out.reshape(NT, Qt, H, value_width)

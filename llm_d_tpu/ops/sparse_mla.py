@@ -32,9 +32,11 @@ visible), and the layer's attention weighs those keys only:
 
 A SLIDING layer's window on the MLA path is served here too
 (``attend_window``): a tile of consecutive queries of one row sees the
-``window - 1`` keys before its first query and the tile's own, so a tile
-gathers that one band of rows through the block table, not a set a query,
-and a step reads Qt + window - 1 rows a tile whatever the context.
+``window - 1`` keys before its first query and the tile's own, one band
+of Qt + window - 1 rows whatever the context.  On the TPU the same flash
+kernel walks that band, from the key block that holds its first key, with
+the window as the bias (``mla_masked``'s docstring: one body, two walks);
+elsewhere a tile gathers the band's rows through the block table.
 """
 
 from __future__ import annotations
@@ -57,18 +59,49 @@ INDEX_KEY_CHUNK = 512
 # Bits of the threshold a pass of ``choose_topk`` settles (2**bits - 1
 # counts in one read of the scores).
 THRESHOLD_BITS = 2
-# Query slots a tile of a windowed layer holds, and the f32 score elements
-# [tiles, WINDOW_Q_TILE, H, WINDOW_Q_TILE + window - 1] one pass of
-# ``attend_window`` may hold (64 MiB).
+# Query slots a tile of a windowed layer holds where XLA serves it, and
+# the f32 score elements [tiles, WINDOW_Q_TILE, H, WINDOW_Q_TILE + window -
+# 1] one pass of ``attend_window`` may hold (64 MiB).  Where the kernel
+# serves it the height follows the geometry (``window_q_tile``).
 WINDOW_Q_TILE = 128
 WINDOW_SCORE_BUDGET = 1 << 24
+
+
+def kernel_refusal(g, block_size: int, table_keys: int) -> str | None:
+    """Why ``mla_masked_attention`` cannot serve the layers of MLA geometry
+    ``g`` (``ModelConfig.mla_geometry``) whose rows' block tables hold
+    ``table_keys`` positions; None: it can.  Geometry the code can see,
+    asked by ``models/mla.py`` (which path), the models' ``forward`` (which
+    tiles) and the engine (what it announces and counts)."""
+    from llm_d_tpu.ops.pallas import mla_masked
+    return (A.pallas_ineligible_reason(block_size, g.row_width)
+            or mla_masked.ineligible_reason(g.num_heads, g.kv_lora_rank,
+                                            table_keys))
+
+
+def kernel_serves(g, backend: str, block_size: int, table_keys: int) -> bool:
+    """Whether ``mla_masked_attention`` serves a layer kind that selects or
+    sees a window: the backend and ``kernel_refusal``, nothing else."""
+    return (A.resolve_backend(backend) == "pallas"
+            and kernel_refusal(g, block_size, table_keys) is None)
+
+
+def window_q_tile(g, kernel: bool) -> int:
+    """Query slots a tile of a windowed layer of geometry ``g`` holds: the
+    kernel's pick from the heads and the row (16 at 64 heads: a decode row
+    of a mixed step costs a tile of 16 slots, a band 3-4 key blocks), the
+    XLA form's WINDOW_Q_TILE where it serves."""
+    if not kernel:
+        return WINDOW_Q_TILE
+    from llm_d_tpu.ops.pallas import mla_masked
+    return mla_masked.pick_q_tile(g.num_heads, g.row_width, g.kv_lora_rank)
 
 
 def with_tiles(batch: Dict[str, jax.Array], q_tile: int
                ) -> Dict[str, jax.Array]:
     """``batch`` plus the query tile list (``ops.attention.query_tiles``) of
     ``q_tile`` slots that a layer that selects (SELECT_Q_TILE) or
-    ``attend_window`` (WINDOW_Q_TILE) walks, no wider than the step's
+    ``attend_window`` (``window_q_tile``) walks, no wider than the step's
     query bucket: derived once a step program by the models' ``forward``."""
     return dict(batch, **A.query_tiles(
         batch, min(q_tile, batch["qtok_idx"].shape[1])))
@@ -245,8 +278,9 @@ def attend_chosen(
         live = jnp.minimum(jnp.max(_tile_positions(batch, tiles), axis=1) + 1,
                            batch["seq_lens"][tile_seq])
         out = mla_masked.mla_masked_attention(
-            q_t, bias, tile_seq, live, kv_cache, batch["block_tables"],
-            layer, block_size=block_size, scale=scale, value_width=R)
+            q_t, bias, tile_seq, live, jnp.zeros_like(live), kv_cache,
+            batch["block_tables"], layer, block_size=block_size, scale=scale,
+            value_width=R)
     else:
         slot_of = (batch["block_tables"][:, :, None] * block_size
                    + jnp.arange(block_size, dtype=jnp.int32)[None, None, :]
@@ -275,10 +309,14 @@ def attend_window(
     layer: jax.Array,
     scale: float,
     R: int,
+    kernel: bool,             # the Pallas kernel serves this geometry
 ) -> jax.Array:               # [T, H, R] f32 attended latents
     """Softmax attention of every query over the last ``window`` keys of its
     sequence, a tile of queries at a time over the one band of rows the
     tile sees."""
+    if kernel:
+        return _attend_window_blocks(
+            q_eff, kv_cache, batch, window, block_size, layer, scale, R)
     T, H, F = q_eff.shape
     tiles = batch if "tile_tok" in batch else with_tiles(batch, WINDOW_Q_TILE)
     tile_tok, tile_seq = tiles["tile_tok"], tiles["tile_seq"]
@@ -313,4 +351,37 @@ def attend_window(
 
     out = jax.lax.map(tile, (q_t, slots, seen), batch_size=max(
         min(WINDOW_SCORE_BUDGET // (qt * H * K), NT), 1))  # [NT, Qt, H, R]
+    return out[tiles["tok_tile"], tiles["tok_slot"]]
+
+
+def _attend_window_blocks(q_eff, kv_cache, batch, window, block_size, layer,
+                          scale, R) -> jax.Array:
+    """``attend_window`` through ``mla_masked_attention``: the band by key
+    BLOCK, from the block that holds the first key the tile's first query
+    sees to its last query's own, the window as the bias over those
+    blocks."""
+    from llm_d_tpu.ops.pallas import mla_masked
+    T, H, F = q_eff.shape
+    tiles = batch if "tile_tok" in batch else with_tiles(
+        batch, mla_masked.pick_q_tile(H, F, R))
+    tile_tok, tile_seq = tiles["tile_tok"], tiles["tile_seq"]
+    NT, qt = tile_tok.shape
+    KB = mla_masked.KEY_BLOCK
+    nb = min(mla_masked.window_bias_blocks(qt, window),
+             batch["block_tables"].shape[1] * block_size // KB)
+    pos_t = _tile_positions(batch, tiles)[:, None, :, None]  # [NT, 1, Qt, 1]
+    len_t = batch["seq_lens"][tile_seq]                     # [NT]
+    first = jnp.maximum(pos_t[:, 0, 0, 0] - (window - 1), 0) // KB
+    key_pos = (first[:, None, None, None] * KB + jnp.arange(
+        nb * KB, dtype=jnp.int32).reshape(1, nb, 1, KB))    # [NT, nb, 1, KB]
+    seen = ((key_pos < len_t[:, None, None, None]) & (key_pos <= pos_t)
+            & (key_pos > pos_t - window))                   # [NT, nb, Qt, KB]
+    # A pad slot rides the last token's queries: its bias masks every key,
+    # so no zero row is appended (a copy of all the step's queries).
+    q_t = q_eff[jnp.minimum(tile_tok, T - 1)]               # [NT, Qt, H, F]
+    out = mla_masked.mla_masked_attention(
+        q_t, jnp.where(seen, 0.0, mla_masked.NEG_INF).astype(jnp.float32),
+        tile_seq, jnp.minimum(jnp.max(pos_t, axis=(1, 2, 3)) + 1, len_t),
+        first, kv_cache, batch["block_tables"], layer,
+        block_size=block_size, scale=scale, value_width=R)
     return out[tiles["tok_tile"], tiles["tok_slot"]]

@@ -386,11 +386,15 @@ def test_ssm_mechanism_check_names_each_fault():
     assert wrong[:, 0].tolist() == [1, 2, 3, 4, 4, 4, 1, 2, 3, 4]
 
 
-@pytest.mark.parametrize("name,moves,counts", [
-    ("attn_key_fill_share.mla", "itl_p95_ms", "attn_k"),           # PR 35
-    ("attn_decode_key_fill_share.mla", "out_tok_s", "attn_dk"),    # PR 37
+@pytest.mark.parametrize("name,moves,counts,cell", [
+    ("attn_key_fill_share.mla", "itl_p95_ms", "attn_k",
+     "kanana2.batch"),                                              # PR 35
+    ("attn_decode_key_fill_share.mla", "out_tok_s", "attn_dk",
+     "kanana2.batch"),                                              # PR 37
+    ("attn_window_key_fill_share", "ttft_p95_ms", "attn_wk",
+     "dots3.longdoc"),                                              # PR 40
 ])
-def test_mla_key_fill_share_is_a_data_file(name, moves, counts):
+def test_mla_key_fill_share_is_a_data_file(name, moves, counts, cell):
     """A PR's one per-layer metric: an entry appended to BENCHMARK.json and
     a file for the reader that is there, no reader code."""
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
@@ -398,7 +402,7 @@ def test_mla_key_fill_share_is_a_data_file(name, moves, counts):
     assert entry == {
         "name": name, "unit": "%", "better": "higher",
         "source": "program_span", "layer": "attention kernels",
-        "moves": moves, "workloads": ["kanana2.batch"]}
+        "moves": moves, "workloads": [cell]}
     d = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())
     assert all(d[k] == entry[k] for k in (
         "name", "unit", "better", "source", "layer", "moves"))
@@ -406,7 +410,8 @@ def test_mla_key_fill_share_is_a_data_file(name, moves, counts):
         "span": "engine.step", "numerator": f"{counts}_real",
         "denominator": [f"{counts}_slots"]}
     moved = next(m for m in bench["end_to_end"] if m["name"] == entry["moves"])
-    assert set(entry["workloads"]) <= set(moved["workloads"])
+    assert set(entry["workloads"]) <= set(moved.get(
+        "workloads", [w["name"] for w in bench["workloads"]]))
 
 
 # ---------------------------------------------------------------------------
@@ -458,7 +463,10 @@ def test_dots3_cell_and_its_files():
     assert mix["prompt_tokens"]["min"] == 2 * conf["index_topk"]
     per_layer = {m["name"]: m for m in bench["per_layer"]}
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-len(DOTS_METRICS):] == DOTS_METRICS   # appended, in order
+    at = names.index(DOTS_METRICS[0])
+    assert names[at:at + len(DOTS_METRICS)] == DOTS_METRICS   # in order
+    assert names[at + len(DOTS_METRICS):] == [
+        "attn_window_key_fill_share"]                       # PR 40, appended
     for name in DOTS_METRICS:
         assert per_layer[name]["workloads"] == ["dots3.longdoc"]
         d = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())

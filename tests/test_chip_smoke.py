@@ -53,6 +53,16 @@ def test_moe_phase_tiny_mla(smoke):
     assert info["moe_op_parity"] <= smoke.MOE_OP_REL_RMS_TOL
 
 
+def test_window_phase_interpreted(smoke):
+    """The kernel interpreted at 8 heads x 128 (tiles of 128 slots, key
+    blocks of 256 keys = 32 pages of 8)."""
+    info = smoke.window_phase(
+        8, 128, 128, 257, [(600, 150), (9, 9), (256, 1), (257, 1)], seed=0,
+        block_size=8, table_blocks=128, interpret=True)
+    assert info["window_rel_diff"] <= smoke.WINDOW_KERNEL_REL_TOL
+    assert info["window_decode_vs_prefill"] <= 2e-6
+
+
 def test_sharded_phase_tiny_on_virtual_devices(smoke, devices):
     """The --chips 4 phase on four of the suite's virtual CPU devices."""
     info = smoke.sharded_phase(

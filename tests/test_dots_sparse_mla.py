@@ -433,44 +433,152 @@ def test_masked_kernel_decode_row_is_its_prefill_row():
         jnp.asarray(q_t, jnp.bfloat16),
         jnp.asarray(bias.reshape(NT, qt, C // KB, KB).transpose(0, 2, 1, 3)),
         jnp.asarray(tiles["tile_seq"]),
-        jnp.asarray(pos.max(1) + 1, jnp.int32)))
+        jnp.asarray(pos.max(1) + 1, jnp.int32), jnp.zeros(NT, jnp.int32)))
     # every slot as a tile of its own
     alone = np.asarray(run(
         jnp.asarray(q_t.reshape(NT * qt, 1, H, F), jnp.bfloat16),
         jnp.asarray(bias.reshape(NT * qt, 1, C // KB, KB).transpose(
             0, 2, 1, 3)),
         jnp.asarray(np.repeat(tiles["tile_seq"], qt)),
-        jnp.asarray(pos.reshape(-1) + 1, jnp.int32)))
+        jnp.asarray(pos.reshape(-1) + 1, jnp.int32),
+        jnp.zeros(NT * qt, jnp.int32)))
     np.testing.assert_allclose(whole.reshape(alone.shape), alone, rtol=0,
                                atol=2e-6)
     assert np.abs(whole).max() > 0
 
 
-@pytest.mark.parametrize("window", [1, 7, 8, 9, 16, 17, 21])
-def test_window_on_the_mla_path_at_page_edges(window):
-    """``attend_window``: a query sees keys t - window < s <= t.  Windows
-    of a page, a page less and more one, two pages; rows whose band starts
-    on a page edge, inside a page, before position 0; prefill chunks,
-    decode rows and a chunk that continues a cached context."""
+def _window_case(kernel, seed, monkeypatch):
+    """(batch with its tile list, q, cache, H, R) for ``attend_window``:
+    the XLA form over pages of 8 keys, or the kernel's walk (interpreted)
+    over key blocks of 256 keys = 32 pages, tiles of 16 slots.  Rows: a
+    prefill chunk that crosses a block edge, a row shorter than any window
+    here but 1 and 8, decode rows whose contexts end one before, on and one
+    past a page edge and a key-block edge (256, 512), a chunk whose first
+    tile's band of 16 + 512 keys straddles four blocks (keys 250-777) and
+    whose second tile is mostly pad slots; the tile list ends in pad
+    tiles."""
     rng = np.random.default_rng(3)
-    rows = [(40, 40), (24, 1), (25, 1), (64, 9), (17, 17), (8, 1), (33, 2)]
-    batch = _batch(rows, seed=window)
-    T, H, R, F = int(batch["positions"].shape[0]), 2, 8, 12
-    slots = (8 * len(rows) + 1) * BS
+    if kernel:
+        from llm_d_tpu.ops.pallas import mla_masked
+        monkeypatch.setattr(
+            mla_masked, "mla_masked_attention", functools.partial(
+                mla_masked.mla_masked_attention, interpret=True))
+        rows = [(300, 300), (9, 9), (255, 1), (256, 1), (257, 1), (511, 1),
+                (512, 1), (513, 1), (514, 1), (782, 20)]
+        H, R, F, width, qt = 8, 128, 128, 128, 16
+    else:
+        rows = [(40, 40), (24, 1), (25, 1), (64, 9), (17, 17), (8, 1),
+                (33, 2)]
+        H, R, F, width, qt = 2, 8, 12, 8, sparse_mla.WINDOW_Q_TILE
+    batch = _batch(rows, tables_width=width, seed=seed)
+    T = int(batch["positions"].shape[0])
     q = jnp.asarray(rng.standard_normal((T, H, F)), jnp.float32)
-    cache = jnp.asarray(rng.standard_normal((2, slots, F)), jnp.float32)
-    out = np.asarray(jax.jit(functools.partial(
-        sparse_mla.attend_window, window=window, block_size=BS, scale=0.25,
-        R=R))(q, cache, batch, layer=jnp.int32(1)))
+    cache = jnp.asarray(
+        rng.standard_normal((2, (width * len(rows) + 1) * BS, F)),
+        jnp.float32)
+    return sparse_mla.with_tiles(batch, qt), q, cache, R
+
+
+def _assert_window(out, q, cache, batch, window, R, holds=True):
+    """``out`` [T, H, R] is (``holds``) or is not attention over the keys
+    t - window < s <= t of each query's own row."""
     seq, qpos = np.asarray(batch["token_seq_ids"]), np.asarray(
         batch["positions"])
-    for t in range(T):
+    off = 0.0
+    for t in range(len(seq)):
         keys = _rows_of(cache[1], batch, seq[t], qpos[t] + 1)
         seen = np.arange(qpos[t] + 1) > qpos[t] - window
         assert seen.sum() == min(window, qpos[t] + 1)
-        np.testing.assert_allclose(
-            out[t], _dense(np.asarray(q[t]), keys, seen, 0.25, R),
-            rtol=2e-5, atol=2e-5)
+        want = _dense(np.asarray(q[t]), keys, seen, 0.25, R)
+        if holds:
+            np.testing.assert_allclose(out[t], want, rtol=2e-5, atol=2e-5)
+        off = max(off, float(np.abs(out[t] - want).max()))
+    assert holds or off > 1e-3
+
+
+WINDOWS = [(False, w) for w in (1, 7, 8, 9, 16, 17, 21)] + [
+    (True, w) for w in (1, 8, 9, 255, 256, 257, 513)]
+
+
+@pytest.mark.parametrize("kernel,window", WINDOWS)
+def test_window_on_the_mla_path_at_page_edges(kernel, window, monkeypatch):
+    """``attend_window``: a query sees keys t - window < s <= t.  The XLA
+    form: windows of a page, a page less and more one, two pages; rows
+    whose band starts on a page edge, inside a page, before position 0;
+    prefill chunks, decode rows and a chunk that continues a cached
+    context.  The kernel's walk (``_window_case``): windows of a page and
+    of a key block, one less and one more, and the published 513, whose
+    bands end on, before and past page and key-block edges."""
+    batch, q, cache, R = _window_case(kernel, window, monkeypatch)
+    out = np.asarray(jax.jit(functools.partial(
+        sparse_mla.attend_window, window=window, block_size=BS, scale=0.25,
+        R=R, kernel=kernel))(q, cache, batch, layer=jnp.int32(1)))
+    _assert_window(out, q, cache, batch, window, R)
+
+
+@pytest.mark.parametrize("kernel,window,served", [
+    (False, 16, 17), (False, 16, 15), (True, 256, 257), (True, 256, 255),
+    (True, 513, 514), (True, 513, 512)])
+def test_a_window_one_key_off_is_caught(kernel, window, served, monkeypatch):
+    """What the comparison above would not forgive: the layer served with
+    a window one key too wide or too narrow."""
+    batch, q, cache, R = _window_case(kernel, 0, monkeypatch)
+    out = np.asarray(jax.jit(functools.partial(
+        sparse_mla.attend_window, window=served, block_size=BS, scale=0.25,
+        R=R, kernel=kernel))(q, cache, batch, layer=jnp.int32(1)))
+    _assert_window(out, q, cache, batch, window, R, holds=False)
+
+
+def test_masked_kernel_window_decode_row_is_its_prefill_row(monkeypatch):
+    """The twin of the test above for a layer with a window: a query as a
+    tile of one slot (a pure-decode step) and inside a prefill tile of 16.
+    The walk starts on absolute multiples of the key block, so the tile of
+    16 walks the same blocks for the query, at most one more before them
+    that is wholly masked for it and leaves its statistics untouched.
+    Bit-equal on the chip (chip_smoke.py); the interpreter's dots of two
+    heights sum in two orders, an f32 ulp apart."""
+    from llm_d_tpu.ops.pallas import mla_masked
+    monkeypatch.setattr(
+        mla_masked, "mla_masked_attention", functools.partial(
+            mla_masked.mla_masked_attention, interpret=True))
+    rng = np.random.default_rng(5)
+    H, R, F, width = 8, 128, 128, 128
+    batch = _batch([(800, 150)], tables_width=width)
+    q = jnp.asarray(rng.standard_normal((150, H, F)), jnp.bfloat16)
+    cache = jnp.asarray(
+        rng.standard_normal((2, (width + 1) * BS, F)), jnp.bfloat16)
+    whole, alone = (np.asarray(jax.jit(functools.partial(
+        sparse_mla.attend_window, window=513, block_size=BS, scale=0.25,
+        R=R, kernel=True))(q, cache, sparse_mla.with_tiles(batch, qt),
+                           layer=jnp.int32(1))) for qt in (16, 1))
+    np.testing.assert_allclose(whole, alone, rtol=0, atol=2e-6)
+    assert np.abs(whole).max() > 0
+
+
+def test_the_window_tile_follows_the_geometry():
+    """About 1,024 fused rows a tile under the scoped VMEM: 16 slots at the
+    published sliding geometry (64 heads, rows of 1,152, values of 1,024),
+    8 at the full layers' 128 heads, halved where the blocks would not
+    fit; the XLA form keeps its 128; a band of 16 + 512 keys is at most
+    four key blocks, a decode row's 513 three."""
+    from llm_d_tpu.ops.pallas import mla_masked
+    g = _config().mla_geometry(SLIDING)._replace(
+        num_heads=64, kv_lora_rank=1024, qk_rope_head_dim=64)
+    assert g.row_width == 1152
+    assert sparse_mla.window_q_tile(g, True) == 16
+    assert sparse_mla.window_q_tile(g, False) == sparse_mla.WINDOW_Q_TILE
+    assert mla_masked.pick_q_tile(128, 640, 512) == sparse_mla.SELECT_Q_TILE
+    assert mla_masked.pick_q_tile(64, 4096, 4096) == 8
+    assert mla_masked.pick_q_tile(2048, 128, 128) == 1
+    assert mla_masked.window_bias_blocks(16, 513) == 4
+    assert mla_masked.window_bias_blocks(1, 513) == 3
+    assert mla_masked.window_bias_blocks(1, 1) == 1
+    assert sparse_mla.kernel_refusal(g, 32, 32768) is None
+    assert "key blocks" in sparse_mla.kernel_refusal(g, 32, 32768 + 32)
+    assert "128-lane" in sparse_mla.kernel_refusal(
+        g._replace(kv_lora_rank=1000), 32, 32768)
+    assert not sparse_mla.kernel_serves(g, "reference", 32, 32768)
+    assert sparse_mla.kernel_serves(g, "pallas", 32, 32768)
 
 
 # ---------------------------------------------------------------------------
@@ -707,3 +815,90 @@ def test_the_kernels_are_not_claimed_for_layers_they_do_not_serve(served):
     eng = served[0]
     assert eng._prefill_tile_dims is None
     assert eng._attn_k_counts([40], [40], None) == {}
+    # The CPU serves the window through XLA: no walk to count.
+    assert eng._window_kernel is False
+    assert eng._attn_wk_counts([40], [40], None) == {}
+    assert not any(k.startswith("attn_wk") for k in _steps(eng)[0])
+
+
+def _published_window(**kw):
+    """The preset with the sliding layers' published geometry: 64 heads
+    over rows of 1,024 + 64 (1,152 cached), a window of 513; the full
+    layers' 128 heads over 512 + 64 (640)."""
+    return _config(**{**dict(
+        num_heads=128, kv_lora_rank=512, qk_rope_head_dim=64,
+        swa_num_heads=64, swa_kv_lora_rank=1024, swa_qk_rope_head_dim=64,
+        sliding_window=513, index_head_dim=128, max_model_len=32768), **kw})
+
+
+def _announced(config, backend="pallas", block_size=32):
+    """An engine shell after ``_announce_attention_path``, and what it
+    counted as disabled."""
+    from types import SimpleNamespace
+    eng = EngineCore.__new__(EngineCore)
+    eng.model_config = config
+    eng.config = SimpleNamespace(attn_backend=backend, mesh=None,
+                                 block_size=block_size)
+    seen = []
+    eng._disable_feature = lambda f, why, startup=False: seen.append((f, why))
+    eng._announce_attention_path(get_model(config).kv_cache_layout(config))
+    return eng, seen
+
+
+def test_a_window_the_kernel_serves_is_not_counted_as_disabled():
+    """``llmd_tpu:engine_feature_disabled_total{feature="pallas_attention"}``
+    speaks for a window layer only where the kernel refuses its geometry;
+    the decision is the geometry's and the backend's, nothing else."""
+    eng, seen = _announced(_published_window())
+    assert seen == [] and eng._window_kernel is True
+    eng, seen = _announced(_published_window(swa_num_heads=36))
+    assert eng._window_kernel is False
+    assert seen == [("pallas_attention",
+                     f"{SLIDING}: 36 heads are not whole sublane tiles of 8")]
+    eng, seen = _announced(_published_window(max_model_len=32768 + 32))
+    assert eng._window_kernel is False
+    assert sorted(k.split(":")[0] for _, k in seen) == sorted([FULL, SLIDING])
+    eng, seen = _announced(_published_window(), backend="reference")
+    assert seen == [] and eng._window_kernel is False
+
+
+def _window_walk_by_hand(ends, news, qt, window, layers):
+    """``attend_window``'s kernel path, a tile at a time (the loop bounds
+    of ``ops.pallas.mla_masked._masked_kernel`` under ``tile_first``)."""
+    real = slots = 0
+    for end, n in zip(ends, news):
+        for lo in range(end - n, end, qt):
+            q = range(lo, min(lo + qt, end))
+            real += sum(min(window, p + 1) for p in q)
+            first = max(q[0] - (window - 1), 0) // 256
+            slots += (q[-1] // 256 + 1 - first) * 256 * qt
+    return {"attn_wk_real": layers * real, "attn_wk_slots": layers * slots}
+
+
+@pytest.mark.parametrize("seed", range(3))
+@pytest.mark.parametrize("Q,qt", [(2048, 16), (4, 4), (1, 1)])
+def test_engine_counts_the_keys_its_window_walk_covers(Q, qt, seed):
+    from llm_d_tpu.engine.packed_batch import BatchLayout
+    eng, _ = _announced(_published_window())
+    rng = np.random.default_rng(seed)
+    news = [int(rng.choice([1, int(rng.integers(1, Q + 1))]))
+            for _ in range(6)]
+    ends = [n + int(rng.choice([0, int(rng.integers(0, 3000 - n))]))
+            for n in news]
+    got = eng._attn_wk_counts(ends, news, BatchLayout(2048, 8, Q, B=4))
+    assert got == _window_walk_by_hand(ends, news, qt, 513, 3)
+    assert 0 < got["attn_wk_real"] <= got["attn_wk_slots"]
+
+
+def test_the_window_tiles_are_counted_at_the_height_the_grid_holds():
+    """``attn_q_slots``: a mean over the layers of the slots the two tile
+    lists hold, the window layers' at ``window_q_tile``: (128 + 16) tiles
+    of 16 slots where the kernel serves, (16 + 16) of 128 where XLA does;
+    the full layers (256 + 16) of 8."""
+    from llm_d_tpu.engine.packed_batch import BatchLayout
+    layout = BatchLayout(2048, 16, 2048, B=4)
+    for backend, window_slots in (("pallas", 144 * 16), ("reference", 32 * 128)):
+        eng, _ = _announced(_published_window(), backend=backend)
+        assert eng._attn_q_counts(2000, layout) == {
+            "attn_q_real": 2000,
+            "attn_q_slots": (3 * 272 * 8 + 3 * window_slots) // 6}

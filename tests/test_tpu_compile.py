@@ -129,15 +129,35 @@ def _mla_masked(T, S, Q, H=128, F=640, R=512, B=1024, L=3):
     qt = min(SELECT_Q_TILE, Q)
     NT = num_query_tiles(T, S, qt)
 
-    def fn(q, bias, ts, live, kc, bt, layer):
-        return kern(q, bias, ts, live, kc, bt, layer, block_size=BS,
+    def fn(q, bias, ts, live, first, kc, bt, layer):
+        return kern(q, bias, ts, live, first, kc, bt, layer, block_size=BS,
                     scale=0.07, value_width=R)
 
     return fn, [_sds((NT, qt, H, F), jnp.bfloat16),
                 _sds((NT, B * BS // KEY_BLOCK, qt, KEY_BLOCK), jnp.float32),
                 _sds((NT,), jnp.int32), _sds((NT,), jnp.int32),
+                _sds((NT,), jnp.int32),
                 _sds((L, SLOTS, F), jnp.bfloat16), _sds((S, B), jnp.int32),
                 _sds((), jnp.int32)]
+
+
+def _mla_window(T, S, Q, H=64, F=1152, R=1024, window=513, B=1024, L=3):
+    """``attend_window`` through the kernel (the window's bias in XLA, the
+    walk from each tile's first visible key block in Mosaic) for a step of
+    T tokens in S rows, at the tile height the geometry picks."""
+    from llm_d_tpu.ops import sparse_mla
+
+    def fn(q, kc, bt, sl, pos, seq, qpos, qtok, layer):
+        batch = dict(block_tables=bt, seq_lens=sl, positions=pos,
+                     token_seq_ids=seq, token_qpos=qpos, qtok_idx=qtok)
+        return sparse_mla.attend_window(q, kc, batch, window, BS, layer,
+                                        0.07, R, kernel=True)
+
+    return fn, [_sds((T, H, F), jnp.bfloat16),
+                _sds((L, SLOTS, F), jnp.bfloat16), _sds((S, B), jnp.int32),
+                _sds((S,), jnp.int32), _sds((T,), jnp.int32),
+                _sds((T,), jnp.int32), _sds((T,), jnp.int32),
+                _sds((S, Q), jnp.int32), _sds((), jnp.int32)]
 
 
 def _ssm_update(H=32, P=128, N=256, G=2, S=64, L=6):
@@ -378,6 +398,13 @@ CASES = [
                  id="mla_masked-dots3-T2048-S16"),
     pytest.param(functools.partial(_mla_masked, T=16, S=16, Q=1),
                  id="mla_masked-dots3-decode-S16"),
+    # Its sliding layers: the same kernel's windowed walk, 64 heads over
+    # the 1,152-wide row with values of 1,024 and a window of 513 (tiles of
+    # 16 slots over 3-4 key blocks; a decode row one slot over 3).
+    pytest.param(functools.partial(_mla_window, T=2048, S=16, Q=2048),
+                 id="mla_masked-dots3-window-T2048-S16"),
+    pytest.param(functools.partial(_mla_window, T=16, S=16, Q=1),
+                 id="mla_masked-dots3-window-decode-S16"),
 ]
 
 
