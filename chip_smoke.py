@@ -27,6 +27,14 @@ holds the chip, at the full width of the models the repo benchmarks
      of 1,024, a window of 513); a query as a decode tile of one slot
      bit-equal to the same query inside its prefill tile.
 
+  5. hybrid-decoder phase — the Mamba-1 kernels
+     (``ops/pallas/ssm1_scan.py``: the selective scan over prompt pieces,
+     the one-token update) against their XLA forms through
+     ``ops.ssm.ssm1_state_update`` on one mixed step, and the one-query
+     read of another layer's cache plane (``paged_attention_read``) against
+     the chunked XLA path, at ``phi4-mini-flash``'s geometry (5,120
+     channels x 16 states float32, 40 paired heads over 10 of 128).
+
 ``--chips 4`` runs ONLY the four-chip phase (TP=4 dense, DP=4/EP=4 MoE, each
 against ``devices[0]`` alone; ``llama3-8b`` TP=4) and reports ``count: 4``.
 
@@ -81,6 +89,10 @@ MOE_A2A_OP_REL_RMS_TOL = 5e-2
 # mean |difference| over mean |value| (the two round the probabilities to
 # bf16 against different maxima; a wrong mask is an O(1) error).
 WINDOW_KERNEL_REL_TOL = 1e-2
+# The Mamba-1 kernels against their XLA forms, float32 on both sides: the
+# same recurrence token by token (the exponentials of two code generators,
+# carried through a chunk's tokens; a wrong piece or slot is an O(1) error).
+SSM1_KERNEL_REL_TOL = 1e-3
 # Per-device bytes_in_use on the four-chip host: max/min at most this.
 MEMORY_BALANCE_FACTOR = 1.5
 
@@ -751,6 +763,111 @@ def window_phase(heads: int, row_width: int, value_width: int, window: int,
 
 
 # --------------------------------------------------------------------------
+# phase 5: a decoder-hybrid-decoder's own kernels, at op level
+# --------------------------------------------------------------------------
+
+def hybrid_phase(inner: int, states: int, chunk: int, heads: int,
+                 kv_heads: int, head_dim: int,
+                 rows: Sequence[Sequence[int]], seed: int,
+                 block_size: int = 32, table_blocks: int = 1024,
+                 interpret: bool = False) -> Dict[str, float]:
+    """``rows``: (context end, new tokens) of each row of one step.  The
+    Mamba-1 state update of that step through the kernels against its XLA
+    forms (y and the pool), then one query a row over the rows' contexts
+    through ``paged_attention_read`` against the chunked XLA recurrence.
+    ``interpret``: the CPU rehearsal's Pallas interpreter."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llm_d_tpu.ops import attention as attn_ops
+    from llm_d_tpu.ops import ssm as ssm_ops
+    from llm_d_tpu.ops.pallas import paged_attention, ssm1_scan
+
+    check(not ssm_ops.ssm1_pallas_ineligible_reason(inner, states, chunk),
+          "the Mamba-1 kernels refuse this geometry")
+    rng = np.random.default_rng(seed)
+    S, T = len(rows), sum(n for _, n in rows)
+    ends = np.asarray([end for end, _ in rows])
+    news = np.asarray([n for _, n in rows])
+    starts = np.cumsum(news) - news
+    seq = np.repeat(np.arange(S), news)
+    need = -(-ends // block_size)
+    pages = rng.permutation(int(need.sum())) + 1
+    tables = np.zeros((S, table_blocks), np.int32)
+    for s in range(S):
+        tables[s, :need[s]] = pages[need[:s].sum():need[:s + 1].sum()]
+    batch = {k: jnp.asarray(v, jnp.int32) for k, v in dict(
+        query_start=starts, query_len=news, state_slot=np.arange(1, S + 1),
+        seq_lens=ends, token_seq_ids=seq,
+        token_qpos=np.arange(T) - starts[seq],
+        qtok_idx=np.zeros((S, max(news))), block_tables=tables).items()}
+    k = iter(jax.random.split(jax.random.PRNGKey(seed), 10))
+    x = jax.random.normal(next(k), (T, inner), jnp.bfloat16)
+    dt = jax.nn.softplus(jax.random.normal(next(k), (T, inner)) - 3.0)
+    A = -jnp.exp(jax.random.uniform(next(k), (states, inner), maxval=2.7))
+    B = 0.3 * jax.random.normal(next(k), (T, states), jnp.bfloat16)
+    C = 0.3 * jax.random.normal(next(k), (T, states), jnp.bfloat16)
+    D = jax.random.normal(next(k), (inner,))
+    pool = jax.random.normal(next(k), (2, S + 2, states, inner))
+    F = kv_heads * head_dim
+    q = jax.random.normal(next(k), (S, heads, head_dim), jnp.bfloat16)
+    slots = (int(need.sum()) + 1) * block_size
+    kc = jax.random.normal(next(k), (2, slots, F), jnp.bfloat16)
+    vc = jax.random.normal(next(k), (2, slots, F), jnp.bfloat16)
+
+    kernels = ((ssm1_scan, "ssm1_chunk_scan"),
+               (ssm1_scan, "ssm1_decode_update"),
+               (paged_attention, "paged_attention_read"))
+    real = [getattr(mod, name) for mod, name in kernels]
+    if interpret:
+        for (mod, name), fn in zip(kernels, real):
+            setattr(mod, name, functools.partial(fn, interpret=True))
+    try:
+        update = jax.jit(ssm_ops.ssm1_state_update, static_argnums=(9, 10))
+        got_y, got_pool = update(x, dt, A, B, C, D, pool, batch,
+                                 jnp.int32(1), chunk, "pallas")
+        want_y, want_pool = update(x, dt, A, B, C, D, pool, batch,
+                                   jnp.int32(1), chunk, "reference")
+        read = {backend: jax.jit(functools.partial(
+            attn_ops.attention_one_query, block_size=block_size,
+            scale=head_dim ** -0.5 * 2 ** 0.5, backend=backend))
+            for backend in ("pallas", "chunked")}
+        got_a = read["pallas"](q, kc, vc, batch, layer=jnp.int32(1))
+        want_a = read["chunked"](q, kc, vc, batch, layer=jnp.int32(1))
+    finally:
+        for (mod, name), fn in zip(kernels, real):
+            setattr(mod, name, fn)
+
+    def rel(got, want):
+        size = float(jnp.mean(jnp.abs(want.astype(jnp.float32))))
+        check(size > 0, "an XLA form computed nothing")
+        return float(jnp.mean(jnp.abs(
+            got.astype(jnp.float32) - want.astype(jnp.float32)))) / size
+
+    used = jnp.arange(1, S + 1)
+    info = {"ssm1_y_rel_diff": rel(got_y, want_y),
+            "ssm1_state_rel_diff": rel(got_pool[1, used], want_pool[1, used]),
+            "cross_read_rel_diff": rel(got_a, want_a)}
+    check(bool(jnp.array_equal(got_pool[0], pool[0])),
+          "the state update touched another layer's plane")
+    log(f"   Mamba-1 state update, {inner} channels x {states} states, "
+        f"pieces of {chunk}, rows {list(map(tuple, rows))}: mean |kernel - "
+        f"XLA| / mean |XLA| of y {info['ssm1_y_rel_diff']:.2e}, of the "
+        f"states {info['ssm1_state_rel_diff']:.2e}; one query a row over "
+        f"another plane, {heads} heads over {kv_heads} of {head_dim}: "
+        f"{info['cross_read_rel_diff']:.2e}")
+    check(max(info["ssm1_y_rel_diff"], info["ssm1_state_rel_diff"])
+          <= SSM1_KERNEL_REL_TOL,
+          "the Mamba-1 kernels differ from their XLA forms")
+    check(info["cross_read_rel_diff"] <= WINDOW_KERNEL_REL_TOL,
+          "the one-query read differs from the chunked XLA path")
+    return info
+
+
+# --------------------------------------------------------------------------
 # --chips 4: one program across the host
 # --------------------------------------------------------------------------
 
@@ -878,6 +995,14 @@ def run_one_chip(seed: int) -> None:
                      [(2300, 300), (40, 40), (511, 1), (512, 1), (513, 1),
                       (514, 1), (7001, 1), (9984, 1)], seed)
     settle("window phase")
+    with phase("hybrid-decoder phase [phi4-mini-flash's geometry]"):
+        # A continued chunk of 300 tokens (three pieces, the last partly
+        # filled), a prompt from position 0, decode rows at short and long
+        # contexts, a one-token prompt.
+        hybrid_phase(5120, 16, 128, 40, 10, 128,
+                     [(4396, 300), (150, 150), (9000, 1), (77, 1), (1, 1)],
+                     seed=seed)
+    settle("hybrid-decoder phase")
 
 
 def run_four_chips(seed: int) -> None:

@@ -54,7 +54,7 @@ from llm_d_tpu.engine.scheduler import Scheduler, SchedulerOutput
 from llm_d_tpu.engine.step_clock import StepClock
 from llm_d_tpu.models import get_model
 from llm_d_tpu.models.config import (
-    FULL, NO_WINDOW, SLIDING, ModelConfig, get_config)
+    CROSS, FULL, GMU, MAMBA, NO_WINDOW, SLIDING, ModelConfig, get_config)
 from llm_d_tpu.ops import sampling as sampling_ops
 from llm_d_tpu.ops.parts import part
 from llm_d_tpu.parallel.mesh import MeshConfig, make_mesh
@@ -837,6 +837,33 @@ class EngineCore:
             return
         self._prefill_tile_dims = (
             c.num_heads // heads_tp, next(iter(layout.values())) // tp)
+        if c.mixer_by_layer:
+            self._announce_layer_kinds()
+
+    def _announce_layer_kinds(self) -> None:
+        """The path each layer KIND of a decoder-hybrid-decoder stack took
+        (Pallas backend, cache geometry eligible): static per engine, as
+        the attention path above."""
+        from llm_d_tpu.ops.ssm import ssm1_pallas_ineligible_reason
+        c = self.model_config
+        reason = ssm1_pallas_ineligible_reason(
+            c.ssm_inner_size, c.ssm_state_size, c.ssm_chunk_size)
+        if reason:
+            self._disable_feature("pallas_ssm", reason)
+        paths = {
+            MAMBA: ("XLA selective scan and one-token update" if reason else
+                    "ssm1_chunk_scan / ssm1_decode_update (Pallas)"),
+            SLIDING: "flash_prefill_paged / paged_attention_decode_update, "
+                     "heads paired, window %d" % c.sliding_window,
+            FULL: "paged_attention_read on the sampled rows (layer %d: the "
+                  "step's keys and values scattered first); "
+                  "flash_prefill_paged elsewhere" % c.cross_kv_layer,
+            CROSS: "paged_attention_read over layer %d's plane, nothing "
+                   "written" % c.cross_kv_layer,
+            GMU: "XLA dots on the sampled rows"}
+        for kind in dict.fromkeys(c.layer_types):
+            logger.info("layer kind %s x %d: %s", kind,
+                        c.layer_types.count(kind), paths[kind])
 
     def _spec_blockers(self) -> List[str]:
         """Startup conditions that would force spec decode off.  Empty
@@ -2942,6 +2969,10 @@ class EngineCore:
         self._step_kv = self._kv_counts(ends, news)
         if self._has_state:
             self._step_kv.update(self._state_counts(ends, news))
+        if self.model_config.mixer_by_layer:
+            self._step_kv.update(self._cross_decoder_counts(
+                ends, [e == sr.request.num_tokens
+                       for e, sr in zip(ends, scheduled)]))
         if Q > 1:
             self._step_kv.update(
                 self._attn_q_counts(int(np.sum(news)), layout))
@@ -2972,9 +3003,18 @@ class EngineCore:
             # A full layer: queries at positions L - n .. L - 1 read p + 1
             # keys.
             full = int((news * (2 * ends - news + 1)).sum()) // 2
-        counts = {"kv_ctx_tokens": c.num_layers * full,
-                  "kv_read_tokens": c.num_layers * full,
-                  "kv_held_tokens": c.num_layers * int(ends.sum()),
+        # Layers whose attention every token of the step goes through, and
+        # planes the rows' tokens are held in.  A stack with mixers by layer
+        # (models/hybrid_decoder.py): the self-decoder's attention layers;
+        # the plane ``cross_kv_layer`` writes is held too, and read by the
+        # sampled rows only (``_cross_decoder_counts`` adds those reads).
+        attending = planes = c.num_layers
+        if c.mixer_by_layer:
+            planes = len(c.layers_of(SLIDING, FULL))
+            attending = planes - 1
+        counts = {"kv_ctx_tokens": attending * full,
+                  "kv_read_tokens": attending * full,
+                  "kv_held_tokens": planes * int(ends.sum()),
                   "kv_dead_tokens": 0}
         upto = _keys_seen_upto
         n_window = c.layer_types.count(SLIDING)
@@ -2994,6 +3034,22 @@ class EngineCore:
             counts["kv_selected_tokens"] = n_full * selected
             counts["index_pairs"] = n_full * full
         return counts
+
+    def _cross_decoder_counts(self, ends, sampled) -> Dict[str, int]:
+        """What a dispatch of a decoder-hybrid-decoder stack asks of its
+        cross-decoder (step_clock.py): only the rows the step samples from
+        (``sampled[r]``) go through ``cross_kv_layer``'s attention and the
+        layers after it, and each of those attentions (that layer's own and
+        the CROSS layers') reads the row's whole context from the one shared
+        plane: added to the step's ``kv_ctx_tokens`` / ``kv_read_tokens``
+        here."""
+        c = self.model_config
+        ends = np.asarray(ends, np.int64)[np.asarray(sampled, bool)]
+        reads = (1 + len(c.layers_of(CROSS))) * int(ends.sum())
+        kv = self._step_kv
+        return {"xdec_rows": len(ends), "xattn_read_tokens": reads,
+                "kv_ctx_tokens": kv["kv_ctx_tokens"] + reads,
+                "kv_read_tokens": kv["kv_read_tokens"] + reads}
 
     def _state_counts(self, ends, news) -> Dict[str, int]:
         """What a dispatch asks of the state pool (step_clock.py): rows
@@ -3061,7 +3117,7 @@ class EngineCore:
         c = self.model_config
         bs = self.config.block_size
         qt = prefill_q_tile(layout.Q, *self._prefill_tile_dims, c.use_mla)
-        kb = prefill_key_block(qt, *self._prefill_tile_dims, c.head_dim_,
+        kb = prefill_key_block(qt, *self._prefill_tile_dims, c.attn_head_dim,
                                bs, c.use_mla)
         q_first, q_last = _tile_spans(ends, news, qt)
         if c.diffusion_block_length:    # the last key its last query sees
@@ -3069,8 +3125,12 @@ class EngineCore:
             q_last = (q_last // B + 1) * B - 1
         real = slots = 0
         n_window = c.layer_types.count(SLIDING)
+        # (a stack with mixers by layer: the self-decoder's attention layers;
+        # ``cross_kv_layer`` and the layers after it attend a query a row)
+        n_full = (len(c.layers_of(FULL)) - 1 if c.mixer_by_layer
+                  else c.num_layers - n_window)
         for window, layers in ((c.sliding_window, n_window),
-                               (NO_WINDOW, c.num_layers - n_window)):
+                               (NO_WINDOW, n_full)):
             if not layers:
                 continue
             k_first = np.maximum(q_first - window + 1, 0)

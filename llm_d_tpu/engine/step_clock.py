@@ -99,6 +99,19 @@ and, for a stack with recurrent state beside its pages
   ssm_resets          rows whose chunk starts at position 0: the program
                       zeroes their state (new, or preempted and recomputed)
 
+and, for a decoder-hybrid-decoder stack (``ModelConfig.mixer_by_layer``;
+``EngineCore._cross_decoder_counts``; the ``kv_*`` counts above then go by
+the layers that attend and the planes that are held: the self-decoder's
+attention layers read, ``cross_kv_layer``'s plane is held beside theirs):
+
+  xdec_rows          rows the step samples from: the only ones that go
+                     through ``cross_kv_layer``'s attention and the layers
+                     after it (beside ``prefill_tokens + decode_tokens``)
+  xattn_read_tokens  sum over those rows of their context x the layers that
+                     read the shared plane (``cross_kv_layer`` itself and
+                     the CROSS layers), a query a row; part of
+                     ``kv_ctx_tokens`` and ``kv_read_tokens`` too
+
 and, counted by the step program itself and fetched with the step's ids
 (the classic path of an MoE stack on one device; ``EngineCore._moe_counts``;
 also on the ``llmd.post`` annotation of the iteration that retired the step,

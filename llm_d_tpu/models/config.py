@@ -11,11 +11,19 @@ models the reference's well-lit paths deploy: Qwen3-0.6B
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax.numpy as jnp
 
 SLIDING, FULL = "sliding_attention", "full_attention"
+# Mixers that are not self-attention over a layer's own keys (a stack that
+# names any of them is walked by models/hybrid_decoder.py): a Mamba-1
+# selective state-space mixer; a gated memory unit, which gates the memory
+# that ``gmu_memory_layer``'s mixer produced for the same token; attention
+# that projects a query only and reads ``cross_kv_layer``'s keys and values.
+MAMBA, GMU, CROSS = "mamba", "gmu", "cross_attention"
+LAYER_KINDS = (SLIDING, FULL, MAMBA, GMU, CROSS)
 # The window of a full-attention layer: one that never binds (above any
 # position, with room to add a position without overflowing int32).
 NO_WINDOW = 1 << 30
@@ -160,6 +168,27 @@ class ModelConfig:
     ssm_conv_kernel: int = 4
     ssm_chunk_size: int = 128               # tokens a piece of the scan
     ssm_inner_size: int = 0                 # 0: heads x head size
+    # > 0: the mixer is Mamba-1 (a MAMBA layer of ``layer_types``, in place
+    # of a layer's attention and not beside it): the decay is A[N, inner]
+    # by channel and state, dt comes from a rank-``ssm_dt_rank`` bottleneck
+    # and the recurrent state is [N, inner] float32 a layer and slot, with
+    # no heads or groups (ops/ssm.py, the ``ssm1_*`` forms).
+    ssm_dt_rank: int = 0
+    # --- a decoder-hybrid-decoder stack (``layer_types`` names mixers) ---
+    # The layers after ``cross_kv_layer`` keep no state of their own: CROSS
+    # layers attend over that FULL layer's cache plane, GMU layers read the
+    # memory ``gmu_memory_layer`` (a MAMBA layer, just before it) computed
+    # for the same token.  So only the rows a step samples from go through
+    # ``cross_kv_layer``'s attention and everything after it.  -1 = none.
+    cross_kv_layer: int = -1
+    gmu_memory_layer: int = -1
+    # Differential attention: heads pair up (neighbours), a pair's output is
+    # softmax(q1 k1) V - lambda softmax(q2 k2) V over the pair's two value
+    # heads side by side, RMS-normed and scaled by 1 - lambda_init(layer).
+    diff_attention: bool = False
+    norm_kind: str = "rms"                  # "layer": LayerNorm, weight + bias
+    use_rope: bool = True                   # False: no positional encoding
+    attention_out_bias: bool = False        # a bias on o_proj too
     # --- muP multipliers, applied where the published code applies them ---
     # (``embed_scale`` above is the embedding's.)
     lm_head_multiplier: float = 1.0         # on the logits
@@ -221,14 +250,36 @@ class ModelConfig:
         return self.ssm_state_size > 0
 
     @property
+    def mixer_by_layer(self) -> bool:
+        """``layer_types`` names mixers that are not self-attention: the
+        stack is a decoder-hybrid-decoder (models/hybrid_decoder.py)."""
+        return bool(set(self.layer_types) & {MAMBA, GMU, CROSS})
+
+    def layers_of(self, *kinds: str) -> Tuple[int, ...]:
+        """The layers of ``layer_types`` of the given kinds, in order."""
+        return tuple(i for i, t in enumerate(self.layer_types) if t in kinds)
+
+    @property
     def ssm_inner_size_(self) -> int:
         return self.ssm_inner_size or self.ssm_num_heads * self.ssm_head_dim
 
     @property
     def ssm_conv_channels(self) -> int:
-        """x, B and C: what the mixer's causal convolution runs over."""
+        """What the mixer's causal convolution runs over: x, B and C
+        (Mamba-2), x alone (Mamba-1)."""
+        if self.ssm_dt_rank:
+            return self.ssm_inner_size_
         return (self.ssm_inner_size_
                 + 2 * self.ssm_num_groups * self.ssm_state_size)
+
+    @property
+    def attn_head_dim(self) -> int:
+        """The head size the attention kernels and the cache row see:
+        differential attention feeds them a PAIR of heads as one."""
+        return self.head_dim_ * (2 if self.diff_attention else 1)
+
+    def diff_lambda_init(self, layer: int) -> float:
+        return 0.8 - 0.6 * math.exp(-0.3 * layer)
 
     def __post_init__(self):
         if self.scoring_func not in ("softmax", "sigmoid"):
@@ -243,12 +294,13 @@ class ModelConfig:
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         kinds = self.layer_types
         if kinds:
-            if len(kinds) != self.num_layers or set(kinds) - {SLIDING, FULL}:
+            if len(kinds) != self.num_layers or set(kinds) - set(LAYER_KINDS):
                 raise ValueError(
-                    f"layer_types must name {self.num_layers} layers as "
-                    f"{SLIDING!r} or {FULL!r}, got {len(kinds)}: {kinds}")
+                    f"layer_types must name {self.num_layers} layers, each "
+                    f"one of {LAYER_KINDS}, got {len(kinds)}: {kinds}")
             if SLIDING in kinds and self.sliding_window < 1:
                 raise ValueError("sliding layers need sliding_window >= 1")
+        self._check_hybrid_decoder()
         if self.use_mla and (self.attn_output_gate or self.sandwich_norm
                              or not self.rope_on_full_attention):
             raise ValueError(
@@ -283,15 +335,32 @@ class ModelConfig:
         for name in ("ssm_multipliers", "mlp_multipliers"):
             object.__setattr__(self, name, tuple(getattr(self, name)))
         if self.has_recurrent_state:
-            if (self.use_mla or kinds or self.diffusion_block_length
-                    or self.is_moe):
+            # What a recurrent state is not served with, one refusal a
+            # field.
+            for field, on in (("kv_lora_rank", self.use_mla),
+                              ("diffusion_block_length",
+                               bool(self.diffusion_block_length)),
+                              ("num_experts", self.is_moe)):
+                if on:
+                    raise ValueError(
+                        f"ssm_state_size with {field}: a state-space mixer "
+                        f"is served in a dense autoregressive stack with "
+                        f"GQA attention; {field} is not")
+            if kinds and not self.mixer_by_layer:
                 raise ValueError(
-                    "a state-space mixer is served beside the GQA attention "
-                    "block of a dense autoregressive stack with every layer "
-                    "full; MLA, layer_types, block diffusion and experts "
-                    "are not")
+                    "ssm_state_size with layer_types of attention kinds "
+                    "only: a mixer BESIDE attention is served with every "
+                    "layer full; name the mixers' layers (\"mamba\") for a "
+                    "mixer in place of attention")
             H, G = self.ssm_num_heads, self.ssm_num_groups
-            if (H < 1 or self.ssm_head_dim < 1 or G < 1 or H % G
+            if self.ssm_dt_rank:
+                if not self.mixer_by_layer or H or self.ssm_head_dim \
+                        or self.ssm_inner_size < 1:
+                    raise ValueError(
+                        "ssm_dt_rank (Mamba-1) needs MAMBA layers in "
+                        "layer_types and ssm_inner_size, and has neither "
+                        "ssm_num_heads nor ssm_head_dim")
+            elif (H < 1 or self.ssm_head_dim < 1 or G < 1 or H % G
                     or self.ssm_inner_size_ != H * self.ssm_head_dim):
                 raise ValueError(
                     f"ssm_num_heads {H} x ssm_head_dim {self.ssm_head_dim} "
@@ -325,6 +394,76 @@ class ModelConfig:
                 raise ValueError(
                     f"mask_token_id {self.mask_token_id} is outside the "
                     f"vocabulary of {self.vocab_size}")
+
+    def _check_hybrid_decoder(self) -> None:
+        """The one form of a stack with mixers by layer that
+        models/hybrid_decoder.py walks, each refusal by its field."""
+        kinds = self.layer_types
+        hybrid_only = (
+            ("cross_kv_layer", self.cross_kv_layer >= 0),
+            ("gmu_memory_layer", self.gmu_memory_layer >= 0),
+            ("diff_attention", self.diff_attention),
+            ("norm_kind", self.norm_kind != "rms"),
+            ("use_rope", not self.use_rope),
+            ("attention_out_bias", self.attention_out_bias),
+            ("ssm_dt_rank", self.ssm_dt_rank > 0))
+        if self.norm_kind not in ("rms", "layer"):
+            raise ValueError(
+                f"norm_kind must be 'rms' or 'layer', got {self.norm_kind!r}")
+        if not self.mixer_by_layer:
+            for field, on in hybrid_only:
+                if on:
+                    raise ValueError(
+                        f"{field} belongs to a stack whose layer_types name "
+                        f"mixers ({MAMBA!r}, {GMU!r}, {CROSS!r})")
+            return
+        for field, on in (("kv_lora_rank", self.use_mla),
+                          ("num_experts", self.is_moe),
+                          ("diffusion_block_length",
+                           bool(self.diffusion_block_length)),
+                          ("attn_output_gate", self.attn_output_gate),
+                          ("sandwich_norm", self.sandwich_norm),
+                          ("qk_norm", self.qk_norm)):
+            if on:
+                raise ValueError(
+                    f"{field} with mixers in layer_types: the hybrid "
+                    f"decoder is dense GQA attention, Mamba-1 and gated "
+                    f"memory units; {field} is not served there")
+        if self.use_rope:
+            raise ValueError(
+                "use_rope with mixers in layer_types: the hybrid decoder's "
+                "attention carries no positional encoding (the recurrent "
+                "layers carry the order)")
+        if not self.ssm_dt_rank or self.ssm_state_size < 1:
+            raise ValueError(
+                "MAMBA layers need ssm_dt_rank, ssm_state_size and "
+                "ssm_inner_size (Mamba-1)")
+        m, x = self.gmu_memory_layer, self.cross_kv_layer
+        if not (0 <= m and x == m + 1 and x < self.num_layers
+                and m % 2 == 0 and self.num_layers % 2 == 0):
+            raise ValueError(
+                f"gmu_memory_layer {m} and cross_kv_layer {x} must be an "
+                f"even layer and the one after it: the stack is walked in "
+                f"pairs (mixer, attention)")
+        want = tuple(
+            (MAMBA if li <= m else GMU) if li % 2 == 0
+            else (FULL if li == x else None if li < x else CROSS)
+            for li in range(self.num_layers))
+        for li, (got, w) in enumerate(zip(kinds, want)):
+            if got != w and not (w is None and got in (SLIDING, FULL)):
+                raise ValueError(
+                    f"layer_types[{li}] is {got!r}: with gmu_memory_layer "
+                    f"{m} and cross_kv_layer {x} the hybrid decoder serves "
+                    f"{MAMBA!r} on even layers up to {m}, {SLIDING!r} or "
+                    f"{FULL!r} on odd layers before {x}, {FULL!r} at {x}, "
+                    f"then {GMU!r} / {CROSS!r} alternating")
+        if self.diff_attention and (self.num_heads % 2 or self.num_kv_heads % 2
+                                    or (self.num_heads // 2)
+                                    % (self.num_kv_heads // 2)):
+            raise ValueError(
+                f"diff_attention pairs neighbouring heads: {self.num_heads} "
+                f"query and {self.num_kv_heads} key-value heads are not "
+                f"whole pairs in whole groups")
 
     def diffusion_quota(self, step: int) -> int:
         """Masked slots the ``step``-th denoising pass of a block reveals."""
@@ -430,6 +569,24 @@ PRESETS = {
         ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
                          0.3535533905932738),
         mlp_multipliers=(0.1767766952966369, 0.011160714285714284)),
+    # Phi-4-mini-flash-reasoning (SambaY, arXiv:2507.06607): a self-decoder
+    # of Mamba-1 and window-512 differential attention layers alternating,
+    # one full-attention layer (17) whose keys and values the seven cross
+    # layers after it read, gated memory units on layer 16's memory between
+    # them; LayerNorm, no positional encoding.  What is no key of the
+    # published config is listed under ``assumed`` in
+    # benchmarks/configs/phi4-mini-flash.json.
+    "phi4-mini-flash": ModelConfig(
+        name="phi4-mini-flash", vocab_size=200064, hidden_size=2560,
+        intermediate_size=10240, num_layers=32, num_heads=40,
+        num_kv_heads=20, head_dim=64, rms_norm_eps=1e-5,
+        tie_word_embeddings=True, attention_bias=True,
+        attention_out_bias=True, max_model_len=32768,
+        layer_types=(MAMBA, SLIDING) * 8 + (MAMBA, FULL) + (GMU, CROSS) * 7,
+        sliding_window=512, cross_kv_layer=17, gmu_memory_layer=16,
+        ssm_state_size=16, ssm_inner_size=5120, ssm_dt_rank=160,
+        ssm_conv_kernel=4, ssm_chunk_size=128, diff_attention=True,
+        norm_kind="layer", use_rope=False),
     "mixtral-8x22b": ModelConfig(
         name="mixtral-8x22b", vocab_size=32768, hidden_size=6144,
         intermediate_size=16384, num_layers=56, num_heads=48, num_kv_heads=8,
@@ -503,6 +660,20 @@ PRESETS = {
         ssm_out_multiplier=0.09375,
         ssm_multipliers=(0.375, 0.25, 0.1875, 0.5, 0.3125),
         mlp_multipliers=(0.1875, 0.03125)),
+    # Tiny decoder-hybrid-decoder for CPU tests: all five layer kinds, a
+    # window (24) under the tests' prompts, a scan piece of 8 tokens, an
+    # inner width that is not twice the hidden one, 4 states, a query group
+    # of 2 pairs a key-value pair.
+    "tiny-hybrid-decoder": ModelConfig(
+        name="tiny-hybrid-decoder", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_layers=10, num_heads=8, num_kv_heads=4,
+        head_dim=8, max_model_len=512, tie_word_embeddings=True,
+        attention_bias=True, attention_out_bias=True,
+        layer_types=(MAMBA, SLIDING) * 2 + (MAMBA, FULL) + (GMU, CROSS) * 2,
+        sliding_window=24, cross_kv_layer=5, gmu_memory_layer=4,
+        ssm_state_size=4, ssm_inner_size=96, ssm_dt_rank=6,
+        ssm_conv_kernel=4, ssm_chunk_size=8, diff_attention=True,
+        norm_kind="layer", use_rope=False),
     # Tiny mixed MLA stack for CPU tests: kinds F F S S S F with a leading
     # dense layer, two latent geometries (heads, ranks, head sizes and rotary
     # base all differ), a top-k and a window both under the tests' contexts,

@@ -648,3 +648,59 @@ def attention_with_kv_update(
             block_size=block_size, scale=scale, soft_cap=soft_cap,
             layer=layer, window=window, limits=limits)
     return out, k_cache, v_cache
+
+
+def attention_one_query(
+    q: jax.Array,            # [S, H, D]: one query a row
+    k_cache: jax.Array,      # stacked [L, slots, KVH*D]
+    v_cache: jax.Array,
+    batch,                   # block_tables [S, B], seq_lens [S]
+    block_size: int,
+    scale=None,
+    backend: str = "auto",
+    layer: Optional[jax.Array] = None,
+):
+    """One query a row over plane ``layer`` of a cache that ALREADY holds
+    the row's keys and values up to the query's own (position ``seq_lens -
+    1``): cross-layer attention, which reads another layer's plane and
+    writes none.  Nothing is written.  The Pallas backend takes the decode
+    kernel's page walk (``paged_attention_read``), the others the chunked
+    XLA recurrence.  Returns [S, H, D]; a padded row (``seq_lens`` 0)
+    zeros."""
+    S, H, D = q.shape
+    F = k_cache.shape[-1]
+    seq_lens, tables = batch["seq_lens"], batch["block_tables"]
+    if (resolve_backend(backend) == "pallas"
+            and pallas_ineligible_reason(block_size, F) is None):
+        from llm_d_tpu.ops.pallas.paged_attention import paged_attention_read
+        return paged_attention_read(
+            q, k_cache, v_cache, tables, seq_lens, block_size=block_size,
+            num_kv_heads=F // D, scale=scale, layer=layer)
+    C = tables.shape[1] * block_size
+    slot_ids = (tables[:, :, None] * block_size
+                + jnp.arange(block_size)[None, None, :]).reshape(S, C)
+    return _flash_over_kv_chunks(
+        q[:, None], (seq_lens - 1)[:, None], slot_ids, seq_lens, k_cache,
+        v_cache, _chunk_size_for(C), scale if scale is not None
+        else D ** -0.5, None, layer=layer)[:, 0]
+
+
+def diff_pair_queries(q: jax.Array) -> jax.Array:
+    """Differential attention through kernels that know GQA only: a PAIR of
+    neighbouring heads is one head of twice the size.  ``q`` [T, H, D] ->
+    [T, H, 2D]: query s of a pair in half s, zero in the other, so that
+    against the pair's keys side by side ``[k1, k2]`` the score is ``q_s .
+    k_s``, and over the pair's values side by side the output is ``P_s [v1,
+    v2]``.  The keys and values need no copy: KVH heads of D folded in a
+    cache row ARE KVH / 2 heads of 2D."""
+    T, H, D = q.shape
+    half = jnp.eye(2, dtype=q.dtype).reshape(1, 1, 2, 2, 1)
+    return (q.reshape(T, H // 2, 2, 1, D) * half).reshape(T, H, 2 * D)
+
+
+def diff_combine(out: jax.Array, lam: jax.Array) -> jax.Array:
+    """``out`` [T, H, 2D], the attention of ``diff_pair_queries``' heads ->
+    float32 [T, H / 2, 2D]: P_1 V - lambda P_2 V of each pair."""
+    T, H, D2 = out.shape
+    o = out.reshape(T, H // 2, 2, D2).astype(jnp.float32)
+    return o[:, :, 0] - lam * o[:, :, 1]

@@ -54,6 +54,7 @@ def _decode_kernel(
     scale: float,
     group: int,
     windowed: bool,
+    write: bool = True,
 ):
     """Fused decode attention + KV update on the STACKED cache.
 
@@ -164,7 +165,7 @@ def _decode_kernel(
 
         # On each sequence's write page (exactly once per call): splice the
         # new-token row into the resident page and write the page back.
-        for g in range(G):
+        for g in range(G if write else 0):
             @pl.when(page_of(g, j) == write_page_g[g])
             def _(g=g):
                 is_wr = row_ids2 == w_row_g[g]
@@ -331,3 +332,64 @@ def paged_attention_decode_update(
         k_cache = k_cache[0]
         v_cache = v_cache[0]
     return out, k_cache, v_cache
+
+
+def _read_kernel(block_tables_ref, seq_lens_ref, layer_ref, q_ref, k_hbm,
+                 v_hbm, o_ref, k_buf, v_buf, sems, **kw):
+    _decode_kernel(block_tables_ref, seq_lens_ref, layer_ref, q_ref, None,
+                   None, k_hbm, v_hbm, o_ref, None, None, k_buf, v_buf, sems,
+                   None, write=False, **kw)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("block_size", "num_kv_heads", "scale",
+                              "interpret", "seq_group"))
+def paged_attention_read(
+    q: jax.Array,             # [S, H, D]
+    k_cache: jax.Array,       # [L, num_slots, KVH*D]
+    v_cache: jax.Array,
+    block_tables: jax.Array,  # [S, B]
+    seq_lens: jax.Array,      # [S]: the query of row s sees keys < seq_lens[s]
+    block_size: int,
+    num_kv_heads: int,
+    scale: float | None = None,
+    layer: jax.Array | None = None,
+    interpret: bool = False,
+    seq_group: int | None = None,
+):
+    """One query a row over a plane of the stacked cache that holds the
+    query's own keys and values already (another layer's plane, or this
+    layer's after the step's rows were scattered): the decode kernel's
+    walk with no row to splice in and no page to write back.  Returns
+    attn_out [S, H, D]."""
+    S, H, D = q.shape
+    scale = scale if scale is not None else D ** -0.5
+    F = k_cache.shape[2]
+    G = pick_seq_group(
+        S, seq_group,
+        4 * block_size * F * k_cache.dtype.itemsize + 8 * H * F)
+    layer_arr = jnp.asarray([0 if layer is None else layer], jnp.int32)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    q_spec = pl.BlockSpec((G, H, D), lambda i, *_: (i, 0, 0),
+                          memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        functools.partial(
+            _read_kernel, block_size=block_size, num_kv_heads=num_kv_heads,
+            scale=scale, group=G, windowed=False),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S // G,),
+            in_specs=[q_spec, any_spec, any_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((2, G, block_size, F), k_cache.dtype),
+                pltpu.VMEM((2, G, block_size, F), v_cache.dtype),
+                pltpu.SemaphoreType.DMA((2, G, 2)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="paged_attention_read",
+        interpret=interpret,
+    )(block_tables, seq_lens, layer_arr, q, k_cache, v_cache)

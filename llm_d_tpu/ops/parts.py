@@ -20,6 +20,11 @@ operation, whatever kernel or fusion implements the part.
   attn.index    a layer that selects its keys (ops/sparse_mla.py): the
                 indexer's projections, the index key's norm and cache write,
                 the scores against the cached index keys and the top-k
+  attn.cross    one query a row over ANOTHER layer's cache plane (a stack
+                whose last layers keep no keys of their own): the kernel or
+                the chunked XLA path, nothing written
+  gmu           a gated memory unit: its two projections and the gate on
+                the memory an earlier layer's mixer left
   router        the router's dot, the top-k, the count of experts touched,
                 and the norm that feeds the MoE block
   experts       ``ops.moe.expert_ffn``: every kernel of the family and its
@@ -45,8 +50,8 @@ import jax
 
 PREFIX = "llmd."
 PARTS = ("embed", "tiles", "attn.proj", "attn.decode", "attn.prefill",
-         "attn.index", "router", "experts", "shared", "mlp", "ssm.proj", "ssm.state",
-         "scan", "head", "sample")
+         "attn.index", "attn.cross", "gmu", "router", "experts", "shared",
+         "mlp", "ssm.proj", "ssm.state", "scan", "head", "sample")
 
 
 def part(name: str):

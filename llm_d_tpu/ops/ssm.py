@@ -277,3 +277,131 @@ def state_update(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         y = jnp.where(single[rows][:, None, None], y, yn)
     y = y + D.astype(F32)[None, :, None] * x.astype(F32)
     return y.astype(x.dtype), pool
+
+
+# --------------------------------------------------------------------------
+# Mamba-1: the decay is A[N, inner] by channel and state, so there are no
+# heads and no dots; the state of a slot is [N, inner] float32 (channels on
+# the lanes) and a token's update is elementwise over it:
+#
+#   S_t = exp(dt_t A) * S_{t-1} + B_t (outer) (dt_t x_t)     y_t = C_t . S_t
+#
+# with ``dt`` [T, inner] by channel and ``B``, ``C`` [T, N] shared by all
+# channels.  The same pool life cycle, the same list of pieces, the same two
+# computations as above (``ops.pallas.ssm1_scan``).
+# --------------------------------------------------------------------------
+
+def ssm1_pallas_ineligible_reason(inner: int, N: int, chunk: int) -> str:
+    """Why the Mamba-1 kernels cannot serve a geometry ('' = they can): a
+    grid program holds whole 128-lane blocks of channels and whole float32
+    sublane tiles of states and of tokens."""
+    from llm_d_tpu.ops.pallas.ssm1_scan import (
+        TOKENS_PER_GROUP, channel_block)
+    if not channel_block(inner):
+        return f"inner width {inner} is no multiple of 128 lanes"
+    if N % 8 or chunk % TOKENS_PER_GROUP:
+        return (f"{N} states, scan pieces of {chunk}: not whole float32 "
+                f"sublane tiles")
+    return ""
+
+
+def ssm1_decode_update_reference(dt, xdt, A, B, C, pool, layer, slot, fresh):
+    """The one-token update in XLA: ``dt``, ``xdt`` [S, inner] float32,
+    ``A`` [N, inner], ``B``, ``C`` [S, N], ``pool`` [L, slots, N, inner].
+    Returns (S_t C_t [S, inner], pool)."""
+    s0 = jnp.where(fresh[:, None, None], 0.0, pool[layer, slot])
+    s1 = jnp.exp(dt[:, None, :] * A[None]) * s0 \
+        + B.astype(F32)[:, :, None] * xdt[:, None, :]
+    y = jnp.sum(s1 * C.astype(F32)[:, :, None], axis=1)
+    return y, pool.at[layer, slot].set(s1)
+
+
+def ssm1_chunk_scan_pallas(dt, xdt, A, B, C, pool, layer, batch, chunk: int):
+    """``ssm1_chunk_scan`` through the kernel: XLA lays the step's tokens
+    out by piece and takes the kernel's output back to the packed batch."""
+    from llm_d_tpu.ops.pallas.ssm1_scan import ssm1_chunk_scan as kernel
+    T = dt.shape[0]
+    pc = scan_pieces(batch, T, chunk)
+    within = jnp.arange(chunk)[None, :]
+    idx = jnp.clip(pc["start"][:, None] + within, 0, T - 1)     # [NT, c]
+    live = (within < pc["length"][:, None])[:, :, None]
+    y, pool = kernel(
+        jnp.where(live, dt[idx], 0.0), jnp.where(live, xdt[idx], 0.0), A,
+        B[idx], C[idx], pool, layer, pc["slot"], pc["first"], pc["fresh"],
+        pc["live"])
+    return y[pc["tok_piece"], pc["tok_off"]], pool
+
+
+def ssm1_chunk_scan(dt, xdt, A, B, C, pool, layer, batch, chunk: int):
+    """The selective scan in XLA over the rows of more than one token:
+    ``dt``, ``xdt`` [T, inner] float32, ``A`` [N, inner], ``B``, ``C`` [T,
+    N], ``pool`` [L, slots, N, inner].  A row's chunk is walked in pieces
+    (``scan_pieces``), a piece token by token (``lax.scan``), the state
+    going from piece to piece and from this step to the row's next through
+    the row's slot.  Returns (S_t C_t [T, inner] float32 with rows of one
+    token left unwritten, pool)."""
+    T, inner = dt.shape
+    pc = scan_pieces(batch, T, chunk)
+
+    def padded(a):      # a piece read at the batch's end stays in bounds
+        return jnp.pad(a, ((0, chunk),) + ((0, 0),) * (a.ndim - 1))
+
+    dtp, xp = padded(dt), padded(xdt)
+    bp, cp = padded(B.astype(F32)), padded(C.astype(F32))
+
+    def piece(i, carry):
+        pool, y = carry
+        t0, n, sl = pc["start"][i], pc["length"][i], pc["slot"][i]
+
+        def take(a):
+            return jax.lax.dynamic_slice_in_dim(a, t0, chunk, axis=0)
+
+        live = (jnp.arange(chunk) < n)[:, None]
+        s0 = jnp.where(pc["first"][i] & pc["fresh"][i], 0.0, pool[layer, sl])
+
+        def token(s, inp):
+            dt_t, x_t, b_t, c_t = inp
+            s = jnp.exp(dt_t[None, :] * A) * s + b_t[:, None] * x_t[None, :]
+            return s, jnp.sum(s * c_t[:, None], axis=0)
+
+        s1, ys = jax.lax.scan(token, s0, (
+            jnp.where(live, take(dtp), 0.0), jnp.where(live, take(xp), 0.0),
+            take(bp), take(cp)))
+        return (pool.at[layer, sl].set(s1),
+                jax.lax.dynamic_update_slice_in_dim(y, ys, t0, axis=0))
+
+    pool, y = jax.lax.fori_loop(
+        0, pc["count"], piece, (pool, jnp.zeros((T + chunk, inner), F32)))
+    return y[:T], pool
+
+
+def ssm1_state_update(x: jax.Array, dt: jax.Array, A: jax.Array,
+                      B: jax.Array, C: jax.Array, D: jax.Array,
+                      pool: jax.Array, batch: Dict[str, jax.Array],
+                      layer: jax.Array, chunk: int, backend: str = "auto"
+                      ) -> Tuple[jax.Array, jax.Array]:
+    """``state_update`` for Mamba-1: ``x`` [T, inner], ``dt`` [T, inner]
+    float32 (after softplus), ``A`` [N, inner] < 0, ``B``, ``C`` [T, N],
+    ``D`` [inner].  Returns (y [T, inner] float32, D x added, pool)."""
+    T, inner = x.shape
+    rows = batch["token_seq_ids"]
+    single = batch["query_len"] == 1
+    xf = x.astype(F32)
+    xdt = xf * dt
+    A = A.astype(F32)
+    tok = jnp.clip(batch["query_start"], 0, T - 1)
+    slot1 = jnp.where(single, batch["state_slot"], 0)
+    kernels = (resolve_backend(backend) == "pallas"
+               and not ssm1_pallas_ineligible_reason(inner, A.shape[0], chunk))
+    update = ssm1_decode_update_reference
+    if kernels:
+        from llm_d_tpu.ops.pallas.ssm1_scan import (
+            ssm1_decode_update as update)
+    y1, pool = update(dt[tok], xdt[tok], A, B[tok], C[tok], pool, layer,
+                      slot1, fresh_rows(batch))
+    y = y1[rows]
+    if batch["qtok_idx"].shape[1] > 1:
+        yn, pool = (ssm1_chunk_scan_pallas if kernels else ssm1_chunk_scan)(
+            dt, xdt, A, B, C, pool, layer, batch, chunk)
+        y = jnp.where(single[rows][:, None], y, yn)
+    return y + D.astype(F32)[None, :] * xf, pool

@@ -63,6 +63,17 @@ def test_window_phase_interpreted(smoke):
     assert info["window_decode_vs_prefill"] <= 2e-6
 
 
+def test_hybrid_phase_interpreted(smoke):
+    """The Mamba-1 kernels and the one-query read interpreted at 256
+    channels x 8 states, pieces of 16, 8 paired heads over 2 of 128."""
+    info = smoke.hybrid_phase(
+        256, 8, 16, 8, 2, 128, [(90, 40), (33, 33), (70, 1), (1, 1)],
+        seed=0, block_size=16, table_blocks=8, interpret=True)
+    assert info["ssm1_y_rel_diff"] <= 1e-5
+    assert info["ssm1_state_rel_diff"] <= 1e-5
+    assert info["cross_read_rel_diff"] <= smoke.WINDOW_KERNEL_REL_TOL
+
+
 def test_sharded_phase_tiny_on_virtual_devices(smoke, devices):
     """The --chips 4 phase on four of the suite's virtual CPU devices."""
     info = smoke.sharded_phase(

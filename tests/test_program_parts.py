@@ -176,10 +176,11 @@ def test_decode_and_mixed_attention_are_told_apart(family, kind):
 def test_the_vocabulary_is_the_one_the_reader_groups():
     grouped = [s for scopes in device_parts.GROUPS.values() for s in scopes]
     assert len(grouped) == len(set(grouped))
-    # llmd.attn.index (PR 39) is in no group of the accepted reader: its
-    # share is read by readers/scope_share.py (device_part_share.index).
+    # llmd.attn.index (PR 39), llmd.attn.cross and llmd.gmu (PR 41) are in
+    # no group of the accepted reader: their shares are read by
+    # readers/scope_share.py (device_part_share.index / .cross / .gmu).
     assert set(grouped) - {device_parts.UNSCOPED} == SCOPES - {
-        "llmd.attn.index"}
+        "llmd.attn.index", "llmd.attn.cross", "llmd.gmu"}
     assert device_parts.scope_of(
         "jit(step_fn)/while/body/closed_call/llmd.ssm.state/llmd.tiles/"
         "cumsum:") == "llmd.tiles"

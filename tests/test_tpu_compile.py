@@ -186,6 +186,47 @@ def _ssm_scan(T, S, H=32, P=128, N=256, G=2, L=6, c=128, slots=65):
                 _sds((NT,), jnp.bool_), _sds((NT,), jnp.bool_)]
 
 
+def _ssm1_update(S=8, inner=5120, N=16, L=9, slots=9):
+    from llm_d_tpu.ops.pallas.ssm1_scan import ssm1_decode_update as fn
+    fn.hlo_name = "ssm1_decode_update"
+    return fn, [_sds((S, inner), jnp.float32), _sds((S, inner), jnp.float32),
+                _sds((N, inner), jnp.float32), _sds((S, N), jnp.bfloat16),
+                _sds((S, N), jnp.bfloat16),
+                _sds((L, slots, N, inner), jnp.float32), _sds((), jnp.int32),
+                _sds((S,), jnp.int32), _sds((S,), jnp.bool_)]
+
+
+def _ssm1_scan(T, S, inner=5120, N=16, c=128, L=9, slots=9):
+    """The Mamba-1 selective scan over the pieces of a step of ``T`` tokens
+    in ``S`` rows."""
+    from llm_d_tpu.ops.pallas.ssm1_scan import ssm1_chunk_scan as fn
+    fn.hlo_name = "ssm1_chunk_scan"
+    NT = -(-T // c) + S
+    return fn, [_sds((NT, c, inner), jnp.float32),
+                _sds((NT, c, inner), jnp.float32),
+                _sds((N, inner), jnp.float32), _sds((NT, c, N), jnp.bfloat16),
+                _sds((NT, c, N), jnp.bfloat16),
+                _sds((L, slots, N, inner), jnp.float32), _sds((), jnp.int32),
+                _sds((NT,), jnp.int32), _sds((NT,), jnp.bool_),
+                _sds((NT,), jnp.bool_), _sds((NT,), jnp.bool_)]
+
+
+def _paged_read(H, KVH, D, S=8, B=1024, L=9):
+    from llm_d_tpu.ops.pallas.paged_attention import paged_attention_read
+    F = KVH * D
+
+    def fn(q, kc, vc, bt, sl, layer):
+        return paged_attention_read(q, kc, vc, bt, sl, block_size=BS,
+                                    num_kv_heads=KVH, scale=0.125,
+                                    layer=layer)
+
+    fn.hlo_name = "paged_attention_read"
+    return fn, [_sds((S, H, D), jnp.bfloat16),
+                _sds((L, SLOTS, F), jnp.bfloat16),
+                _sds((L, SLOTS, F), jnp.bfloat16), _sds((S, B), jnp.int32),
+                _sds((S,), jnp.int32), _sds((), jnp.int32)]
+
+
 def _prefill_tiles(H, KVH, D, T, S, Q, B=64, L=16, window=False,
                    mla=False):
     """Either prefill kernel over a step's query TILE LIST, as the step
@@ -405,6 +446,28 @@ CASES = [
                  id="mla_masked-dots3-window-T2048-S16"),
     pytest.param(functools.partial(_mla_window, T=16, S=16, Q=1),
                  id="mla_masked-dots3-window-decode-S16"),
+    # phi4-mini-flash: the Mamba-1 kernels over the state pool (16 states x
+    # 5,120 channels float32 a slot and layer), the GQA kernels with heads
+    # in pairs (40 over 10 of 128, rows of 1,280, window 512), and the
+    # one-query read of the shared plane.
+    pytest.param(_ssm1_update, id="ssm1_decode_update-phi4flash-S8"),
+    pytest.param(functools.partial(_ssm1_scan, T=2048, S=8),
+                 id="ssm1_chunk_scan-phi4flash-T2048-S8"),
+    pytest.param(functools.partial(_ssm1_scan, T=16, S=1),
+                 id="ssm1_chunk_scan-phi4flash-T16-S1"),
+    pytest.param(functools.partial(_paged_read, 40, 10, 128),
+                 id="paged_attention_read-phi4flash-S8"),
+    pytest.param(functools.partial(_paged_read, 40, 10, 128, S=1),
+                 id="paged_attention_read-phi4flash-S1"),
+    pytest.param(functools.partial(_dense_decode, 40, 10, 128, S=8, B=1024,
+                                   L=9, window=True),
+                 id="paged_decode-phi4flash-window-S8"),
+    pytest.param(functools.partial(_prefill_tiles, 40, 10, 128, T=2048, S=8,
+                                   Q=2048, B=1024, L=9, window=True),
+                 id="flash_prefill-tiles-phi4flash-window-T2048-S8"),
+    pytest.param(functools.partial(_prefill_tiles, 40, 10, 128, T=64, S=8,
+                                   Q=16, B=1024, L=9, window=True),
+                 id="flash_prefill-tiles-phi4flash-window-Q16"),
 ]
 
 
