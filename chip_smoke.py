@@ -35,6 +35,13 @@ holds the chip, at the full width of the models the repo benchmarks
      the chunked XLA path, at ``phi4-mini-flash``'s geometry (5,120
      channels x 16 states float32, 40 paired heads over 10 of 128).
 
+  6. held-experts phase — one layer's call of ``ops.moe._held_expert_ffn``
+     at ``dots3-note-prev``'s widths (32 of 256 bf16 experts of 5,120 x
+     1,536, stacked over planes, seeded routing over the router's width)
+     through the kernels of ``ops/pallas/moe_held.py`` and through the XLA
+     form, at 2,048 and at 16 tokens: their relative difference and both
+     times (smoke, not a measurement).
+
 ``--chips 4`` runs ONLY the four-chip phase (TP=4 dense, DP=4/EP=4 MoE, each
 against ``devices[0]`` alone; ``llama3-8b`` TP=4) and reports ``count: 4``.
 
@@ -61,7 +68,7 @@ import socket
 import sys
 import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 # Stated tolerances on chosen-token logprobs (absolute, nats).  Both sides
 # run bf16 matmuls with f32 accumulation on the same weights; they differ
@@ -93,6 +100,11 @@ WINDOW_KERNEL_REL_TOL = 1e-2
 # same recurrence token by token (the exponentials of two code generators,
 # carried through a chunk's tokens; a wrong piece or slot is an O(1) error).
 SSM1_KERNEL_REL_TOL = 1e-3
+# The held experts' kernels against the XLA form, same bf16 operands and
+# roundings, both rounded to bf16: mean |difference| over mean |value| (the
+# f32 partial sums over blocks of the expert width add in another order; a
+# wrong tile table or a dropped slot is an O(1) error).
+HELD_KERNEL_REL_TOL = 1e-3
 # Per-device bytes_in_use on the four-chip host: max/min at most this.
 MEMORY_BALANCE_FACTOR = 1.5
 
@@ -868,6 +880,89 @@ def hybrid_phase(inner: int, states: int, chunk: int, heads: int,
 
 
 # --------------------------------------------------------------------------
+# phase 6: the held bf16 experts' kernels, at op level
+# --------------------------------------------------------------------------
+
+def held_phase(hidden: int, width: int, held: Tuple[int, int],
+               router_experts: int, k: int, tokens: Sequence[int],
+               seed: int, planes: int = 2, interpret: bool = False,
+               **kernel_kw) -> Dict[str, float]:
+    """One layer's call at each of ``tokens`` through the kernels and
+    through the XLA form of ``_held_expert_ffn``, the same seeded routing
+    over ``router_experts`` (sigmoid scores, top ``k``), experts ``held`` =
+    (first id, count) stacked over ``planes``.  ``interpret``: the CPU
+    rehearsal's Pallas interpreter (with ``kernel_kw`` its small tiles)."""
+    import jax
+    import jax.numpy as jnp
+
+    from llm_d_tpu.ops import moe as moe_ops
+    from llm_d_tpu.ops.pallas import moe_held
+
+    e0, n_held = held
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    make = jax.jit(
+        lambda key, shape: (jax.random.normal(key, shape, jnp.float32)
+                            * shape[-2] ** -0.5).astype(jnp.bfloat16),
+        static_argnums=1)
+    wg = make(ks[0], (planes, n_held, hidden, width))
+    wu = make(ks[1], (planes, n_held, hidden, width))
+    wd = make(ks[2], (planes, n_held, width, hidden))
+    plane = jnp.int32(planes - 1)
+    check(moe_held.ineligible_reason(
+        jax.ShapeDtypeStruct((1, hidden), jnp.bfloat16), wg) is None,
+        f"the kernels refuse hidden {hidden} x width {width}")
+
+    def xla_form(*args):
+        # What a backend without the kernels serves.
+        real = moe_held.ineligible_reason
+        moe_held.ineligible_reason = lambda *a: "the XLA form, asked for"
+        try:
+            return moe_ops._held_expert_ffn(*args, e0, plane)
+        finally:
+            moe_held.ineligible_reason = real
+
+    forms = {
+        "kernel": jax.jit(lambda *a: moe_held.held_expert_ffn(
+            *a, e0, plane, interpret=interpret, **kernel_kw)),
+        "xla": jax.jit(xla_form)}
+
+    def timed(fn, *args, n=1 if interpret else 10):
+        out = jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(n):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return out, (time.perf_counter() - t0) / n * 1e3
+
+    info = {}
+    for T in tokens:
+        kx, kr = jax.random.split(jax.random.fold_in(ks[3], T))
+        x = jax.random.normal(kx, (T, hidden), jnp.float32).astype(
+            jnp.bfloat16)
+        weights, idx = jax.lax.top_k(jax.nn.sigmoid(
+            jax.random.normal(kr, (T, router_experts))), k)
+        weights = weights / weights.sum(-1, keepdims=True)
+        args = (x, weights, idx.astype(jnp.int32), wg, wu, wd)
+        got, kernel_ms = timed(forms["kernel"], *args)
+        want, xla_ms = timed(forms["xla"], *args)
+        want = want.astype(x.dtype).astype(jnp.float32)
+        size = float(jnp.mean(jnp.abs(want)))
+        n_slots = int(jnp.sum((idx >= e0) & (idx < e0 + n_held)))
+        check(n_slots > 0 and size > 0, f"no slot of {T} tokens is held")
+        rel = float(jnp.mean(jnp.abs(got.astype(jnp.float32) - want))) / size
+        info[f"held_rel_diff_T{T}"] = rel
+        log(f"   held experts, {T} tokens, {n_slots} of {T * k} slots on "
+            f"{n_held} of {router_experts} experts of {hidden} x {width}: "
+            f"mean |kernels - XLA| / mean |XLA| = {rel:.2e}; kernels "
+            f"{kernel_ms:.2f} ms, XLA form {xla_ms:.2f} ms a call "
+            f"(smoke, not a measurement)")
+        check(rel <= HELD_KERNEL_REL_TOL,
+              f"the held experts' kernels differ from the XLA form by "
+              f"{rel:.2e} of the values' scale at {T} tokens")
+    return info
+
+
+# --------------------------------------------------------------------------
 # --chips 4: one program across the host
 # --------------------------------------------------------------------------
 
@@ -1003,6 +1098,9 @@ def run_one_chip(seed: int) -> None:
                      [(4396, 300), (150, 150), (9000, 1), (77, 1), (1, 1)],
                      seed=seed)
     settle("hybrid-decoder phase")
+    with phase("held-experts phase [dots3-note-prev's experts]"):
+        held_phase(5120, 1536, (96, 32), 256, 8, (2048, 16), seed)
+    settle("held-experts phase")
 
 
 def run_four_chips(seed: int) -> None:

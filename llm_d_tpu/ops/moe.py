@@ -165,23 +165,27 @@ def _held_expert_ffn(
     w_down: jax.Array,     # [L, E_held, ...]
     e0: int,
     plane: Optional[jax.Array] = None,
-) -> jax.Array:            # [T, H] f32: the held experts' part of the sum
+) -> jax.Array:            # [T, H] the held experts' part of the sum
     """One rank's share of the routed experts on one device: the slots
-    routed to held experts, sorted by expert, go through the grouped
-    product; the rest (at 8 slots a token over 8 ranks, 7 of 8) lie past
-    the last group and are not multiplied by anything.  The product is
-    handed all the sorted slots, as many as the router may send here; what
-    it costs goes by the experts' bytes, not by the rows (on the v5e, one
-    layer's call at 2,048 tokens, 32 of 256 experts of 5120 x 1536: 10.0
-    ms, and 8.8 when handed only the first quarter of the slots, which a
-    conditional on the count chose until PR 39's review: 2 % of a step for
-    a second compiled body).
+    routed to held experts (at 8 slots a token over 8 ranks, 1 of 8).  On
+    the TPU, bf16 rows and experts of whole lane tiles go through
+    ``ops/pallas/moe_held.py``: tiles of held rows only, each touched
+    expert's matrices read once, no row of a slot held elsewhere moved.
+    Below, the XLA form (the CPU, other geometries, the kernels' reference):
+    the grouped product is handed all the sorted slots, the rest lie past
+    the last group.  Why not that form with fewer rows: what it costs goes
+    by the experts' bytes (on the v5e, one layer's call at 2,048 tokens, 32
+    of 256 experts of 5120 x 1536: 10.0 ms, 8.8 with a quarter of the slots).
 
-    ``plane``: the weights are whole stacks over layers.  The grouped
-    product's operands are buffers: a layer's slice handed to it is a copy
-    of the layer's experts a step (compiled for a described v5e: 2.8 GiB of
-    temporaries at 32 experts of 5120 x 1536, 1.4 GB copied a layer), the
-    whole stack is the parameter itself."""
+    ``plane``: the weights are whole stacks over layers, read in place (a
+    layer's slice handed to either form would be a copy of its experts a
+    step, 1.4 GB a layer; the grouped product takes every layer's experts
+    as groups of ONE product, all empty but this layer's)."""
+    from llm_d_tpu.ops.pallas import moe_held
+    if jax.default_backend() == "tpu" \
+            and moe_held.ineligible_reason(x, w_gate) is None:
+        return moe_held.held_expert_ffn(
+            x, weights, idx, w_gate, w_up, w_down, e0, plane)
     T = x.shape[0]
     k = idx.shape[1]
     E_held = w_gate.shape[-3]
@@ -192,9 +196,6 @@ def _held_expert_ffn(
         jnp.where(is_held, lid, E_held), E_held + 1)
     group_sizes = counts[:E_held]
     if plane is not None:
-        # Every layer's experts are groups of ONE grouped product, all
-        # empty but this layer's: the stacks are its operands as they lie
-        # (a reshape), no layer's slice is copied out.
         w_gate, w_up, w_down = (w.reshape((-1,) + w.shape[2:])
                                 for w in (w_gate, w_up, w_down))
         group_sizes = jax.lax.dynamic_update_slice(
@@ -202,8 +203,7 @@ def _held_expert_ffn(
             (plane * E_held,))
     y = _swiglu_grouped(x[order // k], w_gate, w_up, w_down,
                         group_sizes)                              # [S, H] f32
-    held_sorted = is_held[order]
-    y = jnp.where(held_sorted[:, None],
+    y = jnp.where(is_held[order][:, None],
                   y * weights.reshape(S)[order][:, None], 0.0)
     return _unsort_combine(y, order, T, k, inv=inv)
 

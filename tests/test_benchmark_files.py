@@ -472,7 +472,17 @@ def test_dots3_cell_and_its_files():
     at = names.index(DOTS_METRICS[0])
     assert names[at:at + len(DOTS_METRICS)] == DOTS_METRICS   # in order
     assert names[at + len(DOTS_METRICS):] == [
-        "attn_window_key_fill_share"] + PHI4_METRICS    # PRs 40, 41, appended
+        "attn_window_key_fill_share"] + PHI4_METRICS + [
+        "moe_held_hbm_share"]                       # PRs 40, 41, 42, appended
+    held = json.loads((BENCH / "layer_metrics"
+                       / "moe_held_hbm_share.json").read_text())
+    assert per_layer["moe_held_hbm_share"]["workloads"] == ["dots3.longdoc"]
+    assert (held["reader"], held["moves"]) == ("held_stream", "ttft_p50_ms")
+    # the scopes and names the accepted experts' share of the cell reads
+    experts = json.loads((BENCH / "layer_metrics"
+                          / "device_part_share.experts.dots3.json").read_text())
+    assert (held["args"]["scopes"], held["args"]["kernels"]) == (
+        experts["args"]["scopes"], experts["args"]["kernels"])
     for name in DOTS_METRICS:
         assert per_layer[name]["workloads"] == ["dots3.longdoc"]
         d = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())
@@ -643,7 +653,9 @@ def test_phi4flash_cell_and_its_files():
         assert mix[key] == longdoc[key], key
     per_layer = {m["name"]: m for m in bench["per_layer"]}
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-len(PHI4_METRICS):] == PHI4_METRICS   # appended, in order
+    at = names.index(PHI4_METRICS[0])
+    assert names[at:at + len(PHI4_METRICS)] == PHI4_METRICS   # in order
+    assert names[at + len(PHI4_METRICS):] == ["moe_held_hbm_share"]   # PR 42
     for name in PHI4_METRICS:
         assert per_layer[name]["workloads"] == ["phi4flash.longdoc"]
         d = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())

@@ -74,6 +74,16 @@ def test_hybrid_phase_interpreted(smoke):
     assert info["cross_read_rel_diff"] <= smoke.WINDOW_KERNEL_REL_TOL
 
 
+def test_held_phase_interpreted(smoke):
+    """The held experts' kernels interpreted at hidden 1,024 x width 256,
+    4 of 16 experts, tiles of 16 rows and blocks of 128 columns, 40 and 16
+    tokens."""
+    info = smoke.held_phase(1024, 256, (8, 4), 16, 4, (40, 16), seed=0,
+                            interpret=True, row_tile=16, block=128)
+    assert set(info) == {"held_rel_diff_T40", "held_rel_diff_T16"}
+    assert max(info.values()) <= smoke.HELD_KERNEL_REL_TOL
+
+
 def test_sharded_phase_tiny_on_virtual_devices(smoke, devices):
     """The --chips 4 phase on four of the suite's virtual CPU devices."""
     info = smoke.sharded_phase(

@@ -605,3 +605,51 @@ def test_a_share_is_the_parts_whatever_operation_serves_it(
                        "mla_paged", "mla_flash", "paged_attention",
                        "flash_prefill", "ssm_decode", "ssm_chunk"):
             assert kernel not in source, (reader, kernel)
+
+
+@needs_parts_trace
+def test_held_stream_share_counts_touched_experts_once_over_the_parts_time(
+        monkeypatch):
+    """``moe_held_hbm_share`` (readers/held_stream.py): the touched experts'
+    bf16 bytes, each once, over the time under the scopes plus the
+    operations of the given names; nothing on a trace without the count,
+    the names counted wherever they lie."""
+    from readers import held_stream
+    path = str(PARTS_TRACE)
+    conf = {"name": "recorded", "model_config_map": {
+        k: k for k in ("hidden_size", "moe_intermediate_size")},
+        "hidden_size": 2048, "moe_intermediate_size": 512}
+    touched = part_roofline.annotation_counts(path)["moe_experts_touched"]
+    seconds = device_parts.scoped_seconds(path, ("llmd.experts",))
+    got = held_stream.share(path, ("llmd.experts",), ("ragged-dot",), conf,
+                            V5E)
+    want = 100.0 * touched * 3 * 2048 * 512 * 2 / V5E["hbm_bytes_per_s"] \
+        / seconds
+    assert got == pytest.approx(want) and got > 0
+    # a name counts beside the scopes: every operation under another scope
+    # renamed ragged-dot-none lowers the share by that scope's time
+    real = xplanemeta.read
+
+    def renamed(p, lines=()):
+        planes = real(p, lines)
+        for plane in planes:
+            for rec in plane["ops"].values():
+                if device_parts.scope_of(rec.get("tf_op")) == "llmd.mlp":
+                    rec["name"] = "%ragged-dot-none.7 = custom-call()"
+        return planes
+
+    monkeypatch.setattr(xplanemeta, "read", renamed)
+    device_parts.self_times.cache_clear()
+    try:
+        more = device_parts.scoped_seconds(path, ("llmd.experts", "llmd.mlp"))
+        assert held_stream.share(
+            path, ("llmd.experts",), ("ragged-dot",), conf, V5E
+        ) == pytest.approx(want * seconds / more)
+    finally:
+        device_parts.self_times.cache_clear()
+    for old in OLD_TRACES:          # no annotation carries the count
+        assert held_stream.share(str(BENCH / "testdata" / old),
+                                 ("llmd.experts",), ("ragged-dot",), conf,
+                                 V5E) is None
+    assert held_stream.read({"trace": None}, ["llmd.experts"],
+                            ["ragged-dot"], "dots3-note-prev") is None

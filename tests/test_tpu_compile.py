@@ -277,6 +277,24 @@ def _moe(path, T, H=2048, I=512, E=64, k=8, Lm=15):
     return fn, args
 
 
+def _moe_held(T, H=5120, I=1536, E=32, k=8, L=5):
+    """``ops.moe._held_expert_ffn``'s kernels (ops/pallas/moe_held.py) at
+    dots3-note-prev's widths: 32 of 256 bf16 experts of 5120 x 1536, the
+    layers' stacks read in place by plane.  Hidden 5120 compiles in no other
+    kernel of the family; the row tile and the block of the expert width
+    are the module's rule, the same for every T."""
+    from llm_d_tpu.ops.pallas import moe_held
+
+    def fn(x, w, idx, wg, wu, wd, plane):
+        assert moe_held.ineligible_reason(x, wg) is None
+        return moe_held.held_expert_ffn(x, w, idx, wg, wu, wd, 96, plane)
+
+    return fn, [_sds((T, H), jnp.bfloat16), _sds((T, k), jnp.float32),
+                _sds((T, k), jnp.int32), _sds((L, E, H, I), jnp.bfloat16),
+                _sds((L, E, H, I), jnp.bfloat16),
+                _sds((L, E, I, H), jnp.bfloat16), _sds((), jnp.int32)]
+
+
 CASES = [
     # bf16 dense attention (every non-MLA model).
     pytest.param(functools.partial(_dense_decode, 32, 8, 64),
@@ -446,6 +464,12 @@ CASES = [
                  id="mla_masked-dots3-window-T2048-S16"),
     pytest.param(functools.partial(_mla_window, T=16, S=16, Q=1),
                  id="mla_masked-dots3-window-decode-S16"),
+    # Its held bf16 experts: the grouped kernel over tiles of held rows and
+    # the combine, a 2,048-token mixed step and a pure-decode step.
+    pytest.param(functools.partial(_moe_held, T=2048),
+                 id="moe_held-dots3-T2048"),
+    pytest.param(functools.partial(_moe_held, T=16),
+                 id="moe_held-dots3-decode-T16"),
     # phi4-mini-flash: the Mamba-1 kernels over the state pool (16 states x
     # 5,120 channels float32 a slot and layer), the GQA kernels with heads
     # in pairs (40 over 10 of 128, rows of 1,280, window 512), and the
