@@ -105,6 +105,9 @@ SSM1_KERNEL_REL_TOL = 1e-3
 # f32 partial sums over blocks of the expert width add in another order; a
 # wrong tile table or a dropped slot is an O(1) error).
 HELD_KERNEL_REL_TOL = 1e-3
+# A key the indexer's kernel selects otherwise than the XLA form: the two
+# forms sum 64 heads' f32 terms in two orders, a few ulps of a score.
+INDEX_MARGIN_REL_TOL = 1e-5
 # Per-device bytes_in_use on the four-chip host: max/min at most this.
 MEMORY_BALANCE_FACTOR = 1.5
 
@@ -693,23 +696,13 @@ def moe_phase(model: str, n_seqs: int, prompt_len: int, max_tokens: int,
 # phase 4: the masked flash kernel's windowed walk, at op level
 # --------------------------------------------------------------------------
 
-def window_phase(heads: int, row_width: int, value_width: int, window: int,
-                 rows: Sequence[Sequence[int]], seed: int,
-                 block_size: int = 32, table_blocks: int = 1024,
-                 interpret: bool = False) -> Dict[str, float]:
-    """``rows``: (context end, new tokens) of each row of one step, the
-    first a prefill chunk.  The kernel path of ``attend_window`` against
-    its XLA form on the same cache, and the first row's queries as tiles
-    of one slot (a pure-decode step's) against the tiles the geometry
-    picks.  ``interpret``: the CPU rehearsal's Pallas interpreter."""
-    import functools
-
-    import jax
+def step_batch(rows: Sequence[Sequence[int]], seed: int, block_size: int,
+               table_blocks: int):
+    """(the keys the attention ops read of one step's packed batch, the
+    cache slots its pages span): ``rows`` = (context end, new tokens) of
+    each row, the new tokens its last, pages dealt out of order."""
     import jax.numpy as jnp
     import numpy as np
-
-    from llm_d_tpu.ops import sparse_mla
-    from llm_d_tpu.ops.pallas import mla_masked
 
     rng = np.random.default_rng(seed)
     S, T = len(rows), sum(n for _, n in rows)
@@ -728,10 +721,32 @@ def window_phase(heads: int, row_width: int, value_width: int, window: int,
         block_tables=tables, token_seq_ids=seq, positions=pos,
         token_qpos=qpos, qtok_idx=qtok,
         seq_lens=[end for end, _ in rows]).items()}
+    return batch, (sum(need) + 1) * block_size
+
+
+def window_phase(heads: int, row_width: int, value_width: int, window: int,
+                 rows: Sequence[Sequence[int]], seed: int,
+                 block_size: int = 32, table_blocks: int = 1024,
+                 interpret: bool = False) -> Dict[str, float]:
+    """``rows``: (context end, new tokens) of each row of one step, the
+    first a prefill chunk.  The kernel path of ``attend_window`` against
+    its XLA form on the same cache, and the first row's queries as tiles
+    of one slot (a pure-decode step's) against the tiles the geometry
+    picks.  ``interpret``: the CPU rehearsal's Pallas interpreter."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from llm_d_tpu.ops import sparse_mla
+    from llm_d_tpu.ops.pallas import mla_masked
+
+    batch, slots = step_batch(rows, seed, block_size, table_blocks)
+    T = sum(n for _, n in rows)
     kq, kc = jax.random.split(jax.random.PRNGKey(seed))
     q = jax.random.normal(kq, (T, heads, row_width), jnp.bfloat16)
     cache = 0.3 * jax.random.normal(
-        kc, (1, (sum(need) + 1) * block_size, row_width), jnp.bfloat16)
+        kc, (1, slots, row_width), jnp.bfloat16)
 
     real = mla_masked.mla_masked_attention
     if interpret:
@@ -962,6 +977,108 @@ def held_phase(hidden: int, width: int, held: Tuple[int, int],
     return info
 
 
+def index_phase(heads: int, head_dim: int, topk: int,
+                rows: Sequence[Sequence[int]], seed: int,
+                block_size: int = 32, table_blocks: int = 1024,
+                n_check: int = 256, interpret: bool = False
+                ) -> Dict[str, float]:
+    """``rows`` as ``window_phase``'s.  The indexer's kernel
+    (``sparse_mla.index_bias``) against the XLA form (``index_select``) on
+    the same index cache: the sets of the step's first ``n_check`` queries
+    and of every later row's (the two forms sum the heads in two orders, so
+    a set may differ where its threshold's margin is under the rounding:
+    counted, and every key that differs held to that margin), and a query
+    alone in its tile against the same query in a tile of eight (equal)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llm_d_tpu.ops import sparse_mla
+    from llm_d_tpu.ops.pallas import dsa_index
+
+    batch, slots = step_batch(rows, seed, block_size, table_blocks)
+    T = sum(n for _, n in rows)
+    kq, kw, kc = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(kq, (T, heads, head_dim), jnp.bfloat16)
+    w = jax.random.normal(kw, (T, heads)) * (heads * head_dim) ** -0.5
+    cache = jax.random.normal(kc, (1, slots, head_dim), jnp.bfloat16)
+    C = table_blocks * block_size
+
+    real = dsa_index.index_bias, dsa_index.unwritten
+    if interpret:
+        dsa_index.index_bias, dsa_index.unwritten = (
+            functools.partial(fn, interpret=True) for fn in real)
+    try:
+        forms = {name: jax.jit(functools.partial(
+            fn, block_size=block_size, **kw))
+            for name, fn, kw in (
+                ("kernel", sparse_mla.index_bias, {"topk": topk}),
+                ("xla", sparse_mla.index_select, {"topk": topk}),
+                ("scores", sparse_mla.index_scores, {}))}
+
+        def timed(fn, tiles, n=1 if interpret else 10):
+            args = (q, w, cache, tiles)
+            out = jax.block_until_ready(fn(*args, layer=jnp.int32(0)))
+            t0 = time.perf_counter()
+            for _ in range(n):
+                out = fn(*args, layer=jnp.int32(0))
+            jax.block_until_ready(out)
+            return out, (time.perf_counter() - t0) / n * 1e3
+
+        def by_token(per_tile, tiles):
+            return np.asarray(per_tile[tiles["tok_tile"], tiles["tok_slot"]])
+
+        def sets(bias, tiles):     # [NT, blocks, Qt, keys] -> [T, C] bool
+            NT, _, qt, _ = bias.shape
+            return by_token((bias == 0).transpose(0, 2, 1, 3).reshape(
+                NT, qt, C), tiles)
+
+        tiles8 = sparse_mla.with_tiles(batch, sparse_mla.SELECT_Q_TILE)
+        tiles1 = sparse_mla.with_tiles(batch, 1)
+        bias8, kernel_ms = timed(forms["kernel"], tiles8)
+        want, xla_ms = timed(forms["xla"], tiles8)
+        bias1, _ = timed(forms["kernel"], tiles1, n=1)
+        scores, _ = forms["scores"](q, w, cache, tiles8, layer=jnp.int32(0))
+    finally:
+        dsa_index.index_bias, dsa_index.unwritten = real
+    got, alone = sets(bias8, tiles8), sets(bias1, tiles1)
+    want, scores = by_token(want, tiles8), by_token(scores, tiles8)
+    pos = np.asarray(batch["positions"])
+    picked = list(range(min(n_check, rows[0][1]))) + list(range(rows[0][1], T))
+    differ, worst, unequal = 0, 0.0, 0
+    for t in picked:
+        # Past a tile's last key the kernel writes nothing: a token's own
+        # visible columns only.
+        g, x, a = (m[t, :pos[t] + 1] for m in (got, want, alone))
+        check(int(g.sum()) == min(pos[t] + 1, topk),
+              f"query {t} at position {pos[t]} selects {int(g.sum())} keys")
+        unequal += int((g != a).any())
+        if (g != x).any():
+            differ += 1
+            s = scores[t, :pos[t] + 1]
+            edge = np.sort(s)[-topk]
+            worst = max(worst, float(np.abs(s[g != x] - edge).max()
+                                     / max(abs(edge), 1e-30)))
+    info = {"index_sets_differ": differ, "index_worst_margin": worst,
+            "index_decode_vs_prefill": unequal,
+            "index_kernel_ms": kernel_ms, "index_xla_ms": xla_ms}
+    log(f"   indexer, {heads} heads of {head_dim}, top-k {topk}, {T} queries "
+        f"in tiles of {sparse_mla.SELECT_Q_TILE}: {differ} of {len(picked)} "
+        f"sets differ from the XLA form's (the keys that differ lie within "
+        f"{worst:.1e} of the threshold's score, relative); a query alone in "
+        f"its tile against the same query in a tile of eight: {unequal} "
+        f"sets differ; kernel {kernel_ms:.2f} ms, XLA form {xla_ms:.2f} ms "
+        f"a call (smoke, not a measurement)")
+    check(worst <= INDEX_MARGIN_REL_TOL,
+          f"a key the indexer's kernel selects otherwise than the XLA form "
+          f"lies {worst:.1e} of the threshold's score from it")
+    check(unequal == 0, f"{unequal} decode tiles select otherwise than "
+          f"their prefill tiles")
+    return info
+
+
 # --------------------------------------------------------------------------
 # --chips 4: one program across the host
 # --------------------------------------------------------------------------
@@ -1101,6 +1218,13 @@ def run_one_chip(seed: int) -> None:
     with phase("held-experts phase [dots3-note-prev's experts]"):
         held_phase(5120, 1536, (96, 32), 256, 8, (2048, 16), seed)
     settle("held-experts phase")
+    with phase("index phase [dots3-note-prev's indexer]"):
+        # A chunk that crosses the top-k and ends inside a key block, decode
+        # rows under and over the top-k, a prompt shorter than the top-k.
+        index_phase(64, 128, 2048,
+                    [(2500, 600), (1500, 1), (2048, 1), (2049, 1), (6215, 1),
+                     (300, 300)], seed)
+    settle("index phase")
 
 
 def run_four_chips(seed: int) -> None:

@@ -800,8 +800,9 @@ class EngineCore:
         # (heads, cache row width) one shard's Pallas prefill kernels see:
         # what sets their query tile.  None: another path serves prefill.
         self._prefill_tile_dims: Optional[Tuple[int, int]] = None
-        # ``mla_masked_attention`` serves the MLA layers that see a window.
-        self._window_kernel = False
+        # ``mla_masked_attention`` serves the MLA layers that see a window,
+        # it and ``dsa_index.index_bias`` those that select their keys.
+        self._window_kernel = self._select_kernel = False
         if backend != "pallas":
             return
         # A tp shard sees its slice of the heads and of the folded dense
@@ -834,6 +835,8 @@ class EngineCore:
                                           f"{kind}: {reason}")
                 elif g.window:
                     self._window_kernel = True
+                elif g.index_topk:
+                    self._select_kernel = True
             return
         self._prefill_tile_dims = (
             c.num_heads // heads_tp, next(iter(layout.values())) // tp)
@@ -2980,6 +2983,7 @@ class EngineCore:
         else:
             self._step_kv.update(self._attn_dk_counts(ends, layout))
         self._step_kv.update(self._attn_wk_counts(ends, news, layout))
+        self._step_kv.update(self._idx_k_counts(ends, news, layout))
         return packed, layout, scheduled, rows
 
     # ---------- step ----------
@@ -3164,6 +3168,32 @@ class EngineCore:
                     (_keys_seen_upto(ends, g.window)
                      - _keys_seen_upto(ends - news, g.window)).sum()),
                 "attn_wk_slots": n_window * int(blocks.sum()) * KEY_BLOCK * qt}
+
+    def _idx_k_counts(self, ends, news, layout: BatchLayout
+                      ) -> Dict[str, int]:
+        """The (query, key) pairs the indexer's kernel scores for the full
+        layers that select (step_clock.py), summed over those layers and
+        the dispatch's query tiles: a tile of SELECT_Q_TILE slots (no wider
+        than the query bucket) walks whole index blocks from key 0 to its
+        last query's own, and the kernel's tile always holds its eight
+        slots.  Nothing where the XLA form serves those layers."""
+        if not self._select_kernel:
+            return {}
+        from llm_d_tpu.ops import sparse_mla
+        from llm_d_tpu.ops.pallas import dsa_index, mla_masked
+        c = self.model_config
+        bs = self.config.block_size
+        ib = dsa_index.index_block(-(-c.max_model_len // bs) * bs,
+                                   mla_masked.KEY_BLOCK)
+        ends = np.asarray(ends, np.int64)
+        news = np.minimum(np.asarray(news, np.int64), ends)
+        _, q_last = _tile_spans(
+            ends, news, min(sparse_mla.SELECT_Q_TILE, layout.Q))
+        n_full = c.num_layers - c.layer_types.count(SLIDING)
+        return {"idx_k_real": n_full * (
+                    int((news * (2 * ends - news + 1)).sum()) // 2),
+                "idx_k_slots": n_full * int((q_last // ib + 1).sum())
+                * ib * dsa_index.SLOTS}
 
     def _attn_dk_counts(self, ends, layout: BatchLayout) -> Dict[str, int]:
         """The keys the MLA decode kernel's inner loop covers for a

@@ -88,6 +88,18 @@ over those layers:
                   a block x the tile's slots (``ops.sparse_mla.
                   window_q_tile``, ``ops.pallas.mla_masked.KEY_BLOCK``)
 
+and, for a stack whose full layers select their keys through the indexer's
+kernel (``EngineCore._idx_k_counts``; every step, pure decode too), summed
+over those layers:
+
+  idx_k_real      (query, visible key) pairs the indexer must score: the
+                  step's ``index_pairs``
+  idx_k_slots     pairs the kernel's walk covers for them: a query tile's
+                  index blocks, from key 0 to its last query's own, x the
+                  keys a block (``ops.pallas.dsa_index.index_block``) x the
+                  eight slots the kernel's tile holds (a decode row fills
+                  one)
+
 and, for a stack with recurrent state beside its pages
 (``EngineCore._state_counts``; the classic path, also on its
 ``llmd.dispatch`` annotation):

@@ -240,14 +240,13 @@ def mla_attention_block(
 def _mla_attend_selected(lp, c, g, x, cq, q_eff, row, caches, batch, layer,
                          *, block_size: int, backend: str, scale: float,
                          cos, sin):
-    """A FULL layer that selects: write the latent row and the index key,
-    score the step's queries against the sequence's cached index keys, keep
-    ``index_topk`` of them a query, attend to those keys
-    (ops/sparse_mla.py): (out_lat [T, H, R] f32, caches')."""
+    """A FULL layer that selects (ops/sparse_mla.py): write the latent row
+    and the index key, score the step's queries against the row's cached
+    index keys, keep ``index_topk`` a query, attend: (out_lat f32, caches')."""
     from llm_d_tpu.ops import sparse_mla
-    T = x.shape[0]
-    kv_cache, idx_cache = caches
-    Hi, Di, rope = c.index_n_heads, c.index_head_dim, g.qk_rope_head_dim
+    T, (kv_cache, idx_cache) = x.shape[0], caches
+    Hi, Di, rope, topk = (c.index_n_heads, c.index_head_dim,
+                          g.qk_rope_head_dim, g.index_topk)
     with part("attn.index"):
         # The rotary embedding turns the FIRST ``rope`` columns of each
         # index head and of the index key (DeepSeek-V3.2's indexer).
@@ -269,15 +268,16 @@ def _mla_attend_selected(lp, c, g, x, cq, q_eff, row, caches, batch, layer,
              * (Hi * Di) ** -0.5)                               # [T, Hi]
         idx_cache = idx_cache.at[layer, batch["slot_mapping"]].set(
             k_idx.astype(idx_cache.dtype))
-        chosen = sparse_mla.index_select(
-            q_idx, w, idx_cache, batch, block_size, layer, g.index_topk)
+        kernel = sparse_mla.kernel_serves(g, backend, block_size, batch[
+            "block_tables"].shape[-1] * block_size)
+        select = sparse_mla.index_bias if kernel else sparse_mla.index_select
+        chosen = select(q_idx, w, idx_cache, batch, block_size, layer, topk)
     with part(attn_part(batch)):
         kv_cache = kv_cache.at[layer, batch["slot_mapping"]].set(
             row.astype(kv_cache.dtype))
         out_lat = sparse_mla.attend_chosen(
             q_eff, kv_cache, chosen, batch, block_size, layer, scale,
-            g.kv_lora_rank, kernel=sparse_mla.kernel_serves(
-                g, backend, block_size, chosen.shape[-1]))
+            g.kv_lora_rank, kernel=kernel)
     return out_lat, (kv_cache, idx_cache)
 
 

@@ -30,6 +30,18 @@ visible), and the layer's attention weighs those keys only:
     the top-k); elsewhere a tile at a time over the row's gathered table.
     One path for prefill chunks, mixed steps and decode rows.
 
+Two forms of the first two steps, chosen by what ``kernel_serves`` sees
+(the Pallas backend and a geometry ``mla_masked`` takes: the choice
+``attend_chosen(kernel=...)`` makes).  Where it is true, ``index_bias``:
+the scores, the threshold and the bias the masked kernel reads in ONE
+Pallas kernel over the step's tile list (``ops.pallas.dsa_index``), a
+tile's work bounded by the tile's own last key, the scores in VMEM only;
+XLA gathers each row's keys once (``row_index_keys``) and lays the queries
+out.  Everywhere else (the CPU, a refused geometry, the tests' oracle, the
+reference-side tools) the XLA form: ``index_scores`` + ``choose_topk`` =
+``index_select``, a [tiles, slots, C] mask whose shapes are static, so
+every tile pays for the batch's longest context.  Both give the same set.
+
 A SLIDING layer's window on the MLA path is served here too
 (``attend_window``): a tile of consecutive queries of one row sees the
 ``window - 1`` keys before its first query and the tile's own, one band
@@ -56,6 +68,9 @@ from llm_d_tpu.ops import attention as A
 # fused rows, as many as feed the MXU well.
 SELECT_Q_TILE = 8
 INDEX_KEY_CHUNK = 512
+# Keys a step of ``row_index_keys`` gathers a row: a step costs 15 us
+# whatever it moves, a page gathered past the longest context 17 ns.
+ROW_KEY_CHUNK = 2048
 # Bits of the threshold a pass of ``choose_topk`` settles (2**bits - 1
 # counts in one read of the scores).
 THRESHOLD_BITS = 2
@@ -111,6 +126,13 @@ def _tile_positions(batch, tiles) -> jax.Array:
     """[NT, Qt] position of each slot's query, -1 for a pad slot."""
     return jnp.concatenate(
         [batch["positions"], jnp.full((1,), -1, jnp.int32)])[tiles["tile_tok"]]
+
+
+def _tile_live(batch, tiles) -> jax.Array:
+    """[NT] the key each tile's walk ends before: its last query's position
+    + 1, capped by its row's length (0: a tile of pad slots)."""
+    return jnp.minimum(jnp.max(_tile_positions(batch, tiles), axis=1) + 1,
+                       batch["seq_lens"][tiles["tile_seq"]])
 
 
 def index_scores(
@@ -251,10 +273,64 @@ def index_select(
         [within(wd) for wd in widths], scores)
 
 
+def row_index_keys(idx_cache: jax.Array, batch: Dict[str, jax.Array],
+                   block_size: int, layer: jax.Array) -> jax.Array:
+    """[S, C, Di] each row's index keys by position, gathered a PAGE an
+    index once a row (a 2,048-token chunk is 256 tiles of one row), the
+    chunks below the step's longest context only (past them nothing is
+    written: the kernel masks what it reads past a tile's last key)."""
+    from llm_d_tpu.ops.pallas import dsa_index
+    S, B = batch["block_tables"].shape
+    Di = idx_cache.shape[-1]
+    pages = idx_cache.reshape(idx_cache.shape[0], -1, block_size, Di)
+    pc = A._chunk_size_for(B, max(ROW_KEY_CHUNK // block_size, 1))
+    kc = pc * block_size
+    n_live = jnp.minimum(-(-jnp.max(batch["seq_lens"]) // kc), B // pc)
+
+    def chunk(i, keys):
+        return jax.lax.dynamic_update_slice_in_dim(
+            keys, pages[layer, jax.lax.dynamic_slice_in_dim(
+                batch["block_tables"], i * pc, pc, 1)].reshape(S, kc, Di),
+            i * kc, 1)
+
+    return jax.lax.fori_loop(0, n_live, chunk, dsa_index.unwritten(
+        (S, B * block_size, Di), idx_cache.dtype))
+
+
+def index_bias(
+    q_idx: jax.Array,         # [T, Hi, Di] the indexer's queries
+    w: jax.Array,             # [T, Hi] f32 head weights (scales folded in)
+    idx_cache: jax.Array,     # [L, slots, Di] index keys, this step's written
+    batch: Dict[str, jax.Array],
+    block_size: int,
+    layer: jax.Array,
+    topk: int,
+) -> jax.Array:
+    """``index_select`` where the Pallas kernels serve the layer
+    (``kernel_serves``), as the bias ``mla_masked_attention`` reads: [NT,
+    C / KEY_BLOCK, Qt, KEY_BLOCK] f32, 0 where the slot's query attends to
+    the key and ``mla_masked.NEG_INF`` elsewhere, written for the key
+    blocks under each tile's own last key only (``ops.pallas.dsa_index``:
+    a tile's scores, threshold and bias in one kernel that reads the row's
+    keys a block a copy; nothing of them in HBM but the bias)."""
+    from llm_d_tpu.ops.pallas import dsa_index, mla_masked
+    tiles = batch if "tile_tok" in batch else with_tiles(batch, SELECT_Q_TILE)
+    keys = row_index_keys(idx_cache, batch, block_size, layer)
+    # A pad slot rides the last token's queries: its position of -1 sees
+    # no key.
+    at = jnp.minimum(tiles["tile_tok"], q_idx.shape[0] - 1)
+    return dsa_index.index_bias(
+        q_idx[at], w[at], _tile_positions(batch, tiles), tiles["tile_seq"],
+        _tile_live(batch, tiles), keys, topk=topk,
+        key_block=mla_masked.KEY_BLOCK, index_block=dsa_index.index_block(
+            keys.shape[1], mla_masked.KEY_BLOCK))
+
+
 def attend_chosen(
     q_eff: jax.Array,         # [T, H, F] absorbed queries
     kv_cache: jax.Array,      # [L, slots, F] latent rows, this step's written
-    chosen: jax.Array,        # [NT, Qt, C] bool (``index_select``)
+    chosen: jax.Array,        # [NT, Qt, C] bool (``index_select``), or the
+                              # bias ``index_bias`` wrote (f32; ``kernel``)
     batch: Dict[str, jax.Array],
     block_size: int,
     layer: jax.Array,
@@ -267,21 +343,23 @@ def attend_chosen(
     T, H, F = q_eff.shape
     tiles = batch if "tile_tok" in batch else with_tiles(batch, SELECT_Q_TILE)
     tile_seq = tiles["tile_seq"]
-    NT, qt, C = chosen.shape
     q_t = jnp.concatenate([q_eff, jnp.zeros((1, H, F), q_eff.dtype)])[
         tiles["tile_tok"]]                                # [NT, Qt, H, F]
     if kernel:
         from llm_d_tpu.ops.pallas import mla_masked
-        KB = mla_masked.KEY_BLOCK
-        bias = jnp.where(chosen, 0.0, mla_masked.NEG_INF).astype(
-            jnp.float32).reshape(NT, qt, C // KB, KB).transpose(0, 2, 1, 3)
-        live = jnp.minimum(jnp.max(_tile_positions(batch, tiles), axis=1) + 1,
-                           batch["seq_lens"][tile_seq])
+        bias = chosen
+        if bias.dtype == jnp.bool_:
+            NT, qt, C = chosen.shape
+            KB = mla_masked.KEY_BLOCK
+            bias = jnp.where(chosen, 0.0, mla_masked.NEG_INF).astype(
+                jnp.float32).reshape(NT, qt, C // KB, KB).transpose(0, 2, 1, 3)
+        live = _tile_live(batch, tiles)
         out = mla_masked.mla_masked_attention(
             q_t, bias, tile_seq, live, jnp.zeros_like(live), kv_cache,
             batch["block_tables"], layer, block_size=block_size, scale=scale,
             value_width=R)
     else:
+        C = chosen.shape[-1]
         slot_of = (batch["block_tables"][:, :, None] * block_size
                    + jnp.arange(block_size, dtype=jnp.int32)[None, None, :]
                    ).reshape(-1, C)
@@ -381,7 +459,7 @@ def _attend_window_blocks(q_eff, kv_cache, batch, window, block_size, layer,
     q_t = q_eff[jnp.minimum(tile_tok, T - 1)]               # [NT, Qt, H, F]
     out = mla_masked.mla_masked_attention(
         q_t, jnp.where(seen, 0.0, mla_masked.NEG_INF).astype(jnp.float32),
-        tile_seq, jnp.minimum(jnp.max(pos_t, axis=(1, 2, 3)) + 1, len_t),
-        first, kv_cache, batch["block_tables"], layer,
+        tile_seq, _tile_live(batch, tiles), first, kv_cache,
+        batch["block_tables"], layer,
         block_size=block_size, scale=scale, value_width=R)
     return out[tiles["tok_tile"], tiles["tok_slot"]]

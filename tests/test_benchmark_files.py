@@ -393,6 +393,8 @@ def test_ssm_mechanism_check_names_each_fault():
      "kanana2.batch"),                                              # PR 37
     ("attn_window_key_fill_share", "ttft_p95_ms", "attn_wk",
      "dots3.longdoc"),                                              # PR 40
+    ("dsa_index_key_fill_share", "ttft_p95_ms", "idx_k",
+     "dots3.longdoc"),                                              # PR 43
 ])
 def test_mla_key_fill_share_is_a_data_file(name, moves, counts, cell):
     """A PR's one per-layer metric: an entry appended to BENCHMARK.json and
@@ -473,7 +475,8 @@ def test_dots3_cell_and_its_files():
     assert names[at:at + len(DOTS_METRICS)] == DOTS_METRICS   # in order
     assert names[at + len(DOTS_METRICS):] == [
         "attn_window_key_fill_share"] + PHI4_METRICS + [
-        "moe_held_hbm_share"]                       # PRs 40, 41, 42, appended
+        "moe_held_hbm_share",                       # PRs 40, 41, 42, 43,
+        "dsa_index_key_fill_share"]                 # appended
     held = json.loads((BENCH / "layer_metrics"
                        / "moe_held_hbm_share.json").read_text())
     assert per_layer["moe_held_hbm_share"]["workloads"] == ["dots3.longdoc"]
@@ -655,7 +658,8 @@ def test_phi4flash_cell_and_its_files():
     names = [m["name"] for m in bench["per_layer"]]
     at = names.index(PHI4_METRICS[0])
     assert names[at:at + len(PHI4_METRICS)] == PHI4_METRICS   # in order
-    assert names[at + len(PHI4_METRICS):] == ["moe_held_hbm_share"]   # PR 42
+    assert names[at + len(PHI4_METRICS):] == [
+        "moe_held_hbm_share", "dsa_index_key_fill_share"]       # PRs 42, 43
     for name in PHI4_METRICS:
         assert per_layer[name]["workloads"] == ["phi4flash.longdoc"]
         d = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())

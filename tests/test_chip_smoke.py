@@ -84,6 +84,20 @@ def test_held_phase_interpreted(smoke):
     assert max(info.values()) <= smoke.HELD_KERNEL_REL_TOL
 
 
+def test_index_phase_interpreted(smoke, monkeypatch):
+    """The indexer's kernel interpreted at 4 heads of 16, top-k 40, key
+    blocks of 32 keys = 4 pages of 8 and index blocks of two: a chunk that
+    crosses the top-k, decode rows around it, a short prompt."""
+    from llm_d_tpu.ops.pallas import dsa_index, mla_masked
+    monkeypatch.setattr(mla_masked, "KEY_BLOCK", 32)
+    monkeypatch.setattr(dsa_index, "INDEX_BLOCK", 64)
+    info = smoke.index_phase(
+        4, 16, 40, [(150, 130), (39, 1), (40, 1), (41, 1), (97, 1), (9, 9)],
+        seed=0, block_size=8, table_blocks=32, n_check=130, interpret=True)
+    assert info["index_worst_margin"] <= smoke.INDEX_MARGIN_REL_TOL
+    assert info["index_decode_vs_prefill"] == 0
+
+
 def test_sharded_phase_tiny_on_virtual_devices(smoke, devices):
     """The --chips 4 phase on four of the suite's virtual CPU devices."""
     info = smoke.sharded_phase(

@@ -447,6 +447,202 @@ def test_masked_kernel_decode_row_is_its_prefill_row():
     assert np.abs(whole).max() > 0
 
 
+# The indexer's kernel (ops/pallas/dsa_index.py), interpreted in float32 on
+# inputs of eighths, so that every product and every sum of the scores is
+# exact and the two forms' sets can be compared bit for bit: key blocks of
+# two pages (16 keys), a top-k of 20 that a chunk crosses inside a block.
+INDEX_ROWS = [(50, 45), (23, 1), (64, 9), (17, 17), (8, 1), (37, 1)]
+
+
+def _index_kernel_case(monkeypatch, index_block, rows=INDEX_ROWS, coarse=False,
+                       seed=5, Hi=3, Di=8):
+    """(batch, q, w, cache) of one step and the patched kernel modules:
+    ``index_block`` keys a step of the walk, ``coarse``: keys of -1, 0, 1
+    and weights of halves, so that many scores tie at every threshold."""
+    from llm_d_tpu.ops.pallas import dsa_index, mla_masked
+    monkeypatch.setattr(mla_masked, "KEY_BLOCK", 2 * BS)
+    monkeypatch.setattr(dsa_index, "INDEX_BLOCK", index_block)
+    for name in ("index_bias", "unwritten"):
+        monkeypatch.setattr(dsa_index, name, functools.partial(
+            getattr(dsa_index, name), interpret=True))
+    rng = np.random.default_rng(seed)
+    batch = _batch(rows)
+    T = int(batch["positions"].shape[0])
+    slots = (8 * len(rows) + 1) * BS
+    step = 1.0 if coarse else 0.125
+    grid = lambda shape, scale: jnp.asarray(np.round(
+        rng.standard_normal(shape) * scale / step) * step, jnp.float32)
+    return (batch, grid((T, Hi, Di), 1.0),
+            grid((T, Hi), 1.0) if not coarse else jnp.asarray(
+                rng.integers(-1, 3, (T, Hi)) / 2, jnp.float32),
+            grid((2, slots, Di), 0.6 if coarse else 1.0))
+
+
+def _bias_of(chosen):
+    """``attend_chosen``'s rewrite of a [NT, Qt, C] mask as the masked
+    kernel's bias [NT, C / KB, Qt, KB]."""
+    from llm_d_tpu.ops.pallas import mla_masked
+    NT, qt, C = chosen.shape
+    KB = mla_masked.KEY_BLOCK
+    return np.where(np.asarray(chosen), 0.0, mla_masked.NEG_INF).astype(
+        np.float32).reshape(NT, qt, C // KB, KB).transpose(0, 2, 1, 3)
+
+
+def _select_both(batch, q, w, cache, topk, q_tile=sparse_mla.SELECT_Q_TILE):
+    """(the kernel's bias, the XLA form's mask as a bias, each tile's
+    ``live``, the tile list) of one step."""
+    tiles = sparse_mla.with_tiles(batch, q_tile)
+    run = lambda fn: jax.jit(functools.partial(
+        fn, block_size=BS, topk=topk))(q, w, cache, tiles,
+                                       layer=jnp.int32(1))
+    return (np.asarray(run(sparse_mla.index_bias)),
+            _bias_of(run(sparse_mla.index_select)),
+            np.asarray(sparse_mla._tile_live(batch, tiles)), tiles)
+
+
+def _assert_same_under_live(got, want, live):
+    """Equal over the key blocks the masked kernel walks (it ends at the
+    tile's ``live``; the indexer's kernel writes no further)."""
+    KB = got.shape[-1]
+    for n in range(len(live)):
+        nb = -(-int(live[n]) // KB)
+        np.testing.assert_array_equal(got[n, :nb], want[n, :nb], str(n))
+        assert (want[n, nb:] < 0).all()
+
+
+@pytest.mark.parametrize("coarse", [False, True], ids=["eighths", "ties"])
+@pytest.mark.parametrize("index_block", [2 * BS, 4 * BS])
+def test_index_kernel_writes_the_xla_forms_bias(index_block, coarse,
+                                                monkeypatch):
+    """The kernel's bias against ``index_select`` + ``attend_chosen``'s on
+    a mixed step: a chunk that crosses the top-k inside a key block and
+    ends inside another, decode rows under and over the top-k, a fresh
+    prompt shorter than it, pad slots and pad tiles; index blocks of one
+    key block and of two.  ``ties``: scores on a coarse grid, so that equal
+    scores straddle nearly every threshold and the lower positions must be
+    the ones kept (the XLA form's own tests hold it to the stable sort)."""
+    topk = 20
+    batch, q, w, cache = _index_kernel_case(monkeypatch, index_block,
+                                            coarse=coarse)
+    got, want, live, tiles = _select_both(batch, q, w, cache, topk)
+    assert got.shape == want.shape and got.dtype == np.float32
+    _assert_same_under_live(got, want, live)
+    if coarse:      # the case is what it says: ties across a threshold
+        scores, _ = sparse_mla.index_scores(q, w, cache, tiles, BS,
+                                            jnp.int32(1))
+        s = _by_token(scores, batch, sparse_mla.SELECT_Q_TILE)
+        edge = np.sort(s, axis=1)[:, -topk]
+        assert ((s == edge[:, None]).sum(1)[np.isfinite(edge)] > 1).sum() > 20
+
+
+def test_index_kernel_one_slot_tile_is_its_eight_slot_tile(monkeypatch):
+    """A pure-decode step's tiles of one slot and a mixed step's of eight
+    go through one body, padded to the same eight sublanes: every query
+    selects the same set alone in its tile as among seven others."""
+    batch, q, w, cache = _index_kernel_case(monkeypatch, 4 * BS)
+    whole, _, _, tiles8 = _select_both(batch, q, w, cache, 20)
+    alone, want, live, tiles1 = _select_both(batch, q, w, cache, 20, q_tile=1)
+    assert alone.shape[2] == 1 and whole.shape[2] == 8
+    _assert_same_under_live(alone, want, live)
+    KB = whole.shape[-1]
+    pos = np.asarray(batch["positions"])
+    for t in range(len(pos)):
+        nb = pos[t] // KB + 1
+        np.testing.assert_array_equal(
+            alone[tiles1["tok_tile"][t], :nb, tiles1["tok_slot"][t]],
+            whole[tiles8["tok_tile"][t], :nb, tiles8["tok_slot"][t]])
+
+
+@pytest.mark.parametrize("index_block", [2 * BS, 4 * BS])
+def test_index_kernel_reads_nothing_past_a_tiles_live(index_block,
+                                                      monkeypatch):
+    """Every cache slot at and past a tile's ``live`` (the rest of its
+    walk's last block, the row's later pages, the trash page) holds huge
+    finite index keys that would score highest: none is selected, none
+    counted into a threshold (the sets are the XLA form's on the clean
+    cache, and every query keeps min(visible, top-k) keys)."""
+    topk = 20
+    batch, q, w, cache = _index_kernel_case(monkeypatch, index_block)
+    _, want, live, tiles = _select_both(batch, q, w, cache, topk)
+    tables, lens = (np.asarray(batch[k]) for k in ("block_tables",
+                                                   "seq_lens"))
+    dirty = np.full(cache.shape, 1e6, np.float32)
+    for s in range(len(lens)):
+        at = np.arange(lens[s])
+        slots = tables[s, at // BS] * BS + at % BS
+        dirty[:, slots] = np.asarray(cache)[:, slots]
+    got, _, _, _ = _select_both(batch, q, w, jnp.asarray(dirty), topk)
+    _assert_same_under_live(got, want, live)
+    pos = np.asarray(batch["positions"])
+    kept = (got == 0).transpose(0, 2, 1, 3).reshape(
+        got.shape[0], got.shape[2], -1)[tiles["tok_tile"], tiles["tok_slot"]]
+    for t in range(len(pos)):
+        assert kept[t, :pos[t] + 1].sum() == min(pos[t] + 1, topk), t
+        assert not kept[t, pos[t] + 1:-(-(pos[t] + 1) // index_block)
+                        * index_block].any(), t
+
+
+def test_index_kernel_feeds_the_masked_kernel(monkeypatch):
+    """The two kernels of a full layer in a row, as ``models/mla.py`` calls
+    them where they serve: the bias ``index_bias`` wrote, unwritten past
+    each tile's ``live``, read by ``mla_masked_attention`` (interpreted),
+    against the XLA forms' selection and attention on the same caches."""
+    from llm_d_tpu.ops.pallas import mla_masked
+    H, R, F, topk = 8, 128, 128, 20
+    batch, q, w, cache = _index_kernel_case(monkeypatch, 4 * BS)
+    monkeypatch.setattr(
+        mla_masked, "mla_masked_attention", functools.partial(
+            mla_masked.mla_masked_attention, interpret=True))
+    rng = np.random.default_rng(11)
+    q_eff = jnp.asarray(rng.standard_normal((q.shape[0], H, F)), jnp.float32)
+    kv = jnp.asarray(rng.standard_normal((2, cache.shape[1], F)), jnp.float32)
+    tiles = sparse_mla.with_tiles(batch, sparse_mla.SELECT_Q_TILE)
+
+    def layer(select, kernel):
+        chosen = select(q, w, cache, tiles, BS, jnp.int32(1), topk)
+        return sparse_mla.attend_chosen(q_eff, kv, chosen, tiles, BS,
+                                        jnp.int32(1), 0.3, R, kernel=kernel)
+
+    got = jax.jit(lambda: layer(sparse_mla.index_bias, True))()
+    want = jax.jit(lambda: layer(sparse_mla.index_select, False))()
+    assert np.abs(np.asarray(want)).max() > 0
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+def _index_walk_by_hand(ends, news, qt, block, layers):
+    """The indexer's kernel a tile at a time (the loop bounds of
+    ``ops.pallas.dsa_index._index_kernel``): eight slots a tile, whole
+    index blocks from key 0 to the tile's last query's own."""
+    real = slots = 0
+    for end, n in zip(ends, news):
+        for lo in range(end - n, end, qt):
+            q = range(lo, min(lo + qt, end))
+            real += sum(p + 1 for p in q)
+            slots += -(-(q[-1] + 1) // block) * block * 8
+    return {"idx_k_real": layers * real, "idx_k_slots": layers * slots}
+
+
+@pytest.mark.parametrize("seed", range(3))
+@pytest.mark.parametrize("Q,qt", [(2048, 8), (4, 4), (1, 1)])
+def test_engine_counts_the_pairs_its_index_walk_covers(Q, qt, seed):
+    from llm_d_tpu.engine.packed_batch import BatchLayout
+    eng, _ = _announced(_published_window())
+    assert eng._select_kernel is True
+    rng = np.random.default_rng(seed)
+    news = [int(rng.choice([1, int(rng.integers(1, Q + 1))]))
+            for _ in range(6)]
+    ends = [n + int(rng.choice([0, int(rng.integers(0, 9000 - n))]))
+            for n in news]
+    got = eng._idx_k_counts(ends, news, BatchLayout(2048, 8, Q, B=4))
+    assert got == _index_walk_by_hand(ends, news, qt, 2048, 3)
+    assert got["idx_k_real"] == eng._kv_counts(ends, news)["index_pairs"]
+    assert 0 < got["idx_k_real"] <= got["idx_k_slots"]
+    # Where the XLA form serves the full layers there is no walk to count.
+    eng, _ = _announced(_published_window(), backend="reference")
+    assert eng._select_kernel is False
+    assert eng._idx_k_counts(ends, news, None) == {}
+
+
 def _window_case(kernel, seed, monkeypatch):
     """(batch with its tile list, q, cache, H, R) for ``attend_window``:
     the XLA form over pages of 8 keys, or the kernel's walk (interpreted)

@@ -160,6 +160,25 @@ def _mla_window(T, S, Q, H=64, F=1152, R=1024, window=513, B=1024, L=3):
                 _sds((S, Q), jnp.int32), _sds((), jnp.int32)]
 
 
+def _dsa_index(T, S, Q, Hi=64, Di=128, topk=2048, B=1024, L=3):
+    """``sparse_mla.index_bias``: the row keys' gather in XLA, the walk, the
+    threshold and the bias in Mosaic (ops/pallas/dsa_index.py), for a step
+    of T tokens in S rows at dots3-note-prev's indexer (64 heads of 128,
+    the 2,048 best of a table of 32,768 positions)."""
+    from llm_d_tpu.ops import sparse_mla
+
+    def fn(q, w, kc, bt, sl, pos, seq, qpos, qtok, layer):
+        batch = dict(block_tables=bt, seq_lens=sl, positions=pos,
+                     token_seq_ids=seq, token_qpos=qpos, qtok_idx=qtok)
+        return sparse_mla.index_bias(q, w, kc, batch, BS, layer, topk)
+
+    return fn, [_sds((T, Hi, Di), jnp.bfloat16), _sds((T, Hi), jnp.float32),
+                _sds((L, SLOTS, Di), jnp.bfloat16), _sds((S, B), jnp.int32),
+                _sds((S,), jnp.int32), _sds((T,), jnp.int32),
+                _sds((T,), jnp.int32), _sds((T,), jnp.int32),
+                _sds((S, Q), jnp.int32), _sds((), jnp.int32)]
+
+
 def _ssm_update(H=32, P=128, N=256, G=2, S=64, L=6):
     """The state-space mixer's one-token update, in place on the state pool
     of ``S`` slots and the trash slot (falcon-h1-34b's geometry)."""
@@ -464,6 +483,12 @@ CASES = [
                  id="mla_masked-dots3-window-T2048-S16"),
     pytest.param(functools.partial(_mla_window, T=16, S=16, Q=1),
                  id="mla_masked-dots3-window-decode-S16"),
+    # Its full layers' indexer: a tile's scores, threshold and bias in one
+    # kernel, tiles of 8 slots and tiles of one padded to 8.
+    pytest.param(functools.partial(_dsa_index, T=2048, S=16, Q=2048),
+                 id="dsa_index-dots3-T2048-S16"),
+    pytest.param(functools.partial(_dsa_index, T=16, S=16, Q=1),
+                 id="dsa_index-dots3-decode-S16"),
     # Its held bf16 experts: the grouped kernel over tiles of held rows and
     # the combine, a 2,048-token mixed step and a pure-decode step.
     pytest.param(functools.partial(_moe_held, T=2048),
