@@ -624,7 +624,7 @@ def moe_phase(model: str, n_seqs: int, prompt_len: int, max_tokens: int,
     prompts = seeded_prompts(seed + 10, [prompt_len] * n_seqs, vocab)
 
     # Wave A: all sequences at once, no logprobs -> prefill steps above
-    # GROUPED_INT8_MIN_T tokens (streamed kernel), fused multistep decode
+    # ROUTED_INT8_MAX_T tokens (streamed kernel), fused multistep decode
     # above DENSE_INT8_MAX_T rows (routed kernel).
     t0 = time.time()
     a = run_engine(engine, [make_request(f"a{i}", p, max_tokens)

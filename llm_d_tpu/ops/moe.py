@@ -9,9 +9,9 @@ wide-ep decode.yaml:76-132).  Design:
   - Grouped GEMM: tokens are sorted by expert id and fed to
     ``jax.lax.ragged_dot`` — one MXU-friendly kernel over all local experts
     instead of a Python loop (the DeepGEMM role).  The int8 path has its
-    own four-kernel family (dense streaming / fused-routing routed /
-    chunk-streamed routed / sorted grouped — see ``DENSE_INT8_MAX_T``
-    and ``ops.pallas``).
+    own three kernels (dense streaming / fused-routing routed /
+    chunk-streamed routed), chosen by a step's token count alone: see
+    ``DENSE_INT8_MAX_T`` and ``ops.pallas``.
   - Expert parallelism: experts shard over the *flattened* (dp, sp, tp) mesh
     axes ("TPxDP in attention, EP in MoE layers", decode.yaml:76,87).  Two
     dispatch strategies:
@@ -153,7 +153,7 @@ def _local_expert_ffn(
 
     wslot = (weights.reshape(S)[order]
              * is_local[order].astype(jnp.float32))[:, None]
-    return _unsort_combine(y * wslot, order, T, k, inv=inv)
+    return _unsort_combine(y * wslot, inv, T, k)
 
 
 def _held_expert_ffn(
@@ -205,27 +205,19 @@ def _held_expert_ffn(
                         group_sizes)                              # [S, H] f32
     y = jnp.where(is_held[order][:, None],
                   y * weights.reshape(S)[order][:, None], 0.0)
-    return _unsort_combine(y, order, T, k, inv=inv)
+    return _unsort_combine(y, inv, T, k)
 
 
-def _unsort_combine(y: jax.Array, order: jax.Array, T: int, k: int,
-                    dest: Optional[jax.Array] = None,
-                    inv: Optional[jax.Array] = None) -> jax.Array:
+def _unsort_combine(y: jax.Array, inv: jax.Array, T: int,
+                    k: int) -> jax.Array:
     """Per-token combine WITHOUT a [T, H] scatter-add (XLA lowers big row
-    scatters to serialized updates on TPU): un-sort via the inverse
-    permutation (a cheap 1-D scatter + ONE fast row gather), then a
-    [T, k, H] reshape-sum.  ``y`` rows are already combine-weighted, laid
-    out in ``order``'s sorted layout — or, with ``dest``, in a padded
-    layout where sorted slot ``s`` lives at row ``dest[s]`` (the grouped
-    kernel's layout); the index composition stays int32-only."""
-    S = T * k
-    if inv is None:
-        inv = jnp.zeros((S,), jnp.int32).at[order].set(
-            jnp.arange(S, dtype=jnp.int32))
-    src = inv if dest is None else dest[inv]
+    scatters to serialized updates on TPU): un-sort by ONE fast row gather
+    through the sort's inverse permutation ``inv`` (slot ``s`` lies at
+    sorted row ``inv[s]``), then a [T, k, H] reshape-sum.  ``y`` rows are
+    already combine-weighted, in the sorted layout."""
     # f32 AFTER the gather (bf16 rows move at half the bytes); the k-sum
     # accumulates in f32 either way.
-    contrib = y[src].astype(jnp.float32)      # [S, H] in flat (t, k) order
+    contrib = y[inv].astype(jnp.float32)      # [S, H] in flat (t, k) order
     return contrib.reshape(T, k, -1).sum(axis=1)
 
 
@@ -261,76 +253,59 @@ def _dense_expert_ffn(
                       preferred_element_type=jnp.float32)
 
 
-# Below this many tokens the dense all-experts path beats ragged_dot on a
-# single shard (measured crossover on v5e; see _dense_expert_ffn).
+# One device, bf16 or dequantized weights: up to this many tokens take the
+# all-experts batched product (``_dense_expert_ffn`` says why), more take the
+# sorted grouped product.
 DENSE_DISPATCH_MAX_T = 512
 
-# int8 kernel routing, three regimes over a four-kernel family (r7
-# retune — see ops/pallas/moe_routed{,_stream}.py, docs/perf-notes-r7.md
-# and scripts/kernel_bench.py for the measured crossover sweep):
+# int8 experts on the TPU: which of the three kernels serves a step is a
+# function of its token count T alone.  A step's prefill-chunk and decode
+# rows are one T: each layer's expert weights stream once for both.
 #
-#   T <= DENSE_INT8_MAX_T           dense all-experts streaming kernel.
-#     Weight-bound tiny batches: all-experts compute rides under the
-#     weight-stream time anyway, and the routed kernel's per-tile
-#     padding (up to E*rt/2 phantom rows) is at its relative worst.
-#   DENSE < T <= GROUPED_INT8_MIN_T fused-routing routed kernel.
-#     The decode sweet spot: x stays VMEM-resident whole, gather/combine
-#     run as one-hot matmuls inside the kernel, compute is T*k rows.
-#   T >  GROUPED_INT8_MIN_T         chunk-streamed routed kernel
-#     (prefill default): x streams through VMEM in token-order chunks of
-#     LLMD_MOE_PREFILL_CHUNK_T rows (double-buffered), per-chunk
-#     counting-sort metadata rides scalar prefetch, and gather/combine
-#     stay in-kernel one-hot matmuls — the sorted+padded [S_pad, H] HBM
-#     layout and its 4-extra-row-trips/5x-padding glue tax are gone from
-#     the T > 512 regime entirely.  The sorted+padded grouped kernel
-#     (the r5/r6 prefill path) remains as the LLMD_MOE_PREFILL_KERNEL=
-#     grouped fallback / A-B lever.
-#
-# The r6 crossovers keep their names and defaults: the dense window is
-# the genuinely weight-bound region, and GROUPED_INT8_MIN_T still marks
-# where whole-batch VMEM residency ends — above it the STREAMED kernel
-# now takes over instead of the grouped one.  Re-measure on chip via
-# LLMD_MOE_DENSE_KERNEL_MAX_T / LLMD_MOE_GROUPED_MIN_T (invalid values
-# fall back to these defaults rather than crashing the serving path).
-#
-# Fused mixed rounds (r15, engine chunked-prefill/decode fusion): the
-# engine now lands prefill-chunk tokens AND decode/verify tokens in ONE
-# program, so these crossovers apply to the COMBINED per-step T — a
-# 64-row decode batch joined by a 448-token prefill chunk dispatches
-# once at T=512, not twice at T=64 and T=448.  That is the prefill-MFU
-# lever: each layer's expert weights stream from HBM ONCE per step and
-# the prefill GEMM rows amortize the weight traffic the decode rows were
-# already paying (scripts/kernel_bench.py --mixed measures fused-vs-
-# two-program tok/s across the chunk-size x decode-batch plane).
+#   T <= DENSE_INT8_MAX_T    ``dense_moe_int8``, every expert against every
+#     row.  So few rows are bound by the weights' bytes: the extra products
+#     ride under the stream, and the routed kernel's padding of each
+#     expert's rows to a tile (up to E * row_tile / 2 rows) is at its
+#     relative worst.
+#   T <= ROUTED_INT8_MAX_T   ``routed_moe_int8``, T * k rows: the whole batch
+#     stays in VMEM, gather and combine are one-hot products inside the
+#     kernel.  512 rows is where that residency ends (x, the f32 output
+#     block and the double-buffered weight slabs share VMEM).
+#   above                    ``streamed_moe_int8``, the same body over
+#     token-order chunks of PREFILL_CHUNK_T rows streamed through VMEM.
 DENSE_INT8_MAX_T = 64
-GROUPED_INT8_MIN_T = 512
+ROUTED_INT8_MAX_T = 512
 
-# Token-chunk height for the chunk-streamed prefill kernel
-# (LLMD_MOE_PREFILL_CHUNK_T).  The chunk trades the kernel's two taxes:
-# weight re-streaming scales with T/chunk_t passes/layer while the
-# one-hot gather/combine FLOP tax scales with 2*chunk_t/(3*I); 512 sits
-# at the VMEM budget (chunk + f32 accumulator + double-buffered weight
-# tiles) on v5e.  See docs/perf-notes-r7.md.
+# Rows a chunk of the streamed kernel.  A layer's weights are read once a
+# chunk (T / chunk passes) while the one-hot gather and combine cost
+# 2 * chunk / (3 * I) of the expert FLOPs; 512 is what VMEM holds on the
+# v5e beside the f32 accumulator and the double-buffered weight tiles.
 PREFILL_CHUNK_T = 512
 
 
+def _routed_row_tile(slots: int, E: int) -> int:
+    """Rows a tile of the routed and streamed kernels, from the mean rows
+    an expert: small tiles bound each expert's padding (the only waste
+    left), larger ones feed the MXU better once the groups fill them."""
+    return 32 if slots < E * 96 else 64
+
+
 def _env_int(name: str, default: int) -> int:
-    """Integer env knob with invalid-value fallback: a malformed value
-    (e.g. ``LLMD_MOE_GROUPED_MIN_T=banana``) must degrade to the tuned
-    default, not crash the serving path at trace time.  Shared
-    implementation: ``llm_d_tpu.utils.config.env_int``."""
+    """Integer environment option; a malformed value falls back to the
+    default (``llm_d_tpu.utils.config.env_int``) instead of failing the
+    serving path at trace time."""
     from llm_d_tpu.utils.config import env_int
     return env_int(name, default)
 
 
 def _sorted_tile_layout(flat: jax.Array, weights_flat: jax.Array,
                         k: int, E: int, rt: int):
-    """Counting-sort tile layout shared by the routed and grouped int8
+    """Counting-sort tile layout shared by the routed and streamed int8
     kernel paths: rows sorted by expert, each group padded to a ``rt``
     multiple, one expert per tile.
 
-    Returns ``(order, inv, tok_s, slot, wslot_pad, tile_expert,
-    num_tiles)``: ``slot[s]`` is sorted element s's position in the
+    Returns ``(tok_s, slot, wslot_pad, tile_expert, num_tiles)``:
+    ``tok_s[s]`` is sorted element s's token, ``slot[s]`` its position in the
     padded layout (static worst case ``S_pad = ceil(S/rt)*rt + E*rt`` —
     METADATA length only, no [_, H] rows); ``wslot_pad`` carries the
     combine weight per padded slot (0 = pad); ``tile_expert`` maps each
@@ -341,7 +316,7 @@ def _sorted_tile_layout(flat: jax.Array, weights_flat: jax.Array,
     ``num_tiles`` counts the populated tiles.  Empty experts get zero
     tiles — their weights are never streamed."""
     S = flat.shape[0]
-    order, inv, counts = _stable_argsort_bounded(flat, E)
+    order, _, counts = _stable_argsort_bounded(flat, E)
     eid_s = flat[order]
     tok_s = (order // k).astype(jnp.int32)
     padded = -(-counts // rt) * rt
@@ -359,7 +334,7 @@ def _sorted_tile_layout(flat: jax.Array, weights_flat: jax.Array,
     tile_expert = jnp.minimum(
         jnp.searchsorted(bounds, starts, side="right"),
         E - 1).astype(jnp.int32)
-    return order, inv, tok_s, slot, wslot_pad, tile_expert, num_tiles
+    return tok_s, slot, wslot_pad, tile_expert, num_tiles
 
 
 def _routed_int8_kernel_path(x, weights, idx, quant: dict,
@@ -367,26 +342,17 @@ def _routed_int8_kernel_path(x, weights, idx, quant: dict,
                              interpret: bool = False):
     """Metadata-only glue for the fused-routing kernel (decode regime).
 
-    Unlike ``_grouped_int8_kernel_path`` no activation row moves here:
-    the counting sort plus O(S) int32 slot arithmetic produce the
-    scalar-prefetch routing tables and the kernel does the gather /
-    combine itself (ops/pallas/moe_routed.py)."""
+    No activation row moves here: the counting sort plus O(S) int32 slot
+    arithmetic produce the scalar-prefetch routing tables and the kernel
+    does the gather / combine itself (ops/pallas/moe_routed.py)."""
     from llm_d_tpu.ops.pallas.moe_routed import routed_moe_int8
     T, H = x.shape
     k = idx.shape[1]
     E = quant["w_gate_q"].shape[1]
     S = T * k
-    if row_tile is None:
-        # Mean rows/expert governs the tile: small tiles bound the
-        # per-expert padding (the only waste left), larger tiles feed
-        # the MXU better once groups support them.
-        rt = _env_int("LLMD_MOE_ROUTED_ROW_TILE", 0) \
-            or (32 if S < E * 96 else 64)
-    else:
-        rt = row_tile
-    flat = idx.reshape(S)
-    order, _, tok_s, slot, wslot_pad, tile_expert, num_tiles = \
-        _sorted_tile_layout(flat, weights.reshape(S), k, E, rt)
+    rt = row_tile or _routed_row_tile(S, E)
+    tok_s, slot, wslot_pad, tile_expert, num_tiles = _sorted_tile_layout(
+        idx.reshape(S), weights.reshape(S), k, E, rt)
     S_pad = wslot_pad.shape[0]
     NT = S_pad // rt
     # Pad slots keep token 0 with zero combine weight: they select a real
@@ -425,19 +391,14 @@ def _streamed_int8_kernel_path(x, weights, idx, quant: dict,
     k = idx.shape[1]
     E = quant["w_gate_q"].shape[1]
     if chunk_t is None:
-        chunk_t = _env_int("LLMD_MOE_PREFILL_CHUNK_T", PREFILL_CHUNK_T)
+        chunk_t = PREFILL_CHUNK_T
     # bf16 sublane alignment; never a taller chunk than the (aligned)
     # batch itself — small batches degenerate to a single chunk.
     chunk_t = max(16, min(-(-chunk_t // 16) * 16, -(-T // 16) * 16))
     C = -(-T // chunk_t)
     Tp = C * chunk_t
     S_c = chunk_t * k
-    if row_tile is None:
-        # Same auto rule as the routed kernel, on per-chunk group sizes.
-        rt = _env_int("LLMD_MOE_ROUTED_ROW_TILE", 0) \
-            or (32 if S_c < E * 96 else 64)
-    else:
-        rt = row_tile
+    rt = row_tile or _routed_row_tile(S_c, E)     # per-chunk group sizes
     x_p = x.astype(jnp.bfloat16)
     if Tp != T:
         # Pad tokens route to expert 0 with ZERO combine weight: they
@@ -449,7 +410,7 @@ def _streamed_int8_kernel_path(x, weights, idx, quant: dict,
 
     def chunk_layout(flat, wf):
         # Chunk-local layout: tok ids are 0..chunk_t-1 within the chunk.
-        _, _, tok_s, slot, wslot_pad, tile_expert, num_tiles = \
+        tok_s, slot, wslot_pad, tile_expert, num_tiles = \
             _sorted_tile_layout(flat, wf, k, E, rt)
         tok_pad = jnp.zeros((wslot_pad.shape[0],), jnp.int32).at[slot].set(
             tok_s)
@@ -468,50 +429,6 @@ def _streamed_int8_kernel_path(x, weights, idx, quant: dict,
     # out_dtype lets combine-in-f32 callers (the a2a exchange) skip a
     # lossy bf16 round trip of the kernel's native f32 accumulator.
     return out[:T].astype(out_dtype or x.dtype)
-
-
-def _grouped_int8_kernel_path(x, weights, idx, quant: dict,
-                              row_tile: Optional[int] = None,
-                              interpret: bool = False):
-    """Sort/pad/scatter glue for the grouped int8 kernel.
-
-    Rows are sorted by expert and each expert's run padded to a
-    ``row_tile`` multiple so every kernel tile serves exactly one expert
-    (static grid, no ragged_dot).  Pad rows carry zero combine weight.
-    ``quant`` must carry STACKED [Lm, E, ...] payloads and a "layer"
-    plane index (the model's contract; see models/moe.py)."""
-    from llm_d_tpu.ops.pallas.moe_int8 import grouped_moe_int8
-    T, H = x.shape
-    k = idx.shape[1]
-    E = quant["w_gate_q"].shape[1]
-    S = T * k
-    if row_tile is None:
-        # Tiles below 128 rows starve the MXU (measured: rt=32 at bs256
-        # decode ran ~13% slower than the dense kernel despite 8x fewer
-        # FLOPs); 256 once the mean rows/expert supports it.
-        rt = 128 if S < E * 256 else 256
-    else:
-        rt = row_tile
-    flat = idx.reshape(S)
-    order, sort_inv, tok_s, dest, wslot_pad, tile_expert, _ = \
-        _sorted_tile_layout(flat, weights.reshape(S), k, E, rt)
-    S_pad = wslot_pad.shape[0]
-    # Row data moves by GATHER only: big [*, H] scatters lower to
-    # serialized updates on TPU, so the padded layout is built from 1-D
-    # index scatters (cheap) + row gathers.  Padded slots point at the
-    # appended zero row of x_ext and carry zero combine weight.
-    src = jnp.full((S_pad,), T, jnp.int32).at[dest].set(tok_s)
-    x_ext = jnp.concatenate(
-        [x.astype(jnp.bfloat16), jnp.zeros((1, H), jnp.bfloat16)])
-    x_pad = x_ext[src]                                    # [S_pad, H]
-    y_pad = grouped_moe_int8(
-        x_pad, wslot_pad[:, None], tile_expert, quant["layer"],
-        quant["w_gate_q"], quant["w_gate_s"],
-        quant["w_up_q"], quant["w_up_s"],
-        quant["w_down_q"], quant["w_down_s"],
-        row_tile=rt, interpret=interpret)
-    return _unsort_combine(y_pad, order, T, k, dest=dest,
-                           inv=sort_inv).astype(x.dtype)
 
 
 def _dense_int8_kernel_path(x, weights, idx, quant: dict,
@@ -907,10 +824,9 @@ def expert_ffn(
     ``_dense_expert_ffn``), sorted grouped GEMM above it (prefill).
     Multi-device: sparse all-to-all dispatch by default
     (``LLMD_MOE_DISPATCH=psum`` forces the oracle path; see module
-    docstring).  One call serves whatever population the engine batched
-    — under fused mixed rounds (r15) that is prefill-chunk AND
-    decode/verify tokens together, so each layer's expert weights
-    stream once for both (the regime thresholds see the combined T).
+    docstring).  One call serves whatever population the engine batched:
+    prefill-chunk AND decode/verify tokens together, so each layer's
+    expert weights stream once for both (the thresholds see the combined T).
 
     ``quant`` carries int8 expert payloads END TO END: on the TPU
     single-device path they reach the Pallas kernel family (dense
@@ -935,32 +851,18 @@ def expert_ffn(
             dispatch = os.environ.get("LLMD_MOE_DISPATCH", "auto")
         if quant is not None and jax.default_backend() == "tpu" \
                 and dispatch == "auto":
-            # int8 kernel routing, three regimes (an EXPLICIT dispatch
-            # override still gets the classic dequant paths below — the
-            # A/B lever).  See the regime comment at DENSE_INT8_MAX_T.
-            dense_max = _env_int("LLMD_MOE_DENSE_KERNEL_MAX_T",
-                                 DENSE_INT8_MAX_T)
-            grouped_min = _env_int("LLMD_MOE_GROUPED_MIN_T",
-                                   GROUPED_INT8_MIN_T)
-            if x.shape[0] <= dense_max:
-                # Tiny batches: weight-bound; all-experts streaming wins.
+            # The three int8 kernels, by the step's token count (the
+            # comment at DENSE_INT8_MAX_T says why each boundary); an
+            # EXPLICIT dispatch gets the dequantized paths below, the
+            # kernels' reference.
+            if x.shape[0] <= DENSE_INT8_MAX_T:
                 return _dense_int8_kernel_path(x, weights, idx, quant)
-            if x.shape[0] <= grouped_min:
-                # Decode regime: fused-routing kernel, T*k rows, zero
-                # XLA row glue (ops/pallas/moe_routed.py).
+            if x.shape[0] <= ROUTED_INT8_MAX_T:
                 return _routed_int8_kernel_path(x, weights, idx, quant)
-            if os.environ.get("LLMD_MOE_PREFILL_KERNEL",
-                              "streamed") == "grouped":
-                # Fallback / A-B lever: the r5/r6 sorted+padded grouped
-                # kernel with its XLA row glue.
-                return _grouped_int8_kernel_path(x, weights, idx, quant)
-            # Prefill regime (default): chunk-streamed fused-routing
-            # kernel — x streams through VMEM, no sorted+padded
-            # [S_pad, H] layout in HBM (ops/pallas/moe_routed_stream.py).
             return _streamed_int8_kernel_path(x, weights, idx, quant)
         if dispatch == "auto":
-            max_t = _env_int("LLMD_MOE_DENSE_MAX_T", DENSE_DISPATCH_MAX_T)
-            dispatch = "dense" if x.shape[0] <= max_t else "ragged"
+            dispatch = ("dense" if x.shape[0] <= DENSE_DISPATCH_MAX_T
+                        else "ragged")
         if quant is not None:
             w_gate, w_up, w_down = _dequant_layer(quant)
         if dispatch == "dense":

@@ -1,3 +1,4 @@
+# llmd: ignore[PAL002] every block is its array's whole trailing dims: no tiling
 """Pallas TPU kernel: all-experts MoE FFN over STACKED int8 expert weights.
 
 The decode-regime MoE FFN (see ``ops.moe._dense_expert_ffn``) computes
@@ -43,104 +44,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-
-
-def _grouped_kernel(
-    layer_ref,    # [1]  SMEM (scalar prefetch: MoE-layer plane)
-    te_ref,       # [NT] SMEM (scalar prefetch: expert id per row tile)
-    x_ref,        # [RT, H] bf16 (this tile's sorted+padded rows)
-    wslot_ref,    # [RT, 1] f32 combine weight per row (0 = pad/trash)
-    wg_ref,       # [1, 1, H, I] int8 (this tile's expert)
-    wu_ref,       # [1, 1, H, I] int8
-    wd_ref,       # [1, 1, I, H] int8
-    gs_ref,       # [1, 1, 1, I] f32
-    us_ref,       # [1, 1, 1, I] f32
-    ds_ref,       # [1, 1, 1, H] f32
-    o_ref,        # [RT, H] bf16
-):
-    x = x_ref[...]                                        # [RT, H] bf16
-    wg = wg_ref[0, 0].astype(jnp.bfloat16)                # [H, I] exact
-    wu = wu_ref[0, 0].astype(jnp.bfloat16)
-    h = jax.lax.dot(x, wg,
-                    preferred_element_type=jnp.float32) * gs_ref[0, 0]
-    u = jax.lax.dot(x, wu,
-                    preferred_element_type=jnp.float32) * us_ref[0, 0]
-    a = jax.nn.silu(h) * u * wslot_ref[...]               # [RT, I] f32
-    wd = wd_ref[0, 0].astype(jnp.bfloat16)
-    y = jax.lax.dot(a.astype(jnp.bfloat16), wd,
-                    preferred_element_type=jnp.float32) * ds_ref[0, 0]
-    o_ref[...] = y.astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("row_tile", "interpret"))
-def grouped_moe_int8(
-    x_pad: jax.Array,       # [S_pad, H] bf16 — rows sorted by expert, each
-                            #   expert's run padded to a row_tile multiple
-    wslot_pad: jax.Array,   # [S_pad, 1] f32 combine weights (0 = pad row)
-    tile_expert: jax.Array, # [S_pad // row_tile] i32 expert id per tile
-    layer,                  # scalar int32: plane of the stacked weights
-    w_gate_q: jax.Array,    # [Lm, E, H, I] int8
-    w_gate_s: jax.Array,    # [Lm, E, 1, I] f32
-    w_up_q: jax.Array,
-    w_up_s: jax.Array,
-    w_down_q: jax.Array,    # [Lm, E, I, H] int8
-    w_down_s: jax.Array,    # [Lm, E, 1, H] f32
-    row_tile: int = 256,
-    interpret: bool = False,
-) -> jax.Array:             # [S_pad, H] bf16 (combine-weighted rows)
-    """SORTED grouped int8 MoE FFN — the prefill-regime companion of
-    ``dense_moe_int8`` (DeepGEMM's contiguous grouped GEMM role).
-
-    The dense kernel computes every expert against every token — right
-    when decode batches are tiny and the op is weight-bound, an 8x FLOP
-    waste once ``T x E`` work turns MXU-bound (prefill, large decode
-    batches).  Here each grid step processes ONE row tile belonging to
-    ONE expert (``tile_expert``, scalar-prefetched so the weight
-    BlockSpecs follow it): compute is ``S = T*k`` rows instead of
-    ``T*E`` — E/k = 8x less at deepseek-v3-bench shapes.  Consecutive
-    tiles of the same expert reuse the resident weight block (Pallas
-    skips the DMA when the index map repeats), so int8 weight traffic
-    stays one pass per layer.
-
-    The caller owns sort/pad/scatter (``ops.moe._grouped_int8_kernel_
-    path``); pad rows carry ``wslot = 0`` and any expert id — they
-    produce zeros.  Output rows are already combine-weighted: the caller
-    scatter-adds them straight into the [T, H] accumulator.
-    """
-    S_pad, H = x_pad.shape
-    Lm, E, _, I = w_gate_q.shape
-    assert S_pad % row_tile == 0
-    NT = S_pad // row_tile
-    layer_arr = jnp.asarray([layer], jnp.int32)
-
-    def wmap(t, layer_ref, te_ref):
-        return (layer_ref[0], te_ref[t], 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(NT,),
-        in_specs=[
-            pl.BlockSpec((row_tile, H), lambda t, *_: (t, 0)),
-            pl.BlockSpec((row_tile, 1), lambda t, *_: (t, 0)),
-            pl.BlockSpec((1, 1, H, I), wmap),
-            pl.BlockSpec((1, 1, H, I), wmap),
-            pl.BlockSpec((1, 1, I, H), wmap),
-            pl.BlockSpec((1, 1, 1, I), wmap),
-            pl.BlockSpec((1, 1, 1, I), wmap),
-            pl.BlockSpec((1, 1, 1, H), wmap),
-        ],
-        out_specs=pl.BlockSpec((row_tile, H), lambda t, *_: (t, 0)),
-    )
-    return pl.pallas_call(
-        _grouped_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S_pad, H), jnp.bfloat16),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(layer_arr, tile_expert, x_pad, wslot_pad,
-      w_gate_q, w_up_q, w_down_q, w_gate_s, w_up_s, w_down_s)
 
 
 def _kernel(

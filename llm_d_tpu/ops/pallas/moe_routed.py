@@ -1,18 +1,18 @@
 """Pallas TPU kernel: fused-routing grouped int8 MoE FFN (decode regime).
 
-The third point of the int8 MoE kernel family, built for the regime the
-other two lose in:
+One of the three int8 MoE kernels, built for the regime that both of
+these lose in:
 
   - ``dense_moe_int8`` computes every expert against every token — right
     for tiny batches (weight-bound), an 8x routed-FLOPs overspend once
     ``T x E`` work turns MXU-bound (measured r5: decode bs256 spends
     9.47 of 16.8 ms/step there, 12% MFU / 36.9% HBM roofline).
-  - ``grouped_moe_int8`` computes only routed rows, but its XLA glue
-    (padded-row gather/scatter + unsort combine) moves every activation
-    row through HBM twice more and made the grouped route ~13% SLOWER
-    than dense at decode sizes (perf-notes-r5).
+  - a kernel over rows that XLA sorts and pads by expert computes only
+    routed rows, but that glue (padded-row gather/scatter + unsort
+    combine) moves every activation row through HBM twice more: it
+    was no faster than dense at decode sizes and is gone (PR 44).
 
-This kernel keeps the grouped kernel's FLOP discipline and moves ALL
+This kernel keeps that FLOP discipline (routed rows only) and moves ALL
 row-data movement onto the MXU, inside the kernel:
 
   - ``x`` stays in TOKEN order and is resident in VMEM for the whole

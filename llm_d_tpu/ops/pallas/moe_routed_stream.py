@@ -5,16 +5,16 @@ token-ordered and VMEM-resident, and turn the gather / un-sort / combine
 into one-hot matmuls inside the kernel — zero XLA row glue.  Its limit
 is residency: the whole batch plus the f32 output block must sit in VMEM
 (~6 MB at T=512, H=2048), so the prefill regime (T up to 8192 — 32 MB
-bf16 for ``x`` alone) fell back to the sorted+padded grouped kernel,
+bf16 for ``x`` alone) would need rows sorted and padded by expert in HBM,
 whose XLA glue moves every activation row across HBM four extra times
-per layer with up to 5x ``S_pad`` padding inflation (perf-notes-r6; the
+per layer with up to 5x ``S_pad`` padding inflation (the very
 HBM-row-movement tax P/D-Serve, arXiv:2408.08147, charges to the
 prefill side of disaggregated serving).
 
 This kernel removes the residency requirement instead of the fusion:
 
   - ``x`` is split into TOKEN-ORDER chunks of ``chunk_t`` rows
-    (``LLMD_MOE_PREFILL_CHUNK_T``).  The chunk is the resident unit:
+    (``ops.moe.PREFILL_CHUNK_T``).  The chunk is the resident unit:
     grid = (C, NT_c) with the chunk index OUTER, so Pallas streams
     chunk c+1's block (double-buffered, one DMA per chunk) while chunk
     c's expert tiles compute;
@@ -34,7 +34,7 @@ This kernel removes the residency requirement instead of the fusion:
     compute-skipped via the per-chunk ``num_tiles`` guard; experts
     with zero routed tokens in a chunk get no tiles at all.
 
-Cost model vs the grouped path (bench shapes H=2048, I=512, E=64, k=8):
+Cost model vs such a layout (bench shapes H=2048, I=512, E=64, k=8):
 activation HBM traffic collapses to the minimum — ``x`` read once,
 output written once, NO ``[S_pad, H]`` intermediate in HBM at all.  The
 price is (a) the one-hot tax, ``2*chunk_t/(3*I)`` of the FFN FLOPs
@@ -42,10 +42,10 @@ price is (a) the one-hot tax, ``2*chunk_t/(3*I)`` of the FFN FLOPs
 chunk re-streams the weights of every expert it touches, so weight
 traffic is up to ``C`` passes/layer instead of one.  Both are paid
 INSIDE one kernel where Pallas overlaps them with compute, versus the
-grouped path's glue which serializes between kernel launches; the
+sorted layout's glue which serializes between kernel launches; the
 chunk size trades the two taxes (small chunks -> more weight passes,
-large chunks -> more one-hot FLOPs + VMEM).  See
-docs/perf-notes-r7.md for the full accounting.
+large chunks -> more one-hot FLOPs + VMEM); the comment at
+``ops.moe.PREFILL_CHUNK_T`` says where the two meet on the v5e.
 
 Reference role: DeepGEMM's contiguous grouped GEMM for prefill
 (m_grouped_gemm_fp8_fp8_bf16_nt_contiguous; docker/Dockerfile.cuda:

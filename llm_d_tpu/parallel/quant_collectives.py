@@ -20,9 +20,9 @@ quality cost; this module is that trade expressed over JAX collectives:
     EP tuple).
   - byte accounting (:func:`a2a_row_bytes`,
     :func:`ep_a2a_bytes_per_token`) — the ONE place wire bytes per
-    (token, choice) row are computed, shared by ``bench.py``'s v5p-256
-    projection, the kernel microbench, and the engine's
-    ``llmd_tpu:collective_bytes_total`` accounting.
+    (token, choice) row are computed: the engine's
+    ``llmd_tpu:collective_bytes_total`` accounting and the tests of the
+    wire formats read it.
 
 Mode selection rides ``LLMD_COLLECTIVE_DTYPE`` (``auto``/``bf16``/
 ``int8``): ``auto`` resolves to int8 on TPU — gated by the per-collective

@@ -1,7 +1,7 @@
 """Where the persistent XLA compile cache lives — decided in ONE place.
 
-Every entry point (``llmd-serve``, ``bench.py``, ``chip_smoke.py``,
-``scripts/kernel_bench.py``, ``__graft_entry__.py``, the test suite)
+Every entry point (``llmd-serve``, ``benchmarks/run.py``,
+``chip_smoke.py``, ``__graft_entry__.py``, the test suite)
 calls :func:`configure_compile_cache` before its first compile.
 
   - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; nothing here

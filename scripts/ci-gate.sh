@@ -5,7 +5,7 @@
 # oracles) runs pre-release via scripts/run-all-tests.sh.
 set -euo pipefail
 cd "$(dirname "$0")/.."
-python -m compileall -q llm_d_tpu tests scripts bench.py __graft_entry__.py
+python -m compileall -q llm_d_tpu tests scripts __graft_entry__.py
 # llmd-check: the contract-enforcing static-analysis suite (wire headers,
 # metric registry, env knobs, jit/host-sync hygiene, async blocking,
 # Pallas DMA invariants, Dockerfiles).  Fail-fast BEFORE any test

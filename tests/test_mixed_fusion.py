@@ -357,18 +357,3 @@ def test_fused_spans_and_composition_counters(fixed_engine):
     m = fixed_engine.metrics.render().decode()
     assert 'llmd_tpu:step_prefill_tokens_total{model_name="tiny"}' in m
     assert 'llmd_tpu:step_decode_tokens_total{model_name="tiny"}' in m
-
-
-@pytest.mark.slow
-def test_bench_mixed_tok_s_on_tiny():
-    import bench
-    out = bench.bench_mixed("tiny", 4, 2, 0.7, prompt_len=8,
-                            decode_steps=8)
-    row = out[4]
-    assert row["decode_tok_s"] > 0
-    assert row["spec_k"] == 2
-    assert row["prefill_share"] == bench.MIXED_BENCH_SHARE
-    table = out["tpot_vs_prefill_share"]
-    assert set(table) == {"0.00", "0.25", "0.50"}
-    for r in table.values():
-        assert r["tok_s"] > 0 and r["tpot_p99_ms"] > 0

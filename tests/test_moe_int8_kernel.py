@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from llm_d_tpu.ops import moe as moe_ops
 from llm_d_tpu.ops.pallas.moe_int8 import dense_moe_int8
 from llm_d_tpu.ops.quant import dequantize, quantize_int8
 
@@ -55,7 +56,6 @@ def test_kernel_dispatch_wiring_matches_dequant_path():
     combine scatter + stacked call) in interpret mode against the
     _dequant_layer fallback — the backend gate hides this wiring from CPU
     CI otherwise."""
-    from llm_d_tpu.ops import moe as moe_ops
 
     key = jax.random.PRNGKey(1)
     T, E, H, I, k, Lm = 16, 8, 256, 128, 2, 2
@@ -106,47 +106,6 @@ def test_engine_int8_uses_kernel_only_on_tpu():
     assert got[:2] == want[:2]
 
 
-@pytest.mark.parametrize("T,E,H,I,rt", [
-    (16, 8, 256, 128, 8),     # tiny rows, small tile: heavy padding path
-    (64, 4, 512, 256, 16),    # multi-tile experts
-    (36, 8, 256, 128, 16),    # S = T*k NOT a tile multiple (r5 review fix)
-])
-def test_grouped_kernel_matches_dequant_oracle(T, E, H, I, rt):
-    """Grouped (sorted+padded) int8 path == routed dequant oracle.
-    Drives the ACTUAL glue (_grouped_int8_kernel_path: sort, pad,
-    tile_expert construction, scatter-add) in interpret mode."""
-    from llm_d_tpu.ops import moe as moe_ops
-
-    key = jax.random.PRNGKey(7)
-    ks = jax.random.split(key, 6)
-    k = 2
-    x = jax.random.normal(ks[0], (T, H), jnp.bfloat16)
-    idx = jax.random.randint(ks[1], (T, k), 0, E)
-    w = jnp.abs(jax.random.normal(ks[2], (T, k), jnp.float32)) * 0.3
-    wg_q, wg_s = quantize_int8(
-        jax.random.normal(ks[3], (E, H, I), jnp.float32) * 0.05)
-    wu_q, wu_s = quantize_int8(
-        jax.random.normal(ks[4], (E, H, I), jnp.float32) * 0.05)
-    wd_q, wd_s = quantize_int8(
-        jax.random.normal(ks[5], (E, I, H), jnp.float32) * 0.05)
-    stack = lambda a: jnp.stack([jnp.zeros_like(a), a])
-    quant = dict(w_gate_q=stack(wg_q), w_gate_s=stack(wg_s),
-                 w_up_q=stack(wu_q), w_up_s=stack(wu_s),
-                 w_down_q=stack(wd_q), w_down_s=stack(wd_s),
-                 layer=jnp.int32(1))
-
-    got = moe_ops._grouped_int8_kernel_path(
-        x, w, idx, quant, row_tile=rt, interpret=True)
-
-    g, u, d = (dequantize(wg_q, wg_s), dequantize(wu_q, wu_s),
-               dequantize(wd_q, wd_s))
-    want = moe_ops._local_expert_ffn(x, w, idx, g, u, d, jnp.int32(0))
-
-    scale = float(jnp.max(jnp.abs(np.asarray(want)))) + 1e-9
-    np.testing.assert_allclose(np.asarray(got) / scale,
-                               np.asarray(want) / scale, atol=8e-3)
-
-
 def _rand_quant(key, E, H, I, Lm=2, plane=1):
     """Stacked int8 payloads addressing plane 1 (exercises the
     scalar-prefetch layer indexing) + the dequantized plane for oracles."""
@@ -165,7 +124,6 @@ def _rand_quant(key, E, H, I, Lm=2, plane=1):
 
 
 def _assert_routed_matches_oracle(x, w, idx, quant, deq, rt=None):
-    from llm_d_tpu.ops import moe as moe_ops
     got = moe_ops._routed_int8_kernel_path(
         x, w, idx, quant, row_tile=rt, interpret=True)
     want = moe_ops._local_expert_ffn(x, w, idx, *deq, jnp.int32(0))
@@ -200,7 +158,6 @@ def test_routed_kernel_empty_expert_groups():
     ZERO tiles (their weights are never addressed) and the output still
     matches the oracle — the empty-group skip the EPLB-sharded and
     small-batch layouts rely on."""
-    from llm_d_tpu.ops import moe as moe_ops
 
     key = jax.random.PRNGKey(13)
     T, E, H, I, k = 32, 16, 256, 128, 2
@@ -217,7 +174,7 @@ def test_routed_kernel_empty_expert_groups():
     # (same weight index map -> Pallas skips their DMA; a clamp to E-1
     # would stream an unused expert's weights).
     rt, S = 16, T * k
-    _, _, _, _, _, tile_e, num_tiles = moe_ops._sorted_tile_layout(
+    _, _, _, tile_e, num_tiles = moe_ops._sorted_tile_layout(
         idx.reshape(S), w.reshape(S), k, E, rt)
     nt = int(num_tiles)
     active = np.asarray(tile_e[:nt])
@@ -243,7 +200,6 @@ def test_routed_kernel_eplb_physical_layout():
     """Routed kernel under an EPLB replica table: logical ids map to
     physical slots (to_physical_experts), replicas carry the SAME weights,
     and the kernel over the physical layout matches the logical oracle."""
-    from llm_d_tpu.ops import moe as moe_ops
 
     key = jax.random.PRNGKey(19)
     T, E_log, H, I, k = 24, 4, 256, 128, 2
@@ -278,7 +234,6 @@ def test_routed_kernel_eplb_physical_layout():
 
 def _assert_streamed_matches_oracle(x, w, idx, quant, deq,
                                     chunk_t=None, rt=None):
-    from llm_d_tpu.ops import moe as moe_ops
     got = moe_ops._streamed_int8_kernel_path(
         x, w, idx, quant, chunk_t=chunk_t, row_tile=rt, interpret=True)
     want = moe_ops._local_expert_ffn(x, w, idx, *deq, jnp.int32(0))
@@ -317,7 +272,6 @@ def test_streamed_kernel_empty_experts_within_chunk():
     their weights are never streamed for that chunk) and trailing
     inactive tiles repeat the last active expert so their weight DMA is
     skipped.  Output still matches the oracle."""
-    from llm_d_tpu.ops import moe as moe_ops
 
     key = jax.random.PRNGKey(29)
     T, chunk_t, E, H, I, k, rt = 32, 16, 16, 256, 128, 2, 16
@@ -333,7 +287,7 @@ def test_streamed_kernel_empty_experts_within_chunk():
     for c in range(T // chunk_t):
         sl = idx.reshape(-1)[c * S_c:(c + 1) * S_c]
         wl = w.reshape(-1)[c * S_c:(c + 1) * S_c]
-        _, _, _, _, _, tile_e, num_tiles = moe_ops._sorted_tile_layout(
+        _, _, _, tile_e, num_tiles = moe_ops._sorted_tile_layout(
             sl, wl, k, E, rt)
         nt = int(num_tiles)
         active = np.asarray(tile_e[:nt])
@@ -363,7 +317,6 @@ def test_streamed_kernel_eplb_physical_layout():
     kernel's test): logical ids map to physical slots, replicas carry
     the same weights, and the chunked physical layout matches the
     logical oracle."""
-    from llm_d_tpu.ops import moe as moe_ops
 
     key = jax.random.PRNGKey(37)
     T, chunk_t, E_log, H, I, k = 40, 16, 4, 256, 128, 2
@@ -400,7 +353,6 @@ def test_streamed_a2a_matches_dequant_a2a(devices, under_jit):
     (expert_ffn_a2a with quant payloads sharded over the expert dim)
     == the bf16 dequant a2a path — the prefill-regime win carries to
     EP without changing the exchange wire layout."""
-    from llm_d_tpu.ops import moe as moe_ops
     from llm_d_tpu.ops.quant import dequantize
     from llm_d_tpu.parallel.mesh import MeshConfig, make_mesh
 
@@ -422,81 +374,62 @@ def test_streamed_a2a_matches_dequant_a2a(devices, under_jit):
                                atol=1e-2)
 
 
-def _record_dispatch(monkeypatch):
-    from llm_d_tpu.ops import moe as moe_ops
+# Selectors that PR 44 deleted: what they held must no longer reach the
+# choice (each value would have moved it).
+_DELETED_SELECTORS = {
+    "LLMD_MOE_DENSE_KERNEL_MAX_T": "4",
+    "LLMD_MOE_GROUPED_MIN_T": "100",
+    "LLMD_MOE_PREFILL_KERNEL": "grouped",
+    "LLMD_MOE_DENSE_MAX_T": "1",
+    "LLMD_MOE_ROUTED_ROW_TILE": "8",
+    "LLMD_MOE_PREFILL_CHUNK_T": "128",
+}
+
+
+@pytest.fixture
+def kernel_calls(monkeypatch):
+    """The three int8 kernels replaced by recorders of (kernel, row tile,
+    chunk) under a pretended TPU backend, so that ``expert_ffn`` and the
+    real glue paths run on the CPU; the deleted selectors set."""
+    from llm_d_tpu.ops.pallas import moe_int8, moe_routed, moe_routed_stream
     calls = []
-    for name in ("dense", "routed", "grouped", "streamed"):
-        monkeypatch.setattr(
-            moe_ops, f"_{name}_int8_kernel_path",
-            lambda x, *a, _n=name, **kw: calls.append(_n) or x)
+
+    def recorder(name):
+        def kernel(x, *args, row_tile=None, chunk_t=None, **kw):
+            calls.append((name, row_tile, chunk_t))
+            return jnp.zeros(x.shape, jnp.float32)
+        return kernel
+
+    monkeypatch.setattr(moe_int8, "dense_moe_int8", recorder("dense"))
+    monkeypatch.setattr(moe_routed, "routed_moe_int8", recorder("routed"))
+    monkeypatch.setattr(moe_routed_stream, "streamed_moe_int8",
+                        recorder("streamed"))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for name, value in _DELETED_SELECTORS.items():
+        monkeypatch.setenv(name, value)
     return calls
 
 
-def _dispatch(T):
-    from llm_d_tpu.ops import moe as moe_ops
-    quant = dict(w_gate_q=jnp.zeros((1, 4, 8, 8), jnp.int8))
-    moe_ops.expert_ffn(jnp.ones((T, 8), jnp.bfloat16),
-                       jnp.ones((T, 2), jnp.float32),
-                       jnp.zeros((T, 2), jnp.int32),
-                       None, None, None, quant=quant)
-
-
-def test_int8_kernel_routing_thresholds(monkeypatch):
-    """expert_ffn int8 routing, three regimes: T <= DENSE_INT8_MAX_T ->
-    dense streaming kernel; T <= GROUPED_INT8_MIN_T -> fused-routing
-    routed kernel (decode); larger T -> CHUNK-STREAMED kernel (prefill
-    default; the grouped kernel is the env-selected fallback).  TPU
-    backend only."""
-    from llm_d_tpu.ops import moe as moe_ops
-
-    calls = _record_dispatch(monkeypatch)
-    ts = (moe_ops.DENSE_INT8_MAX_T,          # <= lower bound -> dense
-          moe_ops.DENSE_INT8_MAX_T + 1,      # decode window -> routed
-          moe_ops.GROUPED_INT8_MIN_T,        # window top -> routed
-          moe_ops.GROUPED_INT8_MIN_T + 1)    # above -> streamed
-    for T in ts:
-        _dispatch(T)
-    assert calls == ["dense", "routed", "routed", "streamed"]
-
-
-def test_regime_dispatch_default_sweep(monkeypatch):
-    """The ISSUE-pinned sweep: which of the (re-tuned) paths each T
-    selects under the default crossovers."""
-    calls = _record_dispatch(monkeypatch)
-    for T in (8, 64, 65, 512, 513, 8192):
-        _dispatch(T)
-    assert calls == ["dense", "dense", "routed",
-                     "routed", "streamed", "streamed"]
-
-
-def test_regime_dispatch_env_overrides(monkeypatch):
-    """Crossover env overrides move the windows; the prefill-kernel
-    selector swaps streamed for the grouped fallback."""
-    calls = _record_dispatch(monkeypatch)
-    monkeypatch.setenv("LLMD_MOE_DENSE_KERNEL_MAX_T", "4")
-    monkeypatch.setenv("LLMD_MOE_GROUPED_MIN_T", "100")
-    for T in (8, 64, 65, 512, 513, 8192):
-        _dispatch(T)
-    assert calls == ["routed", "routed", "routed",
-                     "streamed", "streamed", "streamed"]
-    calls.clear()
-    monkeypatch.setenv("LLMD_MOE_PREFILL_KERNEL", "grouped")
-    for T in (100, 512, 8192):   # window top still routed; above ->
-        _dispatch(T)             # the grouped fallback, everywhere
-    assert calls == ["routed", "grouped", "grouped"]
-
-
-def test_regime_dispatch_invalid_env_falls_back(monkeypatch):
-    """Malformed crossover values must degrade to the tuned defaults —
-    not crash the serving path at trace time."""
-    calls = _record_dispatch(monkeypatch)
-    monkeypatch.setenv("LLMD_MOE_DENSE_KERNEL_MAX_T", "banana")
-    monkeypatch.setenv("LLMD_MOE_GROUPED_MIN_T", "")
-    monkeypatch.setenv("LLMD_MOE_PREFILL_KERNEL", "warp-drive")
-    for T in (8, 64, 65, 512, 513, 8192):
-        _dispatch(T)
-    # Defaults: identical to test_regime_dispatch_default_sweep (an
-    # unknown prefill-kernel name means the streamed default).
-    assert calls == ["dense", "dense", "routed",
-                     "routed", "streamed", "streamed"]
+@pytest.mark.parametrize("T,want", [
+    (8, ("dense", None, None)),
+    (moe_ops.DENSE_INT8_MAX_T, ("dense", None, None)),
+    (moe_ops.DENSE_INT8_MAX_T + 1, ("routed", 32, None)),
+    (moe_ops.ROUTED_INT8_MAX_T, ("routed", 64, None)),
+    (moe_ops.ROUTED_INT8_MAX_T + 1,
+     ("streamed", 64, moe_ops.PREFILL_CHUNK_T)),
+    (8192, ("streamed", 64, moe_ops.PREFILL_CHUNK_T)),
+], ids=lambda v: v[0] if isinstance(v, tuple) else f"T{v}")
+def test_int8_kernel_choice_by_token_count(kernel_calls, T, want):
+    """Which int8 kernel serves a step, with what row tile and chunk, is a
+    function of its shapes alone: dense up to DENSE_INT8_MAX_T tokens,
+    routed up to ROUTED_INT8_MAX_T, streamed above; the row tile by the
+    mean rows an expert (T * k against E * 96).  No ``LLMD_MOE_*`` variable
+    but ``LLMD_MOE_DISPATCH`` has a say."""
+    E, H, k = 4, 8, 2
+    quant, _ = _rand_quant(jax.random.PRNGKey(0), E, H, 8)
+    idx = jnp.arange(T * k, dtype=jnp.int32).reshape(T, k) % E
+    out = moe_ops.expert_ffn(jnp.ones((T, H), jnp.bfloat16),
+                             jnp.ones((T, k), jnp.float32), idx,
+                             None, None, None, quant=quant)
+    assert out.shape == (T, H)
+    assert kernel_calls == [want]

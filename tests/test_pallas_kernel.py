@@ -1,8 +1,8 @@
 """Pallas decode-kernel parity vs the jnp reference (interpret mode on CPU).
 
 The kernel under test is the TPU differentiator (FlashInfer role,
-reference: docker/Dockerfile.cuda:57-58); bench.py exercises it on real
-hardware, these tests pin its numerics on CPU via ``interpret=True`` across
+reference: docker/Dockerfile.cuda:57-58); the benchmark's cells run it on
+the chip, these tests pin its numerics on CPU via ``interpret=True`` across
 block sizes, GQA ratios, KV widths on both sides of the 128-lane gate, and
 the stacked-cache layer addressing — plus the fallback gate itself.
 """

@@ -359,7 +359,7 @@ def test_a_stack_without_experts_carries_no_count(preset):
 def test_stub_components_are_gone():
     assert "stub_components" not in {
         f.name for f in EngineConfig.__dataclass_fields__.values()}
-    for path in [REPO / "bench.py", *(REPO / "llm_d_tpu").rglob("*.py")]:
+    for path in (REPO / "llm_d_tpu").rglob("*.py"):
         assert "stub_components" not in path.read_text(), path
 
 

@@ -603,31 +603,3 @@ def test_chaos_spec_decode_resume_zero_stream_breaks(inject=None):
         asyncio.run(asyncio.wait_for(run(), timeout=120))
     finally:
         reset()
-
-
-# ---------------------------------------------------------------------------
-# bench wiring: gated metric + per-K table helpers
-# ---------------------------------------------------------------------------
-
-def test_bench_gate_includes_spec_metric():
-    import bench
-    gate = bench._regression_gate(
-        {}, {},
-        {256: {"decode_tok_s": 123.0, "decode_tok_s_band": [120.0, 125.0]}})
-    assert gate["moe_decode_spec_bs256_best_recorded"] is None
-    assert gate["moe_decode_spec_bs256_recorded"] == 123.0
-    assert gate["moe_decode_spec_bs256_regressed"] is None   # first record
-    # No spec sweep (e.g. --quick): the metric degrades to no-verdict.
-    gate = bench._regression_gate({}, {}, None)
-    assert gate["moe_decode_spec_bs256_delta_pct"] is None
-
-
-@pytest.mark.slow
-def test_bench_spec_accepted_tok_s_on_tiny():
-    import bench
-    out = bench.bench_spec("tiny", 4, 2, 0.7, prompt_len=8,
-                           decode_steps=8)
-    row = out[4]
-    assert row["decode_tok_s"] > 0
-    assert 0 <= row["spec_acceptance_pct"] <= 100
-    assert row["accepted_tokens_per_step"] >= 1.0

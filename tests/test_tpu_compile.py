@@ -371,8 +371,6 @@ CASES = [
     # its 16 MB default scope on the kernel's 18 MB of blocks.
     pytest.param(functools.partial(_moe, "streamed", 32768, E=16, k=1),
                  id="streamed_moe_int8-a2a-EP4-T32768"),
-    pytest.param(functools.partial(_moe, "grouped", 2048),
-                 id="grouped_moe_int8-T2048"),
     # A mixed sliding-window / full stack at 32 / 4 x 128 heads, the window
     # a traced scalar, contexts to 32768 (B = 1024 pages), 8 layers: the
     # sequence and query buckets its long-document cell reaches.
@@ -390,8 +388,7 @@ CASES = [
                  id="flash_prefill-bf16-window-S8-Q128"),
     # The int8 expert kernels a served step program holds at expert width
     # 1024 (128 experts, top-8, 6 MoE layers): dense to 64 tokens, routed
-    # to 512, streamed above (ops/moe.py).  The sorted+padded grouped
-    # kernel, an A/B lever no configuration selects, compiles there too.
+    # to 512, streamed above (ops/moe.py).
     pytest.param(functools.partial(_moe, "dense", 64, I=1024, E=128, Lm=6),
                  id="dense_moe_int8-T64-I1024-E128"),
     pytest.param(functools.partial(_moe, "routed", 128, I=1024, E=128, Lm=6),
@@ -404,9 +401,26 @@ CASES = [
     pytest.param(functools.partial(_moe, "streamed", 2048, I=1024, E=128,
                                    Lm=6),
                  id="streamed_moe_int8-T2048-I1024-E128"),
-    pytest.param(functools.partial(_moe, "grouped", 2048, I=1024, E=128,
-                                   Lm=6),
-                 id="grouped_moe_int8-T2048-I1024-E128"),
+    # ... and at expert width 768 (qwen3-30b-a3b and sdar-30b-a3b: 128
+    # experts, top-8, 8 MoE layers; kanana-2-30b-a3b: top-6), the token
+    # counts that the ledger's breakdown names in their cells.
+    pytest.param(functools.partial(_moe, "dense", 16, I=768, E=128, Lm=8),
+                 id="dense_moe_int8-T16-I768-E128"),
+    pytest.param(functools.partial(_moe, "dense", 64, I=768, E=128, Lm=8),
+                 id="dense_moe_int8-T64-I768-E128"),
+    pytest.param(functools.partial(_moe, "routed", 256, I=768, E=128, Lm=8),
+                 id="routed_moe_int8-T256-I768-E128"),
+    pytest.param(functools.partial(_moe, "routed", 512, I=768, E=128, Lm=8),
+                 id="routed_moe_int8-T512-I768-E128"),
+    pytest.param(functools.partial(_moe, "streamed", 1024, I=768, E=128,
+                                   Lm=8),
+                 id="streamed_moe_int8-T1024-I768-E128"),
+    pytest.param(functools.partial(_moe, "streamed", 2048, I=768, E=128,
+                                   Lm=8),
+                 id="streamed_moe_int8-T2048-I768-E128"),
+    pytest.param(functools.partial(_moe, "routed", 256, I=768, E=128, k=6,
+                                   Lm=8),
+                 id="routed_moe_int8-T256-I768-E128-top6-kanana"),
     # The query tile list at the shapes the benchmark's cells serve (token
     # bucket / sequence bucket): kanana-2-30b-a3b's MLA latent row (320
     # tiles of 4 slots; 192 of 16 for a 2,048-token chunk), trinity-mini's
