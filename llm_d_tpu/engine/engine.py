@@ -120,6 +120,41 @@ def derive_num_blocks(hbm_budget_bytes: int, layout: Dict[str, int],
     return max(hbm_budget_bytes // block_bytes, 2)
 
 
+def window_group_blocks(sliding_window: int, block_size: int,
+                        max_num_seqs: int, max_num_batched_tokens: int) -> int:
+    """Pages of the window group of a cache in groups by layer kind
+    (kv_cache.py), from the engine's limits.  A running sequence holds at
+    most the window's keys before its chunk, the chunk itself and a page
+    of slack at either end (the window and the chunk begin anywhere in a
+    page); every sequence slot gets that, and the group half as much again
+    for the pages under the last window of contexts that finished, which a
+    later prefix hit needs; one more is the trash page."""
+    per_slot = -(-(sliding_window - 1 + max_num_batched_tokens)
+                 // block_size) + 2
+    return max_num_seqs * per_slot * 3 // 2 + 1
+
+
+def derive_group_blocks(model_config: ModelConfig, block_size: int,
+                        max_num_seqs: int, max_num_batched_tokens: int,
+                        num_blocks: int) -> Tuple[int, int]:
+    """(pages of the full group, pages of the window group) of a stack whose
+    pages may go by layer kind (``ModelConfig.kv_cache_groups``), out of
+    what ``num_blocks`` pages of every layer cost as one pool.  The window
+    group takes what ``window_group_blocks`` says and the full group the
+    rest.  Where the rule's window group would be no smaller than that one
+    pool, grouping frees nothing: ``(num_blocks, 0)``, one group, the
+    stack served as every stack of one kind is."""
+    c = model_config
+    window = window_group_blocks(
+        c.sliding_window, block_size, max_num_seqs, max_num_batched_tokens)
+    if window >= num_blocks:
+        return num_blocks, 0
+    n_window = c.layer_types.count(SLIDING)
+    n_full = c.num_layers - n_window
+    # (in pages of one layer: the pool's, less the window layers')
+    return (num_blocks * c.num_layers - window * n_window) // n_full, window
+
+
 @dataclasses.dataclass
 class EngineConfig:
     model: str = "tiny"                      # preset name
@@ -173,6 +208,9 @@ class EngineConfig:
     # Auto-size the block pool from an HBM budget instead of num_blocks,
     # see derive_num_blocks.
     kv_cache_hbm_bytes: Optional[int] = None
+    # A KV connector will be attached once the engine stands
+    # (--kv-transfer-config): what the engine sizes at construction for it.
+    kv_transfer: bool = False
     # Speculative decoding (MTP draft-and-verify): "auto" runs the fused
     # draft+verify program on pure-decode rounds whenever spec_k > 0;
     # "off" is a kill switch that restores today's engine byte for byte.
@@ -258,6 +296,24 @@ class EngineCore:
                 ", dp=%d)", derived, config.kv_cache_hbm_bytes / 2**30, dp)
             config = dataclasses.replace(config, num_blocks=derived)
             self.config = config
+        # Pages in groups by layer kind (kv_cache.py): ``_window_blocks``
+        # pages of the window group, 0 = one group, every stack's form
+        # until the lines below say otherwise.
+        self._window_blocks = 0
+        self._groups_blocker = self._cache_groups_blocker()
+        if c.kv_cache_groups and not self._groups_blocker:
+            full, self._window_blocks = derive_group_blocks(
+                c, config.block_size, config.max_num_seqs,
+                config.max_num_batched_tokens, config.num_blocks)
+        if self._window_blocks:
+            logger.info(
+                "kv cache in groups by layer kind: %d pages for the %d full "
+                "layers, %d for the %d window layers (the bytes of %d pages "
+                "of every layer)", full, len(c.layers_of(FULL)),
+                self._window_blocks, len(c.layers_of(SLIDING)),
+                config.num_blocks)
+            config = dataclasses.replace(config, num_blocks=full)
+            self.config = config
         if config.async_scheduling and config.num_scheduler_steps <= 1:
             # The pipeline operates on fused decode blocks; without them the
             # flag would be a silent no-op.
@@ -297,7 +353,9 @@ class EngineCore:
             enable_prefix_caching=(config.enable_prefix_caching
                                    and not self._has_state),
             num_regions=self.dp,
-            state_slots=config.max_num_seqs if self._has_state else 0)
+            state_slots=config.max_num_seqs if self._has_state else 0,
+            window_blocks=self._window_blocks,
+            sliding_window=c.sliding_window if self._window_blocks else 0)
         self.scheduler = Scheduler(
             self.kv_manager,
             max_num_seqs=config.max_num_seqs,
@@ -332,6 +390,7 @@ class EngineCore:
         self._check_block_diffusion()
         self._check_recurrent_state()
         self._check_layer_kinds()
+        self._check_cache_groups()
         # llmd-trace: engine phase spans (queue/prefill/decode + step
         # boundaries).  Everything recorded here is host-side clock
         # arithmetic materialized AFTER the jitted dispatch — tracing can
@@ -449,6 +508,14 @@ class EngineCore:
                     device=NamedSharding(self.mesh, P("dp", *specs[name])))
                 for name, width in layout.items()}
         else:
+            if self._window_blocks:
+                # Pages by layer kind: ONE plane, each layer's region after
+                # the last (its kind's group's pages, ``with_layer_tables``),
+                # so one traced layer body serves both kinds.
+                planes = dict.fromkeys(planes, 1)
+                num_slots = config.block_size * sum(
+                    self._window_blocks if t == SLIDING else config.num_blocks
+                    for t in c.layer_types)
             self.kv_cache = {
                 name: jnp.zeros(
                     (planes[name], num_slots, width), jnp.bfloat16,
@@ -496,6 +563,14 @@ class EngineCore:
         self.tokenizer = None
         self._last_evictions = 0
         self._last_preemptions = 0
+        # (a cache in groups: what /metrics has been told, by group; the
+        # series exist from the start, at 0)
+        self._group_evictions: Dict[str, int] = {}
+        self._group_hit_lost: Dict[str, int] = {}
+        if self._window_blocks:
+            for g in self.kv_manager.groups:
+                self.metrics.add_group_evictions(g.name, 0)
+                self.metrics.add_prefix_hit_lost(g.name, 0)
 
         self.host_tier = None
         if config.kv_offload_blocks > 0:
@@ -696,6 +771,33 @@ class EngineCore:
                         f"{feature} requested but unavailable "
                         f"({mechanism}): refusing to start")
 
+    def _cache_groups_blocker(self) -> str:
+        """Why a stack whose pages could go by layer kind is served as ONE
+        group by this engine ('' where nothing asked for stands in the way):
+        only the classic step path (run ahead included) on one shard gives
+        a window's pages back as it passes them, and the host tier and the
+        wire move one group's pages.  Such a stack keeps every feature a
+        stack of one kind has, at one pool's capacity."""
+        cfg = self.config
+        if not self.model_config.kv_cache_groups:
+            return ""
+        asked = [feature for feature, on in (
+            ("multistep", cfg.num_scheduler_steps > 1),
+            ("spec_decode", self._spec_requested()),
+            ("stacked_dp", bool(cfg.mesh) and (cfg.mesh.dp or 1) > 1),
+            ("kv_offload", cfg.kv_offload_blocks > 0),
+            ("kv_transfer", cfg.kv_transfer)) if on]
+        if not asked:
+            return ""
+        return "%s asked for: one group of pages" % ", ".join(asked)
+
+    def _check_cache_groups(self) -> None:
+        """A stack served as one group because of what was asked for says
+        so once, counted (``engine_feature_disabled_total``): its window
+        layers then hold every token, as before PR 46."""
+        if self._groups_blocker:
+            self._disable_feature("cache_groups", self._groups_blocker)
+
     @property
     def _has_state(self) -> bool:
         return self.model_config.has_recurrent_state
@@ -708,6 +810,18 @@ class EngineCore:
     @kv_connector.setter
     def kv_connector(self, connector) -> None:
         c = self.model_config
+        if connector is not None and self._window_blocks:
+            # A pull or a PD hand-over moves the full group's pages; the
+            # window group's would stay behind.  ``EngineConfig.kv_transfer``
+            # (the server sets it from --kv-transfer-config) builds the
+            # engine with one group, which takes a connector.
+            self.metrics.inc_feature_disabled(
+                "kv_transfer", "cache_groups: the wire carries one group's "
+                "pages")
+            raise ValueError(
+                "a KV connector moves one group's pages and this engine was "
+                "built with its cache in groups by layer kind: build it "
+                "with EngineConfig.kv_transfer set")
         if connector is not None and c.mla_by_kind:
             self.metrics.inc_feature_disabled(
                 "kv_transfer", "layer_kinds: the wire carries one row "
@@ -769,9 +883,7 @@ class EngineCore:
         shapes = self.step_shapes()
         t0 = time.monotonic()
         for T, S, Q in shapes:
-            layout = BatchLayout(T, S, Q, self.max_blocks_per_seq,
-                                 dp=self.dp, R=self.block_length or 1,
-                                 state=self._has_state)
+            layout = self._layout(T, S, Q, dp=self.dp)
             packed = jax.device_put(
                 layout.new_buffer(),
                 self._replicated if self.dp == 1 else self._dp_sharded)
@@ -2774,10 +2886,18 @@ class EngineCore:
 
     # ---------- batch building ----------
 
+    def _layout(self, T: int, S: int, Q: int, B: Optional[int] = None,
+                dp: int = 1) -> BatchLayout:
+        """The layout of a classic step's bucket as THIS engine packs it:
+        a block's slots, a state pool's fields, a grouped cache's tables."""
+        return BatchLayout(
+            T, S, Q, self.max_blocks_per_seq if B is None else B, dp=dp,
+            R=self.block_length or 1, state=self._has_state,
+            groups=bool(self._window_blocks))
+
     def _empty_batch_np(self, T: int, S: int, Q: int, B: int) -> Dict[str, np.ndarray]:
         """An empty (all padded) batch: views of one fresh packed buffer."""
-        layout = BatchLayout(T, S, Q, B, R=self.block_length or 1,
-                             state=self._has_state)
+        layout = self._layout(T, S, Q, B)
         return layout.views(layout.new_buffer())
 
     def _fill_batch(self, arrs: Dict[str, np.ndarray], scheduled,
@@ -2840,6 +2960,17 @@ class EngineCore:
             arrs["state_slot"][:S] = [r.state_slot for r in reqs]
             arrs["query_start"][:S] = firsts
             arrs["query_len"][:S] = ns
+        if self._window_blocks:
+            # The window group's table, entry for entry beside the full
+            # group's (0 where the window has passed), and the write slots
+            # in it: a step writes only pages it holds.
+            tables_w = arrs["block_tables_w"]
+            for s, req in enumerate(reqs):
+                tables_w[s, :len(req.window_block_ids)] = req.window_block_ids
+            arrs["slot_mapping_w"][:T] = (
+                tables_w[row, pos // bs] * bs + pos % bs)
+            arrs["kv_group_blocks"][:] = (self.config.num_blocks,
+                                          self._window_blocks)
 
     def _fill_block_batch(self, arrs: Dict[str, np.ndarray],
                           scheduled) -> None:
@@ -2927,8 +3058,7 @@ class EngineCore:
                              cfg.max_num_batched_tokens)
             S = _next_bucket(S_real, min(cfg.min_seq_bucket, cfg.max_num_seqs),
                              cfg.max_num_seqs)
-            layout = BatchLayout(T, S, Q, B, R=self.block_length or 1,
-                                 state=self._has_state)
+            layout = self._layout(T, S, Q, B)
             buf = layout.new_buffer()
             views = layout.views(buf)
             (self._fill_block_batch if self.block_length
@@ -2969,7 +3099,19 @@ class EngineCore:
         packed = jax.device_put(
             buf, self._replicated if self.dp == 1 else self._dp_sharded)
         self._clock.count("h2d_copies")
-        self._step_kv = self._kv_counts(ends, news)
+        if self._window_blocks:
+            kvm = self.kv_manager
+            self._step_kv = self._kv_counts(
+                ends, news, held_from=cfg.block_size * np.fromiter(
+                    (sr.request.window_first_block for sr in scheduled),
+                    np.int64, len(scheduled)))
+            self._step_kv.update(
+                kv_pages_full=kvm.groups[0].pages_held,
+                kv_pages_full_total=kvm.groups[0].num_blocks - 1,
+                kv_pages_window=kvm.groups[1].pages_held,
+                kv_pages_window_total=kvm.groups[1].num_blocks - 1)
+        else:
+            self._step_kv = self._kv_counts(ends, news)
         if self._has_state:
             self._step_kv.update(self._state_counts(ends, news))
         if self.model_config.mixer_by_layer:
@@ -2988,10 +3130,13 @@ class EngineCore:
 
     # ---------- step ----------
 
-    def _kv_counts(self, ends, news) -> Dict[str, int]:
+    def _kv_counts(self, ends, news, held_from=None) -> Dict[str, int]:
         """What a dispatch asks of the KV cache (step_clock.py): ``news[r]``
         tokens of row r computed, one after the other or as one causal
-        chunk (the same keys either way), ending at context ``ends[r]``."""
+        chunk (the same keys either way), ending at context ``ends[r]``.
+        ``held_from[r]``: the first token of row r whose page the window
+        layers still hold (a cache in groups by layer kind gives the pages
+        before it back); None: every layer holds every token."""
         c = self.model_config
         ends = np.asarray(ends, np.int64)
         news = np.minimum(np.asarray(news, np.int64), ends)
@@ -3026,8 +3171,10 @@ class EngineCore:
             w = c.sliding_window
             windowed = int((upto(ends, w) - upto(ends - news, w)).sum())
             counts["kv_read_tokens"] -= n_window * (full - windowed)
+            held = ends if held_from is None else ends - held_from
+            counts["kv_held_tokens"] -= n_window * int((ends - held).sum())
             counts["kv_dead_tokens"] = n_window * int(
-                np.maximum(ends - w + 1, 0).sum())
+                np.maximum(held - w + 1, 0).sum())
         if c.index_topk:
             # Full layers score every visible key (index_pairs) and attend
             # to the index_topk best of them (kv_selected_tokens).
@@ -3400,7 +3547,7 @@ class EngineCore:
                       budget)
             need += max(-(-(req.num_computed_tokens + ask) // bs)
                         - len(req.block_ids), 0)
-        return need <= self.kv_manager.num_free_blocks
+        return self.kv_manager.has_room(need)
 
     def _launch(self, sched: SchedulerOutput, ahead: bool) -> _LaunchedStep:
         """Build, copy and launch one classic step, and advance its rows as
@@ -3445,11 +3592,16 @@ class EngineCore:
         if touched is not None:
             fetch["touched"] = touched
         samples: List[Tuple[bool, bool, bool]] = []
+        released = 0
         if not self.block_length:
             bs = self.config.block_size
             for i, sr in enumerate(scheduled):
                 req, before = sr.request, sr.request.num_computed_tokens
                 req.num_computed_tokens += sr.num_new_tokens
+                if self._window_blocks:
+                    # The step is launched: the pages its row's window has
+                    # passed go back (kv_cache.py, ``release_passed``).
+                    released += self.kv_manager.release_passed(req)
                 sampled = req.num_computed_tokens == req.num_tokens
                 samples.append((
                     sampled,
@@ -3458,6 +3610,9 @@ class EngineCore:
                     req.num_computed_tokens // bs > before // bs))
                 if sampled:
                     req.inflight_token_ids.append(-1 - int(rows[i]))
+        if self._window_blocks:
+            self._step_kv["kv_window_pages_released"] = released
+            self.metrics.kv_window_pages_released.inc(released)
         return _LaunchedStep(
             sched, scheduled, rows, samples, fetch, routed,
             self._routed_valid, self._step_kv, t0, ahead)
@@ -3780,6 +3935,18 @@ class EngineCore:
         if self._has_state:
             self.metrics.ssm_state_slots_in_use.set(
                 self.kv_manager.state_slots_in_use)
+        if self._window_blocks:
+            kvm = self.kv_manager
+            for g in kvm.groups:
+                self.metrics.set_group_pages(g.name, g.pages_held)
+                for seen, now, add in (
+                        (self._group_evictions, g.eviction_count,
+                         self.metrics.add_group_evictions),
+                        (self._group_hit_lost, kvm.hit_tokens_lost[g.name],
+                         self.metrics.add_prefix_hit_lost)):
+                    if now > seen.get(g.name, 0):
+                        add(g.name, now - seen.get(g.name, 0))
+                        seen[g.name] = now
         if self.kv_manager.eviction_count > self._last_evictions:
             self.metrics.kv_cache_evictions.inc(
                 self.kv_manager.eviction_count - self._last_evictions)

@@ -18,7 +18,11 @@ every slot of every row's block: ``sample_idx`` grows to ``S * R`` entries
 and two arrays join the buffer, which slots are masked and how many the pass
 reveals a row.  ``R`` = 1 is the autoregressive layout, field for field.
 A stack with recurrent state beside its pages (``state``) adds three arrays
-a row; without it the layout is the one above, field for field.
+a row; without it the layout is the one above, field for field.  A cache in
+groups by layer kind (``groups``, kv_cache.py) adds the window group's block
+table and write slots beside the full group's, and the two groups' page
+counts (where each layer's region of the one buffer starts follows from
+them): still the one copy.
 
 Tokens fed on the device (``feed_tokens``): a ``token_ids`` entry
 ``-(row + 1)`` names the token the PREVIOUS step sampled for its row
@@ -47,7 +51,7 @@ _I32, _F32 = np.dtype(np.int32), np.dtype(np.float32)
 
 
 def _fields(T: int, S: int, Q: int, B: int, R: int = 1,
-            state: bool = False):
+            state: bool = False, groups: bool = False):
     """(name, shape, dtype, value of a padded slot) of the step's batch."""
     fields = (
         ("token_ids", (T,), _I32, 0),
@@ -76,16 +80,22 @@ def _fields(T: int, S: int, Q: int, B: int, R: int = 1,
             ("query_start", (S,), _I32, 0),       # the row's first token
             ("query_len", (S,), _I32, 0),         # tokens of its chunk
         )
+    if groups:
+        fields += (
+            ("block_tables_w", (S, B), _I32, 0),  # 0 = trash: passed, unheld
+            ("slot_mapping_w", (T,), _I32, 0),
+            ("kv_group_blocks", (2,), _I32, 0),   # pages: full, window group
+        )
     return fields
 
 
 @functools.lru_cache(maxsize=None)
 def _slots(T: int, S: int, Q: int, B: int, R: int = 1,
-           state: bool = False):
+           state: bool = False, groups: bool = False):
     """((name, start, stop, shape, dtype), ...), the buffer's length, and
     ((start, stop, int32 bit pattern), ...) of the defaults that are not 0."""
     slots, fills, at = [], [], 0
-    for name, shape, dtype, pad in _fields(T, S, Q, B, R, state):
+    for name, shape, dtype, pad in _fields(T, S, Q, B, R, state, groups):
         stop = at + math.prod(shape)
         slots.append((name, at, stop, shape, dtype))
         bits = int(np.array(pad, dtype).view(np.int32))
@@ -102,7 +112,8 @@ class BatchLayout:
     ``R`` sampled slots a sequence (a block-diffusion model's block);
     ``state``: the rows also name their slot of the recurrent-state pool
     and their chunk's place in the batch (a stack with a state-space
-    mixer, ops/ssm.py)."""
+    mixer, ops/ssm.py); ``groups``: the pages go by layer kind, and the
+    batch brings the window group's table and write slots too."""
     T: int
     S: int
     Q: int
@@ -110,9 +121,11 @@ class BatchLayout:
     dp: int = 1
     R: int = 1
     state: bool = False
+    groups: bool = False
 
     def _slots(self):
-        return _slots(self.T, self.S, self.Q, self.B, self.R, self.state)
+        return _slots(self.T, self.S, self.Q, self.B, self.R, self.state,
+                      self.groups)
 
     @property
     def shape(self) -> Tuple[int, ...]:

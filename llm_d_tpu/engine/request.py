@@ -137,6 +137,13 @@ class Request:
     # A stack with recurrent layers: the request's slot of the engine's
     # state pool, held while it holds pages (``KVCacheManager``); 0 = none.
     state_slot: int = 0
+    # A cache in groups by layer kind (``KVCacheManager``): the request's
+    # pages of the window group, entry for entry beside ``block_ids`` (which
+    # are the full group's); an entry the window has passed, or a prefix
+    # hit never took, is 0, the trash page.  ``window_first_block``: the
+    # first entry that may still hold a page.
+    window_block_ids: List[int] = dataclasses.field(default_factory=list)
+    window_first_block: int = 0
 
     def reset_block(self) -> None:
         self.revealed_ahead = {}

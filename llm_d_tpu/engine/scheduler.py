@@ -240,9 +240,8 @@ class Scheduler:
             # Nothing to preempt: shrink the chunk to the blocks that are
             # actually free so mid-prefill requests keep making progress
             # (partial pools must not stall the pass).
-            fit = ((len(req.block_ids) + self.kv.region_free_blocks(
-                self.kv.region_of_request(req)))
-                * self.kv.block_size) - req.num_computed_tokens
+            fit = ((len(req.block_ids) + self.kv.free_blocks_for(req))
+                   * self.kv.block_size) - req.num_computed_tokens
             if fit >= n:
                 # Bookkeeping race (free-list vs region accounting, e.g.
                 # blocks parked in the evictor): the pool claims ``n``

@@ -33,8 +33,23 @@ classic path also hands them to its ``llmd.dispatch`` annotation):
   kv_read_tokens  the same with each layer's window applied (equal to
                   ``kv_ctx_tokens`` for a model without a window)
   kv_held_tokens  sum over rows and layers of the tokens the cache holds
+                  NOW: every token in every layer of a one-group cache; of
+                  a cache in groups by layer kind (kv_cache.py) the window
+                  layers count from the first page the row still holds
   kv_dead_tokens  sum over rows and window layers of those no later query
-                  of the row can see: what a pool per layer kind would free
+                  of the row can see: what a pool per layer kind frees (in
+                  a grouped cache what is left of it: the chunk being
+                  computed and the page the window begins in)
+
+and, for a cache in groups by layer kind (the classic path; the page counts
+also on its ``llmd.dispatch`` annotation, as the step is composed):
+
+  kv_pages_full, kv_pages_window    pages of the group that a running
+                  sequence references or that are kept for a later hit
+  kv_pages_full_total, kv_pages_window_total    the group's pages (its
+                  trash page apart)
+  kv_window_pages_released    window pages the step's rows gave back once
+                  it was launched (the span only)
 
 and, for a stack whose full layers select their keys
 (``ModelConfig.index_topk``; ``kv_read_tokens`` then counts what is

@@ -34,6 +34,22 @@ DIFFUSION_REMASKING = ("sequential", "low_confidence_static",
                        "low_confidence_dynamic")
 
 
+class RopeRule(NamedTuple):
+    """The rotary tables of one layer kind (``ModelConfig.rope_rules``).
+    ``factor`` 0: plain tables of ``theta``.  Otherwise YaRN as published:
+    the inverse frequencies theta^(-2i/d) are blended with the same over
+    ``factor`` by a linear ramp between the correction dimensions of
+    ``beta_fast`` and ``beta_slow`` rotations over ``original_max`` positions
+    (dimensions that turn often keep theirs, slow ones are interpolated),
+    and cos and sin are multiplied by ``attention_factor``."""
+    theta: float
+    factor: float = 0.0
+    original_max: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
 class MLAGeometry(NamedTuple):
     """Latent attention as one layer kind runs it (``mla_geometry``)."""
     num_heads: int
@@ -98,6 +114,13 @@ class ModelConfig:
     sliding_window: int = 0
     # False: FULL layers carry no rotary embedding (SLIDING ones always do).
     rope_on_full_attention: bool = True
+    # The rotary rule by layer kind, as a published ``rope_parameters``
+    # gives it: {kind: {"rope_type": "default" | "yarn", "rope_theta", and
+    # for yarn "factor", "original_max_position_embeddings", "beta_fast",
+    # "beta_slow", "attention_factor"}} (kept as a tuple of items, so the
+    # config stays hashable).  Empty: ``rope_theta`` plain on every layer
+    # that rotates, the tables every other stack has always had.
+    rope_parameters: Tuple = ()
     # sigmoid(h W_gate) multiplies the attention output before o_proj.
     attn_output_gate: bool = False
     # RMS norms on the attention and MLP outputs too, before the residual.
@@ -245,6 +268,33 @@ class ModelConfig:
             self.rope_theta, 0, self.index_topk)
 
     @property
+    def rope_rules(self) -> Tuple[RopeRule, ...]:
+        """The distinct rotary rules of the stack's layer kinds, in the
+        order of ``rope_parameters``; empty where one plain table serves."""
+        return tuple(dict.fromkeys(rule for _, rule in self.rope_parameters))
+
+    @property
+    def layer_rope_rule(self) -> Tuple[int, ...]:
+        """Per layer, its kind's place in ``rope_rules``."""
+        rules, by_kind = self.rope_rules, dict(self.rope_parameters)
+        return tuple(rules.index(by_kind[t]) for t in self.layer_types)
+
+    @property
+    def kv_cache_groups(self) -> Tuple[str, ...]:
+        """The layer kinds whose pages the engine MAY manage as groups of
+        their own (engine/kv_cache.py): a GQA stack of window and full
+        layers holds a window of keys for the former and the whole context
+        for the latter, where the engine's limits make that smaller than
+        one pool (``engine.derive_group_blocks``).  Empty: one group, every layer holds every token
+        (one kind of layer; or cache buffers that go by kind already, a
+        state pool beside the pages, blocks of queries: ROADMAP B10)."""
+        if (self.use_mla or self.mixer_by_layer or self.has_recurrent_state
+                or self.diffusion_block_length
+                or set(self.layer_types) != {FULL, SLIDING}):
+            return ()
+        return (FULL, SLIDING)
+
+    @property
     def has_recurrent_state(self) -> bool:
         """A sequence's state is not keys and values alone."""
         return self.ssm_state_size > 0
@@ -292,6 +342,8 @@ class ModelConfig:
         object.__setattr__(self, "rope_theta", float(self.rope_theta))
         object.__setattr__(self, "swa_rope_theta", float(self.swa_rope_theta))
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        object.__setattr__(self, "rope_parameters",
+                           self._rope_parameters(self.rope_parameters))
         kinds = self.layer_types
         if kinds:
             if len(kinds) != self.num_layers or set(kinds) - set(LAYER_KINDS):
@@ -394,6 +446,41 @@ class ModelConfig:
                 raise ValueError(
                     f"mask_token_id {self.mask_token_id} is outside the "
                     f"vocabulary of {self.vocab_size}")
+
+    def _rope_parameters(self, given) -> Tuple:
+        """``rope_parameters`` as ((kind, RopeRule), ...), from the
+        published mapping by kind or from that form itself."""
+        out = []
+        for kind, rule in (given.items() if isinstance(given, dict)
+                           else given):
+            if isinstance(rule, dict):
+                kind_of = rule.get("rope_type", "default")
+                if kind_of not in ("default", "yarn"):
+                    raise ValueError(
+                        f"rope_parameters[{kind!r}]: rope_type {kind_of!r} "
+                        f"is not served ('default' and 'yarn' are)")
+                theta = float(rule.get("rope_theta", self.rope_theta))
+                if kind_of == "default":
+                    rule = RopeRule(theta)
+                else:
+                    factor = float(rule["factor"])
+                    rule = RopeRule(
+                        theta, factor,
+                        int(rule["original_max_position_embeddings"]),
+                        float(rule.get("beta_fast") or 32.0),
+                        float(rule.get("beta_slow") or 1.0),
+                        float(rule.get("attention_factor")
+                              or 0.1 * math.log(factor) + 1.0))
+            out.append((kind, rule))
+        if out and (self.use_mla or self.mixer_by_layer
+                    or not self.rope_on_full_attention
+                    or set(self.layer_types) - {k for k, _ in out}
+                    or not self.layer_types):
+            raise ValueError(
+                "rope_parameters gives the GQA attention block a rotary "
+                "rule for every kind of layer_types; MLA, the hybrid "
+                "decoder and rope_on_full_attention=False have their own")
+        return tuple(out)
 
     def _check_hybrid_decoder(self) -> None:
         """The one form of a stack with mixers by layer that
@@ -636,6 +723,23 @@ PRESETS = {
         layer_types=(SLIDING, SLIDING, SLIDING, FULL) * 2, sliding_window=48,
         rope_on_full_attention=False, attn_output_gate=True,
         sandwich_norm=True, embed_scale=8.0),
+    # Tiny window / full GQA MoE for CPU tests: mellum2-12b-a2.5b's
+    # mechanisms (every layer sparse, softmax top-2 of 8 renormalised, no
+    # shared expert, per-head q/k norm, YaRN on the full layers only over
+    # an original 64 positions, so that the tests' prompts pass it), a
+    # window that is no multiple of the block size.  Its pages go by layer
+    # kind (``kv_cache_groups``).
+    "tiny-mellum": ModelConfig(
+        name="tiny-mellum", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_layers=8, num_heads=4, num_kv_heads=2,
+        head_dim=16, rms_norm_eps=1e-6, max_model_len=512, qk_norm=True,
+        num_experts=8, num_experts_per_tok=2, moe_intermediate_size=96,
+        layer_types=(SLIDING, SLIDING, SLIDING, FULL) * 2, sliding_window=48,
+        rope_parameters={
+            FULL: {"rope_type": "yarn", "rope_theta": 10000.0, "factor": 4.0,
+                   "original_max_position_embeddings": 64, "beta_fast": 32,
+                   "beta_slow": 1, "attention_factor": 1.1386294361119891},
+            SLIDING: {"rope_type": "default", "rope_theta": 10000.0}}),
     # Tiny block-diffusion MoE for CPU tests: sdar-30b-a3b's mechanisms
     # (per-head q/k norm, softmax top-2 of 8, no shared expert, blocks of 4).
     "tiny-sdar": ModelConfig(

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from llm_d_tpu.models.config import ModelConfig
+from llm_d_tpu.models.config import NO_WINDOW, SLIDING, ModelConfig
 from llm_d_tpu.ops import layers as L
 from llm_d_tpu.ops.attention import (
     attention_with_kv_update, with_block_visibility, with_query_tiles)
@@ -78,6 +78,32 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
     return params
 
 
+def with_layer_tables(batch, config: ModelConfig):
+    """Once a step program, outside the layer scan, what a layer looks up
+    by its traced index: the rotary tables of each rule of a stack whose
+    rotary rule goes by layer kind (``rope_tables``: cos and sin
+    [R, T, D/2]), and for a cache in groups by layer kind each layer's
+    first page in the one buffer (``layer_page0`` [L]: layer l's region
+    holds the pages of its kind's group, ``kv_group_blocks`` of them, after
+    those of the layers before it).  Whether a window stack's pages go by
+    groups is the ENGINE's answer, from its limits and what it was asked
+    for (``engine.derive_group_blocks``), so the config alone cannot say:
+    the engine's static ``BatchLayout.groups`` is what brings
+    ``block_tables_w``, and with it this form of the program.  A batch and
+    a config with neither come back as they are."""
+    c = config
+    if c.rope_rules:
+        with part("attn.proj"):
+            batch = dict(batch, rope_tables=L.rope_tables(
+                batch["positions"], c.head_dim_, c.rope_rules))
+    if "block_tables_w" in batch:
+        with part("tiles"):
+            pages = batch["kv_group_blocks"][jnp.asarray(
+                [t == SLIDING for t in c.layer_types], jnp.int32)]
+            batch = dict(batch, layer_page0=jnp.cumsum(pages) - pages)
+    return batch
+
+
 def attention_block(
     lp: Params, config: ModelConfig, x: jax.Array, batch: Dict[str, jax.Array],
     caches: Tuple[jax.Array, jax.Array], block_size: int, attn_backend: str,
@@ -109,10 +135,34 @@ def attention_block(
         if c.key_multiplier != 1.0:
             kx = (kx * c.key_multiplier).astype(kx.dtype)
 
-        cos, sin = L.rope_cos_sin(batch["positions"], dh, c.rope_theta)
+        if c.rope_rules:
+            # The rule of the layer's kind (YaRN on some): the tables are
+            # the step program's (``with_layer_tables``).
+            tables = batch.get("rope_tables")
+            if tables is None:
+                tables = L.rope_tables(batch["positions"], dh, c.rope_rules)
+            cos, sin = (t[jnp.asarray(c.layer_rope_rule, jnp.int32)[layer]]
+                        for t in tables)
+        else:
+            cos, sin = L.rope_cos_sin(batch["positions"], dh, c.rope_theta)
         window = None
         if c.layer_types:
             window = jnp.asarray(c.layer_windows, jnp.int32)[layer]
+        plane = layer       # of the stacked cache: the layer's own
+        if "block_tables_w" in batch:
+            # Pages in groups by layer kind (engine/kv_cache.py): the table
+            # and the write slots of the layer's group, in the layer's own
+            # region of the one buffer, which has one plane.
+            own = window < NO_WINDOW        # a window layer
+            page0 = batch["layer_page0"][layer]
+            batch = dict(
+                batch,
+                block_tables=jnp.where(own, batch["block_tables_w"],
+                                       batch["block_tables"]) + page0,
+                slot_mapping=jnp.where(own, batch["slot_mapping_w"],
+                                       batch["slot_mapping"])
+                + page0 * block_size)
+            plane = jnp.zeros_like(layer)
         if all(c.layer_rope):
             q = L.apply_rope(q, cos, sin)
             kx = L.apply_rope(kx, cos, sin)
@@ -124,7 +174,7 @@ def attention_block(
     with part(attn_part(batch)):
         attn, *new_caches = attention_with_kv_update(
             q, kx, vx, caches[0], caches[1], batch,
-            block_size=block_size, backend=attn_backend, layer=layer,
+            block_size=block_size, backend=attn_backend, layer=plane,
             mesh=mesh, window=window)
     with part("attn.proj"):
         attn = attn.reshape(T, c.num_heads * dh)
@@ -224,6 +274,7 @@ def forward(
         batch = with_query_tiles(
             with_block_visibility(batch, c.diffusion_block_length),
             c.num_heads, caches0[0].shape[-1], attn_backend, mesh)
+    batch = with_layer_tables(batch, c)
 
     # The FULL stacked KV cache rides the scan carry and each layer updates
     # its plane in place (Pallas aliasing / scatter-at-layer): slicing the

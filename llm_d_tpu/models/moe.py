@@ -34,7 +34,8 @@ from llm_d_tpu.models.llama import (  # noqa: F401  (re-exports: the MoE
     # drafter reads only embed/lm_head from the target params, which both
     # families carry identically)
     attention_block, compute_logits, dense_layer, draft_propose,
-    embed_tokens, init_draft_params, mlp_out, sampled_hidden)
+    embed_tokens, init_draft_params, mlp_out, sampled_hidden,
+    with_layer_tables)
 from llm_d_tpu.ops import layers as L
 from llm_d_tpu.ops import moe as moe_ops
 from llm_d_tpu.ops.attention import (
@@ -279,6 +280,8 @@ def forward(
             # names row T.
             T = batch["token_ids"].shape[-1]
             real = jnp.arange(T) < jnp.sum(batch["qtok_idx"] < T)
+    if not c.use_mla:
+        batches[FULL] = with_layer_tables(batches[FULL], c)
     # DBO threshold by phase: the program's query width is static under jit,
     # and Q == 1 holds exactly for pure-decode programs (single-step or
     # fused).  None (no opts) lets the op consult its standalone env vars;

@@ -36,6 +36,54 @@ def rope_cos_sin(
     return jnp.cos(freqs), jnp.sin(freqs)
 
 
+def yarn_inv_freq(head_dim: int, theta: float, factor: float,
+                  original_max: int, beta_fast: float, beta_slow: float):
+    """YaRN's inverse frequencies [D/2] (numpy, float64: constants of the
+    step program).  Dimension i turns ``original_max / (2 pi theta^(2i/d))``
+    times over the original context; one that turns ``beta_fast`` times or
+    more keeps theta^(-2i/d), one that turns ``beta_slow`` times or fewer
+    takes it over ``factor``, and between the two correction dimensions
+    (floor and ceiling, held inside 0 .. d/2 - 1) the blend is linear."""
+    import math
+
+    import numpy as np
+
+    def correction_dim(rotations: float) -> float:
+        return (head_dim * math.log(original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001           # the published guard against a 0 / 0
+    pos_freqs = theta ** (np.arange(0, head_dim, 2, dtype=np.float64)
+                          / head_dim)
+    ramp = np.clip((np.arange(head_dim // 2) - low) / (high - low), 0, 1)
+    return (1.0 / (factor * pos_freqs)) * ramp + (1.0 / pos_freqs) * (1 - ramp)
+
+
+def rope_tables(positions: jax.Array, head_dim: int, rules
+                ) -> Tuple[jax.Array, jax.Array]:
+    """cos and sin [R, T, D/2] of each of the ``rules``
+    (``models.config.RopeRule``), computed once a step program; a layer
+    takes the pair of its kind by its traced index."""
+    pos = positions.astype(jnp.float32)
+    cos, sin = [], []
+    for rule in rules:
+        if not rule.factor:
+            c, s = rope_cos_sin(positions, head_dim, rule.theta)
+        else:
+            inv_freq = jnp.asarray(yarn_inv_freq(
+                head_dim, rule.theta, rule.factor, rule.original_max,
+                rule.beta_fast, rule.beta_slow), jnp.float32)
+            freqs = pos[:, None] * inv_freq[None, :]
+            c = jnp.cos(freqs) * rule.attention_factor
+            s = jnp.sin(freqs) * rule.attention_factor
+        cos.append(c)
+        sin.append(s)
+    return jnp.stack(cos), jnp.stack(sin)
+
+
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     """Rotate pairs (HF 'half-rotation' convention). x: [T, H, D]."""
     d2 = x.shape[-1] // 2

@@ -1151,6 +1151,7 @@ def engine_config_from_args(args) -> EngineConfig:
         num_scheduler_steps=args.num_scheduler_steps,
         async_scheduling=args.async_scheduling,
         kv_offload_blocks=args.kv_offload_blocks,
+        kv_transfer=bool(args.kv_transfer_config),
         kv_shared_tier_port=args.kv_shared_tier_port,
         kv_shared_tier_peers=tuple(
             s.strip() for s in args.kv_shared_tier_peers.split(",")

@@ -84,6 +84,15 @@ FEATURE_DISABLED_METRIC = "llmd_tpu:engine_feature_disabled_total"
 # under fused multistep, ~1 on the classic per-step path — the
 # dashboard proof that host round-trips per decoded token dropped.
 ENGINE_DISPATCH_METRIC = "llmd_tpu:engine_dispatch_total"
+# A paged cache in groups by layer kind (engine/kv_cache.py): pages a group
+# holds (referenced or kept for a later hit), window pages given back as the
+# window passed them, cached blocks a group's LRU gave up, and the tokens of
+# prefix hits one group could grant and the other had evicted, by the group
+# that lost them.
+KV_GROUP_PAGES_METRIC = "llmd_tpu:kv_group_pages_in_use"
+KV_WINDOW_RELEASED_METRIC = "llmd_tpu:kv_window_pages_released_total"
+KV_GROUP_EVICTIONS_METRIC = "llmd_tpu:kv_group_evictions_total"
+PREFIX_HIT_LOST_METRIC = "llmd_tpu:prefix_cache_hit_tokens_lost_total"
 ENGINE_STEP_METRIC = "llmd_tpu:engine_steps_total"
 # The classic step path one step ahead (engine.py module docstring): steps
 # composed and launched while their predecessor was still on the device,
@@ -290,6 +299,24 @@ class EngineMetrics:
             "Requested features demoted, at startup or per request, by "
             "feature and blocker.",
             ["model_name", "feature", "blocker"], registry=self.registry)
+        self._kv_group_pages = Gauge(
+            KV_GROUP_PAGES_METRIC,
+            "Pages a cache group holds: referenced by a running sequence or "
+            "kept for a later prefix hit.",
+            ["model_name", "group"], registry=self.registry)
+        self.kv_window_pages_released = counter(
+            KV_WINDOW_RELEASED_METRIC,
+            "Window-group pages given back because no later query of "
+            "their sequence can see them.")
+        self._kv_group_evictions = Counter(
+            KV_GROUP_EVICTIONS_METRIC,
+            "Cached KV blocks evicted (LRU), by cache group.",
+            ["model_name", "group"], registry=self.registry)
+        self._prefix_hit_lost = Counter(
+            PREFIX_HIT_LOST_METRIC,
+            "Tokens of prefix-cache hits that one cache group could grant "
+            "and the other had evicted, by the group that lost them.",
+            ["model_name", "group"], registry=self.registry)
         self.engine_dispatches = counter(
             ENGINE_DISPATCH_METRIC,
             "Compiled-program dispatches (one host fetch each); "
@@ -354,6 +381,18 @@ class EngineMetrics:
         if n:
             self._diffusion_passes.labels(
                 model_name=self.model_name, kind=kind).inc(n)
+
+    def set_group_pages(self, group: str, pages: int) -> None:
+        self._kv_group_pages.labels(
+            model_name=self.model_name, group=group).set(pages)
+
+    def add_group_evictions(self, group: str, n: int) -> None:
+        self._kv_group_evictions.labels(
+            model_name=self.model_name, group=group).inc(n)
+
+    def add_prefix_hit_lost(self, group: str, tokens: int) -> None:
+        self._prefix_hit_lost.labels(
+            model_name=self.model_name, group=group).inc(tokens)
 
     def inc_feature_disabled(self, feature: str, blocker: str) -> None:
         self._feature_disabled.labels(

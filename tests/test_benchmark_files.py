@@ -476,7 +476,8 @@ def test_dots3_cell_and_its_files():
     assert names[at + len(DOTS_METRICS):] == [
         "attn_window_key_fill_share"] + PHI4_METRICS + [
         "moe_held_hbm_share",                       # PRs 40, 41, 42, 43,
-        "dsa_index_key_fill_share"]                 # appended
+        "dsa_index_key_fill_share",                 # 46 appended
+        *MELLUM_METRICS]
     held = json.loads((BENCH / "layer_metrics"
                        / "moe_held_hbm_share.json").read_text())
     assert per_layer["moe_held_hbm_share"]["workloads"] == ["dots3.longdoc"]
@@ -628,11 +629,11 @@ def _phi4_conf():
 
 def test_phi4flash_cell_and_its_files():
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    cell = bench["workloads"][-1]
+    cell = bench["workloads"][-2]
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         "phi4flash.longdoc", "phi4-mini-flash", "longdoc-8", 1)
     assert len(cell["why"]) <= 200
-    entry = bench["configs"][-1]
+    entry = bench["configs"][-2]
     conf = _phi4_conf()
     assert entry["name"] == conf["name"] == "phi4-mini-flash"
     assert entry["reduced"] == conf["reduced"] == []        # nothing is cut
@@ -659,7 +660,8 @@ def test_phi4flash_cell_and_its_files():
     at = names.index(PHI4_METRICS[0])
     assert names[at:at + len(PHI4_METRICS)] == PHI4_METRICS   # in order
     assert names[at + len(PHI4_METRICS):] == [
-        "moe_held_hbm_share", "dsa_index_key_fill_share"]       # PRs 42, 43
+        "moe_held_hbm_share", "dsa_index_key_fill_share",       # PRs 42, 43
+        *MELLUM_METRICS]                                        # PR 46
     for name in PHI4_METRICS:
         assert per_layer[name]["workloads"] == ["phi4flash.longdoc"]
         d = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())
@@ -857,3 +859,320 @@ def test_phi4flash_mechanism_check_names_each_fault():
     assert wide.sum(1).tolist() == [1, 2, 3, 4, 5, 5, 5, 5, 5, 5]
     assert blind.sum(1).tolist() == [1, 1, 1, 1, 1, 1, 7, 7, 7, 7]
     assert same.sum(1).tolist() == list(range(1, 11))
+
+
+# ---- mellum2-12b-a2.5b and mellum2.ide (PR 46) ----
+
+MELLUM_METRICS = [
+    "device_idle_share.mellum2", "step_ms.decode.mellum2",
+    "itl_p95_ms.mellum2", "device_part_share.experts.mellum2",
+    "moe_roofline_share.mellum2", "attn_prefill_mxu_share.mellum2",
+    "attn_decode_hbm_share.mellum2", "attn_kv_read_share.mellum2",
+    "kv_window_dead_share.mellum2", "kv_full_pool_fill_share",
+    "kv_window_pool_fill_share", "prefix_hit_lost_share"]
+
+
+def _mellum_conf():
+    return json.loads(
+        (BENCH / "configs" / "mellum2-12b-a2.5b.json").read_text())
+
+
+def test_mellum_cell_and_its_files():
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    cell = bench["workloads"][-1]
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
+        "mellum2.ide", "mellum2-12b-a2.5b", "ide-sessions", 1)
+    assert len(cell["why"]) <= 200
+    entry = bench["configs"][-1]
+    conf = _mellum_conf()
+    assert entry["name"] == conf["name"] == "mellum2-12b-a2.5b"
+    assert entry["reduced"] == conf["reduced"] == [
+        "num_hidden_layers", "layer_types", "mlp_layer_types"]
+    assert entry["source"] == conf["source"] and len(entry["source"]) < 200
+    assert len(entry["why"]) <= 200
+    assert entry["file"] == "benchmarks/configs/mellum2-12b-a2.5b.json"
+    assert conf["reference"] == conf["model_type"] == "mellum"
+    assert (BENCH / "references" / "mellum.py").exists()
+    assert conf["serve_args"] == [
+        "--quantization", "int8", "--max-num-seqs", "32",
+        "--max-num-batched-tokens", "2048", "--block-size", "32",
+        "--kv-cache-hbm-gb", "6.0", "--precompile-step-shapes"]
+    for key in ("reduced_why", "assumed", "deployment"):
+        assert conf[key], key
+    chk = conf["correctness"]
+    assert chk["prompt_lens"] == [24, 700, 2300, 6200]
+    assert chk["prompt_lens"][1] < conf["sliding_window"] < 2048 \
+        < chk["prompt_lens"][2]
+    assert (chk["n_gen"], chk["ks"], chk["moe_op_sizes"]) == (
+        24, [0, 4, 12], [16, 256, 2048])
+    reh = conf["rehearsal"]
+    assert reh["sizes"]["sliding_window"] == 48 < max(
+        reh["correctness"]["prompt_lens"])
+    assert reh["sizes"]["sliding_window"] % 32        # no multiple of a page
+    per_layer = {m["name"]: m for m in bench["per_layer"]}
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names[-len(MELLUM_METRICS):] == MELLUM_METRICS     # last, in order
+    for name in MELLUM_METRICS:
+        assert per_layer[name]["workloads"] == ["mellum2.ide"]
+        d = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())
+        assert d["name"] == name
+        assert (BENCH / "readers" / f"{d['reader']}.py").exists()
+        assert {k: d[k] for k in ("unit", "better", "source", "layer",
+                                  "moves")} == {
+            k: per_layer[name][k] for k in ("unit", "better", "source",
+                                            "layer", "moves")}
+        if "config" in d["args"]:
+            assert d["args"]["config"] == "mellum2-12b-a2.5b"
+    # the accepted lists are as the parent has them
+    assert per_layer["kv_window_dead_share"]["workloads"] == [
+        "trinity-mini.docqa"]
+    assert per_layer["moe_roofline_share.docqa"]["workloads"] == [
+        "trinity-mini.docqa"]
+    assert len(bench["workloads"]) == 8 and all(
+        w["chips"] == 1 for w in bench["workloads"])
+
+
+def test_mellum_mix_is_as_the_issue_wrote_it():
+    mix = json.loads((BENCH / "traffic" / "ide-sessions.json").read_text())
+    assert (mix["loop"], mix["arrivals"]["kind"]) == ("open", "poisson")
+    assert mix["sessions"] == {
+        "asks": 10, "ask_gap_s": 5.0, "prefix_tokens": {
+            "dist": "loguniform", "min": 8192, "max": 32768}}
+    assert mix["prompt_tokens"] == {"dist": "uniform", "min": 64, "max": 512}
+    assert mix["output_tokens"] == {"dist": "lognormal", "median": 48,
+                                    "sigma": 0.6, "min": 16, "max": 128}
+    assert (mix["shared_prefix_tokens"], mix["warmup_seconds"],
+            mix["drain_seconds"], mix["order_seed"]) == (0, 30, 60, 23)
+    assert mix["rate_note"] and mix["order_note"] and mix["why"]
+    # it sorts after the sessions mix at which the stock generator stops
+    assert MIXES.index("ide-sessions.json") > MIXES.index(
+        "docqa-trinity.json")
+    # the longest prompt and its answer fit the positions served
+    conf = _mellum_conf()
+    assert 32768 + 512 + 128 <= conf["max_model_len"] \
+        <= conf["max_position_embeddings"]
+    # a window's requests: sessions x asks, every ask of a session on its
+    # document, all inside the window
+    reqs = traffic.build_schedule(mix, 7, 45, "window")["requests"]
+    assert len(reqs) == 10 * round(mix["rate_rps"] * 45)
+    docs = {}
+    for r in reqs:
+        docs.setdefault(r["session"], set()).add(r["session_tokens"])
+    assert all(len(v) == 1 for v in docs.values())
+    live = sum(next(iter(v)) for v in docs.values())
+    warm = traffic.build_schedule(mix, 7, mix["warmup_seconds"],
+                                  "warmup")["requests"]
+    live += sum({r["session"]: r["session_tokens"] for r in warm}.values())
+    # the window's contexts and the warm-up's, which ten asks a mean 5 s
+    # apart keep alive into it: more than a uniform pool of the same bytes
+    # holds (6 GiB / 24,576 B), under what the full group holds
+    assert 6 * 2**30 // (12 * 2048) < live < 596_896
+
+
+def test_mellum_config_holds_every_published_key():
+    row = _catalog_row("Mellum2-12B-A2.5B-Instruct")
+    conf = _mellum_conf()
+    assert conf["source"] == row["source_url"]
+    for key, value in row["config"].items():
+        if key == "num_hidden_layers":
+            assert (conf[key], value) == (12, 28)
+        elif key in ("layer_types", "mlp_layer_types"):
+            assert conf[key] == value[:12]       # the published layers 0-11
+        else:
+            assert conf[key] == value, key
+    assert conf["layer_types"] == (["sliding_attention"] * 3
+                                   + ["full_attention"]) * 3
+    named = " ".join(conf["assumed"])
+    for key in set(conf["model_config_map"].values()) - set(row["config"]):
+        assert key in named, key
+
+
+@pytest.mark.parametrize("rehearse", [False, True])
+def test_mellum_config_maps_onto_the_program(rehearse):
+    import modelcfg
+    from llm_d_tpu.models import get_model
+    from llm_d_tpu.models.config import ModelConfig, RopeRule
+    mc = ModelConfig(**modelcfg.model_config_fields(_mellum_conf(), rehearse))
+    assert get_model(mc).__name__.endswith("models.moe")
+    assert mc.kv_cache_groups == ("full_attention", "sliding_attention")
+    assert mc.num_layers == 12 and mc.first_dense_layers == 0
+    assert mc.layer_types.count("sliding_attention") == 9
+    assert mc.qk_norm and mc.moe_renormalize and not mc.num_shared_experts
+    assert mc.scoring_func == "softmax" and len(mc.rope_rules) == 2
+    assert mc.layer_rope_rule == (1, 1, 1, 0) * 3
+    if rehearse:
+        assert (mc.sliding_window, mc.max_model_len) == (48, 512)
+        return
+    assert (mc.hidden_size, mc.num_heads, mc.num_kv_heads, mc.head_dim_,
+            mc.num_experts, mc.num_experts_per_tok, mc.moe_intermediate_size,
+            mc.vocab_size, mc.sliding_window, mc.max_model_len) == (
+        2304, 32, 4, 128, 64, 8, 896, 98304, 1024, 36864)
+    assert mc.rope_rules == (
+        RopeRule(5e5, 16.0, 8192, 32.0, 1.0, 1.2772588722239782),
+        RopeRule(5e5))
+    from llm_d_tpu.engine.engine import derive_group_blocks, derive_num_blocks
+    from llm_d_tpu.ops.attention import pallas_ineligible_reason
+    assert pallas_ineligible_reason(32, 512) is None
+    layout = get_model(mc).kv_cache_layout(mc)
+    full, window = derive_group_blocks(
+        mc, 32, 32, 2048,
+        derive_num_blocks(6 * 2**30, layout, mc.num_layers, 32))
+    # the window group by the rule, the full group the rest: more than
+    # twice the tokens of one pool of every layer
+    assert window == 32 * 98 * 3 // 2 + 1
+    assert (full - 1) * 32 > 2 * (6 * 2**30 // (12 * 2048))
+    assert (3 * full + 9 * window) * 32 * 2048 <= 6 * 2**30
+
+
+def test_the_accepted_window_stack_keeps_one_pool_at_its_cells_limits():
+    """``trinity-mini`` may go by groups (``kv_cache_groups``), but at its
+    cell's limits the rule's window group (64 slots x 130 pages, half
+    again) is no smaller than its pool of 10,240 pages: one group, the
+    programs, block ids and metric meaning it had."""
+    import modelcfg
+    from llm_d_tpu.engine.engine import (
+        derive_group_blocks, derive_num_blocks, window_group_blocks)
+    from llm_d_tpu.models import get_model
+    from llm_d_tpu.models.config import ModelConfig
+    conf = modelcfg.load_config("trinity-mini")
+    mc = ModelConfig(**modelcfg.model_config_fields(conf))
+    assert mc.kv_cache_groups == ("full_attention", "sliding_attention")
+    args = conf["serve_args"]
+    arg = lambda name: args[args.index(name) + 1]           # noqa: E731
+    seqs, budget, block = (int(arg(n)) for n in (
+        "--max-num-seqs", "--max-num-batched-tokens", "--block-size"))
+    layout = get_model(mc).kv_cache_layout(mc)
+    pool = derive_num_blocks(int(float(arg("--kv-cache-hbm-gb")) * 2**30),
+                             layout, mc.num_layers, block)
+    assert pool == 10240
+    assert window_group_blocks(mc.sliding_window, block, seqs, budget) > pool
+    assert derive_group_blocks(mc, block, seqs, budget, pool) == (pool, 0)
+
+
+def test_mellum_work_functions_read_its_widths():
+    import kernelwork
+    import partwork
+    conf = _mellum_conf()
+    assert kernelwork.kv_row_bytes(conf) == 2048
+    assert kernelwork.prefill_flops(conf, 10) == 4.0 * 32 * 128 * 10
+    assert partwork.expert_bytes(conf) == 3 * 2304 * 896 + (
+        2 * 896 + 2304) * 4
+    peaks = {"hbm_bytes_per_s": 1e9, "bf16_flops": 1e12}
+    # few pairs: the touched experts' bytes bound; many: the dots
+    assert partwork.experts(conf, {"moe_experts_touched": 64, "moe_pairs": 8},
+                            peaks) == 64 * partwork.expert_bytes(conf) / 1e9
+    assert partwork.experts(
+        conf, {"moe_experts_touched": 64, "moe_pairs": 2048 * 8 * 12},
+        peaks) == 2048 * 8 * 12 * 6.0 * 2304 * 896 / 1e12
+
+
+def test_mellum_span_metrics_on_hand_made_spans():
+    from readers import counter_ratio, span_ratio
+
+    def step(**attrs):
+        return {"name": "engine.step", "ts": 0.0, "dur": 0.01,
+                "attrs": attrs}
+
+    ctx = {"spans": [
+        step(kv_pages_full=50, kv_pages_full_total=100, kv_pages_window=30,
+             kv_pages_window_total=40, kv_held_tokens=1000,
+             kv_dead_tokens=40, kv_read_tokens=300, kv_ctx_tokens=900),
+        step(kv_pages_full=100, kv_pages_full_total=100, kv_pages_window=40,
+             kv_pages_window_total=40, kv_held_tokens=1000,
+             kv_dead_tokens=60, kv_read_tokens=300, kv_ctx_tokens=900),
+        step(kv_held_tokens=5, kv_dead_tokens=0)],      # a one-group step
+        "counters": {
+            "before": {"vllm:prefix_cache_queries_total": 1000.0,
+                       "llmd_tpu:prefix_cache_hit_tokens_lost_total": 10.0},
+            "after": {"vllm:prefix_cache_queries_total": 3000.0,
+                      "llmd_tpu:prefix_cache_hit_tokens_lost_total": 110.0}}}
+
+    def read(name):
+        d = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())
+        reader = {"span_ratio": span_ratio,
+                  "counter_ratio": counter_ratio}[d["reader"]]
+        return reader.read(ctx, **d["args"])
+
+    assert read("kv_full_pool_fill_share") == 75.0
+    assert read("kv_window_pool_fill_share") == 87.5
+    assert read("kv_window_dead_share.mellum2") == pytest.approx(
+        100 * 100 / 2005)
+    assert read("attn_kv_read_share.mellum2") == pytest.approx(100 / 3)
+    assert read("prefix_hit_lost_share") == 5.0
+    # a program without the counts (the parent): nothing, and no error
+    ctx["spans"] = [step(kv_held_tokens=5, kv_dead_tokens=0)]
+    ctx["counters"]["after"].pop(
+        "llmd_tpu:prefix_cache_hit_tokens_lost_total")
+    assert read("kv_full_pool_fill_share") is None
+    assert read("prefix_hit_lost_share") is None
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_mellum_cell_rehearses_on_the_cpu(trace):
+    """``run.py --rehearse --workload mellum2.ide``: the harness's whole
+    path (server, load generator with sessions, the checks (a)-(d) against
+    ``references/mellum.py``) at the tiny preset, whose window of 48 is
+    shorter than its longest prompt; with ``--trace 1`` the grouped
+    cache's counts feed the new metrics."""
+    import os
+    import subprocess
+    out = subprocess.run(
+        [sys.executable, str(BENCH / "run.py"), "--workload", "mellum2.ide",
+         "--seed", str(2**31 + 4646), "--seconds", "4", "--trace", str(trace),
+         "--rehearse"],
+        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode == 0
+    last = json.loads(out.stdout.strip().splitlines()[-1])
+    assert last["correct"] is True and last["failed"] == 0
+    assert last["attempted"] == 12
+    got = {k.removeprefix("cpu_rehearsal."): v["value"]
+           for k, v in last["metrics"].items()}
+    if trace:
+        assert {"kv_full_pool_fill_share", "kv_window_pool_fill_share",
+                "kv_window_dead_share.mellum2", "attn_kv_read_share.mellum2",
+                "prefix_hit_lost_share", "prefix_hit_share",
+                "step_ms.decode.mellum2", "itl_p95_ms.mellum2",
+                "step_ms.mixed"} <= set(got)
+        assert 0.0 < got["kv_full_pool_fill_share"] <= 100.0
+        assert 0.0 < got["kv_window_pool_fill_share"] <= 100.0
+        # two asks in three find their session's document in both groups
+        assert 30.0 < got["prefix_hit_share"] < 67.0
+        assert got["prefix_hit_lost_share"] == 0.0      # nothing is evicted
+        assert got["kv_window_dead_share.mellum2"] < 40.0
+        assert got["attn_kv_read_share.mellum2"] < 100.0
+        # device metrics are read from a device trace only
+        assert not {"moe_roofline_share.mellum2", "device_idle_share.mellum2",
+                    "attn_prefill_mxu_share.mellum2",
+                    "attn_decode_hbm_share.mellum2",
+                    "device_part_share.experts.mellum2"} & set(got)
+    else:
+        assert set(got) == {"ttft_p50_ms", "ttft_p95_ms", "setup_s"}
+
+
+def test_mellum_mechanism_check_names_each_fault():
+    import mellum_mechanism_check as tool
+    import references.mellum as ref
+    from llm_d_tpu.models.config import NO_WINDOW, get_config
+    faults = {f for _, f in tool.WRONG if f}
+    assert faults == {
+        "all_full", "no_yarn", "yarn_everywhere", "attention_factor_1",
+        "int4_experts"}
+    # tried and printed, not required (the tool's docstring says why)
+    assert tool.MUST_REFUSE == faults - {"attention_factor_1"}
+    assert tool.WRONG[0] == ("as published", None)
+    c = get_config("tiny-mellum")
+    full, sliding = ref.rope_rules(c)
+    assert full[1] == 4.0 and sliding[1] == 0.0
+    assert tool.faulty(ref, c, None) == (c, (full, sliding), False)
+    assert tool.faulty(ref, c, "all_full")[0].sliding_window == NO_WINDOW
+    assert tool.faulty(ref, c, "no_yarn")[1] == (sliding, sliding)
+    assert tool.faulty(ref, c, "yarn_everywhere")[1] == (full, full)
+    assert tool.faulty(ref, c, "attention_factor_1")[1] == (
+        full[:-1] + (1.0,), sliding)
+    assert tool.faulty(ref, c, "int4_experts") == (c, (full, sliding), True)
+    import jax.numpy as jnp
+    q = jnp.arange(-128, 128, dtype=jnp.int8)
+    got = tool.int4_experts({"w_up_q": q, "w_up_s": jnp.ones(3)})
+    assert got["w_up_s"].shape == (3,)
+    assert sorted(set(got["w_up_q"].tolist())) == list(range(-128, 113, 16))

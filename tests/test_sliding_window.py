@@ -323,17 +323,20 @@ def test_ring_attention_refuses_a_window():
 
 
 # (g) the counters: what a step asks of the cache, against a count by hand.
-def _by_hand(config, ends, news):
+def _by_hand(config, ends, news, held_from=None):
+    """``held_from``: per row, the first token whose page the window layers
+    still hold (a cache in groups by layer kind); None: all of them."""
     kinds = config.layer_types or (FULL,) * config.num_layers
     ctx = read = held = dead = 0
-    for end, new in zip(ends, news):
+    for end, new, first in zip(ends, news, held_from or [0] * len(ends)):
         for kind in kinds:
             w = config.sliding_window if kind == SLIDING else 1 << 40
             ctx += sum(p + 1 for p in range(end - new, end))
             read += sum(min(p + 1, w) for p in range(end - new, end))
-            held += end
+            first_held = first if kind == SLIDING else 0
+            held += end - first_held
             # the row's next query, at position ``end``, sees keys > end - w
-            dead += sum(1 for j in range(end) if j <= end - w)
+            dead += sum(1 for j in range(first_held, end) if j <= end - w)
     return {"kv_ctx_tokens": ctx, "kv_read_tokens": read,
             "kv_held_tokens": held, "kv_dead_tokens": dead}
 
@@ -366,18 +369,26 @@ def test_step_span_carries_the_counts_and_shapes_precompile():
                                           ignore_eos=True))
     engine.add_request(req)
     ends = []
+    # The window layers' pages go back as the window passes them: a step
+    # holds what the one before left, from the page of the first key that
+    # step's last query could see (pages of 16, window 48).
+    held_from = {64: 0, 128: 16, 150: 80, 151: 96, 152: 96}
     while engine.has_work():
         before = req.num_computed_tokens
         engine.step()
         ends.append(req.num_computed_tokens)
         new = ends[-1] - before
         kv = dict(engine._step_kv)
+        pages = {k: kv.pop(k) for k in list(kv) if k.startswith("kv_pages_")
+                 or k == "kv_window_pages_released"}
+        assert pages["kv_pages_window"] <= pages["kv_pages_window_total"] \
+            == engine.kv_manager.groups[1].num_blocks - 1
         # A step with prefill tokens also says how full its attention grid
         # is; off the chip that grid is the [S, Q] rectangle of its bucket.
         grid = (kv.pop("attn_q_real", None), kv.pop("attn_q_slots", None))
         assert grid == {64: (64, 4 * 64), 22: (22, 4 * 32),
                         1: (None, None)}[new]
-        assert kv == _by_hand(SWA, [ends[-1]], [new])
+        assert kv == _by_hand(SWA, [ends[-1]], [new], [held_from[ends[-1]]])
     assert ends == [64, 128, 150, 151, 152]
     # Serving compiled nothing that the start had not.
     assert engine._step_fn._cache_size() == len(shapes)

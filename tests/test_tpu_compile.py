@@ -401,6 +401,33 @@ CASES = [
     pytest.param(functools.partial(_moe, "streamed", 2048, I=1024, E=128,
                                    Lm=6),
                  id="streamed_moe_int8-T2048-I1024-E128"),
+    # ... at 64 experts of [2304, 896] (hidden 2304 = 18 lane tiles, width
+    # 896 = 7: neither a power of two; top-8, 12 MoE layers), every kernel
+    # at the ends of its token range: the slabs are 1.97 MiB and the kernels
+    # are chosen by the step's token count alone, so there is no geometry
+    # rule to extend (ROADMAP B9).
+    pytest.param(functools.partial(_moe, "dense", 16, H=2304, I=896, Lm=12),
+                 id="dense_moe_int8-T16-H2304-I896-E64"),
+    pytest.param(functools.partial(_moe, "dense", 64, H=2304, I=896, Lm=12),
+                 id="dense_moe_int8-T64-H2304-I896-E64"),
+    pytest.param(functools.partial(_moe, "routed", 128, H=2304, I=896, Lm=12),
+                 id="routed_moe_int8-T128-H2304-I896-E64"),
+    pytest.param(functools.partial(_moe, "routed", 512, H=2304, I=896, Lm=12),
+                 id="routed_moe_int8-T512-H2304-I896-E64"),
+    pytest.param(functools.partial(_moe, "streamed", 1024, H=2304, I=896,
+                                   Lm=12),
+                 id="streamed_moe_int8-T1024-H2304-I896-E64"),
+    pytest.param(functools.partial(_moe, "streamed", 2048, H=2304, I=896,
+                                   Lm=12),
+                 id="streamed_moe_int8-T2048-H2304-I896-E64"),
+    # ... and the attention kernels over a cache in groups by layer kind:
+    # ONE plane, contexts to 36864 (B = 1152 pages), 32 sequence rows.
+    pytest.param(functools.partial(_dense_decode, 32, 4, 128, S=32, B=1152,
+                                   L=1, window=True),
+                 id="paged_decode-bf16-window-one-plane-S32"),
+    pytest.param(functools.partial(_dense_prefill, 32, 4, 128, S=32, Q=2048,
+                                   B=1152, L=1, window=True),
+                 id="flash_prefill-bf16-window-one-plane-S32-Q2048"),
     # ... and at expert width 768 (qwen3-30b-a3b and sdar-30b-a3b: 128
     # experts, top-8, 8 MoE layers; kanana-2-30b-a3b: top-6), the token
     # counts that the ledger's breakdown names in their cells.
