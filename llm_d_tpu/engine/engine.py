@@ -2060,6 +2060,7 @@ class EngineCore:
         # context it kept)
         self._note_step(step_t0, now, [sr.request for sr in scheduled],
                         sched.prefill_tokens, decode_load, fused=True,
+                        prefill_ahead_tokens=sched.prefill_ahead_tokens,
                         spec=True, drafted=total_drafted,
                         accepted=total_accepted,
                         kv=self._kv_counts(
@@ -3366,7 +3367,7 @@ class EngineCore:
                    prefill_tokens: int, decode_tokens: int, *,
                    fused: bool, rounds: int = 1, kv: Dict[str, int],
                    run_ahead: int = 0, wasted_rows: int = 0,
-                   **spec_attrs) -> None:
+                   prefill_ahead_tokens: int = 0, **spec_attrs) -> None:
         """Describe the ``engine.step`` span of the iteration under way:
         the one place all four step paths do, so they share one extent
         (``t0``, the clock read before the RNG split, to tokens
@@ -3380,6 +3381,7 @@ class EngineCore:
             kind=("decode" if prefill_tokens == 0
                   else "prefill" if decode_tokens == 0 else "mixed"),
             n_seqs=len(requests), prefill_tokens=prefill_tokens,
+            prefill_ahead_tokens=prefill_ahead_tokens,
             decode_tokens=decode_tokens, fused=fused, rounds=rounds,
             run_ahead=run_ahead, wasted_rows=wasted_rows,
             **kv, **spec_attrs))
@@ -3502,6 +3504,8 @@ class EngineCore:
         the requests the scheduler finished itself surfaced."""
         sched = self.scheduler.schedule()
         sched_now = time.monotonic()
+        if sched.prefill_ahead_tokens:
+            self.metrics.prefill_ahead_tokens.inc(sched.prefill_ahead_tokens)
         for sr in sched.scheduled:
             if sr.is_first_schedule and not sr.request.queue_wait_observed:
                 sr.request.queue_wait_observed = True
@@ -3653,6 +3657,7 @@ class EngineCore:
                         sched.prefill_tokens, sched.decode_tokens,
                         fused=False, kv={**rec.kv, **moe},
                         run_ahead=int(rec.ahead), wasted_rows=wasted,
+                        prefill_ahead_tokens=sched.prefill_ahead_tokens,
                         **(self._block_pass_counts(scheduled, ids)
                            if self.block_length else {}))
         if self.eplb is not None:

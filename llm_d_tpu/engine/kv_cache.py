@@ -369,6 +369,39 @@ class KVCacheManager:
             n = len(blocks) * self.block_size
         return blocks, n
 
+    def caches_block(self, request: Request, i: int) -> bool:
+        """Is the ``i``-th full block of the request's tokens cached (group
+        0)?  One look and no walk: what a scheduler pass may ask about a
+        waiting request whose look-up it keeps."""
+        hashes = self._req_hashes.get(request.request_id, ())
+        return 0 <= i < len(hashes) and hashes[i] in self._cached
+
+    def same_block(self, a: Request, b: Request, i: int) -> bool:
+        """Do both requests' tokens agree up to the end of their ``i``-th
+        full block (by the chain hashes each has had computed)?"""
+        ha = self._req_hashes.get(a.request_id, ())
+        hb = self._req_hashes.get(b.request_id, ())
+        return 0 <= i < min(len(ha), len(hb)) and ha[i] == hb[i]
+
+    def holds_prefix(self, request: Request, blocks: Sequence[int]) -> bool:
+        """Are ``blocks``, a hit that ``find_cached_prefix`` granted the
+        request earlier, still its first pages: each the page of its
+        content in the request's region, and (a grouped cache) the window
+        group's pages under the window before the boundary still cached?"""
+        n = len(blocks)
+        hashes = self.request_block_hashes(request)
+        region = self.region_of_request(request)
+        full = self.groups[0]
+        if n > len(hashes) or any(
+                full.hash_of.get(b) != h or full.region_of(b) != region
+                for b, h in zip(blocks, hashes)):
+            return False
+        if len(self.groups) > 1:
+            cached = self.groups[1].cached
+            return all(h in cached
+                       for h in hashes[n - min(self._tail_blocks, n):n])
+        return True
+
     def _both_grant(self, request: Request, n_full: int) -> int:
         """The longest hit, in blocks, that the window group grants too:
         the largest n <= ``n_full`` whose last ``_tail_blocks`` blocks (all
@@ -419,7 +452,9 @@ class KVCacheManager:
 
         ``reuse_blocks`` are prefix-cache hits to adopt (only valid when the
         request currently holds no blocks). Returns newly attached block ids
-        (reused + fresh), or None if not enough free blocks (caller preempts).
+        (reused + fresh), or None if not enough free blocks (caller preempts)
+        or the hit is no longer whole (``holds_prefix``: the look-up may be
+        older than this pass, and an evicted page is someone else's now).
         A grouped cache attaches a page of every group for every new block,
         or nothing: of a hit the window group lends the blocks under the
         window before the boundary (``find_cached_prefix`` saw them cached).
@@ -434,6 +469,8 @@ class KVCacheManager:
         reuse: List[List[int]] = [list(reuse_blocks)]
         if reuse_blocks:
             assert not request.block_ids
+            if not self.holds_prefix(request, reuse_blocks):
+                return None         # evicted since the look-up
             new_needed -= len(reuse_blocks)
             if len(self.groups) > 1:
                 n = len(reuse_blocks)

@@ -5,7 +5,7 @@ from __future__ import annotations
 import dataclasses
 import enum
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from llm_d_tpu.ops.sampling import SamplingParams
 from llm_d_tpu.utils.lifecycle import CRITICALITY_TIERS
@@ -57,6 +57,10 @@ class Request:
     num_computed_tokens: int = 0
     block_ids: List[int] = dataclasses.field(default_factory=list)
     num_cached_prompt_tokens: int = 0      # prefix-cache hits (metrics/scoring)
+    # While it waits: (pages to adopt, tokens they hold, the block whose
+    # caching would let it end in one step or None) as the scheduler's look
+    # at it found them; None before that and once admitted.
+    prefix_hit: Optional[Tuple[List[int], int, Optional[int]]] = None
     num_preemptions: int = 0
     # Queue-wait metric latch: preemption resets the computed-token state,
     # so ``is_first_schedule`` fires again on re-admission — without this

@@ -64,6 +64,11 @@ SPEC_ACCEPTED_METRIC = "llmd_tpu:spec_accepted_tokens_total"
 # stream.
 STEP_PREFILL_TOKENS_METRIC = "llmd_tpu:step_prefill_tokens_total"
 STEP_DECODE_TOKENS_METRIC = "llmd_tpu:step_decode_tokens_total"
+# Of the prefill-chunk tokens: those a scheduler pass funded AHEAD of an older
+# request's unfinished prefill, because their request ends in that step
+# (engine/scheduler.py).  Over step_prefill_tokens it is the share of prefill
+# that went out of arrival order: 0 where every prompt fits a step or none does.
+PREFILL_AHEAD_TOKENS_METRIC = "llmd_tpu:prefill_ahead_tokens_total"
 # Generation by diffusion over blocks: forward passes over a block by kind
 # (``denoise``: nothing of the pass is kept; ``commit``: the block's final
 # keys and values are written) and the tokens those passes revealed.
@@ -284,6 +289,10 @@ class EngineMetrics:
             STEP_DECODE_TOKENS_METRIC,
             "Decode + speculative-verify tokens computed per engine "
             "step.")
+        self.prefill_ahead_tokens = counter(
+            PREFILL_AHEAD_TOKENS_METRIC,
+            "Prefill tokens funded ahead of an older request's unfinished "
+            "prefill (their request ends in that step).")
         self._diffusion_passes = Counter(
             DIFFUSION_PASSES_METRIC,
             "Block-diffusion forward passes over one block, by kind "

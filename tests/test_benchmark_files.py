@@ -476,8 +476,8 @@ def test_dots3_cell_and_its_files():
     assert names[at + len(DOTS_METRICS):] == [
         "attn_window_key_fill_share"] + PHI4_METRICS + [
         "moe_held_hbm_share",                       # PRs 40, 41, 42, 43,
-        "dsa_index_key_fill_share",                 # 46 appended
-        *MELLUM_METRICS]
+        "dsa_index_key_fill_share",                 # 46, 47 appended
+        *MELLUM_METRICS, "prefill_ahead_share"]
     held = json.loads((BENCH / "layer_metrics"
                        / "moe_held_hbm_share.json").read_text())
     assert per_layer["moe_held_hbm_share"]["workloads"] == ["dots3.longdoc"]
@@ -661,7 +661,7 @@ def test_phi4flash_cell_and_its_files():
     assert names[at:at + len(PHI4_METRICS)] == PHI4_METRICS   # in order
     assert names[at + len(PHI4_METRICS):] == [
         "moe_held_hbm_share", "dsa_index_key_fill_share",       # PRs 42, 43
-        *MELLUM_METRICS]                                        # PR 46
+        *MELLUM_METRICS, "prefill_ahead_share"]                 # PRs 46, 47
     for name in PHI4_METRICS:
         assert per_layer[name]["workloads"] == ["phi4flash.longdoc"]
         d = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())
@@ -911,7 +911,9 @@ def test_mellum_cell_and_its_files():
     assert reh["sizes"]["sliding_window"] % 32        # no multiple of a page
     per_layer = {m["name"]: m for m in bench["per_layer"]}
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-len(MELLUM_METRICS):] == MELLUM_METRICS     # last, in order
+    at = names.index(MELLUM_METRICS[0])     # appended in order (PR 47's follows)
+    assert names[at:at + len(MELLUM_METRICS)] == MELLUM_METRICS
+    assert names[at + len(MELLUM_METRICS):] == ["prefill_ahead_share"]
     for name in MELLUM_METRICS:
         assert per_layer[name]["workloads"] == ["mellum2.ide"]
         d = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())
@@ -1176,3 +1178,42 @@ def test_mellum_mechanism_check_names_each_fault():
     got = tool.int4_experts({"w_up_q": q, "w_up_s": jnp.ones(3)})
     assert got["w_up_s"].shape == (3,)
     assert sorted(set(got["w_up_q"].tolist())) == list(range(-128, 113, 16))
+
+
+# ---------------------------------------------------------------------------
+# prefill_ahead_share (PR 47): the scheduler's short-first rule, as data
+# ---------------------------------------------------------------------------
+
+def test_prefill_ahead_share_is_a_data_file():
+    """One per-layer metric appended to BENCHMARK.json and a file for the
+    reader that is there; it reads the counter the scheduler's rule brought,
+    0 where nothing was passed and nothing from a program without it."""
+    from readers import counter_ratio
+    from llm_d_tpu.utils.metrics import (PREFILL_AHEAD_TOKENS_METRIC,
+                                         STEP_PREFILL_TOKENS_METRIC)
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    assert bench["per_layer"][-1] == {
+        "name": "prefill_ahead_share", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "scheduler and KV manager",
+        "moves": "ttft_p95_ms",
+        "workloads": ["mellum2.ide", "trinity-mini.docqa", "qwen3moe.chat",
+                      "kanana2.batch", "phi4flash.longdoc"]}
+    entry = bench["per_layer"][-1]
+    d = json.loads((BENCH / "layer_metrics"
+                    / "prefill_ahead_share.json").read_text())
+    assert all(d[k] == entry[k] for k in (
+        "name", "unit", "better", "source", "layer", "moves"))
+    assert d["reader"] == "counter_ratio" and d["args"] == {
+        "numerator": PREFILL_AHEAD_TOKENS_METRIC,
+        "denominator": STEP_PREFILL_TOKENS_METRIC}
+    assert set(entry["workloads"]) <= {w["name"] for w in bench["workloads"]}
+    ctx = {"counters": {
+        "before": {STEP_PREFILL_TOKENS_METRIC: 1000.0,
+                   PREFILL_AHEAD_TOKENS_METRIC: 50.0},
+        "after": {STEP_PREFILL_TOKENS_METRIC: 21000.0,
+                  PREFILL_AHEAD_TOKENS_METRIC: 550.0}}}
+    assert counter_ratio.read(ctx, **d["args"]) == 2.5
+    ctx["counters"]["after"][PREFILL_AHEAD_TOKENS_METRIC] = 50.0
+    assert counter_ratio.read(ctx, **d["args"]) == 0.0      # a control
+    del ctx["counters"]["after"][PREFILL_AHEAD_TOKENS_METRIC]
+    assert counter_ratio.read(ctx, **d["args"]) is None     # the parent
