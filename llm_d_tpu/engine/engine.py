@@ -55,6 +55,7 @@ from llm_d_tpu.engine.step_clock import StepClock
 from llm_d_tpu.models import get_model
 from llm_d_tpu.models.config import (
     CROSS, FULL, GMU, MAMBA, NO_WINDOW, SLIDING, ModelConfig, get_config)
+from llm_d_tpu.ops import moe as moe_ops
 from llm_d_tpu.ops import sampling as sampling_ops
 from llm_d_tpu.ops.parts import part
 from llm_d_tpu.parallel.mesh import MeshConfig, make_mesh
@@ -264,6 +265,7 @@ class _LaunchedStep:
     routed: Any                     # EPLB: routed expert ids, or None
     routed_valid: Optional[np.ndarray]
     kv: Dict[str, int]              # the step's ``_kv_counts`` and the like
+    bucket: int                     # token rows of the step's program
     t0: float                       # the clock read before the launch
     ahead: bool                     # launched with its predecessor in flight
 
@@ -475,6 +477,11 @@ class EngineCore:
                 params = quantize_moe_experts(params, donate=owns_params)
         elif config.quantization is not None:
             raise ValueError(f"unknown quantization {config.quantization!r}")
+        # ``ops.moe.expert_ffn``'s own gate: the int8 kernels serve this
+        # engine's steps (``_moe_counts`` says which one by the bucket).
+        self._int8_expert_kernels = (
+            config.quantization == "int8" and self.mesh.devices.size == 1
+            and moe_ops.int8_kernels_serve())
         shardings = logical_to_sharding(rules, params, self.mesh)
         self.params = shard_pytree(params, shardings)
         self.eplb = None
@@ -3216,17 +3223,22 @@ class EngineCore:
                 "ssm_prefill_tokens": int(news[news > 1].sum()),
                 "ssm_resets": resets}
 
-    def _moe_counts(self, tokens: int, touched) -> Dict[str, int]:
+    def _moe_counts(self, tokens: int, touched, bucket: int) -> Dict[str, int]:
         """What a retired step asked of the routed experts (step_clock.py):
         ``touched`` is the program's own count, fetched with the step's
-        ids; nothing where the program has none."""
+        ids; nothing where the program has none.  Which int8 kernel served
+        the step follows from its token ``bucket`` alone."""
         if touched is None:
             return {}
         c = self.model_config
         moe_layers = c.num_layers - c.first_dense_layers
+        pairs = tokens * c.num_experts_per_tok * moe_layers
+        one_pass = self._int8_expert_kernels \
+            and moe_ops.int8_kernel(bucket) == "one_pass"
         return {"moe_experts_touched": int(touched),
                 "moe_experts_held": moe_layers * c.num_held_experts,
-                "moe_pairs": tokens * c.num_experts_per_tok * moe_layers}
+                "moe_pairs": pairs,
+                "moe_one_pass_pairs": pairs if one_pass else 0}
 
     def _attn_q_counts(self, real: int, layout: BatchLayout) -> Dict[str, int]:
         """What a prefill or mixed dispatch hands prefill attention
@@ -3619,7 +3631,7 @@ class EngineCore:
             self.metrics.kv_window_pages_released.inc(released)
         return _LaunchedStep(
             sched, scheduled, rows, samples, fetch, routed,
-            self._routed_valid, self._step_kv, t0, ahead)
+            self._routed_valid, self._step_kv, layout.T, t0, ahead)
 
     def _retire(self, rec: _LaunchedStep,
                 outputs: List[RequestOutput]) -> None:
@@ -3636,7 +3648,8 @@ class EngineCore:
         self._clock.mark("fetch")
         # llmd: ignore[JIT] the one intended per-step host sync (batched)
         fetched = jax.device_get(rec.fetch)
-        moe = self._moe_counts(sched.total_tokens, fetched.get("touched"))
+        moe = self._moe_counts(sched.total_tokens, fetched.get("touched"),
+                               rec.bucket)
         now = self._clock.mark("post", **moe)
         ids = np.asarray(fetched["ids"])
         logprobs = (np.asarray(fetched["logprobs"])

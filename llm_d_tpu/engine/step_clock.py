@@ -150,6 +150,11 @@ so the count lies on the trace's clock beside the kernels):
   moe_experts_held     MoE layers x routed experts: what a stream of every
                        expert reads
   moe_pairs            real token rows x experts a token x MoE layers
+  moe_one_pass_pairs   ``moe_pairs`` where the step's program has more than
+                       ``ops.moe.ROUTED_INT8_MAX_T`` token rows and the int8
+                       kernels serve it (the one-pass kernel: every expert's
+                       matrices read once a layer), else 0; known on the
+                       host from the step's token bucket, nothing fetched
 
 and, on the classic path's ``llmd.dispatch`` annotation only, beside
 ``prefill_tokens``:

@@ -372,9 +372,9 @@ def forward(
             # per-layer copy) with the MoE-layer plane index; on TPU
             # they reach the Pallas int8 kernel family without a
             # materialized dequant — dense streaming / fused-routing
-            # routed / chunk-streamed by batch regime on one device
-            # (ops/pallas/moe_int8.py, moe_routed.py,
-            # moe_routed_stream.py), and the chunk-streamed kernel per
+            # routed / one-pass by the step's token count on one device
+            # (ops/pallas/moe_int8.py, moe_routed.py, moe_one_pass.py),
+            # and the chunk-streamed kernel (moe_routed_stream.py) per
             # dispatch chunk on the a2a EP mesh path.
             quant = dict(quant_stacked, layer=li - Ld)
             w_gate = w_up = w_down = held_plane = None

@@ -9,9 +9,10 @@ wide-ep decode.yaml:76-132).  Design:
   - Grouped GEMM: tokens are sorted by expert id and fed to
     ``jax.lax.ragged_dot`` — one MXU-friendly kernel over all local experts
     instead of a Python loop (the DeepGEMM role).  The int8 path has its
-    own three kernels (dense streaming / fused-routing routed /
-    chunk-streamed routed), chosen by a step's token count alone: see
-    ``DENSE_INT8_MAX_T`` and ``ops.pallas``.
+    own three kernels on one device (dense streaming / fused-routing
+    routed / one pass over rows sorted by expert), chosen by a step's
+    token count alone: see ``DENSE_INT8_MAX_T`` and ``ops.pallas``; the
+    a2a exchange's arrival chunks have a fourth (chunk-streamed).
   - Expert parallelism: experts shard over the *flattened* (dp, sp, tp) mesh
     axes ("TPxDP in attention, EP in MoE layers", decode.yaml:76,87).  Two
     dispatch strategies:
@@ -271,16 +272,57 @@ DENSE_DISPATCH_MAX_T = 512
 #     stays in VMEM, gather and combine are one-hot products inside the
 #     kernel.  512 rows is where that residency ends (x, the f32 output
 #     block and the double-buffered weight slabs share VMEM).
-#   above                    ``streamed_moe_int8``, the same body over
-#     token-order chunks of PREFILL_CHUNK_T rows streamed through VMEM.
+#   above                    ``one_pass_moe_int8``: the whole step's rows
+#     sorted by expert in HBM, every expert's matrices read ONCE
+#     (``_one_pass_int8_kernel_path``).
+#
+# Until PR 48 the steps above 512 rows went through ``streamed_moe_int8``,
+# the routed body over token-order chunks of PREFILL_CHUNK_T rows: a
+# 2,048-token step was four chunks and each streamed every expert it touched
+# again.  Its cost model was written at H 2048, I 512, E 64 (201 MB a layer),
+# which no cell runs.  At the published widths (T 2,048, v5e: 819 GB/s,
+# 197 TFLOP/s):
+#
+#   configuration                    int8 experts  4 passes  1 pass   the real    rows an expert:
+#                                    a layer                          pairs' dots a chunk / the step
+#   trinity-mini (128 x 1024)        805 MB        3.9 ms    0.98 ms  1.05 ms     32 / 128
+#   qwen3-/sdar-30b-a3b (128 x 768)  604 MB        2.95 ms   0.74 ms  0.79 ms     32 / 128
+#   kanana-2-30b-a3b (same, top-6)   604 MB        2.95 ms   0.74 ms  0.59 ms     24 / 96
+#   mellum2-12b-a2.5b (64 x 896,     397 MB        1.94 ms   0.48 ms  1.03 ms     64 / 256
+#     hidden 2304)
+#
+# and the kernel read 5.5 ms a layer in ``trinity-mini.docqa`` (ledger, PR
+# 47): the re-streaming at 71 % of the HBM rate, in tiles of the 32 rows a
+# CHUNK gives an expert.  The streamed kernel stays for the a2a exchange's
+# arrival chunks (``_a2a_moe_chunk``: k = 1 rows in arrival order, a chunk
+# the resident unit), which no cell runs.
 DENSE_INT8_MAX_T = 64
 ROUTED_INT8_MAX_T = 512
 
-# Rows a chunk of the streamed kernel.  A layer's weights are read once a
-# chunk (T / chunk passes) while the one-hot gather and combine cost
-# 2 * chunk / (3 * I) of the expert FLOPs; 512 is what VMEM holds on the
-# v5e beside the f32 accumulator and the double-buffered weight tiles.
+# Rows a chunk of the streamed kernel (the a2a exchange's only, see above).
+# A shard's weights are read once a chunk (rows / chunk passes) while the
+# one-hot gather and combine cost 2 * chunk / (3 * I) of the expert FLOPs;
+# 512 is what VMEM holds on the v5e beside the f32 accumulator and the
+# double-buffered weight tiles.
 PREFILL_CHUNK_T = 512
+
+
+def int8_kernels_serve(dispatch: str = "auto") -> bool:
+    """Whether a single-device ``expert_ffn`` hands int8 experts to the
+    kernels: on the TPU, unless a dispatch is asked for by name (the
+    dequantized paths, the kernels' reference)."""
+    if dispatch == "auto":
+        dispatch = os.environ.get("LLMD_MOE_DISPATCH", "auto")
+    return jax.default_backend() == "tpu" and dispatch == "auto"
+
+
+def int8_kernel(T: int) -> str:
+    """The int8 expert kernel that serves a single-device step of ``T``
+    token rows on the TPU: ``dense``, ``routed`` or ``one_pass``.  The one
+    rule ``expert_ffn`` dispatches by and the engine counts by."""
+    if T <= DENSE_INT8_MAX_T:
+        return "dense"
+    return "routed" if T <= ROUTED_INT8_MAX_T else "one_pass"
 
 
 def _routed_row_tile(slots: int, E: int) -> int:
@@ -288,6 +330,21 @@ def _routed_row_tile(slots: int, E: int) -> int:
     an expert: small tiles bound each expert's padding (the only waste
     left), larger ones feed the MXU better once the groups fill them."""
     return 32 if slots < E * 96 else 64
+
+
+def _one_pass_row_tile(slots: int, E: int) -> int:
+    """Rows a tile of the one-pass kernel, from the mean rows an expert: the
+    MXU's height from 96 rows an expert up, half of it below (a mean run of
+    48-64 rows in tiles of 128 is a third to a half padding).  One layer's
+    call alone on the v5e, ms at tiles of 64 / 128 / 256 rows (my chip runs,
+    PR 48; rows an expert = T k / E):
+      trinity-mini  T 2048 (128)  3.43 / 3.23 / 3.73   T 1024 (64)   2.15 / 2.25 / 3.56
+      qwen3, sdar   T 2048 (128)  2.86 / 2.74 / 3.12   T 1024 (64)   1.81 / 1.90 / 2.95
+      kanana-2      T 2048 (96)   2.60 / 2.06 / 3.03   T 1024 (48)   1.27 / 1.79 / 2.84
+      mellum2       T 2048 (256)  2.69 / 2.65 / 2.87   T 1024 (128)  1.62 / 1.77 / 2.05
+    (the streamed path: 7.05, 5.78, 4.52, 5.66 at 2,048 rows; 3.60, 2.95, 2.31,
+    2.85 at 1,024: no boundary above ROUTED_INT8_MAX_T where it wins)."""
+    return 128 if slots >= E * 96 else 64
 
 
 def _env_int(name: str, default: int) -> int:
@@ -429,6 +486,100 @@ def _streamed_int8_kernel_path(x, weights, idx, quant: dict,
     # out_dtype lets combine-in-f32 callers (the a2a exchange) skip a
     # lossy bf16 round trip of the kernel's native f32 accumulator.
     return out[:T].astype(out_dtype or x.dtype)
+
+
+def _excl_prefix_rows(m: jax.Array, block: int = 512) -> jax.Array:
+    """Exclusive prefix sums down the rows of ``m`` [T, E] (small counts),
+    int32: blocks of rows against a strict lower triangle on the MXU
+    (integers in bf16, f32 sums: exact) and the blocks' totals before
+    them.  XLA's own cumsum over a long axis is a tree of passes."""
+    T, E = m.shape
+    B = min(block, T)
+    nb = -(-T // B)
+    mb = jnp.pad(m, ((0, nb * B - T), (0, 0))).astype(
+        jnp.bfloat16).reshape(nb, B, E)
+    r = jnp.arange(B, dtype=jnp.int32)
+    tri = (r[:, None] > r[None, :]).astype(jnp.bfloat16)
+    within = jnp.einsum("ij,bje->bie", tri, mb,
+                        preferred_element_type=jnp.float32)
+    totals = jnp.sum(mb, axis=1, dtype=jnp.float32)            # [nb, E]
+    before = jnp.cumsum(totals, axis=0) - totals
+    return (within + before[:, None, :]).astype(jnp.int32).reshape(
+        nb * B, E)[:T]
+
+
+def _one_pass_layout(idx: jax.Array, weights: jax.Array, E: int, rt: int):
+    """The one-pass kernel's layout over ALL of a step's slots ``idx``
+    [T, k]: each expert's slots, in token order, a run padded to ``rt``,
+    one expert a tile, in expert order.
+
+    Returns ``(pos, tok_pad, wslot_pad, tile_expert, num_tiles)``: ``pos``
+    [T, k] the padded slot of each (token, choice); ``tok_pad`` / ``wslot_pad``
+    [NT * rt] the token and the combine weight of each padded slot (token 0
+    at weight 0 = pad), NT = ceil(S / rt) + E the static worst case;
+    ``tile_expert`` [NT], a tile past ``num_tiles`` repeating the last one's
+    expert (its blocks are not fetched again).
+
+    No sort: a slot's rank in its expert's run is the slots of that expert
+    in the tokens before it (``_excl_prefix_rows`` of the per-token counts)
+    plus the token's own earlier choices of it, all compares and sums over
+    [T, k, E]; the one scatter writes the inverse."""
+    T, k = idx.shape
+    S = T * k
+    assert k <= 256, k          # the per-token counts ride in bf16
+    NT = -(-S // rt) + E
+    hit = idx[:, :, None] == jnp.arange(E, dtype=idx.dtype)     # [T, k, E]
+    per_tok = jnp.sum(hit, axis=1, dtype=jnp.int32)             # [T, E]
+    prior = _excl_prefix_rows(per_tok)                          # [T, E]
+    counts = prior[-1] + per_tok[-1]
+    tiles = jax.lax.div(counts + (rt - 1), rt)
+    base = _excl_cumsum(tiles) * rt
+    j = jnp.arange(k, dtype=jnp.int32)
+    earlier = jnp.sum(
+        (idx[:, :, None] == idx[:, None, :]) & (j[None, :] < j[:, None]),
+        axis=-1, dtype=jnp.int32)                               # [T, k]
+    pos = jnp.sum(jnp.where(hit, (base + prior)[:, None, :], 0),
+                  axis=-1) + earlier
+    inverse = jnp.zeros((NT * rt, 2), jnp.int32).at[pos.reshape(S)].set(
+        jnp.stack([jnp.arange(S, dtype=jnp.int32) // k,
+                   jax.lax.bitcast_convert_type(
+                       weights.reshape(S).astype(jnp.float32), jnp.int32)],
+                  axis=1),
+        unique_indices=True, mode="promise_in_bounds")
+    num_tiles = tiles.sum().astype(jnp.int32)      # >= 1: S >= 1 always
+    tile = jnp.minimum(jnp.arange(NT, dtype=jnp.int32), num_tiles - 1)
+    tile_expert = jnp.minimum(
+        jnp.sum(jnp.cumsum(tiles)[None, :] <= tile[:, None], axis=1),
+        E - 1).astype(jnp.int32)
+    return (pos, inverse[:, 0],
+            jax.lax.bitcast_convert_type(inverse[:, 1], jnp.float32),
+            tile_expert, num_tiles)
+
+
+def _one_pass_int8_kernel_path(x, weights, idx, quant: dict,
+                               row_tile: Optional[int] = None,
+                               interpret: bool = False):
+    """Glue for the one-pass kernel (a prefill chunk's step on one device):
+    the layout over the whole step, the rows gathered into it by their
+    token ids, and after the kernel each token's k results gathered back
+    and summed in f32 (ops/pallas/moe_one_pass.py)."""
+    from llm_d_tpu.ops.pallas.moe_one_pass import one_pass_moe_int8
+    T, H = x.shape
+    k = idx.shape[1]
+    E = quant["w_gate_q"].shape[1]
+    rt = row_tile or _one_pass_row_tile(T * k, E)
+    pos, tok_pad, wslot_pad, tile_expert, num_tiles = _one_pass_layout(
+        idx, weights, E, rt)
+    y = one_pass_moe_int8(
+        x.astype(jnp.bfloat16).at[tok_pad].get(mode="promise_in_bounds"),
+        wslot_pad[:, None], tile_expert, num_tiles, quant["layer"],
+        quant["w_gate_q"], quant["w_gate_s"],
+        quant["w_up_q"], quant["w_up_s"],
+        quant["w_down_q"], quant["w_down_s"],
+        row_tile=rt, interpret=interpret)
+    out = jnp.sum(y.at[pos].get(mode="promise_in_bounds"), axis=1,
+                  dtype=jnp.float32)
+    return out.astype(x.dtype)
 
 
 def _dense_int8_kernel_path(x, weights, idx, quant: dict,
@@ -830,7 +981,7 @@ def expert_ffn(
 
     ``quant`` carries int8 expert payloads END TO END: on the TPU
     single-device path they reach the Pallas kernel family (dense
-    streaming / fused-routing routed / chunk-streamed) WITHOUT a
+    streaming / fused-routing routed / one-pass) WITHOUT a
     materialized dequant (XLA cannot fuse ``convert(int8)`` into a dot
     operand, and the int8+bf16 round trip costs ~2.5x the quantized
     bytes — see ops/pallas/moe_int8.py), and on the TPU a2a mesh path
@@ -849,17 +1000,15 @@ def expert_ffn(
     if mesh is None or mesh.devices.size == 1:
         if dispatch == "auto":
             dispatch = os.environ.get("LLMD_MOE_DISPATCH", "auto")
-        if quant is not None and jax.default_backend() == "tpu" \
-                and dispatch == "auto":
+        if quant is not None and int8_kernels_serve(dispatch):
             # The three int8 kernels, by the step's token count (the
             # comment at DENSE_INT8_MAX_T says why each boundary); an
             # EXPLICIT dispatch gets the dequantized paths below, the
             # kernels' reference.
-            if x.shape[0] <= DENSE_INT8_MAX_T:
-                return _dense_int8_kernel_path(x, weights, idx, quant)
-            if x.shape[0] <= ROUTED_INT8_MAX_T:
-                return _routed_int8_kernel_path(x, weights, idx, quant)
-            return _streamed_int8_kernel_path(x, weights, idx, quant)
+            path = {"dense": _dense_int8_kernel_path,
+                    "routed": _routed_int8_kernel_path,
+                    "one_pass": _one_pass_int8_kernel_path}
+            return path[int8_kernel(x.shape[0])](x, weights, idx, quant)
         if dispatch == "auto":
             dispatch = ("dense" if x.shape[0] <= DENSE_DISPATCH_MAX_T
                         else "ragged")

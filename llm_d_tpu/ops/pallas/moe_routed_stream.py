@@ -1,4 +1,22 @@
-"""Pallas TPU kernel: chunk-streamed fused-routing int8 MoE FFN (prefill).
+"""Pallas TPU kernel: chunk-streamed fused-routing int8 MoE FFN (the a2a
+exchange's arrival chunks).
+
+**What this kernel serves since PR 48.**  Its one caller is
+``ops.moe._a2a_moe_chunk``: on an expert-parallel mesh each shard's received
+rows (arrival order, k = 1, validity as the combine weight) go through it a
+chunk at a time, and the result lands in arrival order with no un-sort.  No
+benchmark cell runs that path.  The single-device steps above 512 rows,
+which it served until then, left it for ``moe_one_pass.py``: the cost model
+below was written at H = 2048, I = 512, E = 64 (201 MB of int8 experts a
+layer), which no cell runs.  At the published widths a 2,048-token step's
+four chunks streamed 604-805 MB of experts four times (3.0-3.9 ms a layer at
+the HBM rate against 0.7-1.0 for one pass), in tiles of the 32 rows a CHUNK
+gives an expert, with 33-44 % more FLOPs for the one-hot gather and combine:
+one layer's call read 7.05 ms (``trinity-mini``), 5.78 (``qwen3-30b-a3b``),
+4.52 (``kanana-2-30b-a3b``), 5.66 (``mellum2-12b-a2.5b``) against 3.23, 2.74,
+2.06, 2.65 through the one-pass path (my chip runs, PR 48; the table at
+``ops.moe.DENSE_INT8_MAX_T``).  The text below is the design as it was
+written for the prefill regime; it still describes the kernel.
 
 ``moe_routed.py`` proved the fused-routing idea for decode: keep ``x``
 token-ordered and VMEM-resident, and turn the gather / un-sort / combine

@@ -476,8 +476,8 @@ def test_dots3_cell_and_its_files():
     assert names[at + len(DOTS_METRICS):] == [
         "attn_window_key_fill_share"] + PHI4_METRICS + [
         "moe_held_hbm_share",                       # PRs 40, 41, 42, 43,
-        "dsa_index_key_fill_share",                 # 46, 47 appended
-        *MELLUM_METRICS, "prefill_ahead_share"]
+        "dsa_index_key_fill_share",                 # 46, 47, 48 appended
+        *MELLUM_METRICS, "prefill_ahead_share", "moe_one_pass_share"]
     held = json.loads((BENCH / "layer_metrics"
                        / "moe_held_hbm_share.json").read_text())
     assert per_layer["moe_held_hbm_share"]["workloads"] == ["dots3.longdoc"]
@@ -661,7 +661,8 @@ def test_phi4flash_cell_and_its_files():
     assert names[at:at + len(PHI4_METRICS)] == PHI4_METRICS   # in order
     assert names[at + len(PHI4_METRICS):] == [
         "moe_held_hbm_share", "dsa_index_key_fill_share",       # PRs 42, 43
-        *MELLUM_METRICS, "prefill_ahead_share"]                 # PRs 46, 47
+        *MELLUM_METRICS, "prefill_ahead_share",                 # PRs 46, 47
+        "moe_one_pass_share"]                                   # PR 48
     for name in PHI4_METRICS:
         assert per_layer[name]["workloads"] == ["phi4flash.longdoc"]
         d = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())
@@ -913,7 +914,8 @@ def test_mellum_cell_and_its_files():
     names = [m["name"] for m in bench["per_layer"]]
     at = names.index(MELLUM_METRICS[0])     # appended in order (PR 47's follows)
     assert names[at:at + len(MELLUM_METRICS)] == MELLUM_METRICS
-    assert names[at + len(MELLUM_METRICS):] == ["prefill_ahead_share"]
+    assert names[at + len(MELLUM_METRICS):] == ["prefill_ahead_share",
+                                                "moe_one_pass_share"]
     for name in MELLUM_METRICS:
         assert per_layer[name]["workloads"] == ["mellum2.ide"]
         d = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())
@@ -1192,13 +1194,13 @@ def test_prefill_ahead_share_is_a_data_file():
     from llm_d_tpu.utils.metrics import (PREFILL_AHEAD_TOKENS_METRIC,
                                          STEP_PREFILL_TOKENS_METRIC)
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    assert bench["per_layer"][-1] == {
+    assert bench["per_layer"][-2] == {
         "name": "prefill_ahead_share", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "scheduler and KV manager",
         "moves": "ttft_p95_ms",
         "workloads": ["mellum2.ide", "trinity-mini.docqa", "qwen3moe.chat",
                       "kanana2.batch", "phi4flash.longdoc"]}
-    entry = bench["per_layer"][-1]
+    entry = bench["per_layer"][-2]
     d = json.loads((BENCH / "layer_metrics"
                     / "prefill_ahead_share.json").read_text())
     assert all(d[k] == entry[k] for k in (
@@ -1217,3 +1219,38 @@ def test_prefill_ahead_share_is_a_data_file():
     assert counter_ratio.read(ctx, **d["args"]) == 0.0      # a control
     del ctx["counters"]["after"][PREFILL_AHEAD_TOKENS_METRIC]
     assert counter_ratio.read(ctx, **d["args"]) is None     # the parent
+
+
+# ---------------------------------------------------------------------------
+# moe_one_pass_share (PR 48): the one-pass int8 expert kernel, as data
+# ---------------------------------------------------------------------------
+
+def test_moe_one_pass_share_is_a_data_file():
+    """One per-layer metric appended to BENCHMARK.json and a file for the
+    reader that is there, listed for the five cells with int8 experts (each
+    reports ``ttft_p95_ms``); the counter's own test is in
+    tests/test_program_parts.py."""
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    entry = bench["per_layer"][-1]
+    assert entry == {
+        "name": "moe_one_pass_share", "unit": "%", "better": "higher",
+        "source": "program_span", "layer": "MoE kernels",
+        "moves": "ttft_p95_ms",
+        "workloads": ["qwen3moe.chat", "kanana2.batch", "trinity-mini.docqa",
+                      "sdar.batch", "mellum2.ide"]}
+    d = json.loads((BENCH / "layer_metrics"
+                    / "moe_one_pass_share.json").read_text())
+    assert all(d[k] == entry[k] for k in (
+        "name", "unit", "better", "source", "layer", "moves"))
+    assert d["reader"] == "span_ratio" and d["args"] == {
+        "span": "engine.step", "numerator": "moe_one_pass_pairs",
+        "denominator": ["moe_pairs"]}
+    configs = {c["name"]: c["file"] for c in bench["configs"]}
+    cells = {w["name"]: w for w in bench["workloads"]}
+    for name in entry["workloads"]:
+        conf = json.loads((REPO / configs[cells[name]["config"]]).read_text())
+        assert "--quantization" in conf["serve_args"], name
+    assert sorted(entry["workloads"]) == sorted(
+        n for n, w in cells.items() if "--quantization" in json.loads(
+            (REPO / configs[w["config"]]).read_text())["serve_args"])
+

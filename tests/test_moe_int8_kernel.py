@@ -123,6 +123,24 @@ def _rand_quant(key, E, H, I, Lm=2, plane=1):
     return quant, (deq["w_gate"], deq["w_up"], deq["w_down"])
 
 
+def _eplb_physical(quant, idx):
+    """A physical layout of 4 logical experts: expert 1 gets a replica in
+    slot 4, expert 3 in slot 5 (E_phys = 6), replica weights copies of the
+    logical ones.  Returns the physical payloads and the physical ids of
+    ``idx`` (``to_physical_experts``)."""
+    replica_table = jnp.asarray(
+        [[0, 0], [1, 4], [2, 2], [3, 5]], jnp.int32)
+    num_replicas = jnp.asarray([1, 2, 1, 2], jnp.int32)
+    phys_of = jnp.asarray([0, 1, 2, 3, 1, 3])
+    quant_phys = dict(quant)
+    for name in ("w_gate", "w_up", "w_down"):
+        for suf in ("_q", "_s"):
+            quant_phys[name + suf] = quant[name + suf][:, phys_of]
+    phys_idx = moe_ops.to_physical_experts(idx, replica_table, num_replicas)
+    assert int(phys_idx.max()) >= 4  # replicas actually exercised
+    return quant_phys, phys_idx
+
+
 def _assert_routed_matches_oracle(x, w, idx, quant, deq, rt=None):
     got = moe_ops._routed_int8_kernel_path(
         x, w, idx, quant, row_tile=rt, interpret=True)
@@ -209,19 +227,7 @@ def test_routed_kernel_eplb_physical_layout():
     w = jnp.abs(jax.random.normal(ks[2], (T, k), jnp.float32)) * 0.3
     quant, deq = _rand_quant(ks[3], E_log, H, I)
 
-    # Physical layout: expert 1 gets a replica in slot 4, expert 3 in
-    # slot 5 (E_phys = 6); replica weights are copies of the logical.
-    replica_table = jnp.asarray(
-        [[0, 0], [1, 4], [2, 2], [3, 5]], jnp.int32)
-    num_replicas = jnp.asarray([1, 2, 1, 2], jnp.int32)
-    phys_of = [0, 1, 2, 3, 1, 3]
-    quant_phys = dict(quant)
-    for name in ("w_gate", "w_up", "w_down"):
-        for suf in ("_q", "_s"):
-            a = quant[name + suf]
-            quant_phys[name + suf] = a[:, jnp.asarray(phys_of)]
-    phys_idx = moe_ops.to_physical_experts(idx, replica_table, num_replicas)
-    assert int(phys_idx.max()) >= E_log  # replicas actually exercised
+    quant_phys, phys_idx = _eplb_physical(quant, idx)
 
     got = moe_ops._routed_int8_kernel_path(
         x, w, phys_idx, quant_phys, row_tile=8, interpret=True)
@@ -326,17 +332,7 @@ def test_streamed_kernel_eplb_physical_layout():
     w = jnp.abs(jax.random.normal(ks[2], (T, k), jnp.float32)) * 0.3
     quant, deq = _rand_quant(ks[3], E_log, H, I)
 
-    replica_table = jnp.asarray(
-        [[0, 0], [1, 4], [2, 2], [3, 5]], jnp.int32)
-    num_replicas = jnp.asarray([1, 2, 1, 2], jnp.int32)
-    phys_of = [0, 1, 2, 3, 1, 3]
-    quant_phys = dict(quant)
-    for name in ("w_gate", "w_up", "w_down"):
-        for suf in ("_q", "_s"):
-            a = quant[name + suf]
-            quant_phys[name + suf] = a[:, jnp.asarray(phys_of)]
-    phys_idx = moe_ops.to_physical_experts(idx, replica_table, num_replicas)
-    assert int(phys_idx.max()) >= E_log  # replicas actually exercised
+    quant_phys, phys_idx = _eplb_physical(quant, idx)
 
     got = moe_ops._streamed_int8_kernel_path(
         x, w, phys_idx, quant_phys, chunk_t=chunk_t, row_tile=8,
@@ -374,6 +370,224 @@ def test_streamed_a2a_matches_dequant_a2a(devices, under_jit):
                                atol=1e-2)
 
 
+def _assert_one_pass_matches_oracle(x, w, idx, quant, deq, rt=None):
+    got = moe_ops._one_pass_int8_kernel_path(
+        x, w, idx, quant, row_tile=rt, interpret=True)
+    want = moe_ops._local_expert_ffn(x, w, idx, *deq, jnp.int32(0))
+    assert got.shape == x.shape and got.dtype == x.dtype
+    scale = float(jnp.max(jnp.abs(np.asarray(want)))) + 1e-9
+    np.testing.assert_allclose(np.asarray(got, np.float32) / scale,
+                               np.asarray(want, np.float32) / scale,
+                               atol=8e-3)
+
+
+def _routed_case(key, T, E, H, I, k, distinct=True):
+    """Rows, routing as ``route()`` gives it (top-k of seeded router
+    logits: k distinct experts a token) and stacked int8 experts."""
+    ks = jax.random.split(key, 3)
+    x = jax.random.normal(ks[0], (T, H), jnp.bfloat16)
+    logits = jax.random.normal(ks[1], (T, E), jnp.float32)
+    w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    quant, deq = _rand_quant(ks[2], E, H, I)
+    return x, w, idx.astype(jnp.int32), quant, deq
+
+
+@pytest.mark.parametrize("T,E,H,I,k,rt", [
+    # The four geometries' rows an expert against the tile, at small
+    # widths: trinity-mini / qwen3 (128 experts, top-8: the mean run is one
+    # tile), kanana (top-6: three quarters of one), mellum2 (64 experts:
+    # two tiles), and the 1,024 bucket's half tile.
+    (64, 16, 256, 128, 8, 32),     # S / E = 32 = rt
+    (64, 16, 256, 128, 6, 32),     # S / E = 24: top-6
+    (64, 8, 384, 128, 8, 32),      # S / E = 64 = 2 rt; hidden 3 lane tiles
+    (32, 16, 256, 128, 8, 32),     # S / E = 16 = rt / 2
+    (37, 8, 256, 128, 2, 16),      # T no multiple of anything
+    (48, 16, 256, 128, 8, None),   # the tile the path's own rule gives
+], ids=["mean-1-tile", "top6", "mean-2-tiles-H384", "half-tile", "T37",
+        "rule"])
+def test_one_pass_kernel_matches_dequant_oracle(T, E, H, I, k, rt):
+    """One-pass kernel == routed dequant oracle through the ACTUAL glue
+    (_one_pass_int8_kernel_path: the layout over the whole step, the row
+    gather into it, the kernel, the gather back and the k-sum) in
+    interpret mode."""
+    x, w, idx, quant, deq = _routed_case(jax.random.PRNGKey(43), T, E, H, I,
+                                         k)
+    _assert_one_pass_matches_oracle(x, w, idx, quant, deq, rt=rt)
+
+
+def _layout_reference(idx, w, E, rt):
+    """The layout by a plain stable sort on the host."""
+    idx, w = np.asarray(idx), np.asarray(w)
+    T, k = idx.shape
+    counts = np.bincount(idx.reshape(-1), minlength=E)
+    tiles = -(-counts // rt)
+    base = (np.cumsum(tiles) - tiles) * rt
+    NT = -(-T * k // rt) + E
+    pos = np.zeros((T, k), np.int64)
+    tok_pad = np.zeros(NT * rt, np.int64)
+    wslot = np.zeros(NT * rt, np.float32)
+    seen = np.zeros(E, np.int64)
+    for t in range(T):
+        for j in range(k):
+            e = idx[t, j]
+            pos[t, j] = base[e] + seen[e]
+            tok_pad[pos[t, j]], wslot[pos[t, j]] = t, w[t, j]
+            seen[e] += 1
+    nt = int(tiles.sum())
+    tile_expert = np.repeat(np.arange(E), tiles)
+    tile_expert = np.concatenate(
+        [tile_expert, np.full(NT - nt, tile_expert[-1])])
+    return pos, tok_pad, wslot, tile_expert, nt
+
+
+@pytest.mark.parametrize("T,E,k,rt,seed", [
+    (64, 16, 8, 32, 0), (600, 16, 4, 32, 1), (1100, 8, 2, 64, 2),
+    (5, 4, 3, 16, 3)])
+def test_one_pass_layout_is_the_stable_sort(T, E, k, rt, seed):
+    """``_one_pass_layout`` (per-token counts, a triangular product for the
+    ranks, one scatter) gives what a stable sort by expert gives: slots in
+    token order within an expert's run, runs padded to the tile, in expert
+    order; more rows than one block of the triangular product holds, and
+    duplicate choices within a token (random ids, not top-k)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    idx = jax.random.randint(ks[0], (T, k), 0, E)
+    w = jax.random.uniform(ks[1], (T, k), jnp.float32)
+    got = jax.jit(moe_ops._one_pass_layout, static_argnums=(2, 3))(
+        idx, w, E, rt)
+    want = _layout_reference(idx, w, E, rt)
+    for g, r in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), r)
+
+
+def test_one_pass_kernel_empty_experts_get_no_tile():
+    """Routing concentrated on 3 of 16 experts: only they have tiles (the
+    others' matrices are not read), the idle tiles repeat the last one's
+    expert, and the output matches the oracle."""
+    key = jax.random.PRNGKey(47)
+    T, E, H, I, k, rt = 32, 16, 256, 128, 2, 16
+    ks = jax.random.split(key, 4)
+    x = jax.random.normal(ks[0], (T, H), jnp.bfloat16)
+    hot = jnp.asarray([1, 7, 12], jnp.int32)
+    idx = hot[jax.random.randint(ks[1], (T, k), 0, 3)]
+    w = jnp.abs(jax.random.normal(ks[2], (T, k), jnp.float32)) * 0.3
+    quant, deq = _rand_quant(ks[3], E, H, I)
+    _assert_one_pass_matches_oracle(x, w, idx, quant, deq, rt=rt)
+    _, _, _, tile_e, num_tiles = moe_ops._one_pass_layout(idx, w, E, rt)
+    nt = int(num_tiles)
+    active = np.asarray(tile_e[:nt])
+    assert set(active.tolist()) == {1, 7, 12}
+    assert np.all(np.diff(active) >= 0)
+    assert np.all(np.asarray(tile_e[nt:]) == active[-1])
+
+
+def test_one_pass_kernel_duplicate_routes_accumulate():
+    """A token routed to the SAME expert in both slots: two rows of the
+    expert's run, both summed into the token."""
+    key = jax.random.PRNGKey(53)
+    T, E, H, I = 24, 4, 256, 128
+    ks = jax.random.split(key, 3)
+    x = jax.random.normal(ks[0], (T, H), jnp.bfloat16)
+    idx = jnp.full((T, 2), 2, jnp.int32)
+    w = jnp.abs(jax.random.normal(ks[1], (T, 2), jnp.float32)) * 0.3
+    quant, deq = _rand_quant(ks[2], E, H, I)
+    _assert_one_pass_matches_oracle(x, w, idx, quant, deq, rt=16)
+
+
+def test_one_pass_kernel_padded_rows_of_the_token_bucket():
+    """A token bucket's padded rows (zero rows that all choose the same
+    experts, as a zero embedding does) change no real row's result."""
+    T, real, E, H, I, k = 64, 41, 16, 256, 128, 4
+    x, w, idx, quant, deq = _routed_case(jax.random.PRNGKey(59), T, E, H, I,
+                                         k)
+    pad = jnp.arange(T)[:, None] >= real
+    x = jnp.where(pad, 0, x)
+    idx = jnp.where(pad, idx[real], idx)
+    got = moe_ops._one_pass_int8_kernel_path(x, w, idx, quant, row_tile=16,
+                                             interpret=True)
+    alone = moe_ops._one_pass_int8_kernel_path(
+        x[:real], w[:real], idx[:real], quant, row_tile=16, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got[:real], np.float32),
+                                  np.asarray(alone, np.float32))
+    assert not np.any(np.asarray(got[real:], np.float32))
+
+
+@pytest.mark.parametrize("rows", [32, 33], ids=["one-tile", "one-row-over"])
+def test_one_pass_kernel_run_of_one_tile_and_one_row_over(rows):
+    """An expert whose run is exactly one tile, and one row over (a second
+    tile of one real row, multiplied from the matrices the first tile
+    cast)."""
+    T, E, H, I, rt = 48, 4, 256, 128, 32
+    ks = jax.random.split(jax.random.PRNGKey(61), 3)
+    x = jax.random.normal(ks[0], (T, H), jnp.bfloat16)
+    first = jnp.where(jnp.arange(T) < rows, 1, 3)
+    idx = jnp.stack([first, jnp.zeros((T,), jnp.int32)], axis=1).astype(
+        jnp.int32)
+    w = jnp.abs(jax.random.normal(ks[1], (T, 2), jnp.float32)) * 0.3
+    quant, deq = _rand_quant(ks[2], E, H, I)
+    _, _, _, tile_e, num_tiles = moe_ops._one_pass_layout(idx, w, E, rt)
+    assert np.asarray(tile_e[:int(num_tiles)]).tolist().count(1) == \
+        -(-rows // rt)
+    _assert_one_pass_matches_oracle(x, w, idx, quant, deq, rt=rt)
+
+
+def test_one_pass_kernel_eplb_physical_layout():
+    """One-pass kernel under an EPLB replica table (mirrors the routed
+    kernel's test): replicas carry the same weights, the layout over the
+    physical ids matches the logical oracle."""
+    key = jax.random.PRNGKey(67)
+    T, E_log, H, I, k = 40, 4, 256, 128, 2
+    ks = jax.random.split(key, 4)
+    x = jax.random.normal(ks[0], (T, H), jnp.bfloat16)
+    idx = jax.random.randint(ks[1], (T, k), 0, E_log)
+    w = jnp.abs(jax.random.normal(ks[2], (T, k), jnp.float32)) * 0.3
+    quant, deq = _rand_quant(ks[3], E_log, H, I)
+
+    quant_phys, phys_idx = _eplb_physical(quant, idx)
+
+    got = moe_ops._one_pass_int8_kernel_path(
+        x, w, phys_idx, quant_phys, row_tile=16, interpret=True)
+    want = moe_ops._local_expert_ffn(x, w, idx, *deq, jnp.int32(0))
+    scale = float(jnp.max(jnp.abs(np.asarray(want)))) + 1e-9
+    np.testing.assert_allclose(np.asarray(got, np.float32) / scale,
+                               np.asarray(want, np.float32) / scale,
+                               atol=8e-3)
+
+
+def test_one_pass_rounds_where_the_streamed_kernel_does():
+    """Same mathematics as the streamed kernel the path replaced: bf16
+    after ``silu(h) * u * weight`` and after the down projection, f32
+    elsewhere.  The two differ only in the order of the f32 k-sum, which
+    the last rounding to bf16 shows in an ulp here and there."""
+    x, w, idx, quant, _ = _routed_case(jax.random.PRNGKey(71), 64, 16, 256,
+                                       128, 4)
+    got = moe_ops._one_pass_int8_kernel_path(x, w, idx, quant, row_tile=16,
+                                             interpret=True)
+    was = moe_ops._streamed_int8_kernel_path(x, w, idx, quant, chunk_t=32,
+                                             row_tile=8, interpret=True)
+    got, was = np.asarray(got, np.float32), np.asarray(was, np.float32)
+    ulp = np.maximum(np.abs(was), 2.0 ** -126) * 2.0 ** -7
+    assert np.all(np.abs(got - was) <= ulp)
+    assert np.mean(got != was) < 0.05
+
+
+@pytest.mark.parametrize("T,k,E,want", [
+    (2048, 8, 128, 128),   # trinity-mini, qwen3, sdar: 128 rows an expert
+    (1024, 8, 128, 64),    # ... their 1,024 bucket: 64
+    (2048, 6, 128, 128),   # kanana-2: 96
+    (1024, 6, 128, 64),    # 48
+    (2048, 8, 64, 128),    # mellum2: 256
+    (1024, 8, 64, 128),    # 128
+    (8192, 8, 128, 128),
+])
+def test_one_pass_row_tile_by_rows_an_expert(T, k, E, want):
+    """The MXU's height from 96 rows an expert up, half below: the rule of
+    the one-pass path alone (``_routed_row_tile`` keeps its own for the
+    kernels of 512 rows and fewer, whose programs did not change)."""
+    assert moe_ops._one_pass_row_tile(T * k, E) == want
+    assert moe_ops._routed_row_tile(512 * 8, 128) == 32
+    assert moe_ops._routed_row_tile(512 * 8, 32) == 64
+
+
 # Selectors that PR 44 deleted: what they held must no longer reach the
 # choice (each value would have moved it).
 _DELETED_SELECTORS = {
@@ -391,7 +605,8 @@ def kernel_calls(monkeypatch):
     """The three int8 kernels replaced by recorders of (kernel, row tile,
     chunk) under a pretended TPU backend, so that ``expert_ffn`` and the
     real glue paths run on the CPU; the deleted selectors set."""
-    from llm_d_tpu.ops.pallas import moe_int8, moe_routed, moe_routed_stream
+    from llm_d_tpu.ops.pallas import (
+        moe_int8, moe_one_pass, moe_routed, moe_routed_stream)
     calls = []
 
     def recorder(name):
@@ -404,6 +619,8 @@ def kernel_calls(monkeypatch):
     monkeypatch.setattr(moe_routed, "routed_moe_int8", recorder("routed"))
     monkeypatch.setattr(moe_routed_stream, "streamed_moe_int8",
                         recorder("streamed"))
+    monkeypatch.setattr(moe_one_pass, "one_pass_moe_int8",
+                        recorder("one_pass"))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     for name, value in _DELETED_SELECTORS.items():
         monkeypatch.setenv(name, value)
@@ -415,16 +632,17 @@ def kernel_calls(monkeypatch):
     (moe_ops.DENSE_INT8_MAX_T, ("dense", None, None)),
     (moe_ops.DENSE_INT8_MAX_T + 1, ("routed", 32, None)),
     (moe_ops.ROUTED_INT8_MAX_T, ("routed", 64, None)),
-    (moe_ops.ROUTED_INT8_MAX_T + 1,
-     ("streamed", 64, moe_ops.PREFILL_CHUNK_T)),
-    (8192, ("streamed", 64, moe_ops.PREFILL_CHUNK_T)),
+    (moe_ops.ROUTED_INT8_MAX_T + 1, ("one_pass", 128, None)),
+    (1024, ("one_pass", 128, None)),
+    (2048, ("one_pass", 128, None)),
+    (8192, ("one_pass", 128, None)),
 ], ids=lambda v: v[0] if isinstance(v, tuple) else f"T{v}")
 def test_int8_kernel_choice_by_token_count(kernel_calls, T, want):
-    """Which int8 kernel serves a step, with what row tile and chunk, is a
-    function of its shapes alone: dense up to DENSE_INT8_MAX_T tokens,
-    routed up to ROUTED_INT8_MAX_T, streamed above; the row tile by the
-    mean rows an expert (T * k against E * 96).  No ``LLMD_MOE_*`` variable
-    but ``LLMD_MOE_DISPATCH`` has a say."""
+    """Which int8 kernel serves a step, with what row tile, is a function
+    of its shapes alone: dense up to DENSE_INT8_MAX_T tokens, routed up to
+    ROUTED_INT8_MAX_T, one pass over the weights above (the streamed kernel
+    is the a2a exchange's only); the row tile by the mean rows an expert.
+    No ``LLMD_MOE_*`` variable but ``LLMD_MOE_DISPATCH`` has a say."""
     E, H, k = 4, 8, 2
     quant, _ = _rand_quant(jax.random.PRNGKey(0), E, H, 8)
     idx = jnp.arange(T * k, dtype=jnp.int32).reshape(T, k) % E
@@ -433,3 +651,4 @@ def test_int8_kernel_choice_by_token_count(kernel_calls, T, want):
                              None, None, None, quant=quant)
     assert out.shape == (T, H)
     assert kernel_calls == [want]
+    assert moe_ops.int8_kernel(T) == want[0]

@@ -334,9 +334,42 @@ def test_the_count_rides_the_step_with_and_without_run_ahead(what):
         assert [kw["moe_experts_touched"] for kw in post] == [
             s["attrs"]["moe_experts_touched"] for s in a_steps]
         assert all(set(kw) == {"moe_experts_touched", "moe_experts_held",
-                               "moe_pairs"} for kw in post)
+                               "moe_pairs", "moe_one_pass_pairs"}
+                   for kw in post)
         assert all(kw["sample_rows"] in (4, 8) and "prefill_tokens" in kw
                    and "kv_read_tokens" in kw for kw in dispatch)
+
+
+@pytest.mark.parametrize("bucket,int8_kernels,served", [
+    (16, True, False), (512, True, False), (1024, True, True),
+    (2048, True, True), (2048, False, False)])
+def test_one_pass_pairs_follow_the_token_bucket(bucket, int8_kernels, served):
+    """``moe_one_pass_pairs`` is ``moe_pairs`` where the step's program has
+    more than ``ROUTED_INT8_MAX_T`` token rows and the int8 kernels serve the
+    engine (never on the CPU), else 0: known from the bucket, so no step
+    program changes for it; ``moe_one_pass_share`` reads it through
+    ``span_ratio``, and nothing from a program without it."""
+    eng = _engine("tiny-moe")
+    assert eng._int8_expert_kernels is False          # bf16 experts, a CPU
+    eng._int8_expert_kernels = int8_kernels
+    c = eng.model_config
+    got = eng._moe_counts(100, 7, bucket)
+    pairs = 100 * c.num_experts_per_tok * (c.num_layers
+                                           - c.first_dense_layers)
+    assert got["moe_pairs"] == pairs
+    assert got["moe_one_pass_pairs"] == (pairs if served else 0)
+    metric = json.loads((BENCH / "layer_metrics"
+                         / "moe_one_pass_share.json").read_text())
+    assert metric["reader"] == "span_ratio"
+    spans = [{"name": "engine.step", "dur": 0.01, "attrs": dict(got)},
+             {"name": "engine.step", "dur": 0.01,
+              "attrs": eng._moe_counts(28, 7, 16)}]
+    share = span_ratio.read({"spans": spans}, **metric["args"])
+    assert share == pytest.approx(
+        100.0 * (pairs if served else 0) / (pairs * 1.28))
+    for s in spans:
+        del s["attrs"]["moe_one_pass_pairs"]          # the parent's spans
+    assert span_ratio.read({"spans": spans}, **metric["args"]) is None
 
 
 @pytest.mark.parametrize("preset", ["tiny", "tiny-ssm"])

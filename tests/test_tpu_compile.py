@@ -448,6 +448,19 @@ CASES = [
     pytest.param(functools.partial(_moe, "routed", 256, I=768, E=128, k=6,
                                    Lm=8),
                  id="routed_moe_int8-T256-I768-E128-top6-kanana"),
+    # The one-pass kernel, which serves every single-device step above 512
+    # rows since PR 48 (the streamed kernel's cases above are the a2a
+    # exchange's body at those widths), at the four published geometries and
+    # both token buckets it meets: tiles of 128 rows, the bf16 matrices of an
+    # expert in a 12.6 MB scratch at width 1024.
+    *[pytest.param(functools.partial(_moe, "one_pass", T, **kw),
+                   id=f"one_pass_moe_int8-T{T}-{name}")
+      for name, kw in (
+          ("I1024-E128-trinity", dict(I=1024, E=128, Lm=6)),
+          ("I768-E128-qwen3", dict(I=768, E=128, Lm=8)),
+          ("I768-E128-top6-kanana", dict(I=768, E=128, k=6, Lm=8)),
+          ("H2304-I896-E64-mellum2", dict(H=2304, I=896, Lm=12)))
+      for T in (1024, 2048)],
     # The query tile list at the shapes the benchmark's cells serve (token
     # bucket / sequence bucket): kanana-2-30b-a3b's MLA latent row (320
     # tiles of 4 slots; 192 of 16 for a 2,048-token chunk), trinity-mini's
