@@ -54,7 +54,8 @@ from llm_d_tpu.engine.scheduler import Scheduler, SchedulerOutput
 from llm_d_tpu.engine.step_clock import StepClock
 from llm_d_tpu.models import get_model
 from llm_d_tpu.models.config import (
-    CROSS, FULL, GMU, MAMBA, NO_WINDOW, SLIDING, ModelConfig, get_config)
+    CROSS, FULL, GMU, LINEAR, MAMBA, NO_WINDOW, SLIDING, ModelConfig,
+    get_config)
 from llm_d_tpu.ops import moe as moe_ops
 from llm_d_tpu.ops import sampling as sampling_ops
 from llm_d_tpu.ops.parts import part
@@ -935,7 +936,11 @@ class EngineCore:
             self._disable_feature("pallas_attention", reason)
             return
         c = self.model_config
-        if c.mla_by_kind:
+        if c.linear_by_layer:
+            # (its FULL layers are plain latent attention: the two MLA
+            # kernels and their tile accounting serve them, below)
+            self._announce_linear_layers()
+        elif c.mla_by_kind:
             # Layers that select keys and layers that see a window are
             # served by a kernel of their own, dense under the selection
             # or the window as a bias (ops/sparse_mla.py,
@@ -961,6 +966,21 @@ class EngineCore:
             c.num_heads // heads_tp, next(iter(layout.values())) // tp)
         if c.mixer_by_layer:
             self._announce_layer_kinds()
+
+    def _announce_linear_layers(self) -> None:
+        """The path a stack's LINEAR layers took (Pallas backend): static
+        per engine, as the attention path above."""
+        from llm_d_tpu.ops.linear_attention import pallas_ineligible_reason
+        c = self.model_config
+        reason = pallas_ineligible_reason(
+            c.lin_num_heads, c.lin_key_dim, c.lin_value_dim)
+        if reason:
+            self._disable_feature("pallas_linear_attention", reason)
+        logger.info(
+            "layer kind %s x %d: %s", LINEAR, c.layer_types.count(LINEAR),
+            "XLA delta-rule update and chunked form" if reason else
+            "delta_decode_update / delta_chunk_scan (Pallas) over XLA's "
+            "piece terms")
 
     def _announce_layer_kinds(self) -> None:
         """The path each layer KIND of a decoder-hybrid-decoder stack took
@@ -3165,7 +3185,7 @@ class EngineCore:
         # (models/hybrid_decoder.py): the self-decoder's attention layers;
         # the plane ``cross_kv_layer`` writes is held too, and read by the
         # sampled rows only (``_cross_decoder_counts`` adds those reads).
-        attending = planes = c.num_layers
+        attending = planes = c.attending_layers
         if c.mixer_by_layer:
             planes = len(c.layers_of(SLIDING, FULL))
             attending = planes - 1
@@ -3247,7 +3267,7 @@ class EngineCore:
         rectangle."""
         slots = layout.S * layout.Q
         c = self.model_config
-        if c.mla_by_kind:
+        if c.mla_by_kind and not c.linear_by_layer:
             # ops/sparse_mla.py: a layer that selects and a window layer
             # each walk tiles of their own; a mean over the layers.
             from llm_d_tpu.ops import sparse_mla
@@ -3292,7 +3312,7 @@ class EngineCore:
         # (a stack with mixers by layer: the self-decoder's attention layers;
         # ``cross_kv_layer`` and the layers after it attend a query a row)
         n_full = (len(c.layers_of(FULL)) - 1 if c.mixer_by_layer
-                  else c.num_layers - n_window)
+                  else c.attending_layers - n_window)
         for window, layers in ((c.sliding_window, n_window),
                                (NO_WINDOW, n_full)):
             if not layers:
@@ -3372,8 +3392,9 @@ class EngineCore:
         lens = np.zeros(layout.S, np.int64)
         lens[:len(ends)] = ends
         blocks = -(-np.sort(lens).reshape(-1, group).max(axis=1) // kb)
-        return {"attn_dk_real": c.num_layers * int(lens.sum()),
-                "attn_dk_slots": c.num_layers * int(blocks.sum()) * group * kb}
+        layers = c.attending_layers
+        return {"attn_dk_real": layers * int(lens.sum()),
+                "attn_dk_slots": layers * int(blocks.sum()) * group * kb}
 
     def _note_step(self, t0: float, fetched: float, requests: List[Request],
                    prefill_tokens: int, decode_tokens: int, *,

@@ -23,7 +23,12 @@ SLIDING, FULL = "sliding_attention", "full_attention"
 # that ``gmu_memory_layer``'s mixer produced for the same token; attention
 # that projects a query only and reads ``cross_kv_layer``'s keys and values.
 MAMBA, GMU, CROSS = "mamba", "gmu", "cross_attention"
-LAYER_KINDS = (SLIDING, FULL, MAMBA, GMU, CROSS)
+# A linear-attention mixer in place of a layer's attention, inside a stack
+# of MLA layers and routed experts (models/moe.py walks it as one more layer
+# kind; models/linear_attention.py): a delta rule under a per-channel gate
+# over a recurrent state a head, no keys in pages and no rotary embedding.
+LINEAR = "linear_attention"
+LAYER_KINDS = (SLIDING, FULL, MAMBA, GMU, CROSS, LINEAR)
 # The window of a full-attention layer: one that never binds (above any
 # position, with room to add a position without overflowing int32).
 NO_WINDOW = 1 << 30
@@ -224,6 +229,19 @@ class ModelConfig:
     ssm_multipliers: Tuple[float, ...] = (1.0,) * 5
     # On the MLP's gate pre-activation and on its output.
     mlp_multipliers: Tuple[float, ...] = (1.0, 1.0)
+    # --- LINEAR layers: gated delta-rule linear attention (0 heads = none) ---
+    # q, k, v = SiLU(causal conv(x W)) a head, q and k L2-normed; the state
+    # S [key, value] float32 a head decays by exp(g) a KEY CHANNEL, g =
+    # ``lin_gate_floor`` * sigmoid(exp(A_log) (x W_f + dt_bias)) in
+    # (floor, 0), then takes the delta-rule write beta k (v - S^T k)^T; the
+    # output S^T q is RMS-normed a head and gated by sigmoid(x W_g), one
+    # scalar a head.  The state and the convolutions' tails live in the
+    # engine's state pool (ops/linear_attention.py).
+    lin_num_heads: int = 0
+    lin_key_dim: int = 0                    # K, a head
+    lin_value_dim: int = 0                  # V, a head
+    lin_conv_kernel: int = 4
+    lin_gate_floor: float = -5.0            # the log-decay's lower bound
 
     @property
     def use_mla(self) -> bool:
@@ -297,7 +315,26 @@ class ModelConfig:
     @property
     def has_recurrent_state(self) -> bool:
         """A sequence's state is not keys and values alone."""
-        return self.ssm_state_size > 0
+        return self.ssm_state_size > 0 or self.linear_by_layer
+
+    @property
+    def linear_by_layer(self) -> bool:
+        """``layer_types`` names LINEAR layers: linear-attention mixers
+        among the MLA layers of a MoE stack (models/moe.py)."""
+        return LINEAR in self.layer_types
+
+    @property
+    def attending_layers(self) -> int:
+        """Layers with attention over keys of their own in the paged cache
+        (a LINEAR layer keeps none)."""
+        return self.num_layers - self.layer_types.count(LINEAR)
+
+    @property
+    def lin_conv_channels(self) -> int:
+        """What a LINEAR layer's causal convolutions run over: q, k and v
+        side by side."""
+        return self.lin_num_heads * (2 * self.lin_key_dim
+                                     + self.lin_value_dim)
 
     @property
     def mixer_by_layer(self) -> bool:
@@ -353,6 +390,7 @@ class ModelConfig:
             if SLIDING in kinds and self.sliding_window < 1:
                 raise ValueError("sliding layers need sliding_window >= 1")
         self._check_hybrid_decoder()
+        self._check_linear_attention()
         if self.use_mla and (self.attn_output_gate or self.sandwich_norm
                              or not self.rope_on_full_attention):
             raise ValueError(
@@ -386,9 +424,9 @@ class ModelConfig:
 
         for name in ("ssm_multipliers", "mlp_multipliers"):
             object.__setattr__(self, name, tuple(getattr(self, name)))
-        if self.has_recurrent_state:
-            # What a recurrent state is not served with, one refusal a
-            # field.
+        if self.ssm_state_size > 0:
+            # What a state-space mixer's state is not served with, one
+            # refusal a field.
             for field, on in (("kv_lora_rank", self.use_mla),
                               ("diffusion_block_length",
                                bool(self.diffusion_block_length)),
@@ -481,6 +519,55 @@ class ModelConfig:
                 "rule for every kind of layer_types; MLA, the hybrid "
                 "decoder and rope_on_full_attention=False have their own")
         return tuple(out)
+
+    def _check_linear_attention(self) -> None:
+        """The one form of a stack with LINEAR layers that models/moe.py
+        walks (MLA layers of one geometry among them, routed experts), each
+        refusal by its field."""
+        lin_fields = (("lin_num_heads", self.lin_num_heads),
+                      ("lin_key_dim", self.lin_key_dim),
+                      ("lin_value_dim", self.lin_value_dim))
+        if not self.linear_by_layer:
+            for field, value in lin_fields:
+                if value:
+                    raise ValueError(
+                        f"{field} belongs to a stack whose layer_types name "
+                        f"{LINEAR!r} layers")
+            return
+        for field, value in lin_fields:
+            if value < 1:
+                raise ValueError(
+                    f"{LINEAR!r} layers need {field} (heads, and a key and "
+                    f"a value size a head)")
+        for field, off in (
+                ("kv_lora_rank", not self.use_mla),
+                ("num_experts", not self.is_moe)):
+            if off:
+                raise ValueError(
+                    f"{LINEAR!r} layers without {field}: a linear-attention "
+                    f"mixer is served among the MLA layers of a MoE stack "
+                    f"(models/moe.py)")
+        for field, on in (
+                ("ssm_state_size", self.ssm_state_size > 0),
+                ("diffusion_block_length",
+                 bool(self.diffusion_block_length)),
+                ("sliding_window", SLIDING in self.layer_types),
+                ("index_topk", bool(self.index_topk)),
+                ("mixers in layer_types", self.mixer_by_layer)):
+            if on:
+                raise ValueError(
+                    f"{LINEAR!r} layers with {field}: the other layers of "
+                    f"such a stack are full MLA attention of one geometry; "
+                    f"{field} is not served there")
+        if FULL not in self.layer_types:
+            raise ValueError(
+                f"{LINEAR!r} layers alone: the stack's paged cache is its "
+                f"{FULL!r} layers' latent rows, and it has none")
+        if self.lin_conv_kernel < 2 or not -10.0 <= self.lin_gate_floor < 0:
+            raise ValueError(
+                "lin_conv_kernel must be at least 2 and lin_gate_floor in "
+                "[-10, 0): half a sub-block's decay must stay inside "
+                "float32 (ops/linear_attention.py)")
 
     def _check_hybrid_decoder(self) -> None:
         """The one form of a stack with mixers by layer that
@@ -797,6 +884,22 @@ PRESETS = {
         swa_qk_rope_head_dim=8, swa_v_head_dim=16, swa_rope_theta=500.0,
         mla_lora_rescale=True, attn_head_gate=True,
         index_topk=16, index_n_heads=4, index_head_dim=16),
+    # Tiny linear-attention / MLA MoE for CPU tests: kinds L L L L F L L
+    # behind one leading dense layer (one period of 5 : 1 and a layer), a
+    # key size that is not the value size, a group-limited sigmoid router
+    # of which a quarter of 8 experts is held, no query latent.
+    "tiny-linear-moe": ModelConfig(
+        name="tiny-linear-moe", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_layers=7, num_heads=4, num_kv_heads=1,
+        rope_theta=10000.0, rms_norm_eps=1e-6, max_model_len=512,
+        num_experts=8, num_experts_per_tok=2, moe_intermediate_size=96,
+        num_shared_experts=1, first_dense_layers=1, scoring_func="sigmoid",
+        n_group=4, topk_group=2, routed_scaling_factor=2.5,
+        num_local_experts=2, first_local_expert=0,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16,
+        layer_types=(LINEAR,) * 4 + (FULL,) + (LINEAR,) * 2,
+        lin_num_heads=4, lin_key_dim=16, lin_value_dim=8),
     # Tiny MLA+MoE config for CPU tests.
     "tiny-mla": ModelConfig(
         name="tiny-mla", vocab_size=512, hidden_size=64,

@@ -13,6 +13,13 @@ and its own cache buffers, stacked over the layers of the kind.  ``forward``
 walks the stack as RUNS of consecutive layers of one group (``layer_runs``),
 one ``lax.scan`` a run; a stack of one kind is the two runs it always was.
 
+A stack may also name LINEAR layers (``ModelConfig.linear_by_layer``): a
+linear-attention mixer in place of attention (models/linear_attention.py),
+one more layer kind with its own groups (``lin_dense_layers`` /
+``lin_moe_layers``) whose "cache buffers" are the engine's state pool
+(``ssm``, ``conv``), two more entries of the ``kv_cache`` dict carried
+through the same scans.
+
 This is the model half of the wide-EP path (reference:
 guides/wide-ep-lws/manifests/modelserver/base/decode.yaml:76-132 — EP flags,
 EPLB, DeepEP backends; the engine equivalents live in ``ops.moe``).
@@ -28,7 +35,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from llm_d_tpu.models.config import FULL, SLIDING, ModelConfig
+from llm_d_tpu.models import linear_attention
+from llm_d_tpu.models.config import FULL, LINEAR, SLIDING, ModelConfig
+from llm_d_tpu.models.linear_attention import (  # noqa: F401  (the engine
+    # builds the state pool of a stack with LINEAR layers from the model)
+    STATE_KEYS, state_pool_shapes)
 from llm_d_tpu.models.llama import (  # noqa: F401  (re-exports: the MoE
     # model shares the dense family's logits head and MTP drafter — the
     # drafter reads only embed/lm_head from the target params, which both
@@ -152,13 +163,16 @@ def _init_params_by_kind(c: ModelConfig, key: jax.Array) -> Params:
                       "final_norm": jnp.ones((c.hidden_size,), dt)}
     if not c.tie_word_embeddings:
         params["lm_head"] = w((c.hidden_size, c.vocab_size))
-    for kind in c.mla_layer_kinds:
+    for kind in c.mla_layer_kinds + ((LINEAR,) if c.linear_by_layer else ()):
         for moe in (False, True):
             n = sum(run.stop - run.start for run in layer_runs(c)
                     if (run.kind, run.moe) == (kind, moe))
             if not n:
                 continue
-            p = init_mla_params(c, n, next(k), dt, kind)
+            if kind == LINEAR:
+                p = linear_attention.init_params(c, n, next(k), dt)
+            else:
+                p = init_mla_params(c, n, next(k), dt, kind)
             p["input_norm"] = jnp.ones((n, c.hidden_size), dt)
             p["post_attn_norm"] = jnp.ones((n, c.hidden_size), dt)
             if moe:
@@ -189,13 +203,14 @@ def _init_params_by_kind(c: ModelConfig, key: jax.Array) -> Params:
 def group_name(kind: str, moe: bool) -> str:
     """The parameter group of the layers of ``kind`` with (``moe``) or
     without routed experts."""
-    return (("swa_" if kind == SLIDING else "")
+    return ({SLIDING: "swa_", LINEAR: "lin_"}.get(kind, "")
             + ("moe_layers" if moe else "dense_layers"))
 
 
 class LayerRun(NamedTuple):
     """Consecutive layers of one parameter group."""
-    kind: str        # FULL | SLIDING (FULL where the stack has one geometry)
+    kind: str        # FULL | SLIDING | LINEAR (FULL where the stack has one
+                     # geometry)
     moe: bool
     start: int       # [start, stop) within the group's stack
     stop: int
@@ -245,7 +260,9 @@ def forward(
     Ld = c.first_dense_layers
     stacked = batch["token_ids"].ndim == 2
     x = embed_tokens(params, batch["token_ids"], c)   # [T, D] / [dp, T_l, D]
-    cache_keys = tuple(kv_cache_layout(c))
+    # (a stack with LINEAR layers carries the state pool beside its pages)
+    cache_keys = tuple(kv_cache_layout(c)) + (
+        STATE_KEYS if c.linear_by_layer else ())
     kinds = c.mla_layer_kinds or (FULL,)
     # Once a step program, outside the layer scans: a block-diffusion
     # model's visibility limits, and the query tile list the Pallas prefill
@@ -270,6 +287,8 @@ def forward(
                     batches[kind] = with_query_tiles(
                         batch, g.num_heads, g.row_width, attn_backend, mesh,
                         mla=True)
+            if c.linear_by_layer:
+                batches[LINEAR] = batch
         else:
             batches = {FULL: with_query_tiles(
                 batch, c.num_heads, kv_cache[cache_keys[0]].shape[-1],
@@ -294,8 +313,11 @@ def forward(
             if c.num_local_experts else None)
 
     def attend_local(lp, hn, caches, ab, li, kind=FULL):
-        """Attention dispatch: MLA (the kind's latent buffers) or classic
-        GQA."""
+        """Attention dispatch: MLA (the kind's latent buffers), a LINEAR
+        layer's mixer (the state pool) or classic GQA."""
+        if kind == LINEAR:
+            return linear_attention.mixer_block(
+                lp, c, hn, ab, caches, li, attn_backend)
         if c.use_mla:
             from llm_d_tpu.models.mla import mla_attention_block
             return mla_attention_block(
@@ -490,6 +512,8 @@ def kind_buffers(config: ModelConfig, kind: str = FULL) -> Tuple[str, ...]:
     rows first."""
     if not config.use_mla:
         return ("k", "v")
+    if kind == LINEAR:
+        return STATE_KEYS
     if kind == SLIDING and config.mla_layer_kinds:
         return ("kv_swa",)
     return ("kv", "idx") if config.index_topk else ("kv",)

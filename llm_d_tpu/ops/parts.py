@@ -35,6 +35,10 @@ operation, whatever kernel or fusion implements the part.
   ssm.proj      the mixer's in_proj, out_proj and gated norm
   ssm.state     the causal convolution with its tails, the state update or
                 the chunked scan and the gathers around it
+  lin.proj      a linear-attention layer's projections (q | k | v, decay,
+                write strength, output gate), its output norm and o_proj
+  lin.state     its causal convolution with the tails, the delta-rule
+                update or the chunked form and the gathers around it
   scan          what ``lax.scan`` itself does around a layer body: the loop,
                 the slices of the stacked weights that XLA materialises, the
                 stacking of per-layer outputs (the body's own operations lie
@@ -51,7 +55,8 @@ import jax
 PREFIX = "llmd."
 PARTS = ("embed", "tiles", "attn.proj", "attn.decode", "attn.prefill",
          "attn.index", "attn.cross", "gmu", "router", "experts", "shared",
-         "mlp", "ssm.proj", "ssm.state", "scan", "head", "sample")
+         "mlp", "ssm.proj", "ssm.state", "lin.proj", "lin.state", "scan", "head",
+         "sample")
 
 
 def part(name: str):

@@ -425,6 +425,12 @@ PHI4_METRICS = ["device_idle_share.phi4flash", "xdec_row_share",
                 "device_part_share.cross", "device_part_share.gmu",
                 "ssm1_scan_roofline_share", "ssm1_decode_hbm_share",
                 "xattn_hbm_share", "kv_window_dead_share.phi4flash"]
+# ling3.longdoc's (PR 49), appended last
+LING_METRICS = ["device_part_share.linear", "device_part_share.experts.ling3",
+                "lin_scan_roofline_share", "lin_decode_hbm_share",
+                "mla_prefill_mxu_share.ling3", "mla_decode_hbm_share.ling3",
+                "step_ms.decode.ling3", "device_idle_share.ling3",
+                "itl_p95_ms.ling3"]
 DOTS_METRICS = ["device_idle_share.dots3", "device_part_share.index",
                 "device_part_share.experts.dots3", "dsa_selected_share",
                 "dsa_index_roofline_share", "mla_sparse_roofline_share"]
@@ -477,7 +483,8 @@ def test_dots3_cell_and_its_files():
         "attn_window_key_fill_share"] + PHI4_METRICS + [
         "moe_held_hbm_share",                       # PRs 40, 41, 42, 43,
         "dsa_index_key_fill_share",                 # 46, 47, 48 appended
-        *MELLUM_METRICS, "prefill_ahead_share", "moe_one_pass_share"]
+        *MELLUM_METRICS, "prefill_ahead_share", "moe_one_pass_share",
+        *LING_METRICS]                              # PR 49
     held = json.loads((BENCH / "layer_metrics"
                        / "moe_held_hbm_share.json").read_text())
     assert per_layer["moe_held_hbm_share"]["workloads"] == ["dots3.longdoc"]
@@ -629,11 +636,11 @@ def _phi4_conf():
 
 def test_phi4flash_cell_and_its_files():
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    cell = bench["workloads"][-2]
+    cell = bench["workloads"][-3]
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         "phi4flash.longdoc", "phi4-mini-flash", "longdoc-8", 1)
     assert len(cell["why"]) <= 200
-    entry = bench["configs"][-2]
+    entry = bench["configs"][-3]
     conf = _phi4_conf()
     assert entry["name"] == conf["name"] == "phi4-mini-flash"
     assert entry["reduced"] == conf["reduced"] == []        # nothing is cut
@@ -662,7 +669,7 @@ def test_phi4flash_cell_and_its_files():
     assert names[at + len(PHI4_METRICS):] == [
         "moe_held_hbm_share", "dsa_index_key_fill_share",       # PRs 42, 43
         *MELLUM_METRICS, "prefill_ahead_share",                 # PRs 46, 47
-        "moe_one_pass_share"]                                   # PR 48
+        "moe_one_pass_share", *LING_METRICS]                    # PRs 48, 49
     for name in PHI4_METRICS:
         assert per_layer[name]["workloads"] == ["phi4flash.longdoc"]
         d = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())
@@ -880,11 +887,11 @@ def _mellum_conf():
 
 def test_mellum_cell_and_its_files():
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    cell = bench["workloads"][-1]
+    cell = bench["workloads"][-2]
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         "mellum2.ide", "mellum2-12b-a2.5b", "ide-sessions", 1)
     assert len(cell["why"]) <= 200
-    entry = bench["configs"][-1]
+    entry = bench["configs"][-2]
     conf = _mellum_conf()
     assert entry["name"] == conf["name"] == "mellum2-12b-a2.5b"
     assert entry["reduced"] == conf["reduced"] == [
@@ -914,8 +921,8 @@ def test_mellum_cell_and_its_files():
     names = [m["name"] for m in bench["per_layer"]]
     at = names.index(MELLUM_METRICS[0])     # appended in order (PR 47's follows)
     assert names[at:at + len(MELLUM_METRICS)] == MELLUM_METRICS
-    assert names[at + len(MELLUM_METRICS):] == ["prefill_ahead_share",
-                                                "moe_one_pass_share"]
+    assert names[at + len(MELLUM_METRICS):] == [
+        "prefill_ahead_share", "moe_one_pass_share", *LING_METRICS]
     for name in MELLUM_METRICS:
         assert per_layer[name]["workloads"] == ["mellum2.ide"]
         d = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())
@@ -932,7 +939,7 @@ def test_mellum_cell_and_its_files():
         "trinity-mini.docqa"]
     assert per_layer["moe_roofline_share.docqa"]["workloads"] == [
         "trinity-mini.docqa"]
-    assert len(bench["workloads"]) == 8 and all(
+    assert len(bench["workloads"]) == 9 and all(
         w["chips"] == 1 for w in bench["workloads"])
 
 
@@ -1194,13 +1201,13 @@ def test_prefill_ahead_share_is_a_data_file():
     from llm_d_tpu.utils.metrics import (PREFILL_AHEAD_TOKENS_METRIC,
                                          STEP_PREFILL_TOKENS_METRIC)
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    assert bench["per_layer"][-2] == {
+    assert bench["per_layer"][-2 - len(LING_METRICS)] == {
         "name": "prefill_ahead_share", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "scheduler and KV manager",
         "moves": "ttft_p95_ms",
         "workloads": ["mellum2.ide", "trinity-mini.docqa", "qwen3moe.chat",
                       "kanana2.batch", "phi4flash.longdoc"]}
-    entry = bench["per_layer"][-2]
+    entry = bench["per_layer"][-2 - len(LING_METRICS)]
     d = json.loads((BENCH / "layer_metrics"
                     / "prefill_ahead_share.json").read_text())
     assert all(d[k] == entry[k] for k in (
@@ -1231,7 +1238,7 @@ def test_moe_one_pass_share_is_a_data_file():
     reports ``ttft_p95_ms``); the counter's own test is in
     tests/test_program_parts.py."""
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    entry = bench["per_layer"][-1]
+    entry = bench["per_layer"][-1 - len(LING_METRICS)]
     assert entry == {
         "name": "moe_one_pass_share", "unit": "%", "better": "higher",
         "source": "program_span", "layer": "MoE kernels",
@@ -1254,3 +1261,245 @@ def test_moe_one_pass_share_is_a_data_file():
         n for n, w in cells.items() if "--quantization" in json.loads(
             (REPO / configs[w["config"]]).read_text())["serve_args"])
 
+
+
+# ---- ling-3.0-flash-vl / ling3.longdoc (PR 49) ------------------------------
+
+def _ling_conf():
+    return json.loads((BENCH / "configs"
+                       / "ling-3.0-flash-vl.json").read_text())
+
+
+def test_ling_cell_and_its_files():
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    cell = bench["workloads"][-1]                   # appended last
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
+        "ling3.longdoc", "ling-3.0-flash-vl", "longdoc", 1)
+    assert len(cell["why"]) <= 200
+    entry = bench["configs"][-1]
+    conf = _ling_conf()
+    assert entry["name"] == conf["name"] == "ling-3.0-flash-vl"
+    assert entry["reduced"] == conf["reduced"] == [
+        "num_hidden_layers", "layer_types", "num_experts", "vocab_size",
+        "first_k_dense_replace"]
+    assert entry["source"] == conf["source"] and len(entry["why"]) <= 200
+    assert entry["file"] == "benchmarks/configs/ling-3.0-flash-vl.json"
+    assert conf["reference"] == "ling_linear"
+    assert "--quantization" not in conf["serve_args"]       # bf16 as published
+    # the published layers 1-7: the one leading dense layer, a whole period
+    # at 5 : 1 and one layer more
+    assert conf["layer_types"] == ["linear_attention"] * 4 + [
+        "full_attention"] + ["linear_attention"] * 2
+    assert conf["num_hidden_layers"] == 7 and conf["first_k_dense_replace"] == 1
+    assert conf["layer_types"][1:].count("linear_attention") == 5 \
+        == conf["layer_group_size"] - 1
+    pub = conf["published"]
+    assert (pub["num_hidden_layers"], pub["num_experts"], pub["vocab_size"],
+            pub["first_k_dense_replace"]) == (42, 512, 157184, 2)
+    assert conf["router_experts"] == 512 == 4 * conf["num_experts"]
+    assert pub["vocab_size"] == 4 * conf["vocab_size"]
+    assert conf["num_experts"] >= 8 and conf["vocab_size"] * 8 >= 157184
+    for key in ("reduced_why", "assumed", "deployment", "memory_account"):
+        assert conf[key] and "TO WRITE" not in json.dumps(conf[key]), key
+    assert {"safe_gate", "output_gate", "qk_norm", "rotary", "linear_silu",
+            "router", "mla_layer_of_a_group", "max_model_len",
+            "init_scales"} <= set(conf["assumed"])
+    chk = conf["correctness"]
+    # two prompts of each length: a generated sequence reads as one sample
+    assert chk["prompt_lens"] == [300, 2300, 6200] * 2 and chk["n_gen"] == 128
+    assert max(chk["ks"]) < chk["n_gen"]
+    assert "TO WRITE" not in json.dumps(chk)
+    mix = json.loads((BENCH / "traffic" / "longdoc.json").read_text())
+    assert mix["clients"] == int(conf["serve_args"][
+        conf["serve_args"].index("--max-num-seqs") + 1]) == 16
+    per_layer = {m["name"]: m for m in bench["per_layer"]}
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names[-len(LING_METRICS):] == LING_METRICS       # in order, last
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    for name in LING_METRICS:
+        assert per_layer[name]["workloads"] == ["ling3.longdoc"]
+        d = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())
+        assert (BENCH / "readers" / f"{d['reader']}.py").exists()
+        assert all(d[k] == per_layer[name][k] for k in (
+            "name", "unit", "better", "source", "layer", "moves"))
+        assert "workloads" not in e2e[d["moves"]]       # the cell reports it
+    for name in ("lin_scan_roofline_share", "lin_decode_hbm_share",
+                 "mla_prefill_mxu_share.ling3", "mla_decode_hbm_share.ling3"):
+        d = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())
+        assert d["args"]["config"] == "ling-3.0-flash-vl" and d["unit"] == "%"
+    # no accepted list is lengthened
+    assert all("ling3.longdoc" not in m.get("workloads", [])
+               for m in bench["per_layer"] if m["name"] not in LING_METRICS)
+    assert all("ling3.longdoc" not in m.get("workloads", [])
+               for m in bench["end_to_end"])
+
+
+def test_ling_config_holds_every_published_key():
+    row = _catalog_row("Ling-3.0-flash-VL")
+    conf = _ling_conf()
+    assert conf["source"] == row["source_url"]
+    for key, value in row["config"].items():
+        if key not in conf["reduced"]:
+            assert conf[key] == value, key
+    assert set(conf["reduced"]) - {"layer_types"} <= set(row["config"])
+    # the layers kept carry no SwiGLU limit
+    assert not any(conf["expert_swiglu_limit_list"][1:8]
+                   + conf["share_expert_swiglu_limit_list"][1:8])
+    kinds = ["full_attention" if (i + 1) % conf["layer_group_size"] == 0
+             else "linear_attention" for i in range(42)]
+    assert conf["layer_types"] == kinds[1:8]
+
+
+@pytest.mark.parametrize("rehearse", [False, True])
+def test_ling_config_maps_onto_the_program(rehearse):
+    import modelcfg
+    from llm_d_tpu.models import get_model
+    from llm_d_tpu.models.config import FULL, LINEAR, ModelConfig
+    conf = _ling_conf()
+    mc = ModelConfig(**modelcfg.model_config_fields(conf, rehearse))
+    model = get_model(mc)
+    assert model.__name__.endswith("models.moe") and mc.use_mla
+    assert mc.linear_by_layer and mc.mla_layer_kinds == (FULL,)
+    assert mc.layer_types == tuple(conf["layer_types"])
+    assert mc.layer_types.count(LINEAR) == 6
+    assert (mc.first_dense_layers, mc.first_local_expert, mc.q_lora_rank,
+            mc.num_shared_experts) == (1, 0, 0, 1)
+    assert (mc.scoring_func, mc.moe_renormalize, mc.routed_scaling_factor,
+            mc.lin_gate_floor, mc.lin_conv_kernel) == (
+        "sigmoid", True, 2.5, -5.0, 4)
+    assert model.kv_cache_layers(mc) == {"kv": 1}
+    if not rehearse:
+        assert (mc.num_experts, mc.num_held_experts, mc.num_experts_per_tok,
+                mc.n_group, mc.topk_group) == (512, 128, 8, 8, 4)
+        assert mc.mla_geometry(FULL) == (32, 0, 512, 128, 64, 128, 6e6, 0, 0)
+        assert (mc.lin_num_heads, mc.lin_key_dim, mc.lin_value_dim) == (
+            32, 128, 128)
+        assert model.kv_cache_layout(mc) == {"kv": 640}
+        pool = model.state_pool_shapes(mc, 17)
+        assert pool["ssm"].shape == (6, 17, 32, 128, 128)
+        assert pool["conv"].shape == (6, 17, 3, 12288)
+        assert (mc.vocab_size, mc.max_model_len, mc.hidden_size,
+                mc.intermediate_size, mc.moe_intermediate_size) == (
+            39296, 32768, 2560, 6144, 768)
+        from llm_d_tpu.ops.linear_attention import pallas_ineligible_reason
+        assert not pallas_ineligible_reason(
+            mc.lin_num_heads, mc.lin_key_dim, mc.lin_value_dim)
+
+
+def test_kda_work_counts_real_rows_and_tokens():
+    import kdawork
+    from readers import lin_roofline, scope_share
+    conf = _ling_conf()
+    assert kdawork.geometry(conf) == (6, 32, 128, 128)
+    assert kdawork.state_bytes(conf) == 2 * 2**20
+    # 15 decode rows: each row's state read and written once in six layers
+    assert kdawork.decode_state_bytes(conf, 15) == 15 * 6 * 4 * 2**20
+    # a 2,048-token chunk of one row
+    assert kdawork.scan_flops(conf, 2048) == 2048 * 6 * 32 * 6 * 128 * 128
+    assert kdawork.scan_bytes(conf, 1, 2048) == 6 * (
+        2 * 2**20 + 2048 * 32 * 5 * 128 * 2)
+    peaks = json.loads((BENCH / "peaks.json").read_text())["TPU v5 lite"]
+    counts = {"ssm_decode_rows": 15, "ssm_prefill_rows": 1,
+              "ssm_prefill_tokens": 2048}
+    assert lin_roofline.least_seconds("decode", conf, counts, peaks) == (
+        15 * 6 * 4 * 2**20 / peaks["hbm_bytes_per_s"])
+    assert lin_roofline.least_seconds("scan", conf, counts, peaks) == max(
+        kdawork.scan_flops(conf, 2048) / peaks["bf16_flops"],
+        kdawork.scan_bytes(conf, 1, 2048) / peaks["hbm_bytes_per_s"])
+    with pytest.raises(ValueError, match="unknown bound"):
+        lin_roofline.least_seconds("other", conf, counts, peaks)
+    # without a trace a reader reads nothing (the parent, a CPU rehearsal)
+    assert lin_roofline.read({"trace": None}, "delta_chunk_scan", "scan",
+                             "ling-3.0-flash-vl") is None
+    assert scope_share.read({"trace": None}, ["llmd.lin.state"]) is None
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_ling_cell_rehearses_on_the_cpu(trace):
+    """``run.py --rehearse --workload ling3.longdoc``: the harness's whole
+    path (server, load generator, the checks (a)-(d) against
+    ``references/ling_linear.py``) at the tiny preset."""
+    import os
+    import subprocess
+    out = subprocess.run(
+        [sys.executable, str(BENCH / "run.py"), "--workload",
+         "ling3.longdoc", "--seed", str(2**31 + 4949), "--seconds", "4",
+         "--trace", str(trace), "--rehearse"],
+        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), timeout=900)
+    assert out.returncode == 0
+    last = json.loads(out.stdout.strip().splitlines()[-1])
+    assert last["correct"] is True and last["failed"] == 0
+    assert last["attempted"] > 0
+    got = {k.removeprefix("cpu_rehearsal."): v["value"]
+           for k, v in last["metrics"].items()}
+    if not trace:
+        assert set(got) == {"ttft_p50_ms", "ttft_p95_ms", "setup_s"}
+        return
+    assert {"step_ms.mixed", "step_ms.decode.ling3", "itl_p95_ms.ling3",
+            "attn_query_fill_share", "prefix_hit_share",
+            "queue_wait_p95_ms"} <= set(got)
+    assert got["prefix_hit_share"] == 0.0       # off for a recurrent state
+    # device metrics are read from a device trace only
+    assert not {"device_part_share.linear", "lin_scan_roofline_share",
+                "lin_decode_hbm_share", "device_idle_share.ling3",
+                "mla_prefill_mxu_share.ling3"} & set(got)
+
+
+def test_kda_mechanism_check_names_each_fault():
+    import references.ling_linear as ref
+    import kda_mechanism_check
+    # the tool reads the faults off the reference a configuration names
+    assert not hasattr(kda_mechanism_check, "WRONG")
+    assert {name for name, _, _ in ref.FAULT_TABLE} == {
+        "no_decay", "no_delta", "beta_one", "conv_tail_zeroed",
+        "stale_state", "no_out_gate", "no_group_limit", "int8_weights"}
+    assert all(what and must is True for _, what, must in ref.FAULT_TABLE)
+    source = open(ref.__file__).read()
+    assert all(f'"{name}" in FAULTS' in source
+               or f'"{name}" not in FAULTS' in source
+               for name, _, _ in ref.FAULT_TABLE)
+    assert ref.FAULTS == set()          # nothing wrong in a served comparison
+    assert ref.CHUNK == int(_ling_conf()["serve_args"][
+        _ling_conf()["serve_args"].index("--max-num-batched-tokens") + 1])
+    tol = _ling_conf()["correctness"]["reference_tolerance"]
+    # PR 49, on the chip, 768 positions a seed (calls E1, F1, G1; (median,
+    # p90)): every served reading passes BOTH limits; the median refuses every
+    # reading of the int8-rounded reference, and both limits every fault read
+    served = [(0.0873, 0.2483), (0.1178, 0.3592), (0.1012, 0.2803),
+              (0.0949, 0.2893), (0.0807, 0.2302), (0.0883, 0.2572),
+              (0.1027, 0.2823), (0.0928, 0.2804), (0.0878, 0.2637),
+              (0.1026, 0.2911), (0.1028, 0.2893), (0.1040, 0.2871),
+              (0.0866, 0.2702)]
+    int8 = [(0.1606, 0.4195), (0.2018, 0.5216), (0.1566, 0.4105),
+            (0.1813, 0.4759), (0.1630, 0.4332), (0.1652, 0.4562),
+            (0.1744, 0.4559), (0.1739, 0.4409), (0.1721, 0.4707),
+            (0.1623, 0.4381), (0.1780, 0.4631), (0.1699, 0.4574)]
+    faults = [(2.6003, 3.9230), (2.1988, 3.2881), (1.9355, 3.0323),
+              (1.7472, 2.8449), (0.2529, 0.6506), (0.1914, 0.5640),
+              (0.1609, 0.7489), (0.2099, 0.6432), (0.1795, 0.7159),
+              (0.3157, 0.7261)]
+    assert all(m < tol["median"] and p < tol["p90"] for m, p in served)
+    assert all(m > tol["median"] for m, _ in int8)
+    assert all(m > tol["median"] and p > tol["p90"] for m, p in faults)
+    # room on both sides of each limit, a tenth at least
+    assert 1.1 * max(m for m, _ in served) < tol["median"] \
+        < min(m for m, _ in int8) / 1.1
+    assert 1.1 * max(p for _, p in served) < tol["p90"] \
+        < min(p for _, p in faults) / 1.1
+
+
+def test_lowered_step_programs_names_a_decode_and_a_mixed_program(
+        capsys, monkeypatch):
+    """The tool that holds a PR's step programs against its parent's: two
+    lines a configuration, the same text whenever the tree is the same."""
+    import lowered_step_programs as tool
+    lines = []
+    for _ in range(2):
+        monkeypatch.setattr(sys, "argv", ["tool", "ling-3.0-flash-vl"])
+        assert tool.main() == 0
+        lines.append(capsys.readouterr().out.strip().splitlines())
+    assert lines[0] == lines[1] and len(lines[0]) == 2
+    (decode, mixed) = (ln.split(" (")[1].split(")")[0] for ln in lines[0])
+    assert decode.endswith(", 1") and not mixed.endswith(", 1")
+    assert all(len(ln.split()[-2]) == 40 for ln in lines[0])      # a sha1

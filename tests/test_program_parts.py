@@ -179,8 +179,11 @@ def test_the_vocabulary_is_the_one_the_reader_groups():
     # llmd.attn.index (PR 39), llmd.attn.cross and llmd.gmu (PR 41) are in
     # no group of the accepted reader: their shares are read by
     # readers/scope_share.py (device_part_share.index / .cross / .gmu).
+    # llmd.lin.state and llmd.lin.proj (PR 49) neither: device_part_share
+    # .linear reads the former by its scope, the reader's table prints both.
     assert set(grouped) - {device_parts.UNSCOPED} == SCOPES - {
-        "llmd.attn.index", "llmd.attn.cross", "llmd.gmu"}
+        "llmd.attn.index", "llmd.attn.cross", "llmd.gmu", "llmd.lin.state",
+        "llmd.lin.proj"}
     assert device_parts.scope_of(
         "jit(step_fn)/while/body/closed_call/llmd.ssm.state/llmd.tiles/"
         "cumsum:") == "llmd.tiles"

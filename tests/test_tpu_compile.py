@@ -205,6 +205,35 @@ def _ssm_scan(T, S, H=32, P=128, N=256, G=2, L=6, c=128, slots=65):
                 _sds((NT,), jnp.bool_), _sds((NT,), jnp.bool_)]
 
 
+def _delta_update(H=32, K=128, V=128, S=16, L=6):
+    """A linear-attention layer's one-token delta-rule update, in place on
+    the state pool of ``S`` slots and the trash slot (ling-3.0-flash-vl's
+    geometry)."""
+    from llm_d_tpu.ops.pallas.delta_update import delta_decode_update as fn
+    fn.hlo_name = "delta_decode_update"
+    return fn, [_sds((S, H, K), jnp.float32), _sds((S, H, K), jnp.float32),
+                _sds((S, H, V), jnp.float32), _sds((S, H, K), jnp.float32),
+                _sds((S, H), jnp.float32),
+                _sds((L, S + 1, H, K, V), jnp.float32), _sds((), jnp.int32),
+                _sds((S,), jnp.int32), _sds((S,), jnp.bool_)]
+
+
+def _delta_scan(T, S, H=32, K=128, V=128, L=6, c=64, slots=17):
+    """The walk over the pieces of a step of ``T`` tokens in ``S`` rows
+    (ceil(T / c) + S of them), their terms computed by XLA."""
+    from llm_d_tpu.ops.pallas.delta_scan import delta_chunk_scan as fn
+    fn.hlo_name = "delta_chunk_scan"
+    NT = -(-T // c) + S
+    return fn, [_sds((NT, H, c, K), jnp.float32),
+                _sds((NT, H, c, V), jnp.float32),
+                _sds((NT, H, c, c), jnp.float32),
+                _sds((NT, H, c, K), jnp.float32),
+                _sds((NT, H, c, K), jnp.float32), _sds((NT, H, K), jnp.float32),
+                _sds((L, slots, H, K, V), jnp.float32), _sds((), jnp.int32),
+                _sds((NT,), jnp.int32), _sds((NT,), jnp.bool_),
+                _sds((NT,), jnp.bool_), _sds((NT,), jnp.bool_)]
+
+
 def _ssm1_update(S=8, inner=5120, N=16, L=9, slots=9):
     from llm_d_tpu.ops.pallas.ssm1_scan import ssm1_decode_update as fn
     fn.hlo_name = "ssm1_decode_update"
@@ -517,6 +546,13 @@ CASES = [
                  id="ssm_chunk_scan-falcon-h1-T16-S8"),
     pytest.param(functools.partial(_dense_decode, 20, 4, 128, L=6),
                  id="dense_decode-falcon-h1-20x4x128"),
+    # ling-3.0-flash-vl: the linear-attention layers' two kernels over the
+    # state pool (32 heads x 128 x 128 float32 a slot and layer).
+    pytest.param(_delta_update, id="delta_decode_update-ling3-S16"),
+    pytest.param(functools.partial(_delta_scan, T=2048, S=16),
+                 id="delta_chunk_scan-ling3-T2048-S16"),
+    pytest.param(functools.partial(_delta_scan, T=16, S=8),
+                 id="delta_chunk_scan-ling3-T16-S8"),
     pytest.param(functools.partial(_prefill_tiles, 20, 4, 128, T=2048, S=64,
                                    Q=512, B=1024, L=6),
                  id="flash_prefill-tiles-falcon-h1-T2048-S64"),
